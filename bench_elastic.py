@@ -256,9 +256,7 @@ def run_job(
         server.stop()
 
 
-def _boot_sched_job(
-    tmp, tag, n_records, epochs, num_workers, cache_dir, seed, extra=()
-):
+def _boot_sched_job(tmp, tag, n_records, epochs, num_workers, seed, extra=()):
     """Boot one window-mode ProcessBackend job (its own master/server/
     manager) for the sched contention section. Caller polls and stops."""
     from elasticdl_tpu.cluster.pod_backend import ProcessBackend
@@ -286,7 +284,6 @@ def _boot_sched_job(
             "--local_updates", str(LOCAL_UPDATES),
             "--num_workers", str(num_workers),
             "--worker_backend", "process",
-            "--compile_cache_dir", cache_dir,
             *extra,
         ]
     )
@@ -410,12 +407,11 @@ def sched_main():
     be_records = int(os.environ.get("EDL_SCHED_BENCH_RECORDS", 2048))
     g_records = be_records // 2
     tmp = tempfile.mkdtemp(prefix="edl_sched_bench_")
-    cache = os.path.join(tmp, "xla-cache")
     arbiter = PriorityArbiter(capacity=2)
     # speculation on for the best-effort job: after the preemption it
     # runs degraded, exactly when a straggler clone can win
     be = _boot_sched_job(
-        tmp, "be", be_records, 1, 2, cache, seed=0,
+        tmp, "be", be_records, 1, 2, seed=0,
         extra=("--qos_class", "best-effort", "--speculate"),
     )
     handle_be = arbiter.register(
@@ -451,7 +447,7 @@ def sched_main():
                 assert got == 1, f"guaranteed request got {got} tokens"
                 t_preempt = time.time()
                 g = _boot_sched_job(
-                    tmp, "g", g_records, 1, 1, cache, seed=7,
+                    tmp, "g", g_records, 1, 1, seed=7,
                     extra=("--qos_class", "guaranteed"),
                 )
                 g["manager"].start_workers()
@@ -542,10 +538,10 @@ def main():
         os.environ.get("EDL_ELASTIC_BENCH_EPOCHS", 1 if small_host else 2)
     )
     # Fast worker recovery via the framework's --compile_cache_dir
-    # (default on, shared per seed so the stable and churn runs see the
-    # same cache state): a relaunched replacement reuses the
-    # incumbents' compiled programs instead of re-paying the XLA
-    # compile. EDL_ELASTIC_BENCH_CACHE=0 measures the cold-boot path.
+    # (default on, one fixed directory — common/args.py's resolver):
+    # a relaunched replacement reuses the incumbents' compiled
+    # programs instead of re-paying the XLA compile.
+    # EDL_ELASTIC_BENCH_CACHE=0 measures the cold-boot path.
     use_cache = os.environ.get("EDL_ELASTIC_BENCH_CACHE", "1") == "1"
     # Warm standbys (--num_standby_workers) are the framework's answer
     # to the relaunch transient: a pre-booted, AOT-compiled spare is
@@ -571,7 +567,7 @@ def main():
             f"{int(KILL_FIRST * 100)}% and {int(KILL_LAST * 100)}%",
             file=sys.stderr,
         )
-        cache_dir = os.path.join(tmp, "xla-cache") if use_cache else ""
+        cache_dir = "auto" if use_cache else ""
         # The stable baseline must be measured over a window long
         # enough that scheduler noise averages out: a ~25s window
         # produced a 42% stable swing between seeds in a run where the

@@ -575,7 +575,7 @@ class JobRun:
     dispatcher's draining hook pointed at the manager's policy-stop
     set, and the goodput counters surfaced through GetSchedStats."""
 
-    def __init__(self, spec: JobSpec, run_dir: str, cache_dir: str,
+    def __init__(self, spec: JobSpec, run_dir: str,
                  worker_env: Dict[str, str]):
         self.spec = spec
         self.t0: Optional[float] = None
@@ -587,7 +587,6 @@ class JobRun:
         # bare bool
         self.ps_dead = threading.Event()
         self._run_dir = run_dir
-        self._cache_dir = cache_dir
         self._worker_env = dict(worker_env)
         self._recovery = None
         # master-migration plane (master/migration.py): armed when the
@@ -667,7 +666,6 @@ class JobRun:
             "--local_updates", str(spec.local_updates),
             "--num_workers", str(spec.workers),
             "--worker_backend", "process",
-            "--compile_cache_dir", self._cache_dir,
         ]
         if spec.num_ps:
             argv += ["--num_ps", str(spec.num_ps)]
@@ -1172,7 +1170,6 @@ class ScenarioRunner:
         run = JobRun(
             self._scaled(spec),
             self.run_dir,
-            os.path.join(self.run_dir, "xla-cache"),
             worker_env,
         )
         run.start()
@@ -1239,7 +1236,6 @@ class ScenarioRunner:
                 JobSpec(**{**spec.__dict__, "tag": f"{spec.tag}-baseline"})
             ),
             self.run_dir,
-            os.path.join(self.run_dir, "xla-cache"),
             {},
         )
         base.start()

@@ -1,54 +1,23 @@
-"""Link-weather probing and tracking for the adaptive sync plane.
+"""Link-weather tracking for the adaptive sync plane.
 
-Two complementary sources of "link weather" — an estimate of the
-host<->master/PS link bandwidth that the sync plane rides on:
-
-- ``probe_link_mbps()``: the active h2d probe factored out of bench.py
-  (a plain jax.device_put timing). Fail-loud by contract: the bench
-  refuses to report a window run without link accounting, so a probe
-  that cannot produce a positive number raises instead of returning a
-  placeholder.
-
-- ``LinkWeather``: the passive tracker the worker's sync thread feeds
-  from the push timing it already has. Every window push knows how
-  many wire bytes it sent and how long the RPC took; that ratio IS a
-  bandwidth sample, with zero extra traffic. The tracker keeps a short
-  ring of recent samples and exposes a median-of-recent estimate that
-  is robust to the occasional stalled push.
+"Link weather" is an estimate of the worker<->master/PS network link
+bandwidth that the sync plane rides on. ``LinkWeather`` is the passive
+tracker the worker's sync thread feeds from the push timing it already
+has: every window push knows how many wire bytes it sent and how long
+the RPC took; that ratio IS a bandwidth sample, with zero extra
+traffic. The tracker keeps a short ring of recent samples and exposes
+a median-of-recent estimate that is robust to the occasional stalled
+push.
 
 The pure per-round wire-form decision lives in sync_policy.decide();
-this module only measures.
+this module only measures. (The active h2d probe the bench brackets
+its runs with lives with the bench: bench.py `_probe_link_mbps`.)
 """
 
 from __future__ import annotations
 
 import threading
 from collections import deque
-
-
-def probe_link_mbps() -> float:
-    """Active h2d link-bandwidth probe, run UNCONDITIONALLY around every
-    bench window run. BENCH_r05 shipped ``link_mbps_per_run: []`` /
-    ``headline_link_mbps: null`` because the probe hid behind an
-    ``if on_tpu:`` gate — the weather-normalization column the protocol
-    promises was silently empty. The probe is a plain jax.device_put
-    timing (bench_resnet.measure_link_bandwidth), which works on any
-    backend; if it cannot produce a positive number the caller FAILS
-    rather than report a run without its link weather."""
-    try:
-        from bench_resnet import measure_link_bandwidth
-
-        mbps = float(measure_link_bandwidth())
-    except Exception as e:
-        raise RuntimeError(
-            f"link-bandwidth probe failed ({e!r}): refusing to report "
-            "a window run without link accounting"
-        ) from e
-    if not mbps > 0:
-        raise RuntimeError(
-            f"link-bandwidth probe returned non-positive {mbps!r}"
-        )
-    return mbps
 
 
 class LinkWeather:

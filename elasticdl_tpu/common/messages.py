@@ -194,6 +194,7 @@ class ReportPhaseStatsRequest(_WireRequest):
 
     worker_id: int = -1
     phases: Any = None  # {phase: {"seconds": float, "count": int}}
+    device: Any = None  # {"platform", "device_kind", "chips"}: where they ran
 
 
 @dataclasses.dataclass

@@ -5,12 +5,13 @@ single-file C++ sources compiled on first use with the host toolchain
 and loaded over ctypes — no build step, no wheels, and a pure-Python
 fallback wherever g++ is missing. This helper owns the once-only
 compile/load/cache logic so every native component shares one
-implementation of the staleness check and failure path.
+implementation of the source-hash naming and failure path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -30,22 +31,33 @@ def compile_and_load(
     configure: Callable[[ctypes.CDLL], None],
     what: str = "native library",
 ) -> Optional[ctypes.CDLL]:
-    """Compile `src` into `so` (if missing or older than the source),
-    load it, apply `configure(lib)` (restype/argtypes), cache by path.
-    Returns None — once, with a warning — when the toolchain or load
-    fails; callers fall back to their Python path."""
+    """Compile `src` into `so` named by a hash of the source
+    (`libx.so` -> `libx-<sha256[:16]>.so`), load it, apply
+    `configure(lib)` (restype/argtypes), cache by path. Only a library
+    built from the `.cc` on disk is ever loaded: a copied tree resets
+    mtimes, and a stale build product left by another checkout has
+    another name. Returns None — once, with a warning — when the
+    toolchain or load fails; callers fall back to their Python path."""
     with _lock:
         if so in _cache:
             return _cache[so]
         try:
-            if not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src):
-                os.makedirs(os.path.dirname(so), exist_ok=True)
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+            stem, ext = os.path.splitext(so)
+            built = f"{stem}-{digest}{ext}"
+            if not os.path.exists(built):
+                os.makedirs(os.path.dirname(built), exist_ok=True)
+                # processes of one job race to the first build: each
+                # links its own file and renames it into place whole
+                tmp = f"{built}.{os.getpid()}.tmp"
                 subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", so],
+                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
                     check=True,
                     capture_output=True,
                 )
-            lib = ctypes.CDLL(so)
+                os.replace(tmp, built)
+            lib = ctypes.CDLL(built)
             configure(lib)
             _cache[so] = lib
         except Exception as e:  # pragma: no cover - toolchain missing

@@ -345,7 +345,7 @@ class MasterServicer:
     # -- policy plane (elasticdl_tpu/sched/) --------------------------------
 
     def set_phase_stats_sink(self, fn):
-        """fn(worker_id, phases); wired to
+        """fn(worker_id, phases, device); wired to
         sched.PhaseStatsAggregator.ingest — the autoscaler's telemetry
         feed. Without a sink, ReportPhaseStats is a no-op ack."""
         self._phase_stats_sink = fn
@@ -366,7 +366,11 @@ class MasterServicer:
         harmless, which is what makes this RPC idempotent."""
         sink = getattr(self, "_phase_stats_sink", None)
         if sink is not None:
-            sink(int(req.get("worker_id", -1)), req.get("phases"))
+            sink(
+                int(req.get("worker_id", -1)),
+                req.get("phases"),
+                req.get("device"),
+            )
         return {}
 
     def get_sched_stats(self, req: dict) -> dict:

@@ -23,8 +23,9 @@ scheme re-forming p = exp(s - lse) from O(L*D) residuals — nothing
 quadratic is ever saved, and no atomics: each kernel owns its output
 block (FlashAttention-2 layout). Numerics are validated
 block-for-block against the reference math in
-tests/test_flash_attention.py, in Pallas interpret mode on CPU and
-compiled under EDL_TPU_TESTS=1 on the chip.
+tests/test_flash_attention.py in Pallas interpret mode on CPU, and
+compiled on the chip by chip_smoke.py and under EDL_TPU_TESTS=1
+(`check_against_reference`).
 
 Layout contract: [B, L, H, D] ("blhd", matching transformer_lm), any
 float dtype; compute is f32. L must divide by the 128 block; callers
@@ -322,19 +323,78 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
     """Differentiable fused attention, [B, L, H, D] -> [B, L, H, D].
-    `interpret=True` runs the kernel in the Pallas interpreter (CPU
-    testing)."""
+    `interpret=True` runs the kernel in the Pallas interpreter and is
+    for tests only (no model path passes it); compiled, the kernels
+    are Mosaic programs and exist on the TPU alone."""
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "flash_attention compiles for the TPU only (default backend "
+            f"{jax.default_backend()!r}); model code calls attention(), "
+            "tests pass interpret=True"
+        )
     return _flash_attention(q, k, v, causal, interpret)
+
+
+# check_against_reference's bound on max|kernel - ref| / max|ref|: bf16
+# rounds to 2^-8 of a value; the kernels round p, ds and each output to
+# bf16 where the f32 reference rounds nothing, and the comparison is
+# against the tensor's largest magnitude — four such roundings
+# stacked. Interpret mode on the CPU measures 0.2-0.6%.
+REFERENCE_TOLERANCE = 2.0**-6
+
+
+def check_against_reference(shape, interpret: bool = False, seed: int = 0):
+    """Forward and all three backward kernels at one [B, L, H, D] bf16
+    shape against `reference_attention` in true f32 — chip_smoke.py's
+    kernel phase and the gated chip tests. Returns, for o/dq/dk/dv,
+    max|kernel - ref| / max|ref|. The cotangent is a fixed random
+    tensor, so each gradient is checked against a generic direction.
+    The reference runs one head at a time: its [L, L] scores and their
+    backward copies would not fit beside each other at L=8192."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    q, k, v, w = (
+        jnp.asarray(rng.standard_normal(shape), dtype=jnp.bfloat16)
+        for _ in range(4)
+    )
+
+    def through(attn):
+        def loss(q, k, v, w):
+            o = attn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    (_, o), grads = through(
+        lambda q, k, v: flash_attention(q, k, v, interpret=interpret)
+    )(q, k, v, w)
+    ref_fn = through(reference_attention)
+    refs = []
+    with jax.default_matmul_precision("highest"):
+        for h in range(shape[2]):
+            qh, kh, vh, wh = (
+                x[:, :, h : h + 1].astype(jnp.float32) for x in (q, k, v, w)
+            )
+            (_, oh), gh = ref_fn(qh, kh, vh, wh)
+            refs.append((oh, *gh))
+    errors = {}
+    for name, got, ref in zip(
+        ("o", "dq", "dk", "dv"),
+        (o, *grads),
+        (jnp.concatenate(parts, axis=2) for parts in zip(*refs)),
+    ):
+        got = got.astype(jnp.float32)
+        errors[name] = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+    return errors
 
 
 # Auto-engage threshold: estimated bytes of the materialized scores
 # (+backward copies) beyond which XLA's [L,L] path approaches the
-# 16G HBM and the O(L*D) kernels take over. Chip-measured A/B
-# (docs/performance.md): XLA's fused attention is FASTER wherever its
-# quadratic working set fits (2-2.5x at L<=16k, b1 h8 d64 — head
-# batching beats the per-head grid), and hard-OOMs at L=32k (34G
-# needed) where the kernels run fine — the kernels are the
-# long-context ENABLER, not a short-sequence speedup.
+# 16G HBM and the O(L*D) kernels take over — the kernels are meant as
+# the long-context ENABLER, not a short-sequence speedup. Where the
+# crossover in time and the out-of-memory length sit on this machine
+# is not measured (ROADMAP S5).
 FLASH_SCORE_BYTES = 6e9
 
 
@@ -343,10 +403,9 @@ def attention(q, k, v, causal: bool = True):
 
     On TPU the Pallas kernels engage automatically when the estimated
     quadratic working set of XLA's materializing path would crowd HBM
-    (see FLASH_SCORE_BYTES); otherwise XLA's fused attention runs —
-    measured faster wherever it fits. EDL_TPU_FLASH=1 forces the
-    kernels on for any block-divisible L, EDL_TPU_FLASH=0 forces them
-    off. Numerics are identical either way
+    (see FLASH_SCORE_BYTES); otherwise XLA's fused attention runs.
+    EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
+    EDL_TPU_FLASH=0 forces them off. Numerics are identical either way
     (tests/test_flash_attention.py)."""
     import os
 

@@ -15,10 +15,7 @@ from jax import lax
 
 
 def vma_of(x) -> frozenset:
-    try:
-        return frozenset(jax.typeof(x).vma)
-    except AttributeError:  # outside shard_map / older tracer
-        return frozenset()
+    return frozenset(jax.typeof(x).vma)
 
 
 def match_vma(x, *examples):

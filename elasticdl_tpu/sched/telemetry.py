@@ -49,15 +49,26 @@ class PhaseStatsAggregator:
         self._lock = threading.Lock()
         # worker_id -> deque[(t, cumulative snapshot)]
         self._history: Dict[int, deque] = {}
+        # worker_id -> {"platform", "device_kind", "chips"} as the
+        # worker itself reported it (kept for dead workers too: which
+        # device a phase ran on outlives the process)
+        self._devices: Dict[int, dict] = {}
         self._ingested = 0
 
-    def ingest(self, worker_id: int, phases: Optional[dict]):
+    def ingest(
+        self,
+        worker_id: int,
+        phases: Optional[dict],
+        device: Optional[dict] = None,
+    ):
         """Sink for the servicer's ReportPhaseStats handler."""
         if not isinstance(phases, dict):
             return
         now = self._clock()
         with self._lock:
             self._ingested += 1
+            if isinstance(device, dict):
+                self._devices[int(worker_id)] = device
             hist = self._history.setdefault(int(worker_id), deque())
             if hist and self._decreased(hist[-1][1], phases):
                 hist.clear()  # relaunched worker: counters restarted
@@ -133,6 +144,7 @@ class PhaseStatsAggregator:
                 "workers_reporting": len(self._history),
                 "samples_ingested": self._ingested,
                 "fractions": fr,
+                "devices": dict(self._devices),
             }
 
 

@@ -22,7 +22,7 @@ setup(
         "elasticdl_tpu.master": ["embedding_cpp/*.cc"],
         "elasticdl_tpu.chaos": ["traces/*.json"],
     },
-    python_requires=">=3.9",
+    python_requires=">=3.12",
     install_requires=[
         "numpy",
         "jax",

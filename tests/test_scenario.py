@@ -465,7 +465,6 @@ def test_jobrun_failed_boot_tears_down_partial_state(tmp_path):
     run = JobRun(
         JobSpec(tag="t", records=64),
         run_dir=str(tmp_path),
-        cache_dir=str(tmp_path),
         worker_env={},
     )
     server = _StubStoppable()
@@ -489,7 +488,6 @@ def test_jobrun_stop_is_safe_on_unbooted_run(tmp_path):
     run = JobRun(
         JobSpec(tag="t", records=64),
         run_dir=str(tmp_path),
-        cache_dir=str(tmp_path),
         worker_env={},
     )
     run.stop()

@@ -226,7 +226,7 @@ def test_jobrun_ps_dead_is_an_event():
     """The unrecoverable-PS flag crosses from the recovery plane's
     monitor thread to the scenario driver loop: it must be a
     threading.Event (a real happens-before edge), not a bare bool."""
-    run = JobRun(spec=None, run_dir="", cache_dir="", worker_env={})
+    run = JobRun(spec=None, run_dir="", worker_env={})
     assert isinstance(run.ps_dead, threading.Event)
     assert not run.ps_dead.is_set()
     t = threading.Thread(target=run.ps_dead.set)  # monitor-thread side
