@@ -12,6 +12,12 @@ GRPC_OPTIONS = [
 ]
 
 SERVICE_NAME = "elasticdl_tpu.Master"
+# that service's update and model RPCs: one per update, never per chunk
+# or per record. Their owners (master/main.py's server, worker/main.py's
+# master client) put both sides of them on the phase timeline.
+MASTER_UPDATE_METHODS = frozenset(
+    ("ReportLocalUpdate", "ReportGradient", "GetModel")
+)
 
 
 # Process exit code for "job completed but with dropped poison tasks":
@@ -306,7 +312,10 @@ ENV_REGISTRY = {
     ENV_SCHED_PHASE_SECS: (
         "policy plane: seconds between worker ReportPhaseStats "
         "telemetry sends (PhaseTimers snapshots feeding the "
-        "autoscaler; 0 disables; default 2.0)"
+        "autoscaler; 0 disables; default 2.0). Also the period at "
+        "which a worker or master appends its phase timeline to "
+        "<log dir>/<process>.spans.jsonl (obs/trace.SpanFile; 2 s "
+        "where this is 0: the timeline has no switch)"
     ),
     ENV_SCHED_AUTOSCALE: (
         "1 enables the utilization autoscaler on the master (also "

@@ -34,10 +34,12 @@ from elasticdl_tpu.common.args import (
 )
 from elasticdl_tpu.common.constants import (
     ENV_WORKER_LOG_DIR,
+    MASTER_UPDATE_METHODS,
     JobType,
     WorkerManagerStatus,
 )
 from elasticdl_tpu.common.log_util import get_logger
+from elasticdl_tpu.obs import trace as obs_trace
 
 logger = get_logger(__name__)
 
@@ -415,7 +417,11 @@ def make_backend(args):
         # child — this process never initialises a TPU backend.
         shares = None
         if not (cpu_requested() or cpu_requested(parse_envs(args.envs))):
+            t_probe = time.time()
             found = probe_device()
+            obs_trace.record_phase(
+                "setup.probe_device", t_probe, time.time() - t_probe
+            )
             if found["platform"] != "tpu":
                 raise ValueError(
                     f"no TPU on this host (jax found {found['platform']!r}) "
@@ -454,9 +460,17 @@ def main(argv=None) -> int:
     # belong to the workers this process spawns, so it must never
     # initialise a TPU backend — whatever the environment says
     from elasticdl_tpu.common.device import pin_cpu
+    from elasticdl_tpu.common.timing import process_start_time
 
     pin_cpu()
     args = master_parser().parse_args(argv)
+    # the phase timeline's way out of a process that ends by SIGKILL:
+    # spans go to <tensorboard_log_dir>/master.spans.jsonl as they close
+    obs_trace.start_span_file(
+        getattr(args, "tensorboard_log_dir", "") or "", "master"
+    )
+    started = process_start_time()
+    obs_trace.record_phase("setup.imports", started, time.time() - started)
     try:
         job_type = validate_master_args(args)
         # fail fast on a bad EDL_SCHED_QOS env (the flag itself is
@@ -508,7 +522,10 @@ def main(argv=None) -> int:
             servicer.version, dispatcher.pending_count(TaskType.EVALUATION)
         )
 
-    server = RpcServer(servicer.handlers(), port=args.port)
+    server = RpcServer(
+        servicer.handlers(), port=args.port, timers=servicer.timers,
+        timed_methods=MASTER_UPDATE_METHODS,
+    )
     server.start()
     # the master's own RPC admission counters ride GetSchedStats, the
     # same surface the ps/kv shards expose through their stats() RPC
@@ -567,7 +584,11 @@ def main(argv=None) -> int:
         ENV_SCHED_DOWN_FRAC,
         ENV_SCHED_UP_FRAC,
     )
-    from elasticdl_tpu.sched import PhaseStatsAggregator, UtilizationAutoscaler
+    from elasticdl_tpu.sched import (
+        PhaseStatsAggregator,
+        UtilizationAutoscaler,
+        merge_phase_snapshots,
+    )
 
     aggregator = PhaseStatsAggregator()
     servicer.set_phase_stats_sink(aggregator.ingest)
@@ -602,6 +623,12 @@ def main(argv=None) -> int:
         if autoscaler is not None:
             out["autoscaler"] = autoscaler.stats()
         out["phases"] = aggregator.snapshot()
+        # cumulative seconds and counts beside the sliding share: the
+        # fleet's merged, and the master's own phases
+        out["phases"]["cumulative"] = merge_phase_snapshots(
+            aggregator.latest_cumulative().values()
+        )
+        out["phases"]["master"] = servicer.timers.snapshot()
         # goodput accounting (completed/requeued/recomputed/
         # drain-flushed records) rides the same stats surface the
         # churn harness and operators already poll
@@ -665,7 +692,12 @@ def main(argv=None) -> int:
         manager.on_shard_failure = recovery.on_shard_failure
         # fallback when the plane is torn down first (see finally)
         manager.on_ps_failure = lambda sid: ps_dead.set()
+    t_spawn = time.time()
     manager.start_workers()
+    obs_trace.record_phase(
+        "setup.spawn_workers", t_spawn, time.time() - t_spawn,
+        {"workers": args.num_workers},
+    )
     if autoscaler is not None:
         autoscaler.start()
     logger.info("Worker manager status: %s", WorkerManagerStatus.RUNNING)

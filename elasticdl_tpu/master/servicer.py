@@ -37,9 +37,11 @@ from elasticdl_tpu.common import codec
 from elasticdl_tpu.common.codec import IndexedRows, merge_indexed_rows
 from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
+from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.master.embedding_store import EmbeddingStore
 from elasticdl_tpu.master.ps_optimizer import PSOptimizer
 from elasticdl_tpu.master.sparse_optimizer import SparseOptimizer
+from elasticdl_tpu.obs import trace as obs_trace
 
 logger = get_logger(__name__)
 
@@ -107,6 +109,15 @@ class MasterServicer:
         # direct shard pushes when the list is empty.
         self._agg_group = self.agg_group = agg_group
         self._lock = threading.Lock()
+        # the master's own phases (GetSchedStats.phases.master, and as
+        # spans in the process's recorder): `apply_wait` (handler entry
+        # to lock held), `grad_decode` (the update's wire form to an f32
+        # tree, validated), `apply` (delta add or PSOptimizer step;
+        # `kind: accumulate` where a report only joined the sum),
+        # `model_encode` (the model raveled for the way down), and the
+        # dispatcher's `rpc.decode` / `rpc.encode`. All are timed under
+        # the model lock and recorded after it is released.
+        self.timers = PhaseTimers(sink=obs_trace.record_phase)
         # Sparse applies serialize among THEMSELVES (read-modify-write
         # per id) but run OUTSIDE self._lock: with a KV-shard-backed
         # store every apply is several RPC fan-outs, and holding the
@@ -509,26 +520,42 @@ class MasterServicer:
                 "aux": aux,
             }
         if method == MethodType.MINIMUM:
+            t_enter = time.time()
             with self._lock:
+                t_locked = time.time()
                 if self._params is None:
-                    return {"version": -1, "params": None, "aux": None}
-                if req.get("only_if_newer") and self._version <= version:
+                    resp = {"version": -1, "params": None, "aux": None}
+                elif req.get("only_if_newer") and self._version <= version:
                     # Bandwidth saver over the reference's always-full
                     # model pulls (servicer.py:282-287): the worker
                     # already holds this version.
-                    return {"version": self._version, "params": None, "aux": None}
-                if req.get("flat"):
+                    resp = {"version": self._version, "params": None, "aux": None}
+                elif req.get("flat"):
                     # single-buffer transport (see codec.ravel_np)
-                    return {
+                    resp = {
                         "version": self._version,
                         "params_flat": codec.ravel_np(self._params),
                         "aux": jax.tree_util.tree_map(np.copy, self._aux),
                     }
-                return {
-                    "version": self._version,
-                    "params": jax.tree_util.tree_map(np.copy, self._params),
-                    "aux": jax.tree_util.tree_map(np.copy, self._aux),
-                }
+                else:
+                    resp = {
+                        "version": self._version,
+                        "params": jax.tree_util.tree_map(np.copy, self._params),
+                        "aux": jax.tree_util.tree_map(np.copy, self._aux),
+                    }
+                t_done = time.time()
+            # the wait for the model lock and the model copied out under
+            # it (µs where none goes), recorded after its release
+            version = resp["version"]
+            self.timers.record(
+                "apply_wait", t_enter, t_locked, kind="get_model",
+                version=version,
+            )
+            self.timers.record(
+                "model_encode", t_locked, t_done, kind="get_model",
+                version=version,
+            )
+            return resp
         # FIXED: serve the exact version — from live PS state when it
         # still matches (standalone eval jobs never train past it),
         # else from the eval-snapshot store / durable checkpoints.
@@ -591,7 +618,9 @@ class MasterServicer:
         applied_version = -1
         ckpt_snapshot = None
         sparse_to_apply = None
+        t_enter = time.time()
         with self._lock:
+            t_locked = time.time()
             if self._params is None:
                 raise ValueError("gradient reported before model init")
             if grads is None and req.get("gradient_flat") is not None:
@@ -617,6 +646,7 @@ class MasterServicer:
                     f"future gradient version {report_version} > {self._version}"
                 )
             self._validate(grads)
+            t_decoded = time.time()
 
             if self._use_async:
                 scale = 1.0
@@ -664,12 +694,17 @@ class MasterServicer:
                     applied = True
                     sparse_to_apply = merged
             resp = {"accepted": True, "version": self._version}
+            t_applied = time.time()
             if req.get("return_model") and self._version != report_version:
                 # a step was applied (by this report or a concurrent
                 # one): hand back the new model inline — the sync-SGD
                 # inner loop becomes ONE rpc per minibatch
                 resp["params_flat"] = self._flat_model(req.get("model_dtype"))
                 resp["aux"] = jax.tree_util.tree_map(np.copy, self._aux)
+            marks = (
+                "gradient" if applied else "accumulate",
+                t_enter, t_locked, t_decoded, t_applied, time.time(), resp,
+            )
             if applied:
                 # snapshot the exact applied version UNDER the lock so a
                 # concurrent report can't skip a checkpoint/eval trigger;
@@ -683,6 +718,7 @@ class MasterServicer:
                         jax.tree_util.tree_map(np.copy, self._aux),
                         self._opt_state_snapshot(),
                     )
+        self._record_update(*marks)
         self._apply_sparse(sparse_to_apply)
         if applied:
             # hooks run OUTSIDE the lock: the eval service calls back
@@ -717,6 +753,7 @@ class MasterServicer:
         ckpt_snapshot = None
         t_apply = time.time()
         with self._lock:
+            t_locked = time.time()
             if self._params is None:
                 raise ValueError("local update reported before model init")
             if report_key and report_key in self._seen_local_updates:
@@ -751,6 +788,7 @@ class MasterServicer:
             # pass-through view; bf16 / int8 / top-k (QuantizedDelta /
             # SparseDelta) decode to the dense f32 vector here
             delta = self._unravel_model(codec.delta_to_f32(req["delta_flat"]))
+            t_decoded = time.time()
             self._params = jax.tree_util.tree_map(
                 lambda p, d: p + scale * np.asarray(d, dtype=np.float32),
                 self._params,
@@ -779,14 +817,18 @@ class MasterServicer:
                 ):
                     self._seen_local_updates.popitem(last=False)
             resp = {"version": self._version}
+            t_applied = time.time()
             # base fell behind (concurrent syncs): return the merged model
             if base_version + steps != self._version or req.get("want_model"):
                 resp["params_flat"] = self._flat_model(req.get("model_dtype"))
                 resp["aux"] = jax.tree_util.tree_map(np.copy, self._aux)
+            marks = (
+                "local_update", t_apply, t_locked, t_decoded, t_applied,
+                time.time(), resp,
+            )
+        self._record_update(*marks)
         # lock wait + apply, retro-recorded under the server span (the
         # duplicate early-return above deliberately skips it)
-        from elasticdl_tpu.obs import trace as obs_trace
-
         obs_trace.record_event(
             "master.apply",
             t_apply,
@@ -984,6 +1026,25 @@ class MasterServicer:
         # sharded mode relative to single-PS, which records every apply
         self._report_train_loss(max(version, prev), req.get("loss"))
         return resp
+
+    def _record_update(
+        self, kind, t_enter, t_locked, t_decoded, t_applied, t_encoded, resp
+    ):
+        """One update's phases from the marks taken under the model
+        lock, recorded once it is released: the wait for the lock, the
+        update decoded, the apply (`kind: accumulate` for a gradient
+        that only joined the sum: no step was taken), and the model
+        raveled for the way down where one goes."""
+        version = resp.get("version")
+        record = self.timers.record
+        record("apply_wait", t_enter, t_locked, kind=kind, version=version)
+        record("grad_decode", t_locked, t_decoded, kind=kind, version=version)
+        record("apply", t_decoded, t_applied, kind=kind, version=version)
+        if resp.get("params_flat") is not None:
+            record(
+                "model_encode", t_applied, t_encoded, kind=kind,
+                version=version,
+            )
 
     def _unravel_model(self, vec):  # edl-lint: disable=lock-discipline -- template read only: the param STRUCTURE is fixed for the life of a job (values are irrelevant to the unravel plan), and report callers already hold the non-reentrant self._lock
         """vec -> pytree against the current param template, through

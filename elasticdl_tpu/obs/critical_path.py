@@ -4,7 +4,8 @@ Given the recorder-shaped span dicts of a traced run, decompose the
 worker sync chain's wall time into where it went:
 
 - ``encode``      device->host quantize + wire-delta materialization
-                  (``worker.quantize`` + ``worker.encode``)
+                  (``worker.quantize`` + ``worker.delta_wait`` +
+                  ``worker.d2h`` + ``worker.encode``)
 - ``queue_wait``  dispatcher admission queue + executor hand-off
                   (``rpc.admission_wait``; 0 outside loop mode)
 - ``combine``     CombineBuffer park time not covered by the lock
@@ -61,7 +62,9 @@ def sync_critical_path_from_spans(
 ) -> Optional[dict]:
     """Component breakdown of the sync chain, or None when the span set
     contains no ``worker.window_sync`` roots (tracing was off)."""
-    roots = [s for s in spans if s["name"] == ROOT]
+    # a chain is a sampled trace: a sync the coin passed over is on the
+    # phase timeline under the same name but starts no trace
+    roots = [s for s in spans if s["name"] == ROOT and s.get("trace_id")]
     if not roots:
         return None
     # chain spans only: the worker's pull/absorb traces are separate
@@ -71,7 +74,12 @@ def sync_critical_path_from_spans(
     chain_ids = {s["trace_id"] for s in roots}
     chain = [s for s in spans if s.get("trace_id") in chain_ids]
     sync_wait = sum(float(s.get("dur", 0.0)) for s in roots)
-    encode = _dur(chain, "worker.quantize", "worker.encode")
+    # the wait for the device and the copy out were inside
+    # `worker.encode` until the phase timeline gave each its own span
+    encode = _dur(
+        chain, "worker.quantize", "worker.delta_wait", "worker.d2h",
+        "worker.encode",
+    )
     queue_wait = _dur(chain, "rpc.admission_wait")
     apply = _dur(chain, "ps.apply", "master.apply")
     park = _dur(chain, "fanin.park")
@@ -116,9 +124,11 @@ def sync_exposed_fraction_from_spans(
     drain, beyond-depth backpressure), so bench.py's A/B asserts the
     fraction drops.
 
-    Returns None when the span set has no stall spans at all AND no
-    sync roots (tracing was off — indistinguishable from a stall-free
-    run only when the run also produced no windows)."""
+    The stall spans are on the always-on phase timeline, so every
+    stall counts whatever the sample rate. Returns None when the span
+    set has no stall spans at all AND no sync roots (spans of another
+    process — indistinguishable from a stall-free run only when the
+    run also produced no windows)."""
     stalls = [s for s in spans if s.get("name") == EXPOSED]
     if not stalls and not any(s.get("name") == ROOT for s in spans):
         return None

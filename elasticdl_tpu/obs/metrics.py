@@ -27,7 +27,6 @@ fleet's registries).
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -345,6 +344,3 @@ def stop_serving_for_tests() -> None:
             _http_server.server_close()
             _http_server = None
 
-
-def snapshot_json() -> str:
-    return json.dumps(get_registry().snapshot(), sort_keys=True)

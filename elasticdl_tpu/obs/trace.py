@@ -16,6 +16,17 @@ when tracing is disabled. The sampling decision is made once per trace
 at the root span; child spans inherit it by construction (a child only
 exists when its parent context does).
 
+Beside the sampled traces the recorder holds the always-on phase
+timeline: :func:`record_phase` spans (``cat == "phase"``) that the
+owners' `common/timing.PhaseTimers` write on closing a phase, whatever
+``EDL_TRACE_SAMPLE`` says. Their ``ts`` is ``time.time()``, the clock a
+device trace is laid on. An interval is recorded once: where a sampled
+trace covers it (:func:`child_context`), the phase span carries that
+trace's ids and serves both readers; otherwise it has no ``trace_id``.
+A process that owns a log directory appends them to a JSON-lines file
+as it goes (:class:`SpanFile`), so a SIGKILL loses at most one flush
+period.
+
 Export is Chrome trace-event JSON ("X" complete events, wall-clock
 microsecond timestamps so spans from different processes align on one
 Perfetto timeline) via :func:`dump_trace` / :func:`chrome_trace`, and
@@ -26,7 +37,9 @@ their process recorder's spans; merge with
 
 from __future__ import annotations
 
+import atexit
 import contextlib
+import itertools
 import json
 import os
 import random
@@ -36,7 +49,10 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
-from elasticdl_tpu.common.constants import ENV_TRACE_SAMPLE
+from elasticdl_tpu.common.constants import (
+    ENV_SCHED_PHASE_SECS,
+    ENV_TRACE_SAMPLE,
+)
 
 # Request-dict key carrying the trace envelope across process
 # boundaries. Popped server-side (rpc/transport.ServerDispatcher)
@@ -47,6 +63,9 @@ _STRIPES = 8
 _DEFAULT_CAPACITY = 8192
 
 _tls = threading.local()
+
+# `cat` of the always-on phase timeline (record_phase)
+PHASE_CAT = "phase"
 
 # Resolved sampling probability; None = not yet read from the env.
 # Kept module-global so the disabled fast path is one float compare.
@@ -114,14 +133,21 @@ class SpanRecorder:
     rpc/policy.WireStats. Each deque is bounded; overflow evicts the
     oldest span on that stripe and bumps the dropped counter, so a
     long-running job keeps the most recent window of spans.
+    ``drain`` hands out what was recorded since the last drain (each
+    span once) and leaves the ring as it is for ``snapshot``;
+    ``pressure`` is set when half a stripe waits to be drained, so a
+    drainer that waits on it loses nothing to a burst.
     """
 
     def __init__(
         self, capacity: int = _DEFAULT_CAPACITY, stripes: int = _STRIPES
     ):
         per = max(1, capacity // max(1, stripes))
+        self._half = max(1, per // 2)
+        self.pressure = threading.Event()
+        # counts: [dropped, recorded, drained]
         self._stripes = [
-            (threading.Lock(), deque(maxlen=per), [0])
+            (threading.Lock(), deque(maxlen=per), [0, 0, 0])
             for _ in range(max(1, stripes))
         ]
 
@@ -129,36 +155,53 @@ class SpanRecorder:
         return self._stripes[threading.get_ident() % len(self._stripes)]
 
     def record(self, span: Dict[str, Any]) -> None:
-        lock, ring, dropped = self._stripe()
+        lock, ring, counts = self._stripe()
         with lock:
             if len(ring) == ring.maxlen:
-                dropped[0] += 1
+                counts[0] += 1
             ring.append(span)
+            counts[1] += 1
+            if counts[1] - counts[2] >= self._half:
+                self.pressure.set()
 
     def snapshot(self) -> List[Dict[str, Any]]:
         out: List[Dict[str, Any]] = []
-        for lock, ring, _dropped in self._stripes:
+        for lock, ring, _counts in self._stripes:
             with lock:
                 out.extend(ring)
         out.sort(key=lambda s: s["ts"])
         return out
 
+    def drain(self) -> List[Dict[str, Any]]:
+        """The spans recorded since the last drain, oldest first. One
+        that the ring evicted before it was drained is lost (and
+        counted in ``dropped``)."""
+        out: List[Dict[str, Any]] = []
+        for lock, ring, counts in self._stripes:
+            with lock:
+                fresh = min(counts[1] - counts[2], len(ring))
+                counts[2] = counts[1]
+                if fresh:
+                    out.extend(itertools.islice(ring, len(ring) - fresh, None))
+        out.sort(key=lambda s: s["ts"])
+        return out
+
     def clear(self) -> None:
-        for lock, ring, dropped in self._stripes:
+        for lock, ring, counts in self._stripes:
             with lock:
                 ring.clear()
-                dropped[0] = 0
+                counts[:] = [0, 0, 0]
 
     @property
     def dropped(self) -> int:
         total = 0
-        for lock, _ring, dropped in self._stripes:
+        for lock, _ring, counts in self._stripes:
             with lock:
-                total += dropped[0]
+                total += counts[0]
         return total
 
     def __len__(self) -> int:
-        return sum(len(ring) for _l, ring, _d in self._stripes)
+        return sum(len(ring) for _l, ring, _c in self._stripes)
 
 
 # Process-wide recorder: every instrumented hop in this process records
@@ -232,6 +275,19 @@ def start_span(
     ``root=True`` and the sampling coin lands — otherwise the call is
     a no-op. Callers must ``end()`` the returned span.
     """
+    ctx = child_context(parent, root)
+    if ctx is None:
+        return None
+    return Span(name, cat, ctx, args, recorder or RECORDER)
+
+
+def child_context(
+    parent: Optional[TraceContext] = None, root: bool = False
+) -> Optional[TraceContext]:
+    """The context a span opened here would get, or None when tracing
+    is off or unsampled (``start_span``'s rules). For an interval the
+    phase timeline records anyway: ``record_phase(..., ctx=...)`` then
+    writes the ONE span both the timeline and the trace read."""
     s = _sample
     if s is None:
         s = _resolve_sample()
@@ -242,10 +298,8 @@ def start_span(
     if parent is None:
         if not root or not _sampled():
             return None
-        ctx = TraceContext(_new_id(), _new_id(), None)
-    else:
-        ctx = TraceContext(parent.trace_id, _new_id(), parent.span_id)
-    return Span(name, cat, ctx, args, recorder or RECORDER)
+        return TraceContext(_new_id(), _new_id(), None)
+    return TraceContext(parent.trace_id, _new_id(), parent.span_id)
 
 
 @contextlib.contextmanager
@@ -308,6 +362,38 @@ def record_event(
     )
 
 
+def record_phase(
+    name: str,
+    begin: float,
+    dur: float,
+    args: Optional[Dict[str, Any]] = None,
+    ctx: Optional[TraceContext] = None,
+    recorder: Optional[SpanRecorder] = None,
+) -> None:
+    """One span of the always-on phase timeline: recorded whatever
+    ``EDL_TRACE_SAMPLE`` says. ``begin`` is ``time.time()`` at entry.
+    With ``ctx`` (a sampled trace covers the interval) the span carries
+    the trace's ids; without, it has none."""
+    thread = threading.current_thread()
+    full = {"thread": thread.name}
+    if args:
+        full.update(args)
+    span = {
+        "name": name,
+        "cat": PHASE_CAT,
+        "ts": begin,
+        "dur": max(0.0, dur),
+        "pid": os.getpid(),
+        "tid": thread.ident,
+        "args": full,
+    }
+    if ctx is not None:
+        span["trace_id"] = ctx.trace_id
+        span["span_id"] = ctx.span_id
+        span["parent_id"] = ctx.parent_id
+    (recorder or RECORDER).record(span)
+
+
 def extract(req: Any) -> Optional[TraceContext]:
     """Pop the envelope from an unpacked request dict (server side).
 
@@ -328,13 +414,18 @@ def chrome_trace_from_spans(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Chrome trace-event JSON from recorder-shaped span dicts.
 
     Timestamps are wall-clock microseconds, so spans gathered from
-    several processes (GetTrace fan-out) align on one timeline."""
+    several processes (GetTrace fan-out, the processes' span files, a
+    device trace laid on the same clock) align on one timeline. A span
+    that names its thread (``args.thread``) or its process
+    (``process``) names that row."""
     events = []
+    named = {}
     for s in spans:
         args = dict(s.get("args") or {})
-        args["trace_id"] = s.get("trace_id")
-        args["span_id"] = s.get("span_id")
-        args["parent_id"] = s.get("parent_id")
+        for key in ("trace_id", "span_id", "parent_id"):
+            if s.get(key) is not None:
+                args[key] = s[key]
+        pid, tid = s.get("pid", 0), s.get("tid", 0)
         events.append(
             {
                 "name": s["name"],
@@ -342,12 +433,58 @@ def chrome_trace_from_spans(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
                 "ph": "X",
                 "ts": s["ts"] * 1e6,
                 "dur": s["dur"] * 1e6,
-                "pid": s.get("pid", 0),
-                "tid": s.get("tid", 0),
+                "pid": pid,
+                "tid": tid,
                 "args": args,
             }
         )
+        if s.get("process"):
+            named[(pid, None)] = s["process"]
+        if args.get("thread"):
+            named[(pid, tid)] = args["thread"]
+    for (pid, tid), name in named.items():
+        meta = {"ph": "M", "pid": pid, "args": {"name": name}}
+        if tid is None:
+            meta["name"] = "process_name"
+        else:
+            meta.update(name="thread_name", tid=tid)
+        events.append(meta)
     return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+def spans_from_device_trace(
+    xplane_path: str, asked: float, min_dur: float = 0.0
+) -> List[Dict[str, Any]]:
+    """The device planes of a ``jax.profiler`` trace as span dicts on
+    ``time.time()``'s clock: the trace's clock starts where
+    ``start_trace`` was called, at ``asked``. One row per line of each
+    ``/device:`` plane (``XLA Modules``: a program run; ``XLA Ops``:
+    its operations, which carry the ``jax.named_scope`` they ran
+    under)."""
+    from jax.profiler import ProfileData  # initialises no backend
+
+    spans = []
+    planes = ProfileData.from_file(xplane_path).planes
+    for pi, plane in enumerate(p for p in planes if "/device:" in p.name):
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                dur = e.duration_ns / 1e9
+                if dur < min_dur:
+                    continue
+                name = e.name.split(" = ", 1)[0].lstrip("%")[:120]
+                spans.append(
+                    {
+                        "name": name,
+                        "cat": "device",
+                        "ts": asked + e.start_ns / 1e9,
+                        "dur": dur,
+                        "pid": 1_000_000_000 + pi,
+                        "tid": li,
+                        "process": plane.name,
+                        "args": {"thread": line.name},
+                    }
+                )
+    return spans
 
 
 def chrome_trace(recorder: Optional[SpanRecorder] = None) -> Dict[str, Any]:
@@ -365,3 +502,87 @@ def dump_trace(
         json.dump(doc, f)
     os.replace(tmp, path)
     return path
+
+
+class SpanFile:
+    """The way out of a process that may be SIGKILLed: one daemon
+    thread drains the recorder every ``period_secs`` and appends each
+    span as a JSON line to ``path`` (line-buffered, so a line is whole
+    or absent). A relaunched process appends to the same file under
+    its new ``pid``."""
+
+    def __init__(
+        self,
+        path: str,
+        period_secs: float = 2.0,
+        recorder: Optional[SpanRecorder] = None,
+    ):
+        self.path = path
+        self._period = float(period_secs)
+        self._recorder = recorder or RECORDER
+        self._file = open(path, "a", buffering=1)
+        self._lock = threading.Lock()  # flush() from the thread and stop()
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._loop, daemon=True, name="edl-span-file"
+        )
+
+    def start(self) -> "SpanFile":
+        self._thread.start()
+        atexit.register(self.stop)
+        return self
+
+    def _loop(self):
+        pressure = self._recorder.pressure
+        while not self._stop.is_set():
+            pressure.wait(self._period)  # a burst does not wait a period
+            pressure.clear()
+            self.flush()
+
+    def flush(self) -> int:
+        with self._lock:
+            if self._file.closed:
+                return 0
+            spans = self._recorder.drain()
+            if spans:  # one write a drain: a kill cuts between drains
+                lines = [json.dumps(s, default=str) + "\n" for s in spans]
+                self._file.write("".join(lines))  # edl-lint: disable=lock-discipline -- str.join, no thread is waited for
+            return len(spans)
+
+    def stop(self):
+        self._stop.set()
+        self._recorder.pressure.set()
+        self.flush()
+        with self._lock:
+            self._file.close()
+
+
+def start_span_file(directory: str, basename: str) -> Optional[SpanFile]:
+    """``<directory>/<basename>.spans.jsonl``, flushed every
+    ``EDL_SCHED_PHASE_SECS`` (2 s by default, and where that knob turns
+    the phase stats off). With no directory nothing is written and the
+    ring is all there is."""
+    if not directory:
+        return None
+    try:
+        period = float(os.environ.get(ENV_SCHED_PHASE_SECS, "") or 2.0)
+    except ValueError:
+        period = 2.0
+    if period <= 0:
+        period = 2.0
+    os.makedirs(directory, exist_ok=True)
+    path = os.path.join(directory, f"{basename}.spans.jsonl")
+    return SpanFile(path, period).start()
+
+
+def load_span_file(path: str) -> List[Dict[str, Any]]:
+    """The spans of one ``.spans.jsonl`` file; a last line cut short by
+    a kill is skipped."""
+    spans = []
+    with open(path) as f:
+        for line in f:
+            try:
+                spans.append(json.loads(line))
+            except ValueError:
+                continue
+    return spans

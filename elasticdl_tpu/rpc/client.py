@@ -23,6 +23,7 @@ zero bytes).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Optional
 
 import grpc
@@ -48,7 +49,12 @@ class RpcClient:
         breaker: Optional[CircuitBreaker] = None,
         fault_plan: Optional[chaos.FaultPlan] = None,
         transport: Optional[str] = None,
+        timeline=(),
     ):
+        # the methods whose encode, round trip and decode this client's
+        # owner wants on its phase timeline (per update RPCs; the
+        # worker's master client names MASTER_UPDATE_METHODS)
+        self._timeline = frozenset(timeline)
         channel = grpc.insecure_channel(addr, options=GRPC_OPTIONS)
         plan = fault_plan if fault_plan is not None else chaos.FaultPlan.from_env()
         if plan is not None:
@@ -165,7 +171,22 @@ class RpcClient:
             if tspan is not None:
                 request = dict(request or {})
                 request[obs_trace.ENVELOPE_KEY] = tspan.envelope()
+        # a method the owner put on its phase timeline is there always:
+        # encode, the round trip as this side sees it, decode. Each
+        # interval is ONE span; in a sampled trace the three carry its
+        # ids (the round trip is the trace's client span, the other two
+        # its children), so the trace still accounts for the pack
+        timeline = method in self._timeline
+        tctx = tspan.ctx if tspan is not None else None
+        t_pack = time.time() if timeline else 0.0
         payload = messages.pack(request if request is not None else {})
+        t_sent = time.time() if timeline else 0.0
+        if timeline:
+            obs_trace.record_phase(
+                "rpc.client.encode", t_pack, t_sent - t_pack,
+                {"method": method, "bytes": len(payload)},
+                ctx=obs_trace.child_context(tctx),
+            )
 
         transport = self._transport
 
@@ -190,6 +211,8 @@ class RpcClient:
             self.wire.record(method, received=len(resp_bytes))
             return resp_bytes
 
+        tier = transport.name if transport else "grpc"
+        settled = False
         try:
             resp = self._policy.call(
                 attempt,
@@ -198,12 +221,40 @@ class RpcClient:
                 idempotent=idempotent,
                 breaker=self._breaker,
             )
+            settled = True
         finally:
-            if tspan is not None:
-                tspan.end(
-                    transport=transport.name if transport else "grpc"
+            if not timeline:
+                if tspan is not None:
+                    tspan.end(transport=tier)
+            elif not settled:  # the call raised: no response to date it by
+                obs_trace.record_phase(
+                    f"rpc.client.{method}", t_sent, time.time() - t_sent,
+                    {"bytes": len(payload), "transport": tier, "failed": True},
+                    ctx=tctx,
                 )
-        return messages.unpack(resp)
+        if not timeline:
+            return messages.unpack(resp)
+        t_recv = time.time()
+        out = messages.unpack(resp)
+        t_done = time.time()
+        # the round trip as this side sees it, under the version the
+        # response names: what joins it to the master's spans of the
+        # same update
+        obs_trace.record_phase(
+            f"rpc.client.{method}", t_sent, t_recv - t_sent,
+            {
+                "bytes": len(payload),
+                "transport": tier,
+                "version": out.get("version") if isinstance(out, dict) else None,
+            },
+            ctx=tctx,
+        )
+        obs_trace.record_phase(
+            "rpc.client.decode", t_recv, t_done - t_recv,
+            {"method": method, "bytes": len(resp)},
+            ctx=obs_trace.child_context(tctx),
+        )
+        return out
 
     def close(self):
         self._channel.close()

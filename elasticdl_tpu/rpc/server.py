@@ -61,6 +61,8 @@ class RpcServer:
         fault_plan=None,
         shm_scope: Optional[str] = None,
         shm_generation: int = 0,
+        timers=None,
+        timed_methods=(),
     ):
         # server-side wire-byte accounting (payload bytes per method);
         # surfaced via `wire_stats()` and shard `stats()` RPCs
@@ -75,7 +77,8 @@ class RpcServer:
 
         plan = fault_plan if fault_plan is not None else chaos.FaultPlan.from_env()
         self._dispatcher = transport_mod.ServerDispatcher(
-            handlers, self.wire, fault_plan=plan
+            handlers, self.wire, fault_plan=plan, timers=timers,
+            timed_methods=timed_methods,
         )
         method_handlers = {
             name: grpc.unary_unary_rpc_method_handler(

@@ -256,9 +256,16 @@ class ServerDispatcher:
         wire,
         fault_plan=None,
         mode: Optional[str] = None,
+        timers=None,
+        timed_methods=(),
     ):
         self._handlers = dict(handlers)
         self._wire = wire
+        # the owner's PhaseTimers and the methods it names (the master:
+        # its update and model RPCs): their request decode and response
+        # encode are the owner's phases
+        self._timers = timers
+        self._timed_methods = frozenset(timed_methods) if timers else ()
         self._plan = fault_plan
         self._mode = dispatch_mod.dispatch_mode() if mode is None else mode
         self._admission = None
@@ -363,7 +370,14 @@ class ServerDispatcher:
         self._wire.record(
             method, received=0 if inproc else nbytes, transport=transport
         )
+        # a timed method's decode and encode are spans of the owner's
+        # timeline, recorded once the handler has answered: they carry
+        # the version its response names, which joins them to the
+        # handler's own spans and to the client's round trip
+        timed = method in self._timed_methods
+        t_decode = time.time() if timed else 0.0
         req = messages.unpack(request_bytes) if request_bytes else None
+        t_decoded = time.time() if timed else 0.0
         # trace envelope: always popped (handlers never see the key);
         # a context materializes only when the sender sampled this
         # request AND this process has tracing on
@@ -429,6 +443,19 @@ class ServerDispatcher:
             # resolved frame length they actually consumed).
             resp_bytes = _ShmBcastMarkerBytes(
                 codec.dumps({_SHM_BCAST_KEY: dict(resp.shm_ref)})
+            )
+        elif timed:
+            version = resp.get("version") if isinstance(resp, dict) else None
+            t_encode = time.time()
+            resp_bytes = messages.pack(resp)
+            record = self._timers.record
+            record(
+                "rpc.decode", t_decode, t_decoded, method=method,
+                bytes=nbytes, version=version,
+            )
+            record(
+                "rpc.encode", t_encode, time.time(), method=method,
+                bytes=len(resp_bytes), version=version,
             )
         else:
             resp_bytes = messages.pack(resp)

@@ -17,15 +17,21 @@ which time the master may be back.
 
 from __future__ import annotations
 
+import os
 import sys
+import time
 
 from elasticdl_tpu.api.model_spec import get_model_spec
 from elasticdl_tpu.common.args import worker_parser
 from elasticdl_tpu.common.constants import (
+    ENV_WORKER_LOG_DIR,
     EXIT_CODE_JOB_FAILED,
     EXIT_CODE_MASTER_UNREACHABLE,
+    MASTER_UPDATE_METHODS,
 )
 from elasticdl_tpu.common.log_util import get_logger
+from elasticdl_tpu.common.timing import process_start_time
+from elasticdl_tpu.obs import trace as obs_trace
 
 logger = get_logger(__name__)
 
@@ -130,9 +136,16 @@ def main(argv=None) -> int:
     args = worker_parser().parse_args(argv)
 
     import logging
-    import os
 
     logging.getLogger().setLevel(args.log_level.upper())
+    # the phase timeline's way out of a process that ends by SIGKILL:
+    # <EDL_WORKER_LOG_DIR>/worker-<id>.spans.jsonl; a relaunch appends
+    obs_trace.start_span_file(
+        os.environ.get(ENV_WORKER_LOG_DIR, ""), f"worker-{args.worker_id}"
+    )
+    started = process_start_time()
+    t_backend = time.time()
+    obs_trace.record_phase("setup.imports", started, t_backend - started)
 
     # the CPU is a device only when it was asked for: a worker that
     # finds no chip exits here instead of training on the CPU in silence
@@ -140,6 +153,10 @@ def main(argv=None) -> int:
     from elasticdl_tpu.parallel.mesh import local_mesh
 
     device = require_device(f"worker {args.worker_id}")
+    obs_trace.record_phase(
+        "setup.backend_init", t_backend, time.time() - t_backend,
+        {"platform": device["platform"]},
+    )
     logger.info(
         "Worker %d boot: platform=%s device_kind=%s chips=%s",
         args.worker_id,
@@ -176,7 +193,8 @@ def main(argv=None) -> int:
         for a in getattr(args, "master_candidates", "").split(",")
         if a.strip()
     ] or None
-    client = RpcClient(args.master_addr)
+    # the master's update and model RPCs, both sides on the timeline
+    client = RpcClient(args.master_addr, timeline=MASTER_UPDATE_METHODS)
     try:
         ps_cfg = _boot_handshake(client, args.master_addr, candidates)
     except Exception as e:

@@ -73,6 +73,24 @@ logger = get_logger(__name__)
 
 _BF16 = np.dtype(ml_dtypes.bfloat16)
 
+# what jax itself says of the persistent compile cache, counted so that
+# a program's first call can say whether the cache served it
+_CACHE_EVENTS = {"requests": 0, "hits": 0}
+_CACHE_EVENT_KEYS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+}
+
+
+def _on_jax_event(event, **_kwargs):
+    key = _CACHE_EVENT_KEYS.get(event)
+    if key is not None:
+        _CACHE_EVENTS[key] += 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
+_NO_SPAN = contextlib.nullcontext()
+
 
 def validate_eval_metrics(raw: dict):
     """Only dicts that ARE mergeable states (api/metrics.py) may ride
@@ -136,6 +154,11 @@ class Worker:
             "_spawn_abs",
         ),
     }
+    # phase-timeline state with class defaults: a skeleton built with
+    # `Worker.__new__` needs only `timers`
+    _programs_called = None  # jitted programs whose first call is past
+    _first_run = None  # deque: the first window's (time.time(), mode)
+    _first_run_begun = False  # step loop only
 
     def __init__(
         self,
@@ -457,7 +480,11 @@ class Worker:
         # per-phase wall-clock mirroring the reference's timing study
         # (doc/worker_optimization_design.md:33-60): get_batch /
         # compute / get_model / report_gradient / sync_wait / read
-        self.timers = PhaseTimers()
+        # Closing a phase also records a span on the process's phase
+        # timeline (docs/observability.md): the sink is handed down
+        # here, common/timing.py knows nothing of obs/.
+        self.timers = PhaseTimers(sink=obs_trace.record_phase)
+        self._first_run = deque(maxlen=1)
         # policy-plane telemetry: the run loop ships cumulative timer
         # snapshots to the master every N seconds (ReportPhaseStats —
         # the autoscaler's signal; 0 disables). Failure-tolerant: a
@@ -698,12 +725,14 @@ class Worker:
 
     def pull_model(self, min_version: int = -1, method: str = MethodType.MINIMUM):
         """reference: worker.py:103-124 (var assign becomes pytree swap)."""
-        with obs_trace.span(
-            "worker.pull",
-            cat="worker",
-            root=True,
-            args={"worker": self._id},
-        ):
+        # a worker's first contact with the model is part of set-up
+        first = self._flat is None and self._params is None
+        setup = (
+            self.timers.span("setup.model_init", how="pull")
+            if first
+            else _NO_SPAN
+        )
+        with self._chain_span("worker.pull", root=True), setup:
             return self._pull_model_traced(min_version, method)
 
     def _pull_model_traced(
@@ -847,11 +876,15 @@ class Worker:
             # quantize ON DEVICE before the d2h round: shrinks the
             # device-link bytes too, and the EF residual stays resident
             wire_meta, grads = self._ef_quantize_grad(grads)
-        grads_h, aux_h, loss_h = jax.device_get(
-            (grads, aux_state or None, loss)
-        )
+        fetch = (grads, aux_state or None, loss)
+        with self.timers.span("worker.delta_wait"):
+            jax.block_until_ready(fetch)  # the step itself
+        self._first_run_settled()
+        with self.timers.span("worker.d2h"):
+            grads_h, aux_h, loss_h = jax.device_get(fetch)
         if wire_meta is not None:
-            grads_h = self._materialize_wire_delta(wire_meta, grads_h)
+            with self._chain_span("worker.encode"):
+                grads_h = self._materialize_wire_delta(wire_meta, grads_h)
         if version is None:
             with self._report_lock:
                 version = self._version
@@ -1335,7 +1368,8 @@ class Worker:
         def run(params, aux, batch_embs: Dict[str, BatchEmbedding], features, labels):
             bets = {k: b.bet for k, b in batch_embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in batch_embs.items()}
-            return jitted(params, aux, bets, bet_aux, features, labels)
+            with self._first_call(jitted):
+                return jitted(params, aux, bets, bet_aux, features, labels)
 
         return run
 
@@ -1380,7 +1414,8 @@ class Worker:
         def run(params, aux, batch_embs, features, labels):
             bets = {k: b.bet for k, b in batch_embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in batch_embs.items()}
-            return jitted(params, aux, bets, bet_aux, features, labels)
+            with self._first_call(jitted):
+                return jitted(params, aux, bets, bet_aux, features, labels)
 
         return run
 
@@ -1556,7 +1591,8 @@ class Worker:
             with self.timers.phase("rebase"):
                 tx = self._spec.optimizer()
                 self._opt_state = tx.init(self._flat)
-                self._base_flat = jnp.copy(self._flat)
+                with self._first_call("jit_copy"):
+                    self._base_flat = jnp.copy(self._flat)
                 with self._report_lock:
                     self._base_version = self._version
 
@@ -1569,29 +1605,35 @@ class Worker:
                 embs = self._prepare_embeddings(features)
             bets = {k: b.bet for k, b in embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in embs.items()}
-            (
-                self._flat,
-                self._opt_state,
-                new_aux,
-                loss,
-                gbets,
-            ) = self._local_step_fn(
-                self._flat,
-                self._opt_state,
-                self._aux,
-                bets,
-                bet_aux,
-                features,
-                labels,
-            )
+            with self._first_call(self._local_step_fn):
+                (
+                    self._flat,
+                    self._opt_state,
+                    new_aux,
+                    loss,
+                    gbets,
+                ) = self._local_step_fn(
+                    self._flat,
+                    self._opt_state,
+                    self._aux,
+                    bets,
+                    bet_aux,
+                    features,
+                    labels,
+                )
             # device refs only; the d2h rides the window sync's batch
             self._pending_edl.append((embs, gbets))
         else:
             if self._local_step_fn is None:
                 self._local_step_fn = self._build_local_step()
-            self._flat, self._opt_state, new_aux, loss = self._local_step_fn(
-                self._flat, self._opt_state, self._aux, features, labels
-            )
+            self._first_run_begins("step")
+            with self._first_call(self._local_step_fn):
+                self._flat, self._opt_state, new_aux, loss = (
+                    self._local_step_fn(
+                        self._flat, self._opt_state, self._aux, features,
+                        labels,
+                    )
+                )
         self._aux = new_aux or self._aux
         self._pending_steps += 1
         self._latest_step_loss = loss
@@ -1662,9 +1704,11 @@ class Worker:
         self._ensure_local_ready(first, task)
         if self._local_window_fn is None:
             self._local_window_fn = self._build_local_window_fn()
-        self._flat, self._opt_state, new_aux, loss = self._local_window_fn(
-            self._flat, self._opt_state, self._aux, features, labels
-        )
+        self._first_run_begins("window")
+        with self._first_call(self._local_window_fn):
+            self._flat, self._opt_state, new_aux, loss = self._local_window_fn(
+                self._flat, self._opt_state, self._aux, features, labels
+            )
         self._aux = new_aux or self._aux
         self._pending_steps += self._local_updates
         self._latest_step_loss = loss
@@ -1719,7 +1763,7 @@ class Worker:
                 with self.timers.phase("get_batch"):
                     nxt = next(batches, None)
                 nxt_fut = fetch(nxt)  # in flight during N's compute
-                with self.timers.phase("compute"):
+                with self.timers.phase("compute", steps=1):
                     loss = self._local_minibatch(
                         batch[0],
                         batch[1],
@@ -1739,7 +1783,7 @@ class Worker:
             else:
                 buf.append(batch)
             if buf and (done or len(buf) == W):
-                with self.timers.phase("compute"):
+                with self.timers.phase("compute", steps=len(buf)):
                     n0 = len(jax.tree_util.tree_leaves(buf[0][0])[0])
                     uniform = all(
                         len(jax.tree_util.tree_leaves(f)[0]) == n0
@@ -1789,7 +1833,10 @@ class Worker:
             # their sync's own flush)
             self._flush_deferred_reports()
             return
-        delta_dev = self._flat - self._base_flat  # own buffer, thread-safe
+        t_spawn = time.time()  # `worker.window_sync` starts here
+        with self._first_call("jit_subtract"):
+            # own buffer, thread-safe
+            delta_dev = self._flat - self._base_flat
         wire_meta = None
         wire_form = None
         link_mbps = None
@@ -1815,23 +1862,14 @@ class Worker:
         # sync chain (encode / push RPCs / apply) all hang off this
         # root; it ends when do_sync settles, so its duration IS the
         # window's sync latency
-        wspan = obs_trace.start_span(
-            "worker.window_sync",
-            cat="worker",
-            root=True,
-            args=wspan_args,
-        )
+        wctx = obs_trace.child_context(root=True)
         if self._lossy_sync():
             # EF compression at spawn time, still on the main thread:
             # chained syncs spawn in dispatch order, so each window
             # consumes the residual its predecessor left — the wire
             # carries bf16/int8/top-k but the SUM of what the PS
             # applies tracks the f32 trajectory (see _ef_quantize_delta)
-            with obs_trace.span(
-                "worker.quantize",
-                cat="worker",
-                parent=wspan.ctx if wspan is not None else None,
-            ):
+            with self._chain_span("worker.quantize", parent=wctx):
                 wire_meta, delta_dev = self._ef_quantize_delta(
                     delta_dev, form=wire_form
                 )
@@ -1867,7 +1905,8 @@ class Worker:
         # sink attributed to the version this delta produces (task-end
         # losses in `losses` can belong to earlier windows)
         step_loss = self._latest_step_loss
-        self._base_flat = jnp.copy(self._flat)
+        with self._first_call("jit_copy"):
+            self._base_flat = jnp.copy(self._flat)
         self._pending_steps = 0
         prev = self._sync_thread
         with self._report_lock:
@@ -1900,19 +1939,23 @@ class Worker:
         def do_sync():
             # bind the window's root context so every hop below (client
             # RPC spans, server-side children) chains under it
-            prev_ctx = (
-                obs_trace.bind(wspan.ctx) if wspan is not None else None
-            )
+            prev_ctx = obs_trace.bind(wctx) if wctx is not None else None
             try:
                 do_sync_work()
             finally:
-                if wspan is not None:
+                # spawn (step loop) to settled (this thread): ONE span,
+                # the timeline's and, when sampled, the trace's root
+                self.timers.record_span(
+                    "worker.window_sync", t_spawn, time.time(), ctx=wctx,
+                    steps=steps, bytes=delta_f32_bytes, **wspan_args,
+                )
+                if wctx is not None:
                     obs_trace.bind(prev_ctx)
-                    wspan.end(steps=steps)
 
         def do_sync_work():
             if prev is not None:
-                prev.join()
+                with self.timers.span("worker.chain_wait"):
+                    prev.join()
             with self._report_lock:
                 if self._sync_error is not None or epoch != self._sync_epoch:
                     # chain broken (a predecessor failed) or the main
@@ -1924,21 +1967,26 @@ class Worker:
             # grads + the window's task losses — per-item np.asarray
             # would cost a full round-trip each over a high-latency
             # host<->TPU link.
-            with obs_trace.span("worker.encode", cat="worker"):
+            fetch = (
+                delta_dev,
+                aux_dev or None,
+                [l for _, l in losses],
+                step_loss,
+                [g for _, g in pending_edl],
+            )
+            with self._chain_span("worker.delta_wait"):
+                # the device finishes the window and the delta; what
+                # follows is the copy out alone
+                jax.block_until_ready(fetch)
+            self._first_run_settled()
+            with self._chain_span("worker.d2h", bytes=delta_f32_bytes):
                 delta_h, aux_h, loss_h, step_loss_h, gbets_h = (
-                    jax.device_get(
-                        (
-                            delta_dev,
-                            aux_dev or None,
-                            [l for _, l in losses],
-                            step_loss,
-                            [g for _, g in pending_edl],
-                        )
-                    )
+                    jax.device_get(fetch)
                 )
-                if wire_meta is not None:
-                    # compressed payload: build the codec wire object
-                    # from the host copies (device math ran at spawn)
+            if wire_meta is not None:
+                # compressed payload: build the codec wire object
+                # from the host copies (device math ran at spawn)
+                with self._chain_span("worker.encode"):
                     delta_h = self._materialize_wire_delta(
                         wire_meta, delta_h
                     )
@@ -2089,8 +2137,12 @@ class Worker:
                     if k <= seq and k != pending:
                         del self._base_snapshots[k]
             self._record_synced_losses(losses, loss_h, resp["version"])
-            self._flush_deferred_reports()
+            with self.timers.span("worker.flush_reports"):
+                self._flush_deferred_reports()
 
+        # the step loop's own part of the sync: the delta and the new
+        # base dispatched, the quantize, the bookkeeping above
+        self.timers.record_span("worker.sync_spawn", t_spawn, time.time())
         if blocking:
             try:
                 with self._sync_exposed("flush"):
@@ -2445,12 +2497,7 @@ class Worker:
         # edl-lint: disable=lock-discipline -- racy read is deliberate; _absorb_sync_result_traced re-reads under _report_lock
         if self._sync_result is None:
             return
-        with obs_trace.span(
-            "worker.absorb",
-            cat="worker",
-            root=True,
-            args={"worker": self._id},
-        ):
+        with self._chain_span("worker.absorb", root=True):
             self._absorb_sync_result_traced()
 
     def _absorb_sync_result_traced(self):
@@ -2521,6 +2568,76 @@ class Worker:
     # ------------------------------------------------------- overlap plane
 
     @contextlib.contextmanager
+    def _chain_span(self, name: str, parent=None, root=False, **args):
+        """One boundary of the sync plane: always ONE span of the phase
+        timeline (`PhaseTimers.span`: no exclusive seconds, these run
+        off the step loop or inside one of its phases). When
+        `EDL_TRACE_SAMPLE` samples it, that same span carries the
+        trace's ids and is the thread's current context while open, so
+        the hops below chain under it. Yields the span's `args`."""
+        ctx = obs_trace.child_context(parent, root)
+        if ctx is None:
+            with self.timers.span(name, **args) as info:
+                yield info
+            return
+        prev = obs_trace.bind(ctx)
+        try:
+            with self.timers.span(
+                name, ctx=ctx, worker=self._id, **args
+            ) as info:
+                yield info
+        finally:
+            obs_trace.bind(prev)
+
+    def _first_call(self, program):
+        """`setup.program` around the FIRST call of a jitted program
+        (trace, lower, compile or load from the compile cache,
+        dispatch), at its call site: `program` is the jitted callable
+        or, for an eager op, the name jax gives its program. Later
+        calls get a shared null context."""
+        called = self._programs_called
+        if called is None:
+            called = self._programs_called = set()
+        key = program if isinstance(program, str) else id(program)
+        if key in called:
+            return _NO_SPAN
+        called.add(key)
+        if not isinstance(program, str):
+            program = "jit_" + getattr(program, "__name__", "unnamed")
+        return self._program_span(program)
+
+    @contextlib.contextmanager
+    def _program_span(self, program: str):
+        requests, hits = _CACHE_EVENTS["requests"], _CACHE_EVENTS["hits"]
+        with self.timers.span("setup.program", program=program) as info:
+            yield
+            compiled = _CACHE_EVENTS["requests"] - requests
+            info["compiles"] = compiled
+            if compiled:  # every one of them served by the cache
+                info["cache_hit"] = _CACHE_EVENTS["hits"] - hits == compiled
+
+    def _first_run_begins(self, mode: str):
+        if not self._first_run_begun and self._first_run is not None:
+            self._first_run_begun = True
+            self._first_run.append((time.time(), mode))
+
+    def _first_run_settled(self):
+        """`setup.first_window`: the first window (per step: the first
+        step) from its call to the device being done with it. Closed by
+        the thread that has just waited for the device anyway
+        (`worker.delta_wait`), never by a wait of the step loop's own.
+        Once a process; later calls pay one compare."""
+        if not self._first_run:
+            return
+        try:
+            t_call, mode = self._first_run.popleft()  # one thread gets it
+        except IndexError:
+            return
+        self.timers.record_span(
+            "setup.first_window", t_call, time.time(), mode=mode
+        )
+
+    @contextlib.contextmanager
     def _sync_exposed(self, reason: str):
         """Span-mark wall time the STEP LOOP is blocked on the sync
         plane (joins, blocking pulls, backpressure, drains). These are
@@ -2528,17 +2645,8 @@ class Worker:
         (obs/critical_path.py) can sum exactly the sync wall that
         stayed ON the critical path — the quantity the overlap plane
         exists to shrink, and the bench A/B's acceptance metric."""
-        sp = obs_trace.start_span(
-            "worker.sync_exposed",
-            cat="worker",
-            root=True,
-            args={"worker": self._id, "reason": reason},
-        )
-        try:
+        with self._chain_span("worker.sync_exposed", root=True, reason=reason):
             yield
-        finally:
-            if sp is not None:
-                sp.end()
 
     def _join_bg_pull(self):
         """Settle an in-flight background model pull (main thread)."""
@@ -2588,61 +2696,51 @@ class Worker:
         and version bookkeeping belong to the main thread. Best-effort:
         a failure here costs nothing (the step loop's blocking pull
         still exists), so errors log and drop."""
-        sp = obs_trace.start_span(
-            "worker.bg_pull",
-            cat="worker",
-            root=True,
-            args={"worker": self._id},
-        )
-        prev_ctx = obs_trace.bind(sp.ctx) if sp is not None else None
-        try:
-            staged = None
-            if ps is not None:
-                # non-blocking shard fan-out (ps_client.pull_async);
-                # the aux RPC to the master rides alongside it
-                fut = ps.pull_async(
-                    versions=known_versions,
-                    model_dtype=self._model_wire_dtype(),
-                )
-                aux = None
-                if want_aux:
-                    aux = self._call_master("GetAux", {}).get("aux")
-                versions, vec = fut.result()
-                if all(v >= 0 for v in versions) and vec is not None:
-                    staged = (list(versions), min(versions), vec, aux)
-            else:
-                req = {
-                    "version": cur_version,
-                    "method": MethodType.MINIMUM,
-                    "only_if_newer": True,
-                    "flat": True,
-                }
-                resp = self._call_master("GetModel", req)
-                if (
-                    resp.get("version", -1) >= 0
-                    and resp.get("params_flat") is not None
-                ):
-                    staged = (
-                        None,
-                        resp["version"],
-                        resp["params_flat"],
-                        resp.get("aux"),
+        with self._chain_span("worker.bg_pull", root=True):
+            try:
+                staged = None
+                if ps is not None:
+                    # non-blocking shard fan-out (ps_client.pull_async);
+                    # the aux RPC to the master rides alongside it
+                    fut = ps.pull_async(
+                        versions=known_versions,
+                        model_dtype=self._model_wire_dtype(),
                     )
-            if staged is not None:
-                with self._report_lock:
-                    if epoch == self._sync_epoch and staged[1] > self._version:
-                        self._absorb_staged = staged
-        except Exception as e:
-            logger.debug(
-                "worker %d background model pull failed (benign; the "
-                "step loop's blocking pull remains): %s",
-                self._id,
-                e,
-            )
-        finally:
-            if sp is not None:
-                obs_trace.bind(prev_ctx)
-                sp.end()
+                    aux = None
+                    if want_aux:
+                        aux = self._call_master("GetAux", {}).get("aux")
+                    versions, vec = fut.result()
+                    if all(v >= 0 for v in versions) and vec is not None:
+                        staged = (list(versions), min(versions), vec, aux)
+                else:
+                    req = {
+                        "version": cur_version,
+                        "method": MethodType.MINIMUM,
+                        "only_if_newer": True,
+                        "flat": True,
+                    }
+                    resp = self._call_master("GetModel", req)
+                    if (
+                        resp.get("version", -1) >= 0
+                        and resp.get("params_flat") is not None
+                    ):
+                        staged = (
+                            None,
+                            resp["version"],
+                            resp["params_flat"],
+                            resp.get("aux"),
+                        )
+                if staged is not None:
+                    with self._report_lock:
+                        if epoch == self._sync_epoch and staged[1] > self._version:
+                            self._absorb_staged = staged
+            except Exception as e:
+                logger.debug(
+                    "worker %d background model pull failed (benign; the "
+                    "step loop's blocking pull remains): %s",
+                    self._id,
+                    e,
+                )
 
     def _apply_staged_model(self) -> bool:
         """Fold a background-pulled model in at a window boundary (main
@@ -2659,12 +2757,7 @@ class Worker:
         t = self._sync_thread
         if t is not None and t.is_alive():
             return False  # chain busy: fold at a later boundary
-        with obs_trace.span(
-            "worker.absorb_staged",
-            cat="worker",
-            root=True,
-            args={"worker": self._id},
-        ):
+        with self._chain_span("worker.absorb_staged", root=True):
             return self._apply_staged_model_traced()
 
     def _apply_staged_model_traced(self) -> bool:
@@ -2758,9 +2851,12 @@ class Worker:
             init_embs = self._dev_embedding_inputs(
                 self._prepare_embeddings(features)
             )
-        self._init_model(features, init_embs)
-        self.report_variable()
-        self.pull_model()
+        with self.timers.span("setup.model_init", how="init"):
+            self._init_model(features, init_embs)
+        with self.timers.span("setup.model_init", how="report"):
+            self.report_variable()
+        with self.timers.span("setup.model_init", how="pull"):
+            self.pull_model()
 
     def _ensure_step_ready(self, features, task: Task):
         """Shared per-step preamble: model freshness (pull or lazy
@@ -2789,6 +2885,7 @@ class Worker:
             step = self._train_step
             if not self._divisible(features):
                 step = self._ragged_train_step()
+            self._first_run_begins("step")
             loss, gparams, gbets, new_aux = step(
                 self._step_params(), self._aux, embs, features, labels
             )
@@ -2847,6 +2944,7 @@ class Worker:
         step = self._train_step
         if not self._divisible(features):
             step = self._ragged_train_step()
+        self._first_run_begins("step")
         loss, gparams, _gbets, new_aux = step(
             self._step_params(), self._aux, embs, features, labels
         )
@@ -2930,7 +3028,8 @@ class Worker:
                 and self._use_flat()
                 and v > self._version
             ):
-                self._set_flat(resp["params_flat"], resp.get("aux"))
+                with self.timers.span("worker.absorb"):  # host to device
+                    self._set_flat(resp["params_flat"], resp.get("aux"))
                 self._version = v
                 self._fresh = True
             elif v == self._version:
@@ -3000,7 +3099,7 @@ class Worker:
                     break
                 features, labels = batch
                 batches_ran += 1
-                with self.timers.phase("compute"):
+                with self.timers.phase("compute", steps=1):
                     if self._local_updates:
                         loss = self._local_minibatch(features, labels, task)
                     elif self._step_pipeline_on():

@@ -389,3 +389,20 @@ def test_tracing_off_is_near_free():
     assert start_cost < 5e-6, f"start_span off-path {start_cost * 1e6:.2f}us"
     assert cm_cost < 10e-6, f"span() off-path {cm_cost * 1e6:.2f}us"
     assert ev_cost < 5e-6, f"record_event off-path {ev_cost * 1e6:.2f}us"
+
+    # the phase timeline is on whatever the sample rate, so a phase
+    # with a sink is on the hot path: one time.time(), one striped
+    # append. A regression that adds I/O or a global lock lands far
+    # above this (loose) bound.
+    from elasticdl_tpu.common.timing import PhaseTimers
+
+    timers = PhaseTimers(sink=trace.record_phase)
+    n = 20_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with timers.phase("x"):
+            pass
+    phase_cost = (time.perf_counter() - t0) / n
+    assert phase_cost < 50e-6, f"phase() with a sink {phase_cost * 1e6:.1f}us"
+    assert len(trace.RECORDER) <= trace._DEFAULT_CAPACITY  # still bounded
+    trace.RECORDER.clear()

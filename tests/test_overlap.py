@@ -23,6 +23,7 @@ import threading
 import numpy as np
 import pytest
 
+from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.common import messages
 from elasticdl_tpu.master.ps_optimizer import PSOptimizer
 from elasticdl_tpu.master.servicer import MasterServicer
@@ -201,6 +202,7 @@ def _staged_worker():
     """Worker skeleton with exactly the overlap-plane state
     (mirrors test_sync_pipeline._bare_worker)."""
     w = Worker.__new__(Worker)
+    w.timers = PhaseTimers()
     w._report_lock = threading.Lock()
     w._overlap_sync = True
     w._absorb_staged = None

@@ -12,6 +12,7 @@ import os
 import numpy as np
 import pytest
 
+from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.api.model_spec_helpers import spec_from_module
 from elasticdl_tpu.common import codec
 from elasticdl_tpu.master.ps_group import PSShardGroup
@@ -725,6 +726,7 @@ def test_reset_local_state_clears_shard_versions():
     import threading
 
     w = Worker.__new__(Worker)
+    w.timers = PhaseTimers()
     w._report_lock = threading.Lock()
     w._sync_epoch = 0
     w._fresh = True

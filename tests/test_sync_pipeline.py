@@ -23,6 +23,7 @@ import threading
 import jax.numpy as jnp
 import numpy as np
 
+from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.worker.worker import Worker
 
 
@@ -30,6 +31,7 @@ def _bare_worker():
     """A Worker skeleton with just the sync-pipeline state (no master,
     no model): exactly the fields the pipeline methods touch."""
     w = Worker.__new__(Worker)
+    w.timers = PhaseTimers()
     w._report_lock = threading.Lock()
     w._base_snapshots = {}
     w._sync_result = None

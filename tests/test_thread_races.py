@@ -11,6 +11,7 @@ import time
 
 import pytest
 
+from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.agg.aggregator import AggregatorServicer
 from elasticdl_tpu.chaos.scenario import JobRun
 from elasticdl_tpu.cluster.pod_backend import ProcessBackend
@@ -137,6 +138,7 @@ def test_kv_mirror_counters_account_every_forward(monkeypatch):
 
 def _bare_worker():
     w = Worker.__new__(Worker)
+    w.timers = PhaseTimers()
     w._report_lock = threading.Lock()
     w._sync_error = None
     w._flushed = []
