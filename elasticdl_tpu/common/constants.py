@@ -188,19 +188,25 @@ ENV_REGISTRY = {
         "CLI --sync_bucket_bytes; sharded-PS route only)"
     ),
     ENV_TRANSPORT: (
-        "RPC transport tier: grpc (default), uds (Unix-domain-socket "
-        "fast path to co-located shards), shm (shared-memory rings "
-        "with a UDS doorbell — codec frames never cross a socket), "
-        "inproc (same-interpreter direct dispatch), or auto (prefer "
-        "inproc, then shm, then uds, then grpc); non-grpc tiers apply "
-        "when the endpoint resolves local, else fall back to grpc "
-        "(rpc/transport.py)"
+        "RPC transport tier override. Unset (the default) means uds: "
+        "every RpcServer opens a Unix-domain-socket listener beside "
+        "gRPC and a client whose endpoint resolves to this host, with "
+        "that socket file present, is carried by it; a remote endpoint "
+        "gets gRPC. Explicit values: grpc (pure gRPC, no listener), uds, "
+        "shm (shared-memory rings with a UDS doorbell — codec frames "
+        "never cross a socket), inproc (same-interpreter direct "
+        "dispatch), or auto (prefer inproc, then shm, then uds, then "
+        "grpc); every non-grpc tier applies only to a local endpoint "
+        "whose counterpart is there, and one that cannot connect hands "
+        "the call to gRPC (rpc/transport.py)"
     ),
     ENV_UDS_DIR: (
-        "directory for the UDS fast-path sockets (edl-uds-<port>.sock) "
-        "and the shm tier's doorbell sockets + rendezvous files "
-        "(edl-shm-<port>.{sock,json}); default: the system temp dir — "
-        "must be shared by co-located processes"
+        "directory for the Unix-socket carrier's sockets "
+        "(edl-uds-<port>.sock, one per RpcServer; a boot sweeps those "
+        "of dead servers) and the shm tier's doorbell sockets + "
+        "rendezvous files (edl-shm-<port>.{sock,json}); default: the "
+        "system temp dir — must be shared by co-located processes; may "
+        "be deeper than an AF_UNIX address holds"
     ),
     ENV_TRANSPORT_SHM_RING: (
         "shm tier: per-direction ring capacity in bytes for each "

@@ -10,14 +10,19 @@ process's chaos interceptors when `EDL_CHAOS_SPEC` is set — so fault
 injection exercises exactly the production path (see rpc/chaos.py,
 docs/fault_model.md).
 
-When `EDL_TRANSPORT` enables a fast path and the endpoint resolves
-co-located, the attempt routes the packed codec frame over the selected
-tier (in-process dispatch or a Unix-domain socket, rpc/transport.py)
-INSIDE the same policy/breaker envelope, with the same FaultPlan
-applied by the transport — tier selection changes how bytes move, never
-the failure semantics. WireStats rows carry the tier so bytes-per-sync
-distinguishes wire bytes from co-located ones (inproc counts calls but
-zero bytes).
+A local peer gets a local carrier: when the endpoint resolves to this
+host and its listener is there (with `EDL_TRANSPORT` unset: the
+server's Unix-socket file; the variable overrides, rpc/transport.py),
+the attempt routes the packed codec frame over that tier INSIDE the
+same policy/breaker envelope, with the same FaultPlan applied by the
+transport — tier selection changes how bytes move, never the failure
+semantics. A remote endpoint, or `EDL_TRANSPORT=grpc`, gets gRPC. The
+choice is logged once per (re)selection (`link <addr>: <tier>`); every
+`rpc.client.<Method>` span and WireStats row carries the tier that
+served, so bytes-per-sync distinguishes wire bytes from co-located ones
+(inproc counts calls but zero bytes). A carrier that cannot connect
+(`transport.CarrierDown`: a dead server's socket file) hands that call
+to the gRPC channel this client holds anyway.
 """
 
 from __future__ import annotations
@@ -30,14 +35,18 @@ import grpc
 
 from elasticdl_tpu.common import messages
 from elasticdl_tpu.common.constants import GRPC_OPTIONS, SERVICE_NAME
+from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.obs import trace as obs_trace
 from elasticdl_tpu.rpc import chaos
+from elasticdl_tpu.rpc import transport as transport_mod
 from elasticdl_tpu.rpc.policy import (
     IDEMPOTENT_METHODS,
     CircuitBreaker,
     RetryPolicy,
     wire_stats_for,
 )
+
+logger = get_logger(__name__)
 
 
 class RpcClient:
@@ -70,15 +79,11 @@ class RpcClient:
         # fast-path tier for co-located endpoints (None = plain gRPC).
         # The transport shares `plan` with the interceptors above, so
         # chaos counters advance identically whichever tier serves.
-        from elasticdl_tpu.rpc import transport as transport_mod
-
         # `transport` pins this client's tier regardless of the ambient
         # EDL_TRANSPORT mode (per-link selection: the aggregation tree
         # keeps shm for worker->aggregator while pinning uds/grpc for
         # aggregator->PS); None = the env mode as before.
-        self._transport = transport_mod.select_transport(
-            addr, fault_plan=plan, tier=transport
-        )
+        self._transport = self._select(addr)
         self._policy = policy if policy is not None else RetryPolicy.from_env()
         self._breaker = breaker if breaker is not None else CircuitBreaker(addr)
         self._calls: dict[str, Any] = {}
@@ -89,6 +94,17 @@ class RpcClient:
         # (rpc/policy.wire_stats_for); counted around the policy call
         # so retries of one logical call still tally each resend
         self.wire = wire_stats_for(addr)
+
+    def _select(self, addr: str):
+        transport = transport_mod.select_transport(
+            addr, fault_plan=self._fault_plan, tier=self._tier
+        )
+        logger.info(
+            "link %s: %s", addr, transport.name if transport else "grpc"
+        )
+        # the first fallback of a selection is logged, the rest are not
+        self._fell_back = False
+        return transport
 
     def wait_ready(self, timeout: float = 30.0):
         grpc.channel_ready_future(self._channel).result(timeout=timeout)
@@ -110,11 +126,7 @@ class RpcClient:
             interceptors = plan.client_interceptors()
             if interceptors:
                 channel = grpc.intercept_channel(channel, *interceptors)
-        from elasticdl_tpu.rpc import transport as transport_mod
-
-        transport = transport_mod.select_transport(
-            addr, fault_plan=plan, tier=self._tier
-        )
+        transport = self._select(addr)
         # the swap is deliberately lock-free: each attribute move is a
         # single reference assignment, and a call racing the swap
         # harmlessly finishes (or fails and retries) on whichever
@@ -189,29 +201,51 @@ class RpcClient:
             )
 
         transport = self._transport
+        tier = transport.name if transport else "grpc"
 
-        def attempt(remaining):
-            if transport is not None:
-                inproc = transport.name == "inproc"
-                self.wire.record(
-                    method,
-                    sent=0 if inproc else len(payload),
-                    transport=transport.name,
-                    calls=1 if inproc else None,
-                )
-                resp_bytes = transport.call(method, payload, remaining)
-                self.wire.record(
-                    method,
-                    received=0 if inproc else len(resp_bytes),
-                    transport=transport.name,
-                )
-                return resp_bytes
+        def over_grpc(remaining):
             self.wire.record(method, sent=len(payload))
             resp_bytes = stub(payload, timeout=remaining)
             self.wire.record(method, received=len(resp_bytes))
             return resp_bytes
 
-        tier = transport.name if transport else "grpc"
+        def attempt(remaining):
+            nonlocal tier
+            if transport is None:
+                return over_grpc(remaining)
+            inproc = transport.name == "inproc"
+            sent = 0 if inproc else len(payload)
+            calls = 1 if inproc else None
+            try:
+                resp_bytes = transport.call(method, payload, remaining)
+            except transport_mod.CarrierDown as e:
+                # nothing left this process: the channel serves the
+                # call (and draws its chaos fault)
+                tier = "grpc"
+                if not self._fell_back:
+                    self._fell_back = True
+                    logger.warning(
+                        "link %s: %s carrier is down (%s); serving over "
+                        "grpc until it is back",
+                        self.wire.endpoint, transport.name, e.details(),
+                    )
+                return over_grpc(remaining)
+            except BaseException:
+                # the frame left, or may have: a retry's resend tallies
+                self.wire.record(
+                    method, sent=sent, transport=transport.name, calls=calls
+                )
+                raise
+            tier = transport.name
+            self.wire.record(
+                method,
+                sent=sent,
+                received=0 if inproc else len(resp_bytes),
+                transport=transport.name,
+                calls=calls,
+            )
+            return resp_bytes
+
         settled = False
         try:
             resp = self._policy.call(

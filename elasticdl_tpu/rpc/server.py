@@ -7,9 +7,12 @@ format supports bf16 and nested pytrees (see common/codec.py).
 
 Every server also serves the transport fast paths (rpc/transport.py):
 its handler table is registered in the in-process dispatch registry
-keyed by the bound port, and — when `EDL_TRANSPORT` enables them — a
-Unix-domain-socket listener and/or a shared-memory listener share the
-same `ServerDispatcher`, so chaos/fencing/abort classification is
+keyed by the bound port, and a Unix-domain-socket listener
+(`edl-uds-<port>.sock` in `EDL_UDS_DIR`) opens beside gRPC unless
+`EDL_TRANSPORT` says otherwise — with the variable unset that is the
+carrier a client on this host gets. `EDL_TRANSPORT=shm|auto` adds (or
+swaps in) the shared-memory listener, `grpc` opens neither. All share
+the same `ServerDispatcher`, so chaos/fencing/abort classification is
 identical on every tier.
 """
 

@@ -3,7 +3,9 @@
 The elastic window path is link-bound (docs/performance.md), yet a
 co-located PS shard pays full gRPC framing for bytes that never leave
 the host. This module adds three fast paths under the SAME call
-surface, selected per endpoint by `EDL_TRANSPORT`:
+surface. Which carrier a link uses is decided from what the code can
+observe — is the peer on this host, and is its local listener there —
+with `EDL_TRANSPORT` as the override (and the test handle):
 
 - **uds** — a Unix-domain-socket byte protocol carrying codec frames
   with a minimal length-prefixed header, skipping gRPC/HTTP-2 framing
@@ -49,11 +51,35 @@ reachable (a registered in-process dispatcher, a readable shm
 rendezvous file with its doorbell socket, or an existing socket file);
 otherwise the caller falls back to gRPC. `auto` prefers
 inproc > shm > uds > grpc.
+
+With `EDL_TRANSPORT` unset the mode is **uds**: every `RpcServer`
+opens its Unix-socket listener beside gRPC, a client whose endpoint is
+local and whose socket file exists is carried by it, and a remote
+endpoint (the k8s path advertises the pod IP) gets gRPC. Unset is not
+`auto`: no `inproc` (in-process tests keep their sockets) and no `shm`
+(a segment per connection). `EDL_TRANSPORT=grpc` is pure gRPC: no
+listener, no fast path.
+
+The socket tiers receive a frame with no copy beyond the kernel's:
+`_recv_frame` reads into memory that was never zero-filled and hands
+the dispatcher / `messages.unpack` a read-only view of it, over which
+the codec builds its `np.frombuffer` views. A buffer is never reused:
+each frame gets its own, which lives as long as an array decoded from
+it (the master keeps such views past the handler: `grads_to_wait` > 1,
+fan-in).
+
+A local carrier that cannot CONNECT (the socket file of a dead server,
+a server relaunched under `EDL_TRANSPORT=grpc` on a reused port) raises
+`CarrierDown` before anything was sent or any client-side fault was
+drawn; `RpcClient` then serves that call over the gRPC channel it
+holds anyway, so a stale file costs a failed `connect()` a call, never
+an endpoint that answers UNAVAILABLE for ever.
 """
 
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import os
 import socket
@@ -66,6 +92,7 @@ from multiprocessing import shared_memory as _shm_mod
 from typing import Callable, Dict, Optional
 
 import grpc
+import numpy as np
 
 from elasticdl_tpu.common import codec, messages
 from elasticdl_tpu.common.constants import (
@@ -139,11 +166,16 @@ _SHM_ACK = b"\x06"
 _SHM_BCAST_KEY = "__shm_bcast__"
 
 
+#: The mode when EDL_TRANSPORT is unset: a local peer with a listener
+#: gets the Unix-socket carrier, everyone else gRPC.
+DEFAULT_MODE = TRANSPORT_UDS
+
+
 def transport_mode(env=None) -> str:
-    """The configured tier ("grpc"/"uds"/"shm"/"inproc"/"auto");
-    unknown values log once and mean grpc."""
+    """The configured tier ("grpc"/"uds"/"shm"/"inproc"/"auto"); unset
+    means DEFAULT_MODE, unknown values log once and mean grpc."""
     env = os.environ if env is None else env
-    mode = (env.get(ENV_TRANSPORT, "") or TRANSPORT_GRPC).strip().lower()
+    mode = (env.get(ENV_TRANSPORT, "") or DEFAULT_MODE).strip().lower()
     if mode not in TRANSPORT_TIERS and mode != "auto":
         logger.warning("unknown %s=%r; using grpc", ENV_TRANSPORT, mode)
         return TRANSPORT_GRPC
@@ -164,6 +196,26 @@ def server_shm_enabled() -> bool:
 def uds_dir(env=None) -> str:
     env = os.environ if env is None else env
     return env.get(ENV_UDS_DIR) or tempfile.gettempdir()
+
+
+#: sockaddr_un.sun_path holds 108 bytes, the terminating NUL included
+_SUN_PATH_MAX = 107
+
+
+@contextlib.contextmanager
+def _sock_addr(path: str):
+    """The address to bind or connect `path` by. A path too long for
+    an AF_UNIX address (a TMPDIR deep inside a checkout) is reached
+    through an open descriptor of its directory, so a long
+    EDL_UDS_DIR costs nothing instead of silently meaning gRPC."""
+    if len(os.fsencode(path)) <= _SUN_PATH_MAX:
+        yield path
+        return
+    fd = os.open(os.path.dirname(path), os.O_RDONLY | os.O_DIRECTORY)
+    try:
+        yield f"/proc/self/fd/{fd}/{os.path.basename(path)}"
+    finally:
+        os.close(fd)
 
 
 def uds_path_for(port: int) -> str:
@@ -541,20 +593,108 @@ def _error_frame(e: grpc.RpcError) -> bytes:
     return _RESP_ERR.pack(1, code.value[0], len(detail_b)) + detail_b
 
 
-def _recv_exact(conn: socket.socket, n: int, *, eof_ok: bool = False):
-    """Read exactly n bytes; None on a clean EOF at a frame boundary
-    (eof_ok), ConnectionError on EOF mid-frame."""
-    buf = bytearray(n)
-    view = memoryview(buf)
+def _recv_fill(conn: socket.socket, view, n: int, eof_ok: bool = False) -> bool:
+    """Fill view[:n] from the socket; False on a clean EOF before the
+    first byte (eof_ok), ConnectionError on EOF after it."""
     got = 0
     while got < n:
         k = conn.recv_into(view[got:], n - got)
         if k == 0:
             if eof_ok and got == 0:
-                return None
+                return False
             raise ConnectionError(f"peer closed mid-frame ({got}/{n} bytes)")
         got += k
+    return True
+
+
+def _recv_exact(conn: socket.socket, n: int, *, eof_ok: bool = False):
+    """Read exactly n bytes (headers, names, details: small); None on
+    a clean EOF at a frame boundary (eof_ok), ConnectionError on EOF
+    mid-frame."""
+    buf = bytearray(n)
+    if not _recv_fill(conn, memoryview(buf), n, eof_ok):
+        return None
     return bytes(buf)
+
+
+def _frame_buffer(n: int):
+    """(writable view, read-only view) of fresh memory for a frame of
+    n bytes. The memory is not zero-filled (a `bytearray(n)` is, one
+    pass over 649 MB for nothing) and the read-only view is what the
+    codec decodes from: no trailing `bytes()` copy. Read-only, like
+    the `bytes` it replaces, so decoded arrays stay read-only views."""
+    buf = np.empty(n, dtype=np.uint8)
+    return memoryview(buf), memoryview(buf).toreadonly()
+
+
+def _recv_frame(conn: socket.socket, n: int):
+    """Read a frame body of exactly n bytes with no copy beyond the
+    kernel's; ConnectionError on EOF inside it."""
+    view, frame = _frame_buffer(n)
+    _recv_fill(conn, view, n)
+    return frame
+
+
+class CarrierDown(PolicyRpcError):
+    """A local carrier could not connect: nothing was sent and no
+    client-side fault was drawn, so `RpcClient` may serve the call
+    over gRPC instead. UNAVAILABLE to anyone who does not."""
+
+    def __init__(self, details: str):
+        super().__init__(grpc.StatusCode.UNAVAILABLE, details)
+
+
+def _listen_unix(path: str) -> socket.socket:
+    """A listening AF_UNIX socket at `path`. The name appears only
+    once the socket listens (bound under a temporary name, then
+    renamed over whatever a predecessor on this port left), so a
+    socket file that refuses a connection belongs to a dead process —
+    which is what lets every boot sweep the directory of them: each
+    RpcServer makes such a file, and a SIGKILLed one cannot remove
+    its own. OSError when the directory is unusable."""
+    tmp = f"{path}.{os.getpid()}.tmp"
+    sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    try:
+        with _sock_addr(tmp) as addr:
+            sock.bind(addr)
+        sock.listen(128)
+        os.rename(tmp, path)
+    except OSError:
+        # a half-built listener has no owner to close() it: the
+        # caller never gets the object, so release the fd here
+        sock.close()
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        raise
+    _reap_dead_sockets(os.path.dirname(path))
+    return sock
+
+
+def _reap_dead_sockets(directory: str) -> None:
+    try:
+        names = os.listdir(directory)
+    except OSError:
+        return
+    for name in names:
+        if not (name.startswith("edl-uds-") and name.endswith(".sock")):
+            continue
+        path = os.path.join(directory, name)
+        probe = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+        probe.settimeout(0.2)
+        try:
+            with _sock_addr(path) as addr:
+                probe.connect(addr)
+        except ConnectionRefusedError:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass
+        except OSError:
+            pass  # gone already, or busy: alive
+        finally:
+            probe.close()
 
 
 class UdsServer:
@@ -566,19 +706,7 @@ class UdsServer:
 
     def __init__(self, port: int, dispatcher: ServerDispatcher):
         self.path = uds_path_for(port)
-        try:
-            os.unlink(self.path)
-        except FileNotFoundError:
-            pass
-        self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        try:
-            self._sock.bind(self.path)
-            self._sock.listen(128)
-        except OSError:
-            # a half-built listener has no owner to close() it: the
-            # caller never gets the object, so release the fd here
-            self._sock.close()
-            raise
+        self._sock = _listen_unix(self.path)
         self._dispatcher = dispatcher
         self._closed = False
         self._thread: Optional[threading.Thread] = None
@@ -622,9 +750,13 @@ class UdsServer:
                     return
                 mlen, blen = _REQ_HEADER.unpack(header)
                 method = _recv_exact(conn, mlen).decode("utf-8")
-                body = _recv_exact(conn, blen)
                 try:
-                    resp = self._dispatcher.dispatch(method, body, TRANSPORT_UDS)
+                    # no local names the frame: once dispatched it lives
+                    # on only in what the handler kept of it, and a 649
+                    # MB request is gone before the connection's next
+                    resp = self._dispatcher.dispatch(
+                        method, _recv_frame(conn, blen), TRANSPORT_UDS
+                    )
                 except grpc.RpcError as e:
                     conn.sendall(_error_frame(e))
                     continue
@@ -675,21 +807,10 @@ class AsyncUdsServer:
 
     def __init__(self, port: int, dispatcher: ServerDispatcher, core=None):
         self.path = uds_path_for(port)
-        try:
-            os.unlink(self.path)
-        except FileNotFoundError:
-            pass
         self._dispatcher = dispatcher
         self._core = core if core is not None else dispatch_mod.get_loop_core()
-        self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        try:
-            self._sock.bind(self.path)
-            self._sock.listen(128)
-            self._sock.setblocking(False)
-        except OSError:
-            # a half-built listener has no owner to close() it
-            self._sock.close()
-            raise
+        self._sock = _listen_unix(self.path)
+        self._sock.setblocking(False)
         self._server = None
         # live connection writers, severed on close(): a stopped server
         # must refuse pooled clients exactly like a stopped gRPC server
@@ -793,12 +914,11 @@ class UdsTransport:
                 return self._pool.pop()
         conn = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         try:
-            conn.connect(self._path)
+            with _sock_addr(self._path) as addr:
+                conn.connect(addr)
         except OSError as e:
             conn.close()
-            raise PolicyRpcError(
-                grpc.StatusCode.UNAVAILABLE, f"uds connect {self._path}: {e}"
-            )
+            raise CarrierDown(f"uds connect {self._path}: {e}")
         return conn
 
     def _checkin(self, conn: socket.socket):
@@ -821,9 +941,11 @@ class UdsTransport:
                     pass
 
     def call(self, method: str, payload: bytes, timeout: float) -> bytes:
-        after = transport_faults_before(self._plan, method, "client")
+        # connect first: CarrierDown leaves the FaultPlan untouched, so
+        # the gRPC channel that serves the call instead draws its fault
         conn = self._checkout()
         try:
+            after = transport_faults_before(self._plan, method, "client")
             conn.settimeout(max(0.001, float(timeout)))
             mb = method.encode("utf-8")
             conn.sendall(_REQ_HEADER.pack(len(mb), len(payload)) + mb)
@@ -831,7 +953,7 @@ class UdsTransport:
             status = _recv_exact(conn, 1)[0]
             if status == 0:
                 (blen,) = struct.unpack("<I", _recv_exact(conn, 4))
-                body = _recv_exact(conn, blen)
+                body = _recv_frame(conn, blen)
             else:
                 code_val, dlen = struct.unpack("<iH", _recv_exact(conn, 6))
                 detail = _recv_exact(conn, dlen).decode("utf-8", "replace")
@@ -1095,7 +1217,8 @@ class ShmServer:
             pass
         self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         try:
-            self._sock.bind(self.doorbell)
+            with _sock_addr(self.doorbell) as addr:
+                self._sock.bind(addr)
             self._sock.listen(128)
             self.broadcaster = ShmBroadcaster(self._prefix + "x")
             self._conn_seq = 0
@@ -1290,11 +1413,11 @@ class ShmServer:
                 except OSError:
                     pass
 
-    def _recv_chunked(self, conn, region, total: int) -> bytes:
+    def _recv_chunked(self, conn, region, total: int):
         """Oversize-request fallback: assemble the frame through the
         ring in ring-sized pieces (one copy — the zero-copy contract
         holds only for frames that fit the ring)."""
-        out = bytearray(total)
+        out, frame = _frame_buffer(total)
         got = 0
         conn.settimeout(shm_doorbell_timeout())
         try:
@@ -1307,7 +1430,7 @@ class ShmServer:
                 conn.sendall(_SHM_ACK)  # client may reuse the region
         finally:
             conn.settimeout(None)
-        return bytes(out)
+        return frame
 
     def _send_chunked(self, conn, region, resp: bytes) -> None:
         total = len(resp)
@@ -1364,7 +1487,12 @@ class _ShmConn:
     def __init__(self, doorbell: str):
         sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         try:
-            sock.connect(doorbell)
+            with _sock_addr(doorbell) as addr:
+                sock.connect(addr)
+        except OSError as e:
+            sock.close()
+            raise CarrierDown(f"shm connect {doorbell}: {e}")
+        try:
             sock.settimeout(shm_doorbell_timeout())
             hello = _recv_exact(sock, _SHM_HELLO.size)
             gen, nlen, ring = _SHM_HELLO.unpack(hello)
@@ -1435,9 +1563,10 @@ class ShmTransport:
         conn.destroy()
 
     def call(self, method: str, payload: bytes, timeout: float) -> bytes:
-        after = transport_faults_before(self._plan, method, "client")
+        # connect first, as UdsTransport.call does and for its reason
         conn = self._checkout()
         try:
+            after = transport_faults_before(self._plan, method, "client")
             conn.sock.settimeout(max(0.001, float(timeout)))
             mb = method.encode("utf-8")
             n = len(payload)
@@ -1464,7 +1593,7 @@ class ShmTransport:
             elif status == 3:
                 body = self._resolve_bcast(bytes(conn.resp[:length]))
             elif status == 2:
-                buf = bytearray(length)
+                buf, body = _frame_buffer(length)
                 got = 0
                 while got < length:
                     (clen,) = _SHM_CHUNK.unpack(
@@ -1477,7 +1606,6 @@ class ShmTransport:
                     buf[got : got + clen] = conn.resp[:clen]
                     got += clen
                     conn.sock.sendall(_SHM_ACK)
-                body = bytes(buf)
             else:
                 code_val, dlen = _SHM_ERR.unpack(
                     _recv_exact(conn.sock, _SHM_ERR.size)

@@ -23,9 +23,23 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import atexit  # noqa: E402
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
 import time  # noqa: E402
 
 import pytest  # noqa: E402
+
+# Every RpcServer opens a Unix-socket listener in EDL_UDS_DIR (the
+# system temp dir when unset), named by its port. Each pytest process
+# gets a directory of its own (an xdist worker inherits the
+# controller's environment, so whatever is set is replaced), so the
+# sweep below never reads another worker's live socket as this test's
+# leak, and everything a test spawns inherits it. Short, because an
+# AF_UNIX path holds 108 bytes; removed when the process ends.
+_uds_dir = tempfile.mkdtemp(prefix="edl-t-")
+os.environ["EDL_UDS_DIR"] = _uds_dir
+atexit.register(shutil.rmtree, _uds_dir, ignore_errors=True)
 
 # -- OS-resource leak sweep ----------------------------------------------------
 #

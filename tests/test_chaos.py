@@ -513,6 +513,16 @@ def _run_training_job(tmp, tag, monkeypatch, chaos_spec):
             assert not manager.all_exited(), f"job[{tag}]: all workers gone"
             time.sleep(0.05)
         assert not dispatcher.has_failed_tasks()
+        if any(f["kind"] == "crash" for f in (chaos_spec or {}).get("faults", ())):
+            # the whole job is about a second of RPCs once both workers
+            # are up (less on the local carrier), so the survivor can
+            # finish it before the victim has made the GetTask it dies
+            # on, or before the manager has seen it die: the crash and
+            # its relaunch are part of what this run must show, so it
+            # ends when they have happened, not a poll earlier
+            while manager.relaunches() < 1:
+                assert time.time() < deadline, f"job[{tag}]: no relaunch"
+                time.sleep(0.05)
         params, _aux, _version = servicer.get_params_copy()
         stats = [sv.stats() for sv in servicer.ps_group.servicers]
         return {

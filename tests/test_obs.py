@@ -54,13 +54,15 @@ def _echo_roundtrip():
     return outer_id
 
 
-@pytest.mark.parametrize("tier", ["grpc", "uds", "inproc", "shm"])
+@pytest.mark.parametrize("tier", ["grpc", "uds", "inproc", "shm", "unset"])
 def test_span_parent_child_roundtrip_per_tier(tier, monkeypatch, tmp_path):
-    if tier == "grpc":
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    if tier == "unset":
+        # nothing set: a local peer is carried by the Unix socket
         monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+        tier = "uds"
     else:
         monkeypatch.setenv(ENV_TRANSPORT, tier)
-        monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
     outer_id = _echo_roundtrip()
     spans = trace.RECORDER.snapshot()
     clients = [s for s in spans if s["name"] == "rpc.client.Echo"]

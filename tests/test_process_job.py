@@ -186,6 +186,46 @@ def test_multiprocess_training_job_sharded_ps(tmp_path):
     assert model.version > 0
 
 
+def test_window_job_with_nothing_set_rides_the_local_carrier(
+    tmp_path, monkeypatch
+):
+    """A two-window job through `master.main` with EDL_TRANSPORT unset:
+    the worker process finds the master on this host with its socket
+    file there, so every window delta crosses by the Unix-socket
+    carrier (the worker's own timeline says so), and the job ends at
+    the version an undisturbed job must: one per step."""
+    import json
+
+    from elasticdl_tpu.common.constants import (
+        ENV_TRANSPORT,
+        ENV_WORKER_LOG_DIR,
+    )
+
+    tmp = str(tmp_path)
+    data = os.path.join(tmp, "data")
+    os.makedirs(data)
+    _write_shards(data, n_files=1, records_each=64)
+    logs = os.path.join(tmp, "logs")
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    monkeypatch.setenv(ENV_WORKER_LOG_DIR, logs)
+    output = os.path.join(tmp, "final.ckpt")
+    argv = _master_argv(
+        data, output, num_workers=1, extra=("--local_updates", "2")
+    )
+    argv[argv.index("--num_epochs") + 1] = "1"
+    assert master_main(argv) == 0
+    # 64 records / minibatch 16 = 4 steps = two windows of 2
+    assert _load_params(output).version == 4
+    with open(os.path.join(logs, "worker-0.spans.jsonl")) as f:
+        spans = [json.loads(line) for line in f if line.strip()]
+    reports = [
+        s for s in spans if s.get("name") == "rpc.client.ReportLocalUpdate"
+    ]
+    assert len(reports) == 2
+    assert [s["args"]["transport"] for s in reports] == ["uds", "uds"]
+    assert [s["args"]["version"] for s in reports] == [2, 4]
+
+
 def _run_standby_kill_job(tmp, extra_args=(), kill_after_records=1):
     """Shared harness for the warm-standby e2e tests: 1 active + 1
     standby through the real master wiring, SIGKILL the active once

@@ -76,15 +76,26 @@ def shm_env(monkeypatch, tmp_path):
 
 
 def test_mode_default_and_unknown(monkeypatch):
+    # unset: a local peer gets the Unix-socket carrier (never `auto`:
+    # no inproc, no shm); an explicit grpc or an unknown value is grpc
     monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    assert transport.transport_mode() == "uds"
+    assert transport.server_fast_paths_enabled()
+    assert not transport.server_shm_enabled()
+    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
     assert transport.transport_mode() == "grpc"
+    assert not transport.server_fast_paths_enabled()
     monkeypatch.setenv(ENV_TRANSPORT, "warp-drive")
     assert transport.transport_mode() == "grpc"
     monkeypatch.setenv(ENV_TRANSPORT, "AUTO")
     assert transport.transport_mode() == "auto"
 
 
-def test_select_grpc_mode_returns_none(monkeypatch):
+def test_select_grpc_mode_returns_none(monkeypatch, tmp_path):
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
+    assert transport.select_transport("localhost:12345") is None
+    # unset and local, but no listener there: gRPC
     monkeypatch.delenv(ENV_TRANSPORT, raising=False)
     assert transport.select_transport("localhost:12345") is None
 
@@ -820,3 +831,320 @@ def test_uds_transport_close_drains_pool(tmp_path):
     t.close()
     assert all(c.closed for c in conns)
     assert t._pool == []
+
+
+# -- the unset default: a local peer gets the local carrier -------------------
+
+
+@pytest.fixture
+def unset_env(monkeypatch, tmp_path):
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+
+
+@pytest.fixture
+def client_log():
+    """Records of rpc/client.py's logger (it does not propagate)."""
+    import logging
+
+    from elasticdl_tpu.rpc import client as client_mod
+
+    records = []
+
+    class _Keep(logging.Handler):
+        def emit(self, record):
+            records.append(record.getMessage())
+
+    handler = _Keep(level=logging.INFO)
+    client_mod.logger.addHandler(handler)
+    yield records
+    client_mod.logger.removeHandler(handler)
+
+
+def _stale_socket_file(port: int) -> str:
+    """What a SIGKILLed server leaves: a socket file nothing listens
+    behind."""
+    path = transport.uds_path_for(port)
+    dead = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    dead.bind(path)
+    dead.close()
+    assert os.path.exists(path)
+    return path
+
+
+def test_unset_local_endpoint_with_live_server_selects_uds(
+    unset_env, client_log
+):
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    addr = f"localhost:{server.port}"
+    client = RpcClient(addr, policy=fast_policy())
+    try:
+        assert os.path.exists(transport.uds_path_for(server.port))
+        assert client._transport is not None
+        assert client._transport.name == "uds"
+        assert f"link {addr}: uds" in client_log
+        _roundtrip(client)
+        by_tier = client.wire.snapshot()["transports"]
+        assert by_tier["uds"]["calls"] == 1 and "grpc" not in by_tier
+        # the hostname form of this host is local too
+        assert transport.select_transport(
+            f"{socket.gethostname()}:{server.port}"
+        ).name == "uds"
+    finally:
+        client.close()
+        server.stop()
+    assert not os.path.exists(transport.uds_path_for(server.port))
+
+
+def test_unset_remote_host_selects_grpc(unset_env, client_log):
+    """A non-local host string gets gRPC even when a socket file of
+    the same port number lies in this host's directory (the k8s path
+    advertises pod IPs; another pod's port is not this host's)."""
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    try:
+        port = server.port
+        assert os.path.exists(transport.uds_path_for(port))
+        assert transport.select_transport(f"10.0.0.7:{port}") is None
+        assert transport.select_transport(f"ps-7.example.com:{port}") is None
+        client = RpcClient(f"ps-7.example.com:{port}", policy=fast_policy())
+        try:
+            assert client._transport is None
+            assert f"link ps-7.example.com:{port}: grpc" in client_log
+        finally:
+            client.close()
+    finally:
+        server.stop()
+
+
+def test_explicit_grpc_is_pure_grpc_and_opens_no_listener(
+    monkeypatch, tmp_path, client_log
+):
+    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    addr = f"localhost:{server.port}"
+    client = RpcClient(addr, policy=fast_policy())
+    try:
+        assert server._uds is None and server._shm is None
+        assert os.listdir(str(tmp_path)) == []
+        assert client._transport is None
+        assert f"link {addr}: grpc" in client_log
+        _roundtrip(client)
+        assert set(client.wire.snapshot()["transports"]) == {"grpc"}
+    finally:
+        client.close()
+        server.stop()
+
+
+def _big_tree(seed: int, mb: int = 64):
+    """A nested tree of `mb` MB and a bit: bf16 and f32 leaves, far
+    above the socket buffer (208 KiB) and the shm ring (4 MiB)."""
+    import ml_dtypes
+
+    rng = np.random.default_rng(seed)
+    n = mb * (1 << 20) // 4
+    f32 = rng.standard_normal(n // 2, dtype=np.float32)
+    bf16 = rng.standard_normal(n, dtype=np.float32).astype(ml_dtypes.bfloat16)
+    return {
+        "delta": {
+            "layer0": {"kernel": f32.reshape(-1, 256), "bias": f32[:7].copy()},
+            "layer1": [bf16.reshape(128, -1), {"scale": bf16[:33].copy()}],
+        },
+        "steps": 8,
+    }
+
+
+def _assert_bit_equal(got, want):
+    import jax
+
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    for g, w in zip(got_leaves, want_leaves):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("env_fixture", ["unset_env", "uds_env", "shm_env"])
+def test_large_frame_roundtrip_is_bit_equal(env_fixture, request):
+    """A 64 MB+ nested frame up and the same frame down: every leaf
+    arrives bit for bit, on the default carrier and on both explicit
+    ones (shm chunks it through the ring in both directions)."""
+    request.getfixturevalue(env_fixture)
+    seen = {}
+
+    def mirror(req):
+        seen["nbytes"] = sum(
+            a.nbytes for a in (
+                req["delta"]["layer0"]["kernel"], req["delta"]["layer1"][0]
+            )
+        )
+        return req
+
+    server = RpcServer({"Mirror": mirror}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        expected = os.environ.get(ENV_TRANSPORT, "uds")
+        assert client._transport.name == expected
+        tree = _big_tree(seed=3)
+        resp = client.call("Mirror", tree, timeout=120, idempotent=False)
+        assert seen["nbytes"] >= 64 * (1 << 20)
+        _assert_bit_equal(resp, tree)
+        # decoded leaves are read-only views of the received frame,
+        # as they were over the `bytes` it used to be copied into
+        assert not resp["delta"]["layer0"]["kernel"].flags.writeable
+        row = client.wire.snapshot()["transports"][expected]
+        assert row["bytes_sent"] >= 64 * (1 << 20)
+        assert row["bytes_received"] >= 64 * (1 << 20)
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.mark.parametrize("env_fixture", ["unset_env", "shm_env"])
+def test_arrays_of_request_n_survive_request_n_plus_1(env_fixture, request):
+    """The reuse guard. The master keeps views of a request past its
+    handler (`grads_to_wait` > 1 accumulation, fan-in); the receive
+    path hands out the very buffer `recv_into` filled, so if that
+    buffer were ever reused for the connection's next frame the kept
+    arrays would silently change. Same on the client for responses."""
+    request.getfixturevalue(env_fixture)
+    kept = []
+
+    def keep(req):
+        kept.append(req["grad"])
+        # a frame of its own per response, larger than the shm ring
+        return {"model": np.full(2 << 20, float(len(kept)), np.float32)}
+
+    server = RpcServer({"Keep": keep}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        n = 2 << 20  # 8 MB of f32: above the ring, so shm chunks it
+        models = []
+        for i in range(1, 4):
+            resp = client.call(
+                "Keep", {"grad": np.full(n, float(i), np.float32)},
+                timeout=60, idempotent=False,
+            )
+            models.append(resp["model"])
+        # one pooled connection carried all three
+        pool = client._transport._pool
+        assert len(pool) == 1
+        for i, (grad, model) in enumerate(zip(kept, models), start=1):
+            assert grad.shape == (n,) and model.shape == (n,)
+            assert float(grad.min()) == float(grad.max()) == float(i)
+            assert float(model.min()) == float(model.max()) == float(i)
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_stale_socket_file_is_served_over_grpc_and_logged_once(
+    monkeypatch, tmp_path, client_log
+):
+    """A master relaunched under EDL_TRANSPORT=grpc on a reused port
+    (or a dead one's socket file, never swept) must not strand a
+    worker whose rule says uds: the connect fails, the call goes over
+    the channel the client holds anyway, and the log says so once."""
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
+    hits = []
+    server = RpcServer(_echo_handlers(hits), port=0)  # no listener
+    server.start()
+    monkeypatch.delenv(ENV_TRANSPORT)
+    path = _stale_socket_file(server.port)
+    addr = f"localhost:{server.port}"
+    client = RpcClient(addr, policy=fast_policy())
+    try:
+        assert client._transport.name == "uds"  # the file is there
+        for i in range(3):
+            assert client.call("Echo", {"x": i}, timeout=10)["x"] == i
+        assert hits == [0, 1, 2]  # each served once
+        fallbacks = [m for m in client_log if "carrier is down" in m]
+        assert len(fallbacks) == 1 and "uds" in fallbacks[0]
+        by_tier = client.wire.snapshot()["transports"]
+        assert by_tier["grpc"]["calls"] == 3 and "uds" not in by_tier
+        # ENOENT is the same story as ECONNREFUSED
+        os.unlink(path)
+        assert client.call("Echo", {"x": 9}, timeout=10)["x"] == 9
+        assert len([m for m in client_log if "carrier is down" in m]) == 1
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_carrier_down_draws_no_client_fault(unset_env):
+    """The fallback happens before the FaultPlan is consulted, so the
+    gRPC interceptor is the one injection layer of a call the channel
+    serves: an `nth: 1` error fires exactly once, not once a tier."""
+    plan = FaultPlan.from_spec({"faults": [
+        {"kind": "error", "methods": ["Echo"], "side": "client", "nth": 1},
+    ]})
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    server._uds.close()  # the listener dies, gRPC lives
+    _stale_socket_file(server.port)
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
+    )
+    try:
+        assert client._transport.name == "uds"
+        resp = client.call("Echo", {"x": 5}, timeout=10, idempotent=True)
+        assert resp["x"] == 5
+        # two attempts, each counted once: the injected error, the retry
+        assert (plan.faults[0]._count, plan.faults[0]._fires) == (2, 1)
+    finally:
+        client.close()
+        server.stop()  # closing the listener again unlinks the file
+
+
+def test_boot_sweeps_dead_sockets_and_spares_live_ones(unset_env):
+    """Every RpcServer makes a socket file and a SIGKILLed one cannot
+    remove its own: the next boot in the directory does, and leaves
+    every listening neighbour alone."""
+    neighbour = RpcServer(_echo_handlers(), port=0)
+    neighbour.start()
+    dead = _stale_socket_file(45997)
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    client = RpcClient(f"localhost:{neighbour.port}", policy=fast_policy())
+    try:
+        assert not os.path.exists(dead)
+        assert os.path.exists(transport.uds_path_for(neighbour.port))
+        assert client._transport.name == "uds"
+        _roundtrip(client)
+    finally:
+        client.close()
+        server.stop()
+        neighbour.stop()
+
+
+def test_socket_directory_deeper_than_an_af_unix_address(
+    monkeypatch, tmp_path
+):
+    """sun_path holds 108 bytes; a TMPDIR inside a checkout is easily
+    deeper. The carrier reaches its directory through a descriptor
+    instead of silently meaning gRPC."""
+    deep = tmp_path / ("d" * 60) / ("e" * 60)
+    deep.mkdir(parents=True)
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    monkeypatch.setenv(ENV_UDS_DIR, str(deep))
+    assert len(transport.uds_path_for(50000)) > 108
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        assert server._uds is not None
+        assert client._transport.name == "uds"
+        _roundtrip(client)
+        assert set(client.wire.snapshot()["transports"]) == {"uds"}
+    finally:
+        client.close()
+        server.stop()
+    assert os.listdir(str(deep)) == []
