@@ -27,10 +27,11 @@ local layers.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,10 +69,28 @@ class TransformerConfig:
     # the cheap elementwise ops (gelu/layernorm/softmax) — most of full
     # remat's memory win at a few percent of its recompute cost
     remat_policy: str = ""  # "" (full) | "dots" 
+    # -- the unsharded block's further settings (plain_forward only; the
+    # mesh path refuses them, `_require_mesh_support`) ----------------
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
+    # "gelu": w2(gelu(w1 x)); "swiglu": wd(silu(wg x) * wu x), no bias
+    mlp: str = "gelu"
+    # four norms a layer: h + ln1b(attn(ln1(h))), h + ln2b(mlp(ln2(h)))
+    sandwich_norm: bool = False
+    # > 1: a looped LM (Ouro): the whole stack runs n_loops times over
+    # ONE set of layer weights, the final norm closes every pass, and
+    # every pass is an exit with its own logits and a learned gate; the
+    # outputs are `LoopedOutputs` and the loss `looped_exit_loss`
+    n_loops: int = 1
+    exit_entropy_weight: float = 0.1  # beta of the exit objective
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def looped(self) -> bool:
+        return self.n_loops > 1
 
 
 def _remat(body, cfg: "TransformerConfig"):
@@ -107,15 +126,29 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
         layers["router"] = norm(L, d, cfg.n_experts)
         layers["ew1"] = norm(L, cfg.n_experts, d, cfg.d_expert)
         layers["ew2"] = norm(L, cfg.n_experts, cfg.d_expert, d)
+    elif cfg.mlp == "swiglu":
+        layers["wg"] = norm(L, d, cfg.d_ff)
+        layers["wu"] = norm(L, d, cfg.d_ff)
+        layers["wd"] = norm(L, cfg.d_ff, d)
     else:
         layers["w1"] = norm(L, d, cfg.d_ff)
         layers["w2"] = norm(L, cfg.d_ff, d)
-    return {
+    if cfg.sandwich_norm:
+        layers["ln1b"] = np.ones((L, d), np.float32)
+        layers["ln2b"] = np.ones((L, d), np.float32)
+    params = {
         "embed": norm(cfg.vocab, d, scale=0.02),
         "layers": layers,
         "ln_f": np.ones((d,), np.float32),
         "head": norm(d, cfg.vocab),
     }
+    if cfg.looped:
+        # Linear(d, 1): the gates start near one half at every exit
+        params["exit_gate"] = {
+            "w": norm(d, 1, scale=0.02),
+            "b": np.zeros((1,), np.float32),
+        }
+    return params
 
 
 def param_partition_specs(cfg: TransformerConfig) -> Dict:
@@ -125,6 +158,7 @@ def param_partition_specs(cfg: TransformerConfig) -> Dict:
     weights shard their E dim over dp (the EP group). Embedding/head
     replicated (vocab-parallel is a later optimization).
     """
+    _require_mesh_support(cfg)
     layers = {
         "ln1": P("pp", None),
         "wq": P("pp", None, "tp"),
@@ -151,14 +185,29 @@ def param_partition_specs(cfg: TransformerConfig) -> Dict:
 # -------------------------------------------------------------------- model
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
-    """Rotary embedding; x: [B, L, H, D], positions: [L] global."""
+def _require_mesh_support(cfg: TransformerConfig):
+    """The 4-axis mesh path knows the original block only."""
+    if cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped:
+        raise NotImplementedError(
+            "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
+            "block once: mlp='swiglu', sandwich_norm and n_loops > 1 "
+            "exist on the unsharded path (plain_forward) only"
+        )
+
+
+def _rope(
+    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0
+) -> jnp.ndarray:
+    """Rotary embedding; x: [B, L, H, D], positions: [L] global. The
+    angles are float32 whatever x is (bfloat16 holds no whole number
+    above 256 exactly: position 2047 would turn as 2048); their cosine
+    and sine are cast to x's dtype."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=x.dtype) / half))
-    ang = positions.astype(x.dtype)[:, None] * freqs[None, :]  # [L, half]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [L, half]
+    cos = jnp.cos(ang).astype(x.dtype)[None, :, None, :]
+    sin = jnp.sin(ang).astype(x.dtype)[None, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -169,17 +218,17 @@ def _block(cfg: TransformerConfig, lp: Dict, h: jnp.ndarray, positions) -> Tuple
     tp = lax.axis_size("tp")
     h_local = cfg.n_heads // tp
 
-    x = rms_norm(h, lp["ln1"])
+    x = rms_norm(h, lp["ln1"], cfg.norm_eps)
     q = (x @ lp["wq"]).reshape(mb, lc, h_local, cfg.head_dim)
     k = (x @ lp["wk"]).reshape(mb, lc, h_local, cfg.head_dim)
     v = (x @ lp["wv"]).reshape(mb, lc, h_local, cfg.head_dim)
-    q = _rope(q, positions)
-    k = _rope(k, positions)
+    q = _rope(q, positions, cfg.rope_base)
+    k = _rope(k, positions, cfg.rope_base)
     attn = ring_attention(q, k, v, "sp", causal=True)
     attn = attn.reshape(mb, lc, h_local * cfg.head_dim)
     h = h + lax.psum(attn @ lp["wo"], "tp")
 
-    x = rms_norm(h, lp["ln2"])
+    x = rms_norm(h, lp["ln2"], cfg.norm_eps)
     if cfg.n_experts:
         flat = x.reshape(mb * lc, d)
         out, aux = moe_ffn(
@@ -216,7 +265,7 @@ def _local_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     outputs, aux = gpipe(stage_fn, params["layers"], micro, "pp", has_aux=True)
     h = outputs.reshape(b, lc, cfg.d_model)
 
-    h = rms_norm(h, params["ln_f"].astype(cfg.dtype))
+    h = rms_norm(h, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
     logits = h @ params["head"].astype(cfg.dtype)  # [B, Lc, V]
     return logits, aux
 
@@ -295,6 +344,64 @@ def data_spec() -> P:
     return P("dp", "sp")
 
 
+class LoopedOutputs(NamedTuple):
+    """What a looped LM's forward gives and its loss takes: every
+    exit's logits, the pre-sigmoid value of every exit's gate, and the
+    configuration's weight of the objective's entropy term."""
+
+    logits: jnp.ndarray  # [T, B, L, V], compute dtype
+    gates: jnp.ndarray  # [T, B, L], float32
+    entropy_weight: float
+
+
+def exit_distribution(gates: jnp.ndarray):
+    """(q, log q), [T, ...] each, of the exit gates' pre-sigmoid values
+    `gates` [T, ...]: with lambda_t = sigmoid(gates[t]),
+    q_1 = lambda_1, q_t = lambda_t prod_{j<t} (1 - lambda_j), and the
+    last exit takes what is left, q_T = prod_{j<T} (1 - lambda_j) (its
+    own gate is not read). Formed from log-sigmoids, so a saturated
+    gate gives a finite log q."""
+    gates = gates.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gates[:-1]), axis=0)  # [T-1, ...]
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]], axis=0)
+    log_q = jnp.concatenate(
+        [jax.nn.log_sigmoid(gates[:-1]) + before, stay[-1:]], axis=0
+    )
+    return jnp.exp(log_q), log_q
+
+
+def exit_cross_entropies(logits: jnp.ndarray, targets: jnp.ndarray):
+    """Next-token cross-entropy of every exit and token, float32:
+    logits [T, B, L, V], targets [B, L] -> [T, B, L]."""
+    logits = logits.astype(jnp.float32)
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    index = jnp.broadcast_to(targets[None, ..., None], logits.shape[:-1] + (1,))
+    return logz - jnp.take_along_axis(logits, index, axis=-1)[..., 0]
+
+
+def looped_exit_loss(outputs: LoopedOutputs, targets):
+    """The looped LM's stage-I objective (Ouro): per token the exit
+    distribution's expected cross-entropy less `entropy_weight` times
+    its entropy, then the mean over tokens."""
+    with jax.named_scope("exit_heads"):
+        ce = exit_cross_entropies(outputs.logits, targets)
+        q, log_q = exit_distribution(outputs.gates)
+        entropy = -jnp.sum(q * log_q, axis=0)
+        return jnp.mean(
+            jnp.sum(q * ce, axis=0) - outputs.entropy_weight * entropy
+        )
+
+
+def exit_stats(gates: jnp.ndarray) -> Dict:
+    """The mean exit distribution [T] over the batch's tokens and the
+    expected exit pass sum_t t q_t (1-based) — what the zoo adapter
+    leaves in its `window_stats` collection."""
+    q, _ = exit_distribution(lax.stop_gradient(gates))
+    mean_q = jnp.mean(q.reshape(q.shape[0], -1), axis=1)
+    passes = jnp.arange(1, q.shape[0] + 1, dtype=jnp.float32)
+    return {"exit_q": mean_q, "expected_exit": jnp.sum(passes * mean_q)}
+
+
 def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     """Vectorized unsharded forward — the same math as the sharded path
     restricted to a 1-device mesh, without the machinery: `lax.scan`
@@ -310,7 +417,19 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     (parallel/moe.moe_ffn_local — same routing math as the
     expert-parallel path, no collectives). Casts params to cfg.dtype
     itself. Returns (logits, aux): aux is the summed Switch
-    load-balance loss (0 for dense)."""
+    load-balance loss (0 for dense).
+
+    ONE definition of the block serves every unsharded LM; the
+    configuration selects the MLP (`mlp`), the rotary base, the norm
+    epsilon and the four-norm sandwich. With `n_loops` > 1 the stack
+    is run that many times by an outer `lax.scan` that closes over
+    the one set of stacked layer weights (the gradients of the passes
+    sum into one leaf), the final norm closes every pass, each layer
+    application is rematerialized, and the first return value is
+    `LoopedOutputs`: all exits' logits and gates. Only that looped
+    block carries `jax.named_scope`s (`looped_stack` > `attention`,
+    `mlp`; `exit_heads`): the other configurations' programs keep the
+    metadata, and so the compile-cache keys, they had."""
     from elasticdl_tpu.ops.flash_attention import attention
     from elasticdl_tpu.parallel.moe import moe_ffn_local
 
@@ -318,39 +437,74 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     b, l = tokens.shape
     h = params["embed"][tokens]  # [B, L, d]
     positions = jnp.arange(l)
+    eps = cfg.norm_eps
+    scope = jax.named_scope if cfg.looped else (
+        lambda _name: contextlib.nullcontext()
+    )
 
     def body(carry, lp):
         h, aux = carry
-        x = rms_norm(h, lp["ln1"])
-        q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        q, k = _rope(q, positions), _rope(k, positions)
-        attn = attention(q, k, v, causal=True).reshape(b, l, -1)
-        h = h + attn @ lp["wo"]
-        x = rms_norm(h, lp["ln2"])
-        if cfg.n_experts:
-            out, a = moe_ffn_local(
-                x.reshape(b * l, cfg.d_model),
-                lp["router"],
-                lp["ew1"],
-                lp["ew2"],
-                capacity_factor=cfg.capacity_factor,
-            )
-            h = h + out.reshape(b, l, cfg.d_model)
-            aux = aux + a
-        else:
-            h = h + jax.nn.gelu(x @ lp["w1"]) @ lp["w2"]
+        with scope("attention"):
+            x = rms_norm(h, lp["ln1"], eps)
+            q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+            k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+            v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+            q = _rope(q, positions, cfg.rope_base)
+            k = _rope(k, positions, cfg.rope_base)
+            out = attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
+            if cfg.sandwich_norm:
+                out = rms_norm(out, lp["ln1b"], eps)
+            h = h + out
+        with scope("mlp"):
+            x = rms_norm(h, lp["ln2"], eps)
+            if cfg.n_experts:
+                out, a = moe_ffn_local(
+                    x.reshape(b * l, cfg.d_model),
+                    lp["router"],
+                    lp["ew1"],
+                    lp["ew2"],
+                    capacity_factor=cfg.capacity_factor,
+                )
+                out = out.reshape(b, l, cfg.d_model)
+            elif cfg.mlp == "swiglu":
+                out = (jax.nn.silu(x @ lp["wg"]) * (x @ lp["wu"])) @ lp["wd"]
+            else:
+                out = jax.nn.gelu(x @ lp["w1"]) @ lp["w2"]
+            if cfg.sandwich_norm:
+                out = rms_norm(out, lp["ln2b"], eps)
+            h = h + out
+            if cfg.n_experts:
+                aux = aux + a
         return (h, aux), None
 
-    if cfg.remat:
+    if cfg.remat or cfg.looped:
         body = _remat(body, cfg)
 
-    (h, aux), _ = lax.scan(
-        body, (h, jnp.zeros((), dtype=h.dtype)), params["layers"]
-    )
-    h = rms_norm(h, params["ln_f"])
-    return h @ params["head"], aux
+    def stack(carry):
+        (h, aux), _ = lax.scan(body, carry, params["layers"])
+        return rms_norm(h, params["ln_f"], eps), aux
+
+    carry = (h, jnp.zeros((), dtype=h.dtype))
+    if not cfg.looped:
+        h, aux = stack(carry)
+        return h @ params["head"], aux
+
+    def one_pass(carry, _):
+        h, aux = stack(carry)
+        return (h, aux), h  # the next pass reads this pass's normed output
+
+    with jax.named_scope("looped_stack"):
+        (_, aux), exits = lax.scan(
+            one_pass, carry, None, length=cfg.n_loops
+        )  # exits: [T, B, L, d]
+    with jax.named_scope("exit_heads"):
+        gate = params["exit_gate"]
+        gates = exits.astype(jnp.float32) @ gate["w"].astype(
+            jnp.float32
+        ) + gate["b"].astype(jnp.float32)
+        return LoopedOutputs(
+            exits @ params["head"], gates[..., 0], cfg.exit_entropy_weight
+        ), aux
 
 
 def build_loss_fn(cfg: TransformerConfig, mesh: Mesh):
@@ -365,6 +519,8 @@ def build_loss_fn(cfg: TransformerConfig, mesh: Mesh):
 
         def plain_loss(params, tokens):
             logits, aux = plain_forward(cfg, params, tokens[:, :-1])
+            if cfg.looped:
+                return looped_exit_loss(logits, tokens[:, 1:])
             loss = token_cross_entropy(logits, tokens[:, 1:])
             if cfg.n_experts:
                 loss = loss + cfg.aux_weight * aux.astype(jnp.float32)

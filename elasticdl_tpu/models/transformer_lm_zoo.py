@@ -23,10 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from elasticdl_tpu.common.constants import WINDOW_STATS
 from elasticdl_tpu.models.record_codec import decode_token_records
 from elasticdl_tpu.models.transformer_lm import (
+    LoopedOutputs,
     TransformerConfig,
+    exit_stats,
     init_params,
+    looped_exit_loss,
     plain_forward,
     token_cross_entropy,
 )
@@ -42,9 +46,17 @@ class TransformerLM:
     def init(self, rng, tokens):
         seed = int(np.asarray(jax.random.key_data(rng)).ravel()[-1]) & 0x7FFFFFFF
         params = init_params(np.random.default_rng(seed), self.cfg)
-        return {"params": params}
+        if not self.cfg.looped:
+            return {"params": params}
+        return {
+            "params": params,
+            WINDOW_STATS: {
+                "exit_q": np.zeros((self.cfg.n_loops,), np.float32),
+                "expected_exit": np.zeros((), np.float32),
+            },
+        }
 
-    def apply(self, variables, tokens):
+    def apply(self, variables, tokens, mutable=None):
         # the vectorized scan-over-layers fast path for dense AND MoE
         # (capacity-bounded einsum dispatch, parallel/moe.moe_ffn_local).
         # MoE configs return (logits, aux): the Switch load-balance
@@ -54,6 +66,13 @@ class TransformerLM:
         # pair, mirroring the mesh path's build_loss_fn
         # (transformer_lm.py:243-253).
         logits, aux = plain_forward(self.cfg, variables["params"], tokens)
+        if self.cfg.looped:
+            # all T exits and gates go to loss(); the step's mean exit
+            # distribution is the new state of the non-trainable
+            # collection, which a training step asks for (`mutable`)
+            if mutable:
+                return logits, {WINDOW_STATS: exit_stats(logits.gates)}
+            return logits
         if self.cfg.n_experts:
             return logits, self.cfg.aux_weight * aux
         return logits
@@ -73,13 +92,18 @@ def dataset_fn(records, mode):
 
 
 def _split_outputs(outputs):
-    """(logits, weighted_aux) for MoE configs, (logits, 0) for dense."""
+    """(logits, weighted_aux) for MoE configs, (logits, 0) for dense;
+    a looped configuration is judged by its last exit."""
+    if isinstance(outputs, LoopedOutputs):
+        outputs = outputs.logits[-1]
     if isinstance(outputs, tuple):
         return outputs
     return outputs, jnp.zeros((), dtype=jnp.float32)
 
 
 def loss(outputs, labels):
+    if isinstance(outputs, LoopedOutputs):
+        return looped_exit_loss(outputs, labels)
     logits, aux = _split_outputs(outputs)
     return token_cross_entropy(logits, labels) + aux.astype(jnp.float32)
 

@@ -44,6 +44,7 @@ from elasticdl_tpu.api.model_spec import ModelSpec
 from elasticdl_tpu.common.constants import (
     ENV_BENCH_MFU,
     ENV_BET_PREFETCH,
+    ENV_HLO_SCOPES,
     ENV_OVERLAP_SYNC,
     ENV_SCHED_PHASE_SECS,
     ENV_SYNC_ADAPTIVE,
@@ -52,7 +53,9 @@ from elasticdl_tpu.common.constants import (
     ENV_SYNC_DEPTH,
     ENV_SYNC_DTYPE,
     ENV_SYNC_LOCAL_STEPS,
+    ENV_WORKER_LOG_DIR,
     MAX_MINIBATCH_RETRY_NUM,
+    WINDOW_STATS,
     Mode,
 )
 from elasticdl_tpu.common import codec
@@ -61,6 +64,7 @@ from elasticdl_tpu.common.linkprobe import LinkWeather
 from elasticdl_tpu.common.device import device_report
 from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.common.timing import PhaseTimers
+from elasticdl_tpu.obs import hlo_scopes
 from elasticdl_tpu.obs import trace as obs_trace
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
 from elasticdl_tpu.worker.task_data_service import (
@@ -1705,11 +1709,17 @@ class Worker:
         if self._local_window_fn is None:
             self._local_window_fn = self._build_local_window_fn()
         self._first_run_begins("window")
-        with self._first_call(self._local_window_fn):
+        first = self._first_call(self._local_window_fn)
+        with first:
             self._flat, self._opt_state, new_aux, loss = self._local_window_fn(
                 self._flat, self._opt_state, self._aux, features, labels
             )
         self._aux = new_aux or self._aux
+        if first is not _NO_SPAN:
+            self._write_scope_map(
+                self._local_window_fn,
+                (self._flat, self._opt_state, self._aux, features, labels),
+            )
         self._pending_steps += self._local_updates
         self._latest_step_loss = loss
         if self._pending_steps >= self._local_updates * self._sync_local_steps:
@@ -1982,6 +1992,18 @@ class Worker:
             with self._chain_span("worker.d2h", bytes=delta_f32_bytes):
                 delta_h, aux_h, loss_h, step_loss_h, gbets_h = (
                     jax.device_get(fetch)
+                )
+            stats = (aux_h or {}).get(WINDOW_STATS)
+            if stats:
+                # what the model's last step of the window left in its
+                # WINDOW_STATS collection (small vectors: a looped
+                # LM's mean exit distribution), on the timeline
+                now = time.time()
+                self.timers.record_span(
+                    "worker.window_stats", now, now, steps=steps, **{
+                        k: np.asarray(v, np.float64).round(6).tolist()
+                        for k, v in stats.items()
+                    },
                 )
             if wire_meta is not None:
                 # compressed payload: build the codec wire object
@@ -2588,6 +2610,37 @@ class Worker:
                 yield info
         finally:
             obs_trace.bind(prev)
+
+    def _write_scope_map(self, program, args):
+        """Where `EDL_HLO_SCOPES=1` asks for it (whoever takes a
+        device trace of this worker does): every HLO instruction of the
+        window program with its `op_name`, which carries the model's
+        `jax.named_scope`s, for the trace's readers
+        (obs/hlo_scopes.py). Called once, after the program's first
+        call and outside its `setup.program` span, with that call's
+        arguments: jax then hands back the lowering and the executable
+        of the call (45 ms for the looped LM's window program on the
+        v5e, whose compile is 36 s; the log line says what it took), so
+        nothing is lowered, compiled or loaded twice."""
+        log_dir = os.environ.get(ENV_WORKER_LOG_DIR, "")
+        if not log_dir or os.environ.get(ENV_HLO_SCOPES, "") != "1":
+            return
+        t0 = time.time()
+        try:
+            text = program.lower(*args).compile().as_text()
+            t_text = time.time()
+            path = os.path.join(log_dir, f"worker-{self._id}.hlo_scopes.json")
+            count = hlo_scopes.write(path, "jit_" + program.__name__, text)
+            logger.info(
+                "Worker %d: op_names of %d instructions of the window "
+                "program -> %s (the compiled text in %.3f s, the file in "
+                "%.3f s)", self._id, count, path, t_text - t0,
+                time.time() - t_text,
+            )
+        except Exception:  # a trace reader's aid must not stop training
+            logger.warning(
+                "Worker %d: no HLO scope map written", self._id, exc_info=True
+            )
 
     def _first_call(self, program):
         """`setup.program` around the FIRST call of a jitted program
