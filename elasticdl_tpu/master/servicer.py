@@ -61,13 +61,43 @@ def _is_shard_outage_exc(exc) -> bool:
     return False
 
 
-def _to_f32(tree):
-    return jax.tree_util.tree_map(
-        lambda a: np.asarray(a, dtype=np.float32)
-        if np.issubdtype(np.asarray(a).dtype, np.floating)
-        else np.asarray(a),
-        tree,
-    )
+def _own_f32(tree):
+    """The tree as the master adopts it: every leaf an array of its own
+    (writeable, C-contiguous; float leaves as float32), never the
+    caller's array or a view of a received frame, because
+    `report_local_update` adds into these leaves in place."""
+
+    def own(a):
+        a = np.asarray(a)
+        floating = np.issubdtype(a.dtype, np.floating)
+        return np.array(a, dtype=np.float32 if floating else None, order="C")
+
+    return jax.tree_util.tree_map(own, tree)
+
+
+def _add_delta(params, delta, scale: float):
+    """`p + scale * d` for every leaf, bit for bit (a float32 product,
+    then a float32 add; 1.0 * d is d), written into `p` where `p` is a
+    writeable float32 array. Any other leaf (read-only out of
+    `PSOptimizer.step`, not float32) is replaced by the freshly
+    allocated sum, and is added into from the next delta on. `delta` is
+    only read: it may be a view of a received frame. Returns the tree,
+    how many of its leaves were added in place, and how many it has."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    in_place = 0
+    steps = jax.tree_util.tree_leaves(delta)
+    for i, (p, d) in enumerate(zip(leaves, steps, strict=True)):
+        step = d if scale == 1.0 else scale * d
+        if (
+            isinstance(p, np.ndarray)
+            and p.dtype == np.float32
+            and p.flags.writeable
+        ):
+            np.add(p, step, out=p)
+            in_place += 1
+        else:
+            leaves[i] = np.asarray(p + step)
+    return treedef.unflatten(leaves), in_place, len(leaves)
 
 
 class MasterServicer:
@@ -136,7 +166,7 @@ class MasterServicer:
         self._lr_staleness_modulation = lr_staleness_modulation
         self._staleness_window = staleness_window
 
-        self._params = _to_f32(init_params) if init_params is not None else None
+        self._params = _own_f32(init_params) if init_params is not None else None
         # non-trainable collections (e.g. batch_stats) — restored from a
         # checkpoint alongside init_params, or lazily set by the first
         # worker's ReportVariable
@@ -590,7 +620,7 @@ class MasterServicer:
         with self._lock:
             first = self._params is None
             if first:
-                self._params = _to_f32(req["params"])
+                self._params = _own_f32(req["params"])
                 if req.get("aux") is not None:
                     self._aux = req["aux"]
                 if self._ps_group is not None:
@@ -739,7 +769,14 @@ class MasterServicer:
         For a single worker this is mathematically identical to
         per-step sync SGD — the delta is exactly the sum of its local
         updates — while moving the model over the wire once per window
-        instead of twice per minibatch."""
+        instead of twice per minibatch.
+
+        The model is updated IN PLACE (`_add_delta`): the delta is
+        added into the leaves the master holds, and nothing the size of
+        the model is allocated per sync. So every reader of
+        `self._params` must copy what it hands out while it holds
+        `self._lock`; a reference to a leaf kept across the lock is a
+        torn read. The request's buffer is only read."""
         if self._ps_group is not None:
             raise ValueError(
                 "sharded PS: deltas go to the shard endpoints "
@@ -789,10 +826,8 @@ class MasterServicer:
             # SparseDelta) decode to the dense f32 vector here
             delta = self._unravel_model(codec.delta_to_f32(req["delta_flat"]))
             t_decoded = time.time()
-            self._params = jax.tree_util.tree_map(
-                lambda p, d: p + scale * np.asarray(d, dtype=np.float32),
-                self._params,
-                delta,
+            self._params, in_place, leaves = _add_delta(
+                self._params, delta, scale
             )
             if aux_state is not None:
                 self._aux = aux_state
@@ -826,7 +861,7 @@ class MasterServicer:
                 "local_update", t_apply, t_locked, t_decoded, t_applied,
                 time.time(), resp,
             )
-        self._record_update(*marks)
+        self._record_update(*marks, in_place=in_place, leaves=leaves)
         # lock wait + apply, retro-recorded under the server span (the
         # duplicate early-return above deliberately skips it)
         obs_trace.record_event(
@@ -1028,18 +1063,24 @@ class MasterServicer:
         return resp
 
     def _record_update(
-        self, kind, t_enter, t_locked, t_decoded, t_applied, t_encoded, resp
+        self, kind, t_enter, t_locked, t_decoded, t_applied, t_encoded, resp,
+        **apply_args
     ):
         """One update's phases from the marks taken under the model
         lock, recorded once it is released: the wait for the lock, the
         update decoded, the apply (`kind: accumulate` for a gradient
-        that only joined the sum: no step was taken), and the model
-        raveled for the way down where one goes."""
+        that only joined the sum: no step was taken; of a local update,
+        `apply_args` say how many `leaves` the model has and into how
+        many the delta was added `in_place`), and the model raveled for
+        the way down where one goes."""
         version = resp.get("version")
         record = self.timers.record
         record("apply_wait", t_enter, t_locked, kind=kind, version=version)
         record("grad_decode", t_locked, t_decoded, kind=kind, version=version)
-        record("apply", t_decoded, t_applied, kind=kind, version=version)
+        record(
+            "apply", t_decoded, t_applied, kind=kind, version=version,
+            **apply_args,
+        )
         if resp.get("params_flat") is not None:
             record(
                 "model_encode", t_applied, t_encoded, kind=kind,
