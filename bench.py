@@ -228,11 +228,8 @@ def _pull_fanout_cell(
 
     Prices the prepacked model-down path: the shard encodes each
     (version, wire-form) once and serves every pull of that version
-    from the cached frame. Over shm the frame is published into a
-    broadcast segment that each puller maps — the serve path performs
-    ZERO payload copies (asserted via the shard's encode-copy counter);
-    over uds the shared frame is still encoded once but each response
-    pays a socket write. Returns the prepack counters + pulls/sec."""
+    from the cached frame; each response pays a socket write. Returns
+    the prepack counters + pulls/sec."""
     import threading
 
     import numpy as np
@@ -248,7 +245,6 @@ def _pull_fanout_cell(
         servicer = PSShardServicer(0, 1)
         server = RpcServer(servicer.handlers(), port=0)
         servicer.attach_wire_stats(server.wire)
-        servicer.attach_shm_publisher(server.shm_broadcaster)
         server.start()
         endpoint = f"localhost:{server.port}"
         init = RpcClient(endpoint)
@@ -297,11 +293,6 @@ def _pull_fanout_cell(
     # (first-pull races can encode more than once; each must still
     # serve >= N pulls on average)
     assert served // max(1, encodes) >= n_workers, (served, encodes)
-    if tier == "shm":
-        assert copied == 0, (
-            f"shm pull-serve path copied {copied} payload bytes — the "
-            "broadcast publish must pack straight into the segment"
-        )
     return {
         "pulls_per_sec": round(served / elapsed, 1),
         "prepack_encodes": encodes,
@@ -620,14 +611,11 @@ def main():
     )
 
     # ---- transport tiers: co-located fast paths vs gRPC ----
-    # Same short job over the inproc, uds and shm tiers; the per-tier
-    # wire rollup must show the timed region riding the fast path — any
-    # bytes under "grpc" mean the tier silently fell back. The shm tier
-    # additionally asserts ZERO uds bytes: its frames move through
-    # mapped rings, and the doorbell socket carries only handshakes
-    # (which WireStats never counts as uds traffic).
+    # Same short job over the inproc and uds tiers; the per-tier wire
+    # rollup must show the timed region riding the fast path — any
+    # bytes under "grpc" mean the tier silently fell back.
     tier_runs = {}
-    for tier in ("inproc", "uds", "shm"):
+    for tier in ("inproc", "uds"):
         t_imgs, t_worker, _ = run_job(
             model_module,
             path,
@@ -648,15 +636,6 @@ def main():
             f"{tier} tier leaked {grpc_bytes} bytes onto gRPC — "
             "co-located fast path silently fell back"
         )
-        if tier == "shm":
-            uds_row = tr.get("uds") or {}
-            uds_bytes = (
-                uds_row.get("bytes_sent", 0) + uds_row.get("bytes_received", 0)
-            )
-            assert uds_bytes == 0, (
-                f"shm tier leaked {uds_bytes} bytes onto uds — "
-                "ring path silently fell back to the socket tier"
-            )
         tier_runs[tier] = {
             "images_per_sec": round(t_imgs, 1),
             "bytes_per_sync_up": t_worker.wire_summary["bytes_per_sync_up"],
@@ -670,14 +649,11 @@ def main():
             file=sys.stderr,
         )
 
-    # ---- prepacked model-down broadcast: pull fan-out shm vs uds ----
+    # ---- prepacked model-down: pull fan-out ----
     # N clients pulling the same PS model version: the prepack cache
     # encodes each (version, wire-form) ONCE and serves the whole
-    # fan-out from it; over shm the payload additionally rides a
-    # broadcast segment every puller maps (0 encode copies, asserted).
-    pull_fanout = {
-        tier: _pull_fanout_cell(tier) for tier in ("uds", "shm")
-    }
+    # fan-out from it.
+    pull_fanout = {"uds": _pull_fanout_cell("uds")}
     for tier, cell in pull_fanout.items():
         print(
             f"bench[pull-fanout {tier}]: {cell['pulls_per_sec']} pulls/s; "
@@ -1024,12 +1000,10 @@ def main():
         "compressed_bytes_per_sync_ratio_vs_f32": compress_ratio,
         # co-located transport fast paths: each run's wire
         # rollup is split per tier; grpc_bytes_total == 0 is
-        # asserted above (no silent fallback), and the shm run
-        # additionally asserted 0 uds bytes
+        # asserted above (no silent fallback)
         "transport_tiers": tier_runs,
-        # prepacked model-down broadcast: N pullers served from
-        # one cached encode per (version, wire-form); the shm
-        # cell asserted 0 payload-copy bytes on the serve path
+        # prepacked model-down: N pullers served from one cached
+        # encode per (version, wire-form)
         "pull_fanout": pull_fanout,
         "deepfm_sparse_window_records_per_sec": dfm_recs_per_sec,
         "deepfm_bet_prefetch_ab": dfm_pair,
@@ -1118,14 +1092,12 @@ def main():
             "EF sync plane priced against wire_f32_baseline "
             "(same job shape, f32 wire), convergence-gated "
             "like the headline; transport_tiers re-runs the "
-            "short window job over the co-located inproc, uds "
-            "and shm fast paths with the per-tier byte split "
-            "(grpc bytes asserted 0 — no silent fallback; the "
-            "shm run also asserts 0 uds bytes). pull_fanout "
-            "prices the prepacked model-down broadcast: 8 "
+            "short window job over the co-located inproc and "
+            "uds fast paths with the per-tier byte split "
+            "(grpc bytes asserted 0 — no silent fallback). "
+            "pull_fanout prices the prepacked model-down: 8 "
             "clients x 16 pulls of one 4 MB model version, "
-            "served from one cached encode (over shm via a "
-            "mapped broadcast segment, 0 payload copies). "
+            "served from one cached encode. "
             "overlap_ab is the overlap-plane A/B (16 exact-fit "
             "windows, traced): sync_exposed_fraction is the "
             "span-measured share of step-loop wall spent "

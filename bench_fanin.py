@@ -31,14 +31,11 @@ asserts version == applied_pushes (no report lost or double-applied).
 Grid: wire in {f32 (dense 4 MB slice), topk (1% top-k sparse over the
 same slice)} x N in {8, 64, 256} x tier x core in {blocking (threads
 dispatch, no combine), loop_combine}. The inproc tier runs both wires;
-the uds and shm tiers run ONLY the topk wire — shipping dense 4 MB
-frames through a socket/ring measures memcpy throughput, not dispatch
-(both cores bottleneck on moving the same bytes), and the compressed
-wire tier exists precisely because raw bytes are the socket-path
-bottleneck (see docs/performance.md). The shm tier moves each frame
-through a per-connection shared-memory ring (one doorbell wake per
-call, no kernel copy of the payload), so its columns price the
-zero-copy transport against uds on identical requests. The acceptance
+the uds tier runs ONLY the topk wire — shipping dense 4 MB frames
+through a socket measures memcpy throughput, not dispatch (both cores
+bottleneck on moving the same bytes), and the compressed wire tier
+exists precisely because raw bytes are the socket-path bottleneck
+(see docs/performance.md). The acceptance
 bar is the N=256 speedup of loop_combine over blocking on the same
 machine (>= 4x on the best cell; the top-k cell is the headline — that
 is the wire form fan-in-at-scale deployments ship).
@@ -61,12 +58,10 @@ import numpy as np
 
 DEFAULT_NS = (8, 64, 256)
 #: tier -> wire forms benched on it (module docstring: dense frames
-#: over a socket measure memcpy, not dispatch, so the socket-shaped
-#: tiers — uds and the shared-memory ring tier — run topk only)
+#: over a socket measure memcpy, not dispatch, so uds runs topk only)
 DEFAULT_GRID = (
     ("inproc", ("f32", "topk")),
     ("uds", ("topk",)),
-    ("shm", ("topk",)),
 )
 DEFAULT_SLICE = 1 << 20  # 4 MB of f32 per report — a realistic PS slice
 TOPK_DENSITY = 0.01
@@ -192,8 +187,7 @@ def run_cell(
         ]
         stats = servicer.stats()
         version = stats["version"]
-        # which tiers actually carried the cell (the shm smoke asserts
-        # 0 grpc/uds bytes — no silent fallback to a socket path)
+        # which tiers actually carried the cell
         transports = server.wire_stats().get("transports", {})
     finally:
         try:
@@ -299,7 +293,7 @@ def run_tree_cell(
     n_workers: int = TREE_N,
     n_aggs: int = TREE_H,
     *,
-    tier: str = "shm",
+    tier: str = "uds",
     upstream: str = "uds",
     wire: str = "topk",
     slice_len: int = DEFAULT_SLICE,
@@ -318,11 +312,11 @@ def run_tree_cell(
     master launches), so the member decode + presum + fan-back work
     that the flat core burns on the master's interpreter runs on the
     aggregator hosts' own CPUs, exactly the offload the tree buys in
-    production. worker->aggregator rides `tier` (shm — intra-host,
-    zero socket bytes), aggregator->PS is pinned to `upstream`
-    (uds — the cross-host stand-in; select_transport's per-link tier
-    override). The PS runs the SAME loop+combine core as the flat
-    comparator, so the delta is purely the tree.
+    production. worker->aggregator rides `tier` (uds — the carrier a
+    local peer gets), aggregator->PS is pinned to `upstream`
+    (select_transport's per-link tier override). The PS runs the SAME
+    loop+combine core as the flat comparator, so the delta is purely
+    the tree.
 
     Two measurements per cell:
     - a synchronized fan-in round: every worker pushes exactly once
@@ -582,11 +576,11 @@ def run_suite(
     if tree_cell:
         n, h = tree_cell
         flat = run_cell(
-            n, "shm", dispatch="loop", combine=True, wire="topk",
+            n, "uds", dispatch="loop", combine=True, wire="topk",
             slice_len=slice_len, warmup_s=warmup_s, window_s=window_s,
         )
         cell = run_tree_cell(
-            n, h, tier="shm", upstream="uds", wire="topk",
+            n, h, tier="uds", upstream="uds", wire="topk",
             slice_len=slice_len, warmup_s=warmup_s, window_s=window_s,
         )
         assert flat["version"] == flat["applied_pushes"]

@@ -1,7 +1,7 @@
 """Cross-host aggregation tree (host-local presum aggregators).
 
-An aggregator node terminates its host's worker pushes over the shm
-tier, presums each rendezvoused cohort with the fan-in math
+An aggregator node terminates its host's worker pushes over the
+host's local carrier, presums each rendezvoused cohort with the fan-in math
 (master/fanin.presum_f32), and forwards ONE combined delta per cohort
 upstream to the PS shard — dropping master fan-in degree from #workers
 to #hosts. See agg/aggregator.py for the protocol and

@@ -4,7 +4,8 @@ Runs one `AggregatorServicer` (agg/aggregator.py) behind an RPC
 endpoint: the host-local combine/forward rung of the aggregation tree.
 Spawned by the master's `AggGroup` in process mode — one per worker
 host in a real deployment, so the workers' pushes terminate over the
-shm tier and only the combined deltas cross the host boundary.
+host's local carrier and only the combined deltas cross the host
+boundary.
 
 The node is model-oblivious (it sums decoded f32 slices), so unlike
 ps_shard_main there is no model-spec flag subset — just the slot
@@ -47,11 +48,6 @@ def agg_parser() -> argparse.ArgumentParser:
         "rpc/fencing.py)",
     )
     p.add_argument(
-        "--shm_scope", default="",
-        help="shm-tier segment namespace for this slot (stable across "
-        "relaunches within a job — rpc/transport.ShmServer)",
-    )
-    p.add_argument(
         "--log_level", default="info",
         help="root logger level for this process",
     )
@@ -79,15 +75,9 @@ def main(argv=None) -> int:
         endpoints,
         generation=args.generation,
     )
-    server = RpcServer(
-        servicer.handlers(),
-        port=args.port,
-        shm_scope=args.shm_scope or None,
-        shm_generation=args.generation,
-    )
+    server = RpcServer(servicer.handlers(), port=args.port)
     servicer.attach_wire_stats(server.wire)
     servicer.attach_admission_stats(server.admission_stats)
-    servicer.attach_shm_publisher(server.shm_broadcaster)
     servicer.register_metrics()
 
     from elasticdl_tpu.obs import flight

@@ -6,9 +6,8 @@ wire bytes into the master's link — scale with fleet size. The
 aggregator moves that same combine stage onto the worker's host (the
 BytePS-style hierarchical-PS shape; Horovod's hierarchical allreduce
 is the collective-side analog): workers push per-shard window deltas
-to their host aggregator over the shm tier (zero intra-host socket
-bytes), the aggregator presums each rendezvoused cohort with the
-IDENTICAL `fanin.presum_f32` math (dense cache-blocked adds, int8
+to their host aggregator over the local carrier, the aggregator
+presums each rendezvoused cohort with the IDENTICAL `fanin.presum_f32` math (dense cache-blocked adds, int8
 dequant, top-k scatter-add — bitwise-identical to the serial
 interleaving for exactly-representable values), and forwards ONE
 combined delta per cohort upstream over uds/grpc carrying the member
@@ -93,8 +92,7 @@ def agg_wait_s(env=None) -> float:
 def upstream_tier(env=None) -> str:
     """Transport tier for the aggregator->PS leg (default uds: Unix
     socket when the shard resolves local, else the selector's grpc
-    fallback — the socket half of the shm-intra-host / socket-upstream
-    split)."""
+    fallback)."""
     env = os.environ if env is None else env
     return (env.get(ENV_AGG_UPSTREAM_TIER, "") or "uds").strip().lower()
 
@@ -102,8 +100,8 @@ def upstream_tier(env=None) -> str:
 class AggregatorServicer:
     """One aggregator node: worker-facing AggPushDelta surface plus the
     upstream forward clients, one per PS shard. Served behind the same
-    RpcServer/ServerDispatcher stack as a PS shard (shm tier, loop
-    core, admission queues, chaos hooks all reused)."""
+    RpcServer/ServerDispatcher stack as a PS shard (local carrier,
+    loop core, admission queues, chaos hooks all reused)."""
 
     #: obs reads answer for the PROCESS (postmortems want them from a
     #: fenced node); AggStats is the bench/test counters surface and
@@ -148,7 +146,6 @@ class AggregatorServicer:
         self._upstream_errors = 0
         self._wire = None
         self._admission_fn = None
-        self._shm_pub = None
 
     # -- handler table -------------------------------------------------------
 
@@ -294,8 +291,7 @@ class AggregatorServicer:
                 from elasticdl_tpu.rpc.client import RpcClient
 
                 # per-link tier: uds/grpc upstream regardless of the
-                # ambient EDL_TRANSPORT (which keeps the worker-facing
-                # side on shm) — rpc/client.py `transport=`
+                # ambient EDL_TRANSPORT — rpc/client.py `transport=`
                 c = RpcClient(
                     self._ps_endpoints[shard], transport=self._tier
                 )
@@ -376,26 +372,12 @@ class AggregatorServicer:
         # one serialization for the whole cohort: every member's base
         # fell behind the combined version, so every member gets the
         # merged slice — identical bytes, shared by reference (the
-        # same prepacked fan-out the PS-side combine stage does). On
-        # the shm tier the frame is published ONCE into a read-only
-        # broadcast segment and each member's response carries only
-        # the tiny marker (rpc/transport broadcast substitution) — the
-        # intra-host fan-back costs one encode, not k ring copies.
+        # same prepacked fan-out the PS-side combine stage does)
         from elasticdl_tpu.common import messages
 
-        obj = {"version": resp["version"], "vec": resp["vec"]}
-        shared = None
-        with self._lock:
-            shm_pub = self._shm_pub
-        if shm_pub is not None:
-            pub = shm_pub.publish(obj)
-            if pub is not None:
-                ref, view = pub
-                shared = messages.Prepacked(
-                    source=lambda v=view: v, shm_ref=ref
-                )
-        if shared is None:
-            shared = messages.Prepacked(messages.pack(obj))
+        shared = messages.Prepacked(
+            messages.pack({"version": resp["version"], "vec": resp["vec"]})
+        )
         for m in members:
             m.resp = shared
 
@@ -447,15 +429,6 @@ class AggregatorServicer:
         with self._lock:
             self._admission_fn = fn
 
-    def attach_shm_publisher(self, pub):
-        """Point cohort fan-back at the hosting RpcServer's shm
-        broadcast publisher (RpcServer.shm_broadcaster), same contract
-        as PSShardServicer.attach_shm_publisher; None when the shm
-        tier is off. Guarded like attach_wire_stats: the combiner
-        thread reads this mid-flight in _forward_batch."""
-        with self._lock:
-            self._shm_pub = pub
-
     def stats(self) -> Dict[str, Any]:
         with self._lock:
             out = {
@@ -474,8 +447,7 @@ class AggregatorServicer:
             out["bytes_sent"] = snap["bytes_sent"]
             out["bytes_received"] = snap["bytes_received"]
             # per-tier rows so a remote caller (bench smoke, operator)
-            # can verify the worker-facing side really rode shm — zero
-            # socket-tier bytes is the intra-host acceptance bar
+            # can see which carrier the worker-facing side rode
             out["transports"] = snap.get("transports", {})
         if admission_fn is not None:
             adm = admission_fn()

@@ -17,7 +17,6 @@ new PS endpoint list after a PS relaunch.
 from __future__ import annotations
 
 import subprocess
-import uuid
 from typing import List, Optional
 
 from elasticdl_tpu.common.log_util import get_logger
@@ -47,9 +46,6 @@ class AggGroup:
         # fencing generation per aggregator SLOT, bumped on relaunch;
         # workers stamp these as AggPushDelta epochs (rpc/fencing.py)
         self.generations: List[int] = [0] * num_aggs
-        # shm-tier segment namespace, per-job nonce stable per slot
-        # across relaunches (same reclamation contract as ps_group)
-        self._shm_ns = uuid.uuid4().hex[:8]
         self._servers = []  # inproc RpcServers
         self.servicers = []  # inproc servicer refs (tests read stats())
         self._procs: List[subprocess.Popen] = []
@@ -91,7 +87,6 @@ class AggGroup:
         flags = [
             "--agg_id", str(agg_id),
             "--generation", str(self.generations[agg_id]),
-            "--shm_scope", f"{self._shm_ns}.agg{agg_id}",
             "--ps_endpoints", ",".join(self._ps_endpoints),
         ]
         return flags
@@ -105,15 +100,9 @@ class AggGroup:
             self._ps_endpoints,
             generation=self.generations[i],
         )
-        server = RpcServer(
-            servicer.handlers(),
-            port=0,
-            shm_scope=f"{self._shm_ns}.agg{i}",
-            shm_generation=self.generations[i],
-        )
+        server = RpcServer(servicer.handlers(), port=0)
         servicer.attach_wire_stats(server.wire)
         servicer.attach_admission_stats(server.admission_stats)
-        servicer.attach_shm_publisher(server.shm_broadcaster)
         servicer.register_metrics()
         server.start()
         return servicer, server

@@ -550,7 +550,7 @@ class Analysis:
         self, cls: Tuple[str, str], attr: str
     ) -> Optional[Tuple[str, ...]]:
         """The close-like call chain that releases `cls`.`attr`, or
-        None: ('ShmServer.close', 'self._sock'). Used by findings and
+        None: ('UdsServer.close', 'self._sock'). Used by findings and
         pinned by the repo cross-check tests."""
         path, cname = cls
         info = self.g.classes.get(cls)

@@ -22,8 +22,8 @@ thread/lock structure the callgraph already knows:
                            thread only ever performs blocking reads
                            (``accept``/``recv``/``get``/...) on the
                            attribute, closing it first is exactly how
-                           you unblock the loop (ShmServer/UdsServer do
-                           this deliberately). Anything else — sends,
+                           you unblock the loop (UdsServer does this
+                           deliberately). Anything else — sends,
                            dispatches, state updates — races the close.
 - ``double-close-unsafe``  a close path unlinks a file/segment with no
                            guard (``try/except``, ``missing_ok=True``,

@@ -689,12 +689,7 @@ def unravel_np(vec: np.ndarray, template) -> Any:
 def dumps_parts(obj: Any):
     """Serialize a pytree as an ordered list of v2-frame parts (buffer
     views of the source arrays plus the prefix/header/pad bytes) and
-    the total frame length. `b"".join(parts)` IS the frame; a caller
-    holding a mapped destination (the shm transport's broadcast
-    segments, rpc/transport.py) instead sizes the destination from the
-    total and writes the parts in place via `write_frame_into` — the
-    descriptor header and the 64-byte-aligned payload segments land
-    directly in shared memory with no intermediate wire buffer."""
+    the total frame length. `b"".join(parts)` IS the frame."""
     builder = _FrameBuilder()
     tree = _build_frame_tree(obj, builder)
     header = msgpack.packb(
@@ -714,25 +709,6 @@ def dumps_parts(obj: Any):
         parts.append(seg)
         total += pad + seg.nbytes
     return parts, total
-
-
-def write_frame_into(parts, total: int, buf) -> int:
-    """Write `dumps_parts` output into a writable buffer (e.g. a mapped
-    shared-memory segment) and return the frame length. The segment
-    writes here are the frame's single materialization — the same copy
-    `dumps` pays in its final join, just landing in the destination
-    mapping instead of a private bytes object."""
-    view = memoryview(buf)
-    if total > len(view):
-        raise ValueError(
-            f"frame of {total} bytes exceeds destination of {len(view)}"
-        )
-    off = 0
-    for p in parts:
-        pv = memoryview(p).cast("B")
-        view[off:off + len(pv)] = pv
-        off += len(pv)
-    return off
 
 
 def dumps(obj: Any) -> bytes:

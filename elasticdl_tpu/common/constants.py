@@ -87,8 +87,6 @@ ENV_SYNC_ADAPTIVE = "EDL_SYNC_ADAPTIVE"
 ENV_SYNC_BUCKET_BYTES = "EDL_SYNC_BUCKET_BYTES"
 ENV_TRANSPORT = "EDL_TRANSPORT"
 ENV_UDS_DIR = "EDL_UDS_DIR"
-ENV_TRANSPORT_SHM_RING = "EDL_TRANSPORT_SHM_RING_BYTES"
-ENV_TRANSPORT_SHM_DOORBELL_TIMEOUT = "EDL_TRANSPORT_SHM_DOORBELL_TIMEOUT"
 ENV_DISPATCH = "EDL_DISPATCH"
 ENV_DISPATCH_EXECUTOR = "EDL_DISPATCH_EXECUTOR"
 ENV_QUEUE_DEPTH_REPORT = "EDL_QUEUE_DEPTH_REPORT"
@@ -200,31 +198,18 @@ ENV_REGISTRY = {
         "gRPC and a client whose endpoint resolves to this host, with "
         "that socket file present, is carried by it; a remote endpoint "
         "gets gRPC. Explicit values: grpc (pure gRPC, no listener), uds, "
-        "shm (shared-memory rings with a UDS doorbell — codec frames "
-        "never cross a socket), inproc (same-interpreter direct "
-        "dispatch), or auto (prefer inproc, then shm, then uds, then "
-        "grpc); every non-grpc tier applies only to a local endpoint "
-        "whose counterpart is there, and one that cannot connect hands "
+        "inproc (same-interpreter direct dispatch), or auto (prefer "
+        "inproc, then uds, then grpc); every non-grpc tier applies "
+        "only to a local endpoint whose counterpart is there, and one "
+        "that cannot connect hands "
         "the call to gRPC (rpc/transport.py)"
     ),
     ENV_UDS_DIR: (
         "directory for the Unix-socket carrier's sockets "
         "(edl-uds-<port>.sock, one per RpcServer; a boot sweeps those "
-        "of dead servers) and the shm tier's doorbell sockets + "
-        "rendezvous files (edl-shm-<port>.{sock,json}); default: the "
-        "system temp dir — must be shared by co-located processes; may "
-        "be deeper than an AF_UNIX address holds"
-    ),
-    ENV_TRANSPORT_SHM_RING: (
-        "shm tier: per-direction ring capacity in bytes for each "
-        "connection's shared-memory segment (default 4194304 = 4 MiB, "
-        "rounded up to the 64-byte codec segment alignment); frames "
-        "larger than the ring fall back to a chunked copy path"
-    ),
-    ENV_TRANSPORT_SHM_DOORBELL_TIMEOUT: (
-        "shm tier: seconds for doorbell handshake and chunk-ack socket "
-        "operations (default 5.0); per-call deadlines still come from "
-        "the caller's RPC timeout budget"
+        "of dead servers); default: the system temp dir — must be "
+        "shared by co-located processes; may be deeper than an AF_UNIX "
+        "address holds"
     ),
     ENV_DISPATCH: (
         "server dispatch core: threads (default; blocking "
@@ -280,10 +265,9 @@ ENV_REGISTRY = {
     ENV_AGG_UPSTREAM_TIER: (
         "aggregation tree: transport tier for the aggregator->PS "
         "upstream link (default uds = Unix socket when the PS resolves "
-        "local, else grpc; grpc forces sockets; shm/inproc/auto as in "
+        "local, else grpc; grpc forces gRPC; inproc/auto as in "
         "EDL_TRANSPORT) — the worker->aggregator leg keeps following "
-        "EDL_TRANSPORT, so shm intra-host + sockets upstream is the "
-        "default split"
+        "EDL_TRANSPORT"
     ),
     ENV_BENCH_LINK_FLOOR: (
         "bench.py: probed link-bandwidth floor in MB/s below which a "

@@ -522,35 +522,15 @@ class Prepacked:
     bytes to each member's transport turns k response serializations
     into one. `pack` passes the bytes through untouched.
 
-    Two extensions carry the shm broadcast plane (rpc/transport.py):
-    `shm_ref` names a published read-only broadcast segment holding
-    these same frame bytes — the shm tier answers with a tiny marker
-    the client resolves against its own mapping instead of moving the
-    frame — and `source` defers materializing `data` until a
-    socket-bound tier actually needs a private bytes object (the
-    broadcast encode writes the frame straight into the segment, so
-    shm-only fan-out never pays the join).
-
     Mapping-style reads (`resp["vec"]`, `resp.get(...)`) decode the
     frame lazily, so a handler returning Prepacked still duck-types as
     its response dict for direct (non-RPC) callers."""
 
-    __slots__ = ("_data", "_source", "_obj", "shm_ref")
+    __slots__ = ("data", "_obj")
 
-    def __init__(self, data: Optional[bytes] = None, source=None,
-                 shm_ref: Optional[dict] = None):
-        if data is None and source is None:
-            raise ValueError("Prepacked needs frame bytes or a source")
-        self._data = data
-        self._source = source
+    def __init__(self, data: bytes):
+        self.data = data
         self._obj = None
-        self.shm_ref = shm_ref
-
-    @property
-    def data(self) -> bytes:
-        if self._data is None:
-            self._data = bytes(self._source())
-        return self._data
 
     def _decoded(self) -> Any:
         if self._obj is None:

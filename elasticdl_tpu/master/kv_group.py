@@ -18,7 +18,6 @@ from __future__ import annotations
 import os
 import subprocess
 import time
-import uuid
 from typing import List, Optional
 
 from elasticdl_tpu.common.log_util import get_logger
@@ -51,8 +50,6 @@ class KVShardGroup:
         # fencing generation per shard slot (rpc/fencing.py), bumped on
         # every relaunch
         self.generations: List[int] = [0] * num_shards
-        # shm-tier segment namespace, same contract as PSShardGroup
-        self._shm_ns = uuid.uuid4().hex[:8]
         self._servers = []
         # inproc servicer refs (tests/recovery read stats, drive flush)
         self.servicers = []
@@ -104,12 +101,7 @@ class KVShardGroup:
         servicer = KVShardServicer(
             i, self._n, generation=self.generations[i]
         )
-        server = RpcServer(
-            servicer.handlers(),
-            port=0,
-            shm_scope=f"{self._shm_ns}.kv{i}",
-            shm_generation=self.generations[i],
-        )
+        server = RpcServer(servicer.handlers(), port=0)
         servicer.attach_admission_stats(server.admission_stats)
         servicer.attach_wire_stats(server.wire)
         servicer.register_metrics()
@@ -121,7 +113,6 @@ class KVShardGroup:
             "--shard_id", str(i),
             "--num_shards", str(self._n),
             "--generation", str(self.generations[i]),
-            "--shm_scope", f"{self._shm_ns}.kv{i}",
         ]
 
     def _start_process(self):

@@ -44,12 +44,6 @@ def kv_shard_parser() -> argparse.ArgumentParser:
         "requests carrying a different epoch are rejected — "
         "rpc/fencing.py)",
     )
-    p.add_argument(
-        "--shm_scope", default="",
-        help="shm-tier segment namespace for this shard slot (stable "
-        "across relaunches within a job; keys boot-time segment "
-        "reclamation — rpc/transport.ShmServer)",
-    )
     return p
 
 
@@ -72,12 +66,7 @@ def main(argv=None) -> int:
     servicer = KVShardServicer(
         args.shard_id, args.num_shards, generation=args.generation
     )
-    server = RpcServer(
-        servicer.handlers(),
-        port=args.port,
-        shm_scope=args.shm_scope or None,
-        shm_generation=args.generation,
-    )
+    server = RpcServer(servicer.handlers(), port=args.port)
     servicer.attach_admission_stats(server.admission_stats)
     servicer.attach_wire_stats(server.wire)
     servicer.register_metrics()

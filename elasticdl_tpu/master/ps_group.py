@@ -33,7 +33,6 @@ from __future__ import annotations
 import os
 import subprocess
 import time
-import uuid
 from typing import List, Optional
 
 import numpy as np
@@ -91,11 +90,6 @@ class PSShardGroup:
         # fencing generation per shard SLOT, bumped on every relaunch;
         # clients stamp these as request epochs (rpc/fencing.py)
         self.generations: List[int] = [0] * num_shards
-        # shm-tier segment namespace: one job nonce so concurrent jobs
-        # on a host never collide, stable per slot across relaunches so
-        # the relaunch (at its bumped generation) can sweep a SIGKILLed
-        # predecessor's segments (rpc/transport.ShmServer)
-        self._shm_ns = uuid.uuid4().hex[:8]
         self._servers = []  # inproc RpcServers
         # inproc servicer refs: tests/operators read stats() (e.g. the
         # chaos e2e asserts the dedup ring absorbed retried pushes)
@@ -146,7 +140,6 @@ class PSShardGroup:
             "--dedup_cap", str(self._dedup_cap),
             "--grads_to_wait", str(self._sync_flags["grads_to_wait"]),
             "--staleness_window", str(self._sync_flags["staleness_window"]),
-            "--shm_scope", f"{self._shm_ns}.ps{shard_id}",
         ] + self._shard_argv
         if self._sync_flags["use_async"]:
             flags.append("--use_async")
@@ -207,15 +200,9 @@ class PSShardGroup:
             fanin_combine=self._fanin_combine,
             **self._sync_flags,
         )
-        server = RpcServer(
-            servicer.handlers(),
-            port=0,
-            shm_scope=f"{self._shm_ns}.ps{i}",
-            shm_generation=self.generations[i],
-        )
+        server = RpcServer(servicer.handlers(), port=0)
         servicer.attach_wire_stats(server.wire)
         servicer.attach_admission_stats(server.admission_stats)
-        servicer.attach_shm_publisher(server.shm_broadcaster)
         servicer.register_metrics()
         server.start()
         return servicer, server

@@ -181,12 +181,6 @@ class PSShardServicer:
         self._prepack_encodes = 0
         self._prepack_served = 0
         self._prepack_copy_bytes = 0
-        # shm broadcast publisher (rpc/server.RpcServer.shm_broadcaster),
-        # attached like the wire stats; when present, prepacked pull
-        # frames are published once into a per-version read-only
-        # segment every co-located client maps — N pulls, one encode,
-        # zero payload copies
-        self._shm_pub = None
 
     # -- handler table -------------------------------------------------------
 
@@ -443,13 +437,9 @@ class PSShardServicer:
         self, version: int, vec: np.ndarray, form: str
     ) -> messages.Prepacked:
         """One pull frame for (version, form). f32 packs the live slice
-        directly (zero-copy into the frame / broadcast segment — the
-        caller's version recheck covers the unlocked read); other wire
-        forms pay their dtype conversion once per version. With the shm
-        publisher attached the frame is written straight into a
-        broadcast segment and the Prepacked carries its descriptor; the
-        frame bytes for non-shm tiers materialize lazily from the
-        mapped view."""
+        directly (zero-copy into the frame — the caller's version
+        recheck covers the unlocked read); other wire forms pay their
+        dtype conversion once per version."""
         with obs_trace.span(
             "ps.prepack_encode",
             cat="ps",
@@ -460,17 +450,9 @@ class PSShardServicer:
                 if form == "float32"
                 else vec.astype(codec.dtype_from_str(form))
             )
-            obj = {"version": version, "vec": arr}
-            with self._lock:
-                shm_pub = self._shm_pub
-            if shm_pub is not None:
-                pub = shm_pub.publish(obj)
-                if pub is not None:
-                    ref, view = pub
-                    return messages.Prepacked(
-                        source=lambda v=view: v, shm_ref=ref
-                    )
-            return messages.Prepacked(messages.pack(obj))
+            return messages.Prepacked(
+                messages.pack({"version": version, "vec": arr})
+            )
 
     def push_grad(self, req: dict) -> dict:
         """Per-step gradient slice. Async mode applies immediately
@@ -923,15 +905,6 @@ class PSShardServicer:
         (RpcServer.admission_stats), same contract as
         attach_wire_stats."""
         self._admission_fn = fn
-
-    def attach_shm_publisher(self, pub):
-        """Point pull prepacking at the hosting RpcServer's shm
-        broadcast publisher (RpcServer.shm_broadcaster), same contract
-        as attach_wire_stats; pass None when the shm tier is off.
-        Guarded: handler threads read the reference mid-flight in
-        _encode_pull_entry, and attachment happens after bind."""
-        with self._lock:
-            self._shm_pub = pub
 
     def stats(self) -> Dict[str, int]:
         """Push accounting (exactness evidence for the chaos tests):

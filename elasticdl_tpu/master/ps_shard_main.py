@@ -65,13 +65,6 @@ def ps_shard_parser() -> argparse.ArgumentParser:
         "pushes outside the shard lock (master/fanin.py; default "
         "honors EDL_FANIN_COMBINE)",
     )
-    p.add_argument(
-        "--shm_scope", default="",
-        help="shm-tier segment namespace for this shard slot (stable "
-        "across relaunches within a job; with --generation it keys "
-        "the boot-time reclamation of a SIGKILLed predecessor's "
-        "segments — rpc/transport.ShmServer)",
-    )
     return p
 
 
@@ -119,15 +112,9 @@ def main(argv=None) -> int:
         # flag forces combining on; absent flag defers to the env knob
         fanin_combine=True if args.fanin_combine else None,
     )
-    server = RpcServer(
-        servicer.handlers(),
-        port=args.port,
-        shm_scope=args.shm_scope or None,
-        shm_generation=args.generation,
-    )
+    server = RpcServer(servicer.handlers(), port=args.port)
     servicer.attach_wire_stats(server.wire)
     servicer.attach_admission_stats(server.admission_stats)
-    servicer.attach_shm_publisher(server.shm_broadcaster)
     servicer.register_metrics()
 
     from elasticdl_tpu.obs import flight

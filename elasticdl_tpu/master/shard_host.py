@@ -65,9 +65,6 @@ def spawn_shard_processes(
         # shard default to tempfile.gettempdir() independently, and a
         # TMPDIR divergence would silently strand the sockets in two
         # places (clients fall back to grpc, masking the fast path).
-        # The shm tier's doorbell sockets AND rendezvous files
-        # (edl-shm-<port>.{sock,json}) live in this same dir, so the
-        # one setdefault covers both fast paths.
         from elasticdl_tpu.common.constants import ENV_UDS_DIR
         from elasticdl_tpu.rpc import transport as _transport
 

@@ -2,8 +2,8 @@
 
 Every RPC carries a compact ``{"t": trace_id, "s": span_id}`` envelope
 under ``ENVELOPE_KEY`` inside the request dict — the wire codec ignores
-unknown keys, so the envelope rides all four transport tiers
-(grpc|uds|shm|inproc) without schema changes. Each hop records a span
+unknown keys, so the envelope rides every transport tier
+(grpc|uds|inproc) without schema changes. Each hop records a span
 into a bounded lock-striped :class:`SpanRecorder` ring (the striping
 mirrors rpc/policy.WireStats): worker sync chain, transport send/recv,
 dispatcher admission-queue wait, CombineBuffer park+presum, shard-lock

@@ -81,8 +81,7 @@ class RpcClient:
         # chaos counters advance identically whichever tier serves.
         # `transport` pins this client's tier regardless of the ambient
         # EDL_TRANSPORT mode (per-link selection: the aggregation tree
-        # keeps shm for worker->aggregator while pinning uds/grpc for
-        # aggregator->PS); None = the env mode as before.
+        # pins uds/grpc for aggregator->PS); None = the env mode.
         self._transport = self._select(addr)
         self._policy = policy if policy is not None else RetryPolicy.from_env()
         self._breaker = breaker if breaker is not None else CircuitBreaker(addr)
@@ -292,7 +291,7 @@ class RpcClient:
 
     def close(self):
         self._channel.close()
-        # the shm transport holds pooled connections + broadcast
-        # mappings; other tiers have no client-side resources
+        # the uds transport holds pooled connections; other tiers
+        # have no client-side resources
         if self._transport is not None and hasattr(self._transport, "close"):
             self._transport.close()

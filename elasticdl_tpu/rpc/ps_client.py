@@ -57,7 +57,7 @@ class ShardedPS:
         # aggregation tree (agg/): when armed, window-delta pushes
         # route through the host aggregator (AggPushDelta) instead of
         # direct to the shards — one client per shard so the per-shard
-        # fan-out keeps its connection parallelism on the shm tier.
+        # fan-out keeps its connection parallelism.
         # Any agg-path failure drops the route and replays direct under
         # the SAME report_key (shard dedup keeps versions exact); the
         # worker re-arms from GetPSConfig once `agg_dropped` reports it.
@@ -285,7 +285,7 @@ class ShardedPS:
         (shard_versions, vec|None). The worker's overlap plane uses
         this to page a newer model in while the step loop computes —
         the transport stack is safe for it (RpcClient serializes per
-        endpoint under `_calls_lock`; the shm tier checks out pooled
+        endpoint under `_calls_lock`; the uds tier checks out pooled
         connections per call), so an async pull may overlap concurrent
         push_delta fan-outs on the same client."""
         if self._async_pool is None:

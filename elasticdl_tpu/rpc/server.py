@@ -8,18 +8,16 @@ format supports bf16 and nested pytrees (see common/codec.py).
 Every server also serves the transport fast paths (rpc/transport.py):
 its handler table is registered in the in-process dispatch registry
 keyed by the bound port, and a Unix-domain-socket listener
-(`edl-uds-<port>.sock` in `EDL_UDS_DIR`) opens beside gRPC unless
-`EDL_TRANSPORT` says otherwise — with the variable unset that is the
-carrier a client on this host gets. `EDL_TRANSPORT=shm|auto` adds (or
-swaps in) the shared-memory listener, `grpc` opens neither. All share
-the same `ServerDispatcher`, so chaos/fencing/abort classification is
-identical on every tier.
+(`edl-uds-<port>.sock` in `EDL_UDS_DIR`) opens beside gRPC — the
+carrier a client on this host gets — unless `EDL_TRANSPORT=grpc`. All
+share the same `ServerDispatcher`, so chaos/fencing/abort
+classification is identical on every tier.
 """
 
 from __future__ import annotations
 
 from concurrent import futures
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict
 
 import grpc
 
@@ -62,8 +60,6 @@ class RpcServer:
         service_name: str = SERVICE_NAME,
         max_workers: int = 64,
         fault_plan=None,
-        shm_scope: Optional[str] = None,
-        shm_generation: int = 0,
         timers=None,
         timed_methods=(),
     ):
@@ -120,38 +116,11 @@ class RpcServer:
                     self.port,
                     e,
                 )
-        self._shm = None
-        if transport_mod.server_shm_enabled():
-            # one ShmServer class for both dispatch cores: under loop
-            # dispatch the conn thread parks on the reactor shim, like
-            # a grpc pool thread (rpc/transport.ShmServer docstring)
-            try:
-                self._shm = transport_mod.ShmServer(
-                    self.port,
-                    self._dispatcher,
-                    scope=shm_scope,
-                    generation=shm_generation,
-                )
-            except OSError as e:
-                logger.warning(
-                    "shm fast path unavailable for port %s (%s)",
-                    self.port,
-                    e,
-                )
-
-    @property
-    def shm_broadcaster(self):
-        """The shm tier's broadcast publisher, or None when the tier is
-        inactive; PSShard attaches this to publish prepacked pull
-        frames as per-version broadcast segments."""
-        return self._shm.broadcaster if self._shm is not None else None
 
     def start(self):
         self._server.start()
         if self._uds is not None:
             self._uds.start()
-        if self._shm is not None:
-            self._shm.start()
         self._register_metrics()
 
     def _register_metrics(self):
@@ -226,8 +195,6 @@ class RpcServer:
         transport_mod.unregister_inproc(self.port)
         if self._uds is not None:
             self._uds.close()
-        if self._shm is not None:
-            self._shm.close()
         self._server.stop(grace)
         self._dispatcher.close()
 

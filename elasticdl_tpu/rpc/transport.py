@@ -1,11 +1,11 @@
-"""Transport tiers for the RPC plane: grpc / uds / shm / inproc.
+"""Carriers for the RPC plane: grpc / uds / inproc.
 
-The elastic window path is link-bound (docs/performance.md), yet a
-co-located PS shard pays full gRPC framing for bytes that never leave
-the host. This module adds three fast paths under the SAME call
-surface. Which carrier a link uses is decided from what the code can
-observe — is the peer on this host, and is its local listener there —
-with `EDL_TRANSPORT` as the override (and the test handle):
+A link between two processes has two carriers. A peer on this host
+whose listener is there is carried by a Unix socket; everyone else,
+and any call whose local carrier cannot connect, by gRPC. Which one a
+link gets is decided from what the code can observe — is the peer on
+this host, is its socket file there — with `EDL_TRANSPORT` as the
+override (and the test handle):
 
 - **uds** — a Unix-domain-socket byte protocol carrying codec frames
   with a minimal length-prefixed header, skipping gRPC/HTTP-2 framing
@@ -13,22 +13,6 @@ with `EDL_TRANSPORT` as the override (and the test handle):
   and the receiver hands the codec one contiguous buffer to build
   `np.frombuffer` views over — the zero-copy contract of codec v2 holds
   end to end.
-- **shm** — per-connection shared-memory segments
-  (`multiprocessing.shared_memory`) carrying the same codec frames for
-  co-located SEPARATE processes (shard_host subprocesses): the sender
-  writes the frame into its connection's ring region, a tiny
-  Unix-socket doorbell message carries only the wakeup + method name +
-  frame length, and the server hands the dispatcher `np.frombuffer`
-  views built directly over the mapped region — request payload bytes
-  never cross a socket and are never copied on the receive side. The
-  server additionally publishes read-only BROADCAST segments for
-  prepacked fan-out responses (PSShard pull's per-version model frame):
-  the reply is then a marker the client resolves against its own
-  mapping of the published segment, so N co-located pullers share one
-  encode and zero per-pull payload copies. Rendezvous is a port-keyed
-  JSON file next to the doorbell socket embedding the serving fencing
-  generation; a relaunched shard sweeps its predecessor's segments and
-  rendezvous files at boot, so a client can never attach a dead ring.
 - **inproc** — when the serving `RpcServer` lives in the SAME
   interpreter (bench/test mode, `PSShardGroup` inproc shards), the call
   dispatches directly into the server's handler table: the packed frame
@@ -47,26 +31,29 @@ so a tier cannot silently bypass FaultPlan injection.
 
 Selection (`select_transport`) is conservative: a non-grpc tier is used
 only when the endpoint host resolves local AND the counterpart is
-reachable (a registered in-process dispatcher, a readable shm
-rendezvous file with its doorbell socket, or an existing socket file);
-otherwise the caller falls back to gRPC. `auto` prefers
-inproc > shm > uds > grpc.
+reachable (a registered in-process dispatcher, or an existing socket
+file); otherwise the caller falls back to gRPC. `auto` prefers
+inproc > uds > grpc.
 
 With `EDL_TRANSPORT` unset the mode is **uds**: every `RpcServer`
 opens its Unix-socket listener beside gRPC, a client whose endpoint is
 local and whose socket file exists is carried by it, and a remote
 endpoint (the k8s path advertises the pod IP) gets gRPC. Unset is not
-`auto`: no `inproc` (in-process tests keep their sockets) and no `shm`
-(a segment per connection). `EDL_TRANSPORT=grpc` is pure gRPC: no
-listener, no fast path.
+`auto`: no `inproc` (in-process tests keep their sockets).
+`EDL_TRANSPORT=grpc` is pure gRPC: no listener, no fast path.
 
-The socket tiers receive a frame with no copy beyond the kernel's:
+The socket tier receives a frame with no copy beyond the kernel's:
 `_recv_frame` reads into memory that was never zero-filled and hands
 the dispatcher / `messages.unpack` a read-only view of it, over which
 the codec builds its `np.frombuffer` views. A buffer is never reused:
 each frame gets its own, which lives as long as an array decoded from
 it (the master keeps such views past the handler: `grads_to_wait` > 1,
 fan-in).
+
+A frame longer than the socket header's u32 length field can say
+(`MAX_FRAME_BYTES`) is refused before a byte of it is sent, by the
+client for a request and by the server for a response, as OUT_OF_RANGE:
+not retryable, since the same frame would be refused again.
 
 A local carrier that cannot CONNECT (the socket file of a dead server,
 a server relaunched under `EDL_TRANSPORT=grpc` on a reused port) raises
@@ -80,7 +67,6 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
-import json
 import os
 import socket
 import struct
@@ -88,17 +74,14 @@ import tempfile
 import threading
 import time
 from concurrent import futures
-from multiprocessing import shared_memory as _shm_mod
 from typing import Callable, Dict, Optional
 
 import grpc
 import numpy as np
 
-from elasticdl_tpu.common import codec, messages
+from elasticdl_tpu.common import messages
 from elasticdl_tpu.common.constants import (
     ENV_TRANSPORT,
-    ENV_TRANSPORT_SHM_DOORBELL_TIMEOUT,
-    ENV_TRANSPORT_SHM_RING,
     ENV_UDS_DIR,
 )
 from elasticdl_tpu.common.log_util import get_logger
@@ -114,16 +97,10 @@ logger = get_logger(__name__)
 
 TRANSPORT_GRPC = "grpc"
 TRANSPORT_UDS = "uds"
-TRANSPORT_SHM = "shm"
 TRANSPORT_INPROC = "inproc"
 #: The tiers WireStats rows may carry; "auto" is a selection policy,
 #: not a tier.
-TRANSPORT_TIERS = (
-    TRANSPORT_GRPC,
-    TRANSPORT_UDS,
-    TRANSPORT_SHM,
-    TRANSPORT_INPROC,
-)
+TRANSPORT_TIERS = (TRANSPORT_GRPC, TRANSPORT_UDS, TRANSPORT_INPROC)
 
 _LOCAL_HOSTS = frozenset(
     {"localhost", "127.0.0.1", "[::1]", "::1", "0.0.0.0", "[::]", ""}
@@ -139,31 +116,10 @@ _RESP_OK = struct.Struct("<BI")
 #: the gRPC tier would have surfaced.
 _RESP_ERR = struct.Struct("<BiH")
 
-_CODE_BY_VALUE = {c.value[0]: c for c in grpc.StatusCode}
+#: The longest frame either header's u32 length field can say.
+MAX_FRAME_BYTES = 0xFFFFFFFF
 
-#: shm handshake (server -> client on accept): u32 fencing generation,
-#: u32 segment-name length, u64 per-direction ring bytes; then the
-#: segment name utf-8. The client attaches the named segment: request
-#: region [0, ring), response region [ring, 2*ring).
-_SHM_HELLO = struct.Struct("<IIQ")
-#: shm request doorbell: kind (1 = whole frame already in the request
-#: region, 2 = chunked transfer follows), u16 method length, u32 frame
-#: length (total length for kind 2); then the method utf-8.
-_SHM_REQ = struct.Struct("<BHI")
-#: shm response doorbell: status (0 = ok frame in the response region,
-#: 1 = error, 2 = chunked ok follows, 3 = broadcast marker frame in the
-#: response region), u32 length.
-_SHM_RESP = struct.Struct("<BI")
-#: chunk sub-header (either direction): u32 chunk length; each chunk is
-#: acked with one byte before the region is overwritten.
-_SHM_CHUNK = struct.Struct("<I")
-#: shm error tail after a status-1 doorbell: i32 grpc status-code
-#: value, u16 detail length; then the detail utf-8.
-_SHM_ERR = struct.Struct("<iH")
-_SHM_ACK = b"\x06"
-#: Top-level key of a broadcast marker frame; the value is the segment
-#: descriptor {"seg": <name>, "n": <frame bytes>}.
-_SHM_BCAST_KEY = "__shm_bcast__"
+_CODE_BY_VALUE = {c.value[0]: c for c in grpc.StatusCode}
 
 
 #: The mode when EDL_TRANSPORT is unset: a local peer with a listener
@@ -172,7 +128,7 @@ DEFAULT_MODE = TRANSPORT_UDS
 
 
 def transport_mode(env=None) -> str:
-    """The configured tier ("grpc"/"uds"/"shm"/"inproc"/"auto"); unset
+    """The configured tier ("grpc"/"uds"/"inproc"/"auto"); unset
     means DEFAULT_MODE, unknown values log once and mean grpc."""
     env = os.environ if env is None else env
     mode = (env.get(ENV_TRANSPORT, "") or DEFAULT_MODE).strip().lower()
@@ -186,11 +142,6 @@ def server_fast_paths_enabled() -> bool:
     """Whether RpcServer should open the UDS listener (the inproc
     registry is always populated — it is a dict entry, not a socket)."""
     return transport_mode() in (TRANSPORT_UDS, "auto")
-
-
-def server_shm_enabled() -> bool:
-    """Whether RpcServer should open the shared-memory listener."""
-    return transport_mode() in (TRANSPORT_SHM, "auto")
 
 
 def uds_dir(env=None) -> str:
@@ -223,57 +174,6 @@ def uds_path_for(port: int) -> str:
     port number is the rendezvous, so clients derive the path from the
     endpoint they already hold (GetPSConfig / shard_host endpoints)."""
     return os.path.join(uds_dir(), f"edl-uds-{int(port)}.sock")
-
-
-_SHM_DEFAULT_RING = 1 << 22  # 4 MiB per direction
-
-
-def shm_ring_bytes(env=None) -> int:
-    """Per-direction ring capacity for each shm connection, rounded up
-    to the codec's 64-byte segment alignment so region offset 0 always
-    satisfies the zero-copy view contract."""
-    env = os.environ if env is None else env
-    try:
-        n = int(env.get(ENV_TRANSPORT_SHM_RING, "") or _SHM_DEFAULT_RING)
-    except ValueError:
-        n = _SHM_DEFAULT_RING
-    n = max(n, 4096)
-    return (n + 63) // 64 * 64
-
-
-def shm_doorbell_timeout(env=None) -> float:
-    """Socket timeout for the doorbell handshake and chunk-ack phases
-    (the per-call deadline still comes from the caller's budget)."""
-    env = os.environ if env is None else env
-    try:
-        t = float(env.get(ENV_TRANSPORT_SHM_DOORBELL_TIMEOUT, "") or 5.0)
-    except ValueError:
-        t = 5.0
-    return max(t, 0.001)
-
-
-def shm_doorbell_path(port: int) -> str:
-    """Doorbell socket path for a server on gRPC `port`; like the UDS
-    tier, the port number is the rendezvous key."""
-    return os.path.join(uds_dir(), f"edl-shm-{int(port)}.sock")
-
-
-def shm_rendezvous_path(port: int) -> str:
-    """Rendezvous JSON for a server on gRPC `port`: scope, fencing
-    generation, segment-name prefix, doorbell path, ring bytes, pid.
-    Written atomically AFTER the doorbell socket is listening, so its
-    existence is the client-visible signal the tier is up; swept by the
-    successor's boot reclamation when the writer dies."""
-    return os.path.join(uds_dir(), f"edl-shm-{int(port)}.json")
-
-
-def read_shm_rendezvous(port: int) -> Optional[dict]:
-    try:
-        with open(shm_rendezvous_path(port), "r", encoding="utf-8") as f:
-            info = json.load(f)
-    except (OSError, ValueError):
-        return None
-    return info if isinstance(info, dict) else None
 
 
 def _sanitized_detail(e: BaseException) -> str:
@@ -482,21 +382,7 @@ class ServerDispatcher:
             if sp is not None:
                 obs_trace.bind(prev_ctx)
                 sp.end()
-        if (
-            transport == TRANSPORT_SHM
-            and isinstance(resp, messages.Prepacked)
-            and getattr(resp, "shm_ref", None)
-        ):
-            # broadcast substitution: the wire carries only a tiny
-            # descriptor frame — the payload stays in the published
-            # read-only segment every co-located client maps once per
-            # version. WireStats therefore records marker bytes here
-            # (the documented shm asymmetry: clients account the
-            # resolved frame length they actually consumed).
-            resp_bytes = _ShmBcastMarkerBytes(
-                codec.dumps({_SHM_BCAST_KEY: dict(resp.shm_ref)})
-            )
-        elif timed:
+        if timed:
             version = resp.get("version") if isinstance(resp, dict) else None
             t_encode = time.time()
             resp_bytes = messages.pack(resp)
@@ -577,7 +463,7 @@ class InprocTransport:
 def _rpc_error_fields(e: grpc.RpcError):
     """(status code, clamped detail bytes) for a dispatch failure —
     enough to rebuild the PolicyRpcError the gRPC tier would have
-    surfaced; shared by the uds and shm error framings."""
+    surfaced."""
     code = e.code() if callable(getattr(e, "code", None)) else None
     if not isinstance(code, grpc.StatusCode):
         code = grpc.StatusCode.INTERNAL
@@ -585,6 +471,19 @@ def _rpc_error_fields(e: grpc.RpcError):
     if callable(getattr(e, "details", None)):
         details = e.details() or ""
     return code, details.encode("utf-8")[:1024]
+
+
+def _refuse_oversize(what: str, method: str, n: int) -> None:
+    """A frame the length field cannot say is refused whole, before a
+    byte of it is sent. OUT_OF_RANGE is not retryable (the same frame
+    would be refused again), unlike the UNAVAILABLE a link that is
+    down answers with."""
+    if n > MAX_FRAME_BYTES:
+        raise PolicyRpcError(
+            grpc.StatusCode.OUT_OF_RANGE,
+            f"uds {what} frame of {method} is {n} bytes; the carrier's "
+            f"limit is {MAX_FRAME_BYTES}",
+        )
 
 
 def _error_frame(e: grpc.RpcError) -> bytes:
@@ -757,6 +656,7 @@ class UdsServer:
                     resp = self._dispatcher.dispatch(
                         method, _recv_frame(conn, blen), TRANSPORT_UDS
                     )
+                    _refuse_oversize("response", method, len(resp))
                 except grpc.RpcError as e:
                     conn.sendall(_error_frame(e))
                     continue
@@ -848,6 +748,7 @@ class AsyncUdsServer:
                     resp = await self._dispatcher.dispatch_async(
                         method, body, TRANSPORT_UDS
                     )
+                    _refuse_oversize("response", method, len(resp))
                 except grpc.RpcError as e:
                     writer.write(_error_frame(e))
                     await writer.drain()
@@ -941,6 +842,7 @@ class UdsTransport:
                     pass
 
     def call(self, method: str, payload: bytes, timeout: float) -> bytes:
+        _refuse_oversize("request", method, len(payload))
         # connect first: CarrierDown leaves the FaultPlan untouched, so
         # the gRPC channel that serves the call instead draws its fault
         conn = self._checkout()
@@ -968,7 +870,7 @@ class UdsTransport:
                 grpc.StatusCode.DEADLINE_EXCEEDED,
                 f"uds call {method} timed out after {timeout:.3f}s",
             )
-        except (ConnectionError, OSError, struct.error) as e:
+        except (ConnectionError, OSError) as e:
             conn.close()
             conn = None
             raise PolicyRpcError(
@@ -979,741 +881,6 @@ class UdsTransport:
                 self._checkin(conn)
         transport_faults_after(after, method)
         return body
-
-
-# --------------------------------------------------------------------------
-# shm: codec frames through per-connection shared-memory rings, with a
-# Unix-socket doorbell for wakeup (no spinning) and read-only broadcast
-# segments for prepacked fan-out responses
-
-
-class _ShmBcastMarkerBytes(bytes):
-    """Response-bytes subtype produced by `ServerDispatcher._invoke`
-    when an shm response was substituted by a broadcast marker; the
-    ShmServer conn loop keys the status-3 doorbell off this type so the
-    marker survives the ordinary bytes-returning dispatch chain (both
-    dispatch cores, including the loop executor bridge)."""
-
-
-def _shm_error_frame(e: grpc.RpcError) -> bytes:
-    code, detail_b = _rpc_error_fields(e)
-    return (
-        _SHM_RESP.pack(1, 0)
-        + _SHM_ERR.pack(code.value[0], len(detail_b))
-        + detail_b
-    )
-
-
-class _QuietSharedMemory(_shm_mod.SharedMemory):
-    """SharedMemory whose destructor tolerates still-exported views.
-    At interpreter shutdown GC order is arbitrary, so a caller-held
-    np view over a mapping can outlive the segment object; the base
-    destructor then raises BufferError into "Exception ignored"
-    noise. The kernel reclaims the mapping at process exit either
-    way."""
-
-    def __del__(self):
-        try:
-            super().__del__()
-        except BufferError:
-            pass
-
-
-_attach_lock = threading.Lock()
-
-
-def _attach_shm_segment(name: str) -> _shm_mod.SharedMemory:
-    """Attach (never create) an existing segment. CPython < 3.13
-    registers even attachments with the multiprocessing resource
-    tracker, which would unlink server-owned segments when THIS
-    process exits (and warn about "leaks"); suppress the registration
-    for the attach — segment lifecycle belongs to the serving side.
-    (Suppression beats unregistering afterwards: an unregister without
-    a matching registration in the same process makes the tracker
-    daemon print KeyError tracebacks at exit.)
-
-    The suppression monkeypatch is process-global, so every segment
-    CREATE must hold the same lock (`_create_shm_segment`) — a create
-    landing inside another thread's suppression window would lose its
-    tracker registration, and its eventual unlink would feed the
-    tracker daemon an unmatched unregister (KeyError traceback)."""
-    from multiprocessing import resource_tracker
-
-    with _attach_lock:
-        orig = resource_tracker.register
-        resource_tracker.register = lambda *a, **k: None
-        try:
-            return _QuietSharedMemory(name=name)
-        finally:
-            resource_tracker.register = orig
-
-
-def _create_shm_segment(name: str, size: int) -> _shm_mod.SharedMemory:
-    """Create a segment under `_attach_lock` so its tracker
-    registration cannot be swallowed by a concurrent attach's
-    register-suppression window (see `_attach_shm_segment`)."""
-    with _attach_lock:
-        return _QuietSharedMemory(name=name, create=True, size=size)
-
-
-def _sanitize_scope(scope: str) -> str:
-    out = "".join(c if c.isalnum() or c in "._-" else "-" for c in scope)
-    return out[:48] or "s"
-
-
-def _unlink_segments(prefix: str) -> None:
-    """Best-effort unlink of every segment whose name starts with
-    `prefix`. Enumeration uses /dev/shm (Linux shm_open backing); on
-    platforms without it the rendezvous-file sweep still removes the
-    doorbell + json, and the kernel reclaims segments with the last
-    unmap."""
-    if not prefix:
-        return
-    try:
-        names = os.listdir("/dev/shm")
-    except OSError:
-        return
-    for name in names:
-        if name.startswith(prefix):
-            try:
-                os.unlink(os.path.join("/dev/shm", name))
-            except OSError:
-                pass
-
-
-class ShmBroadcaster:
-    """Server-owned publisher of read-only broadcast segments: one
-    whole codec frame per segment, written via `codec.dumps_parts` +
-    `write_frame_into` straight into the fresh mapping (the final join
-    copy of `dumps` never happens). Keeps the last few segments alive
-    so clients racing a version bump can still attach the previous
-    one; everything is unlinked on close."""
-
-    KEEP = 4
-
-    def __init__(self, prefix: str):
-        self._prefix = prefix
-        self._lock = threading.Lock()
-        self._segments: list = []  # [(name, SharedMemory, view)]
-        self._retired: list = []  # evicted but still-referenced mappings
-        self._seq = 0
-        self._closed = False
-
-    def publish(self, obj) -> Optional[tuple]:
-        """Encode `obj` into a new segment; returns (ref, view) where
-        `ref` is the marker descriptor and `view` a memoryview over the
-        published frame, or None once closed."""
-        parts, total = codec.dumps_parts(obj)
-        with self._lock:
-            if self._closed:
-                return None
-            self._seq += 1
-            name = f"{self._prefix}b{self._seq}"
-        seg = _create_shm_segment(name, max(total, 1))
-        codec.write_frame_into(parts, total, seg.buf)
-        view = memoryview(seg.buf)[:total]
-        with self._lock:
-            if self._closed:
-                view.release()
-                seg.close()
-                try:
-                    seg.unlink()
-                except OSError:
-                    pass
-                return None
-            self._segments.append((name, seg, view))
-            evicted = []
-            while len(self._segments) > self.KEEP:
-                evicted.append(self._segments.pop(0))
-            retired, self._retired = self._retired, []
-        for old_name, old_seg, old_view in evicted:
-            try:
-                old_seg.unlink()
-            except OSError:
-                pass
-            old_view.release()
-            self._close_or_retire(old_seg)
-        for old_seg in retired:
-            self._close_or_retire(old_seg)
-        return {"seg": name, "n": int(total)}, view
-
-    def _close_or_retire(self, seg) -> None:
-        try:
-            seg.close()
-        except BufferError:
-            # a served Prepacked still holds a view over the mapping;
-            # retry on the next publish/close instead of crashing the
-            # serve path
-            with self._lock:
-                self._retired.append(seg)
-
-    def close(self) -> None:
-        with self._lock:
-            self._closed = True
-            segments = self._segments
-            retired = self._retired
-            self._segments = []
-            self._retired = []
-        for name, seg, view in segments:
-            try:
-                seg.unlink()
-            except OSError:
-                pass
-            view.release()
-            try:
-                seg.close()
-            except BufferError:  # pragma: no cover - caller kept a view
-                pass
-        for seg in retired:
-            try:
-                seg.close()
-            except BufferError:  # pragma: no cover
-                pass
-
-
-class ShmServer:
-    """Threaded shared-memory listener sharing an RpcServer's
-    dispatcher. Each accepted doorbell connection gets its own
-    SharedMemory segment (request region [0, ring), response region
-    [ring, 2*ring)); the doorbell socket carries only wakeups, method
-    names, and frame lengths. Request frames that fit the ring are
-    handed to the dispatcher as a memoryview over the mapping — the
-    codec builds `np.frombuffer` views directly over shared memory, so
-    request payloads cross processes with zero copies; oversize frames
-    fall back to a chunked copy through the ring. Serves BOTH
-    `EDL_DISPATCH` cores through `ServerDispatcher.dispatch` (under
-    the loop core the conn thread parks on the reactor shim, exactly
-    like a grpc pool thread — shm connections are few per host, so the
-    thread-per-connection read side costs what the grpc pool already
-    pays).
-
-    Boot order is the crash-safety story: sweep the dead predecessor's
-    segments/rendezvous (same port, or same scope at any older
-    generation), bind the doorbell, then atomically publish the
-    rendezvous file embedding THIS fencing generation — a client
-    resolving the file can never attach a dead ring. Raises OSError
-    from __init__ when the doorbell path is unusable — the caller logs
-    and serves gRPC only."""
-
-    def __init__(
-        self,
-        port: int,
-        dispatcher: ServerDispatcher,
-        scope: Optional[str] = None,
-        generation: int = 0,
-    ):
-        self.port = int(port)
-        self._dispatcher = dispatcher
-        self.generation = int(generation)
-        self._scope = _sanitize_scope(scope) if scope else f"p{self.port}"
-        self._ring = shm_ring_bytes()
-        self._prefix = f"edlshm.{self._scope}.g{self.generation}."
-        self._reclaim_stale()
-        self.doorbell = shm_doorbell_path(self.port)
-        self.path = shm_rendezvous_path(self.port)
-        try:
-            os.unlink(self.doorbell)
-        except FileNotFoundError:
-            pass
-        self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        try:
-            with _sock_addr(self.doorbell) as addr:
-                self._sock.bind(addr)
-            self._sock.listen(128)
-            self.broadcaster = ShmBroadcaster(self._prefix + "x")
-            self._conn_seq = 0
-            self._thread: Optional[threading.Thread] = None
-            # live connections, severed on close(): a stopped server
-            # must refuse pooled clients exactly like a stopped gRPC
-            # server
-            self._conns: set = set()
-            self._conn_threads: list = []
-            self._conns_lock = threading.Lock()
-            self._closed = False
-            tmp = self.path + ".tmp"
-            with open(tmp, "w", encoding="utf-8") as f:
-                json.dump(
-                    {
-                        "scope": self._scope,
-                        "generation": self.generation,
-                        "prefix": self._prefix,
-                        "doorbell": self.doorbell,
-                        "ring": self._ring,
-                        "pid": os.getpid(),
-                    },
-                    f,
-                )
-            os.replace(tmp, self.path)
-        except Exception:
-            # a raise between the doorbell bind and the rendezvous
-            # write (disk full, unlinkable path, broadcast segment
-            # collision) leaves a half-built server the caller cannot
-            # close(): release the doorbell socket/path and the
-            # broadcast segment before re-raising so a relaunch on the
-            # same port starts clean instead of inheriting our debris
-            self._sock.close()
-            broadcaster = getattr(self, "broadcaster", None)
-            if broadcaster is not None:
-                broadcaster.close()
-            for leftover in (self.doorbell, self.path + ".tmp"):
-                try:
-                    os.unlink(leftover)
-                except OSError:
-                    pass
-            raise
-
-    def _reclaim_stale(self) -> None:
-        """Sweep a dead predecessor's rings. The rendezvous file keyed
-        by MY port is stale by construction (the caller's gRPC bind
-        proved the port free); any segment carrying MY scope predates
-        this server (one live server per scope slot, and this server
-        has created nothing yet); and same-scope rendezvous files on
-        OTHER ports at an OLDER fencing generation belong to a
-        SIGKILLed incarnation whose relaunch (this one) got a fresh
-        port."""
-        mine = read_shm_rendezvous(self.port)
-        if mine is not None:
-            _unlink_segments(str(mine.get("prefix", "")))
-            for p in (
-                str(mine.get("doorbell", "")),
-                shm_rendezvous_path(self.port),
-            ):
-                try:
-                    os.unlink(p)
-                except OSError:
-                    pass
-        _unlink_segments(f"edlshm.{self._scope}.")
-        try:
-            names = os.listdir(uds_dir())
-        except OSError:
-            return
-        for name in names:
-            if not (name.startswith("edl-shm-") and name.endswith(".json")):
-                continue
-            path = os.path.join(uds_dir(), name)
-            try:
-                with open(path, "r", encoding="utf-8") as f:
-                    other = json.load(f)
-            except (OSError, ValueError):
-                continue
-            if not isinstance(other, dict):
-                continue
-            try:
-                other_gen = int(other.get("generation", -1))
-            except (TypeError, ValueError):
-                continue
-            if other.get("scope") == self._scope and other_gen < self.generation:
-                _unlink_segments(str(other.get("prefix", "")))
-                for p in (str(other.get("doorbell", "")), path):
-                    try:
-                        os.unlink(p)
-                    except OSError:
-                        pass
-
-    def start(self):
-        self._thread = threading.Thread(
-            target=self._accept_loop,
-            name=f"shm-accept-{self.doorbell}",
-            daemon=True,
-        )
-        self._thread.start()
-
-    def _is_closed(self) -> bool:
-        with self._conns_lock:
-            return self._closed
-
-    def _accept_loop(self):
-        while not self._is_closed():
-            try:
-                conn, _ = self._sock.accept()
-            except OSError:
-                return  # closed
-            t = threading.Thread(
-                target=self._serve_conn, args=(conn,), daemon=True
-            )
-            with self._conns_lock:
-                # reap finished conn threads so a long-lived server
-                # under connection churn doesn't grow the list (and
-                # close()'s join sweep) without bound
-                for dead in [
-                    x for x in self._conn_threads if not x.is_alive()
-                ]:
-                    self._conn_threads.remove(dead)
-                self._conn_threads.append(t)
-            t.start()
-
-    def _serve_conn(self, conn: socket.socket):
-        with self._conns_lock:
-            if self._closed:
-                conn.close()
-                return
-            self._conns.add(conn)
-            self._conn_seq += 1
-            name = f"{self._prefix}c{self._conn_seq}"
-        seg = None
-        req_region = resp_region = None
-        try:
-            seg = _create_shm_segment(name, 2 * self._ring)
-            mb = name.encode("utf-8")
-            conn.sendall(
-                _SHM_HELLO.pack(self.generation, len(mb), self._ring) + mb
-            )
-            req_region = memoryview(seg.buf)[: self._ring]
-            resp_region = memoryview(seg.buf)[self._ring : 2 * self._ring]
-            while not self._is_closed():
-                header = _recv_exact(conn, _SHM_REQ.size, eof_ok=True)
-                if header is None:
-                    return
-                kind, mlen, length = _SHM_REQ.unpack(header)
-                method = _recv_exact(conn, mlen).decode("utf-8")
-                if kind == 1:
-                    if length > self._ring:
-                        raise ConnectionError(
-                            f"shm frame length {length} exceeds ring"
-                        )
-                    # zero-copy hand-off: the dispatcher (and the codec
-                    # below it) reads straight from the mapped region,
-                    # which stays untouched until the response doorbell
-                    body = req_region[:length]
-                else:
-                    body = self._recv_chunked(conn, req_region, length)
-                try:
-                    resp = self._dispatcher.dispatch(method, body, TRANSPORT_SHM)
-                except grpc.RpcError as e:
-                    conn.sendall(_shm_error_frame(e))
-                    continue
-                if isinstance(resp, _ShmBcastMarkerBytes):
-                    resp_region[: len(resp)] = resp
-                    conn.sendall(_SHM_RESP.pack(3, len(resp)))
-                elif len(resp) <= self._ring:
-                    resp_region[: len(resp)] = resp
-                    conn.sendall(_SHM_RESP.pack(0, len(resp)))
-                else:
-                    self._send_chunked(conn, resp_region, resp)
-        except (ConnectionError, OSError, struct.error):
-            pass  # client went away; per-connection state is the segment
-        finally:
-            with self._conns_lock:
-                self._conns.discard(conn)
-            try:
-                conn.close()
-            except OSError:
-                pass
-            if req_region is not None:
-                req_region.release()
-            if resp_region is not None:
-                resp_region.release()
-            if seg is not None:
-                try:
-                    seg.close()
-                except BufferError:  # pragma: no cover - handler kept a view
-                    pass
-                try:
-                    seg.unlink()
-                except OSError:
-                    pass
-
-    def _recv_chunked(self, conn, region, total: int):
-        """Oversize-request fallback: assemble the frame through the
-        ring in ring-sized pieces (one copy — the zero-copy contract
-        holds only for frames that fit the ring)."""
-        out, frame = _frame_buffer(total)
-        got = 0
-        conn.settimeout(shm_doorbell_timeout())
-        try:
-            while got < total:
-                (clen,) = _SHM_CHUNK.unpack(_recv_exact(conn, _SHM_CHUNK.size))
-                if clen > len(region) or got + clen > total:
-                    raise ConnectionError(f"shm chunk overrun ({clen} bytes)")
-                out[got : got + clen] = region[:clen]
-                got += clen
-                conn.sendall(_SHM_ACK)  # client may reuse the region
-        finally:
-            conn.settimeout(None)
-        return frame
-
-    def _send_chunked(self, conn, region, resp: bytes) -> None:
-        total = len(resp)
-        conn.sendall(_SHM_RESP.pack(2, total))
-        rv = memoryview(resp)
-        sent = 0
-        conn.settimeout(shm_doorbell_timeout())
-        try:
-            while sent < total:
-                clen = min(self._ring, total - sent)
-                region[:clen] = rv[sent : sent + clen]
-                conn.sendall(_SHM_CHUNK.pack(clen))
-                _recv_exact(conn, 1)  # client copied the chunk out
-                sent += clen
-        finally:
-            conn.settimeout(None)
-
-    def close(self):
-        with self._conns_lock:
-            self._closed = True
-            conns = list(self._conns)
-            threads = list(self._conn_threads)
-        for conn in conns:
-            try:
-                conn.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-        try:
-            self._sock.close()
-        except OSError:
-            pass
-        # deterministic teardown: wait for each conn thread's segment
-        # unlink so close() returning means /dev/shm is clean (tests
-        # and operators check exactly that); the prefix sweep backstops
-        # a thread that outlives the join timeout
-        for t in threads:
-            t.join(timeout=5)
-        self.broadcaster.close()
-        _unlink_segments(self._prefix)
-        for p in (self.doorbell, self.path):
-            try:
-                os.unlink(p)
-            except OSError:
-                pass
-
-
-class _ShmConn:
-    """One client connection: the doorbell socket plus this
-    connection's mapped segment regions. Destroyed (never pooled) on
-    any protocol error — a fresh connection re-runs the handshake."""
-
-    __slots__ = ("sock", "seg", "ring", "generation", "req", "resp")
-
-    def __init__(self, doorbell: str):
-        sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        try:
-            with _sock_addr(doorbell) as addr:
-                sock.connect(addr)
-        except OSError as e:
-            sock.close()
-            raise CarrierDown(f"shm connect {doorbell}: {e}")
-        try:
-            sock.settimeout(shm_doorbell_timeout())
-            hello = _recv_exact(sock, _SHM_HELLO.size)
-            gen, nlen, ring = _SHM_HELLO.unpack(hello)
-            name = _recv_exact(sock, nlen).decode("utf-8")
-            seg = _attach_shm_segment(name)
-        except (ConnectionError, OSError, struct.error) as e:
-            sock.close()
-            raise PolicyRpcError(
-                grpc.StatusCode.UNAVAILABLE, f"shm connect {doorbell}: {e}"
-            )
-        self.sock = sock
-        self.seg = seg
-        self.ring = int(ring)
-        self.generation = int(gen)
-        self.req = memoryview(seg.buf)[: self.ring]
-        self.resp = memoryview(seg.buf)[self.ring : 2 * self.ring]
-
-    def destroy(self):
-        try:
-            self.sock.close()
-        except OSError:
-            pass
-        self.req.release()
-        self.resp.release()
-        try:
-            self.seg.close()
-        except BufferError:  # pragma: no cover - caller kept a view
-            pass
-
-
-class ShmTransport:
-    """Client side of the shm tier: a small pool of persistent
-    connections (pipelined step reports overlap calls, like the UDS
-    pool), per-call socket timeouts from the deadline budget, and the
-    same PolicyRpcError surfaces as the other tiers. Ordinary
-    responses are copied out of the response region (one copy, the
-    same cost as a socket recv); broadcast markers resolve to a
-    memoryview over the published segment this process maps once per
-    version — the zero-copy model-down path."""
-
-    name = TRANSPORT_SHM
-
-    #: broadcast attachments kept mapped per transport
-    BCAST_KEEP = 4
-
-    def __init__(self, port: int, fault_plan=None):
-        self._port = int(port)
-        self._doorbell = shm_doorbell_path(port)
-        self._plan = fault_plan
-        self._pool: list = []
-        self._pool_lock = threading.Lock()
-        self._bcast: Dict[str, tuple] = {}  # name -> (SharedMemory, view)
-        self._bcast_order: list = []
-        self._bcast_retired: list = []
-        self._bcast_lock = threading.Lock()
-
-    def _checkout(self) -> _ShmConn:
-        with self._pool_lock:
-            if self._pool:
-                return self._pool.pop()
-        return _ShmConn(self._doorbell)
-
-    def _checkin(self, conn: _ShmConn):
-        with self._pool_lock:
-            if len(self._pool) < 8:
-                self._pool.append(conn)
-                return
-        conn.destroy()
-
-    def call(self, method: str, payload: bytes, timeout: float) -> bytes:
-        # connect first, as UdsTransport.call does and for its reason
-        conn = self._checkout()
-        try:
-            after = transport_faults_before(self._plan, method, "client")
-            conn.sock.settimeout(max(0.001, float(timeout)))
-            mb = method.encode("utf-8")
-            n = len(payload)
-            if n <= conn.ring:
-                conn.req[:n] = payload
-                conn.sock.sendall(_SHM_REQ.pack(1, len(mb), n) + mb)
-            else:
-                conn.sock.sendall(_SHM_REQ.pack(2, len(mb), n) + mb)
-                pv = memoryview(payload)
-                sent = 0
-                while sent < n:
-                    clen = min(conn.ring, n - sent)
-                    conn.req[:clen] = pv[sent : sent + clen]
-                    conn.sock.sendall(_SHM_CHUNK.pack(clen))
-                    _recv_exact(conn.sock, 1)  # server copied the chunk
-                    sent += clen
-            status, length = _SHM_RESP.unpack(
-                _recv_exact(conn.sock, _SHM_RESP.size)
-            )
-            if status == 0:
-                # private copy: the response region is reused by the
-                # next call on this connection
-                body = bytes(conn.resp[:length])
-            elif status == 3:
-                body = self._resolve_bcast(bytes(conn.resp[:length]))
-            elif status == 2:
-                buf, body = _frame_buffer(length)
-                got = 0
-                while got < length:
-                    (clen,) = _SHM_CHUNK.unpack(
-                        _recv_exact(conn.sock, _SHM_CHUNK.size)
-                    )
-                    if clen > conn.ring or got + clen > length:
-                        raise ConnectionError(
-                            f"shm chunk overrun ({clen} bytes)"
-                        )
-                    buf[got : got + clen] = conn.resp[:clen]
-                    got += clen
-                    conn.sock.sendall(_SHM_ACK)
-            else:
-                code_val, dlen = _SHM_ERR.unpack(
-                    _recv_exact(conn.sock, _SHM_ERR.size)
-                )
-                detail = _recv_exact(conn.sock, dlen).decode("utf-8", "replace")
-                code = _CODE_BY_VALUE.get(code_val, grpc.StatusCode.UNKNOWN)
-                self._checkin(conn)
-                conn = None
-                raise PolicyRpcError(code, detail)
-        except socket.timeout:
-            conn.destroy()
-            conn = None
-            raise PolicyRpcError(
-                grpc.StatusCode.DEADLINE_EXCEEDED,
-                f"shm call {method} timed out after {timeout:.3f}s",
-            )
-        except (ConnectionError, OSError, struct.error) as e:
-            conn.destroy()
-            conn = None
-            raise PolicyRpcError(
-                grpc.StatusCode.UNAVAILABLE, f"shm {self._doorbell}: {e}"
-            )
-        finally:
-            if conn is not None:
-                self._checkin(conn)
-        transport_faults_after(after, method)
-        return body
-
-    def _resolve_bcast(self, marker: bytes):
-        """Resolve a broadcast marker to a memoryview over this
-        process's mapping of the published segment. An attach race with
-        segment rotation surfaces as UNAVAILABLE — retryable, and the
-        retried pull lands on the current version's segment."""
-        try:
-            ref = messages.unpack(marker).get(_SHM_BCAST_KEY)
-        except Exception:
-            ref = None
-        if not isinstance(ref, dict):
-            raise PolicyRpcError(
-                grpc.StatusCode.INTERNAL, "shm broadcast marker malformed"
-            )
-        name = str(ref.get("seg", ""))
-        n = int(ref.get("n", 0))
-        with self._bcast_lock:
-            ent = self._bcast.get(name)
-        if ent is None:
-            # first touch of this segment in this process: the actual
-            # page-in cost of the zero-copy model-down path — spanned
-            # so the overlap A/B's traces show where it lands (on the
-            # background absorb thread, not the step loop)
-            with obs_trace.span(
-                "rpc.client.bcast_map", cat="rpc", args={"seg": name}
-            ):
-                try:
-                    seg = _attach_shm_segment(name)
-                except (OSError, ValueError) as e:
-                    raise PolicyRpcError(
-                        grpc.StatusCode.UNAVAILABLE,
-                        f"shm broadcast segment {name} rotated: {e}",
-                    )
-                view = memoryview(seg.buf)
-            evicted = []
-            with self._bcast_lock:
-                if name not in self._bcast:
-                    self._bcast[name] = (seg, view)
-                    self._bcast_order.append(name)
-                    while len(self._bcast_order) > self.BCAST_KEEP:
-                        evicted.append(
-                            self._bcast.pop(self._bcast_order.pop(0))
-                        )
-                    retired, self._bcast_retired = self._bcast_retired, []
-                else:
-                    evicted.append((seg, view))
-                    retired = []
-                ent = self._bcast[name]
-            for old_seg, old_view in evicted:
-                old_view.release()
-                self._close_or_retire(old_seg)
-            for old_seg in retired:
-                self._close_or_retire(old_seg)
-        return ent[1][:n]
-
-    def _close_or_retire(self, seg) -> None:
-        try:
-            seg.close()  # attachment only; the server owns the unlink
-        except BufferError:
-            # a resolved pull response still references the mapping;
-            # retry on a later eviction instead of invalidating it
-            with self._bcast_lock:
-                self._bcast_retired.append(seg)
-
-    def close(self) -> None:
-        """Destroy pooled connections and drop broadcast attachments
-        (mappings a caller still references are left to the GC)."""
-        with self._pool_lock:
-            pool, self._pool = self._pool, []
-        for conn in pool:
-            conn.destroy()
-        with self._bcast_lock:
-            entries = list(self._bcast.values())
-            self._bcast.clear()
-            self._bcast_order.clear()
-            retired, self._bcast_retired = self._bcast_retired, []
-        for seg, view in entries:
-            view.release()
-            self._close_or_retire(seg)
-        for seg in retired:
-            self._close_or_retire(seg)
 
 
 # --------------------------------------------------------------------------
@@ -1748,8 +915,7 @@ def select_transport(addr: str, fault_plan=None, tier: Optional[str] = None):
 
     `tier` overrides the process-wide EDL_TRANSPORT mode for ONE link —
     the aggregation tree uses it to pin the aggregator->PS upstream leg
-    to uds/grpc while the worker->aggregator leg keeps the ambient shm
-    mode (agg/aggregator.py). Unknown values fall back to the env mode
+    (agg/aggregator.py). Unknown values fall back to the env mode
     rather than raising (same never-raises contract)."""
     mode = transport_mode()
     if tier is not None:
@@ -1763,10 +929,6 @@ def select_transport(addr: str, fault_plan=None, tier: Optional[str] = None):
         return None
     if mode in (TRANSPORT_INPROC, "auto") and inproc_dispatcher(port) is not None:
         return InprocTransport(port, fault_plan)
-    if mode in (TRANSPORT_SHM, "auto"):
-        info = read_shm_rendezvous(port)
-        if info is not None and os.path.exists(str(info.get("doorbell", ""))):
-            return ShmTransport(port, fault_plan)
     if mode in (TRANSPORT_UDS, "auto"):
         path = uds_path_for(port)
         if os.path.exists(path):

@@ -411,9 +411,8 @@ class Worker:
         self._sync_bucket_bytes = sync_bucket_bytes
         self._bucket_bounds = None  # lazy: layer-aligned cut points
         # Async model-down absorb: a daemon thread pulls the announced
-        # newer model (over shm this maps the prepacked broadcast
-        # segment — a zero-copy page-in) and stages it in
-        # `_absorb_staged` under `_report_lock`; the step loop folds it
+        # newer model and stages it in `_absorb_staged` under
+        # `_report_lock`; the step loop folds it
         # in at the next window boundary through the same monotonic
         # version guard as piggyback absorbs. The staging buffer is
         # sync-thread state: never read it bare on the step loop (see
@@ -2711,9 +2710,8 @@ class Worker:
     def _maybe_start_bg_pull(self, min_version: int):
         """Kick the async model-down page-in: when a task announces a
         newer version, pull it on a daemon thread while the step loop
-        keeps computing (over shm the pull maps the prepacked broadcast
-        segment — a zero-copy page-in). The result is STAGED, never
-        applied: `_apply_staged_model` folds it in at the next window
+        keeps computing. The result is STAGED, never applied:
+        `_apply_staged_model` folds it in at the next window
         boundary. No-op when the overlap plane is off, a pull is
         already in flight, or something is already staged."""
         if not self._overlap_sync or not self._use_flat():

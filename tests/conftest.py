@@ -43,13 +43,14 @@ atexit.register(shutil.rmtree, _uds_dir, ignore_errors=True)
 
 # -- OS-resource leak sweep ----------------------------------------------------
 #
-# The transport/chaos/migration suites spawn real servers backed by
-# /dev/shm segments and AF_UNIX sockets; a teardown bug there leaks
-# kind (docs/fault_model.md, SIGKILL reclamation) and, being
-# name-collision-prone, poisons LATER tests in the same run. The sweep
-# snapshots both namespaces around each test in the suites that own
-# them and fails loud with the leaked names — the runtime counterpart
-# of the static `resource-lifecycle` family.
+# The transport/chaos/migration suites spawn real servers, each with
+# an AF_UNIX listener; a teardown bug there leaks its socket file
+# (docs/fault_model.md, SIGKILL reclamation) and, the name being the
+# port's, poisons a LATER test that is handed the port again. The
+# sweep snapshots this process's socket directory around each test in
+# the suites that own such servers and fails loud with the leaked
+# names — the runtime counterpart of the static `resource-lifecycle`
+# family.
 
 _SWEPT_MODULES = frozenset({
     "test_transport",
@@ -58,29 +59,15 @@ _SWEPT_MODULES = frozenset({
     "test_migration",
     "test_process_job",
 })
-_SHM_DIR = "/dev/shm"
 _LEAK_GRACE_SECS = 5.0
 
 
-def _shm_segments():
-    try:
-        names = os.listdir(_SHM_DIR)
-    except OSError:
-        return frozenset()
-    return frozenset(n for n in names if n.startswith("edlshm."))
-
-
 def _stray_uds():
-    from elasticdl_tpu.rpc import transport
-
     try:
-        names = os.listdir(transport.uds_dir())
+        names = os.listdir(_uds_dir)
     except OSError:
         return frozenset()
-    return frozenset(
-        n for n in names
-        if n.startswith("edl-uds-") or n.startswith("edl-shm-")
-    )
+    return frozenset(n for n in names if n.startswith("edl-uds-"))
 
 
 @pytest.fixture(autouse=True)
@@ -88,29 +75,18 @@ def _os_resource_sweep(request):
     if request.module.__name__ not in _SWEPT_MODULES:
         yield
         return
-    shm_before = _shm_segments()
-    uds_before = _stray_uds()
+    before = _stray_uds()
     yield
     # daemon reaper threads (subprocess transports, deferred unlinks)
     # may lag the test body by a beat; poll before declaring a leak
     deadline = time.monotonic() + _LEAK_GRACE_SECS
     while True:
-        leaked_shm = _shm_segments() - shm_before
-        leaked_uds = _stray_uds() - uds_before
-        if not leaked_shm and not leaked_uds:
+        leaked = _stray_uds() - before
+        if not leaked:
             return
         if time.monotonic() >= deadline:
             break
         time.sleep(0.05)
-    parts = []
-    if leaked_shm:
-        parts.append(
-            f"/dev/shm segments leaked: {sorted(leaked_shm)}"
-        )
-    if leaked_uds:
-        parts.append(
-            f"stray transport sockets/manifests leaked: {sorted(leaked_uds)}"
-        )
     pytest.fail(
-        f"{request.node.nodeid} leaked OS resources — " + "; ".join(parts)
+        f"{request.node.nodeid} leaked transport sockets: {sorted(leaked)}"
     )

@@ -34,6 +34,15 @@ def optimizer():
     return optax.sgd(0.5)
 
 
+def optimizer_delayed():
+    """For jobs whose pushes apply one version late (two async
+    workers): x ~ U(-1, 1) gives the bias curvature 2, and an update
+    delayed by one step is stable only below step * curvature = 1 —
+    sgd(0.5) sits on that edge, 0.3 is inside it and still lands the
+    kernel within 0.2 of 2.0 in 16 steps."""
+    return optax.sgd(0.3)
+
+
 def eval_metrics_fn(predictions, labels):
     return {"mse": jnp.mean((predictions - labels) ** 2)}
 

@@ -427,7 +427,8 @@ def test_agg_sigkill_mid_cohort_falls_back_exact(tmp_path, monkeypatch):
     report_key — final shard versions exactly equal the push count, no
     member lost, no member double-applied — the death is visible to the
     recovery plane via poll_dead, the relaunched slot serves at a
-    bumped generation, and the job's shm segments are swept on stop."""
+    bumped generation, and its boot swept the dead node's socket file:
+    the job leaves none behind on stop."""
     from elasticdl_tpu.common.constants import (
         ENV_RPC_BACKOFF,
         ENV_RPC_RETRIES,
@@ -435,7 +436,7 @@ def test_agg_sigkill_mid_cohort_falls_back_exact(tmp_path, monkeypatch):
         ENV_UDS_DIR,
     )
 
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
     monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
     # the dead node must surface as an outage fast (the client replays
     # direct), not ride the production backoff ladder
@@ -520,12 +521,8 @@ def test_agg_sigkill_mid_cohort_falls_back_exact(tmp_path, monkeypatch):
             ps.close()
         agg.stop()
         group.stop()
-    # the SIGKILLed node's segments were reclaimed; teardown left the
-    # tier clean (same contract as the PS shm chaos test)
+    # the SIGKILLed node's socket file was reclaimed; teardown left the
+    # directory clean (same contract as the PS SIGKILL chaos test)
     assert not [
-        f for f in os.listdir("/dev/shm") if f.startswith("edlshm.")
-    ]
-    assert not [
-        f for f in os.listdir(str(tmp_path))
-        if f.startswith("edl-shm-") and f.endswith(".json")
+        f for f in os.listdir(str(tmp_path)) if f.startswith("edl-uds-")
     ]

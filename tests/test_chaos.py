@@ -489,6 +489,12 @@ def _run_training_job(tmp, tag, monkeypatch, chaos_spec):
             "--num_ps", "2",
             "--ps_mode", "inproc",
             "--staleness_window", "1",
+            # two workers applying every push as it lands work one
+            # version behind each other: at the fixture's default
+            # step (0.5) the bias sits on the stability edge of a
+            # one-step-delayed update and rings for the whole job
+            # whenever both workers start in the same instant
+            "--optimizer", "optimizer_delayed",
         ]
     )
     _spec, dispatcher, servicer, _evs, _ckpt = build_master(args, "training")
@@ -617,17 +623,22 @@ def test_chaos_training_job_exact_accounting(tmp_path, monkeypatch):
 
 @pytest.mark.e2e
 @pytest.mark.chaos
-def test_chaos_exact_accounting_over_uds_tier(tmp_path, monkeypatch):
+@pytest.mark.parametrize("mode", ["uds", "unset"])
+def test_chaos_exact_accounting_over_the_local_carrier(
+    mode, tmp_path, monkeypatch
+):
     """The acceptance run again, but with every localhost RPC routed
-    over the Unix-domain-socket fast path (EDL_TRANSPORT=uds inherits
-    into the spawned workers). Faults inject at the UDS framing layer
+    over the Unix-domain-socket carrier: named (EDL_TRANSPORT=uds
+    inherits into the spawned workers) and as what a local peer gets
+    with nothing set. Faults inject at the UDS framing layer
     (transport_faults_before/after) instead of gRPC interceptors, and
     the accounting bar is the same absolute one: every record exactly
     once, dedup absorbing the drop-retry, shard versions landing at
     [16, 16]. Uses real subprocess workers — the crash fault's
     os._exit must kill a worker, not the test process, so the inproc
     tier is deliberately NOT exercised here (it has no process
-    boundary and no crash surface)."""
+    boundary and no crash surface). Teardown is part of the carrier's
+    contract: the job leaves no socket file behind."""
     from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
     from elasticdl_tpu.testing import write_linear_records
 
@@ -636,7 +647,10 @@ def test_chaos_exact_accounting_over_uds_tier(tmp_path, monkeypatch):
         write_linear_records(
             os.path.join(tmp, f"shard-{i}.rio"), 64, seed=i, noise=0.05
         )
-    monkeypatch.setenv(ENV_TRANSPORT, "uds")
+    if mode == "unset":
+        monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    else:
+        monkeypatch.setenv(ENV_TRANSPORT, mode)
     monkeypatch.setenv(ENV_UDS_DIR, tmp)
     chaos_spec = {
         "seed": 11,
@@ -651,7 +665,7 @@ def test_chaos_exact_accounting_over_uds_tier(tmp_path, monkeypatch):
              "once_file": os.path.join(tmp, "crash.once")},
         ],
     }
-    result = _run_training_job(tmp, "uds-chaos", monkeypatch, chaos_spec)
+    result = _run_training_job(tmp, f"{mode}-chaos", monkeypatch, chaos_spec)
     # exact accounting: identical absolute numbers to the fault-free
     # gRPC baseline in test_chaos_training_job_exact_accounting
     assert result["completed_records"] == 256
@@ -665,82 +679,44 @@ def test_chaos_exact_accounting_over_uds_tier(tmp_path, monkeypatch):
     tiers = result["server_transports"]
     assert tiers.get("uds", {}).get("calls", 0) > 0, tiers
     assert tiers.get("grpc", {}).get("calls", 0) == 0, tiers
+    assert not [f for f in os.listdir(tmp) if f.startswith("edl-uds-")]
 
 
 @pytest.mark.e2e
 @pytest.mark.chaos
-def test_chaos_exact_accounting_over_shm_tier(tmp_path, monkeypatch):
-    """The acceptance run over the shared-memory ring tier
-    (EDL_TRANSPORT=shm inherits into the spawned workers; the
-    rendezvous files live in the pinned EDL_UDS_DIR). Faults inject at
-    the shm framing layer through the SAME transport_faults_before/
-    after hooks as the uds tier, and the bar is the same absolute one:
-    every record exactly once, dedup absorbing the drop-retry, shard
-    versions landing at [16, 16]. Also asserts the job left no orphan
-    ring segments behind — teardown is part of the tier's contract."""
-    from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
-    from elasticdl_tpu.testing import write_linear_records
-
-    tmp = str(tmp_path)
-    for i in range(2):
-        write_linear_records(
-            os.path.join(tmp, f"shard-{i}.rio"), 64, seed=i, noise=0.05
-        )
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
-    monkeypatch.setenv(ENV_UDS_DIR, tmp)
-    chaos_spec = {
-        "seed": 11,
-        "faults": [
-            {"kind": "error", "code": "UNAVAILABLE",
-             "methods": ["PSPushGrad"], "roles": ["worker"], "every": 4,
-             "max_fires": 3},
-            {"kind": "drop", "methods": ["PSPushGrad"], "roles": ["worker"],
-             "nth": 3},
-            {"kind": "crash", "methods": ["GetTask"], "roles": ["worker"],
-             "targets": ["0"], "nth": 2, "when": "after",
-             "once_file": os.path.join(tmp, "crash.once")},
-        ],
-    }
-    result = _run_training_job(tmp, "shm-chaos", monkeypatch, chaos_spec)
-    # exact accounting: identical absolute numbers to the fault-free
-    # gRPC baseline in test_chaos_training_job_exact_accounting
-    assert result["completed_records"] == 256
-    assert result["versions"] == [16, 16]
-    assert result["applied"] == 32
-    assert result["duplicates"] >= 1, "no drop-retry was deduped"
-    assert result["relaunches"] >= 1
-    assert abs(result["kernel"] - 2.0) < 0.6, result["kernel"]
-    # the ring tier actually carried the job: worker calls over shm,
-    # none over grpc or uds (no silent fallback to a socket path)
-    tiers = result["server_transports"]
-    assert tiers.get("shm", {}).get("calls", 0) > 0, tiers
-    assert tiers.get("grpc", {}).get("calls", 0) == 0, tiers
-    assert tiers.get("uds", {}).get("calls", 0) == 0, tiers
-    # teardown left no ring segments or rendezvous files behind
-    assert not [
-        f for f in os.listdir("/dev/shm") if f.startswith("edlshm.")
-    ]
-    assert not [
-        f for f in os.listdir(tmp)
-        if f.startswith("edl-shm-") and f.endswith(".json")
-    ]
-
-
-@pytest.mark.e2e
-@pytest.mark.chaos
-def test_shm_sigkill_shard_leaves_no_orphan_segments(tmp_path, monkeypatch):
-    """Stale-ring reclamation, end to end: SIGKILL a PS shard
-    subprocess serving over shm (no atexit, no finally — the kernel
-    keeps its segments and rendezvous file alive), relaunch the slot at
-    a bumped fencing generation, and assert the successor's boot sweep
-    removed every dead-generation segment. The group teardown must then
-    leave /dev/shm and the rendezvous dir empty."""
+def test_sigkill_shard_leaves_a_socket_file_the_relaunch_sweeps(
+    tmp_path, monkeypatch
+):
+    """Dead-listener reclamation, end to end: SIGKILL a PS shard
+    subprocess serving over its Unix socket (no atexit, no finally —
+    its socket file stays, with nothing listening behind it), relaunch
+    the slot at a bumped fencing generation, and assert the
+    successor's boot sweep removed the file and the slot serves over
+    `uds` again. The group teardown must then leave the socket
+    directory empty."""
     import signal
 
     from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
     from elasticdl_tpu.master.ps_group import PSShardGroup
+    from elasticdl_tpu.rpc import transport
 
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
+    def socket_files():
+        return sorted(
+            f for f in os.listdir(str(tmp_path)) if f.startswith("edl-uds-")
+        )
+
+    def live_socket_files():
+        return sorted(
+            os.path.basename(
+                transport.uds_path_for(int(ep.rpartition(":")[2]))
+            )
+            for ep in group.endpoints
+        )
+
+    def carriers():
+        return {c._transport.name for c in group.client()._clients}
+
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
     monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
     group = PSShardGroup(
         2,
@@ -758,39 +734,31 @@ def test_shm_sigkill_shard_leaves_no_orphan_segments(tmp_path, monkeypatch):
         group.ensure_init(vec)
         versions, got = group.client().pull()
         np.testing.assert_array_equal(got, vec)
-        live = [
-            f for f in os.listdir("/dev/shm") if f.startswith("edlshm.")
-        ]
-        assert any(".ps0.g0." in s for s in live), live
+        assert carriers() == {"uds"}
+        assert socket_files() == live_socket_files()
+        dead = live_socket_files()[0]
 
         pid = group._procs[0].pid
         os.kill(pid, signal.SIGKILL)
         group._procs[0].wait()
+        assert dead in socket_files(), "SIGKILL runs no teardown"
         group.relaunch_shard(0)  # generation 0 -> 1
-        deadline = time.time() + 10
-        while time.time() < deadline:
-            orphans = [
-                f
-                for f in os.listdir("/dev/shm")
-                if f.startswith("edlshm.") and ".ps0.g0." in f
-            ]
-            if not orphans:
-                break
-            time.sleep(0.05)
-        assert not orphans, f"dead-generation segments survived: {orphans}"
-        # the relaunched (empty) slot re-inits and serves over shm again
+        # the successor swept the dead listener's file at boot: what
+        # is left is one file per live shard (the kernel may hand the
+        # dead port out again, so the name alone proves nothing)
+        assert socket_files() == live_socket_files()
+        # the relaunched (empty) slot re-inits and serves over uds again
         group.ensure_init(vec)
+        assert carriers() == {"uds"}
+        for c in group.client()._clients:
+            c.wire.reset()
         versions, _got = group.client().pull()
         assert len(versions) == 2
+        tiers = group.client().wire_stats()["transports"]
+        assert tiers["uds"]["calls"] == 2 and "grpc" not in tiers, tiers
     finally:
         group.stop()
-    assert not [
-        f for f in os.listdir("/dev/shm") if f.startswith("edlshm.")
-    ]
-    assert not [
-        f for f in os.listdir(str(tmp_path))
-        if f.startswith("edl-shm-") and f.endswith(".json")
-    ]
+    assert socket_files() == []
 
 
 @pytest.mark.e2e
@@ -1405,16 +1373,16 @@ def test_flight_recorder_orders_fault_fence_and_recovery():
 
 @pytest.mark.e2e
 @pytest.mark.chaos
-def test_traced_chaos_job_over_shm_emits_sync_span_tree(
+def test_traced_chaos_job_over_uds_emits_sync_span_tree(
     tmp_path, monkeypatch
 ):
-    """The chaos job over shm, traced (EDL_TRACE_SAMPLE=1) on the loop
-    dispatch core: the master-process span ring must reconstruct the
-    sync chain worker -> transport -> dispatcher admission -> shard
-    apply as a Perfetto-loadable trace — server spans carry the shm
-    tier and a worker-side parent (the envelope crossed the ring),
-    admission waits chain under them, and the shard applies share their
-    traces. Accounting stays exact: the dispatch core and the tracer
+    """The chaos job on the default carrier, traced
+    (EDL_TRACE_SAMPLE=1) on the loop dispatch core: the master-process
+    span ring must reconstruct the sync chain worker -> transport ->
+    dispatcher admission -> shard apply as a Perfetto-loadable trace —
+    server spans carry the uds tier and a worker-side parent (the
+    envelope crossed the socket), admission waits chain under them,
+    and the shard applies share their traces. Accounting stays exact: the dispatch core and the tracer
     change how requests are served and observed, never the result."""
     from elasticdl_tpu.common.constants import (
         ENV_DISPATCH,
@@ -1430,7 +1398,7 @@ def test_traced_chaos_job_over_shm_emits_sync_span_tree(
         write_linear_records(
             os.path.join(tmp, f"shard-{i}.rio"), 64, seed=i, noise=0.05
         )
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
     monkeypatch.setenv(ENV_UDS_DIR, tmp)
     monkeypatch.setenv(ENV_DISPATCH, "loop")
     monkeypatch.setenv(ENV_TRACE_SAMPLE, "1")
@@ -1448,7 +1416,7 @@ def test_traced_chaos_job_over_shm_emits_sync_span_tree(
     }
     try:
         result = _run_training_job(
-            tmp, "shm-traced-chaos", monkeypatch, chaos_spec
+            tmp, "uds-traced-chaos", monkeypatch, chaos_spec
         )
         assert result["completed_records"] == 256
         assert result["versions"] == [16, 16]
@@ -1458,9 +1426,9 @@ def test_traced_chaos_job_over_shm_emits_sync_span_tree(
         spans = obs_trace.RECORDER.snapshot()
         sync = [s for s in spans if s["name"] == "rpc.server.PSPushGrad"]
         assert sync, sorted({s["name"] for s in spans})
-        # the envelope crossed the shm ring: every sync serve names the
+        # the envelope crossed the socket: every sync serve names the
         # tier and chains under a worker-process client span
-        assert {s["args"]["transport"] for s in sync} == {"shm"}
+        assert {s["args"]["transport"] for s in sync} == {"uds"}
         assert all(s["parent_id"] for s in sync)
         sync_ids = {s["span_id"] for s in sync}
         sync_traces = {s["trace_id"] for s in sync}

@@ -54,7 +54,7 @@ def _echo_roundtrip():
     return outer_id
 
 
-@pytest.mark.parametrize("tier", ["grpc", "uds", "inproc", "shm", "unset"])
+@pytest.mark.parametrize("tier", ["grpc", "uds", "inproc", "unset"])
 def test_span_parent_child_roundtrip_per_tier(tier, monkeypatch, tmp_path):
     monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
     if tier == "unset":

@@ -23,6 +23,8 @@ from elasticdl_tpu.worker.worker import Worker
 
 from tests.fixtures import linear_module
 
+FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures")
+
 
 def test_slice_boundaries_cover_and_partition():
     for n, k in [(10, 3), (7, 7), (5, 8), (1000003, 4), (0, 2)]:
@@ -559,6 +561,101 @@ def test_process_mode_shard_group(tmp_path):
         group.stop()
 
 
+# -- the spawn contract: group and shard main are one version ------------------
+
+
+class _RecordedSpawn:
+    """Stands in for subprocess.Popen inside master/shard_host.py:
+    keeps the argv a group would have executed and publishes a port,
+    so `start()` returns without a process."""
+
+    spawned = []
+
+    def __init__(self, argv, env=None):
+        type(self).spawned.append(list(argv))
+        with open(argv[argv.index("--port_file") + 1], "w") as f:
+            f.write("1")
+        self.returncode = None
+
+    def poll(self):
+        return self.returncode
+
+    def terminate(self):
+        self.returncode = 0
+
+    def wait(self, timeout=None):
+        return self.returncode
+
+
+def _ps_group():
+    return PSShardGroup(
+        2,
+        mode="process",
+        shard_argv=[
+            "--model_zoo", FIXTURES,
+            "--model_def", "linear_module.custom_model",
+            "--minibatch_size", "16",
+        ],
+        use_async=True,
+        lr_staleness_modulation=True,
+        fanin_combine=True,
+    )
+
+
+def _kv_group():
+    from elasticdl_tpu.master.kv_group import KVShardGroup
+
+    return KVShardGroup(2, mode="process")
+
+
+def _agg_group():
+    from elasticdl_tpu.agg.group import AggGroup
+
+    return AggGroup(2, ["localhost:1", "localhost:2"], mode="process")
+
+
+@pytest.mark.parametrize(
+    "make_group, entry, parser_name",
+    [
+        (_ps_group, "elasticdl_tpu.master.ps_shard_main", "ps_shard_parser"),
+        (_kv_group, "elasticdl_tpu.master.kv_shard_main", "kv_shard_parser"),
+        (_agg_group, "elasticdl_tpu.agg.agg_main", "agg_parser"),
+    ],
+    ids=["ps", "kv", "agg"],
+)
+def test_group_spawn_argv_parses_under_its_mains_parser(
+    make_group, entry, parser_name, monkeypatch
+):
+    """A group and the main it spawns ship together, so a flag removed
+    on one side only is a boot failure of every process-mode job.
+    Everything a group hands its subprocess must parse under that
+    main's OWN parser with nothing left over, and say which slot and
+    which fencing generation it is — for a first boot and a relaunch."""
+    import importlib
+
+    from elasticdl_tpu.master import shard_host
+
+    monkeypatch.setattr(_RecordedSpawn, "spawned", [])
+    monkeypatch.setattr(shard_host.subprocess, "Popen", _RecordedSpawn)
+    group = make_group()
+    try:
+        group.start()
+        group.relaunch_shard(1)
+    finally:
+        group.stop()
+    spawned = _RecordedSpawn.spawned
+    assert len(spawned) == 3
+    parser = getattr(importlib.import_module(entry), parser_name)()
+    slots = []
+    for argv in spawned:
+        assert argv[1:3] == ["-m", entry]
+        args, unknown = parser.parse_known_args(argv[3:])
+        assert unknown == [], unknown
+        slot = args.agg_id if entry.endswith("agg_main") else args.shard_id
+        slots.append((slot, args.generation))
+    assert slots == [(0, 0), (1, 0), (1, 1)]
+
+
 # -- pull prepack cache (model-down broadcast) --------------------------------
 
 
@@ -667,55 +764,45 @@ def test_pull_encode_runs_outside_shard_lock():
     np.testing.assert_array_equal(result["resp"]["vec"], np.ones(32))
 
 
-def test_pull_prepack_shm_broadcast_views_survive_server_close():
-    """Over the shm tier a pull resolves to a view over the broadcast
-    segment. A client that already resolved a frame must be able to
-    keep READING it after the server closes (Linux keeps unlinked
-    mappings alive until the last map drops) — only new calls fail."""
-    import tempfile
-
+def test_pull_prepack_over_the_wire_one_encode_and_views_outlive_server(
+    monkeypatch, tmp_path
+):
+    """Over the local carrier N pullers of one version are served the
+    SAME cached frame bytes: one encode, no per-pull copy on the serve
+    path. An array a client decoded from its reply is a view of the
+    buffer the reply was received into, which the client owns: it
+    stays readable after the server stops — only new calls fail."""
     from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
     from elasticdl_tpu.rpc.client import RpcClient
     from elasticdl_tpu.rpc.server import RpcServer
 
-    tmp = tempfile.mkdtemp()
-    prev = {
-        k: os.environ.get(k) for k in (ENV_TRANSPORT, ENV_UDS_DIR)
-    }
-    os.environ[ENV_TRANSPORT] = "shm"
-    os.environ[ENV_UDS_DIR] = tmp
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    shard = PSShardServicer(0, 1)
+    server = RpcServer(shard.handlers(), port=0)
+    shard.attach_wire_stats(server.wire)
+    server.start()
+    clients = [RpcClient(f"localhost:{server.port}") for _ in range(4)]
     try:
-        shard = PSShardServicer(0, 1)
-        server = RpcServer(
-            shard.handlers(), port=0, shm_scope="tt.bcast", shm_generation=0
-        )
-        shard.attach_wire_stats(server.wire)
-        shard.attach_shm_publisher(server.shm_broadcaster)
-        server.start()
-        client = RpcClient(f"localhost:{server.port}")
-        try:
-            vec = np.arange(1024, dtype=np.float32)
-            client.call("PSInit", {"vec": vec, "version": 0})
-            got = client.call("PSPull", {})
-            np.testing.assert_array_equal(got["vec"], vec)
-            stats = shard.stats()
-            assert stats["prepack_encodes"] == 1
-            assert stats["prepack_encode_copy_bytes"] == 0
-            server.stop()
-            # the already-decoded response stays readable post-close
-            np.testing.assert_array_equal(got["vec"], vec)
-        finally:
-            client.close()
-            server.stop()
-        assert not [
-            f for f in os.listdir("/dev/shm") if ".tt.bcast." in f
-        ]
+        assert {c._transport.name for c in clients} == {"uds"}
+        vec = np.arange(1024, dtype=np.float32)
+        clients[0].call("PSInit", {"vec": vec, "version": 0})
+        got = [c.call("PSPull", {}) for c in clients]
+        stats = shard.stats()
+        assert stats["prepack_encodes"] == 1
+        assert stats["prepack_served_pulls"] == 4
+        assert stats["prepack_encode_copy_bytes"] == 0
+        server.stop()
+        for c in clients:
+            c.close()
+        # the already-decoded responses stay readable post-close
+        for resp in got:
+            assert resp["version"] == 0
+            np.testing.assert_array_equal(resp["vec"], vec)
     finally:
-        for k, v in prev.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        for c in clients:
+            c.close()
+        server.stop()
 
 
 def test_reset_local_state_clears_shard_versions():

@@ -351,107 +351,6 @@ def test_transport_lints_the_real_tree():
     ], findings
 
 
-# shm-tier twin of TRANSPORT_GOOD, shaped like the real rpc/transport.py
-# shm plane: a registered ShmTransport, a ring listener funneling every
-# frame through the dispatcher, and a non-Transport/-Server helper
-# (ShmBroadcaster) that the registry rules must NOT scope in
-TRANSPORT_SHM_GOOD = """
-TRANSPORT_SHM = "shm"
-TRANSPORT_TIERS = ("grpc", "uds", TRANSPORT_SHM, "inproc")
-
-
-def transport_faults_before(plan, method, side):
-    return []
-
-
-def transport_faults_after(after, method):
-    pass
-
-
-class ServerDispatcher:
-    def dispatch(self, method, request_bytes, transport):
-        after = transport_faults_before(None, method, "server")
-        resp = b""
-        transport_faults_after(after, method)
-        return resp
-
-
-class ShmTransport:
-    name = TRANSPORT_SHM
-
-    def call(self, method, payload, timeout):
-        after = transport_faults_before(None, method, "client")
-        transport_faults_after(after, method)
-        return b""
-
-
-class ShmServer:
-    def serve_conn(self, dispatcher, method, ring_view):
-        body = ring_view[:4]
-        return dispatcher.dispatch(method, body, "shm")
-
-
-class ShmBroadcaster:
-    def publish(self, version, payload):
-        return "edlshm.p0.g0.xb1"
-"""
-
-
-def test_transport_shm_registry_clean(tmp_path):
-    """Negative fixture: a conforming shm tier (registered name, full
-    call surface, chaos hooks, dispatcher-routed ring listener, and a
-    broadcast helper outside the *Transport/*Server naming scope) is
-    lint-silent."""
-    root = _tree(tmp_path, {"transport.py": TRANSPORT_SHM_GOOD})
-    assert run_analysis(root, rules=["rpc-conformance"]) == []
-
-
-def test_transport_shm_unregistered_tier_is_drift(tmp_path):
-    # the shm class ships but TRANSPORT_TIERS never learned the name —
-    # its WireStats rows would be untracked
-    src = TRANSPORT_SHM_GOOD.replace(
-        '("grpc", "uds", TRANSPORT_SHM, "inproc")',
-        '("grpc", "uds", "inproc")',
-    )
-    root = _tree(tmp_path, {"transport.py": src})
-    findings = run_analysis(root, rules=["rpc-conformance"])
-    drift = [f for f in findings if f.check == "transport-surface-drift"]
-    assert len(drift) == 1, findings
-    assert "ShmTransport" in drift[0].message
-
-
-def test_transport_shm_chaos_bypass(tmp_path):
-    # an shm fast path that skips FaultPlan injection: the ring write
-    # is so cheap it is tempting to go straight to the wire
-    src = TRANSPORT_SHM_GOOD.replace(
-        'after = transport_faults_before(None, method, "client")\n'
-        "        transport_faults_after(after, method)",
-        "pass",
-    )
-    root = _tree(tmp_path, {"transport.py": src})
-    findings = run_analysis(root, rules=["rpc-conformance"])
-    bypass = [f for f in findings if f.check == "transport-chaos-bypass"]
-    assert len(bypass) == 1, findings
-    assert "ShmTransport" in bypass[0].message
-
-
-def test_transport_shm_ring_server_dispatch_bypass(tmp_path):
-    # a ring listener decoding frames into its own method table instead
-    # of ServerDispatcher — the one drift the zero-copy path must not
-    # reintroduce
-    src = TRANSPORT_SHM_GOOD.replace(
-        'return dispatcher.dispatch(method, body, "shm")',
-        "return self.handlers[method](body)",
-    )
-    root = _tree(tmp_path, {"transport.py": src})
-    findings = run_analysis(root, rules=["rpc-conformance"])
-    bypass = [
-        f for f in findings if f.check == "transport-dispatch-bypass"
-    ]
-    assert len(bypass) == 1, findings
-    assert "ShmServer" in bypass[0].message
-
-
 # aggregator forward-path twin (agg/aggregator.py): workers push on the
 # dedup-keyed AggPushDelta surface through a dispatcher-routed listener,
 # the presummed cohort forwards upstream as ONE PSPushDeltaCombined
@@ -548,7 +447,7 @@ def test_agg_forward_upstream_chaos_bypass(tmp_path):
 
 
 def test_agg_forward_listener_dispatch_bypass(tmp_path):
-    # an aggregator ring listener decoding worker pushes into its own
+    # an aggregator listener decoding worker pushes into its own
     # method table instead of ServerDispatcher — admission queues,
     # fencing, and server-side chaos would all silently vanish from
     # the worker-facing leg

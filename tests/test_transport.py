@@ -1,16 +1,16 @@
-"""Transport-tier tests: the inproc/UDS/shm fast paths under the gRPC
+"""Transport-tier tests: the inproc/UDS fast paths under the gRPC
 call surface (rpc/transport.py).
 
 Covers tier selection (conservative fallback to gRPC on any doubt),
-round-trips over every tier with the SAME failure semantics (fencing
--> FAILED_PRECONDITION, handler bugs -> INTERNAL with sanitized
-detail, unknown method -> UNIMPLEMENTED), chaos FaultPlan injection on
-the fast paths, the WireStats transport dimension (per-endpoint bytes
-summing correctly across mixed tiers, inproc calls counted with ZERO
-wire bytes), and the shm ring edge cases: frames larger than the ring
-chunk through it, concurrent clients keep frames paired, a closed
-server severs pooled clients, and boot-time reclamation sweeps a dead
-predecessor's segments and rendezvous files.
+one carrier contract held on both carriers a link between processes
+can get — the Unix socket and gRPC, its silent fallback: the SAME
+failure semantics (fencing -> FAILED_PRECONDITION, handler bugs ->
+INTERNAL with sanitized detail, unknown method -> UNIMPLEMENTED, a
+stopped server -> UNAVAILABLE), chaos FaultPlan injection, frames
+bit-equal and never reused — the WireStats transport dimension
+(per-endpoint bytes summing correctly across mixed tiers, inproc calls
+counted with ZERO wire bytes), a frame the socket header cannot
+describe, and the resource lifecycle of the socket listener.
 """
 
 import os
@@ -67,9 +67,23 @@ def inproc_env(monkeypatch):
 
 
 @pytest.fixture
-def shm_env(monkeypatch, tmp_path):
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
+def grpc_env(monkeypatch, tmp_path):
+    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
     monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+
+
+@pytest.fixture(params=["uds", "grpc"])
+def carrier(request, monkeypatch, tmp_path):
+    """The two carriers of a link between processes. gRPC serves any
+    call whose socket cannot connect, unannounced, so whatever the
+    socket tier promises a caller gRPC is held to as well."""
+    monkeypatch.setenv(ENV_TRANSPORT, request.param)
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    return request.param
+
+
+def _tier(client) -> str:
+    return client._transport.name if client._transport else "grpc"
 
 
 # -- tier selection -----------------------------------------------------------
@@ -77,11 +91,10 @@ def shm_env(monkeypatch, tmp_path):
 
 def test_mode_default_and_unknown(monkeypatch):
     # unset: a local peer gets the Unix-socket carrier (never `auto`:
-    # no inproc, no shm); an explicit grpc or an unknown value is grpc
+    # no inproc); an explicit grpc or an unknown value is grpc
     monkeypatch.delenv(ENV_TRANSPORT, raising=False)
     assert transport.transport_mode() == "uds"
     assert transport.server_fast_paths_enabled()
-    assert not transport.server_shm_enabled()
     monkeypatch.setenv(ENV_TRANSPORT, "grpc")
     assert transport.transport_mode() == "grpc"
     assert not transport.server_fast_paths_enabled()
@@ -136,6 +149,29 @@ def test_select_auto_prefers_inproc_over_uds(monkeypatch, tmp_path):
         transport.unregister_inproc(45998)
 
 
+def test_link_tier_pins_one_link_and_unknown_means_ambient(
+    monkeypatch, tmp_path
+):
+    """`tier` overrides the process's mode for ONE link (the
+    aggregator's upstream leg). A value that is not a tier decides
+    nothing: the ambient mode does, as for an unknown EDL_TRANSPORT."""
+    monkeypatch.delenv(ENV_TRANSPORT, raising=False)
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    addr = f"localhost:{server.port}"
+    try:
+        assert transport.select_transport(addr, tier="grpc") is None
+        assert transport.select_transport(addr, tier=" UDS ").name == "uds"
+        assert transport.select_transport(addr, tier="auto").name == "inproc"
+        assert transport.select_transport(addr, tier="warp").name == "uds"
+        monkeypatch.setenv(ENV_TRANSPORT, "grpc")
+        assert transport.select_transport(addr, tier="warp") is None
+        assert transport.select_transport(addr, tier="uds").name == "uds"
+    finally:
+        server.stop()
+
+
 def test_endpoint_is_local_variants():
     assert transport.endpoint_is_local("localhost:1")
     assert transport.endpoint_is_local("127.0.0.1:1")
@@ -160,18 +196,16 @@ def _roundtrip(client):
     )
 
 
-@pytest.mark.parametrize("env_fixture", ["uds_env", "inproc_env", "shm_env"])
+@pytest.mark.parametrize("env_fixture", ["uds_env", "inproc_env", "grpc_env"])
 def test_fast_tier_roundtrip_and_errors(env_fixture, request):
     """Echo round-trip plus the three failure classifications, on each
-    fast tier — byte-identical semantics to the gRPC tier."""
+    tier — byte-identical semantics whichever carries the call."""
     request.getfixturevalue(env_fixture)
     server = RpcServer(_echo_handlers(), port=0)
     server.start()
     client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
     try:
-        expected = ENV_TRANSPORT and os.environ[ENV_TRANSPORT]
-        assert client._transport is not None
-        assert client._transport.name == expected
+        assert _tier(client) == os.environ[ENV_TRANSPORT]
         _roundtrip(client)
         # handler bug -> INTERNAL, sanitized single-line detail
         with pytest.raises(grpc.RpcError) as ei:
@@ -189,7 +223,7 @@ def test_fast_tier_roundtrip_and_errors(env_fixture, request):
         server.stop()
 
 
-def test_uds_unknown_method_unimplemented(uds_env):
+def test_unknown_method_unimplemented(carrier):
     server = RpcServer(_echo_handlers(), port=0)
     server.start()
     client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
@@ -220,13 +254,19 @@ def test_inproc_server_gone_is_unavailable(inproc_env):
         server.stop()
 
 
-def test_uds_server_gone_is_unavailable(uds_env):
+def test_server_gone_is_unavailable(carrier):
+    """stop() severs pooled connections: the next call fails like a
+    stopped gRPC server's, never hangs and never reaches a handler."""
     server = RpcServer(_echo_handlers(), port=0)
     server.start()
     client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
     try:
+        assert _tier(client) == carrier
         _roundtrip(client)
         server.stop()
+        # stopped, not stopping: inside stop()'s grace a gRPC server
+        # still answers a new call, with CANCELLED
+        server.wait()
         with pytest.raises(grpc.RpcError) as ei:
             client.call("Echo", {"x": 1}, timeout=1)
         assert ei.value.code() in (
@@ -238,9 +278,10 @@ def test_uds_server_gone_is_unavailable(uds_env):
         server.stop()
 
 
-def test_uds_concurrent_calls(uds_env):
+def test_concurrent_calls_stay_paired(carrier):
     """The worker's pipelined reports overlap calls on one client; the
-    connection pool must keep request/response frames paired."""
+    connection pool (the channel's streams) must keep request/response
+    frames paired."""
     from concurrent.futures import ThreadPoolExecutor
 
     server = RpcServer(_echo_handlers(), port=0)
@@ -283,7 +324,7 @@ def test_uds_large_payload_roundtrip(uds_env):
 # -- chaos injection on the fast paths ---------------------------------------
 
 
-def test_uds_client_error_injection_retried(uds_env):
+def test_client_error_injection_retried(carrier):
     hits = []
     server = RpcServer(_echo_handlers(hits), port=0)
     server.start()
@@ -294,7 +335,7 @@ def test_uds_client_error_injection_retried(uds_env):
         f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
     )
     try:
-        assert client._transport is not None and client._transport.name == "uds"
+        assert _tier(client) == carrier
         assert client.call("Echo", {"x": 1}, timeout=10, idempotent=True)[
             "x"
         ] == 1
@@ -304,10 +345,11 @@ def test_uds_client_error_injection_retried(uds_env):
         server.stop()
 
 
-def test_uds_drop_applies_then_retry_reaches_server(uds_env):
-    """Same contract as the gRPC interceptor: a dropped response means
-    the handler RAN; the retry hits the server a second time (which is
-    why mutating ops carry report_keys)."""
+def test_drop_applies_then_retry_reaches_server(carrier):
+    """One contract for the socket tier's hooks and the gRPC
+    interceptor: a dropped response means the handler RAN; the retry
+    hits the server a second time (which is why mutating ops carry
+    report_keys)."""
     hits = []
     server = RpcServer(_echo_handlers(hits), port=0)
     server.start()
@@ -352,7 +394,7 @@ def test_inproc_server_side_error_injection(inproc_env):
         server.stop()
 
 
-def test_uds_injected_error_is_policy_error(uds_env):
+def test_injected_error_is_policy_error(carrier):
     """Non-idempotent calls surface the injected error unretried, as
     the exact class the policy/chaos stack uses everywhere."""
     server = RpcServer(_echo_handlers(), port=0)
@@ -364,8 +406,9 @@ def test_uds_injected_error_is_policy_error(uds_env):
         f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
     )
     try:
-        with pytest.raises(InjectedRpcError):
+        with pytest.raises(InjectedRpcError) as ei:
             client.call("Echo", {"x": 1}, timeout=10, idempotent=False)
+        assert isinstance(ei.value, PolicyRpcError)
     finally:
         client.close()
         server.stop()
@@ -486,15 +529,7 @@ def test_uds_path_rendezvous_is_port_keyed(monkeypatch, tmp_path):
     assert transport.uds_path_for(50051) != transport.uds_path_for(50052)
 
 
-# -- shm tier -----------------------------------------------------------------
-
-
-def _no_shm_segments(scope_fragment: str) -> bool:
-    return not any(
-        scope_fragment in f
-        for f in os.listdir("/dev/shm")
-        if f.startswith("edlshm.")
-    )
+# -- the tier registry ---------------------------------------------------------
 
 
 def test_transport_tiers_registry():
@@ -504,245 +539,8 @@ def test_transport_tiers_registry():
     assert transport.TRANSPORT_TIERS == (
         transport.TRANSPORT_GRPC,
         transport.TRANSPORT_UDS,
-        transport.TRANSPORT_SHM,
         transport.TRANSPORT_INPROC,
     )
-    assert transport.TRANSPORT_SHM == "shm"
-
-
-def test_shm_select_without_rendezvous_falls_back(monkeypatch, tmp_path):
-    """EDL_TRANSPORT=shm with no rendezvous file for the port: the
-    conservative contract — fall back to gRPC, never attach blind."""
-    monkeypatch.setenv(ENV_TRANSPORT, "shm")
-    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
-    assert transport.select_transport("localhost:45997") is None
-
-
-def test_shm_rendezvous_embeds_scope_and_generation(shm_env):
-    """The port-keyed rendezvous file carries the fencing generation
-    and segment prefix a client needs to attach the RIGHT incarnation's
-    rings (satellite: generation-keyed rendezvous)."""
-    server = RpcServer(
-        _echo_handlers(), port=0, shm_scope="tt.ps0", shm_generation=3
-    )
-    server.start()
-    try:
-        info = transport.read_shm_rendezvous(server.port)
-        assert info is not None
-        assert info["scope"] == "tt.ps0"
-        assert info["generation"] == 3
-        assert info["prefix"] == "edlshm.tt.ps0.g3."
-        assert os.path.exists(info["doorbell"])
-        # and a client attaching through it lands on the shm tier
-        client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-        try:
-            assert client._transport is not None
-            assert client._transport.name == "shm"
-            _roundtrip(client)
-        finally:
-            client.close()
-    finally:
-        server.stop()
-    assert _no_shm_segments(".tt.ps0.")
-    assert transport.read_shm_rendezvous(server.port) is None
-
-
-def test_shm_server_gone_is_unavailable(shm_env):
-    """close() severs pooled client connections: the next call fails
-    like a stopped gRPC server, never hangs on a dead ring."""
-    server = RpcServer(_echo_handlers(), port=0)
-    server.start()
-    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-    try:
-        _roundtrip(client)
-        server.stop()
-        with pytest.raises(grpc.RpcError) as ei:
-            client.call("Echo", {"x": 1}, timeout=1)
-        assert ei.value.code() in (
-            grpc.StatusCode.UNAVAILABLE,
-            grpc.StatusCode.DEADLINE_EXCEEDED,
-        )
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_concurrent_calls_keep_frames_paired(shm_env):
-    """Pipelined overlapping calls on one pooled client: each response
-    ring must answer the request that rode its own connection."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    server = RpcServer(_echo_handlers(), port=0)
-    server.start()
-    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-    try:
-        with ThreadPoolExecutor(max_workers=8) as pool:
-            futs = [
-                pool.submit(client.call, "Echo", {"x": i}, 30)
-                for i in range(32)
-            ]
-            got = sorted(f.result()["x"] for f in futs)
-        assert got == list(range(32))
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_frame_larger_than_ring_is_chunked(shm_env, monkeypatch):
-    """A frame bigger than the ring must chunk through it intact, both
-    directions — the fallback that keeps tiny-ring configs correct."""
-    from elasticdl_tpu.common.constants import ENV_TRANSPORT_SHM_RING
-
-    monkeypatch.setenv(ENV_TRANSPORT_SHM_RING, "8192")
-    assert transport.shm_ring_bytes() == 8192
-    vec = np.random.default_rng(5).standard_normal(1 << 15).astype(np.float32)
-
-    def big(req):
-        np.testing.assert_array_equal(req["v"], vec)
-        return {"v": req["v"] * 2}
-
-    server = RpcServer({"Big": big}, port=0)
-    server.start()
-    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-    try:
-        assert client._transport is not None
-        assert client._transport.name == "shm"
-        resp = client.call("Big", {"v": vec}, timeout=30)
-        np.testing.assert_allclose(resp["v"], vec * 2)
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_loop_dispatch_roundtrip(shm_env, monkeypatch):
-    """The shm listener serves the event-loop core through the same
-    reactor shim as grpc pool threads — both EDL_DISPATCH cores answer
-    over the ring."""
-    from elasticdl_tpu.common.constants import ENV_DISPATCH
-
-    monkeypatch.setenv(ENV_DISPATCH, "loop")
-    server = RpcServer(_echo_handlers(), port=0)
-    server.start()
-    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-    try:
-        assert client._transport is not None
-        assert client._transport.name == "shm"
-        _roundtrip(client)
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_client_error_injection_retried(shm_env):
-    """Chaos parity: the FaultPlan hooks fire at the shm framing layer
-    exactly like the uds tier — an injected client-side error never
-    reaches the server and the policy retry lands."""
-    hits = []
-    server = RpcServer(_echo_handlers(hits), port=0)
-    server.start()
-    plan = FaultPlan.from_spec(
-        {"faults": [{"kind": "error", "methods": ["Echo"], "nth": 1}]}
-    )
-    client = RpcClient(
-        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
-    )
-    try:
-        assert client._transport is not None and client._transport.name == "shm"
-        assert client.call("Echo", {"x": 1}, timeout=10, idempotent=True)[
-            "x"
-        ] == 1
-        assert hits == [1], "injected attempt must never reach the server"
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_drop_applies_then_retry_reaches_server(shm_env):
-    hits = []
-    server = RpcServer(_echo_handlers(hits), port=0)
-    server.start()
-    plan = FaultPlan.from_spec(
-        {"faults": [{"kind": "drop", "methods": ["Echo"], "nth": 1}]}
-    )
-    client = RpcClient(
-        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
-    )
-    try:
-        assert client.call("Echo", {"x": 7}, timeout=10, idempotent=True)[
-            "x"
-        ] == 7
-        assert hits == [7, 7]
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_wire_stats_no_socket_bytes(shm_env):
-    """The tier-labeled accounting: all payload bytes land under "shm",
-    none under grpc or uds (the doorbell carries only handshakes, which
-    WireStats never counts), and the server mirrors the client."""
-    server = RpcServer(_echo_handlers(), port=0)
-    server.start()
-    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-    try:
-        client.wire.reset()
-        _roundtrip(client)
-        snap = client.wire.snapshot()
-        assert list(snap["transports"]) == ["shm"]
-        row = snap["transports"]["shm"]
-        assert row["bytes_sent"] > 0 and row["bytes_received"] > 0
-        srv = server.wire.snapshot()["transports"]
-        assert set(srv) == {"shm"}
-        assert srv["shm"]["bytes_received"] == row["bytes_sent"]
-        assert srv["shm"]["bytes_sent"] == row["bytes_received"]
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_shm_boot_reclaims_dead_predecessor(shm_env):
-    """A SIGKILLed incarnation leaves segments + a rendezvous file with
-    no owner. Booting the successor (same scope, bumped generation)
-    must sweep all of it BEFORE binding — satellite: stale-ring
-    reclamation. Covers both sweep keys: same-port rendezvous and
-    same-scope older-generation rendezvous parked on another port."""
-    scope = "tt.reclaim0"
-    # fabricate the dead incarnation's leavings: one ring segment, one
-    # same-scope g0 rendezvous on a DIFFERENT port, pointing at it
-    dead = transport._create_shm_segment(f"edlshm.{scope}.g0.c1", 4096)
-    dead.close()
-    other_port = 45901
-    with open(transport.shm_rendezvous_path(other_port), "w") as f:
-        import json as _json
-
-        _json.dump(
-            {
-                "scope": scope,
-                "generation": 0,
-                "prefix": f"edlshm.{scope}.g0.",
-                "doorbell": transport.shm_doorbell_path(other_port),
-                "ring": 4096,
-                "pid": 0,
-            },
-            f,
-        )
-    assert not _no_shm_segments(f".{scope}.")
-    server = RpcServer(
-        _echo_handlers(), port=0, shm_scope=scope, shm_generation=1
-    )
-    server.start()
-    try:
-        # the g0 orphan and the stale rendezvous are gone; g1 serves
-        assert _no_shm_segments(f".{scope}.g0.")
-        assert transport.read_shm_rendezvous(other_port) is None
-        client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
-        try:
-            _roundtrip(client)
-        finally:
-            client.close()
-    finally:
-        server.stop()
-    assert _no_shm_segments(f".{scope}.")
 
 
 # -- resource lifecycle on the failure paths (regressions) --------------------
@@ -786,31 +584,6 @@ def test_async_uds_server_bind_failure_closes_socket(
     with pytest.raises(OSError):
         transport.AsyncUdsServer(45997, disp, core=object())
     assert captured_sockets
-    assert all(s.fileno() == -1 for s in captured_sockets)
-
-
-def test_shm_server_rendezvous_failure_cleans_up(
-    monkeypatch, tmp_path, captured_sockets
-):
-    # regression: a raise after segment-create but before the
-    # rendezvous write (the connect()-side mirror of the same bug)
-    # leaked the doorbell socket, the broadcast shm segment, and the
-    # half-written manifest — none had an owner to close them
-    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
-    scope = "bootfail"
-
-    def replace_fails(src, dst):
-        raise OSError("rendezvous write failed")
-
-    monkeypatch.setattr(transport.os, "replace", replace_fails)
-    disp = transport.ServerDispatcher(_echo_handlers(), WireStats("t"))
-    with pytest.raises(OSError, match="rendezvous write failed"):
-        transport.ShmServer(45996, disp, scope=scope)
-    assert _no_shm_segments(f".{scope}.")  # broadcaster segment freed
-    assert not os.path.exists(transport.shm_doorbell_path(45996))
-    assert not os.path.exists(
-        transport.shm_rendezvous_path(45996) + ".tmp"
-    )
     assert all(s.fileno() == -1 for s in captured_sockets)
 
 
@@ -884,6 +657,10 @@ def test_unset_local_endpoint_with_live_server_selects_uds(
         assert client._transport is not None
         assert client._transport.name == "uds"
         assert f"link {addr}: uds" in client_log
+        # the row is the process's for this endpoint, and the kernel
+        # hands a port out again: count from here, not from whichever
+        # earlier test's server had the number
+        client.wire.reset()
         _roundtrip(client)
         by_tier = client.wire.snapshot()["transports"]
         assert by_tier["uds"]["calls"] == 1 and "grpc" not in by_tier
@@ -928,10 +705,11 @@ def test_explicit_grpc_is_pure_grpc_and_opens_no_listener(
     addr = f"localhost:{server.port}"
     client = RpcClient(addr, policy=fast_policy())
     try:
-        assert server._uds is None and server._shm is None
+        assert server._uds is None
         assert os.listdir(str(tmp_path)) == []
         assert client._transport is None
         assert f"link {addr}: grpc" in client_log
+        client.wire.reset()
         _roundtrip(client)
         assert set(client.wire.snapshot()["transports"]) == {"grpc"}
     finally:
@@ -941,7 +719,7 @@ def test_explicit_grpc_is_pure_grpc_and_opens_no_listener(
 
 def _big_tree(seed: int, mb: int = 64):
     """A nested tree of `mb` MB and a bit: bf16 and f32 leaves, far
-    above the socket buffer (208 KiB) and the shm ring (4 MiB)."""
+    above the socket buffer (208 KiB)."""
     import ml_dtypes
 
     rng = np.random.default_rng(seed)
@@ -969,11 +747,11 @@ def _assert_bit_equal(got, want):
         assert g.tobytes() == w.tobytes()
 
 
-@pytest.mark.parametrize("env_fixture", ["unset_env", "uds_env", "shm_env"])
+@pytest.mark.parametrize("env_fixture", ["unset_env", "uds_env", "grpc_env"])
 def test_large_frame_roundtrip_is_bit_equal(env_fixture, request):
     """A 64 MB+ nested frame up and the same frame down: every leaf
     arrives bit for bit, on the default carrier and on both explicit
-    ones (shm chunks it through the ring in both directions)."""
+    ones."""
     request.getfixturevalue(env_fixture)
     seen = {}
 
@@ -990,7 +768,8 @@ def test_large_frame_roundtrip_is_bit_equal(env_fixture, request):
     client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
     try:
         expected = os.environ.get(ENV_TRANSPORT, "uds")
-        assert client._transport.name == expected
+        assert _tier(client) == expected
+        client.wire.reset()
         tree = _big_tree(seed=3)
         resp = client.call("Mirror", tree, timeout=120, idempotent=False)
         assert seen["nbytes"] >= 64 * (1 << 20)
@@ -1006,7 +785,7 @@ def test_large_frame_roundtrip_is_bit_equal(env_fixture, request):
         server.stop()
 
 
-@pytest.mark.parametrize("env_fixture", ["unset_env", "shm_env"])
+@pytest.mark.parametrize("env_fixture", ["unset_env", "grpc_env"])
 def test_arrays_of_request_n_survive_request_n_plus_1(env_fixture, request):
     """The reuse guard. The master keeps views of a request past its
     handler (`grads_to_wait` > 1 accumulation, fan-in); the receive
@@ -1018,14 +797,14 @@ def test_arrays_of_request_n_survive_request_n_plus_1(env_fixture, request):
 
     def keep(req):
         kept.append(req["grad"])
-        # a frame of its own per response, larger than the shm ring
+        # a frame of its own per response
         return {"model": np.full(2 << 20, float(len(kept)), np.float32)}
 
     server = RpcServer({"Keep": keep}, port=0)
     server.start()
     client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
     try:
-        n = 2 << 20  # 8 MB of f32: above the ring, so shm chunks it
+        n = 2 << 20  # 8 MB of f32
         models = []
         for i in range(1, 4):
             resp = client.call(
@@ -1033,13 +812,91 @@ def test_arrays_of_request_n_survive_request_n_plus_1(env_fixture, request):
                 timeout=60, idempotent=False,
             )
             models.append(resp["model"])
-        # one pooled connection carried all three
-        pool = client._transport._pool
-        assert len(pool) == 1
+        if client._transport is not None:
+            # one pooled connection carried all three
+            assert len(client._transport._pool) == 1
         for i, (grad, model) in enumerate(zip(kept, models), start=1):
             assert grad.shape == (n,) and model.shape == (n,)
             assert float(grad.min()) == float(grad.max()) == float(i)
             assert float(model.min()) == float(model.max()) == float(i)
+    finally:
+        client.close()
+        server.stop()
+
+
+# -- a frame the socket header cannot describe --------------------------------
+
+
+def test_oversize_request_is_refused_unsent_and_unretried(
+    unset_env, monkeypatch
+):
+    """The header's length field is a u32. A request it cannot say is
+    refused before a byte leaves, with a status the policy does not
+    retry (the link is not down: the same frame would be refused
+    again) and a message that names the size and the limit."""
+    monkeypatch.setattr(transport, "MAX_FRAME_BYTES", 4096)
+    hits = []
+    server = RpcServer(_echo_handlers(hits), port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    attempts = []
+    real_call = client._transport.call
+
+    def counted(*args):
+        attempts.append(args[0])
+        return real_call(*args)
+
+    monkeypatch.setattr(client._transport, "call", counted)
+    try:
+        with pytest.raises(PolicyRpcError) as ei:
+            client.call(
+                "Echo", {"x": np.zeros(4096, np.float32)},
+                timeout=10, idempotent=True,
+            )
+        assert ei.value.code() == grpc.StatusCode.OUT_OF_RANGE
+        assert "4096" in ei.value.details()
+        assert "request frame of Echo is 16" in ei.value.details()
+        assert attempts == ["Echo"] and hits == []
+        assert server.wire.snapshot()["bytes_received"] == 0
+        # a frame that fits is carried as before, on the same client
+        _roundtrip(client)
+        assert _tier(client) == "uds"
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.mark.parametrize("core", ["threads", "loop"])
+def test_oversize_response_is_refused_by_the_server(
+    unset_env, monkeypatch, core
+):
+    """The response side of the same field, on both listeners: the
+    handler's answer is not sent, the client gets the refusal as a
+    status (not a reset, which it would take for a dead link and
+    retry), and the connection serves the next call."""
+    from elasticdl_tpu.common.constants import ENV_DISPATCH
+
+    monkeypatch.setenv(ENV_DISPATCH, core)
+    monkeypatch.setattr(transport, "MAX_FRAME_BYTES", 4096)
+    hits = []
+
+    def big(req):
+        hits.append(req["x"])
+        return {"v": np.zeros(4096, np.float32)}
+
+    server = RpcServer({"Big": big, **_echo_handlers()}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        assert _tier(client) == "uds"
+        with pytest.raises(grpc.RpcError) as ei:
+            client.call("Big", {"x": 1}, timeout=10, idempotent=True)
+        assert ei.value.code() == grpc.StatusCode.OUT_OF_RANGE
+        assert "response frame of Big is 16" in ei.value.details()
+        assert "4096" in ei.value.details()
+        assert hits == [1]  # answered once, not retried
+        _roundtrip(client)
+        assert len(client._transport._pool) == 1
     finally:
         client.close()
         server.stop()
@@ -1063,6 +920,7 @@ def test_stale_socket_file_is_served_over_grpc_and_logged_once(
     client = RpcClient(addr, policy=fast_policy())
     try:
         assert client._transport.name == "uds"  # the file is there
+        client.wire.reset()
         for i in range(3):
             assert client.call("Echo", {"x": i}, timeout=10)["x"] == i
         assert hits == [0, 1, 2]  # each served once
@@ -1142,6 +1000,7 @@ def test_socket_directory_deeper_than_an_af_unix_address(
     try:
         assert server._uds is not None
         assert client._transport.name == "uds"
+        client.wire.reset()
         _roundtrip(client)
         assert set(client.wire.snapshot()["transports"]) == {"uds"}
     finally:
