@@ -20,9 +20,12 @@ also zero-copy on ENCODE. The old v1 encoder ran every array through
 the resulting bin into its output buffer (a second full copy). v2
 instead packs a small msgpack header holding dtype/shape/offset
 descriptors and appends the raw array bytes out-of-band as buffer
-views of the contiguous source arrays; the only full-size copy left is
-the final `b"".join` that materializes the single wire buffer gRPC
-needs (see docs/architecture.md, "Wire plane").
+views of the contiguous source arrays (`dumps_parts`). The only
+full-size copy left is the `b"".join` of `dumps`, and it is the
+one-buffer carriers' alone: gRPC, `inproc` and files need a single
+buffer; the Unix-socket carrier writes a request's parts to the socket
+as they lie (`messages.PackedParts`, `rpc/transport.py`; see
+docs/architecture.md, "Wire plane").
 
     offset  size  field
     0       1     0xC1 frame magic (a reserved, never-emitted msgpack
@@ -88,8 +91,8 @@ class _EncodeCopyCounter(threading.local):
     """Per-thread tally of host bytes COPIED while encoding (the
     contiguity fallback). The zero-copy guarantee is tested against
     this: encoding a pytree of contiguous host arrays must report 0
-    (the final frame join is the single allowed full-size copy and is
-    inherent to producing one wire buffer). Device->host transfers for
+    (the frame join is the single allowed full-size copy, taken only
+    where a carrier needs one wire buffer). Device->host transfers for
     jax arrays are not counted — they are transfers, not wire-plane
     copies."""
 
@@ -687,9 +690,11 @@ def unravel_np(vec: np.ndarray, template) -> Any:
 
 
 def dumps_parts(obj: Any):
-    """Serialize a pytree as an ordered list of v2-frame parts (buffer
-    views of the source arrays plus the prefix/header/pad bytes) and
-    the total frame length. `b"".join(parts)` IS the frame."""
+    """Serialize a pytree as an ordered list of v2-frame parts (bytes
+    for the prefix/header/pads, flat uint8 views of the source arrays,
+    which keep them alive) and the total frame length.
+    `b"".join(parts)` IS the frame; a carrier that writes to a socket
+    sends the parts and never joins."""
     builder = _FrameBuilder()
     tree = _build_frame_tree(obj, builder)
     header = msgpack.packb(
@@ -713,8 +718,9 @@ def dumps_parts(obj: Any):
 
 def dumps(obj: Any) -> bytes:
     """Serialize a pytree (nested dict/list/tuple of arrays, scalars,
-    strings) as a v2 frame. Contiguous array bytes enter the frame as
-    buffer views; the single full-size copy is the final join."""
+    strings) as a v2 frame in one buffer. Contiguous array bytes enter
+    the frame as buffer views; the single full-size copy is this
+    join."""
     parts, _ = dumps_parts(obj)
     return b"".join(parts)
 

@@ -553,5 +553,44 @@ def pack(obj: Any) -> bytes:
     return codec.dumps(obj)
 
 
+class PackedParts:
+    """A packed frame that has not been joined: the ordered parts
+    `codec.dumps_parts` makes (bytes and flat uint8 views of the source
+    arrays, which the views keep alive) and their total length, which
+    `len()` answers. A carrier that writes to a socket sends the parts
+    as they lie; one that needs a single buffer (gRPC, inproc) asks
+    `contiguous()`, which joins once however often it is asked, so a
+    retry resends what the first attempt sent."""
+
+    __slots__ = ("parts", "nbytes", "_data")
+
+    def __init__(self, parts, nbytes: int):
+        self.parts = parts
+        self.nbytes = nbytes
+        self._data = None
+
+    def __len__(self) -> int:
+        return self.nbytes
+
+    @property
+    def joined(self) -> bool:
+        """Whether a carrier asked for the one buffer."""
+        return self._data is not None
+
+    def contiguous(self) -> bytes:
+        if self._data is None:
+            parts = self.parts
+            self._data = parts[0] if len(parts) == 1 else b"".join(parts)
+        return self._data
+
+
+def pack_parts(obj: Any) -> PackedParts:
+    """`pack` without the join: what `RpcClient` hands its carrier. A
+    `Prepacked` is the one part its maker joined."""
+    if isinstance(obj, Prepacked):
+        return PackedParts([obj.data], len(obj.data))
+    return PackedParts(*codec.dumps_parts(obj))
+
+
 def unpack(data: bytes) -> Any:
     return codec.loads(data)

@@ -13,7 +13,9 @@ docs/fault_model.md).
 A local peer gets a local carrier: when the endpoint resolves to this
 host and its listener is there (with `EDL_TRANSPORT` unset: the
 server's Unix-socket file; the variable overrides, rpc/transport.py),
-the attempt routes the packed codec frame over that tier INSIDE the
+the attempt routes the packed request (`messages.PackedParts`: the
+socket takes its parts as they lie; a carrier that needs one buffer
+joins them, once) over that tier INSIDE the
 same policy/breaker envelope, with the same FaultPlan applied by the
 transport — tier selection changes how bytes move, never the failure
 semantics. A remote endpoint, or `EDL_TRANSPORT=grpc`, gets gRPC. The
@@ -190,12 +192,18 @@ class RpcClient:
         timeline = method in self._timeline
         tctx = tspan.ctx if tspan is not None else None
         t_pack = time.time() if timeline else 0.0
-        payload = messages.pack(request if request is not None else {})
+        # packed, not joined: the socket carrier sends the parts as
+        # they lie; a carrier that needs one buffer joins them, once
+        payload = messages.pack_parts(request if request is not None else {})
         t_sent = time.time() if timeline else 0.0
         if timeline:
             obs_trace.record_phase(
                 "rpc.client.encode", t_pack, t_sent - t_pack,
-                {"method": method, "bytes": len(payload)},
+                {
+                    "method": method,
+                    "bytes": len(payload),
+                    "parts": len(payload.parts),
+                },
                 ctx=obs_trace.child_context(tctx),
             )
 
@@ -204,7 +212,7 @@ class RpcClient:
 
         def over_grpc(remaining):
             self.wire.record(method, sent=len(payload))
-            resp_bytes = stub(payload, timeout=remaining)
+            resp_bytes = stub(payload.contiguous(), timeout=remaining)
             self.wire.record(method, received=len(resp_bytes))
             return resp_bytes
 
@@ -258,11 +266,16 @@ class RpcClient:
         finally:
             if not timeline:
                 if tspan is not None:
-                    tspan.end(transport=tier)
+                    tspan.end(transport=tier, joined=payload.joined)
             elif not settled:  # the call raised: no response to date it by
                 obs_trace.record_phase(
                     f"rpc.client.{method}", t_sent, time.time() - t_sent,
-                    {"bytes": len(payload), "transport": tier, "failed": True},
+                    {
+                        "bytes": len(payload),
+                        "transport": tier,
+                        "joined": payload.joined,
+                        "failed": True,
+                    },
                     ctx=tctx,
                 )
         if not timeline:
@@ -278,6 +291,7 @@ class RpcClient:
             {
                 "bytes": len(payload),
                 "transport": tier,
+                "joined": payload.joined,
                 "version": out.get("version") if isinstance(out, dict) else None,
             },
             ctx=tctx,

@@ -9,15 +9,25 @@ override (and the test handle):
 
 - **uds** — a Unix-domain-socket byte protocol carrying codec frames
   with a minimal length-prefixed header, skipping gRPC/HTTP-2 framing
-  entirely. The frame bytes go to `sendall` as-is (no re-serialization)
-  and the receiver hands the codec one contiguous buffer to build
-  `np.frombuffer` views over — the zero-copy contract of codec v2 holds
-  end to end.
+  entirely. A request arrives here as the parts the codec made
+  (`messages.PackedParts`: prefix, header, pads, views of the source
+  arrays) and they are written to the socket in order, never joined
+  into a buffer of their own (`_send_parts`); the receiver hands the
+  codec one contiguous buffer to build `np.frombuffer` views over — the
+  zero-copy contract of codec v2 holds end to end. The bytes on the
+  socket are the frame `codec.dumps` would have made: the receiving
+  side cannot tell.
 - **inproc** — when the serving `RpcServer` lives in the SAME
   interpreter (bench/test mode, `PSShardGroup` inproc shards), the call
-  dispatches directly into the server's handler table: the packed frame
-  is passed by reference, no socket at all. WireStats records these
-  calls with zero wire bytes under the "inproc" tier.
+  dispatches directly into the server's handler table: the frame, which
+  this carrier asks the request to join (the dispatcher decodes from
+  one buffer), is passed by reference, no socket at all. WireStats
+  records these calls with zero wire bytes under the "inproc" tier.
+
+The join (`PackedParts.contiguous()`, at most once a request however
+many attempts send it) is the one-buffer carriers' alone: `inproc`,
+and gRPC, which `RpcClient` also hands any call whose socket cannot
+connect.
 
 Every tier runs the identical server-side core, `ServerDispatcher`:
 chaos faults (rpc/chaos.py, via `transport_faults_before/after` — the
@@ -430,8 +440,8 @@ def inproc_dispatcher(port: int) -> Optional[ServerDispatcher]:
 
 
 class InprocTransport:
-    """Direct dispatch into a same-interpreter RpcServer. The packed
-    codec frame crosses by reference — zero wire bytes, zero copies.
+    """Direct dispatch into a same-interpreter RpcServer. The joined
+    codec frame crosses by reference — zero wire bytes.
     The dispatcher is re-resolved per call so a shard relaunch (new
     server object on a new port -> new client) or a stopped server
     surfaces as UNAVAILABLE for the retry/recovery machinery, never a
@@ -443,7 +453,7 @@ class InprocTransport:
         self._port = int(port)
         self._plan = fault_plan
 
-    def call(self, method: str, payload: bytes, timeout: float) -> bytes:
+    def call(self, method: str, payload, timeout: float) -> bytes:
         after = transport_faults_before(self._plan, method, "client")
         dispatcher = inproc_dispatcher(self._port)
         if dispatcher is None:
@@ -451,7 +461,10 @@ class InprocTransport:
                 grpc.StatusCode.UNAVAILABLE,
                 f"inproc server for port {self._port} is gone",
             )
-        resp = dispatcher.dispatch(method, payload, TRANSPORT_INPROC)
+        # the dispatcher decodes from one buffer
+        resp = dispatcher.dispatch(
+            method, payload.contiguous(), TRANSPORT_INPROC
+        )
         transport_faults_after(after, method)
         return resp
 
@@ -532,6 +545,37 @@ def _recv_frame(conn: socket.socket, n: int):
     view, frame = _frame_buffer(n)
     _recv_fill(conn, view, n)
     return frame
+
+
+#: The most buffers one `sendmsg` may gather (Linux's UIO_MAXIOV).
+_IOV_MAX = 1024
+
+
+def _send_parts(conn: socket.socket, head: bytes, parts, deadline: float):
+    """Write `head`, then a frame's parts in order, gathered by
+    `sendmsg` from where they lie: no buffer the size of the frame,
+    and a frame that fits the socket buffer is one system call, header
+    and all. A turn sends at most a socket buffer's worth (about 200
+    KB with a timeout set), so a long part leaves over many turns:
+    what has left is dropped from the front and the rest gathered
+    again, at most `_IOV_MAX` buffers a turn. `deadline` (monotonic)
+    is one budget over all the turns. The bytes on the socket are
+    `head + b"".join(parts)`. (One `sendall` a long part, short parts
+    joined, read 12-28 ms more wire a 649 MB sync on the chip's host:
+    PERF.md, PR 30.)"""
+    bufs = [memoryview(head)]
+    bufs += [memoryview(part) for part in parts if len(part)]
+    i = 0
+    while i < len(bufs):
+        conn.settimeout(max(0.001, deadline - time.monotonic()))
+        sent = conn.sendmsg(bufs[i:i + _IOV_MAX])
+        while sent:
+            n = bufs[i].nbytes
+            if sent < n:
+                bufs[i] = bufs[i][sent:]
+                break
+            sent -= n
+            i += 1
 
 
 class CarrierDown(PolicyRpcError):
@@ -841,17 +885,22 @@ class UdsTransport:
                 except OSError:  # pragma: no cover - already severed
                     pass
 
-    def call(self, method: str, payload: bytes, timeout: float) -> bytes:
+    def call(self, method: str, payload, timeout: float) -> bytes:
+        """`payload` is a `messages.PackedParts`: its parts go to the
+        socket in order and are never joined."""
         _refuse_oversize("request", method, len(payload))
         # connect first: CarrierDown leaves the FaultPlan untouched, so
         # the gRPC channel that serves the call instead draws its fault
         conn = self._checkout()
         try:
             after = transport_faults_before(self._plan, method, "client")
-            conn.settimeout(max(0.001, float(timeout)))
             mb = method.encode("utf-8")
-            conn.sendall(_REQ_HEADER.pack(len(mb), len(payload)) + mb)
-            conn.sendall(payload)
+            _send_parts(
+                conn,
+                _REQ_HEADER.pack(len(mb), len(payload)) + mb,
+                payload.parts,
+                time.monotonic() + float(timeout),
+            )
             status = _recv_exact(conn, 1)[0]
             if status == 0:
                 (blen,) = struct.unpack("<I", _recv_exact(conn, 4))

@@ -15,11 +15,13 @@ describe, and the resource lifecycle of the socket listener.
 
 import os
 import socket
+import time
 
 import grpc
 import numpy as np
 import pytest
 
+from elasticdl_tpu.common import codec, messages
 from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
 from elasticdl_tpu.rpc import transport
 from elasticdl_tpu.rpc.chaos import FaultPlan, InjectedRpcError
@@ -1007,3 +1009,345 @@ def test_socket_directory_deeper_than_an_af_unix_address(
         client.close()
         server.stop()
     assert os.listdir(str(deep)) == []
+
+
+# -- a request reaches the socket as its parts --------------------------------
+
+
+class _FrameKeeper:
+    """Stands where a `ServerDispatcher` does and keeps the bytes of
+    every frame the listener hands it."""
+
+    def __init__(self):
+        self.frames = []
+
+    def dispatch(self, method, frame, tier):
+        self.frames.append((method, bytes(frame)))
+        return messages.pack({"ok": len(self.frames)})
+
+
+@pytest.fixture
+def keeper(unset_env):
+    """A `UdsServer` over a `_FrameKeeper`, and a transport to it."""
+    dispatcher = _FrameKeeper()
+    server = transport.UdsServer(50123, dispatcher)
+    server.start()
+    client = transport.UdsTransport(server.path)
+    yield dispatcher, client
+    client.close()
+    server.close()
+
+
+def _rng(seed=0):
+    return np.random.default_rng(seed)
+
+
+_PART_TREES = {
+    # leaf sizes off the 64-byte grid (a pad before each), a leaf long
+    # enough to be written from where it lies, bf16, an empty leaf
+    "unaligned": lambda: {
+        "a": _rng(1).standard_normal(7, dtype=np.float32),
+        "b": {"c": _rng(2).standard_normal(70001, dtype=np.float32),
+              "d": np.arange(13, dtype=np.int64)},
+        "e": [np.ones(3, np.float32).astype(codec._BFLOAT16),
+              np.zeros(0, np.float32)],
+        "v": 7, "key": "w0-t3",
+    },
+    "non_contiguous": lambda: {
+        "t": _rng(3).standard_normal((300, 200), dtype=np.float32).T,
+        "s": np.arange(40000, dtype=np.float32)[::2],
+    },
+    "indexed_rows": lambda: {
+        "emb": codec.IndexedRows(
+            values=_rng(4).standard_normal((1031, 17), dtype=np.float32),
+            indices=np.arange(1031, dtype=np.int64) * 3,
+        ),
+    },
+    "quantized_delta": lambda: {
+        "delta": codec.QuantizedDelta(
+            q=_rng(5).integers(-127, 128, 100003).astype(np.int8),
+            scale=_rng(6).random(25, dtype=np.float32), chunk=4096, offset=5,
+        ),
+        "steps": 8,
+    },
+    "empty": lambda: {},
+}
+
+
+@pytest.mark.parametrize("tree", sorted(_PART_TREES))
+def test_the_parts_on_the_socket_are_the_frame(keeper, tree):
+    """What the listener reads from a request sent as its parts is,
+    byte for byte, `codec.dumps` of that request: pads, a compacted
+    leaf and the structured wire forms included. A receiver of either
+    version reads either sender."""
+    dispatcher, client = keeper
+    request = _PART_TREES[tree]()
+    payload = messages.pack_parts(request)
+    frame = codec.dumps(request)
+    assert len(payload) == len(frame)
+    resp = client.call("Push", payload, 10.0)
+    assert messages.unpack(resp) == {"ok": 1}
+    assert dispatcher.frames == [("Push", frame)]
+    assert not payload.joined
+    # sent again (a retry), the same bytes
+    client.call("Push", payload, 10.0)
+    assert dispatcher.frames[1] == ("Push", frame)
+
+
+def test_a_small_frame_is_one_write_and_a_long_leaf_leaves_uncopied(keeper):
+    """A `GetTask` and its kind leave in one system call, header and
+    all; a long leaf is gathered from the array it views, over as
+    many turns as the socket buffer makes of it."""
+    dispatcher, client = keeper
+    turns = []
+
+    class _Spy:
+        def __init__(self, conn):
+            self._conn = conn
+
+        def sendmsg(self, bufs):
+            turns.append(list(bufs))
+            return self._conn.sendmsg(bufs)
+
+        def __getattr__(self, name):
+            return getattr(self._conn, name)
+
+    real_checkout = client._checkout
+
+    def checkout():  # the pool hands a checked-in spy back as it is
+        conn = real_checkout()
+        return conn if isinstance(conn, _Spy) else _Spy(conn)
+
+    client._checkout = checkout
+    client.call("GetTask", messages.pack_parts({"worker_id": 3}), 10.0)
+    assert len(turns) == 1
+    del turns[:]
+    big = np.arange(1 << 18, dtype=np.float32)  # 1 MiB: several turns
+    request = {"a": np.ones(5, np.float32), "big": big, "z": np.ones(9)}
+    client.call("Push", messages.pack_parts(request), 10.0)
+    assert len(turns) > 1
+    # the leaf is offered whole from where it lies, and what a turn
+    # left behind is offered again from there
+    for bufs, whole in ((turns[0], True), (turns[1], False)):
+        (leaf,) = [
+            b for b in bufs
+            if np.shares_memory(np.frombuffer(b, np.uint8), big)
+        ]
+        assert (leaf.nbytes == big.nbytes) == whole
+    assert dispatcher.frames[1] == ("Push", codec.dumps(request))
+
+
+def test_more_parts_than_iov_max_and_a_shrunk_socket_buffer(keeper):
+    """Neither the gather list's limit nor the socket buffer's size
+    bounds a frame: over 6,000 parts (`IOV_MAX` is 1024) through a
+    send buffer smaller than one leaf arrive whole."""
+    dispatcher, client = keeper
+    conn = client._checkout()
+    conn.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1024)
+    client._checkin(conn)
+    assert os.sysconf("SC_IOV_MAX") == transport._IOV_MAX < 3000
+    rng = _rng(7)
+    request = {
+        f"leaf{i}": rng.standard_normal(1000 + i % 7, dtype=np.float32)
+        for i in range(3000)
+    }
+    payload = messages.pack_parts(request)
+    assert len(payload.parts) > 3000
+    client.call("Push", payload, 60.0)
+    assert len(client._pool) == 1  # the shrunk connection carried it
+    assert dispatcher.frames == [("Push", codec.dumps(request))]
+    assert not payload.joined
+
+
+def test_the_deadline_is_one_budget_over_all_the_parts(unset_env, tmp_path):
+    """A peer that stops reading fails the call once the call's budget
+    is spent, however many parts are still to go: not a budget a part."""
+    path = str(tmp_path / "stuck.sock")
+    listener = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+    listener.bind(path)
+    listener.listen(1)  # accepts in the kernel, never reads
+    client = transport.UdsTransport(path)
+    request = {f"l{i}": np.zeros(1 << 18, np.float32) for i in range(40)}
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(PolicyRpcError) as ei:
+            client.call("Push", messages.pack_parts(request), 0.5)
+    finally:
+        client.close()
+        listener.close()
+    assert ei.value.code() == grpc.StatusCode.DEADLINE_EXCEEDED
+    assert time.monotonic() - t0 < 5.0  # 40 parts of 0.5 s each: 20 s
+
+
+@pytest.mark.parametrize("kind", ["error", "drop"])
+def test_a_retry_after_a_drawn_fault_resends_the_parts_intact(
+    unset_env, kind
+):
+    """An injected client error costs an attempt before anything left;
+    a dropped response one after the whole frame did. Either way the
+    retry sends the same parts, and the server decodes what was
+    meant."""
+    seen = []
+
+    def push(req):
+        seen.append({k: np.array(v) for k, v in req["delta"].items()})
+        return {"ok": True}
+
+    server = RpcServer({"Push": push}, port=0)
+    server.start()
+    plan = FaultPlan.from_spec(
+        {"faults": [{"kind": kind, "methods": ["Push"], "nth": 1}]}
+    )
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
+    )
+    delta = {
+        "w": _rng(8).standard_normal(100003, dtype=np.float32),
+        "b": _rng(9).standard_normal(5, dtype=np.float32),
+    }
+    try:
+        assert _tier(client) == "uds"
+        client.wire.reset()
+        resp = client.call(
+            "Push", {"delta": delta}, timeout=10, idempotent=True
+        )
+        assert resp == {"ok": True}
+        assert len(seen) == (2 if kind == "drop" else 1)
+        for got in seen:
+            _assert_bit_equal(got, delta)
+        frame = len(codec.dumps({"delta": delta}))
+        row = client.wire.snapshot()["transports"]["uds"]
+        # both attempts tally the frame's length, unjoined as it is
+        assert row["bytes_sent"] == 2 * frame
+    finally:
+        client.close()
+        server.stop()
+
+
+@pytest.fixture
+def timeline_spans():
+    from elasticdl_tpu.obs import trace
+
+    trace.configure(0.0)  # the timeline needs no sampling
+    trace.RECORDER.clear()
+    yield lambda name: [
+        s for s in trace.RECORDER.snapshot() if s["name"] == name
+    ]
+    trace.RECORDER.clear()
+    trace.configure(None)
+
+
+@pytest.fixture
+def joins(monkeypatch):
+    """Every `PackedParts` a client packed, and how often each was
+    really joined."""
+    made, counts = [], []
+    real_pack, real_join = messages.pack_parts, messages.PackedParts.contiguous
+
+    def pack_parts(obj):
+        made.append(real_pack(obj))
+        return made[-1]
+
+    def contiguous(self):
+        if not self.joined:
+            counts.append(id(self))
+        return real_join(self)
+
+    monkeypatch.setattr(messages, "pack_parts", pack_parts)
+    monkeypatch.setattr(messages.PackedParts, "contiguous", contiguous)
+    return made, counts
+
+
+def _span_args(spans, name):
+    (span,) = spans(name)
+    return span["args"]
+
+
+def test_the_socket_carrier_never_joins_and_the_spans_say_so(
+    unset_env, timeline_spans, joins
+):
+    made, counts = joins
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), timeline=("Echo",)
+    )
+    try:
+        client.call("Echo", {"x": np.ones(1 << 16, np.float32)}, timeout=10)
+    finally:
+        client.close()
+        server.stop()
+    assert len(made) == 1 and counts == [] and not made[0].joined
+    encode = _span_args(timeline_spans, "rpc.client.encode")
+    assert encode["parts"] == len(made[0].parts) >= 3
+    assert encode["bytes"] == len(made[0])
+    trip = _span_args(timeline_spans, "rpc.client.Echo")
+    assert (trip["transport"], trip["joined"]) == ("uds", False)
+    assert trip["bytes"] == len(made[0])
+
+
+def test_the_grpc_fallback_joins_exactly_once_over_its_retries(
+    unset_env, timeline_spans, joins
+):
+    """A call whose socket cannot connect is gRPC's, which needs one
+    buffer: joined once, however many attempts send it."""
+    made, counts = joins
+    plan = FaultPlan.from_spec({"faults": [
+        {"kind": "error", "methods": ["Echo"], "side": "client", "nth": 1},
+    ]})
+    hits = []
+    server = RpcServer(_echo_handlers(hits), port=0)
+    server.start()
+    server._uds.close()
+    _stale_socket_file(server.port)
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan,
+        timeline=("Echo",),
+    )
+    try:
+        assert client._transport.name == "uds"
+        resp = client.call("Echo", {"x": 5}, timeout=10, idempotent=True)
+        assert resp["x"] == 5 and hits == [5]
+        assert plan.faults[0]._count == 2  # two attempts over gRPC
+    finally:
+        client.close()
+        server.stop()
+    assert len(made) == 1 and counts == [id(made[0])]
+    assert made[0].contiguous() is made[0].contiguous()
+    trip = _span_args(timeline_spans, "rpc.client.Echo")
+    assert (trip["transport"], trip["joined"]) == ("grpc", True)
+    assert _span_args(timeline_spans, "rpc.client.encode")["parts"] >= 2
+
+
+@pytest.mark.parametrize("env_fixture", ["inproc_env", "grpc_env"])
+def test_a_one_buffer_carrier_joins_exactly_once(
+    env_fixture, request, timeline_spans, joins
+):
+    request.getfixturevalue(env_fixture)
+    made, counts = joins
+    server = RpcServer(_echo_handlers(), port=0)
+    server.start()
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), timeline=("Echo",)
+    )
+    try:
+        tier = _tier(client)
+        _roundtrip(client)
+    finally:
+        client.close()
+        server.stop()
+    assert len(made) == 1 and counts == [id(made[0])]
+    trip = _span_args(timeline_spans, "rpc.client.Echo")
+    assert (trip["transport"], trip["joined"]) == (tier, True)
+
+
+def test_a_prepacked_request_passes_through_unjoined(keeper):
+    """The one part its maker joined: sent as it lies on the socket,
+    handed over as it is where one buffer is asked for."""
+    dispatcher, client = keeper
+    frame = codec.dumps({"delta": np.arange(9, dtype=np.float32)})
+    payload = messages.pack_parts(messages.Prepacked(frame))
+    assert len(payload) == len(frame) and payload.parts == [frame]
+    client.call("Push", payload, 10.0)
+    assert dispatcher.frames == [("Push", frame)] and not payload.joined
+    assert payload.contiguous() is frame
