@@ -209,6 +209,11 @@ class RpcClient:
 
         transport = self._transport
         tier = transport.name if transport else "grpc"
+        # what the socket carrier says of the call that served, for the
+        # round trip's span: whether the response lies in memory an
+        # earlier one of its connection lay in (never, on the one-buffer
+        # carriers), and the socket buffers as the kernel granted them
+        link = {"recv_reused": False}
 
         def over_grpc(remaining):
             self.wire.record(method, sent=len(payload))
@@ -244,6 +249,8 @@ class RpcClient:
                 )
                 raise
             tier = transport.name
+            if not inproc:
+                link.update(transport.last_call())
             self.wire.record(
                 method,
                 sent=sent,
@@ -266,7 +273,7 @@ class RpcClient:
         finally:
             if not timeline:
                 if tspan is not None:
-                    tspan.end(transport=tier, joined=payload.joined)
+                    tspan.end(transport=tier, joined=payload.joined, **link)
             elif not settled:  # the call raised: no response to date it by
                 obs_trace.record_phase(
                     f"rpc.client.{method}", t_sent, time.time() - t_sent,
@@ -275,6 +282,7 @@ class RpcClient:
                         "transport": tier,
                         "joined": payload.joined,
                         "failed": True,
+                        **link,
                     },
                     ctx=tctx,
                 )
@@ -293,6 +301,7 @@ class RpcClient:
                 "transport": tier,
                 "joined": payload.joined,
                 "version": out.get("version") if isinstance(out, dict) else None,
+                **link,
             },
             ctx=tctx,
         )

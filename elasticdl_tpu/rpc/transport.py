@@ -55,10 +55,16 @@ endpoint (the k8s path advertises the pod IP) gets gRPC. Unset is not
 The socket tier receives a frame with no copy beyond the kernel's:
 `_recv_frame` reads into memory that was never zero-filled and hands
 the dispatcher / `messages.unpack` a read-only view of it, over which
-the codec builds its `np.frombuffer` views. A buffer is never reused:
-each frame gets its own, which lives as long as an array decoded from
-it (the master keeps such views past the handler: `grads_to_wait` > 1,
-fan-in).
+the codec builds its `np.frombuffer` views. A frame's memory lives as
+long as an array decoded from it (the master keeps such views past the
+handler: `grads_to_wait` > 1, fan-in). A connection keeps the memory
+its last large frame lay in (`_FrameMemory`) and receives the next one
+there, but only once nothing reads the old frame any more: the memory
+comes back to the connection when the last view of the frame dies, so
+whoever keeps a view keeps the memory and the connection's next frame
+gets fresh pages, as every frame did before. Both ends ask the kernel
+for socket buffers of `SOCKET_BUFFER_BYTES`, so a long frame crosses
+in turns of megabytes.
 
 A frame longer than the socket header's u32 length field can say
 (`MAX_FRAME_BYTES`) is refused before a byte of it is sent, by the
@@ -76,6 +82,7 @@ an endpoint that answers UNAVAILABLE for ever.
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import os
 import socket
@@ -83,6 +90,7 @@ import struct
 import tempfile
 import threading
 import time
+import weakref
 from concurrent import futures
 from typing import Callable, Dict, Optional
 
@@ -255,7 +263,12 @@ class ServerDispatcher:
         if self._executor is not None:
             self._executor.shutdown(wait=False)
 
-    def dispatch(self, method: str, request_bytes, transport: str) -> bytes:
+    def dispatch(
+        self, method: str, request_bytes, transport: str, recv_reused=False
+    ) -> bytes:
+        """`recv_reused`: the carrier received the request into memory
+        an earlier frame of its connection lay in (the `rpc.decode`
+        span of a timed method says so; the loop core does not)."""
         if self._core is not None:
             if transport == TRANSPORT_INPROC:
                 # direct scheduling: there is no socket to multiplex, so
@@ -280,7 +293,9 @@ class ServerDispatcher:
         after = []
         if transport != TRANSPORT_GRPC:
             after = transport_faults_before(self._plan, method, "server")
-        resp_bytes = self._invoke(method, request_bytes, transport)
+        resp_bytes = self._invoke(
+            method, request_bytes, transport, recv_reused=recv_reused
+        )
         # drop/crash-after fire with the handler APPLIED (same contract
         # as the server interceptor: state changed, response withheld)
         transport_faults_after(after, method)
@@ -318,7 +333,8 @@ class ServerDispatcher:
         return resp_bytes
 
     def _invoke(
-        self, method: str, request_bytes, transport: str, t_admit=None
+        self, method: str, request_bytes, transport: str, t_admit=None,
+        recv_reused=False,
     ) -> bytes:
         from elasticdl_tpu.rpc.fencing import EpochFencedError
 
@@ -399,7 +415,7 @@ class ServerDispatcher:
             record = self._timers.record
             record(
                 "rpc.decode", t_decode, t_decoded, method=method,
-                bytes=nbytes, version=version,
+                bytes=nbytes, version=version, recv_reused=recv_reused,
             )
             record(
                 "rpc.encode", t_encode, time.time(), method=method,
@@ -531,20 +547,119 @@ def _recv_exact(conn: socket.socket, n: int, *, eof_ok: bool = False):
 
 def _frame_buffer(n: int):
     """(writable view, read-only view) of fresh memory for a frame of
-    n bytes. The memory is not zero-filled (a `bytearray(n)` is, one
-    pass over 649 MB for nothing) and the read-only view is what the
-    codec decodes from: no trailing `bytes()` copy. Read-only, like
-    the `bytes` it replaces, so decoded arrays stay read-only views."""
+    n bytes, which belongs to the frame alone and goes when the last
+    array decoded from it does: what a small frame gets, and a large
+    one whose connection has no memory to lend. Not zero-filled (a
+    `bytearray(n)` is, one pass over the frame for nothing), and the
+    read-only view is what the codec decodes from: no trailing
+    `bytes()` copy. Read-only, like the `bytes` it replaces, so
+    decoded arrays stay read-only views."""
     buf = np.empty(n, dtype=np.uint8)
     return memoryview(buf), memoryview(buf).toreadonly()
 
 
-def _recv_frame(conn: socket.socket, n: int):
+#: From this size on a connection keeps the memory a frame lay in.
+#: Chosen on the chip's host (PERF.md, PR 31): up to 32 MiB, glibc's
+#: largest mmap threshold, malloc hands a freed buffer's pages back
+#: mapped as they were and keeping them buys nothing (a 31 MiB frame:
+#: 7.0 ms a call fresh, 6.5 kept); over it every `np.empty` is a
+#: mapping of its own, 4 KiB a fault as `recv_into` fills it (33 MiB:
+#: 41 ms fresh, 7.6 kept). Task dispatch, stats and acknowledgements
+#: are far under it.
+KEEP_FRAME_BYTES = 32 << 20
+
+#: Kept memory is sized in whole multiples of this, so a header that
+#: grows by a byte does not replace the buffer of a 649 MB frame.
+_KEEP_GRANULE = 1 << 20
+
+
+class _FrameMemory:
+    """The memory one connection receives its large frames into: at
+    most one buffer, lent to a frame and back here when the last view
+    of that frame dies.
+
+    What decides is what the program can observe, not a setting. Each
+    frame is decoded over a lease, an array of its own over the
+    buffer, which every `np.frombuffer` view of the frame keeps alive
+    through the read-only `memoryview` it was built on; the lease's
+    `weakref.finalize` holds the buffer and hands it back, in CPython
+    the moment the last reference goes. A handler that keeps nothing
+    has returned the buffer before the connection reads its next
+    header; one that keeps an array of request n (`grads_to_wait` > 1,
+    fan-in, a client that holds the model it pulled) leaves the
+    connection with nothing to lend, and frame n+1 gets fresh memory.
+    A frame of another size replaces the buffer, the old one freed
+    first; `close()` frees it with the connection."""
+
+    def __init__(self):
+        # whichever thread drops the last view puts the buffer back,
+        # possibly inside a collection that interrupted `lend` on this
+        # one: a deque's append and pop are atomic and take no lock
+        # that a thread could ask for twice. maxlen: never two
+        self._spare = collections.deque(maxlen=1)
+        self._open = True
+
+    def lend(self, n: int):
+        """(writable view, read-only view, reused) for a frame of n
+        bytes; `reused` says the memory is the one an earlier frame of
+        this connection lay in."""
+        if n < KEEP_FRAME_BYTES:
+            return (*_frame_buffer(n), False)
+        size = -(-n // _KEEP_GRANULE) * _KEEP_GRANULE
+        try:
+            buf = self._spare.pop()
+        except IndexError:
+            buf = None
+        reused = buf is not None and buf.nbytes == size
+        if not reused:
+            buf = None  # replaced, not grown beside: freed first
+            buf = np.empty(size, dtype=np.uint8)
+        lease = buf[:n]
+        weakref.finalize(lease, self._give_back, buf).atexit = False
+        return memoryview(lease), memoryview(lease).toreadonly(), reused
+
+    def _give_back(self, buf):
+        if self._open:
+            self._spare.append(buf)
+
+    def close(self):
+        """Keep nothing from here on; a frame still read elsewhere
+        keeps its memory until its last view dies, then frees it."""
+        self._open = False
+        self._spare.clear()
+
+
+def _recv_frame(conn: socket.socket, n: int, memory: _FrameMemory):
     """Read a frame body of exactly n bytes with no copy beyond the
-    kernel's; ConnectionError on EOF inside it."""
-    view, frame = _frame_buffer(n)
+    kernel's, into the memory the connection lends (the pages of its
+    last large frame, mapped already, when nobody reads that one any
+    more); ConnectionError on EOF inside it. Returns the read-only
+    frame and whether its memory was reused."""
+    view, frame, reused = memory.lend(n)
     _recv_fill(conn, view, n)
-    return frame
+    return frame, reused
+
+
+#: The send and receive buffer both ends of a Unix-socket link ask
+#: for. The kernel's default (208 KB) makes 3,000 turns of a 649 MB
+#: frame, each taking the interpreter lock two or three times on
+#: either side; 4 MB makes under 200. Chosen on the chip by C's
+#: `sync_wire_ms` (PERF.md, PR 31: 1 MB 233, 2 MB 183, 4 MB 161 ms; 8
+#: MB is granted as 4 there). `wmem_max` / `rmem_max` may cap it: what
+#: the kernel granted is what the spans report.
+SOCKET_BUFFER_BYTES = 4 << 20
+
+
+def _ask_socket_buffers(sock: socket.socket):
+    """Ask for `SOCKET_BUFFER_BYTES` each way; (sndbuf, rcvbuf) as the
+    kernel granted them (Linux reports twice what it was asked, for
+    its own bookkeeping)."""
+    for opt in (socket.SO_SNDBUF, socket.SO_RCVBUF):
+        sock.setsockopt(socket.SOL_SOCKET, opt, SOCKET_BUFFER_BYTES)
+    return (
+        sock.getsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF),
+        sock.getsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF),
+    )
 
 
 #: The most buffers one `sendmsg` may gather (Linux's UIO_MAXIOV).
@@ -555,10 +670,11 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline: float):
     """Write `head`, then a frame's parts in order, gathered by
     `sendmsg` from where they lie: no buffer the size of the frame,
     and a frame that fits the socket buffer is one system call, header
-    and all. A turn sends at most a socket buffer's worth (about 200
-    KB with a timeout set), so a long part leaves over many turns:
-    what has left is dropped from the front and the rest gathered
-    again, at most `_IOV_MAX` buffers a turn. `deadline` (monotonic)
+    and all. A turn sends at most a socket buffer's worth (what the
+    kernel granted of `SOCKET_BUFFER_BYTES`; 208 KB where nothing was
+    asked), so a long part leaves over many turns: what has left is
+    dropped from the front and the rest gathered again, at most
+    `_IOV_MAX` buffers a turn. `deadline` (monotonic)
     is one budget over all the turns. The bytes on the socket are
     `head + b"".join(parts)`. (One `sendall` a long part, short parts
     joined, read 12-28 ms more wire a 649 MB sync on the chip's host:
@@ -686,7 +802,9 @@ class UdsServer:
                 conn.close()
                 return
             self._conns.add(conn)
+        memory = _FrameMemory()
         try:
+            _ask_socket_buffers(conn)
             while not self._is_closed():
                 header = _recv_exact(conn, _REQ_HEADER.size, eof_ok=True)
                 if header is None:
@@ -694,12 +812,7 @@ class UdsServer:
                 mlen, blen = _REQ_HEADER.unpack(header)
                 method = _recv_exact(conn, mlen).decode("utf-8")
                 try:
-                    # no local names the frame: once dispatched it lives
-                    # on only in what the handler kept of it, and a 649
-                    # MB request is gone before the connection's next
-                    resp = self._dispatcher.dispatch(
-                        method, _recv_frame(conn, blen), TRANSPORT_UDS
-                    )
+                    resp = self._serve_frame(conn, method, blen, memory)
                     _refuse_oversize("response", method, len(resp))
                 except grpc.RpcError as e:
                     conn.sendall(_error_frame(e))
@@ -707,14 +820,25 @@ class UdsServer:
                 conn.sendall(_RESP_OK.pack(0, len(resp)))
                 conn.sendall(resp)
         except (ConnectionError, OSError):
-            pass  # client went away; per-connection state is none
+            pass  # client went away
         finally:
+            memory.close()
             with self._conns_lock:
                 self._conns.discard(conn)
             try:
                 conn.close()
             except OSError:
                 pass
+
+    def _serve_frame(self, conn, method: str, blen: int, memory) -> bytes:
+        # a call of its own, so that nothing names the frame once it
+        # returns: the frame lives on only in what the handler kept of
+        # it, and a 649 MB request the handler only read has given its
+        # memory back before the connection's next
+        frame, reused = _recv_frame(conn, blen, memory)
+        return self._dispatcher.dispatch(
+            method, frame, TRANSPORT_UDS, recv_reused=reused
+        )
 
     def close(self):
         with self._conns_lock:
@@ -837,6 +961,22 @@ class AsyncUdsServer:
                 pass
 
 
+class _ClientConn(socket.socket):
+    """A pooled client connection and what it keeps between calls: the
+    memory its last large response lay in, the socket buffers the
+    kernel granted it, and whether it has carried a large request."""
+
+    def __init__(self):
+        super().__init__(socket.AF_UNIX, socket.SOCK_STREAM)
+        self.memory = _FrameMemory()
+        self.sndbuf = self.rcvbuf = 0
+        self.large = False
+
+    def close(self):
+        self.memory.close()
+        super().close()
+
+
 class UdsTransport:
     """Client side of the UDS fast path: a small pool of persistent
     connections (the worker's pipelined step reports overlap calls), a
@@ -852,15 +992,26 @@ class UdsTransport:
         self._plan = fault_plan
         self._pool: list = []
         self._pool_lock = threading.Lock()
+        self._last = threading.local()  # .link: see last_call()
 
-    def _checkout(self) -> socket.socket:
+    def _checkout(self, large: bool = False) -> "_ClientConn":
+        """A pooled connection, or a new one. A large request goes by
+        the connection that carried the last large one, whose far end
+        holds the memory that one lay in, and a small request by
+        another where one is pooled: a peer's large frames keep to one
+        connection, and the server to one buffer a peer, however many
+        connections the peer's threads opened."""
         with self._pool_lock:
+            for i in reversed(range(len(self._pool))):
+                if self._pool[i].large == large:
+                    return self._pool.pop(i)
             if self._pool:
                 return self._pool.pop()
-        conn = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+        conn = _ClientConn()
         try:
             with _sock_addr(self._path) as addr:
                 conn.connect(addr)
+            conn.sndbuf, conn.rcvbuf = _ask_socket_buffers(conn)
         except OSError as e:
             conn.close()
             raise CarrierDown(f"uds connect {self._path}: {e}")
@@ -885,14 +1036,26 @@ class UdsTransport:
                 except OSError:  # pragma: no cover - already severed
                     pass
 
+    def last_call(self) -> dict:
+        """What the connection that served the calling thread's last
+        answered call can say of it, for the round trip's span:
+        `sndbuf` and `rcvbuf` as the kernel granted them, and
+        `recv_reused`, whether the response lies in memory an earlier
+        one of that connection lay in. (Not a parameter of `call`: the
+        tiers' `call` is one signature, rpc-conformance.)"""
+        return getattr(self._last, "link", {})
+
     def call(self, method: str, payload, timeout: float) -> bytes:
         """`payload` is a `messages.PackedParts`: its parts go to the
         socket in order and are never joined."""
         _refuse_oversize("request", method, len(payload))
         # connect first: CarrierDown leaves the FaultPlan untouched, so
         # the gRPC channel that serves the call instead draws its fault
-        conn = self._checkout()
+        large = len(payload) >= KEEP_FRAME_BYTES
+        conn = self._checkout(large)
         try:
+            if large:
+                conn.large = True
             after = transport_faults_before(self._plan, method, "client")
             mb = method.encode("utf-8")
             _send_parts(
@@ -904,7 +1067,12 @@ class UdsTransport:
             status = _recv_exact(conn, 1)[0]
             if status == 0:
                 (blen,) = struct.unpack("<I", _recv_exact(conn, 4))
-                body = _recv_frame(conn, blen)
+                body, reused = _recv_frame(conn, blen, conn.memory)
+                self._last.link = {
+                    "recv_reused": reused,
+                    "sndbuf": conn.sndbuf,
+                    "rcvbuf": conn.rcvbuf,
+                }
             else:
                 code_val, dlen = struct.unpack("<iH", _recv_exact(conn, 6))
                 detail = _recv_exact(conn, dlen).decode("utf-8", "replace")

@@ -7,7 +7,8 @@ can get — the Unix socket and gRPC, its silent fallback: the SAME
 failure semantics (fencing -> FAILED_PRECONDITION, handler bugs ->
 INTERNAL with sanitized detail, unknown method -> UNIMPLEMENTED, a
 stopped server -> UNAVAILABLE), chaos FaultPlan injection, frames
-bit-equal and never reused — the WireStats transport dimension
+bit-equal, their memory reused only once nothing reads it — the
+WireStats transport dimension
 (per-endpoint bytes summing correctly across mixed tiers, inproc calls
 counted with ZERO wire bytes), a frame the socket header cannot
 describe, and the resource lifecycle of the socket listener.
@@ -16,6 +17,7 @@ describe, and the resource lifecycle of the socket listener.
 import os
 import socket
 import time
+import weakref
 
 import grpc
 import numpy as np
@@ -826,6 +828,314 @@ def test_arrays_of_request_n_survive_request_n_plus_1(env_fixture, request):
         server.stop()
 
 
+# -- a connection receives into the memory it already holds -------------------
+
+
+_LARGE = 2 << 20  # float32s: an 8 MB frame, over `keep_from_1mb`
+_OTHER = _LARGE + (1 << 19)  # 2 MB more: another size of kept memory
+_SMALL = 1 << 15  # 128 KB: under it
+
+
+@pytest.fixture
+def keep_from_1mb(monkeypatch):
+    """The threshold is the chip's host's (32 MiB: PERF.md, PR 31);
+    the rule is the same from 1 MiB on, with frames a test can afford."""
+    monkeypatch.setattr(transport, "KEEP_FRAME_BYTES", 1 << 20)
+
+
+def _frame_memory(arr):
+    """The array that owns the memory `arr`'s frame lay in: down the
+    bases to the frame's `memoryview`, then what that was exported by
+    (a lease on the connection's memory, or a frame's own buffer).
+    None where the frame is gRPC's `bytes`."""
+    while isinstance(arr, np.ndarray):
+        arr = arr.base
+    if not isinstance(arr, memoryview):
+        return None
+    owner = arr.obj
+    return owner if owner.base is None else owner.base
+
+
+class _ReceivingEnd:
+    """One server, one client, one pooled connection, and frames that
+    travel in `direction`: `recv` moves one frame of `n` float32s of
+    `value` and gives what its receiver decoded: the array's address,
+    a weak reference to the memory its frame lay in, and the array
+    itself where the receiver keeps it (the handler's `kept` list for
+    a request: the master's accumulation; the caller's hand for a
+    response)."""
+
+    def __init__(self, direction, server_kw=None, **client_kw):
+        self.direction = direction
+        self.kept = []
+        self._keep = False
+        self._seen = None
+        self.server = RpcServer(
+            {"Move": self._move}, port=0, **(server_kw or {})
+        )
+        self.server.start()
+        self.client = RpcClient(
+            f"localhost:{self.server.port}", policy=fast_policy(), **client_kw
+        )
+        self.socket = _tier(self.client) == "uds"
+
+    def _note(self, arr):
+        memory = _frame_memory(arr)
+        return (
+            arr.__array_interface__["data"][0],
+            None if memory is None else weakref.ref(memory),
+        )
+
+    def _move(self, req):
+        if self.direction == "response":
+            return {"x": np.full(req["n"], req["value"], np.float32),
+                    "version": 1}
+        x = req["x"]
+        assert float(x.min()) == float(x.max()) == req["value"]
+        self._seen = self._note(x)
+        if self._keep:
+            self.kept.append(x)
+        return {"version": 1}
+
+    def recv(self, value, n=_LARGE, keep=False):
+        if self.direction == "response":
+            resp = self.client.call(
+                "Move", {"n": n, "value": value}, timeout=60, idempotent=False
+            )
+            arr = resp["x"]
+            assert float(arr.min()) == float(arr.max()) == value
+            if keep:
+                self.kept.append(arr)
+            return self._note(arr)
+        self._keep = keep
+        self.client.call(
+            "Move", {"x": np.full(n, value, np.float32), "value": value},
+            timeout=60, idempotent=False,
+        )
+        return self._seen
+
+    def close(self):
+        self.client.close()
+        self.server.stop()
+
+
+def _dies(ref, timeout=10.0):
+    """Whether the weakly referenced memory goes within `timeout` (a
+    server's connection thread frees its own once it reads the EOF)."""
+    deadline = time.monotonic() + timeout
+    while ref() is not None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return ref() is None
+
+
+def _kept_nothing(end):
+    """(a) A receiver that keeps nothing: the second large frame of the
+    same size lies where the first did; one of another size replaces
+    the memory (the old freed, not kept beside) and is reused in turn."""
+    first, memory = end.recv(1.0)
+    second, again = end.recv(2.0)
+    if end.socket:  # the buffer itself, not an address malloc gave twice
+        assert again() is memory() is not None
+    del again
+    third, _ = end.recv(3.0, n=_OTHER)
+    fourth, _ = end.recv(4.0, n=_OTHER)
+    end.recv(5.0)  # and back to the first size
+    if end.socket:
+        assert second == first
+        assert fourth == third
+        assert _dies(memory)
+
+
+def _kept_one(end):
+    """(b) A receiver that keeps an array of frame n: frame n+1 lies
+    elsewhere, frame n+2 where n+1 did, and the kept array reads frame
+    n's values after both."""
+    first, _ = end.recv(1.0, keep=True)
+    second, _ = end.recv(2.0)
+    third, _ = end.recv(3.0)
+    (kept,) = end.kept
+    assert float(kept.min()) == float(kept.max()) == 1.0
+    assert kept.__array_interface__["data"][0] == first
+    if end.socket:
+        assert second != first
+        assert third == second
+
+
+def _dropped_later(end):
+    """(c) A kept array that is dropped later gives the memory back:
+    the frame after that lies where the kept one did."""
+    first, memory = end.recv(1.0, keep=True)
+    second, spare = end.recv(2.0)
+    assert float(end.kept[0].max()) == 1.0
+    del end.kept[:]
+    third, _ = end.recv(3.0)
+    if end.socket:
+        assert second != first
+        assert third == first
+        assert memory() is not None
+        # one buffer a connection, never two: frame n+1's went when
+        # frame n's came back
+        assert _dies(spare)
+
+
+def _closed(end):
+    """(d) A closed connection holds no memory; an array that outlives
+    it still reads its frame, and takes the memory with it when it
+    goes."""
+    _, idle = end.recv(1.0)
+    _, held = end.recv(2.0, keep=True)
+    if end.socket:
+        assert idle() is held() is not None
+    end.close()
+    (kept,) = end.kept
+    assert float(kept.min()) == float(kept.max()) == 2.0
+    del kept, end.kept[:]
+    if end.socket:
+        assert _dies(held)
+
+
+def _small(end):
+    """(e) A frame under the threshold never keeps memory: it lies in
+    a buffer of its own, which goes with its last array while the
+    connection lives on."""
+    assert _SMALL * 4 < transport.KEEP_FRAME_BYTES
+    first, memory = end.recv(1.0, n=_SMALL, keep=True)
+    end.recv(2.0, n=_SMALL)
+    (kept,) = end.kept
+    assert float(kept.min()) == float(kept.max()) == 1.0
+    if end.socket:
+        # the frame's own buffer, not a lease on a longer one
+        assert _frame_memory(kept).nbytes < _SMALL * 4 + 4096
+        del kept, end.kept[:]
+        assert _dies(memory, timeout=1.0)
+        assert len(end.client._transport._pool) == 1
+
+
+_REUSE_CASES = {
+    "kept_nothing": _kept_nothing,
+    "kept_one": _kept_one,
+    "dropped_later": _dropped_later,
+    "closed": _closed,
+    "small": _small,
+}
+
+
+@pytest.mark.parametrize("env_fixture", ["unset_env", "grpc_env"])
+@pytest.mark.parametrize("direction", ["request", "response"])
+@pytest.mark.parametrize("case", sorted(_REUSE_CASES))
+def test_a_connection_receives_into_the_memory_it_holds(
+    case, direction, env_fixture, request, keep_from_1mb
+):
+    """Beside the reuse guard above: a connection lends the memory of
+    its last large frame to the next one exactly when nothing reads
+    the old frame any more. Requests into the server and responses
+    into the client alike; over gRPC, which has no such memory, the
+    same traffic reads the same values."""
+    request.getfixturevalue(env_fixture)
+    end = _ReceivingEnd(direction)
+    try:
+        assert end.socket == (env_fixture == "unset_env")
+        _REUSE_CASES[case](end)
+        if end.socket and case != "closed":
+            # one pooled connection carried every frame
+            assert len(end.client._transport._pool) == 1
+    finally:
+        end.close()
+
+
+def test_a_peers_large_requests_keep_to_one_connection(
+    unset_env, keep_from_1mb
+):
+    """A worker's threads leave several connections in the pool (the
+    sync thread's, the task loop's). Its large requests go by the one
+    that carried the last, whichever order the pool holds them in, and
+    its small ones by another: the server keeps one buffer a peer."""
+    end = _ReceivingEnd("request")
+    try:
+        pool = end.client._transport
+        two = [pool._checkout(), pool._checkout()]
+        for conn in two:
+            pool._checkin(conn)
+        _, memory = end.recv(1.0)
+        for value in (2.0, 3.0, 4.0):
+            end.recv(value, n=_SMALL)
+            for conn in [pool._checkout(), pool._checkout()]:
+                pool._checkin(conn)  # the other way round each time
+            _, again = end.recv(value)
+            assert again() is memory() is not None
+            del again
+        assert sorted(conn.large for conn in two) == [False, True]
+        assert len(pool._pool) == 2
+    finally:
+        end.close()
+
+
+class _Spans:
+    """A `PhaseTimers` stand-in that keeps what the dispatcher records."""
+
+    def __init__(self):
+        self.records = []
+
+    def record(self, name, t0, t1, **args):
+        self.records.append((name, args))
+
+
+@pytest.mark.parametrize("env_fixture", ["unset_env", "grpc_env"])
+def test_the_spans_say_whether_the_memory_was_reused(
+    env_fixture, request, timeline_spans, keep_from_1mb
+):
+    """The master's `rpc.decode` of a request and the client's round
+    trip carry `recv_reused` (false for a connection's first frame,
+    for a small one and on gRPC), and the round trip the socket
+    buffers as the kernel granted them."""
+    request.getfixturevalue(env_fixture)
+    timers = _Spans()
+    end = _ReceivingEnd(
+        "request", timeline=("Move",),
+        server_kw={"timers": timers, "timed_methods": ("Move",)},
+    )
+    try:
+        for value in (1.0, 2.0, 3.0):
+            end.recv(value)
+        end.recv(4.0, n=_SMALL)
+        uds = end.socket
+        if uds:
+            (conn,) = end.client._transport._pool
+            granted = {
+                "sndbuf": conn.getsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF),
+                "rcvbuf": conn.getsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF),
+            }
+            # asked for more than the kernel's default, and got it or
+            # the cap; Linux reports twice what it grants
+            assert granted["sndbuf"] > 212992
+            assert granted["sndbuf"] <= 2 * transport.SOCKET_BUFFER_BYTES
+    finally:
+        end.close()
+    decodes = [a for name, a in timers.records if name == "rpc.decode"]
+    assert [a["recv_reused"] for a in decodes] == [False, uds, uds, False]
+    trips = [s["args"] for s in timeline_spans("rpc.client.Move")]
+    # the acknowledgement is a small frame: never reused
+    assert [a["recv_reused"] for a in trips] == [False] * 4
+    for args in trips:
+        if uds:
+            assert {k: args[k] for k in granted} == granted
+        else:
+            assert "sndbuf" not in args and "rcvbuf" not in args
+
+
+def test_a_large_response_says_reused_on_the_clients_span(
+    unset_env, timeline_spans, keep_from_1mb
+):
+    end = _ReceivingEnd("response", timeline=("Move",))
+    try:
+        for value in (1.0, 2.0, 3.0):
+            end.recv(value)
+    finally:
+        end.close()
+    trips = [s["args"] for s in timeline_spans("rpc.client.Move")]
+    assert [a["recv_reused"] for a in trips] == [False, True, True]
+
+
 # -- a frame the socket header cannot describe --------------------------------
 
 
@@ -1021,7 +1331,7 @@ class _FrameKeeper:
     def __init__(self):
         self.frames = []
 
-    def dispatch(self, method, frame, tier):
+    def dispatch(self, method, frame, tier, recv_reused=False):
         self.frames.append((method, bytes(frame)))
         return messages.pack({"ok": len(self.frames)})
 
@@ -1114,15 +1424,18 @@ def test_a_small_frame_is_one_write_and_a_long_leaf_leaves_uncopied(keeper):
 
     real_checkout = client._checkout
 
-    def checkout():  # the pool hands a checked-in spy back as it is
-        conn = real_checkout()
+    def checkout(large=False):  # the pool hands a checked-in spy back as it is
+        conn = real_checkout(large)
         return conn if isinstance(conn, _Spy) else _Spy(conn)
 
     client._checkout = checkout
     client.call("GetTask", messages.pack_parts({"worker_id": 3}), 10.0)
     assert len(turns) == 1
     del turns[:]
-    big = np.arange(1 << 18, dtype=np.float32)  # 1 MiB: several turns
+    # several turns: four times what the kernel granted the connection
+    granted = client._checkout()
+    client._checkin(granted)
+    big = np.arange(granted.sndbuf, dtype=np.float32)
     request = {"a": np.ones(5, np.float32), "big": big, "z": np.ones(9)}
     client.call("Push", messages.pack_parts(request), 10.0)
     assert len(turns) > 1
