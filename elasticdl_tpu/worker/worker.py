@@ -23,6 +23,7 @@ from __future__ import annotations
 import contextlib
 import inspect
 import os
+import sys
 import threading
 import time
 import uuid
@@ -93,6 +94,21 @@ def _on_jax_event(event, **_kwargs):
 
 
 jax.monitoring.register_event_listener(_on_jax_event)
+
+
+@contextlib.contextmanager
+def _compiles_into(info: dict):
+    """`compiles` and, where there were any, `cache_hit` (every one of
+    them served by the persistent cache) of the block, into a span's
+    arguments."""
+    requests, hits = _CACHE_EVENTS["requests"], _CACHE_EVENTS["hits"]
+    yield
+    compiled = _CACHE_EVENTS["requests"] - requests
+    info["compiles"] = compiled
+    if compiled:
+        info["cache_hit"] = _CACHE_EVENTS["hits"] - hits == compiled
+
+
 _NO_SPAN = contextlib.nullcontext()
 
 
@@ -1309,23 +1325,55 @@ class Worker:
         return model.apply(variables, *args, **kwargs), None
 
     def _init_model(self, features, embeddings):
+        """Build the variables the worker offers the PS: the span
+        `setup.model_init` with `how: init`. A flax module's `init` is
+        traced (`traced: true`, with `compiles` and `cache_hit` as
+        `setup.program` carries them), a duck-typed adapter's is called
+        as it is; the model's type alone decides."""
         model = self._spec.model
         args = [features]
         if self._emb_specs:
             args.append(embeddings)
         kwargs = {"train": False} if self._takes_train_kwarg() else {}
-        # init on CPU: flax init is eager op-by-op — one dispatch per
-        # op on the chip (cost not measured on this machine); on host
-        # it is milliseconds, then ONE bulk transfer
-        with jax.default_device(jax.local_devices(backend="cpu")[0]):
-            variables = model.init(self._rng, *args, **kwargs)
-        variables = jax.tree_util.tree_map(np.asarray, variables)
-        self._params = variables["params"]
-        self._aux = {k: v for k, v in variables.items() if k != "params"}
-        self._maybe_init_flat_from_tree(self._params)
-        if not self._use_flat():
-            self._params = jax.tree_util.tree_map(jnp.asarray, self._params)
-        self._aux = jax.tree_util.tree_map(jnp.asarray, self._aux)
+        # a flax module exists only where flax is imported: the LM
+        # adapters' jobs never import it (0.3 s of a worker's boot)
+        linen = sys.modules.get("flax.linen")
+        traced = linen is not None and isinstance(model, linen.Module)
+        with self.timers.span(
+            "setup.model_init", how="init", traced=traced
+        ) as info:
+            # on the host's backend, measured against the chip's
+            # (ResNet-50, v5e host, PR 32): 3.4 s against 4.0 s from
+            # the compile cache, 7.3 s against 22.9 s compiling; the
+            # values are eager `model.init`'s there (bit for bit but
+            # for a last place where XLA folds two constant factors:
+            # tests/test_traced_init.py), the chip's are not; and
+            # nothing is added to the chip's memory
+            with jax.default_device(jax.local_devices(backend="cpu")[0]):
+                if traced:
+                    # flax `init` runs `__call__`. Called eagerly that
+                    # was a whole forward pass on the first batch, op
+                    # by op on the host: 43 s of ResNet-50's set-up at
+                    # 256 images of 224 px in bfloat16. Under a trace
+                    # the forward only yields shapes and XLA removes it
+                    # whole: what compiles and runs is the initialisers
+                    # alone. `_build_train_step` traces the same module
+                    # a moment later, so one that cannot be traced
+                    # cannot be trained
+                    def init(rng, *call_args):
+                        return model.init(rng, *call_args, **kwargs)
+
+                    with _compiles_into(info):
+                        variables = jax.jit(init)(self._rng, *args)
+                else:  # e.g. TransformerLM: draws with numpy, no forward
+                    variables = model.init(self._rng, *args, **kwargs)
+            variables = jax.tree_util.tree_map(np.asarray, variables)
+            self._params = variables["params"]
+            self._aux = {k: v for k, v in variables.items() if k != "params"}
+            self._maybe_init_flat_from_tree(self._params)
+            if not self._use_flat():
+                self._params = jax.tree_util.tree_map(jnp.asarray, self._params)
+            self._aux = jax.tree_util.tree_map(jnp.asarray, self._aux)
 
     def _build_train_step(self):
         spec = self._spec
@@ -2660,13 +2708,9 @@ class Worker:
 
     @contextlib.contextmanager
     def _program_span(self, program: str):
-        requests, hits = _CACHE_EVENTS["requests"], _CACHE_EVENTS["hits"]
         with self.timers.span("setup.program", program=program) as info:
-            yield
-            compiled = _CACHE_EVENTS["requests"] - requests
-            info["compiles"] = compiled
-            if compiled:  # every one of them served by the cache
-                info["cache_hit"] = _CACHE_EVENTS["hits"] - hits == compiled
+            with _compiles_into(info):
+                yield
 
     def _first_run_begins(self, mode: str):
         if not self._first_run_begun and self._first_run is not None:
@@ -2902,8 +2946,7 @@ class Worker:
             init_embs = self._dev_embedding_inputs(
                 self._prepare_embeddings(features)
             )
-        with self.timers.span("setup.model_init", how="init"):
-            self._init_model(features, init_embs)
+        self._init_model(features, init_embs)  # its own `how: init` span
         with self.timers.span("setup.model_init", how="report"):
             self.report_variable()
         with self.timers.span("setup.model_init", how="pull"):

@@ -601,6 +601,27 @@ def test_a_program_s_first_call_is_one_setup_span_at_its_call_site():
     assert "setup.program" not in worker.timers.snapshot()
 
 
+def test_an_adapter_s_init_says_it_was_not_traced():
+    """`how: init` with `traced: false` is the adapter's own draw (the
+    LMs' numpy normals), with no compile to report."""
+    from elasticdl_tpu.api.model_spec_helpers import spec_from_module
+    from elasticdl_tpu.models import transformer_lm_zoo
+    from elasticdl_tpu.testing import InProcessMaster
+    from elasticdl_tpu.worker.worker import Worker
+
+    spec = spec_from_module(transformer_lm_zoo)
+    servicer = MasterServicer(
+        grads_to_wait=1, optimizer=PSOptimizer(spec.optimizer())
+    )
+    worker = Worker(0, InProcessMaster(servicer), spec, minibatch_size=2)
+    worker._lazy_init_model(np.zeros((2, 8), np.int32))
+    args = [s["args"] for s in _spans("setup.model_init")]
+    assert [a["how"] for a in args] == ["init", "report", "pull"]
+    assert args[0]["traced"] is False
+    assert "compiles" not in args[0] and "cache_hit" not in args[0]
+    assert all("traced" not in a for a in args[1:])
+
+
 def _train(tmp_path, monkeypatch, local_updates, records=128):
     """A real Worker against a real servicer, in process."""
     from elasticdl_tpu.api.model_spec_helpers import spec_from_module
@@ -637,8 +658,15 @@ def test_a_worker_s_run_is_on_the_timeline(tmp_path, monkeypatch, local_updates)
     assert sum(s["args"]["steps"] for s in computes) == 128 // 16
     assert worker.timers.snapshot()["compute"]["count"] == len(computes)
     programs = {s["args"]["program"] for s in _spans("setup.program")}
-    how = [s["args"]["how"] for s in _spans("setup.model_init")]
-    assert {"init", "report", "pull"} <= set(how)
+    inits = _spans("setup.model_init")
+    # the failed first pull, then the handshake in its order
+    assert [s["args"]["how"] for s in inits][-3:] == ["init", "report", "pull"]
+    # a flax module's `init` is traced, and says what it compiled as a
+    # `setup.program` does; the init program has no span of that name
+    (init,) = [s["args"] for s in inits if s["args"]["how"] == "init"]
+    assert init["traced"] is True and init["compiles"] >= 0
+    assert ("cache_hit" in init) == bool(init["compiles"])
+    assert not [p for p in programs if "init" in p]
     if local_updates:
         assert {"jit_window", "jit_subtract", "jit_copy"} <= programs
         syncs = _spans("worker.window_sync")
