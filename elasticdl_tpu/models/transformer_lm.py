@@ -42,7 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from elasticdl_tpu.parallel.moe import moe_ffn
 from elasticdl_tpu.parallel.pipeline import gpipe
 from elasticdl_tpu.parallel.ring_attention import ring_attention
-from elasticdl_tpu.parallel.tp_layers import rms_norm
+from elasticdl_tpu.parallel.tp_layers import rms_norm, swiglu
 
 MESH_AXES = ("pp", "dp", "sp", "tp")
 
@@ -83,6 +83,30 @@ class TransformerConfig:
     # outputs are `LoopedOutputs` and the loss `looped_exit_loss`
     n_loops: int = 1
     exit_entropy_weight: float = 0.1  # beta of the exit objective
+    # "mla": latent attention (DeepSeek-V2). Keys and values come from
+    # one normed latent of `kv_lora_rank`; a head's query and key are
+    # `qk_nope_dim` of their own plus `qk_rope_dim` that turn (the
+    # turning key is ONE vector all heads share), its value `v_head_dim`
+    attention: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional["YarnScaling"] = None  # the turning part's angles
+    # this many leading layers keep the dense MLP (`mlp`, `d_ff`) in a
+    # stack whose other layers are expert layers (`params["dense"]`)
+    n_dense_layers: int = 0
+    # > 0: the expert layers route every token to its `moe_top_k`
+    # likeliest of `n_experts` and drop none; experts are SwiGLUs of
+    # `d_expert`, `n_shared_experts` more of that width see every
+    # token, and of the routed ones this program holds `held_experts`
+    # = (first, count), all of them when None
+    # (`parallel.moe.moe_topk_held`); `aux_weight` is then the weight of
+    # the sequence-wise balance term. 0: the Switch top-1 layer
+    moe_top_k: int = 0
+    n_shared_experts: int = 0
+    held_experts: Optional[Tuple[int, int]] = None
+    routed_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -91,6 +115,63 @@ class TransformerConfig:
     @property
     def looped(self) -> bool:
         return self.n_loops > 1
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.held_experts or (0, self.n_experts)
+
+
+class YarnScaling(NamedTuple):
+    """YaRN's settings as a model's `rope_scaling` states them."""
+
+    factor: float
+    beta_fast: float
+    beta_slow: float
+    original_length: int
+    mscale: float
+    mscale_all_dim: float
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, base: float, yarn: Optional[YarnScaling]):
+    """The `dim // 2` rotary frequencies, float32: base^(-2i/dim), and
+    under YaRN that blended with the same divided by `factor` — pairs
+    that turn more than `beta_fast` times over the original length
+    keep their frequency, those that turn less than `beta_slow` times
+    take the divided one, a linear ramp between (Peng et al. 2023, as
+    the released DeepSeek-V2 modelling file computes it)."""
+    exponent = np.arange(0, dim, 2, dtype=np.float32) / dim
+    plain = 1.0 / base**exponent
+    if yarn is None:
+        return plain.astype(np.float32)
+
+    def turns_to_pair(turns):
+        return dim * math.log(
+            yarn.original_length / (turns * 2 * math.pi)
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(turns_to_pair(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_to_pair(yarn.beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2, dtype=np.float32) - low)
+        / max(high - low, 0.001),
+        0, 1,
+    )
+    return (plain / yarn.factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def mla_softmax_scale(cfg: "TransformerConfig") -> float:
+    """(qk_nope + qk_rope)^-0.5 x mscale^2, mscale from YaRN's
+    `mscale_all_dim` (1 without YaRN)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_yarn is not None:
+        scale *= _yarn_mscale(
+            cfg.rope_yarn.factor, cfg.rope_yarn.mscale_all_dim
+        ) ** 2
+    return scale
 
 
 def _remat(body, cfg: "TransformerConfig"):
@@ -114,6 +195,8 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
         return (rng.standard_normal(shape) * scale).astype(np.float32)
 
     L, d, hd = cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim
+    if cfg.moe_top_k or cfg.attention == "mla" or cfg.n_dense_layers:
+        return _init_routed_params(norm, cfg)
     layers = {
         "ln1": np.ones((L, d), np.float32),
         "wq": norm(L, d, hd),
@@ -149,6 +232,53 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
             "b": np.zeros((1,), np.float32),
         }
     return params
+
+
+def _init_routed_params(norm, cfg: TransformerConfig) -> Dict:
+    """The stack that is not of one shape: `n_dense_layers` layers with
+    the dense gated MLP under "dense", then the expert layers under
+    "layers", each stacked on its own leading dim; latent attention in
+    both."""
+    d, n_dense = cfg.d_model, cfg.n_dense_layers
+    if not (cfg.moe_top_k and cfg.attention == "mla" and cfg.mlp == "swiglu"):
+        raise NotImplementedError(
+            "the routed stack is built with latent attention, top-k "
+            "experts and SwiGLU MLPs together (attention='mla', "
+            "moe_top_k > 0, mlp='swiglu')"
+        )
+
+    def block(L):
+        heads, rank = cfg.n_heads, cfg.kv_lora_rank
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            "wq": norm(L, d, heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)),
+            "wkva": norm(L, d, rank + cfg.qk_rope_dim),
+            "kv_norm": np.ones((L, rank), np.float32),
+            "wkvb": norm(L, rank, heads * (cfg.qk_nope_dim + cfg.v_head_dim)),
+            "wo": norm(L, heads * cfg.v_head_dim, d),
+            "ln2": np.ones((L, d), np.float32),
+        }
+
+    dense = block(n_dense)
+    dense.update(
+        wg=norm(n_dense, d, cfg.d_ff), wu=norm(n_dense, d, cfg.d_ff),
+        wd=norm(n_dense, cfg.d_ff, d),
+    )
+    L, (_first, held) = cfg.n_layers - n_dense, cfg.held
+    f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
+    layers = block(L)
+    layers.update(
+        router=norm(L, d, cfg.n_experts),
+        eg=norm(L, held, d, f), eu=norm(L, held, d, f), ed=norm(L, held, f, d),
+        sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d),
+    )
+    return {
+        "embed": norm(cfg.vocab, d, scale=0.02),
+        "dense": dense,
+        "layers": layers,
+        "ln_f": np.ones((d,), np.float32),
+        "head": norm(d, cfg.vocab),
+    }
 
 
 def param_partition_specs(cfg: TransformerConfig) -> Dict:
@@ -187,24 +317,31 @@ def param_partition_specs(cfg: TransformerConfig) -> Dict:
 
 def _require_mesh_support(cfg: TransformerConfig):
     """The 4-axis mesh path knows the original block only."""
-    if cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped:
+    if (
+        cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped
+        or cfg.attention != "mha" or cfg.n_dense_layers or cfg.moe_top_k
+    ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
-            "block once: mlp='swiglu', sandwich_norm and n_loops > 1 "
-            "exist on the unsharded path (plain_forward) only"
+            "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
+            "attention='mla', n_dense_layers and moe_top_k exist on the "
+            "unsharded path (plain_forward) only"
         )
 
 
 def _rope(
-    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0
+    x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0,
+    freqs=None,
 ) -> jnp.ndarray:
     """Rotary embedding; x: [B, L, H, D], positions: [L] global. The
     angles are float32 whatever x is (bfloat16 holds no whole number
     above 256 exactly: position 2047 would turn as 2048); their cosine
-    and sine are cast to x's dtype."""
+    and sine are cast to x's dtype. Pair i is (x[i], x[i + D/2]).
+    `freqs` [D/2] float32 replaces base^(-2i/D) (`yarn_frequencies`)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if freqs is None:
+        freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [L, half]
     cos = jnp.cos(ang).astype(x.dtype)[None, :, None, :]
     sin = jnp.sin(ang).astype(x.dtype)[None, :, None, :]
@@ -402,6 +539,39 @@ def exit_stats(gates: jnp.ndarray) -> Dict:
     return {"exit_q": mean_q, "expected_exit": jnp.sum(passes * mean_q)}
 
 
+def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
+    """Latent attention on the normed x [B, L, d] -> [B, L, d]. Queries
+    are projected whole (no query latent); keys and values come from
+    one normed latent c and ONE rotary key that every head shares:
+    q = [q_nope | rope(q_pe)], k = [k_nope(c) | rope(k_pe)], v = v(c).
+    `wkvb`'s columns are a head's k_nope then its v, head by head. The
+    turning parts are laid out as `_rope` turns them, pair i =
+    (x[i], x[i + D/2]); the released modelling file stores them
+    interleaved and permutes to this layout before it turns them, so
+    with weights of one's own the two differ by a fixed permutation of
+    the turning columns of `wq` and `wkva`."""
+    from elasticdl_tpu.ops.flash_attention import attention
+
+    b, l, _ = x.shape
+    heads, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, rot = cfg.qk_nope_dim, cfg.qk_rope_dim
+    freqs = yarn_frequencies(rot, cfg.rope_base, cfg.rope_yarn)
+    q = (x @ lp["wq"]).reshape(b, l, heads, nope + rot)
+    kva = x @ lp["wkva"]  # [B, L, rank + rot]
+    latent = rms_norm(kva[..., :rank], lp["kv_norm"], cfg.norm_eps)
+    kv = (latent @ lp["wkvb"]).reshape(b, l, heads, nope + cfg.v_head_dim)
+    q_pe = _rope(q[..., nope:], positions, freqs=freqs)
+    k_pe = _rope(kva[..., None, rank:], positions, freqs=freqs)  # one head
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, l, heads, rot))], axis=-1
+    )
+    out = attention(
+        q, k, kv[..., nope:], causal=True, scale=mla_softmax_scale(cfg)
+    )
+    return out.reshape(b, l, heads * cfg.v_head_dim) @ lp["wo"]
+
+
 def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     """Vectorized unsharded forward — the same math as the sharded path
     restricted to a 1-device mesh, without the machinery: `lax.scan`
@@ -420,77 +590,127 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     load-balance loss (0 for dense).
 
     ONE definition of the block serves every unsharded LM; the
-    configuration selects the MLP (`mlp`), the rotary base, the norm
-    epsilon and the four-norm sandwich. With `n_loops` > 1 the stack
-    is run that many times by an outer `lax.scan` that closes over
-    the one set of stacked layer weights (the gradients of the passes
-    sum into one leaf), the final norm closes every pass, each layer
-    application is rematerialized, and the first return value is
-    `LoopedOutputs`: all exits' logits and gates. Only that looped
-    block carries `jax.named_scope`s (`looped_stack` > `attention`,
-    `mlp`; `exit_heads`): the other configurations' programs keep the
-    metadata, and so the compile-cache keys, they had."""
-    from elasticdl_tpu.ops.flash_attention import attention
-    from elasticdl_tpu.parallel.moe import moe_ffn_local
+    configuration selects the attention (`attention`), the MLP (`mlp`),
+    the rotary base, the norm epsilon and the four-norm sandwich. With
+    `n_loops` > 1 the stack is run that many times by an outer
+    `lax.scan` that closes over the one set of stacked layer weights
+    (the gradients of the passes sum into one leaf), the final norm
+    closes every pass, each layer application is rematerialized, and
+    the first return value is `LoopedOutputs`: all exits' logits and
+    gates. With `moe_top_k` the stack is not of one shape: the
+    `n_dense_layers` dense layers are scanned first, then the expert
+    layers (`parallel/moe.moe_topk_held` on the experts held here),
+    and aux is the summed sequence-wise balance term. Only those two
+    carry `jax.named_scope`s (`looped_stack` > `attention`, `mlp`;
+    `exit_heads`; and `mla`, `mlp`, `moe` > `route`, `experts`,
+    `shared`): the other configurations' programs keep the metadata,
+    and so the compile-cache keys, they had."""
+    return plain_forward_stats(cfg, params, tokens)[:2]
 
+
+def plain_forward_stats(
+    cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray
+):
+    """`plain_forward` and, third, what the expert layers' routers did
+    with this batch: `expert_tokens` [expert layers, held], and the
+    layers' mean `held_share` and `router_entropy` ({} without
+    `moe_top_k`). The zoo adapter leaves it in `window_stats`."""
+    from elasticdl_tpu.ops.flash_attention import attention
+    from elasticdl_tpu.parallel.moe import moe_ffn_local, moe_topk_held
+
+    stored = params
     params = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), params)
     b, l = tokens.shape
     h = params["embed"][tokens]  # [B, L, d]
     positions = jnp.arange(l)
     eps = cfg.norm_eps
-    scope = jax.named_scope if cfg.looped else (
+    routed = bool(cfg.moe_top_k)
+    scope = jax.named_scope if cfg.looped or routed else (
         lambda _name: contextlib.nullcontext()
     )
 
-    def body(carry, lp):
-        h, aux = carry
-        with scope("attention"):
-            x = rms_norm(h, lp["ln1"], eps)
-            q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-            k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-            v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-            q = _rope(q, positions, cfg.rope_base)
-            k = _rope(k, positions, cfg.rope_base)
-            out = attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
-            if cfg.sandwich_norm:
-                out = rms_norm(out, lp["ln1b"], eps)
-            h = h + out
-        with scope("mlp"):
-            x = rms_norm(h, lp["ln2"], eps)
-            if cfg.n_experts:
-                out, a = moe_ffn_local(
-                    x.reshape(b * l, cfg.d_model),
-                    lp["router"],
-                    lp["ew1"],
-                    lp["ew2"],
-                    capacity_factor=cfg.capacity_factor,
-                )
-                out = out.reshape(b, l, cfg.d_model)
-            elif cfg.mlp == "swiglu":
-                out = (jax.nn.silu(x @ lp["wg"]) * (x @ lp["wu"])) @ lp["wd"]
-            else:
-                out = jax.nn.gelu(x @ lp["w1"]) @ lp["w2"]
-            if cfg.sandwich_norm:
-                out = rms_norm(out, lp["ln2b"], eps)
-            h = h + out
-            if cfg.n_experts:
-                aux = aux + a
-        return (h, aux), None
+    def attend(lp, x):
+        if cfg.attention == "mla":
+            return _mla(cfg, lp, x, positions)
+        q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+        k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+        v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+        q = _rope(q, positions, cfg.rope_base)
+        k = _rope(k, positions, cfg.rope_base)
+        return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
 
-    if cfg.remat or cfg.looped:
-        body = _remat(body, cfg)
+    def layer(experts: bool):
+        """The scanned body of a layer with the dense MLP, or with the
+        configuration's expert layer in its place."""
+
+        def body(carry, lp):
+            h, aux = carry
+            stats = {}
+            with scope("mla" if cfg.attention == "mla" else "attention"):
+                out = attend(lp, rms_norm(h, lp["ln1"], eps))
+                if cfg.sandwich_norm:
+                    out = rms_norm(out, lp["ln1b"], eps)
+                h = h + out
+            with scope("moe" if experts and routed else "mlp"):
+                x = rms_norm(h, lp["ln2"], eps)
+                if experts and routed:
+                    out, a, stats = moe_topk_held(
+                        x, lp["router"],
+                        (lp["eg"], lp["eu"], lp["ed"]),
+                        (lp["sg"], lp["su"], lp["sd"]),
+                        top_k=cfg.moe_top_k, held=cfg.held,
+                        scaling=cfg.routed_scaling,
+                    )
+                elif experts:
+                    out, a = moe_ffn_local(
+                        x.reshape(b * l, cfg.d_model),
+                        lp["router"],
+                        lp["ew1"],
+                        lp["ew2"],
+                        capacity_factor=cfg.capacity_factor,
+                    )
+                    out = out.reshape(b, l, cfg.d_model)
+                elif cfg.mlp == "swiglu":
+                    out = swiglu(x, lp["wg"], lp["wu"], lp["wd"])
+                else:
+                    out = jax.nn.gelu(x @ lp["w1"]) @ lp["w2"]
+                if cfg.sandwich_norm:
+                    out = rms_norm(out, lp["ln2b"], eps)
+                h = h + out
+                if experts:
+                    aux = aux + a
+            return (h, aux), stats
+
+        if cfg.remat or cfg.looped:
+            return _remat(body, cfg)
+        return body
+
+    expert_layers = params["layers"]
+    if routed:
+        # the router decides in float32 from float32 weights
+        expert_layers = {**expert_layers, "router": stored["layers"]["router"]}
 
     def stack(carry):
-        (h, aux), _ = lax.scan(body, carry, params["layers"])
-        return rms_norm(h, params["ln_f"], eps), aux
+        if cfg.n_dense_layers:
+            carry, _ = lax.scan(layer(False), carry, params["dense"])
+        (h, aux), stats = lax.scan(
+            layer(bool(cfg.n_experts)), carry, expert_layers
+        )
+        return rms_norm(h, params["ln_f"], eps), aux, stats
 
-    carry = (h, jnp.zeros((), dtype=h.dtype))
+    carry = (h, jnp.zeros((), dtype=jnp.float32 if routed else h.dtype))
     if not cfg.looped:
-        h, aux = stack(carry)
-        return h @ params["head"], aux
+        h, aux, stats = stack(carry)
+        if routed:
+            stats = {
+                "expert_tokens": stats["expert_tokens"],
+                "held_share": jnp.mean(stats["held_share"]),
+                "router_entropy": jnp.mean(stats["router_entropy"]),
+            }
+        return h @ params["head"], aux, stats
 
     def one_pass(carry, _):
-        h, aux = stack(carry)
+        h, aux, _stats = stack(carry)
         return (h, aux), h  # the next pass reads this pass's normed output
 
     with jax.named_scope("looped_stack"):
@@ -504,7 +724,7 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
         ) + gate["b"].astype(jnp.float32)
         return LoopedOutputs(
             exits @ params["head"], gates[..., 0], cfg.exit_entropy_weight
-        ), aux
+        ), aux, {}
 
 
 def build_loss_fn(cfg: TransformerConfig, mesh: Mesh):

@@ -31,7 +31,7 @@ from elasticdl_tpu.models.transformer_lm import (
     exit_stats,
     init_params,
     looped_exit_loss,
-    plain_forward,
+    plain_forward_stats,
     token_cross_entropy,
 )
 
@@ -46,15 +46,27 @@ class TransformerLM:
     def init(self, rng, tokens):
         seed = int(np.asarray(jax.random.key_data(rng)).ravel()[-1]) & 0x7FFFFFFF
         params = init_params(np.random.default_rng(seed), self.cfg)
-        if not self.cfg.looped:
-            return {"params": params}
-        return {
-            "params": params,
-            WINDOW_STATS: {
-                "exit_q": np.zeros((self.cfg.n_loops,), np.float32),
-                "expected_exit": np.zeros((), np.float32),
-            },
-        }
+        if self.cfg.looped:
+            return {
+                "params": params,
+                WINDOW_STATS: {
+                    "exit_q": np.zeros((self.cfg.n_loops,), np.float32),
+                    "expected_exit": np.zeros((), np.float32),
+                },
+            }
+        if self.cfg.moe_top_k:
+            layers = self.cfg.n_layers - self.cfg.n_dense_layers
+            return {
+                "params": params,
+                WINDOW_STATS: {
+                    "expert_tokens": np.zeros(
+                        (layers, self.cfg.held[1]), np.float32
+                    ),
+                    "held_share": np.zeros((), np.float32),
+                    "router_entropy": np.zeros((), np.float32),
+                },
+            }
+        return {"params": params}
 
     def apply(self, variables, tokens, mutable=None):
         # the vectorized scan-over-layers fast path for dense AND MoE
@@ -65,7 +77,9 @@ class TransformerLM:
         # runs (ADVICE r4) — `loss`/`eval_metrics_fn` below unpack the
         # pair, mirroring the mesh path's build_loss_fn
         # (transformer_lm.py:243-253).
-        logits, aux = plain_forward(self.cfg, variables["params"], tokens)
+        logits, aux, stats = plain_forward_stats(
+            self.cfg, variables["params"], tokens
+        )
         if self.cfg.looped:
             # all T exits and gates go to loss(); the step's mean exit
             # distribution is the new state of the non-trainable
@@ -73,6 +87,10 @@ class TransformerLM:
             if mutable:
                 return logits, {WINDOW_STATS: exit_stats(logits.gates)}
             return logits
+        if self.cfg.moe_top_k and mutable:
+            # what the routers did with this batch, as the looped LM
+            # leaves its exit distribution
+            return (logits, self.cfg.aux_weight * aux), {WINDOW_STATS: stats}
         if self.cfg.n_experts:
             return logits, self.cfg.aux_weight * aux
         return logits

@@ -46,10 +46,14 @@ BLOCK = 128  # q/k block edge: MXU-native tile
 _NEG_INF = -1e30
 
 
-def reference_attention(q, k, v, causal: bool = True):
-    """Plain-XLA causal attention, [B, L, H, D] -> [B, L, H, D]."""
+def reference_attention(q, k, v, causal: bool = True, scale=None):
+    """Plain-XLA causal attention, [B, L, H, D] -> [B, L, H, Dv]: `v`
+    may be of another width than `q` and `k` (latent attention: 192-wide
+    queries and keys, 128-wide values). `scale` multiplies the scores
+    where 1/sqrt(D) is not the model's (YaRN's softmax scale)."""
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    s = s / math.sqrt(d) if scale is None else s * scale
     if causal:
         L = q.shape[1]
         mask = jnp.tril(jnp.ones((L, L), dtype=bool))
@@ -398,7 +402,7 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0):
 FLASH_SCORE_BYTES = 6e9
 
 
-def attention(q, k, v, causal: bool = True):
+def attention(q, k, v, causal: bool = True, scale=None):
     """Dispatcher, the single entry point for model code.
 
     On TPU the Pallas kernels engage automatically when the estimated
@@ -406,15 +410,24 @@ def attention(q, k, v, causal: bool = True):
     (see FLASH_SCORE_BYTES); otherwise XLA's fused attention runs.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
     EDL_TPU_FLASH=0 forces them off. Numerics are identical either way
-    (tests/test_flash_attention.py)."""
+    (tests/test_flash_attention.py). The kernels know one head width
+    and the scale 1/sqrt(D): values of another width than the queries
+    (latent attention) or a `scale` of the caller's never reach them,
+    whatever the flag says."""
     import os
 
     from elasticdl_tpu.common.constants import ENV_TPU_FLASH
 
     b, L, h, _d = q.shape
     flag = os.environ.get(ENV_TPU_FLASH)
-    if jax.default_backend() == "tpu" and L % BLOCK == 0 and flag != "0":
+    kernel_shapes = scale is None and v.shape[-1] == q.shape[-1]
+    if (
+        kernel_shapes
+        and jax.default_backend() == "tpu"
+        and L % BLOCK == 0
+        and flag != "0"
+    ):
         score_bytes = 2.5 * b * h * L * L * 2  # bf16 probs, fwd+bwd copies
         if flag == "1" or score_bytes > FLASH_SCORE_BYTES:
             return flash_attention(q, k, v, causal)
-    return reference_attention(q, k, v, causal)
+    return reference_attention(q, k, v, causal, scale)

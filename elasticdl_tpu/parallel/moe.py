@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from elasticdl_tpu.parallel.tp_layers import swiglu
+
 
 def _route(
     x: jnp.ndarray, router_w: jnp.ndarray, num_experts: int, capacity: int
@@ -115,3 +117,178 @@ def moe_ffn(
     ye = ye.reshape(num_experts, capacity, d)
     out = jnp.einsum("tec,ecd->td", combine, ye)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Top-k, dropless, gated experts, on the share of the experts held here
+
+
+def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int):
+    """softmax over all of the router's outputs in float32, then the
+    `top_k` largest, greedy: x [T, d], router_w [d, E] ->
+    (probs [T, E] f32, gate [T, k] f32, chosen [T, k] int32).
+
+    Float32 whatever x is, with a float32 product (`HIGHEST`: the TPU's
+    default passes a float32 matmul through bfloat16 once): which
+    experts a token takes is decided by the order of its
+    probabilities, and two of 64 lie closer than bfloat16 tells apart
+    for a token in every few. The gates are the chosen probabilities as
+    they are (not renormalised over the k). Equal probabilities go to
+    the lower expert first (`lax.top_k` is stable)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, chosen = lax.top_k(probs, top_k)
+    return probs, gate, chosen.astype(jnp.int32)
+
+
+def sequence_balance_loss(probs: jnp.ndarray, chosen: jnp.ndarray):
+    """The sequence-wise balance term, before its weight: per sequence
+    sum_e f_e P_e with f_e = E / (k s) x #{tokens of the sequence that
+    chose e} and P_e the sequence's mean probability of e, then the
+    mean over sequences. probs [B, S, E] f32, chosen [B, S, k]. The
+    counts carry no gradient; over all E experts, held here or not."""
+    _b, s, e = probs.shape
+    k = chosen.shape[-1]
+    counts = jnp.sum(
+        jax.nn.one_hot(chosen, e, dtype=jnp.float32), axis=(1, 2)
+    )  # [B, E]
+    f = lax.stop_gradient(counts) * (e / (k * s))
+    return jnp.mean(jnp.sum(f * jnp.mean(probs, axis=1), axis=-1))
+
+
+def _take_sorted(sorted_rows: jnp.ndarray, pos: jnp.ndarray):
+    """sorted_rows[pos], zeros where pos lies behind the buffer."""
+    rows = sorted_rows.shape[0]
+    taken = sorted_rows[jnp.minimum(pos, rows - 1)]
+    return jnp.where((pos < rows)[:, None], taken, 0)
+
+
+# The two moves between token order and expert order. `order[r]` is the
+# assignment (token x k + choice) that sorted row r holds and `pos` is
+# its inverse, so each move is a gather and its transpose is the OTHER
+# move, again a gather: differentiated as they stand, both would
+# transpose into scatter-adds of T k rows of d, which the TPU walks row
+# by row.
+
+
+@jax.custom_vjp
+def _dispatch(xf, order, pos):
+    """Token rows to expert order: xf [T, d] -> [R, d], R = len(order)."""
+    return xf[order // (pos.shape[0] // xf.shape[0])]
+
+
+def _dispatch_fwd(xf, order, pos):
+    return _dispatch(xf, order, pos), (order, pos, xf.shape[0])
+
+
+def _dispatch_bwd(saved, g):
+    _order, pos, t = saved
+    return _take_sorted(g, pos).reshape(t, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect(sorted_rows, order, pos):
+    """Expert order back to assignments: [R, d] -> [T k, d], zeros for
+    an assignment whose row lies behind the buffer."""
+    return _take_sorted(sorted_rows, pos)
+
+
+def _collect_fwd(sorted_rows, order, pos):
+    return _take_sorted(sorted_rows, pos), order
+
+
+def _collect_bwd(order, g):
+    return g[order], None, None
+
+
+_collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+def moe_topk_held(
+    x: jnp.ndarray,
+    router_w: jnp.ndarray,
+    experts: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+    shared: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+    *,
+    top_k: int,
+    held: Tuple[int, int],
+    scaling: float = 1.0,
+):
+    """A top-k dropless expert layer that is told which experts it
+    holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x).
+
+    x [B, S, d]; router_w [d, E] over ALL E experts; `experts` the
+    stacked SwiGLU weights (wg [n, d, f], wu [n, d, f], wd [n, f, d])
+    of the n experts `held = (first, n)` names, experts first ..
+    first + n - 1 of E; `shared` one SwiGLU (the shared experts side by
+    side). -> (y [B, S, d], the sequence-wise balance term (unweighted,
+    f32), stats of the routing: `expert_tokens` [n] (counts, float32),
+    `held_share`, `router_entropy`).
+
+    This is one chip's part of an expert-parallel layer, computed
+    without the exchange: what the experts held elsewhere would add is
+    left out (the guide's section 4), and nothing here stands in for
+    them. No capacity and no dropped token. The T k assignments are
+    sorted by expert, those to experts not held behind the last held
+    group, and the held groups go through `lax.ragged_dot` (on the TPU
+    a grouped matmul that walks whole tiles of rows and skips what lies
+    behind the groups). The sorted buffer has T x min(k, n) rows: a
+    token takes k DIFFERENT experts, so at most min(k, n) of its
+    assignments can be held here, and a router that sends every token
+    to held experts fills every row. Anything shorter drops under
+    skew. At uniform routing n / E of the rows are used."""
+    b, s, d = x.shape
+    t = b * s
+    first, n = held
+    wg, wu, wd = experts
+    xf = x.reshape(t, d)
+    with jax.named_scope("route"):
+        probs, gate, chosen = route_topk(xf, router_w, top_k)
+        local = chosen - first
+        here = (local >= 0) & (local < n)
+        # a stable sort on (held group, else n) keeps token order
+        # inside a group and puts every absent assignment last
+        group = jnp.where(here, local, n).reshape(t * top_k)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32)  # its inverse
+        order = order[: t * min(top_k, n)]
+        sizes = jnp.sum(
+            jax.nn.one_hot(group, n + 1, dtype=jnp.int32), axis=0
+        )[:n]  # [n] rows of each held expert
+        used = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+        # rows behind the groups are zeros going in and coming out:
+        # what a grouped matmul leaves there is not specified
+        rows = jnp.where(used, _dispatch(xf, order, pos), 0)
+    with jax.named_scope("experts"):
+        hidden = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) * lax.ragged_dot(
+            rows, wu, sizes
+        )
+        out = lax.ragged_dot(hidden, wd, sizes)
+    with jax.named_scope("route"):
+        picked = _collect(jnp.where(used, out, 0), order, pos)
+        weight = jnp.where(here, gate * scaling, 0.0)  # [T, k] f32
+        routed = jnp.sum(
+            weight[:, :, None]
+            * picked.reshape(t, top_k, d).astype(jnp.float32),
+            axis=1,
+        ).astype(x.dtype)
+        balance = sequence_balance_loss(
+            probs.reshape(b, s, -1), chosen.reshape(b, s, top_k)
+        )
+        entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1)
+        stats = {
+            "expert_tokens": sizes.astype(jnp.float32),
+            "held_share": jnp.sum(sizes) / jnp.float32(t * top_k),
+            "router_entropy": jnp.mean(entropy),
+        }
+    with jax.named_scope("shared"):
+        y = routed + swiglu(xf, *shared)
+    return y.reshape(b, s, d), balance, jax.tree_util.tree_map(
+        lax.stop_gradient, stats
+    )

@@ -18,6 +18,7 @@ all-reduce on the backward pass.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -40,3 +41,9 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     """RMSNorm over the feature dim (replicated weight)."""
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * lax.rsqrt(var + eps) * weight
+
+
+def swiglu(x: jnp.ndarray, wg: jnp.ndarray, wu: jnp.ndarray, wd: jnp.ndarray) -> jnp.ndarray:
+    """The gated MLP wd(silu(wg x) * wu x), no bias: a dense layer's, a
+    looped layer's and a shared expert's alike."""
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd

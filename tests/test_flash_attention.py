@@ -118,3 +118,43 @@ def test_dispatcher_falls_back_off_tpu():
     out = attention(q, k, v)
     ref = reference_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+@pytest.mark.parametrize("forced", ["1", None], ids=["flash-forced", "auto"])
+@pytest.mark.parametrize("case", ["wider-keys", "own-scale"])
+def test_values_of_another_width_or_a_scale_never_reach_the_kernels(
+    monkeypatch, forced, case
+):
+    """Latent attention: 192-wide queries and keys beside 128-wide
+    values, and a softmax scale of the model's own. `attention` and
+    `reference_attention` take both; the Pallas kernels know one width
+    and 1/sqrt(D), so such shapes stay on XLA's path even on a TPU with
+    the kernels forced on. Same-width calls without a scale go where
+    they went."""
+    import math
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    rng = np.random.default_rng(0)
+    b, L, h, dk, dv = 1, 128, 2, 48, 32
+    q = jnp.asarray(rng.standard_normal((b, L, h, dk)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, L, h, dk)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, L, h, dv if case == "wider-keys" else dk)), jnp.float32)
+    scale = 0.5 * dk**-0.5 if case == "own-scale" else None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if forced:
+        monkeypatch.setenv("EDL_TPU_FLASH", forced)
+    called = []
+    monkeypatch.setattr(
+        fa, "flash_attention", lambda *a, **kw: called.append(a) or a[2]
+    )
+    out = fa.attention(q, k, v, causal=True, scale=scale)
+    assert not called
+    assert out.shape == (b, L, h, v.shape[-1])
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (scale or 1 / math.sqrt(dk))
+    s = jnp.where(jnp.tril(jnp.ones((L, L), bool))[None, None], s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+    if forced:  # the same call with one width and no scale still takes them
+        fa.attention(q, k, k, causal=True)
+        assert len(called) == 1
