@@ -117,8 +117,11 @@ def worker_logs(log_dir):
     "done": [time of each completed task]}} parsed from the worker logs."""
     out = {}
     for name in sorted(os.listdir(log_dir)) if os.path.isdir(log_dir) else ():
+        named = re.fullmatch(r"worker-(\d+)\.log", name)
+        if named is None:  # a worker's span file lies beside its log
+            continue
         text = _read(os.path.join(log_dir, name))
-        wid = int(re.search(r"worker-(\d+)\.log", name).group(1))
+        wid = int(named.group(1))
         boot = _BOOT.search(text)
         out[wid] = {
             "boot": boot and {

@@ -23,9 +23,12 @@ descriptors and appends the raw array bytes out-of-band as buffer
 views of the contiguous source arrays (`dumps_parts`). The only
 full-size copy left is the `b"".join` of `dumps`, and it is the
 one-buffer carriers' alone: gRPC, `inproc` and files need a single
-buffer; the Unix-socket carrier writes a request's parts to the socket
-as they lie (`messages.PackedParts`, `rpc/transport.py`; see
-docs/architecture.md, "Wire plane").
+buffer; the Unix-socket carrier writes a frame's parts to the socket
+as they lie, request and response alike (`messages.PackedParts`,
+`rpc/transport.py`; see docs/architecture.md, "Wire plane"). A model
+on its way down is not even raveled first: a `LeafVector` enters the
+frame as the vector its leaves would concatenate to, from where they
+lie.
 
     offset  size  field
     0       1     0xC1 frame magic (a reserved, never-emitted msgpack
@@ -493,15 +496,16 @@ class _FrameBuilder:
     __slots__ = ("segments", "offset")
 
     def __init__(self):
-        # [(pad_before, uint8-view)] in payload order
+        # [(pad_before, uint8-views)] in payload order: a segment is
+        # one view, or the consecutive views a `LeafVector` lies in
         self.segments: list = []
         self.offset = 0
 
-    def add(self, seg: np.ndarray) -> int:
+    def add(self, views, nbytes: int) -> int:
         pad = (-self.offset) % _SEGMENT_ALIGN
         off = self.offset + pad
-        self.segments.append((pad, seg))
-        self.offset = off + seg.nbytes
+        self.segments.append((pad, views))
+        self.offset = off + nbytes
         return off
 
 
@@ -517,7 +521,7 @@ def _frame_descriptor(a: np.ndarray, builder: _FrameBuilder) -> dict:
         _encode_copies.arrays += 1
         a = np.ascontiguousarray(a)
     seg = a.reshape(-1).view(np.uint8)
-    off = builder.add(seg)
+    off = builder.add((seg,), seg.nbytes)
     return {"d": _dtype_to_str(a.dtype), "s": shape, "o": off, "n": seg.nbytes}
 
 
@@ -548,6 +552,12 @@ def _build_frame_tree(obj: Any, builder: _FrameBuilder) -> Any:
         }
     if isinstance(obj, np.ndarray):
         return {_ND_KEY: True, **_frame_descriptor(obj, builder)}
+    if isinstance(obj, LeafVector):
+        # the entry a float32 ndarray of this length gets, over one
+        # segment made of the leaves where they lie
+        nbytes = obj.size * 4
+        off = builder.add([p.view(np.uint8) for p in obj.pieces], nbytes)
+        return {_ND_KEY: True, "d": "<f4", "s": [obj.size], "o": off, "n": nbytes}
     if isinstance(obj, dict):
         return {k: _build_frame_tree(v, builder) for k, v in obj.items()}
     if isinstance(obj, list):
@@ -641,6 +651,36 @@ def ravel_np(tree) -> np.ndarray:
     )
 
 
+class LeafVector:
+    """One float32 vector that lies in several arrays: what `ravel_np`
+    of a tree would concatenate, without concatenating. `pieces` are
+    flat C-contiguous float32 arrays, in order; whoever builds one
+    answers for their staying as they are until the frame has left (a
+    read-only leaf that is replaced and never written, or a copy of its
+    own). In a v2 frame it is the vector: the header entry an ndarray
+    of `size` gets, over one segment whose parts are the pieces' bytes,
+    so `loads` on the other side sees the `ravel_np` vector and the
+    frame is the same byte for byte. A caller that is handed one
+    directly, with no wire between, reads it with `np.asarray`."""
+
+    __slots__ = ("pieces", "size")
+
+    def __init__(self, pieces):
+        self.pieces = list(pieces)
+        for p in self.pieces:
+            if p.dtype != np.float32 or p.ndim != 1 or not p.flags.c_contiguous:
+                raise TypeError("a LeafVector piece is a flat float32 array")
+        self.size = sum(p.size for p in self.pieces)
+
+    def __array__(self, dtype=None, copy=None):
+        vec = (
+            np.concatenate(self.pieces)
+            if self.pieces
+            else np.zeros(0, np.float32)
+        )
+        return vec if dtype is None else vec.astype(dtype, copy=False)
+
+
 def template_meta(template) -> tuple:
     """(shapes, sizes, treedef) of a pytree — the unravel plan. One
     `np.asarray` per leaf; callers on hot paths cache the result via
@@ -708,12 +748,11 @@ def dumps_parts(obj: Any):
     if head_pad:
         parts.append(b"\x00" * head_pad)
     total = _FRAME_PREFIX.size + len(header) + head_pad
-    for pad, seg in builder.segments:
+    for pad, views in builder.segments:
         if pad:
             parts.append(b"\x00" * pad)
-        parts.append(seg)
-        total += pad + seg.nbytes
-    return parts, total
+        parts.extend(views)
+    return parts, total + builder.offset
 
 
 def dumps(obj: Any) -> bytes:

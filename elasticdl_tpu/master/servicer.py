@@ -42,6 +42,7 @@ from elasticdl_tpu.master.embedding_store import EmbeddingStore
 from elasticdl_tpu.master.ps_optimizer import PSOptimizer
 from elasticdl_tpu.master.sparse_optimizer import SparseOptimizer
 from elasticdl_tpu.obs import trace as obs_trace
+from elasticdl_tpu.rpc.transport import FrameMemory
 
 logger = get_logger(__name__)
 
@@ -144,10 +145,14 @@ class MasterServicer:
         # to lock held), `grad_decode` (the update's wire form to an f32
         # tree, validated), `apply` (delta add or PSOptimizer step;
         # `kind: accumulate` where a report only joined the sum),
-        # `model_encode` (the model raveled for the way down), and the
-        # dispatcher's `rpc.decode` / `rpc.encode`. All are timed under
-        # the model lock and recorded after it is released.
+        # `model_encode` (the model laid out for the way down: see
+        # `_flat_model`), and the dispatcher's `rpc.decode` /
+        # `rpc.encode`. All are timed under the model lock and recorded
+        # after it is released.
         self.timers = PhaseTimers(sink=obs_trace.record_phase)
+        # where `_flat_model` copies the leaves it may not send by view:
+        # one buffer, lent to a response and back when its frame has left
+        self._model_memory = FrameMemory()
         # Sparse applies serialize among THEMSELVES (read-modify-write
         # per id) but run OUTSIDE self._lock: with a KV-shard-backed
         # store every apply is several RPC fan-outs, and holding the
@@ -551,6 +556,7 @@ class MasterServicer:
             }
         if method == MethodType.MINIMUM:
             t_enter = time.time()
+            sent = {}
             with self._lock:
                 t_locked = time.time()
                 if self._params is None:
@@ -561,10 +567,11 @@ class MasterServicer:
                     # already holds this version.
                     resp = {"version": self._version, "params": None, "aux": None}
                 elif req.get("flat"):
-                    # single-buffer transport (see codec.ravel_np)
+                    # one vector on the wire (see codec.ravel_np)
+                    vec, sent = self._flat_model()
                     resp = {
                         "version": self._version,
-                        "params_flat": codec.ravel_np(self._params),
+                        "params_flat": vec,
                         "aux": jax.tree_util.tree_map(np.copy, self._aux),
                     }
                 else:
@@ -583,7 +590,7 @@ class MasterServicer:
             )
             self.timers.record(
                 "model_encode", t_locked, t_done, kind="get_model",
-                version=version,
+                version=version, **sent,
             )
             return resp
         # FIXED: serve the exact version — from live PS state when it
@@ -666,7 +673,7 @@ class MasterServicer:
                 # worker's retry needs no separate pull round-trip
                 resp = {"accepted": False, "version": self._version}
                 if req.get("return_model"):
-                    resp["params_flat"] = self._flat_model(
+                    resp["params_flat"], _ = self._flat_model(
                         req.get("model_dtype")
                     )
                     resp["aux"] = jax.tree_util.tree_map(np.copy, self._aux)
@@ -725,15 +732,19 @@ class MasterServicer:
                     sparse_to_apply = merged
             resp = {"accepted": True, "version": self._version}
             t_applied = time.time()
+            sent = None
             if req.get("return_model") and self._version != report_version:
                 # a step was applied (by this report or a concurrent
                 # one): hand back the new model inline — the sync-SGD
                 # inner loop becomes ONE rpc per minibatch
-                resp["params_flat"] = self._flat_model(req.get("model_dtype"))
+                resp["params_flat"], sent = self._flat_model(
+                    req.get("model_dtype")
+                )
                 resp["aux"] = jax.tree_util.tree_map(np.copy, self._aux)
             marks = (
                 "gradient" if applied else "accumulate",
-                t_enter, t_locked, t_decoded, t_applied, time.time(), resp,
+                t_enter, t_locked, t_decoded, t_applied, time.time(),
+                resp["version"], sent,
             )
             if applied:
                 # snapshot the exact applied version UNDER the lock so a
@@ -801,7 +812,7 @@ class MasterServicer:
                 self._duplicate_local_updates += 1
                 return {
                     "version": self._version,
-                    "params_flat": self._flat_model(req.get("model_dtype")),
+                    "params_flat": self._flat_model(req.get("model_dtype"))[0],
                     "aux": jax.tree_util.tree_map(np.copy, self._aux),
                     "duplicate": True,
                 }
@@ -853,13 +864,16 @@ class MasterServicer:
                     self._seen_local_updates.popitem(last=False)
             resp = {"version": self._version}
             t_applied = time.time()
+            sent = None
             # base fell behind (concurrent syncs): return the merged model
             if base_version + steps != self._version or req.get("want_model"):
-                resp["params_flat"] = self._flat_model(req.get("model_dtype"))
+                resp["params_flat"], sent = self._flat_model(
+                    req.get("model_dtype")
+                )
                 resp["aux"] = jax.tree_util.tree_map(np.copy, self._aux)
             marks = (
                 "local_update", t_apply, t_locked, t_decoded, t_applied,
-                time.time(), resp,
+                time.time(), resp["version"], sent,
             )
         self._record_update(*marks, in_place=in_place, leaves=leaves)
         # lock wait + apply, retro-recorded under the server span (the
@@ -1063,17 +1077,17 @@ class MasterServicer:
         return resp
 
     def _record_update(
-        self, kind, t_enter, t_locked, t_decoded, t_applied, t_encoded, resp,
-        **apply_args
+        self, kind, t_enter, t_locked, t_decoded, t_applied, t_encoded,
+        version, sent, **apply_args
     ):
         """One update's phases from the marks taken under the model
         lock, recorded once it is released: the wait for the lock, the
         update decoded, the apply (`kind: accumulate` for a gradient
         that only joined the sum: no step was taken; of a local update,
         `apply_args` say how many `leaves` the model has and into how
-        many the delta was added `in_place`), and the model raveled for
-        the way down where one goes."""
-        version = resp.get("version")
+        many the delta was added `in_place`), and the model laid out
+        for the way down where one goes (`sent`: what `_flat_model`
+        says of how)."""
         record = self.timers.record
         record("apply_wait", t_enter, t_locked, kind=kind, version=version)
         record("grad_decode", t_locked, t_decoded, kind=kind, version=version)
@@ -1081,10 +1095,10 @@ class MasterServicer:
             "apply", t_decoded, t_applied, kind=kind, version=version,
             **apply_args,
         )
-        if resp.get("params_flat") is not None:
+        if sent is not None:
             record(
                 "model_encode", t_applied, t_encoded, kind=kind,
-                version=version,
+                version=version, **sent,
             )
 
     def _unravel_model(self, vec):  # edl-lint: disable=lock-discipline -- template read only: the param STRUCTURE is fixed for the life of a job (values are irrelevant to the unravel plan), and report callers already hold the non-reentrant self._lock
@@ -1101,13 +1115,56 @@ class MasterServicer:
             return u(vec)
 
     def _flat_model(self, model_dtype=None):  # edl-lint: disable=lock-discipline -- caller holds self._lock
-        """Raveled params, optionally narrowed to the worker's wire
-        dtype (bf16 halves the piggyback bytes; the worker re-widens —
-        standard mixed-precision weight transport)."""
-        vec = codec.ravel_np(self._params)
+        """The model as the one float32 vector that goes down to a
+        worker (`ravel_np`'s, tree_flatten order), never concatenated:
+        a `codec.LeafVector` over the leaves, which the frame is built
+        from and the socket gathers from where they lie. And how each
+        leaf got there, which `model_encode` reports. What decides is
+        what can be seen of the leaf:
+
+        - read-only float32 (`PSOptimizer.step`'s: the next step
+          replaces it and nothing ever writes it) goes `by_view`; the
+          view keeps it alive, and the send, after the lock is
+          released, cannot be torn;
+        - any other (`_add_delta` writes it in place; not float32) is
+          `copied` here, under the lock, into memory this servicer
+          keeps and lends (`lent`: the copy went into pages an earlier
+          response lay in): back when the response's last view dies,
+          so a second response in flight gets memory of its own.
+
+        A `model_dtype` other than float32 narrows the raveled vector
+        (bf16 halves the piggyback bytes; the worker re-widens): a copy
+        by nature."""
+        leaves = jax.tree_util.tree_leaves(self._params)
         if model_dtype and model_dtype != "float32":
-            vec = vec.astype(codec.dtype_from_str(model_dtype))
-        return vec
+            vec = codec.ravel_np(self._params).astype(
+                codec.dtype_from_str(model_dtype)
+            )
+            return vec, {"by_view": 0, "copied": len(leaves), "lent": False}
+        stays = [
+            isinstance(a, np.ndarray)
+            and a.dtype == np.float32
+            and a.flags.c_contiguous
+            and not a.flags.writeable
+            for a in leaves
+        ]
+        to_copy = sum(np.size(a) for a, stay in zip(leaves, stays) if not stay)
+        view, _, lent = self._model_memory.lend(4 * to_copy)
+        room = np.frombuffer(view, dtype=np.float32)
+        pieces, at = [], 0
+        for a, stay in zip(leaves, stays):
+            if stay:
+                pieces.append(a.reshape(-1))
+                continue
+            a = np.asarray(a)
+            piece = room[at:at + a.size]
+            np.copyto(piece, a.reshape(-1), casting="unsafe")
+            pieces.append(piece)
+            at += a.size
+        by_view = sum(stays)
+        return codec.LeafVector(pieces), {
+            "by_view": by_view, "copied": len(leaves) - by_view, "lent": lent,
+        }
 
     def _apply_sparse(self, edl_grads):  # edl-lint: disable=lock-discipline -- ride-through deliberately blocks: no sparse apply can proceed mid-recovery
         """Apply IndexedRows to the (possibly RPC-backed) store —

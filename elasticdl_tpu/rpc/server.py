@@ -36,9 +36,10 @@ def _grpc_adapter(dispatcher, method: str) -> Callable:
 
     def handler(request_bytes: bytes, context) -> bytes:
         try:
+            # gRPC sends one buffer
             return dispatcher.dispatch(
                 method, request_bytes, transport_mod.TRANSPORT_GRPC
-            )
+            ).contiguous()
         except PolicyRpcError as e:
             # abort() raises — nothing after it runs
             context.abort(e.code(), e.details())

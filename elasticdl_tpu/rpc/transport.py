@@ -9,14 +9,14 @@ override (and the test handle):
 
 - **uds** — a Unix-domain-socket byte protocol carrying codec frames
   with a minimal length-prefixed header, skipping gRPC/HTTP-2 framing
-  entirely. A request arrives here as the parts the codec made
-  (`messages.PackedParts`: prefix, header, pads, views of the source
-  arrays) and they are written to the socket in order, never joined
-  into a buffer of their own (`_send_parts`); the receiver hands the
-  codec one contiguous buffer to build `np.frombuffer` views over — the
-  zero-copy contract of codec v2 holds end to end. The bytes on the
-  socket are the frame `codec.dumps` would have made: the receiving
-  side cannot tell.
+  entirely. A frame, request or response, arrives here as the parts
+  the codec made (`messages.PackedParts`: prefix, header, pads, views
+  of the source arrays) and they are written to the socket in order,
+  never joined into a buffer of their own (`_send_parts`); the
+  receiver hands the codec one contiguous buffer to build
+  `np.frombuffer` views over — the zero-copy contract of codec v2
+  holds end to end. The bytes on the socket are the frame
+  `codec.dumps` would have made: the receiving side cannot tell.
 - **inproc** — when the serving `RpcServer` lives in the SAME
   interpreter (bench/test mode, `PSShardGroup` inproc shards), the call
   dispatches directly into the server's handler table: the frame, which
@@ -24,10 +24,13 @@ override (and the test handle):
   one buffer), is passed by reference, no socket at all. WireStats
   records these calls with zero wire bytes under the "inproc" tier.
 
-The join (`PackedParts.contiguous()`, at most once a request however
+The join (`PackedParts.contiguous()`, at most once a frame however
 many attempts send it) is the one-buffer carriers' alone: `inproc`,
-and gRPC, which `RpcClient` also hands any call whose socket cannot
-connect.
+gRPC, which `RpcClient` also hands any call whose socket cannot
+connect, and for a response `AsyncUdsServer` (`EDL_DISPATCH=loop`),
+which copies on the way in too. `ServerDispatcher` packs a response
+as its parts and joins it, inside its `rpc.encode` span, only for
+those.
 
 Every tier runs the identical server-side core, `ServerDispatcher`:
 chaos faults (rpc/chaos.py, via `transport_faults_before/after` — the
@@ -58,7 +61,7 @@ the dispatcher / `messages.unpack` a read-only view of it, over which
 the codec builds its `np.frombuffer` views. A frame's memory lives as
 long as an array decoded from it (the master keeps such views past the
 handler: `grads_to_wait` > 1, fan-in). A connection keeps the memory
-its last large frame lay in (`_FrameMemory`) and receives the next one
+its last large frame lay in (`FrameMemory`) and receives the next one
 there, but only once nothing reads the old frame any more: the memory
 comes back to the connection when the last view of the frame dies, so
 whoever keeps a view keeps the memory and the connection's next frame
@@ -265,10 +268,13 @@ class ServerDispatcher:
 
     def dispatch(
         self, method: str, request_bytes, transport: str, recv_reused=False
-    ) -> bytes:
-        """`recv_reused`: the carrier received the request into memory
-        an earlier frame of its connection lay in (the `rpc.decode`
-        span of a timed method says so; the loop core does not)."""
+    ) -> messages.PackedParts:
+        """The response, packed as its parts: the socket carrier sends
+        them as they lie, a carrier that needs one buffer asks
+        `contiguous()`. `recv_reused`: the carrier received the request
+        into memory an earlier frame of its connection lay in (the
+        `rpc.decode` span of a timed method says so; the loop core does
+        not)."""
         if self._core is not None:
             if transport == TRANSPORT_INPROC:
                 # direct scheduling: there is no socket to multiplex, so
@@ -293,17 +299,17 @@ class ServerDispatcher:
         after = []
         if transport != TRANSPORT_GRPC:
             after = transport_faults_before(self._plan, method, "server")
-        resp_bytes = self._invoke(
+        payload = self._invoke(
             method, request_bytes, transport, recv_reused=recv_reused
         )
         # drop/crash-after fire with the handler APPLIED (same contract
         # as the server interceptor: state changed, response withheld)
         transport_faults_after(after, method)
-        return resp_bytes
+        return payload
 
     async def dispatch_async(
         self, method: str, request_bytes, transport: str
-    ) -> bytes:
+    ) -> messages.PackedParts:
         """Loop-mode dispatch: admission on the loop, then the blocking
         half (chaos hooks + legacy sync handler) bridged through the
         bounded executor — handler work and chaos latency sleeps never
@@ -324,18 +330,18 @@ class ServerDispatcher:
 
     def _dispatch_blocking(
         self, method: str, request_bytes, transport: str, t_admit=None
-    ) -> bytes:
+    ) -> messages.PackedParts:
         after = []
         if transport != TRANSPORT_GRPC:
             after = transport_faults_before(self._plan, method, "server")
-        resp_bytes = self._invoke(method, request_bytes, transport, t_admit)
+        payload = self._invoke(method, request_bytes, transport, t_admit)
         transport_faults_after(after, method)
-        return resp_bytes
+        return payload
 
     def _invoke(
         self, method: str, request_bytes, transport: str, t_admit=None,
         recv_reused=False,
-    ) -> bytes:
+    ) -> messages.PackedParts:
         from elasticdl_tpu.rpc.fencing import EpochFencedError
 
         fn = self._handlers.get(method)
@@ -408,10 +414,17 @@ class ServerDispatcher:
             if sp is not None:
                 obs_trace.bind(prev_ctx)
                 sp.end()
+        t_encode = time.time() if timed else 0.0
+        # packed, not joined: the blocking socket listener gathers the
+        # parts to the socket from where they lie (a model on its way
+        # down: from the leaves it lies in). Every other carrier needs
+        # one buffer, and gets it here, inside the span that times the
+        # packing, once
+        payload = messages.pack_parts(resp)
+        if transport != TRANSPORT_UDS or self._core is not None:
+            payload.contiguous()
         if timed:
             version = resp.get("version") if isinstance(resp, dict) else None
-            t_encode = time.time()
-            resp_bytes = messages.pack(resp)
             record = self._timers.record
             record(
                 "rpc.decode", t_decode, t_decoded, method=method,
@@ -419,17 +432,16 @@ class ServerDispatcher:
             )
             record(
                 "rpc.encode", t_encode, time.time(), method=method,
-                bytes=len(resp_bytes), version=version,
+                bytes=len(payload), version=version,
+                parts=len(payload.parts), joined=payload.joined,
             )
-        else:
-            resp_bytes = messages.pack(resp)
         self._wire.record(
             method,
-            sent=0 if inproc else len(resp_bytes),
+            sent=0 if inproc else len(payload),
             transport=transport,
             calls=1,
         )
-        return resp_bytes
+        return payload
 
 
 # --------------------------------------------------------------------------
@@ -477,10 +489,10 @@ class InprocTransport:
                 grpc.StatusCode.UNAVAILABLE,
                 f"inproc server for port {self._port} is gone",
             )
-        # the dispatcher decodes from one buffer
+        # the dispatcher decodes from one buffer, and so does the caller
         resp = dispatcher.dispatch(
             method, payload.contiguous(), TRANSPORT_INPROC
-        )
+        ).contiguous()
         transport_faults_after(after, method)
         return resp
 
@@ -573,10 +585,11 @@ KEEP_FRAME_BYTES = 32 << 20
 _KEEP_GRANULE = 1 << 20
 
 
-class _FrameMemory:
-    """The memory one connection receives its large frames into: at
-    most one buffer, lent to a frame and back here when the last view
-    of that frame dies.
+class FrameMemory:
+    """The memory one connection receives its large frames into (and
+    the master copies a model it may not send by view into: see
+    `MasterServicer._flat_model`): at most one buffer, lent to a frame
+    and back here when the last view of that frame dies.
 
     What decides is what the program can observe, not a setting. Each
     frame is decoded over a lease, an array of its own over the
@@ -629,7 +642,7 @@ class _FrameMemory:
         self._spare.clear()
 
 
-def _recv_frame(conn: socket.socket, n: int, memory: _FrameMemory):
+def _recv_frame(conn: socket.socket, n: int, memory: FrameMemory):
     """Read a frame body of exactly n bytes with no copy beyond the
     kernel's, into the memory the connection lends (the pages of its
     last large frame, mapped already, when nobody reads that one any
@@ -666,7 +679,7 @@ def _ask_socket_buffers(sock: socket.socket):
 _IOV_MAX = 1024
 
 
-def _send_parts(conn: socket.socket, head: bytes, parts, deadline: float):
+def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
     """Write `head`, then a frame's parts in order, gathered by
     `sendmsg` from where they lie: no buffer the size of the frame,
     and a frame that fits the socket buffer is one system call, header
@@ -675,7 +688,8 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline: float):
     asked), so a long part leaves over many turns: what has left is
     dropped from the front and the rest gathered again, at most
     `_IOV_MAX` buffers a turn. `deadline` (monotonic)
-    is one budget over all the turns. The bytes on the socket are
+    is one budget over all the turns; with none the socket's own
+    timeout stands (a server's connection blocks). The bytes on the socket are
     `head + b"".join(parts)`. (One `sendall` a long part, short parts
     joined, read 12-28 ms more wire a 649 MB sync on the chip's host:
     PERF.md, PR 30.)"""
@@ -683,7 +697,8 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline: float):
     bufs += [memoryview(part) for part in parts if len(part)]
     i = 0
     while i < len(bufs):
-        conn.settimeout(max(0.001, deadline - time.monotonic()))
+        if deadline is not None:
+            conn.settimeout(max(0.001, deadline - time.monotonic()))
         sent = conn.sendmsg(bufs[i:i + _IOV_MAX])
         while sent:
             n = bufs[i].nbytes
@@ -802,7 +817,7 @@ class UdsServer:
                 conn.close()
                 return
             self._conns.add(conn)
-        memory = _FrameMemory()
+        memory = FrameMemory()
         try:
             _ask_socket_buffers(conn)
             while not self._is_closed():
@@ -812,13 +827,9 @@ class UdsServer:
                 mlen, blen = _REQ_HEADER.unpack(header)
                 method = _recv_exact(conn, mlen).decode("utf-8")
                 try:
-                    resp = self._serve_frame(conn, method, blen, memory)
-                    _refuse_oversize("response", method, len(resp))
+                    self._serve_frame(conn, method, blen, memory)
                 except grpc.RpcError as e:
                     conn.sendall(_error_frame(e))
-                    continue
-                conn.sendall(_RESP_OK.pack(0, len(resp)))
-                conn.sendall(resp)
         except (ConnectionError, OSError):
             pass  # client went away
         finally:
@@ -830,15 +841,24 @@ class UdsServer:
             except OSError:
                 pass
 
-    def _serve_frame(self, conn, method: str, blen: int, memory) -> bytes:
-        # a call of its own, so that nothing names the frame once it
-        # returns: the frame lives on only in what the handler kept of
-        # it, and a 649 MB request the handler only read has given its
-        # memory back before the connection's next
+    def _serve_frame(self, conn, method: str, blen: int, memory):
+        # a call of its own, so that nothing names the frame or the
+        # response once it returns: the frame lives on only in what
+        # the handler kept of it, and a 649 MB request the handler
+        # only read has given its memory back before the connection's
+        # next; the response's parts are views of what the handler
+        # answered with (a model's leaves, or the copy the master lent
+        # them), let go the moment they have left
         frame, reused = _recv_frame(conn, blen, memory)
-        return self._dispatcher.dispatch(
+        resp = self._dispatcher.dispatch(
             method, frame, TRANSPORT_UDS, recv_reused=reused
         )
+        # before a byte of the answer leaves, as when this returned the
+        # response: the caller may drop what it keeps on the answer,
+        # and the request's memory has to be back by then
+        del frame
+        _refuse_oversize("response", method, len(resp))
+        _send_parts(conn, _RESP_OK.pack(0, len(resp)), resp.parts)
 
     def close(self):
         with self._conns_lock:
@@ -913,10 +933,13 @@ class AsyncUdsServer:
                 method = (await reader.readexactly(mlen)).decode("utf-8")
                 body = await reader.readexactly(blen)
                 try:
-                    resp = await self._dispatcher.dispatch_async(
+                    payload = await self._dispatcher.dispatch_async(
                         method, body, TRANSPORT_UDS
                     )
-                    _refuse_oversize("response", method, len(resp))
+                    _refuse_oversize("response", method, len(payload))
+                    # one buffer, as on the way in (`readexactly`):
+                    # this listener copies both ways (ROADMAP D4b)
+                    resp = payload.contiguous()
                 except grpc.RpcError as e:
                     writer.write(_error_frame(e))
                     await writer.drain()
@@ -968,7 +991,7 @@ class _ClientConn(socket.socket):
 
     def __init__(self):
         super().__init__(socket.AF_UNIX, socket.SOCK_STREAM)
-        self.memory = _FrameMemory()
+        self.memory = FrameMemory()
         self.sndbuf = self.rcvbuf = 0
         self.large = False
 

@@ -354,7 +354,7 @@ def test_no_reader_sees_a_delta_half_added():
         try:
             while time.monotonic() < stop:
                 if flat:
-                    vec = _read(s, "get_model_flat")
+                    vec = np.asarray(_read(s, "get_model_flat"))
                 else:
                     vec = codec.ravel_np(s.get_params_copy()[0])
                 if vec.min() != vec.max():
@@ -380,3 +380,298 @@ def test_no_reader_sees_a_delta_half_added():
     assert version > 0
     for leaf in jax.tree_util.tree_leaves(model):
         np.testing.assert_array_equal(leaf, np.float32(version))
+
+
+# -- the model goes down as the leaves it lies in (PR 34) --------------------
+
+
+_MODEL_LEAVES = 161  # ResNet-50's count
+
+
+def _model(rng, elems=2000):
+    """161 leaves off the 64-byte grid: matrices, vectors, a scalar."""
+    tree = {
+        f"l{i:03d}": rng.standard_normal(
+            (elems + i % 7, 3) if i % 5 else (elems + i,)
+        ).astype(np.float32)
+        for i in range(_MODEL_LEAVES - 1)
+    }
+    tree["temp"] = np.asarray(rng.standard_normal(), dtype=np.float32)
+    return tree
+
+
+def _encode_spans(s):
+    spans = []
+
+    def sink(name, begin, dur, args, ctx=None):
+        if name == "model_encode":
+            spans.append(dict(args))
+
+    s.timers = PhaseTimers(sink=sink)
+    return spans
+
+
+def _pull(s, **req):
+    return s.get_model({"method": MethodType.MINIMUM, "flat": True, **req})
+
+
+def _model_bytes(s):
+    return 4 * _size(s.get_params_copy()[0])
+
+
+@pytest.fixture
+def lend_from_1mb(monkeypatch):
+    """`transport.KEEP_FRAME_BYTES` is the chip's host's (32 MiB); the
+    rule is the same from 1 MiB on, with a model a test can afford."""
+    from elasticdl_tpu.rpc import transport
+
+    monkeypatch.setattr(transport, "KEEP_FRAME_BYTES", 1 << 20)
+
+
+def _peak_while(fn):
+    """(what `fn` returned, the most numpy and Python held over what
+    they held before, in bytes, while it ran)."""
+    import tracemalloc
+
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        out = fn()
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    return out, peak - before
+
+
+@pytest.mark.parametrize(
+    "reader", ["get_model", "gradient_response", "stale_rejection"]
+)
+def test_read_only_leaves_go_down_by_view(reader, lend_from_1mb):
+    """What `PSOptimizer.step` leaves behind is read-only and replaced,
+    never written: every leaf enters the frame where it lies, nothing
+    of the model's size is allocated for the vector or for the frame's
+    parts, and the span says so."""
+    rng = np.random.default_rng(34)
+    s = _servicer("after_gradient", _model(rng))
+    spans = _encode_spans(s)
+    grads = jax.tree_util.tree_map(np.ones_like, s.get_params_copy()[0])
+    nbytes = _model_bytes(s)
+    assert nbytes > 3 << 20
+
+    def read():
+        if reader == "get_model":
+            return _pull(s)
+        version = s.version if reader == "gradient_response" else -5
+        return s.report_gradient(
+            {"version": version, "gradient": grads, "return_model": True}
+        )
+
+    if reader == "get_model":
+        from elasticdl_tpu.common import messages
+
+        def read_and_pack():
+            resp = read()
+            return resp, messages.pack_parts(resp)
+
+        (resp, payload), peak = _peak_while(read_and_pack)
+        assert peak < nbytes // 8
+        assert len(payload) > nbytes and not payload.joined
+    else:
+        resp = read()
+    assert resp.get("accepted", True) == (reader != "stale_rejection")
+    vec = resp["params_flat"]
+    assert isinstance(vec, codec.LeafVector)
+    held = jax.tree_util.tree_leaves(s._params)
+    assert len(vec.pieces) == len(held) == _MODEL_LEAVES
+    for piece, leaf in zip(vec.pieces, held):
+        assert not leaf.flags.writeable
+        assert piece.size == leaf.size and (
+            leaf.size == 0 or np.shares_memory(piece, leaf)
+        )
+    np.testing.assert_array_equal(vec, codec.ravel_np(s._params))
+    if reader != "stale_rejection":  # which records no span, as before
+        (sent,) = spans
+        assert (sent["by_view"], sent["copied"], sent["lent"]) == (
+            _MODEL_LEAVES, 0, False,
+        )
+    # the next step replaces the leaves: what went down stays as it was
+    kept = np.array(vec)
+    s.report_gradient({"version": s.version, "gradient": grads})
+    np.testing.assert_array_equal(vec, kept)
+    assert not np.array_equal(kept, codec.ravel_np(s._params))
+
+
+def test_writeable_leaves_are_copied_into_memory_the_servicer_lends(
+    lend_from_1mb,
+):
+    """A model `_add_delta` writes in place is copied under the lock,
+    into the memory the first response lay in once that is dropped; a
+    response still held keeps its memory, and the next gets its own."""
+    rng = np.random.default_rng(35)
+    s = _servicer("init_params", _model(rng))
+    spans = _encode_spans(s)
+    nbytes = _model_bytes(s)
+
+    def where(vec):
+        return vec.pieces[0].__array_interface__["data"][0]
+
+    first = _pull(s)["params_flat"]
+    want = codec.ravel_np(s._params)
+    np.testing.assert_array_equal(first, want)
+    for piece in first.pieces:
+        for leaf in jax.tree_util.tree_leaves(s._params):
+            assert not np.shares_memory(piece, leaf)
+    at = where(first)
+    del first, piece
+    # dropped: the second pull lies where the first did, and maps
+    # nothing of the model's size
+    resp, peak = _peak_while(lambda: _pull(s))
+    assert peak < nbytes // 8
+    second = resp.pop("params_flat")
+    assert where(second) == at
+    # held: the third gets memory of its own, and the update between
+    # leaves the second as it was
+    _update(s, np.ones(nbytes // 4, np.float32))
+    third = _pull(s)["params_flat"]
+    assert where(third) != at
+    np.testing.assert_array_equal(second, want)
+    np.testing.assert_array_equal(third, want + np.float32(1.0))
+    assert [
+        (a["by_view"], a["copied"], a["lent"]) for a in spans
+    ] == [
+        (0, _MODEL_LEAVES, False),
+        (0, _MODEL_LEAVES, True),
+        (0, _MODEL_LEAVES, False),
+    ]
+
+
+def test_each_leaf_goes_by_what_can_be_seen_of_it(lend_from_1mb):
+    """One algorithm over a mixed tree: read-only float32 by view, a
+    writeable leaf and one of another dtype copied, in order."""
+    rng = np.random.default_rng(36)
+    tree = _model(rng)
+    s = _servicer("init_params", tree)
+    spans = _encode_spans(s)
+    leaves = jax.tree_util.tree_leaves(s._params)
+    for leaf in leaves[::2]:
+        leaf.flags.writeable = False
+    s._params["l003"] = np.arange(7, dtype=np.int64)
+    s._params["l003"].flags.writeable = False  # read-only, but not float32
+    vec = _pull(s)["params_flat"]
+    leaves = jax.tree_util.tree_leaves(s._params)
+    by_view = [
+        leaf.size and np.shares_memory(piece, leaf)
+        for piece, leaf in zip(vec.pieces, leaves)
+    ]
+    want_by_view = [
+        not leaf.flags.writeable and leaf.dtype == np.float32
+        for leaf in leaves
+    ]
+    assert by_view == want_by_view and 70 < sum(by_view) < 90
+    np.testing.assert_array_equal(vec, codec.ravel_np(s._params))
+    (sent,) = spans
+    assert sent["by_view"] == sum(by_view)
+    assert sent["copied"] == _MODEL_LEAVES - sum(by_view)
+
+
+def test_a_narrowed_model_is_a_copy_and_says_so():
+    s = _servicer("after_gradient", _tree(np.random.default_rng(37)))
+    spans = _encode_spans(s)
+    grads = jax.tree_util.tree_map(np.ones_like, s.get_params_copy()[0])
+    resp = s.report_gradient(
+        {"version": s.version, "gradient": grads, "return_model": True,
+         "model_dtype": "bfloat16"}
+    )
+    vec = resp["params_flat"]
+    assert isinstance(vec, np.ndarray) and vec.dtype == codec._BFLOAT16
+    np.testing.assert_array_equal(
+        vec, codec.ravel_np(s._params).astype(codec._BFLOAT16)
+    )
+    assert spans == [{
+        "kind": "gradient", "version": 2, "by_view": 0, "copied": 3,
+        "lent": False,
+    }]
+
+
+@pytest.mark.parametrize("carrier", ["uds", "grpc", "inproc"])
+def test_a_model_pulled_while_deltas_land_arrives_whole(
+    carrier, monkeypatch, tmp_path, lend_from_1mb
+):
+    """The torn-read guard, over a real link. Every delta adds one to
+    every element, in place, so a model received whole is constant and
+    says its version. Two pullers keep two responses in flight: the
+    copy is made under the lock, and the memory lent to one response is
+    not handed to the next while the first is still on its way."""
+    from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
+    from elasticdl_tpu.rpc.client import RpcClient
+    from elasticdl_tpu.rpc.server import RpcServer
+
+    monkeypatch.setenv(ENV_TRANSPORT, carrier)
+    monkeypatch.setenv(ENV_UDS_DIR, str(tmp_path))
+    tree = {
+        "a": np.zeros((600, 512), np.float32),
+        "b": {"c": np.zeros((8, 128, 128), np.float32)},
+        "d": np.zeros((), np.float32),
+    }
+    s = _servicer("init_params", tree)
+    spans = _encode_spans(s)
+    n = _size(tree)
+    assert 4 * n > 1 << 20
+    one = np.ones(n, np.float32)
+    server = RpcServer(s.handlers(), port=0)
+    server.start()
+    stop = time.monotonic() + 1.5
+    torn, errors, pulls = [], [], []
+
+    def write():
+        try:
+            while time.monotonic() < stop:
+                s.report_local_update(
+                    {"delta_flat": one, "steps": 1, "base_version": 0}
+                )
+        except Exception as exc:  # pragma: no cover - the assertion below
+            errors.append(exc)
+
+    def pull():
+        client = RpcClient(f"localhost:{server.port}")
+        try:
+            assert (
+                client._transport.name if client._transport else "grpc"
+            ) == carrier
+            while time.monotonic() < stop:
+                resp = client.call(
+                    "GetModel",
+                    {"method": MethodType.MINIMUM, "flat": True},
+                    timeout=60,
+                )
+                vec = resp["params_flat"]
+                low, high = float(vec.min()), float(vec.max())
+                if not (vec.shape == (n,) and low == high == resp["version"]):
+                    torn.append((resp["version"], low, high))
+                pulls.append(resp["version"])
+        except Exception as exc:  # pragma: no cover
+            errors.append(exc)
+        finally:
+            client.close()
+
+    threads = [threading.Thread(target=write) for _ in range(2)] + [
+        threading.Thread(target=pull) for _ in range(2)
+    ]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+        server.stop()
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not torn
+    assert len(pulls) > 4 and max(pulls) > 0
+    assert all(a["copied"] == 3 and a["by_view"] == 0 for a in spans)
+    # both ways: lent where the last response had left, fresh where
+    # the other puller's was still in flight
+    assert any(a["lent"] for a in spans)

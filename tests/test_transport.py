@@ -1333,7 +1333,7 @@ class _FrameKeeper:
 
     def dispatch(self, method, frame, tier, recv_reused=False):
         self.frames.append((method, bytes(frame)))
-        return messages.pack({"ok": len(self.frames)})
+        return messages.pack_parts({"ok": len(self.frames)})
 
 
 @pytest.fixture
@@ -1552,8 +1552,9 @@ def timeline_spans():
 
 @pytest.fixture
 def joins(monkeypatch):
-    """Every `PackedParts` a client packed, and how often each was
-    really joined."""
+    """Every `PackedParts` packed in this process (a call's request,
+    then the server's response to it), and how often each was really
+    joined."""
     made, counts = [], []
     real_pack, real_join = messages.pack_parts, messages.PackedParts.contiguous
 
@@ -1579,8 +1580,12 @@ def _span_args(spans, name):
 def test_the_socket_carrier_never_joins_and_the_spans_say_so(
     unset_env, timeline_spans, joins
 ):
+    """Neither way: not the request, not the response (PR 34)."""
     made, counts = joins
-    server = RpcServer(_echo_handlers(), port=0)
+    timers = _Spans()
+    server = RpcServer(
+        _echo_handlers(), port=0, timers=timers, timed_methods=("Echo",)
+    )
     server.start()
     client = RpcClient(
         f"localhost:{server.port}", policy=fast_policy(), timeline=("Echo",)
@@ -1590,13 +1595,19 @@ def test_the_socket_carrier_never_joins_and_the_spans_say_so(
     finally:
         client.close()
         server.stop()
-    assert len(made) == 1 and counts == [] and not made[0].joined
+    assert len(made) == 2 and counts == []
+    request, response = made
+    assert not request.joined and not response.joined
     encode = _span_args(timeline_spans, "rpc.client.encode")
-    assert encode["parts"] == len(made[0].parts) >= 3
-    assert encode["bytes"] == len(made[0])
+    assert encode["parts"] == len(request.parts) >= 3
+    assert encode["bytes"] == len(request)
     trip = _span_args(timeline_spans, "rpc.client.Echo")
     assert (trip["transport"], trip["joined"]) == ("uds", False)
-    assert trip["bytes"] == len(made[0])
+    assert trip["bytes"] == len(request)
+    (served,) = [a for name, a in timers.records if name == "rpc.encode"]
+    assert served["joined"] is False
+    assert served["parts"] == len(response.parts) >= 3
+    assert served["bytes"] == len(response)
 
 
 def test_the_grpc_fallback_joins_exactly_once_over_its_retries(
@@ -1625,7 +1636,8 @@ def test_the_grpc_fallback_joins_exactly_once_over_its_retries(
     finally:
         client.close()
         server.stop()
-    assert len(made) == 1 and counts == [id(made[0])]
+    # the request, sent twice, and the one response: each joined once
+    assert len(made) == 2 and counts == [id(made[0]), id(made[1])]
     assert made[0].contiguous() is made[0].contiguous()
     trip = _span_args(timeline_spans, "rpc.client.Echo")
     assert (trip["transport"], trip["joined"]) == ("grpc", True)
@@ -1636,9 +1648,14 @@ def test_the_grpc_fallback_joins_exactly_once_over_its_retries(
 def test_a_one_buffer_carrier_joins_exactly_once(
     env_fixture, request, timeline_spans, joins
 ):
+    """The request, and the response the same (PR 34): joined inside
+    the server's `rpc.encode` span, which says so."""
     request.getfixturevalue(env_fixture)
     made, counts = joins
-    server = RpcServer(_echo_handlers(), port=0)
+    timers = _Spans()
+    server = RpcServer(
+        _echo_handlers(), port=0, timers=timers, timed_methods=("Echo",)
+    )
     server.start()
     client = RpcClient(
         f"localhost:{server.port}", policy=fast_policy(), timeline=("Echo",)
@@ -1649,9 +1666,11 @@ def test_a_one_buffer_carrier_joins_exactly_once(
     finally:
         client.close()
         server.stop()
-    assert len(made) == 1 and counts == [id(made[0])]
+    assert len(made) == 2 and counts == [id(made[0]), id(made[1])]
     trip = _span_args(timeline_spans, "rpc.client.Echo")
     assert (trip["transport"], trip["joined"]) == (tier, True)
+    (served,) = [a for name, a in timers.records if name == "rpc.encode"]
+    assert served["joined"] is True and served["bytes"] == len(made[1])
 
 
 def test_a_prepacked_request_passes_through_unjoined(keeper):
@@ -1664,3 +1683,197 @@ def test_a_prepacked_request_passes_through_unjoined(keeper):
     client.call("Push", payload, 10.0)
     assert dispatcher.frames == [("Push", frame)] and not payload.joined
     assert payload.contiguous() is frame
+
+
+# -- a response reaches the socket as its parts, a model as its leaves --------
+
+
+def _leaf_model(leaves=161, seed=34, elems=300):
+    """A model of ResNet-50's leaf count, sizes off the 64-byte grid."""
+    rng = _rng(seed)
+    return {
+        f"l{i:04d}": rng.standard_normal(
+            (elems + i % 11, 3) if i % 4 else (elems + i % 13,)
+        ).astype(np.float32)
+        for i in range(leaves)
+    }
+
+
+def _model_master(kind):
+    """A real `MasterServicer` holding the model with the leaves of
+    `kind`: `read_only` after a `PSOptimizer` step (sent by view),
+    `writeable` as set-up and `_add_delta` leave them (copied under
+    the lock)."""
+    import jax
+    import optax
+
+    from elasticdl_tpu.master.ps_optimizer import PSOptimizer
+    from elasticdl_tpu.master.servicer import MasterServicer
+
+    s = MasterServicer(grads_to_wait=1, optimizer=PSOptimizer(optax.sgd(0.5)))
+    s.report_variable(
+        {"params": _leaf_model(), "aux": {"stats": np.arange(5.0)}}
+    )
+    if kind == "read_only":
+        grads = jax.tree_util.tree_map(np.ones_like, s.get_params_copy()[0])
+        s.report_gradient({"version": 0, "gradient": grads})
+    return s
+
+
+@pytest.fixture
+def frames(monkeypatch):
+    """Every frame unpacked in this process, as the bytes it was: a
+    call's request where the server decodes it, then its response
+    where the client does."""
+    seen = []
+    real = messages.unpack
+
+    def unpack(data):
+        seen.append(bytes(data))
+        return real(data)
+
+    monkeypatch.setattr(messages, "unpack", unpack)
+    return seen
+
+
+@pytest.mark.parametrize("kind", ["read_only", "writeable"])
+@pytest.mark.parametrize("env_fixture", ["uds_env", "grpc_env", "inproc_env"])
+def test_a_model_response_is_the_raveled_frame_byte_for_byte(
+    env_fixture, kind, request, frames, joins
+):
+    """What a client receives for a model pull is `codec.dumps` of the
+    response with the vector `ravel_np` made, to the byte, whichever
+    carrier and whichever way the leaves went: no client can tell.
+    The socket never joins it; a one-buffer carrier joins it once."""
+    request.getfixturevalue(env_fixture)
+    made, counts = joins
+    s = _model_master(kind)
+    timers = _Spans()
+    server = RpcServer(
+        s.handlers(), port=0, timers=timers, timed_methods=("GetModel",)
+    )
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        tier = _tier(client)
+        resp = client.call(
+            "GetModel", {"method": "minimum", "flat": True}, timeout=30
+        )
+    finally:
+        client.close()
+        server.stop()
+    params, aux, version = s.get_params_copy()
+    raveled = {
+        "version": version,
+        "params_flat": codec.ravel_np(params),
+        "aux": aux,
+    }
+    assert version == (1 if kind == "read_only" else 0)
+    assert frames[-1] == codec.dumps(raveled)
+    assert resp["params_flat"].tobytes() == raveled["params_flat"].tobytes()
+    response = made[-1]
+    # prefix, header, pad, then the vector's one segment: a view a leaf
+    assert 161 < len(response.parts) < 161 + 8
+    assert response.joined == (tier != "uds")
+    assert counts.count(id(response)) == (tier != "uds")
+    (served,) = [a for name, a in timers.records if name == "rpc.encode"]
+    assert served["joined"] == (tier != "uds")
+    assert served["parts"] == len(response.parts)
+    assert served["bytes"] == len(frames[-1])
+
+
+def test_a_model_of_more_leaves_than_iov_max_through_a_shrunk_buffer(
+    unset_env, monkeypatch, frames
+):
+    """The response direction of the gather list's limit: 3,000 leaves
+    (`IOV_MAX` is 1024) through socket buffers smaller than one leaf,
+    both ends', arrive as the frame."""
+    monkeypatch.setattr(transport, "SOCKET_BUFFER_BYTES", 1024)
+    model = _leaf_model(leaves=3000, elems=1000)
+    pieces = [a.reshape(-1) for a in model.values()]
+
+    def pull(req):
+        return {"version": 7, "params_flat": codec.LeafVector(pieces)}
+
+    server = RpcServer({"Pull": pull}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        assert _tier(client) == "uds"
+        resp = client.call("Pull", {}, timeout=60)
+        (conn,) = client._transport._pool
+        assert conn.sndbuf < 8192 > conn.rcvbuf
+    finally:
+        client.close()
+        server.stop()
+    want = np.concatenate(pieces)
+    assert resp["params_flat"].tobytes() == want.tobytes()
+    assert frames[-1] == codec.dumps({"version": 7, "params_flat": want})
+    assert len(messages.pack_parts(pull({})).parts) > 2 * transport._IOV_MAX
+
+
+@pytest.mark.parametrize("core", ["threads", "loop"])
+def test_an_oversize_or_failed_model_response_leaves_the_link_serving(
+    unset_env, monkeypatch, core
+):
+    """A response packed as its parts is refused by its length before
+    a part is sent, a handler's failure is an error frame, and the
+    connection carries the next model after either, on both listeners."""
+    from elasticdl_tpu.common.constants import ENV_DISPATCH
+
+    monkeypatch.setenv(ENV_DISPATCH, core)
+    pieces = [a.reshape(-1) for a in _leaf_model(leaves=20).values()]
+    nbytes = 4 * sum(p.size for p in pieces)
+
+    def pull(req):
+        if req.get("fail"):
+            raise ValueError("no model\nyet")
+        return {"version": 1, "params_flat": codec.LeafVector(pieces)}
+
+    server = RpcServer({"Pull": pull}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        assert _tier(client) == "uds"
+        monkeypatch.setattr(transport, "MAX_FRAME_BYTES", nbytes // 2)
+        with pytest.raises(grpc.RpcError) as ei:
+            client.call("Pull", {}, timeout=10, idempotent=True)
+        assert ei.value.code() == grpc.StatusCode.OUT_OF_RANGE
+        assert "response frame of Pull is" in ei.value.details()
+        monkeypatch.setattr(transport, "MAX_FRAME_BYTES", 0xFFFFFFFF)
+        with pytest.raises(grpc.RpcError) as ei:
+            client.call("Pull", {"fail": True}, timeout=10)
+        assert ei.value.code() == grpc.StatusCode.INTERNAL
+        assert "ValueError: no model yet" in ei.value.details()
+        resp = client.call("Pull", {}, timeout=10)
+        assert resp["params_flat"].tobytes() == np.concatenate(pieces).tobytes()
+        assert len(client._transport._pool) == 1
+    finally:
+        client.close()
+        server.stop()
+
+
+def test_a_response_in_its_parts_is_let_go_once_it_has_left(unset_env):
+    """The listener names a response only while it sends it: what the
+    parts view (a model's leaves, the memory the master lent) is free
+    again while the connection waits for its next request."""
+    leaf = np.ones(1 << 18, np.float32)
+    alive = weakref.ref(leaf)
+
+    def pull(req):
+        return {"params_flat": codec.LeafVector([handed.pop()])}
+
+    handed = [leaf]
+    del leaf
+    server = RpcServer({"Pull": pull}, port=0)
+    server.start()
+    client = RpcClient(f"localhost:{server.port}", policy=fast_policy())
+    try:
+        assert _tier(client) == "uds"
+        resp = client.call("Pull", {}, timeout=10)
+        assert float(resp["params_flat"].sum()) == float(1 << 18)
+        assert _dies(alive, timeout=2.0)
+        assert len(client._transport._pool) == 1  # and the link is up
+    finally:
+        client.close()
+        server.stop()
