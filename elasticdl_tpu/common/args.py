@@ -549,9 +549,19 @@ def resolve_compile_cache_envs(args=None) -> dict:
     `default_compile_cache_dir()`, an explicit --compile_cache_dir is
     itself, and "" is off. MIN_COMPILE_TIME_SECS=0 caches every
     program — an elastic job's win is the replacement's boot, and its
-    model may well compile in under the 1s default threshold."""
+    model may well compile in under the 1s default threshold.
+    INCLUDE_METADATA_IN_KEY: jax leaves an instruction's `op_name` out
+    of the cache's key by default, so a program whose scopes alone were
+    edited came back with the executable, and the names, of the source
+    before the edit (obs/hlo_scopes.py reads those names). With it an
+    edited program compiles once more; a relaunched worker of the same
+    checkout still hits."""
+    tuning = {
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+        "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY": "1",
+    }
     if ENV_COMPILE_CACHE_DIR in os.environ:
-        return {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+        return tuning
     cache_dir = getattr(args, "compile_cache_dir", "auto")
     if cache_dir == "auto":
         if getattr(args, "worker_backend", "process") != "process":
@@ -559,10 +569,7 @@ def resolve_compile_cache_envs(args=None) -> dict:
         cache_dir = default_compile_cache_dir()
     if not cache_dir:
         return {}
-    return {
-        ENV_COMPILE_CACHE_DIR: cache_dir,
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-    }
+    return {ENV_COMPILE_CACHE_DIR: cache_dir, **tuning}
 
 
 def compile_cache_dir() -> str:
@@ -579,6 +586,7 @@ def enable_compile_cache():
 
     jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def worker_forward_args(args, worker_id: int, master_addr: str) -> List[str]:

@@ -103,7 +103,6 @@ ENV_OPT_MIRROR_SECS = "EDL_OPT_MIRROR_SECS"
 ENV_BET_PREFETCH = "EDL_BET_PREFETCH"
 ENV_BENCH_MFU = "EDL_BENCH_MFU"
 ENV_WORKER_LOG_DIR = "EDL_WORKER_LOG_DIR"
-ENV_HLO_SCOPES = "EDL_HLO_SCOPES"
 ENV_TB_BACKEND = "EDL_TPU_TB_BACKEND"
 ENV_NO_NATIVE_KV = "EDL_TPU_NO_NATIVE_KV"
 ENV_TPU_FLASH = "EDL_TPU_FLASH"
@@ -287,12 +286,6 @@ ENV_REGISTRY = {
     ENV_WORKER_LOG_DIR: (
         "directory for per-worker log files under the ProcessBackend "
         "(empty = inherit stdio)"
-    ),
-    ENV_HLO_SCOPES: (
-        "1: the worker writes the window program's {HLO instruction: "
-        "op_name} to <EDL_WORKER_LOG_DIR>/worker-<id>.hlo_scopes.json "
-        "after the program's first call, for readers of a device trace "
-        "(obs/hlo_scopes.py); set by whoever takes the trace"
     ),
     ENV_TB_BACKEND: (
         "TensorBoard event-writer backend override "

@@ -27,7 +27,6 @@ local layers.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -433,10 +432,11 @@ def token_cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray):
     """Mean next-token CE in f32 — THE loss definition, shared by the
     sharded path, the plain fast path, the dense reference, and the
     model-zoo spec (one place to fix numerics/masking for all four)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
+    with jax.named_scope("head"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.mean(logz - gold)
 
 
 def _local_loss(cfg: TransformerConfig, params, inputs, targets):
@@ -520,7 +520,7 @@ def looped_exit_loss(outputs: LoopedOutputs, targets):
     """The looped LM's stage-I objective (Ouro): per token the exit
     distribution's expected cross-entropy less `entropy_weight` times
     its entropy, then the mean over tokens."""
-    with jax.named_scope("exit_heads"):
+    with jax.named_scope("exit_heads"), jax.named_scope("head"):
         ce = exit_cross_entropies(outputs.logits, targets)
         q, log_q = exit_distribution(outputs.gates)
         entropy = -jnp.sum(q * log_q, axis=0)
@@ -600,11 +600,13 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     gates. With `moe_top_k` the stack is not of one shape: the
     `n_dense_layers` dense layers are scanned first, then the expert
     layers (`parallel/moe.moe_topk_held` on the experts held here),
-    and aux is the summed sequence-wise balance term. Only those two
-    carry `jax.named_scope`s (`looped_stack` > `attention`, `mlp`;
-    `exit_heads`; and `mla`, `mlp`, `moe` > `route`, `experts`,
-    `shared`): the other configurations' programs keep the metadata,
-    and so the compile-cache keys, they had."""
+    and aux is the summed sequence-wise balance term. Every
+    configuration's operations carry `jax.named_scope`s, which a
+    reader of a device trace joins to (obs/hlo_scopes.py): `embed`;
+    in each layer `attention` (`mla`) and `mlp` (`moe` > `route`,
+    `experts`, `shared`); `head` round the final norm, the logits and
+    the loss; and round those the looped LM's `looped_stack` and
+    `exit_heads`."""
     return plain_forward_stats(cfg, params, tokens)[:2]
 
 
@@ -621,13 +623,11 @@ def plain_forward_stats(
     stored = params
     params = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), params)
     b, l = tokens.shape
-    h = params["embed"][tokens]  # [B, L, d]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]  # [B, L, d]
     positions = jnp.arange(l)
     eps = cfg.norm_eps
     routed = bool(cfg.moe_top_k)
-    scope = jax.named_scope if cfg.looped or routed else (
-        lambda _name: contextlib.nullcontext()
-    )
 
     def attend(lp, x):
         if cfg.attention == "mla":
@@ -646,12 +646,14 @@ def plain_forward_stats(
         def body(carry, lp):
             h, aux = carry
             stats = {}
-            with scope("mla" if cfg.attention == "mla" else "attention"):
+            with jax.named_scope(
+                "mla" if cfg.attention == "mla" else "attention"
+            ):
                 out = attend(lp, rms_norm(h, lp["ln1"], eps))
                 if cfg.sandwich_norm:
                     out = rms_norm(out, lp["ln1b"], eps)
                 h = h + out
-            with scope("moe" if experts and routed else "mlp"):
+            with jax.named_scope("moe" if experts and routed else "mlp"):
                 x = rms_norm(h, lp["ln2"], eps)
                 if experts and routed:
                     out, a, stats = moe_topk_held(
@@ -696,7 +698,8 @@ def plain_forward_stats(
         (h, aux), stats = lax.scan(
             layer(bool(cfg.n_experts)), carry, expert_layers
         )
-        return rms_norm(h, params["ln_f"], eps), aux, stats
+        with jax.named_scope("head"):
+            return rms_norm(h, params["ln_f"], eps), aux, stats
 
     carry = (h, jnp.zeros((), dtype=jnp.float32 if routed else h.dtype))
     if not cfg.looped:
@@ -707,7 +710,8 @@ def plain_forward_stats(
                 "held_share": jnp.mean(stats["held_share"]),
                 "router_entropy": jnp.mean(stats["router_entropy"]),
             }
-        return h @ params["head"], aux, stats
+        with jax.named_scope("head"):
+            return h @ params["head"], aux, stats
 
     def one_pass(carry, _):
         h, aux, _stats = stack(carry)
@@ -722,8 +726,10 @@ def plain_forward_stats(
         gates = exits.astype(jnp.float32) @ gate["w"].astype(
             jnp.float32
         ) + gate["b"].astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = exits @ params["head"]
         return LoopedOutputs(
-            exits @ params["head"], gates[..., 0], cfg.exit_entropy_weight
+            logits, gates[..., 0], cfg.exit_entropy_weight
         ), aux, {}
 
 

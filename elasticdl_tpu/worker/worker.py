@@ -45,7 +45,6 @@ from elasticdl_tpu.api.model_spec import ModelSpec
 from elasticdl_tpu.common.constants import (
     ENV_BENCH_MFU,
     ENV_BET_PREFETCH,
-    ENV_HLO_SCOPES,
     ENV_OVERLAP_SYNC,
     ENV_SCHED_PHASE_SECS,
     ENV_SYNC_ADAPTIVE,
@@ -110,6 +109,12 @@ def _compiles_into(info: dict):
 
 
 _NO_SPAN = contextlib.nullcontext()
+
+
+def _program_name(program) -> str:
+    """The name jax gives a jitted callable's program, as the device
+    trace and the `setup.*` spans show it."""
+    return "jit_" + getattr(program, "__name__", "unnamed")
 
 
 def validate_eval_metrics(raw: dict):
@@ -177,6 +182,7 @@ class Worker:
     # phase-timeline state with class defaults: a skeleton built with
     # `Worker.__new__` needs only `timers`
     _programs_called = None  # jitted programs whose first call is past
+    _scope_maps = None  # {program: hlo_scopes.describe's record} so far
     _first_run = None  # deque: the first window's (time.time(), mode)
     _first_run_begun = False  # step loop only
 
@@ -1419,8 +1425,9 @@ class Worker:
         def run(params, aux, batch_embs: Dict[str, BatchEmbedding], features, labels):
             bets = {k: b.bet for k, b in batch_embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in batch_embs.items()}
-            with self._first_call(jitted):
-                return jitted(params, aux, bets, bet_aux, features, labels)
+            args = (params, aux, bets, bet_aux, features, labels)
+            with self._first_call(jitted, args):
+                return jitted(*args)
 
         return run
 
@@ -1446,7 +1453,7 @@ class Worker:
         has_emb = bool(self._emb_specs)
         unravel = self._unravel if (self._flat_transport and self._template is not None) else None
 
-        def step(params_in, aux, bets, bet_aux, features, labels):
+        def eval_step(params_in, aux, bets, bet_aux, features, labels):
             params = unravel(params_in) if unravel else params_in
             embeddings = (
                 {
@@ -1460,13 +1467,14 @@ class Worker:
             outputs, _ = self._apply_model(variables, features, embeddings, train=False)
             return outputs
 
-        jitted = self._shard_jit_eval(step)
+        jitted = self._shard_jit_eval(eval_step)
 
         def run(params, aux, batch_embs, features, labels):
             bets = {k: b.bet for k, b in batch_embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in batch_embs.items()}
-            with self._first_call(jitted):
-                return jitted(params, aux, bets, bet_aux, features, labels)
+            args = (params, aux, bets, bet_aux, features, labels)
+            with self._first_call(jitted, args):
+                return jitted(*args)
 
         return run
 
@@ -1542,8 +1550,10 @@ class Worker:
             (loss, new_aux), grad = jax.value_and_grad(loss_fn, has_aux=True)(
                 flat
             )
-            updates, opt_state = tx.update(grad, opt_state, flat)
-            return flat + updates, opt_state, new_aux if new_aux else aux, loss
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grad, opt_state, flat)
+                flat = flat + updates
+            return flat, opt_state, new_aux if new_aux else aux, loss
 
         return step
 
@@ -1574,9 +1584,11 @@ class Worker:
             (loss, new_aux), (gflat, gbets) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(flat, bets)
-            updates, opt_state = tx.update(gflat, opt_state, flat)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(gflat, opt_state, flat)
+                flat = flat + updates
             return (
-                flat + updates,
+                flat,
                 opt_state,
                 new_aux if new_aux else aux,
                 loss,
@@ -1656,34 +1668,28 @@ class Worker:
                 embs = self._prepare_embeddings(features)
             bets = {k: b.bet for k, b in embs.items()}
             bet_aux = {k: (b.inverse, b.mask) for k, b in embs.items()}
-            with self._first_call(self._local_step_fn):
+            args = (
+                self._flat, self._opt_state, self._aux, bets, bet_aux,
+                features, labels,
+            )
+            with self._first_call(self._local_step_fn, args):
                 (
                     self._flat,
                     self._opt_state,
                     new_aux,
                     loss,
                     gbets,
-                ) = self._local_step_fn(
-                    self._flat,
-                    self._opt_state,
-                    self._aux,
-                    bets,
-                    bet_aux,
-                    features,
-                    labels,
-                )
+                ) = self._local_step_fn(*args)
             # device refs only; the d2h rides the window sync's batch
             self._pending_edl.append((embs, gbets))
         else:
             if self._local_step_fn is None:
                 self._local_step_fn = self._build_local_step()
             self._first_run_begins("step")
-            with self._first_call(self._local_step_fn):
+            args = (self._flat, self._opt_state, self._aux, features, labels)
+            with self._first_call(self._local_step_fn, args):
                 self._flat, self._opt_state, new_aux, loss = (
-                    self._local_step_fn(
-                        self._flat, self._opt_state, self._aux, features,
-                        labels,
-                    )
+                    self._local_step_fn(*args)
                 )
         self._aux = new_aux or self._aux
         self._pending_steps += 1
@@ -1756,17 +1762,12 @@ class Worker:
         if self._local_window_fn is None:
             self._local_window_fn = self._build_local_window_fn()
         self._first_run_begins("window")
-        first = self._first_call(self._local_window_fn)
-        with first:
+        args = (self._flat, self._opt_state, self._aux, features, labels)
+        with self._first_call(self._local_window_fn, args):
             self._flat, self._opt_state, new_aux, loss = self._local_window_fn(
-                self._flat, self._opt_state, self._aux, features, labels
+                *args
             )
         self._aux = new_aux or self._aux
-        if first is not _NO_SPAN:
-            self._write_scope_map(
-                self._local_window_fn,
-                (self._flat, self._opt_state, self._aux, features, labels),
-            )
         self._pending_steps += self._local_updates
         self._latest_step_loss = loss
         if self._pending_steps >= self._local_updates * self._sync_local_steps:
@@ -2659,42 +2660,60 @@ class Worker:
             obs_trace.bind(prev)
 
     def _write_scope_map(self, program, args):
-        """Where `EDL_HLO_SCOPES=1` asks for it (whoever takes a
-        device trace of this worker does): every HLO instruction of the
-        window program with its `op_name`, which carries the model's
-        `jax.named_scope`s, for the trace's readers
-        (obs/hlo_scopes.py). Called once, after the program's first
-        call and outside its `setup.program` span, with that call's
-        arguments: jax then hands back the lowering and the executable
-        of the call (45 ms for the looped LM's window program on the
-        v5e, whose compile is 36 s; the log line says what it took), so
-        nothing is lowered, compiled or loaded twice."""
+        """What the compiled `program` says of itself, for the readers
+        of a device trace (obs/hlo_scopes.py): every HLO instruction's
+        `op_name`, which carries the `jax.named_scope`s it was traced
+        under, and the executable's memory analysis, added to
+        `$EDL_WORKER_LOG_DIR/worker-<id>.hlo_scopes.json` (nothing
+        where the directory is unset). Called once a program, after
+        its first call and outside its `setup.program` span, with that
+        call's arguments (donated ones too: only their shapes are
+        read): jax then hands back the lowering and the executable of
+        the call, so nothing is lowered, compiled or loaded twice.
+        `setup.scope_map` says what it cost and whether the names are
+        this trace's (`stale`: hlo_scopes.describe)."""
         log_dir = os.environ.get(ENV_WORKER_LOG_DIR, "")
-        if not log_dir or os.environ.get(ENV_HLO_SCOPES, "") != "1":
+        if not log_dir or args is None:
             return
-        t0 = time.time()
+        name = _program_name(program)
         try:
-            text = program.lower(*args).compile().as_text()
-            t_text = time.time()
-            path = os.path.join(log_dir, f"worker-{self._id}.hlo_scopes.json")
-            count = hlo_scopes.write(path, "jit_" + program.__name__, text)
-            logger.info(
-                "Worker %d: op_names of %d instructions of the window "
-                "program -> %s (the compiled text in %.3f s, the file in "
-                "%.3f s)", self._id, count, path, t_text - t0,
-                time.time() - t_text,
-            )
+            with self.timers.span("setup.scope_map", program=name) as info:
+                lowered = program.lower(*args)
+                record = hlo_scopes.describe(lowered, lowered.compile())
+                info["instructions"] = record["count"]
+                info["named"] = len(record["instructions"])
+                info["temp_bytes"] = record["memory"].get("temp")
+                info["argument_bytes"] = record["memory"].get("argument")
+                info["stale"] = record["stale"]
+                if record["stale"]:
+                    info["missing"] = record["missing"]
+                if self._scope_maps is None:
+                    self._scope_maps = {}
+                self._scope_maps[name] = record
+                hlo_scopes.write_programs(
+                    os.path.join(log_dir, f"worker-{self._id}.hlo_scopes.json"),
+                    self._scope_maps,
+                )
+            if record["stale"]:
+                logger.warning(
+                    "Worker %d: the executable of %s does not name %s, which "
+                    "this trace does: the compile cache served one compiled "
+                    "from other source, its map is marked stale",
+                    self._id, name, record["missing"],
+                )
         except Exception:  # a trace reader's aid must not stop training
             logger.warning(
-                "Worker %d: no HLO scope map written", self._id, exc_info=True
+                "Worker %d: no HLO scope map of %s written", self._id, name,
+                exc_info=True,
             )
 
-    def _first_call(self, program):
+    def _first_call(self, program, args=None):
         """`setup.program` around the FIRST call of a jitted program
         (trace, lower, compile or load from the compile cache,
-        dispatch), at its call site: `program` is the jitted callable
-        or, for an eager op, the name jax gives its program. Later
-        calls get a shared null context."""
+        dispatch), at its call site: `program` is the jitted callable,
+        with the call's arguments, or, for an eager op, the name jax
+        gives its program. On the way out a callable's map is written
+        (`_write_scope_map`). Later calls get a shared null context."""
         called = self._programs_called
         if called is None:
             called = self._programs_called = set()
@@ -2702,9 +2721,15 @@ class Worker:
         if key in called:
             return _NO_SPAN
         called.add(key)
-        if not isinstance(program, str):
-            program = "jit_" + getattr(program, "__name__", "unnamed")
-        return self._program_span(program)
+        if isinstance(program, str):
+            return self._program_span(program)
+        return self._first_call_of(program, args)
+
+    @contextlib.contextmanager
+    def _first_call_of(self, program, args):
+        with self._program_span(_program_name(program)):
+            yield
+        self._write_scope_map(program, args)
 
     @contextlib.contextmanager
     def _program_span(self, program: str):

@@ -48,7 +48,6 @@ def test_looped_lm_trains_through_master_main(tmp_path, monkeypatch, local_updat
     output = os.path.join(tmp, "final.ckpt")
     logs = os.path.join(tmp, "logs")
     monkeypatch.setenv("EDL_WORKER_LOG_DIR", logs)
-    monkeypatch.setenv("EDL_HLO_SCOPES", "1")  # as a traced run asks
     rc = master_main(
         [
             "--model_zoo", MODELS_DIR,
@@ -97,43 +96,64 @@ def test_looped_lm_trains_through_master_main(tmp_path, monkeypatch, local_updat
 def test_the_scope_map_is_written_where_asked_and_costs_no_compile(
     tmp_path, monkeypatch
 ):
-    """`EDL_HLO_SCOPES=1` alone makes the worker write it; the text
-    comes from the executable of the call before: jax's own counters
-    see no second lowering and no second compile."""
+    """A log directory alone makes the worker write it, on the way out
+    of the program's first call; the text and the memory analysis come
+    from the executable of that call, donated arguments and all: jax's
+    own counters see no second lowering and no second compile."""
     import jax
     from jax import monitoring
 
+    from elasticdl_tpu.common.timing import PhaseTimers
+    from elasticdl_tpu.obs import trace
     from elasticdl_tpu.worker.worker import Worker
 
     worker = Worker.__new__(Worker)
     worker._id = 0
+    worker.timers = PhaseTimers(sink=trace.record_phase)
 
     def window(x):
         with jax.named_scope("looped_stack"):
             return jnp.tanh(x) * 2.0
 
-    program = jax.jit(window)
-    out = program(jnp.ones((4,)))
+    program = jax.jit(window, donate_argnums=(0,))
+    monkeypatch.delenv("EDL_WORKER_LOG_DIR", raising=False)
+    worker._write_scope_map(program, (jnp.ones((4,)),))
+    assert os.listdir(tmp_path) == []  # no directory, no file
     monkeypatch.setenv("EDL_WORKER_LOG_DIR", str(tmp_path))
-    monkeypatch.delenv("EDL_HLO_SCOPES", raising=False)
-    worker._write_scope_map(program, (out,))
-    assert os.listdir(tmp_path) == []
-    monkeypatch.setenv("EDL_HLO_SCOPES", "1")
     seen = []
 
     def listener(event, _seconds, **_kw):
         seen.append(event)
 
+    args = (jnp.ones((4,)),)
+    trace.RECORDER.clear()
     monitoring.register_event_duration_secs_listener(listener)
     try:
-        worker._write_scope_map(program, (out,))
+        with worker._first_call(program, args):
+            program(*args)
+            assert args[0].is_deleted()
+            called = list(seen)
     finally:
         monitoring.unregister_event_duration_listener(listener)
-    assert not [e for e in seen if "mlir" in e or "backend_compile" in e], seen
+    assert [e for e in called if "backend_compile" in e], called
+    later = seen[len(called):]  # what the map's writing added: nothing
+    assert not [e for e in later if "mlir" in e or "backend_compile" in e], later
+    with worker._first_call(program, args):
+        pass  # a later call: no span, no second map
     with open(tmp_path / "worker-0.hlo_scopes.json") as f:
         record = json.load(f)
     assert record["program"] == "jit_window"
     assert any("looped_stack" in p for p in record["instructions"].values())
+    assert record["programs"]["jit_window"]["instructions"] == record["instructions"]
+    spans = [s for s in trace.RECORDER.snapshot() if s["name"].startswith("setup.")]
+    assert [s["name"] for s in spans] == ["setup.program", "setup.scope_map"]
+    program_span, map_span = spans
+    # outside `setup.program`, so `setup_programs_s` does not move
+    assert map_span["ts"] >= program_span["ts"] + program_span["dur"] - 1e-6
+    assert map_span["args"]["program"] == "jit_window"
+    assert map_span["args"]["stale"] is False
+    assert 0 < map_span["args"]["named"] <= map_span["args"]["instructions"]
+    trace.RECORDER.clear()
 
 
 @pytest.mark.parametrize("setting", [

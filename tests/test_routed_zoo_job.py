@@ -37,7 +37,6 @@ def test_routed_lm_trains_through_master_main_on_the_serial_chain(
     output = os.path.join(tmp, "final.ckpt")
     logs = os.path.join(tmp, "logs")
     monkeypatch.setenv("EDL_WORKER_LOG_DIR", logs)
-    monkeypatch.setenv("EDL_HLO_SCOPES", "1")  # as a traced run asks
     rc = master_main(
         [
             "--model_zoo", FIXTURES,
