@@ -36,6 +36,8 @@ _INSTRUCTION = re.compile(
 )
 # every instruction of the text, named or not: `%fusion.1 = f32[...`
 _ANY_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = ", re.M)
+# a Pallas kernel in the compiled text: Mosaic's custom call
+_KERNEL = 'custom_call_target="tpu_custom_call"'
 # a name location of the lowering's debug text, `loc("jvp(head)/mul"(#loc3))`;
 # a file location is `loc("/path/file.py":12:3)`
 _NAME_LOCATION = re.compile(r'loc\("([^"]+)"[()]')
@@ -51,6 +53,19 @@ def op_names(hlo_text: str) -> Dict[str, str]:
     return dict(_INSTRUCTION.findall(hlo_text))
 
 
+def _words(path: str, leaf: bool = False):
+    """The scope words of one `op_name`, outermost first: every word of
+    every component but the last (with it when `leaf`), `jit(...)`
+    left out wherever it stands."""
+    parts = _JIT.sub("", path).split("/")
+    return [
+        w
+        for part in (parts if leaf else parts[:-1])
+        for w in re.split(r"[()]", part)
+        if w
+    ]
+
+
 def scopes(paths: Iterable[str], leaf: bool = False) -> Set[str]:
     """The scopes `paths` run through: every word of every component
     but the last (the primitive's own name; with it when `leaf`), a
@@ -58,11 +73,26 @@ def scopes(paths: Iterable[str], leaf: bool = False) -> Set[str]:
     `head`. A `jit(...)` is left out wherever it stands: what it wraps
     is a function's name, and the compiler inlines and folds such
     helpers away whole."""
-    found = set()
-    for path in paths:
-        parts = _JIT.sub("", path).split("/")
-        for part in parts if leaf else parts[:-1]:
-            found.update(w for w in re.split(r"[()]", part) if w)
+    return {w for path in paths for w in _words(path, leaf)}
+
+
+def kernels(hlo_text: str) -> Dict[str, int]:
+    """{scope: how many Mosaic custom calls (Pallas kernels) of
+    `hlo_text` sit under it}, the scope being the innermost one of the
+    call's `op_name` (`.../transpose(jvp(attention))/pallas_call` ->
+    `attention`; "" for a call with none: the compiler's own Mosaic
+    programs, its grouped matmul behind `lax.ragged_dot`, are named
+    `ragged-dot-none` and nothing else). {} for a program without a
+    kernel: whether a dispatcher engaged its kernels is then read from
+    the program, not inferred from a time."""
+    found: Dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if _KERNEL not in line:
+            continue
+        named = _INSTRUCTION.match(line)
+        words = _words(named.group(2)) if named else []
+        scope = words[-1] if words else ""
+        found[scope] = found.get(scope, 0) + 1
     return found
 
 
@@ -82,8 +112,8 @@ def describe(lowered, compiled) -> Dict:
     """One program's record from the lowering and the executable of a
     call jax has already made (`program.lower(*args)` and its
     `.compile()` hand both back): `instructions`, `count` (all the
-    text's instructions, named or not), `memory`, and `stale` with the
-    scopes `missing` from the executable."""
+    text's instructions, named or not), `kernels`, `memory`, and
+    `stale` with the scopes `missing` from the executable."""
     text = compiled.as_text()
     instructions = op_names(text)
     traced = scopes(_NAME_LOCATION.findall(lowered.as_text(debug_info=True)))
@@ -91,6 +121,7 @@ def describe(lowered, compiled) -> Dict:
     return {
         "instructions": instructions,
         "count": len(_ANY_INSTRUCTION.findall(text)),
+        "kernels": kernels(text),
         "memory": memory(compiled),
         "stale": bool(missing),
         "missing": missing[:10],

@@ -1,35 +1,40 @@
 """Fused causal attention as Pallas TPU kernels (fwd + bwd).
 
-The hot op of the flagship transformer (models/transformer_lm.py) and
-of each ring-attention step (parallel/ring_attention.py) is blockwise
-softmax(QK^T)V. XLA's stock lowering materializes the [L, L] score
-matrix in HBM for the full-sequence path; these kernels keep the
-working set in VMEM with the standard flash-attention online-softmax
-accumulator (m/l running max/denominator), so HBM traffic is O(L*D)
-instead of O(L^2) and the MXU sees back-to-back [BQ,D]x[D,BK] and
-[BQ,BK]x[BK,D] matmuls with f32 accumulation.
+The hot op of the transformer (models/transformer_lm.py) and of a
+one-device ring (parallel/ring_attention.py) is softmax(QK^T)V. From
+2048 tokens on, XLA's lowering writes the [L, L] scores to HBM, reads
+them back and keeps them for the backward pass; these kernels keep a
+tile of them in VMEM with the online-softmax accumulator (m/l running
+max/denominator), so HBM traffic is O(L*D) instead of O(L^2) and the
+MXU sees back-to-back [BQ,D]x[D,BK] and [BQ,BK]x[BK,D] matmuls with
+f32 accumulation.
 
-No reference equivalent (the 2019 reference has no attention model);
-this is the "pallas kernels for the hot ops" arm of the TPU-first
-design. All three kernels (fwd, dq, dk+dv) are STREAMING: the
-non-owned sequence dimension rides the innermost grid axis — one
-[BLOCK, D] tile in flight per input, accumulators live in VMEM scratch
-across grid steps, output blocks revisit until their row/column is
-done. VMEM use is O(BLOCK*D) regardless of L (the earlier seq-resident
-layout hit Mosaic's 16M scoped-vmem wall at L=8192), which is what
-makes long-context the kernel's home regime. The forward also emits
-the per-row logsumexp; the backward is the standard two-kernel flash
-scheme re-forming p = exp(s - lse) from O(L*D) residuals — nothing
-quadratic is ever saved, and no atomics: each kernel owns its output
-block (FlashAttention-2 layout). Numerics are validated
-block-for-block against the reference math in
+All three kernels (fwd, dq, dk+dv) are STREAMING: the non-owned
+sequence dimension rides the innermost grid axis, one tile in flight
+per input, accumulators in VMEM scratch across grid steps, output
+blocks revisited until their row/column is done. The tile is as large
+as the ladder allows (`pick_tiles`: 1024 x 1024 where L divides by
+it), because a grid step costs more than a 128^3 matmul: at 128 x 128
+the kernels ran at half XLA's speed at 2048 tokens, at 1024 x 1024 at
+2.1 to 2.2 times it (FLASH_MIN_LENGTH has the numbers). Under the
+causal mask a step above the diagonal runs nothing and fetches
+nothing (its block index is held at the nearest visible tile's), and
+only a tile the diagonal crosses is masked. A head width of a multiple
+of 128 is read where it lies in [B, L, H, D]; a narrower one is folded
+through memory (`_Layout`). VMEM use is O(tile) whatever L is. The
+forward also emits the per-row logsumexp; the backward is the standard
+two-kernel flash scheme re-forming p = exp(s - lse) from O(L*D)
+residuals: nothing quadratic is ever saved, and no atomics, each
+kernel owns its output block (FlashAttention-2 layout). Numerics are
+validated tile pair by tile pair against the reference math in
 tests/test_flash_attention.py in Pallas interpret mode on CPU, and
 compiled on the chip by chip_smoke.py and under EDL_TPU_TESTS=1
 (`check_against_reference`).
 
 Layout contract: [B, L, H, D] ("blhd", matching transformer_lm), any
-float dtype; compute is f32. L must divide by the 128 block; callers
-with ragged L use the jnp fallback (`reference_attention`).
+float dtype; scores, softmax and accumulators are f32. L must divide
+by the 128 block; callers with ragged L use the jnp fallback
+(`reference_attention`).
 """
 
 from __future__ import annotations
@@ -42,7 +47,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 128  # q/k block edge: MXU-native tile
+BLOCK = 128  # the smallest tile edge: L must divide by it
+# Tile edges, largest first: a kernel call cuts queries and keys alike
+# into the first that divides L. 1024 is where the sweep on the chip
+# stopped paying (FLASH_MIN_LENGTH, docs/performance.md): a wider k edge
+# steps through the whole square again, a wider q edge was slower.
+TILE_LADDER = (1024, 512, 256, 128)
+# What a kernel may take of the v5e's 128 MiB of VMEM: its tiles twice
+# (the pipeline's two buffers), its accumulators and the float32 score
+# tile with its copies (s, p, dp, ds: 4 MiB each at 1024 x 1024).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _NEG_INF = -1e30
 
 
@@ -62,32 +76,115 @@ def reference_attention(q, k, v, causal: bool = True, scale=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _causal_mask(qi, kj, s):
-    """Mask s [BQ, BK] by global position for the (qi, kj) block pair;
-    off-diagonal visible blocks pass through unchanged."""
-    rows = qi * BLOCK + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 0)
-    cols = kj * BLOCK + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 1)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+def pick_tiles(L: int):
+    """(q edge, k edge) of the tiles the kernels cut a sequence of `L`
+    into, or None where not even BLOCK divides it (the caller's
+    fallback is `reference_attention`)."""
+    edge = next((t for t in TILE_LADDER if L > 0 and L % t == 0), None)
+    return edge and (edge, edge)
 
 
-def _fold(x, b, L, h, d):
-    return x.transpose(0, 2, 1, 3).reshape(b * h, L, d)
+def _on_visible_tiles(qi, kj, bq: int, bk: int, causal: bool, body):
+    """Run `body(masked)` for the (qi, kj) tile pair: nothing for a
+    tile wholly above the diagonal, `masked` only where the diagonal
+    crosses the tile."""
+    if not causal:
+        body(False)
+        return
+    visible = kj * bk <= qi * bq + bq - 1  # its first key, its last query
+    crossed = kj * bk + bk - 1 > qi * bq  # its last key, its first query
+    pl.when(visible & crossed)(lambda: body(True))
+    pl.when(visible & jnp.logical_not(crossed))(lambda: body(False))
 
 
-def _unfold(x, b, L, h, d):
-    return x.reshape(b, h, L, d).transpose(0, 2, 1, 3)
+def _mask(s, qi, kj, bq: int, bk: int, q_axis: int):
+    """Mask a score tile by global position; queries run along
+    `q_axis` of `s`, keys along the other."""
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _tile_picks(bq: int, bk: int, causal: bool):
+    """(own, seen_k, seen_q): which tile along L a BlockSpec fetches at
+    grid step (head, j, t). `own` is the outer axis's tile; `seen_k` the
+    k tile of step t under q tile j, `seen_q` the q tile of step t over
+    k tile j. Under the causal mask a step off the triangle is held at
+    the nearest tile on it (the row's last visible k tile, the column's
+    first visible q tile), so it fetches nothing new."""
+    own = lambda j, t: j  # noqa: E731
+    if not causal:
+        return own, (lambda j, t: t), (lambda j, t: t)
+    seen_k = lambda j, t: jnp.minimum(t, ((j + 1) * bq - 1) // bk)  # noqa: E731
+    seen_q = lambda j, t: jnp.maximum(t, (j * bk) // bq)  # noqa: E731
+    return own, seen_k, seen_q
+
+
+class _Layout:
+    """How the kernels see [B, L, H, D]: one (head, rows) tile of width
+    D at a time. A head width the lanes divide is read where it lies,
+    as columns h*D.. of [B, L, H*D]; a narrower head cannot be a block
+    of that array (its last edge must be a multiple of 128 or the whole
+    of it), so it is folded to [B*H, L, D] through memory."""
+
+    def __init__(self, b, L, h, d):
+        self.b, self.L, self.h, self.d = b, L, h, d
+        self.in_place = d % 128 == 0
+        self.shape = (b, L, h * d) if self.in_place else (b * h, L, d)
+
+    def view(self, x):
+        if self.in_place:
+            return x.reshape(self.shape)
+        return x.transpose(0, 2, 1, 3).reshape(self.shape)
+
+    def unview(self, x):
+        b, L, h, d = self.b, self.L, self.h, self.d
+        if self.in_place:
+            return x.reshape(b, L, h, d)
+        return x.reshape(b, h, L, d).transpose(0, 2, 1, 3)
+
+    def spec(self, rows: int, pick):
+        """Tiles of `rows` rows; `pick(j, t)` is the tile's index along
+        L at grid step (head, j, t)."""
+        h = self.h
+        if self.in_place:
+            index = lambda i, j, t: (i // h, pick(j, t), i % h)  # noqa: E731
+        else:
+            index = lambda i, j, t: (i, pick(j, t), 0)  # noqa: E731
+        return pl.BlockSpec((1, rows, self.d), index)
+
+
+def _params(interpret: bool):
+    if interpret:
+        return {"interpret": True}
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        )
+    }
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 # ----------------------------------------------------------------- forward
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-               *, n_k: int, causal: bool, scale: float):
-    """Streaming forward: grid (bh, q-block, k-block), k innermost.
-    One [BLOCK, D] tile per input is resident; the online-softmax state
-    (acc/m/l) lives in VMEM scratch across the k sweep; o/lse write
-    once at the sweep's end (their block index is constant over kj, so
-    Mosaic keeps them in VMEM until then)."""
+               *, bq: int, bk: int, causal: bool, scale: float):
+    """Streaming forward: grid (head, q tile, k tile), k innermost. One
+    tile per input is resident; the online-softmax state (acc/m/l)
+    lives in VMEM scratch across the k sweep; o/lse write once at the
+    sweep's end (their block index is constant over kj, so Mosaic keeps
+    them in VMEM until then). A step above the diagonal runs nothing
+    and, its index clamped to the row's last visible tile, fetches
+    nothing."""
     qi, kj = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -96,76 +193,66 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    visible = kj <= qi if causal else kj >= 0
-
-    @pl.when(visible)
-    def _body():
-        q = q_ref[0]  # [BQ, D], input dtype: MXU-native operands
-        kb = k_ref[0]
+    def body(masked):
         vb = v_ref[0]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK]
-        if causal:
-            s = _causal_mask(qi, kj, s)
-        m_prev, l_prev = m_ref[...], l_ref[...]
+        # operands in the input dtype: bf16 MXU passes, f32 accumulation
+        s = _dot(q_ref[0], k_ref[0], _NT) * scale  # [BQ, BK]
+        if masked:
+            s = _mask(s, qi, kj, bq, bk, q_axis=0)
+        m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         m_ref[...] = m_new
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb,  # p in operand dtype: bf16 MXU pass
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(vb.dtype), vb, _NN)
 
-    @pl.when(kj == n_k - 1)
+    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])  # [BLOCK, 1]
+        lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])  # [BQ, 1]
 
 
-def _flash_forward(q, k, v, causal: bool, interpret: bool):
+def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles):
     """Returns (o [B,L,H,D], lse [B*H, L, 1])."""
     b, L, h, d = q.shape
-    assert L % BLOCK == 0, f"L={L} must divide by {BLOCK}"
-    n_k = L // BLOCK
-    scale = 1.0 / math.sqrt(d)
-    # [B, L, H, D] -> [B*H, L, D]; grid = (head, q-block, k-block)
-    qf, kf, vf = (_fold(x, b, L, h, d) for x in (q, k, v))
-    q_spec = pl.BlockSpec((1, BLOCK, d), lambda i, j, t: (i, j, 0))
-    kv_spec = pl.BlockSpec((1, BLOCK, d), lambda i, j, t: (i, t, 0))
+    bq, bk = tiles
+    lay = _Layout(b, L, h, d)
+    own, seen_k, _ = _tile_picks(bq, bk, causal)
+    q_spec, kv_spec = lay.spec(bq, own), lay.spec(bk, seen_k)
     # rows ([B*H, L, 1]) carry a trailing singleton so Mosaic's tiling
-    # rule holds: block (1, BLOCK, 1) -> last two dims (BLOCK, 1) are
+    # rule holds: block (1, BQ, 1) -> last two dims (BQ, 1) are
     # (div-by-8, equal-to-array)
-    lse_spec = pl.BlockSpec((1, BLOCK, 1), lambda i, j, t: (i, j, 0))
+    lse_spec = pl.BlockSpec((1, bq, 1), lambda i, j, t: (i, j, 0))
     out, lse = pl.pallas_call(
-        functools.partial(_fa_kernel, n_k=n_k, causal=causal, scale=scale),
+        functools.partial(
+            _fa_kernel, bq=bq, bk=bk, causal=causal, scale=1.0 / math.sqrt(d)
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+            jax.ShapeDtypeStruct(lay.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32),
         ],
-        grid=(b * h, n_k, n_k),
+        grid=(b * h, L // bq, L // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, lse_spec],
         scratch_shapes=[
-            pltpu.VMEM((BLOCK, d), jnp.float32),
-            pltpu.VMEM((BLOCK, 1), jnp.float32),
-            pltpu.VMEM((BLOCK, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf)
-    return _unfold(out, b, L, h, d), lse
+        **_params(interpret),
+    )(lay.view(q), lay.view(k), lay.view(v))
+    return lay.unview(out), lse
 
 
 # ---------------------------------------------------------------- backward
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, n_k: int, causal: bool, scale: float):
-    """Streaming dq: grid (bh, q-block, k-block), k innermost. Re-forms
+               acc_ref, *, bq: int, bk: int, causal: bool, scale: float):
+    """Streaming dq: grid (head, q tile, k tile), k innermost. Re-forms
     p = exp(s - lse), ds = p * (do v^T - delta) * scale, accumulates
     dq += ds k in VMEM scratch across the k sweep."""
     qi, kj = pl.program_id(1), pl.program_id(2)
@@ -174,45 +261,33 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    visible = kj <= qi if causal else kj >= 0
-
-    @pl.when(visible)
-    def _body():
-        q = q_ref[0]  # [BQ, D]
-        do = do_ref[0]
-        lse = lse_ref[0]  # [BQ, 1]
-        delta = delta_ref[0]
+    def body(masked):
         kb = k_ref[0]
-        vb = v_ref[0]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_mask(qi, kj, s)
-        p = jnp.exp(s - lse)  # [BQ, BK]
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * scale).astype(kb.dtype)
-        acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        s = _dot(q_ref[0], kb, _NT) * scale  # [BQ, BK]
+        if masked:
+            s = _mask(s, qi, kj, bq, bk, q_axis=0)
+        p = jnp.exp(s - lse_ref[0])  # lse, delta: [BQ, 1]
+        dp = _dot(do_ref[0], v_ref[0], _NT)
+        ds = (p * (dp - delta_ref[0]) * scale).astype(kb.dtype)
+        acc_ref[...] = acc_ref[...] + _dot(ds, kb, _NN)
 
-    @pl.when(kj == n_k - 1)
+    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, *, n_q: int, causal: bool,
+                dv_ref, dk_acc, dv_acc, *, bq: int, bk: int, causal: bool,
                 scale: float):
-    """Streaming dk/dv: grid (bh, k-block, q-block), q innermost. The
+    """Streaming dk/dv: grid (head, k tile, q tile), q innermost. The
     owned k/v tiles stay resident (their index is constant over qi);
     q/do/lse/delta tiles stream past; dk/dv accumulate in VMEM scratch.
-    No atomics — this kernel owns its k-block's outputs."""
+    No atomics: this kernel owns its k tile's outputs. The scores are
+    formed transposed, keys down and queries across ([BK, BQ]), so
+    p^T do and ds^T q are plain products and lse/delta come as rows
+    ([1, BQ], one dense line each) that broadcast down the tile."""
     kj, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -220,123 +295,116 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    visible = qi >= kj if causal else qi >= 0
-
-    @pl.when(visible)
-    def _body():
-        kb = k_ref[0]  # [BK, D]
-        vb = v_ref[0]
+    def body(masked):
         qb = q_ref[0]  # [BQ, D]
         do = do_ref[0]
-        lse = lse_ref[0]  # [BQ, 1]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            s = _causal_mask(qi, kj, s)
-        p = jnp.exp(s - lse)  # [BQ, BK]
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * scale).astype(qb.dtype)
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        st = _dot(k_ref[0], qb, _NT) * scale  # [BK, BQ]
+        if masked:
+            st = _mask(st, qi, kj, bq, bk, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0])  # lse, delta: [1, BQ]
+        dv_acc[...] = dv_acc[...] + _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v_ref[0], do, _NT)
+        dst = (pt * (dpt - delta_ref[0]) * scale).astype(qb.dtype)
+        dk_acc[...] = dk_acc[...] + _dot(dst, qb, _NN)
 
-    @pl.when(qi == n_q - 1)
+    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool):
+def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
+                    tiles):
     b, L, h, d = q.shape
-    n_blocks = L // BLOCK
+    bq, bk = tiles
     scale = 1.0 / math.sqrt(d)
-    qf, kf, vf, gf = (_fold(x, b, L, h, d) for x in (q, k, v, g))
-    of = _fold(o, b, L, h, d)
+    lay = _Layout(b, L, h, d)
+    qf, kf, vf, gf = (lay.view(x) for x in (q, k, v, g))
     # delta_i = rowsum(do_i * o_i): tiny elementwise+reduce, XLA fuses
     delta = jnp.sum(
-        gf.astype(jnp.float32) * of.astype(jnp.float32),
-        axis=-1,
-        keepdims=True,
-    )  # [B*H, L, 1] — trailing singleton for the tiling rule
-    own = pl.BlockSpec((1, BLOCK, d), lambda i, j, t: (i, j, 0))
-    stream = pl.BlockSpec((1, BLOCK, d), lambda i, j, t: (i, t, 0))
-    row_own = pl.BlockSpec((1, BLOCK, 1), lambda i, j, t: (i, j, 0))
-    row_stream = pl.BlockSpec((1, BLOCK, 1), lambda i, j, t: (i, t, 0))
+        g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1).reshape(b * h, L, 1)
+    own, seen_k, seen_q = _tile_picks(bq, bk, causal)
+    column = pl.BlockSpec((1, bq, 1), lambda i, j, t: (i, j, 0))
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, n_k=n_blocks, causal=causal, scale=scale
+            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
-        grid=(b * h, n_blocks, n_blocks),
-        in_specs=[own, stream, stream, own, row_own, row_own],
-        out_specs=own,
-        scratch_shapes=[pltpu.VMEM((BLOCK, d), jnp.float32)],
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct(lay.shape, q.dtype),
+        grid=(b * h, L // bq, L // bk),
+        in_specs=[
+            lay.spec(bq, own), lay.spec(bk, seen_k), lay.spec(bk, seen_k),
+            lay.spec(bq, own), column, column,
+        ],
+        out_specs=lay.spec(bq, own),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        **_params(interpret),
     )(qf, kf, vf, gf, lse, delta)
+    row = pl.BlockSpec((1, 1, bq), lambda i, j, t: (i, 0, seen_q(j, t)))
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, n_q=n_blocks, causal=causal, scale=scale
+            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, L, d), v.dtype),
+            jax.ShapeDtypeStruct(lay.shape, k.dtype),
+            jax.ShapeDtypeStruct(lay.shape, v.dtype),
         ],
-        grid=(b * h, n_blocks, n_blocks),
-        in_specs=[stream, own, own, stream, row_stream, row_stream],
-        out_specs=[own, own],
+        grid=(b * h, L // bk, L // bq),
+        in_specs=[
+            lay.spec(bq, seen_q), lay.spec(bk, own), lay.spec(bk, own),
+            lay.spec(bq, seen_q), row, row,
+        ],
+        out_specs=[lay.spec(bk, own), lay.spec(bk, own)],
         scratch_shapes=[
-            pltpu.VMEM((BLOCK, d), jnp.float32),
-            pltpu.VMEM((BLOCK, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=interpret,
-    )(qf, kf, vf, gf, lse, delta)
-    return tuple(_unfold(x, b, L, h, d) for x in (dq, dk, dv))
+        **_params(interpret),
+    )(qf, kf, vf, gf, lse.reshape(b * h, 1, L), delta.reshape(b * h, 1, L))
+    return tuple(lay.unview(x) for x in (dq, dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention(q, k, v, causal: bool, interpret: bool):
-    return _flash_forward(q, k, v, causal, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles):
+    return _flash_forward(q, k, v, causal, interpret, tiles)[0]
 
 
-def _fa_fwd(q, k, v, causal, interpret):
-    o, lse = _flash_forward(q, k, v, causal, interpret)
+def _fa_fwd(q, k, v, causal, interpret, tiles):
+    o, lse = _flash_forward(q, k, v, causal, interpret, tiles)
     return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(causal, interpret, residuals, g):
+def _fa_bwd(causal, interpret, tiles, residuals, g):
     # two-kernel flash backward (dq; dk+dv) from O(L*D) residuals —
-    # the [L, L] score matrix is re-formed blockwise in VMEM, never
+    # the [L, L] score matrix is re-formed tile by tile in VMEM, never
     # materialized in HBM
     q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, causal, interpret)
+    return _flash_backward(q, k, v, o, lse, g, causal, interpret, tiles)
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = True, interpret: bool = False):
+def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
+                    tiles=None):
     """Differentiable fused attention, [B, L, H, D] -> [B, L, H, D].
     `interpret=True` runs the kernel in the Pallas interpreter and is
     for tests only (no model path passes it); compiled, the kernels
-    are Mosaic programs and exist on the TPU alone."""
+    are Mosaic programs and exist on the TPU alone. `tiles` = (q edge,
+    k edge) in place of `pick_tiles(L)`: the tests' and the sweep's."""
     if not interpret and jax.default_backend() != "tpu":
         raise RuntimeError(
             "flash_attention compiles for the TPU only (default backend "
             f"{jax.default_backend()!r}); model code calls attention(), "
             "tests pass interpret=True"
         )
-    return _flash_attention(q, k, v, causal, interpret)
+    L = q.shape[1]
+    tiles = tuple(tiles or pick_tiles(L) or ())
+    if len(tiles) != 2 or L % tiles[0] or L % tiles[1]:
+        raise ValueError(f"no tile of {tiles or TILE_LADDER} divides L={L}")
+    return _flash_attention(q, k, v, causal, interpret, tiles)
 
 
 # check_against_reference's bound on max|kernel - ref| / max|ref|: bf16
@@ -393,41 +461,48 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0):
     return errors
 
 
-# Auto-engage threshold: estimated bytes of the materialized scores
-# (+backward copies) beyond which XLA's [L,L] path approaches the
-# 16G HBM and the O(L*D) kernels take over — the kernels are meant as
-# the long-context ENABLER, not a short-sequence speedup. Where the
-# crossover in time and the out-of-memory length sit on this machine
-# is not measured (ROADMAP S5).
-FLASH_SCORE_BYTES = 6e9
+# The shortest sequence the dispatcher hands to the kernels: where
+# they first beat XLA's path, forward and backward together on the same
+# bf16 inputs inside one program. Measured on one TPU v5e chip ("TPU v5
+# lite", jax 0.9.0, libtpu 0.0.34) on 2026-09-29, ms a call, XLA |
+# kernels at the ladder's tiles:
+#   (2, 1024, 12, 64) 0.258 | 0.398    (2, 1024, 16, 128) 0.446 | 0.542
+#   (2, 2048, 12, 64) 2.713 | 1.231    (2, 2048, 16, 128) 3.677 | 1.738
+#   (2, 4096, 12, 64) 10.24 | 4.161    (2, 4096, 16, 128) 13.86 | 5.821
+# At 1024 and under XLA keeps a head's scores on the chip and wins (512:
+# 0.079 | 0.166, 0.104 | 0.234); from 2048 it writes them out, ten times
+# the time for four times the work. Lengths between the two are not
+# measured and stay with XLA.
+FLASH_MIN_LENGTH = 2048
 
 
 def attention(q, k, v, causal: bool = True, scale=None):
     """Dispatcher, the single entry point for model code.
 
-    On TPU the Pallas kernels engage automatically when the estimated
-    quadratic working set of XLA's materializing path would crowd HBM
-    (see FLASH_SCORE_BYTES); otherwise XLA's fused attention runs.
+    On a TPU the Pallas kernels take a call whose sequence the tile
+    ladder divides and that is at least FLASH_MIN_LENGTH long, at
+    either head width measured (the rule reads nothing but the call's
+    own shapes); XLA's attention takes the rest.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
-    EDL_TPU_FLASH=0 forces them off. Numerics are identical either way
-    (tests/test_flash_attention.py). The kernels know one head width
-    and the scale 1/sqrt(D): values of another width than the queries
-    (latent attention) or a `scale` of the caller's never reach them,
-    whatever the flag says."""
+    EDL_TPU_FLASH=0 forces them off. The kernels hold their scores in
+    float32 where XLA's path rounds them to the inputs' dtype
+    (tests/test_flash_attention.py holds both to the float32 math).
+    The kernels know one head width and the scale 1/sqrt(D): values of
+    another width than the queries (latent attention) or a `scale` of
+    the caller's never reach them, whatever the flag says."""
     import os
 
     from elasticdl_tpu.common.constants import ENV_TPU_FLASH
 
-    b, L, h, _d = q.shape
+    L = q.shape[1]
     flag = os.environ.get(ENV_TPU_FLASH)
     kernel_shapes = scale is None and v.shape[-1] == q.shape[-1]
     if (
         kernel_shapes
         and jax.default_backend() == "tpu"
-        and L % BLOCK == 0
+        and pick_tiles(L) is not None
         and flag != "0"
+        and (flag == "1" or L >= FLASH_MIN_LENGTH)
     ):
-        score_bytes = 2.5 * b * h * L * L * 2  # bf16 probs, fwd+bwd copies
-        if flag == "1" or score_bytes > FLASH_SCORE_BYTES:
-            return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal)
     return reference_attention(q, k, v, causal, scale)

@@ -37,10 +37,10 @@ def ring_attention(
     """q, k, v: [B, Lc, H, D] local sequence chunks -> [B, Lc, H, D].
 
     With axis size 1 this degenerates to plain attention and delegates
-    to `ops.flash_attention.attention`: XLA's fused attention by
-    default, the Pallas O(L*D)-HBM kernel when EDL_TPU_FLASH=1 on TPU
-    (opt-in — see that module's dispatcher docstring for the measured
-    platform tradeoff). The ring path keeps the lax online-softmax
+    to `ops.flash_attention.attention`: on a TPU the Pallas O(L*D)-HBM
+    kernels from 2048 tokens on, XLA's attention under that (that
+    module's FLASH_MIN_LENGTH has the measured crossover;
+    EDL_TPU_FLASH=1/0 force either). The ring path keeps the lax online-softmax
     (its K/V blocks already never materialize the full score matrix).
     """
     sp = lax.axis_size(axis_name)

@@ -2670,8 +2670,9 @@ class Worker:
         call's arguments (donated ones too: only their shapes are
         read): jax then hands back the lowering and the executable of
         the call, so nothing is lowered, compiled or loaded twice.
-        `setup.scope_map` says what it cost and whether the names are
-        this trace's (`stale`: hlo_scopes.describe)."""
+        `setup.scope_map` says what it cost, how many Pallas kernels
+        the program holds under which scope (`kernels`), and whether
+        the names are this trace's (`stale`: hlo_scopes.describe)."""
         log_dir = os.environ.get(ENV_WORKER_LOG_DIR, "")
         if not log_dir or args is None:
             return
@@ -2682,6 +2683,7 @@ class Worker:
                 record = hlo_scopes.describe(lowered, lowered.compile())
                 info["instructions"] = record["count"]
                 info["named"] = len(record["instructions"])
+                info["kernels"] = record["kernels"]
                 info["temp_bytes"] = record["memory"].get("temp")
                 info["argument_bytes"] = record["memory"].get("argument")
                 info["stale"] = record["stale"]

@@ -13,8 +13,25 @@ from elasticdl_tpu.ops.flash_attention import (
     BLOCK,
     attention,
     flash_attention,
+    pick_tiles,
     reference_attention,
 )
+
+# (L, (q edge, k edge)): None = what `pick_tiles` gives. At L = 512 a
+# 256 x 128 pair has tiles the diagonal crosses off-centre ((1, 3)),
+# skipped ones ((0, 2)) and fully visible ones ((1, 1)); 128 x 256 the
+# same with the long edge along the keys.
+TILE_CASES = [
+    (2 * BLOCK, None),
+    (4 * BLOCK, (256, 128)),
+    (4 * BLOCK, (128, 256)),
+    (4 * BLOCK, (256, 256)),
+    (4 * BLOCK, (512, 128)),
+]
+TILE_IDS = [
+    f"L{L}-" + ("ladder" if t is None else f"q{t[0]}k{t[1]}")
+    for L, t in TILE_CASES
+]
 
 
 def _qkv(b=2, L=2 * BLOCK, h=2, d=32, dtype=jnp.float32, seed=0):
@@ -26,11 +43,53 @@ def _qkv(b=2, L=2 * BLOCK, h=2, d=32, dtype=jnp.float32, seed=0):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_kernel_matches_reference(causal):
-    q, k, v = _qkv()
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
+@pytest.mark.parametrize("L, tiles", TILE_CASES, ids=TILE_IDS)
+def test_kernel_matches_reference(causal, L, tiles):
+    q, k, v = _qkv(b=1, L=L)
+    out = flash_attention(q, k, v, causal=causal, interpret=True, tiles=tiles)
     ref = reference_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [32, 64, 128])
+@pytest.mark.parametrize("L, tiles", TILE_CASES[1:], ids=TILE_IDS[1:])
+def test_every_kind_of_tile_matches_reference(L, tiles, d):
+    """Output and the three gradients, against a generic cotangent, on
+    tile pairs that put a crossed, a skipped and a fully visible tile
+    into each kernel's sweep; d = 128 is the width read in place (no
+    fold through memory), 32 and 64 the folded ones."""
+    q, k, v = _qkv(b=1, L=L, h=2, d=d, seed=7)
+    w = _qkv(b=1, L=L, h=2, d=d, seed=8)[0]
+
+    def through(attn):
+        def loss(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o * w), o
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o), grads = through(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True, tiles=tiles)
+    )(q, k, v)
+    (_, o_ref), grads_ref = through(reference_attention)(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for got, want in zip(grads, grads_ref):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=5e-5
+        )
+
+
+@pytest.mark.parametrize("L, want", [
+    (128, (128, 128)), (384, (128, 128)), (2048, (1024, 1024)),
+    (16384, (1024, 1024)), (96, None),
+])
+def test_the_ladder_picks_a_tile_or_leaves_the_length_to_the_fallback(L, want):
+    """The largest edge of the ladder that divides L, under each
+    edge's cap; a length that not even BLOCK divides has no tile and
+    `attention` falls back (test_dispatcher_falls_back_off_tpu)."""
+    assert pick_tiles(L) == want
+    if want:
+        assert L % want[0] == 0 and L % want[1] == 0
 
 
 def test_kernel_matches_reference_bf16():
@@ -60,8 +119,11 @@ def test_multi_block_causality():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("L", [BLOCK, 3 * BLOCK])
-def test_gradients_match_reference(causal, L):
+@pytest.mark.parametrize("L, tiles", [
+    (BLOCK, None), (3 * BLOCK, None), (4 * BLOCK, (256, 128)),
+    (4 * BLOCK, (128, 256)),
+], ids=["L128", "L384", "L512-q256k128", "L512-q128k256"])
+def test_gradients_match_reference(causal, L, tiles):
     """The Pallas backward kernels (dq; dk+dv, lse residuals) against
     grad-of-reference-math, across block counts and causality — the
     multi-block causal case exercises the triangular loop bounds of
@@ -69,7 +131,11 @@ def test_gradients_match_reference(causal, L):
     q, k, v = _qkv(b=1, L=L, h=2, d=16, seed=3)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, interpret=True) ** 2)
+        return jnp.sum(
+            flash_attention(
+                q, k, v, causal=causal, interpret=True, tiles=tiles
+            ) ** 2
+        )
 
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
@@ -158,3 +224,48 @@ def test_values_of_another_width_or_a_scale_never_reach_the_kernels(
     if forced:  # the same call with one width and no scale still takes them
         fa.attention(q, k, k, causal=True)
         assert len(called) == 1
+
+
+@pytest.mark.parametrize("case, q_shape, v_width, scale, flag, kernels", [
+    ("dense-lm-2048", (2, 2048, 12, 64), 64, None, None, True),
+    ("looped-lm-2048", (2, 2048, 16, 128), 128, None, None, True),
+    ("long-4096", (1, 4096, 8, 64), 64, None, None, True),
+    ("xla-wins-at-1024", (2, 1024, 12, 64), 64, None, None, False),
+    ("xla-wins-at-512", (2, 512, 16, 128), 128, None, None, False),
+    ("unmeasured-1536-stays", (1, 1536, 8, 64), 64, None, None, False),
+    ("forced-on-at-1024", (2, 1024, 12, 64), 64, None, "1", True),
+    ("forced-off-at-2048", (2, 2048, 12, 64), 64, None, "0", False),
+    ("latent-192-over-128", (4, 2048, 16, 192), 128, None, None, False),
+    ("own-scale", (2, 2048, 16, 128), 128, 0.05, None, False),
+    ("no-tile-divides-96", (2, 96, 12, 64), 64, None, "1", False),
+    ("no-tile-divides-2080", (1, 2080, 8, 64), 64, None, None, False),
+])
+def test_the_rule_reads_shapes_and_engages_where_the_chip_said(
+    monkeypatch, case, q_shape, v_width, scale, flag, kernels
+):
+    """On a TPU `attention` hands a call to the kernels from what it
+    sees in its arguments: a length the ladder divides, at least
+    FLASH_MIN_LENGTH (measured: XLA wins at 1024, the kernels at 2048,
+    at both head widths), one head width and no scale of the caller's.
+    Traced only (`eval_shape`): nothing of these sizes is computed."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    assert fa.FLASH_MIN_LENGTH == 2048
+    assert not hasattr(fa, "FLASH_SCORE_BYTES")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if flag is None:
+        monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+    else:
+        monkeypatch.setenv("EDL_TPU_FLASH", flag)
+    called = []
+    monkeypatch.setattr(
+        fa, "flash_attention", lambda *a, **kw: called.append(a) or a[2]
+    )
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((*q_shape[:3], v_width), jnp.bfloat16)
+    out = jax.eval_shape(
+        lambda q, k, v: fa.attention(q, k, v, causal=True, scale=scale),
+        q, q, v,
+    )
+    assert out.shape == v.shape
+    assert bool(called) is kernels, case

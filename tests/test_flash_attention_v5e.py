@@ -1,0 +1,68 @@
+"""The three Pallas kernels compiled for a TPU v5e that is described,
+not attached (docs/performance.md): what Mosaic refuses (a block that
+breaks the tiling rule, more VMEM than a kernel may take) is refused
+here, at the shapes the benchmark's cells run, at no chip time. Nothing
+executes. The topology is described inside a fixture, in this file
+alone: one process at a time may load the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from elasticdl_tpu.obs import hlo_scopes
+from elasticdl_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape", [
+    (2, 2048, 12, 64),  # lm-dense-160m: folded, 1024 x 1024 tiles
+    (2, 2048, 16, 128),  # ouro-2.6b: read in place
+    (1, 16384, 8, 64),  # the long sequence of test_cluster_gated.py
+    (1, 384, 2, 64),  # a length only BLOCK divides
+    (1, 2048, 4, 256),  # the widest head: the tiles' VMEM at its largest
+], ids=lambda s: "x".join(map(str, s)))
+def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, w):
+        with jax.named_scope("attention"):
+            o = fa._flash_attention(
+                q, k, v, True, False, fa.pick_tiles(shape[1])
+            )
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x, x).compile()
+    )
+    # forward, dq, dk+dv, each under the scope it was traced in
+    assert hlo_scopes.kernels(compiled.as_text()) == {"attention": 3}
+    # nothing quadratic in L is set aside: O(L*D) residuals and copies
+    b, L, h, d = shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 20 * b * L * h * max(d, 128)

@@ -217,3 +217,42 @@ def test_the_dense_lm_s_program_names_its_blocks_and_is_the_unscoped_one(
     assert scoped["count"] == bare["count"]
     assert len(scoped["instructions"]) == len(bare["instructions"])
     assert scoped["memory"] == bare["memory"]
+
+
+# the v5e compiler's text round a Pallas kernel, cut to what is read:
+# a forward call inside a scanned layer, its two backward calls, a
+# kernel outside every scope, and a plain fusion that is no kernel
+_KERNEL_HLO = """\
+HloModule jit_window
+
+%fused_computation (p: bf16[24,2048,64]) -> bf16[24,2048,64] {
+  ROOT %multiply.1 = bf16[24,2048,64]{2,1,0} multiply(%p, %p), metadata={op_name="jit(window)/while/body/jvp(attention)/mul"}
+}
+
+ENTRY %main {
+  %fusion.7 = bf16[24,2048,64]{2,1,0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(window)/while/body/jvp(attention)/mul"}
+  %attention.30 = (bf16[24,2048,64]{2,1,0:T(8,128)(2,1)}, f32[24,2048,1]{2,1,0:T(8,128)}) custom-call(%fusion.7, %k, %v), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[24,2048,64]{2,1,0}}, frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(window)/while/body/jvp(attention)/pallas_call" stack_frame_id=10}, backend_config={"custom_call_config": {"body": "TUxJUg=="}}
+  %attention.31 = bf16[24,2048,64]{2,1,0} custom-call(%q, %k, %v), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(window)/while/body/transpose(jvp(attention))/pallas_call" stack_frame_id=2}
+  %attention.32 = (bf16[24,2048,64]{2,1,0}, bf16[24,2048,64]{2,1,0}) custom-call(%q, %k, %v), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(window)/while/body/transpose(jvp(attention))/pallas_call" stack_frame_id=2}
+  %pallas_call.4 = f32[8,128]{1,0} custom-call(%y), custom_call_target="tpu_custom_call", metadata={op_name="jit(window)/jit(helper)/pallas_call"}
+  ROOT %sort.2 = f32[8]{0} custom-call(%z), custom_call_target="SomeOtherTarget", metadata={op_name="jit(window)/optimizer/sort"}
+}
+"""
+
+
+def test_the_map_counts_a_program_s_pallas_kernels_by_their_scope(
+    monkeypatch,
+):
+    """`kernels`: Mosaic's custom calls under the innermost scope of
+    their `op_name`, `jvp(...)` and `transpose(...)` looked through;
+    other custom calls and plain fusions are not kernels; a program
+    without one reads none (the dense LM's window on the CPU, where
+    `attention` never takes the kernels)."""
+    assert hlo_scopes.kernels(_KERNEL_HLO) == {"attention": 3, "": 1}
+    assert hlo_scopes.op_names(_KERNEL_HLO)["attention.30"].endswith(
+        "jvp(attention)/pallas_call"
+    )
+    assert hlo_scopes.kernels("HloModule empty\n") == {}
+    record = _lm_window_record(monkeypatch, scoped=True)
+    assert record["kernels"] == {}
+    assert sum(record["kernels"].values()) == 0
