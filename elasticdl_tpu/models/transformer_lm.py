@@ -106,10 +106,59 @@ class TransformerConfig:
     n_shared_experts: int = 0
     held_experts: Optional[Tuple[int, int]] = None
     routed_scaling: float = 1.0
+    # how the top-k layer scores and gates: "softmax" over all experts
+    # with the chosen probabilities as gates, or "sigmoid" (each
+    # expert's own score; the k largest of score + `router_bias`, a
+    # leaf no gradient reaches, are chosen and the bias is not in the
+    # gate). `moe_renormalize` divides the chosen scores by their sum.
+    # `aux_weight` 0 leaves the balance term out of the loss
+    moe_score: str = "softmax"
+    moe_renormalize: bool = False
+    # False: latent attention turns nothing (`mla_use_nope`): the
+    # shared key columns and the queries' last `qk_rope_dim` stay as
+    # projected
+    mla_rope: bool = True
+    # the mixer of every layer of the stack, dense layers first:
+    # "mha", "mla" or "kda"; None = `attention` in every layer. Layers
+    # that follow each other with one mixer and one kind of MLP are
+    # one scanned run
+    layer_types: Optional[Tuple[str, ...]] = None
+    # "kda": gated delta-rule linear attention (Kimi Delta Attention):
+    # `kda_heads` heads of `kda_head_dim` (keys and values alike), a
+    # causal depthwise convolution of `kda_conv` taps on q, k and v, a
+    # per-channel decay and an output gate through low-rank pairs of
+    # `kda_head_dim`, the recurrence in chunks of `kda_chunk` tokens
+    # (`ops/kda.py`)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        return tuple(self.layer_types or (self.attention,) * self.n_layers)
+
+    @property
+    def runs(self) -> Tuple[Tuple[str, bool, int], ...]:
+        """The stack as scanned runs: (mixer, expert layer?, layers)."""
+        runs = []
+        for i, mixer in enumerate(self.mixers):
+            kind = (mixer, bool(self.n_experts) and i >= self.n_dense_layers)
+            if runs and runs[-1][:2] == kind:
+                runs[-1] = kind + (runs[-1][2] + 1,)
+            else:
+                runs.append(kind + (1,))
+        return tuple(runs)
+
+    @property
+    def mixed(self) -> bool:
+        """More than one mixer: the runs lie under `params["stack"]`,
+        not under "dense" and "layers"."""
+        return len(set(self.mixers)) > 1
 
     @property
     def looped(self) -> bool:
@@ -173,6 +222,19 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
     return scale
 
 
+# a run's leaves that the forward pass reads as stored (float32) and not
+# as cast to the compute dtype
+_FLOAT32_LEAVES = ("router", "router_bias", "dt_bias")
+# how a routed stack's stats, one a layer, become one number a step; a
+# stat without a rule here is a KeyError when the program is traced
+_OVER_LAYERS = {
+    "held_share": jnp.mean,
+    "router_entropy": jnp.mean,
+    "router_bias_absmax": jnp.max,
+    "kda_log_decay_min": jnp.min,
+}
+
+
 def _remat(body, cfg: "TransformerConfig"):
     """Per-layer rematerialization with the configured policy."""
     if cfg.remat_policy == "dots":
@@ -194,8 +256,11 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
         return (rng.standard_normal(shape) * scale).astype(np.float32)
 
     L, d, hd = cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim
-    if cfg.moe_top_k or cfg.attention == "mla" or cfg.n_dense_layers:
-        return _init_routed_params(norm, cfg)
+    if (
+        cfg.moe_top_k or cfg.n_dense_layers or cfg.layer_types
+        or cfg.attention in ("mla", "kda")
+    ):
+        return _init_routed_params(norm, rng, cfg)
     layers = {
         "ln1": np.ones((L, d), np.float32),
         "wq": norm(L, d, hd),
@@ -233,20 +298,26 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
     return params
 
 
-def _init_routed_params(norm, cfg: TransformerConfig) -> Dict:
-    """The stack that is not of one shape: `n_dense_layers` layers with
-    the dense gated MLP under "dense", then the expert layers under
-    "layers", each stacked on its own leading dim; latent attention in
-    both."""
-    d, n_dense = cfg.d_model, cfg.n_dense_layers
-    if not (cfg.moe_top_k and cfg.attention == "mla" and cfg.mlp == "swiglu"):
+def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
+    """The stack that is not of one shape: one stacked tree a run of
+    `cfg.runs`, each on its own leading dim. With one mixer throughout
+    that is `n_dense_layers` layers with the dense gated MLP under
+    "dense", then the expert layers under "layers"; with mixers that
+    differ the runs lie in order under "stack"."""
+    d = cfg.d_model
+    if not (
+        cfg.moe_top_k and cfg.mlp == "swiglu"
+        and set(cfg.mixers) <= {"mla", "kda"}
+        and len(cfg.mixers) == cfg.n_layers
+    ):
         raise NotImplementedError(
-            "the routed stack is built with latent attention, top-k "
-            "experts and SwiGLU MLPs together (attention='mla', "
+            "the routed stack is built with latent attention or "
+            "delta-rule attention, top-k experts and SwiGLU MLPs together "
+            "(attention / layer_types of 'mla' and 'kda', one a layer, "
             "moe_top_k > 0, mlp='swiglu')"
         )
 
-    def block(L):
+    def mla(L):
         heads, rank = cfg.n_heads, cfg.kv_lora_rank
         return {
             "ln1": np.ones((L, d), np.float32),
@@ -258,26 +329,82 @@ def _init_routed_params(norm, cfg: TransformerConfig) -> Dict:
             "ln2": np.ones((L, d), np.float32),
         }
 
-    dense = block(n_dense)
-    dense.update(
-        wg=norm(n_dense, d, cfg.d_ff), wu=norm(n_dense, d, cfg.d_ff),
-        wd=norm(n_dense, cfg.d_ff, d),
-    )
-    L, (_first, held) = cfg.n_layers - n_dense, cfg.held
+    def kda(L):
+        heads, hd, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+        wide = heads * hd
+
+        def conv():  # a tap's weights; the taps sum like a fan-in
+            return norm(L, taps, wide, scale=1.0 / math.sqrt(taps))
+
+        block = {
+            "ln1": np.ones((L, d), np.float32),
+            "wq": norm(L, d, wide), "wk": norm(L, d, wide),
+            "wv": norm(L, d, wide),
+            "conv_q": conv(), "conv_k": conv(), "conv_v": conv(),
+            "f_down": norm(L, d, hd), "f_up": norm(L, hd, wide),
+            "wbeta": norm(L, heads, d, scale=1.0 / math.sqrt(d)),  # [heads, d]
+            "g_down": norm(L, d, hd), "g_up": norm(L, hd, wide),
+            "wo": norm(L, wide, d),
+        }
+        # the family's convention: the decay's step softplus(dt_bias)
+        # uniform in (0.001, 0.1); its rate is `kda_a_log`, below
+        dt = rng.uniform(0.001, 0.1, (L, wide))
+        block.update(
+            dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            o_norm=np.ones((L, hd), np.float32),
+            ln2=np.ones((L, d), np.float32),
+        )
+        return block
+
+    (_first, held) = cfg.held
     f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
-    layers = block(L)
-    layers.update(
-        router=norm(L, d, cfg.n_experts),
-        eg=norm(L, held, d, f), eu=norm(L, held, d, f), ed=norm(L, held, f, d),
-        sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d),
-    )
-    return {
+
+    def run(mixer, experts, L):
+        tree = mla(L) if mixer == "mla" else kda(L)
+        if experts:
+            tree.update(
+                router=norm(L, d, cfg.n_experts),
+                eg=norm(L, held, d, f), eu=norm(L, held, d, f),
+                ed=norm(L, held, f, d),
+                sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d),
+            )
+            if cfg.moe_score == "sigmoid":
+                tree["router_bias"] = np.zeros((L, cfg.n_experts), np.float32)
+        else:
+            tree.update(
+                wg=norm(L, d, cfg.d_ff), wu=norm(L, d, cfg.d_ff),
+                wd=norm(L, cfg.d_ff, d),
+            )
+        return tree
+
+    if cfg.mixed:
+        stack = {"stack": [run(*r) for r in cfg.runs]}
+    else:
+        n_dense = cfg.n_dense_layers
+        stack = {
+            "dense": run(cfg.mixers[0], False, n_dense),
+            "layers": run(cfg.mixers[0], True, cfg.n_layers - n_dense),
+        }
+    params = {
         "embed": norm(cfg.vocab, d, scale=0.02),
-        "dense": dense,
-        "layers": layers,
+        **stack,
         "ln_f": np.ones((d,), np.float32),
         "head": norm(d, cfg.vocab),
     }
+    n_kda = cfg.mixers.count("kda")
+    if n_kda:
+        # the decay's rate exp(a_log), uniform in (1, 16): ONE flat leaf
+        # for all KDA layers, in stack order, [KDA layers x heads]. No
+        # leaf of this tree ends in a dim of 32 (`wbeta` is stored
+        # [heads, d] for the same reason): the v5e compiler reads such
+        # a leaf out of the flat parameter vector by viewing the WHOLE
+        # vector as [n / 32, 32], which its (8, 128) tiles pad fourfold
+        # (8.98 GB for the 2.41 GB of Kimi-Linear's cut, and half of
+        # that again for the bfloat16 copy)
+        params["kda_a_log"] = np.log(
+            rng.uniform(1.0, 16.0, (n_kda * cfg.kda_heads,))
+        ).astype(np.float32)
+    return params
 
 
 def param_partition_specs(cfg: TransformerConfig) -> Dict:
@@ -319,12 +446,13 @@ def _require_mesh_support(cfg: TransformerConfig):
     if (
         cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped
         or cfg.attention != "mha" or cfg.n_dense_layers or cfg.moe_top_k
+        or cfg.layer_types
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
             "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
-            "attention='mla', n_dense_layers and moe_top_k exist on the "
-            "unsharded path (plain_forward) only"
+            "attention='mla' and 'kda', layer_types, n_dense_layers and "
+            "moe_top_k exist on the unsharded path (plain_forward) only"
         )
 
 
@@ -549,7 +677,8 @@ def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
     (x[i], x[i + D/2]); the released modelling file stores them
     interleaved and permutes to this layout before it turns them, so
     with weights of one's own the two differ by a fixed permutation of
-    the turning columns of `wq` and `wkva`."""
+    the turning columns of `wq` and `wkva`. With `mla_rope` off
+    nothing turns: those columns enter the scores as projected."""
     from elasticdl_tpu.ops.flash_attention import attention
 
     b, l, _ = x.shape
@@ -560,8 +689,10 @@ def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
     kva = x @ lp["wkva"]  # [B, L, rank + rot]
     latent = rms_norm(kva[..., :rank], lp["kv_norm"], cfg.norm_eps)
     kv = (latent @ lp["wkvb"]).reshape(b, l, heads, nope + cfg.v_head_dim)
-    q_pe = _rope(q[..., nope:], positions, freqs=freqs)
-    k_pe = _rope(kva[..., None, rank:], positions, freqs=freqs)  # one head
+    q_pe, k_pe = q[..., nope:], kva[..., None, rank:]  # k_pe: one head
+    if cfg.mla_rope:
+        q_pe = _rope(q_pe, positions, freqs=freqs)
+        k_pe = _rope(k_pe, positions, freqs=freqs)
     q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_pe, (b, l, heads, rot))], axis=-1
@@ -570,6 +701,57 @@ def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
         q, k, kv[..., nope:], causal=True, scale=mla_softmax_scale(cfg)
     )
     return out.reshape(b, l, heads * cfg.v_head_dim) @ lp["wo"]
+
+
+def _causal_conv(x: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
+    """Depthwise over time, then SiLU: y_t = silu(sum_i taps[i] *
+    x_{t - (n - 1) + i}), zeros before the sequence's start. x
+    [B, L, C], taps [n, C]."""
+    n, length = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
+    return jax.nn.silu(
+        sum(padded[:, i:i + length] * taps[i] for i in range(n))
+    )
+
+
+def _kda(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
+    """Kimi Delta Attention on the normed x [B, L, d] -> ([B, L, d],
+    the most negative cumulative log-decay inside a chunk). q, k and v
+    are projected, convolved over time and SiLU'd; per head q and k are
+    scaled to unit length (q by d^-1/2 more); the log-decay per key
+    channel is -exp(a_log) softplus(f_up f_down x + dt_bias) and the
+    write strength sigmoid(wbeta x); the recurrence is `ops/kda.py`'s;
+    its output is RMS-normed per head, gated by sigmoid(g_up g_down x)
+    and projected. `a_log` and `dt_bias` are the float32 leaves; decay,
+    lengths and the recurrence are float32 whatever `cfg.dtype` is."""
+    from elasticdl_tpu.ops.kda import kda_chunked
+
+    b, l, _ = x.shape
+    heads, hd = cfg.kda_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+
+    def per_head(y):
+        return y.reshape(b, l, heads, hd)
+
+    with jax.named_scope("conv"):
+        q, k, v = (
+            per_head(_causal_conv(x @ lp[w], lp[c])).astype(f32)
+            for w, c in (("wq", "conv_q"), ("wk", "conv_k"), ("wv", "conv_v"))
+        )
+        q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-12) * hd**-0.5
+        k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-12)
+    with jax.named_scope("gates"):
+        step = jax.nn.softplus(
+            ((x @ lp["f_down"]) @ lp["f_up"]).astype(f32) + lp["dt_bias"].astype(f32)
+        )
+        g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * per_head(step)
+        beta = jax.nn.sigmoid((x @ lp["wbeta"].T).astype(f32))
+        gate = jax.nn.sigmoid(per_head((x @ lp["g_down"]) @ lp["g_up"]))
+    with jax.named_scope("scan"):
+        o, log_decay_min = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+    with jax.named_scope("out"):
+        o = rms_norm(o, lp["o_norm"].astype(f32), cfg.norm_eps).astype(x.dtype)
+        return (o * gate).reshape(b, l, heads * hd) @ lp["wo"], log_decay_min
 
 
 def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
@@ -629,40 +811,45 @@ def plain_forward_stats(
     eps = cfg.norm_eps
     routed = bool(cfg.moe_top_k)
 
-    def attend(lp, x):
-        if cfg.attention == "mla":
-            return _mla(cfg, lp, x, positions)
+    def attend(mixer, lp, x):
+        """-> (the mixer's output, its stats)."""
+        if mixer == "mla":
+            return _mla(cfg, lp, x, positions), {}
+        if mixer == "kda":
+            out, log_decay_min = _kda(cfg, lp, x)
+            return out, {"kda_log_decay_min": log_decay_min}
         q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
         k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
         v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
         q = _rope(q, positions, cfg.rope_base)
         k = _rope(k, positions, cfg.rope_base)
-        return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
+        return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"], {}
 
-    def layer(experts: bool):
-        """The scanned body of a layer with the dense MLP, or with the
-        configuration's expert layer in its place."""
+    def layer(mixer: str, experts: bool):
+        """The scanned body of a layer with `mixer` and the dense MLP,
+        or the configuration's expert layer in its place."""
 
         def body(carry, lp):
             h, aux = carry
-            stats = {}
-            with jax.named_scope(
-                "mla" if cfg.attention == "mla" else "attention"
-            ):
-                out = attend(lp, rms_norm(h, lp["ln1"], eps))
+            with jax.named_scope("attention" if mixer == "mha" else mixer):
+                out, stats = attend(mixer, lp, rms_norm(h, lp["ln1"], eps))
                 if cfg.sandwich_norm:
                     out = rms_norm(out, lp["ln1b"], eps)
                 h = h + out
             with jax.named_scope("moe" if experts and routed else "mlp"):
                 x = rms_norm(h, lp["ln2"], eps)
                 if experts and routed:
-                    out, a, stats = moe_topk_held(
+                    out, a, routing = moe_topk_held(
                         x, lp["router"],
                         (lp["eg"], lp["eu"], lp["ed"]),
                         (lp["sg"], lp["su"], lp["sd"]),
                         top_k=cfg.moe_top_k, held=cfg.held,
                         scaling=cfg.routed_scaling,
+                        score=cfg.moe_score, bias=lp.get("router_bias"),
+                        renormalize=cfg.moe_renormalize,
+                        balance=bool(cfg.aux_weight),
                     )
+                    stats = {**stats, **routing}
                 elif experts:
                     out, a = moe_ffn_local(
                         x.reshape(b * l, cfg.d_model),
@@ -687,28 +874,55 @@ def plain_forward_stats(
             return _remat(body, cfg)
         return body
 
-    expert_layers = params["layers"]
-    if routed:
-        # the router decides in float32 from float32 weights
-        expert_layers = {**expert_layers, "router": stored["layers"]["router"]}
+    def run_trees(tree):
+        if cfg.mixed:
+            return tree["stack"]
+        return ([tree["dense"]] if cfg.n_dense_layers else []) + [tree["layers"]]
+
+    # what decides in float32 reads the float32 leaves, not their casts:
+    # the router (and its selection bias), the decay's rate and step
+    trees = [
+        {**cast, **{k: kept[k] for k in _FLOAT32_LEAVES if k in kept}}
+        for cast, kept in zip(run_trees(params), run_trees(stored))
+    ]
+    runs = [r for r in cfg.runs if r[2]]
+    if "kda" in cfg.mixers:  # their rates lie in one leaf, in stack order
+        # behind a barrier: the compiler otherwise moves this reshape
+        # to rows of 32 in front of the slice that cuts the leaf out of
+        # the flat parameter vector, and views the whole vector so
+        # (`_init_routed_params`)
+        a_log = lax.optimization_barrier(stored["kda_a_log"]).reshape(
+            -1, cfg.kda_heads
+        )
+        seen = 0
+        for (mixer, _experts, layers), tree in zip(runs, trees):
+            if mixer == "kda":
+                tree["a_log"] = a_log[seen:seen + layers]
+                seen += layers
 
     def stack(carry):
-        if cfg.n_dense_layers:
-            carry, _ = lax.scan(layer(False), carry, params["dense"])
-        (h, aux), stats = lax.scan(
-            layer(bool(cfg.n_experts)), carry, expert_layers
-        )
+        gathered = {}
+        for (mixer, experts, _layers), tree in zip(runs, trees):
+            carry, stats = lax.scan(layer(mixer, experts), carry, tree)
+            for name, value in stats.items():
+                gathered.setdefault(name, []).append(value)
+        h, aux = carry
         with jax.named_scope("head"):
-            return rms_norm(h, params["ln_f"], eps), aux, stats
+            return rms_norm(h, params["ln_f"], eps), aux, {
+                name: jnp.concatenate(values)
+                for name, values in gathered.items()
+            }
 
     carry = (h, jnp.zeros((), dtype=jnp.float32 if routed else h.dtype))
     if not cfg.looped:
         h, aux, stats = stack(carry)
         if routed:
             stats = {
-                "expert_tokens": stats["expert_tokens"],
-                "held_share": jnp.mean(stats["held_share"]),
-                "router_entropy": jnp.mean(stats["router_entropy"]),
+                "expert_tokens": stats.pop("expert_tokens"),
+                **{
+                    name: _OVER_LAYERS[name](value)
+                    for name, value in stats.items()
+                },
             }
         with jax.named_scope("head"):
             return h @ params["head"], aux, stats

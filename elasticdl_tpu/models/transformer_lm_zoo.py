@@ -56,16 +56,19 @@ class TransformerLM:
             }
         if self.cfg.moe_top_k:
             layers = self.cfg.n_layers - self.cfg.n_dense_layers
-            return {
-                "params": params,
-                WINDOW_STATS: {
-                    "expert_tokens": np.zeros(
-                        (layers, self.cfg.held[1]), np.float32
-                    ),
-                    "held_share": np.zeros((), np.float32),
-                    "router_entropy": np.zeros((), np.float32),
-                },
+            stats = {
+                "expert_tokens": np.zeros(
+                    (layers, self.cfg.held[1]), np.float32
+                ),
+                "held_share": np.zeros((), np.float32),
+                "router_entropy": np.zeros((), np.float32),
             }
+            # what a layer kind adds to the routing's three
+            if self.cfg.moe_score == "sigmoid":
+                stats["router_bias_absmax"] = np.zeros((), np.float32)
+            if "kda" in self.cfg.mixers:
+                stats["kda_log_decay_min"] = np.zeros((), np.float32)
+            return {"params": params, WINDOW_STATS: stats}
         return {"params": params}
 
     def apply(self, variables, tokens, mutable=None):

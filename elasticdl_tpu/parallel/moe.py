@@ -144,6 +144,31 @@ def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int):
     return probs, gate, chosen.astype(jnp.int32)
 
 
+def route_sigmoid_topk(
+    x: jnp.ndarray, router_w: jnp.ndarray, bias, top_k: int,
+    renormalize: bool,
+):
+    """Each expert's own score, s = sigmoid(x W) in float32 (a
+    `HIGHEST` product, as `route_topk`'s), then the `top_k` largest of
+    s + `bias` ([E], a selection bias no gradient reaches; None = 0).
+    The gates are the chosen experts' s, the bias not in them, and with
+    `renormalize` divided by their sum over all `top_k` chosen, held
+    here or not. -> (s [T, E], gate [T, k], chosen [T, k] int32)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    biased = scores if bias is None else scores + lax.stop_gradient(
+        bias.astype(jnp.float32)
+    )
+    _, chosen = lax.top_k(biased, top_k)
+    gate = jnp.take_along_axis(scores, chosen, axis=-1)
+    if renormalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return scores, gate, chosen.astype(jnp.int32)
+
+
 def sequence_balance_loss(probs: jnp.ndarray, chosen: jnp.ndarray):
     """The sequence-wise balance term, before its weight: per sequence
     sum_e f_e P_e with f_e = E / (k s) x #{tokens of the sequence that
@@ -219,9 +244,19 @@ def moe_topk_held(
     top_k: int,
     held: Tuple[int, int],
     scaling: float = 1.0,
+    score: str = "softmax",
+    bias=None,
+    renormalize: bool = False,
+    balance: bool = True,
 ):
     """A top-k dropless expert layer that is told which experts it
     holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x).
+
+    `score` "softmax" routes by `route_topk`; "sigmoid" by
+    `route_sigmoid_topk` with the selection `bias` and, under
+    `renormalize`, gates that sum to one over a token's k experts
+    (then times `scaling`). `balance` False leaves the balance term out
+    (0): a layer balanced by its selection bias has none in its loss.
 
     x [B, S, d]; router_w [d, E] over ALL E experts; `experts` the
     stacked SwiGLU weights (wg [n, d, f], wu [n, d, f], wd [n, f, d])
@@ -249,7 +284,12 @@ def moe_topk_held(
     wg, wu, wd = experts
     xf = x.reshape(t, d)
     with jax.named_scope("route"):
-        probs, gate, chosen = route_topk(xf, router_w, top_k)
+        if score == "softmax":
+            probs, gate, chosen = route_topk(xf, router_w, top_k)
+        else:
+            probs, gate, chosen = route_sigmoid_topk(
+                xf, router_w, bias, top_k, renormalize
+            )
         local = chosen - first
         here = (local >= 0) & (local < n)
         # a stable sort on (held group, else n) keeps token order
@@ -278,17 +318,21 @@ def moe_topk_held(
             * picked.reshape(t, top_k, d).astype(jnp.float32),
             axis=1,
         ).astype(x.dtype)
-        balance = sequence_balance_loss(
+        balance_term = sequence_balance_loss(
             probs.reshape(b, s, -1), chosen.reshape(b, s, top_k)
-        )
+        ) if balance else jnp.zeros((), jnp.float32)
+        if score != "softmax":  # the entropy of the scores' shares
+            probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
         entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1)
         stats = {
             "expert_tokens": sizes.astype(jnp.float32),
             "held_share": jnp.sum(sizes) / jnp.float32(t * top_k),
             "router_entropy": jnp.mean(entropy),
         }
+        if bias is not None:
+            stats["router_bias_absmax"] = jnp.max(jnp.abs(bias))
     with jax.named_scope("shared"):
         y = routed + swiglu(xf, *shared)
-    return y.reshape(b, s, d), balance, jax.tree_util.tree_map(
+    return y.reshape(b, s, d), balance_term, jax.tree_util.tree_map(
         lax.stop_gradient, stats
     )

@@ -533,12 +533,22 @@ def _error_frame(e: grpc.RpcError) -> bytes:
     return _RESP_ERR.pack(1, code.value[0], len(detail_b)) + detail_b
 
 
+#: The most bytes one `sendmsg` or `recv_into` is asked to move. Linux
+#: moves at most 2^31 - 4096 a call whatever it is asked (MAX_RW_COUNT)
+#: and a frame may be longer (`MAX_FRAME_BYTES`; the first was a
+#: 2,409.7 MB delta), so a long frame leaves and arrives in turns. The
+#: limit is stated here, not left to the kernel, so that a test can
+#: lower it and walk a short frame through the same turns.
+MAX_CALL_BYTES = (1 << 31) - 4096
+
+
 def _recv_fill(conn: socket.socket, view, n: int, eof_ok: bool = False) -> bool:
-    """Fill view[:n] from the socket; False on a clean EOF before the
-    first byte (eof_ok), ConnectionError on EOF after it."""
+    """Fill view[:n] from the socket, at most `MAX_CALL_BYTES` a call;
+    False on a clean EOF before the first byte (eof_ok),
+    ConnectionError on EOF after it."""
     got = 0
     while got < n:
-        k = conn.recv_into(view[got:], n - got)
+        k = conn.recv_into(view[got:], min(n - got, MAX_CALL_BYTES))
         if k == 0:
             if eof_ok and got == 0:
                 return False
@@ -679,6 +689,21 @@ def _ask_socket_buffers(sock: socket.socket):
 _IOV_MAX = 1024
 
 
+def _cut_to(bufs, room: int):
+    """The front of `bufs` that holds `room` bytes, the last buffer
+    cut where they end. Walked only while more than a call's worth is
+    left of a frame: a turn of a shorter frame gathers its buffers as
+    they are (a per-step cell sends 270 of them in tens of turns)."""
+    turn = []
+    for buf in bufs:
+        if buf.nbytes >= room:
+            turn.append(buf[:room])
+            break
+        turn.append(buf)
+        room -= buf.nbytes
+    return turn
+
+
 def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
     """Write `head`, then a frame's parts in order, gathered by
     `sendmsg` from where they lie: no buffer the size of the frame,
@@ -687,7 +712,9 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
     kernel granted of `SOCKET_BUFFER_BYTES`; 208 KB where nothing was
     asked), so a long part leaves over many turns: what has left is
     dropped from the front and the rest gathered again, at most
-    `_IOV_MAX` buffers a turn. `deadline` (monotonic)
+    `_IOV_MAX` buffers and `MAX_CALL_BYTES` bytes a turn (a part
+    longer than that, a 2.4 GB delta, is cut for the call and goes on
+    from where the call left it). `deadline` (monotonic)
     is one budget over all the turns; with none the socket's own
     timeout stands (a server's connection blocks). The bytes on the socket are
     `head + b"".join(parts)`. (One `sendall` a long part, short parts
@@ -695,11 +722,15 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
     PERF.md, PR 30.)"""
     bufs = [memoryview(head)]
     bufs += [memoryview(part) for part in parts if len(part)]
-    i = 0
+    i, left = 0, sum(buf.nbytes for buf in bufs)
     while i < len(bufs):
         if deadline is not None:
             conn.settimeout(max(0.001, deadline - time.monotonic()))
-        sent = conn.sendmsg(bufs[i:i + _IOV_MAX])
+        turn = bufs[i:i + _IOV_MAX]
+        if left > MAX_CALL_BYTES:
+            turn = _cut_to(turn, MAX_CALL_BYTES)
+        sent = conn.sendmsg(turn)
+        left -= sent
         while sent:
             n = bufs[i].nbytes
             if sent < n:

@@ -340,6 +340,7 @@ class Worker:
         self._local_window_fn = None  # scanned whole-window step
         self._opt_state = None
         self._base_flat = None  # device copy of params at last sync
+        self._subtract_into_base = None  # jitted on the serial chain
         self._base_version = -1
         self._pending_steps = 0
         self._sync_thread = None  # tail of the chained async delta pushes
@@ -1893,8 +1894,7 @@ class Worker:
             return
         t_spawn = time.time()  # `worker.window_sync` starts here
         with self._first_call("jit_subtract"):
-            # own buffer, thread-safe
-            delta_dev = self._flat - self._base_flat
+            delta_dev = self._delta_from_base()
         wire_meta = None
         wire_form = None
         link_mbps = None
@@ -2243,6 +2243,33 @@ class Worker:
                 with self.timers.phase("sync_wait"):
                     with self._sync_exposed("backpressure"):
                         self._sync_inflight.popleft().join()
+
+    def _delta_from_base(self):
+        """flat - base, in a buffer of its own (the sync thread reads it
+        while the step loop goes on). On the serial chain the old base
+        is DONATED to the subtraction, so the delta lies where the base
+        lay and the new base (`jit_copy`, next) is the only buffer the
+        sync's moment adds: 20 B a parameter at that moment (flat, two
+        moments, delta, new base), not 24. Only there: with syncs in
+        flight the base may still be the snapshot an unsettled sync's
+        merged model is folded in against (`_base_snapshots`), and a
+        job takes one of the two forms for its whole life, so nothing
+        compiles after set-up."""
+        with self._report_lock:
+            held = any(
+                snap is self._base_flat
+                for snap in self._base_snapshots.values()
+            )
+        if self._max_inflight_syncs or held:
+            return self._flat - self._base_flat
+        if self._subtract_into_base is None:
+
+            def subtract(flat, base):  # the device trace's `jit_subtract`
+                return flat - base
+
+            self._subtract_into_base = jax.jit(subtract, donate_argnums=(1,))
+        base, self._base_flat = self._base_flat, None
+        return self._subtract_into_base(self._flat, base)
 
     @property
     def sync_decisions(self):
