@@ -58,6 +58,12 @@ intermediate of its own) and the inverse's (`unit_lower_inverse`: two
 products, no second substitution). The rest is jax's; the pass over
 the chunks recomputes a chunk's four products from the state the chunk
 started from, which is all it keeps.
+
+On a TPU, at head widths the lanes divide, `intra` runs as the Pallas
+kernels of `ops/kda_kernels.py` (`takes_kernels`: the same mathematics,
+a chunk's intermediates never leaving the chip); `intra_stage` below is
+the path of every other call and the kernels' oracle. The pass over the
+chunks is this file's `lax.scan` on either path.
 """
 
 from __future__ import annotations
@@ -232,38 +238,95 @@ def chunk_step(S, xs):
     return keep * S + _mm(k_out, W), _mm(q_in, S) + _mm(Bqk, W)
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
+def intra_stage(q, k, v, g, beta, sub: int):
+    """Everything of a chunk that does not read the carried state, for
+    all chunks at once, in plain jax: q, k, g [..., C, dk], v
+    [..., C, dv], beta [..., C, 1] float32 -> (U, Wt, q_in, Bqk, k_out,
+    total [..., 1, dk]). The path of every call the kernels do not take
+    (`takes_kernels`), and their oracle."""
+    dv = v.shape[-1]
+    G = jnp.cumsum(g, axis=-2)
+    total = G[..., -1:, :]
+    A, Bqk = decay_pairs(q, k, G, sub)
+    decay = jnp.exp(G)
+    solved = _mm(
+        unit_lower_inverse(beta * A, sub),
+        beta * jnp.concatenate([v, k * decay], axis=-1),
+    )
+    U, Wt = solved[..., :dv], solved[..., dv:]  # T beta V, T beta K exp G
+    k_out = jnp.swapaxes(k * jnp.exp(total - G), -1, -2)  # [.., dk, chunk]
+    return U, Wt, q * decay, Bqk, k_out, total
+
+
+# The stage runs as the Pallas kernels of `ops/kda_kernels.py` where
+# Mosaic's tiling takes its blocks: a head read where it lies is a
+# block column of [B, L, H * d], so both head widths are multiples of
+# the 128 lanes; a chunk's rows and the diagonal blocks' are multiples
+# of the 8 sublanes, and the blocks divide the chunk. Timed on one TPU
+# v5e ("TPU v5 lite", jax 0.9.0, libtpu 0.0.34) on 2026-09-30 at the
+# one shape a cell runs, (2, 2048, 32, 128) in chunks of 64 and blocks
+# of 16, a layer's whole scan, ms a call, jax | kernels: forward 10.41
+# | 3.91, forward + backward 28.29 | 11.58 (`docs/performance.md`). The
+# rule's other shapes (chunks of 16 to 128, blocks of 8 to 64, a width
+# of 256) compile for the v5e and hold to the recurrence in the
+# interpreter, and are not timed: a narrower head and the tests'
+# chunks of 32 in blocks of 8 on the CPU stay with `intra_stage`.
+KERNEL_LANES = 128
+KERNEL_SUBLANES = 8
+
+
+def takes_kernels(dk: int, dv: int, chunk: int, sub: int, backend=None) -> bool:
+    """Whether `kda_chunked` hands a call's `intra` stage to the
+    kernels: read from the call's own shapes and the backend alone."""
+    backend = jax.default_backend() if backend is None else backend
+    return (
+        backend == "tpu"
+        and dk % KERNEL_LANES == 0
+        and dv % KERNEL_LANES == 0
+        and sub % KERNEL_SUBLANES == 0
+        and chunk % sub == 0
+    )
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
+                interpret: bool = False):
     """q, k [B, L, H, dk], v [B, L, H, dv], g [B, L, H, dk] (log-decay,
     <= 0), beta [B, L, H] -> (o [B, L, H, dv] float32, the most negative
     cumulative log-decay inside a chunk, a float32 scalar). L need not
     be a multiple of `chunk`: the tail is padded with tokens that write
-    nothing (k = 0, beta = 0) and do not decay (g = 0)."""
+    nothing (k = 0, beta = 0) and do not decay (g = 0). `interpret=True`
+    runs the `intra` stage as the kernels in the Pallas interpreter and
+    is for tests only (no model path passes it)."""
     B, L, H, dk = q.shape
     dv = v.shape[-1]
     pad = -L % chunk
     f32 = jnp.float32
+    sub = min(sub, chunk)
 
-    def chunks(x):  # [B, L, H, ...] -> [n, B, H, chunk, ...]
+    def padded(x):  # [B, L, H, ...] -> [B, L + pad, H, ...] float32
         x = x.astype(f32)
         if pad:
             x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return x
+
+    def chunks(x):  # [B, L + pad, H, ...] -> [n, B, H, chunk, ...]
         x = x.reshape((B, (L + pad) // chunk, chunk) + x.shape[2:])
         return jnp.moveaxis(x, (1, 3), (0, 2))
 
-    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
-    beta = chunks(beta)[..., None]  # [n, B, H, chunk, 1]
+    q, k, v, g, beta = (padded(x) for x in (q, k, v, g, beta))
     with jax.named_scope("intra"):
-        G = jnp.cumsum(g, axis=-2)
-        total = G[..., -1:, :]  # [n, B, H, 1, dk]
-        A, Bqk = decay_pairs(q, k, G, min(sub, chunk))
-        decay = jnp.exp(G)
-        solved = _mm(
-            unit_lower_inverse(beta * A, min(sub, chunk)),
-            beta * jnp.concatenate([v, k * decay], axis=-1),
-        )
-        U, Wt = solved[..., :dv], solved[..., dv:]  # T beta V, T beta K exp G
-        q_in = q * decay
-        k_out = jnp.swapaxes(k * jnp.exp(total - G), -1, -2)  # [.., dk, chunk]
+        if interpret or takes_kernels(dk, dv, chunk, sub):
+            from elasticdl_tpu.ops import kda_kernels
+
+            U, Wt, q_in, Bqk, k_out, total = kda_kernels.intra_stage(
+                q, k, v, g, beta, chunk, sub, interpret
+            )
+            k_out = jnp.swapaxes(k_out, -1, -2)  # a layout, not a copy
+        else:
+            U, Wt, q_in, Bqk, k_out, total = intra_stage(
+                chunks(q), chunks(k), chunks(v), chunks(g),
+                chunks(beta)[..., None], sub,
+            )
         keep = jnp.exp(jnp.swapaxes(total, -1, -2))  # [n, B, H, dk, 1]
     with jax.named_scope("state"):
         # `chunk_step` is looked up here, at the call: a control of the
@@ -297,3 +360,63 @@ def kda_recurrent(q, k, v, g, beta):
         tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)),
     )
     return jnp.moveaxis(o, 0, 1)
+
+
+def _layer_inputs(shape, seed: int):
+    """q, k, v, g, beta at [B, L, H, d] as a layer makes them: SiLU of
+    normals, q and k scaled to unit length (q by d^-1/2 more), a
+    log-decay of -a x softplus(normal - 3) with a in (1, 16), a
+    sigmoid's write strength (`compare.py`'s `scan_errors` draws the
+    same)."""
+    d = shape[-1]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v = (jax.nn.silu(jax.random.normal(key, shape)) for key in keys[:3])
+    q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-12) * d**-0.5
+    k = k * lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-12)
+    rate = jax.random.uniform(keys[3], (shape[2], 1), minval=1.0, maxval=16.0)
+    g = -rate * jax.nn.softplus(jax.random.normal(keys[4], shape) - 3.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], shape[:3]))
+    return q, k, v, g, beta
+
+
+def check_against_recurrence(shape, interpret: bool = False, seed: int = 0,
+                             chunk: int = 64):
+    """`kda_chunked` as this backend dispatches it (on a TPU at a head
+    width of 128: the kernels; `interpret=True`: the kernels in the
+    interpreter) at one [B, L, H, d] shape against `kda_recurrent`, the
+    outputs and the five input gradients of a fixed random cotangent:
+    {"o", "dq", "dk", "dv", "dg", "dbeta": max|chunked - recurrent| /
+    max|recurrent|, "kernels": whether the kernels ran}. The gated chip
+    check beside `flash_attention.check_against_reference`."""
+    args = _layer_inputs(shape, seed)
+    w = jax.random.normal(jax.random.PRNGKey(seed + 1), shape)
+
+    def through(f):
+        def loss(w, *a):
+            o = f(*a)
+            return jnp.sum(o * w), o
+
+        return jax.jit(
+            jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5), has_aux=True)
+        )
+
+    (_, o), grads = through(
+        lambda *a: kda_chunked(*a, chunk=chunk, interpret=interpret)[0]
+    )(w, *args)
+    # the recurrence four heads at a time: its backward pass keeps a
+    # state a token, 8.6 GB for all 32 heads at 2 x 2048
+    recurrent, wants = through(kda_recurrent), []
+    for h in range(0, shape[2], 4):
+        (_, oh), gh = recurrent(*(x[:, :, h:h + 4] for x in (w, *args)))
+        wants.append((oh, *gh))
+    errors = {
+        name: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        for name, a, b in zip(
+            ("o", "dq", "dk", "dv", "dg", "dbeta"), (o, *grads),
+            (jnp.concatenate(parts, axis=2) for parts in zip(*wants)),
+        )
+    }
+    errors["kernels"] = bool(interpret or takes_kernels(
+        shape[-1], shape[-1], chunk, min(16, chunk)
+    ))
+    return errors

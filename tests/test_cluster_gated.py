@@ -217,6 +217,33 @@ print(json.dumps(check_against_reference((2, 2 * BLOCK, 4, 64))))
 
 
 @pytest.mark.skipif(not TPU, reason="EDL_TPU_TESTS=1 needs the real chip")
+def test_tpu_kda_kernels_compiled():
+    """The delta rule's `intra` kernels, forward and backward, compiled
+    on the real chip at the hybrid cell's head width: `kda_chunked`'s
+    output and five input gradients against the recurrence a token at
+    a time (the CPU suite covers interpret mode and the lowering for a
+    described v5e; 3e-5 of the largest is three times the most the chip
+    read at (2, 2048, 32, 128), dk's 9.6e-6: PERF.md section 6, PR 42)."""
+    code = """
+import json, sys
+sys.path.insert(0, %r)
+from elasticdl_tpu.ops.kda import check_against_recurrence
+print(json.dumps(check_against_recurrence((2, 512, 4, 128))))
+""" % (REPO,)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", code],
+        capture_output=True, text=True, timeout=600, env=env, cwd=REPO,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    errors = json.loads(out.stdout.strip().splitlines()[-1])
+    assert errors.pop("kernels") is True
+    assert set(errors) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
+    assert max(errors.values()) <= 3e-5, errors
+
+
+@pytest.mark.skipif(not TPU, reason="EDL_TPU_TESTS=1 needs the real chip")
 def test_tpu_flash_attention_long_sequence():
     """The long-context claim, executed: at L=16384 the naive score
     matrix alone is [B,H,L,L] = 4 GiB bf16 per (B,H)=8 — the flash

@@ -217,21 +217,34 @@ def _dot_precisions(jaxpr):
     return found
 
 
+@pytest.mark.parametrize("entry", ["jax", "kernels"])
 @pytest.mark.parametrize("ambient", [None, "bfloat16"])
 @pytest.mark.parametrize("pass_", ["forward", "backward"])
 def test_every_product_of_the_scan_states_float32_whatever_the_caller_s_precision(
-    ambient, pass_
+    ambient, pass_, entry
 ):
     """`config.json` states the chunked form's matrices and the carried
     state as float32; the TPU's default rounds a float32 operand to
     bfloat16, so each product names `HIGHEST` itself, forward and
-    backward, under any ambient precision."""
-    args = recurrence_inputs(0.01, length=128)
-    loss = lambda *a: jnp.sum(jnp.sin(kda.kda_chunked(*a)[0]))  # noqa: E731
+    backward, under any ambient precision. The walk descends into a
+    `pallas_call`'s jaxpr, so the kernel entry (a head of 128, the
+    `intra` stage inside the kernels) is held to the same."""
+    if entry == "jax":
+        args, kw = recurrence_inputs(0.01, length=128), {}
+        least = {"forward": 20, "backward": 50}[pass_]
+    else:
+        args = recurrence_inputs(0.01, length=128, heads=1, dk=128, dv=128)
+        kw = {"interpret": True}
+        # two chunks a grid step, eight products each forward (20 with
+        # the pass over the chunks' four) and 63 with the transposes
+        least = {"forward": 20, "backward": 60}[pass_]
+    loss = lambda *a: jnp.sum(jnp.sin(kda.kda_chunked(*a, **kw)[0]))  # noqa: E731
     traced = loss if pass_ == "forward" else jax.grad(loss, argnums=(0, 1, 2, 3, 4))
     with jax.default_matmul_precision(ambient):
-        precisions = _dot_precisions(jax.make_jaxpr(traced)(*args).jaxpr)
-    assert len(precisions) >= (20 if pass_ == "forward" else 50)
+        jaxpr = jax.make_jaxpr(traced)(*args)
+        precisions = _dot_precisions(jaxpr.jaxpr)
+    assert ("pallas_call" in str(jaxpr)) == (entry == "kernels")
+    assert len(precisions) >= least
     highest = jax.lax.Precision.HIGHEST
     assert all(p in (highest, (highest, highest)) for p in precisions), precisions
 
