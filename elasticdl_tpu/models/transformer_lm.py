@@ -119,9 +119,9 @@ class TransformerConfig:
     # projected
     mla_rope: bool = True
     # the mixer of every layer of the stack, dense layers first:
-    # "mha", "mla" or "kda"; None = `attention` in every layer. Layers
-    # that follow each other with one mixer and one kind of MLP are
-    # one scanned run
+    # "mha", "mla", "kda" or "conv"; None = `attention` in every layer.
+    # Layers that follow each other with one mixer and one kind of MLP
+    # are one scanned run
     layer_types: Optional[Tuple[str, ...]] = None
     # "kda": gated delta-rule linear attention (Kimi Delta Attention):
     # `kda_heads` heads of `kda_head_dim` (keys and values alike), a
@@ -133,10 +133,28 @@ class TransformerConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 64
+    # "conv": the double-gated short convolution (LFM2): (b, c, u) =
+    # split3(x W_in), a causal depthwise convolution of `conv_taps`
+    # taps over b * u with no bias and no activation, then (c * that)
+    # W_out
+    conv_taps: int = 3
+    # "mha" with fewer key-value heads than query heads (grouped-query
+    # attention: query head i reads key-value head i // group); None =
+    # `n_heads`
+    n_kv_heads: Optional[int] = None
+    # "mha": an RMS norm over every query's and key's head_dim, one
+    # weight of head_dim each a layer, before the rotation
+    qk_norm: bool = False
+    # the logits read the embedding transposed; no "head" leaf
+    tie_embeddings: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
 
     @property
     def mixers(self) -> Tuple[str, ...]:
@@ -224,7 +242,7 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
 
 # a run's leaves that the forward pass reads as stored (float32) and not
 # as cast to the compute dtype
-_FLOAT32_LEAVES = ("router", "router_bias", "dt_bias")
+_FLOAT32_LEAVES = ("router", "router_bias", "dt_bias", "q_norm", "k_norm")
 # how a routed stack's stats, one a layer, become one number a step; a
 # stat without a rule here is a KeyError when the program is traced
 _OVER_LAYERS = {
@@ -232,6 +250,7 @@ _OVER_LAYERS = {
     "router_entropy": jnp.mean,
     "router_bias_absmax": jnp.max,
     "kda_log_decay_min": jnp.min,
+    "shortconv_gate_absmax": jnp.max,
 }
 
 
@@ -255,20 +274,13 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
         scale = scale if scale is not None else 1.0 / math.sqrt(shape[-2] if len(shape) > 1 else shape[-1])
         return (rng.standard_normal(shape) * scale).astype(np.float32)
 
-    L, d, hd = cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim
+    L, d = cfg.n_layers, cfg.d_model
     if (
         cfg.moe_top_k or cfg.n_dense_layers or cfg.layer_types
         or cfg.attention in ("mla", "kda")
     ):
         return _init_routed_params(norm, rng, cfg)
-    layers = {
-        "ln1": np.ones((L, d), np.float32),
-        "wq": norm(L, d, hd),
-        "wk": norm(L, d, hd),
-        "wv": norm(L, d, hd),
-        "wo": norm(L, hd, d),
-        "ln2": np.ones((L, d), np.float32),
-    }
+    layers = _init_mha(norm, cfg, L)
     if cfg.n_experts:
         layers["router"] = norm(L, d, cfg.n_experts)
         layers["ew1"] = norm(L, cfg.n_experts, d, cfg.d_expert)
@@ -287,8 +299,9 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
         "embed": norm(cfg.vocab, d, scale=0.02),
         "layers": layers,
         "ln_f": np.ones((d,), np.float32),
-        "head": norm(d, cfg.vocab),
     }
+    if not cfg.tie_embeddings:
+        params["head"] = norm(d, cfg.vocab)
     if cfg.looped:
         # Linear(d, 1): the gates start near one half at every exit
         params["exit_gate"] = {
@@ -296,6 +309,25 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
             "b": np.zeros((1,), np.float32),
         }
     return params
+
+
+def _init_mha(norm, cfg: TransformerConfig, L: int) -> Dict:
+    """A multi-head attention layer's leaves but its MLP's, for L
+    stacked layers: `n_kv_heads` key-value heads, and under `qk_norm`
+    the two norms' weights."""
+    d, hd = cfg.d_model, cfg.head_dim
+    tree = {
+        "ln1": np.ones((L, d), np.float32),
+        "wq": norm(L, d, cfg.n_heads * hd),
+        "wk": norm(L, d, cfg.kv_heads * hd),
+        "wv": norm(L, d, cfg.kv_heads * hd),
+        "wo": norm(L, cfg.n_heads * hd, d),
+        "ln2": np.ones((L, d), np.float32),
+    }
+    if cfg.qk_norm:
+        tree["q_norm"] = np.ones((L, hd), np.float32)
+        tree["k_norm"] = np.ones((L, hd), np.float32)
+    return tree
 
 
 def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
@@ -307,14 +339,15 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     d = cfg.d_model
     if not (
         cfg.moe_top_k and cfg.mlp == "swiglu"
-        and set(cfg.mixers) <= {"mla", "kda"}
+        and set(cfg.mixers) <= {"mla", "kda", "conv", "mha"}
         and len(cfg.mixers) == cfg.n_layers
     ):
         raise NotImplementedError(
-            "the routed stack is built with latent attention or "
-            "delta-rule attention, top-k experts and SwiGLU MLPs together "
-            "(attention / layer_types of 'mla' and 'kda', one a layer, "
-            "moe_top_k > 0, mlp='swiglu')"
+            "the routed stack is built with latent attention, delta-rule "
+            "attention or short convolutions beside grouped-query "
+            "attention, top-k experts and SwiGLU MLPs together (attention "
+            "'mla' or 'kda', or layer_types of 'mla', 'kda', 'conv' and "
+            "'mha', one a layer; moe_top_k > 0, mlp='swiglu')"
         )
 
     def mla(L):
@@ -356,18 +389,36 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         )
         return block
 
+    def conv(L):
+        taps = cfg.conv_taps
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            "in_proj": norm(L, d, 3 * d),
+            # a tap's weights; the taps sum like a fan-in
+            "conv": norm(L, taps, d, scale=1.0 / math.sqrt(taps)),
+            "out_proj": norm(L, d, d),
+            "ln2": np.ones((L, d), np.float32),
+        }
+
+    mixer_trees = {
+        "mla": mla, "kda": kda, "conv": conv,
+        "mha": lambda L: _init_mha(norm, cfg, L),
+    }
     (_first, held) = cfg.held
     f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
 
     def run(mixer, experts, L):
-        tree = mla(L) if mixer == "mla" else kda(L)
+        tree = mixer_trees[mixer](L)
         if experts:
             tree.update(
                 router=norm(L, d, cfg.n_experts),
                 eg=norm(L, held, d, f), eu=norm(L, held, d, f),
                 ed=norm(L, held, f, d),
-                sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d),
             )
+            if fs:  # a layer with no shared expert has no such leaves
+                tree.update(
+                    sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d)
+                )
             if cfg.moe_score == "sigmoid":
                 tree["router_bias"] = np.zeros((L, cfg.n_experts), np.float32)
         else:
@@ -389,8 +440,9 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         "embed": norm(cfg.vocab, d, scale=0.02),
         **stack,
         "ln_f": np.ones((d,), np.float32),
-        "head": norm(d, cfg.vocab),
     }
+    if not cfg.tie_embeddings:
+        params["head"] = norm(d, cfg.vocab)
     n_kda = cfg.mixers.count("kda")
     if n_kda:
         # the decay's rate exp(a_log), uniform in (1, 16): ONE flat leaf
@@ -446,13 +498,15 @@ def _require_mesh_support(cfg: TransformerConfig):
     if (
         cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped
         or cfg.attention != "mha" or cfg.n_dense_layers or cfg.moe_top_k
-        or cfg.layer_types
+        or cfg.layer_types or cfg.n_kv_heads or cfg.qk_norm
+        or cfg.tie_embeddings
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
             "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
-            "attention='mla' and 'kda', layer_types, n_dense_layers and "
-            "moe_top_k exist on the unsharded path (plain_forward) only"
+            "attention='mla' and 'kda', layer_types, n_dense_layers, "
+            "moe_top_k, n_kv_heads, qk_norm and tie_embeddings exist on "
+            "the unsharded path (plain_forward) only"
         )
 
 
@@ -704,14 +758,56 @@ def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
 
 
 def _causal_conv(x: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise over time, then SiLU: y_t = silu(sum_i taps[i] *
-    x_{t - (n - 1) + i}), zeros before the sequence's start. x
+    """Depthwise over time, the taps alone: y_t = sum_i taps[i] *
+    x_{t - (n - 1) + i}, zeros before the sequence's start. x
     [B, L, C], taps [n, C]."""
     n, length = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-    return jax.nn.silu(
-        sum(padded[:, i:i + length] * taps[i] for i in range(n))
+    return sum(padded[:, i:i + length] * taps[i] for i in range(n))
+
+
+def _mha(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
+    """Multi-head attention on the normed x [B, L, d] -> [B, L, d]:
+    `n_heads` queries over `kv_heads` keys and values (the dispatcher
+    widens them), under `qk_norm` queries and keys normed per head,
+    then rotated."""
+    from elasticdl_tpu.ops.flash_attention import attention
+
+    b, l, _ = x.shape
+    q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
+    k = (x @ lp["wk"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
+    v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q, k = _qk_norm(lp, q, k, cfg.norm_eps)
+    q = _rope(q, positions, cfg.rope_base)
+    k = _rope(k, positions, cfg.rope_base)
+    return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
+
+
+def _qk_norm(lp: Dict, q: jnp.ndarray, k: jnp.ndarray, eps: float):
+    """Every query and key [B, L, H, D] RMS-normed over its D, one
+    weight of D for the queries and one for the keys; in float32, as
+    the weights are stored, then back to the compute dtype."""
+    f32 = jnp.float32
+    return (
+        rms_norm(q.astype(f32), lp["q_norm"], eps).astype(q.dtype),
+        rms_norm(k.astype(f32), lp["k_norm"], eps).astype(k.dtype),
     )
+
+
+def _conv(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
+    """The double-gated short convolution (LFM2) on the normed x
+    [B, L, d] -> ([B, L, d], the largest |c * y|): (b, c, u) =
+    split3(x W_in); y = the causal depthwise taps over b * u, no bias
+    and no activation; out = (c * y) W_out."""
+    with jax.named_scope("shortconv"):
+        with jax.named_scope("in_proj"):
+            b, c, u = jnp.split(x @ lp["in_proj"], 3, axis=-1)
+        with jax.named_scope("gate_conv"):
+            gated = c * _causal_conv(b * u, lp["conv"])
+            absmax = jnp.max(jnp.abs(gated)).astype(jnp.float32)
+        with jax.named_scope("out_proj"):
+            return gated @ lp["out_proj"], absmax
 
 
 def _kda(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
@@ -735,7 +831,7 @@ def _kda(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
 
     with jax.named_scope("conv"):
         q, k, v = (
-            per_head(_causal_conv(x @ lp[w], lp[c])).astype(f32)
+            per_head(jax.nn.silu(_causal_conv(x @ lp[w], lp[c]))).astype(f32)
             for w, c in (("wq", "conv_q"), ("wk", "conv_k"), ("wv", "conv_v"))
         )
         q = q * lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-12) * hd**-0.5
@@ -799,7 +895,6 @@ def plain_forward_stats(
     with this batch: `expert_tokens` [expert layers, held], and the
     layers' mean `held_share` and `router_entropy` ({} without
     `moe_top_k`). The zoo adapter leaves it in `window_stats`."""
-    from elasticdl_tpu.ops.flash_attention import attention
     from elasticdl_tpu.parallel.moe import moe_ffn_local, moe_topk_held
 
     stored = params
@@ -818,12 +913,10 @@ def plain_forward_stats(
         if mixer == "kda":
             out, log_decay_min = _kda(cfg, lp, x)
             return out, {"kda_log_decay_min": log_decay_min}
-        q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        k = (x @ lp["wk"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        v = (x @ lp["wv"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        q = _rope(q, positions, cfg.rope_base)
-        k = _rope(k, positions, cfg.rope_base)
-        return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"], {}
+        if mixer == "conv":
+            out, gate_absmax = _conv(cfg, lp, x)
+            return out, {"shortconv_gate_absmax": gate_absmax}
+        return _mha(cfg, lp, x, positions), {}
 
     def layer(mixer: str, experts: bool):
         """The scanned body of a layer with `mixer` and the dense MLP,
@@ -842,7 +935,8 @@ def plain_forward_stats(
                     out, a, routing = moe_topk_held(
                         x, lp["router"],
                         (lp["eg"], lp["eu"], lp["ed"]),
-                        (lp["sg"], lp["su"], lp["sd"]),
+                        (lp["sg"], lp["su"], lp["sd"])
+                        if cfg.n_shared_experts else None,
                         top_k=cfg.moe_top_k, held=cfg.held,
                         scaling=cfg.routed_scaling,
                         score=cfg.moe_score, bias=lp.get("router_bias"),
@@ -913,6 +1007,12 @@ def plain_forward_stats(
                 for name, values in gathered.items()
             }
 
+    def head(h):
+        """The logits: by the head, or by the embedding transposed."""
+        if cfg.tie_embeddings:
+            return h @ params["embed"].T
+        return h @ params["head"]
+
     carry = (h, jnp.zeros((), dtype=jnp.float32 if routed else h.dtype))
     if not cfg.looped:
         h, aux, stats = stack(carry)
@@ -925,7 +1025,7 @@ def plain_forward_stats(
                 },
             }
         with jax.named_scope("head"):
-            return h @ params["head"], aux, stats
+            return head(h), aux, stats
 
     def one_pass(carry, _):
         h, aux, _stats = stack(carry)
@@ -941,7 +1041,7 @@ def plain_forward_stats(
             jnp.float32
         ) + gate["b"].astype(jnp.float32)
         with jax.named_scope("head"):
-            logits = exits @ params["head"]
+            logits = head(exits)
         return LoopedOutputs(
             logits, gates[..., 0], cfg.exit_entropy_weight
         ), aux, {}
@@ -1048,7 +1148,9 @@ def place_params(params: Dict, cfg: TransformerConfig, mesh: Mesh) -> Dict:
 
 def reference_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     """Unsharded single-device reference (for equivalence tests):
-    the same math with loops instead of collectives."""
+    the same math with loops instead of collectives. The original
+    block only, as the mesh path it is held against."""
+    _require_mesh_support(cfg)
     inputs = tokens
     b, l = inputs.shape
     h = jnp.asarray(params["embed"])[inputs]

@@ -68,6 +68,8 @@ class TransformerLM:
                 stats["router_bias_absmax"] = np.zeros((), np.float32)
             if "kda" in self.cfg.mixers:
                 stats["kda_log_decay_min"] = np.zeros((), np.float32)
+            if "conv" in self.cfg.mixers:
+                stats["shortconv_gate_absmax"] = np.zeros((), np.float32)
             return {"params": params, WINDOW_STATS: stats}
         return {"params": params}
 

@@ -489,11 +489,26 @@ def attention(q, k, v, causal: bool = True, scale=None):
     (tests/test_flash_attention.py holds both to the float32 math).
     The kernels know one head width and the scale 1/sqrt(D): values of
     another width than the queries (latent attention) or a `scale` of
-    the caller's never reach them, whatever the flag says."""
+    the caller's never reach them, whatever the flag says.
+
+    `k` and `v` may have fewer heads than `q` (grouped-query attention,
+    the query heads a multiple): query head i reads key-value head
+    i // group. Both paths know equal heads only, so k and v are
+    widened to the query heads here, in front of either, and the
+    gradient sums over a group by itself; a call with equal heads is
+    traced as it was."""
     import os
 
     from elasticdl_tpu.common.constants import ENV_TPU_FLASH
 
+    group, rest = divmod(q.shape[2], k.shape[2])
+    if rest or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads over {k.shape[2]} key and "
+            f"{v.shape[2]} value heads: not whole groups"
+        )
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     L = q.shape[1]
     flag = os.environ.get(ENV_TPU_FLASH)
     kernel_shapes = scale is None and v.shape[-1] == q.shape[-1]

@@ -18,7 +18,7 @@ auxiliary loss alongside the output.
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -239,7 +239,7 @@ def moe_topk_held(
     x: jnp.ndarray,
     router_w: jnp.ndarray,
     experts: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
-    shared: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+    shared: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
     *,
     top_k: int,
     held: Tuple[int, int],
@@ -250,7 +250,8 @@ def moe_topk_held(
     balance: bool = True,
 ):
     """A top-k dropless expert layer that is told which experts it
-    holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x).
+    holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x) (no S(x)
+    and no `shared` scope when `shared` is None).
 
     `score` "softmax" routes by `route_topk`; "sigmoid" by
     `route_sigmoid_topk` with the selection `bias` and, under
@@ -262,9 +263,10 @@ def moe_topk_held(
     stacked SwiGLU weights (wg [n, d, f], wu [n, d, f], wd [n, f, d])
     of the n experts `held = (first, n)` names, experts first ..
     first + n - 1 of E; `shared` one SwiGLU (the shared experts side by
-    side). -> (y [B, S, d], the sequence-wise balance term (unweighted,
-    f32), stats of the routing: `expert_tokens` [n] (counts, float32),
-    `held_share`, `router_entropy`).
+    side), None for a layer without one. -> (y [B, S, d], the
+    sequence-wise balance term (unweighted, f32), stats of the
+    routing: `expert_tokens` [n] (counts, float32), `held_share`,
+    `router_entropy`).
 
     This is one chip's part of an expert-parallel layer, computed
     without the exchange: what the experts held elsewhere would add is
@@ -331,8 +333,11 @@ def moe_topk_held(
         }
         if bias is not None:
             stats["router_bias_absmax"] = jnp.max(jnp.abs(bias))
-    with jax.named_scope("shared"):
-        y = routed + swiglu(xf, *shared)
+    if shared is None:
+        y = routed
+    else:
+        with jax.named_scope("shared"):
+            y = routed + swiglu(xf, *shared)
     return y.reshape(b, s, d), balance_term, jax.tree_util.tree_map(
         lax.stop_gradient, stats
     )
