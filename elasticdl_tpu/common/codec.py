@@ -367,6 +367,8 @@ def delta_length(obj: Any) -> int:
         return obj.n
     if isinstance(obj, SparseDelta):
         return obj.n
+    if isinstance(obj, LeafVector):
+        return obj.size
     return int(np.asarray(obj).size)
 
 
@@ -387,6 +389,8 @@ def delta_nbytes(obj: Any) -> int:
         return int(np.asarray(obj.q).nbytes + np.asarray(obj.scale).nbytes)
     if isinstance(obj, SparseDelta):
         return int(np.asarray(obj.indices).nbytes) + delta_nbytes(obj.values)
+    if isinstance(obj, LeafVector):
+        return obj.size * 4  # counted, not joined
     return int(np.asarray(obj).nbytes)
 
 
@@ -555,8 +559,16 @@ def _build_frame_tree(obj: Any, builder: _FrameBuilder) -> Any:
     if isinstance(obj, LeafVector):
         # the entry a float32 ndarray of this length gets, over one
         # segment made of the leaves where they lie
+        # (a piece still on its way enters as itself: its length is
+        # known, `part_bytes` waits for the rest)
         nbytes = obj.size * 4
-        off = builder.add([p.view(np.uint8) for p in obj.pieces], nbytes)
+        off = builder.add(
+            [
+                p if isinstance(p, PendingPiece) else p.view(np.uint8)
+                for p in obj.pieces
+            ],
+            nbytes,
+        )
         return {_ND_KEY: True, "d": "<f4", "s": [obj.size], "o": off, "n": nbytes}
     if isinstance(obj, dict):
         return {k: _build_frame_tree(v, builder) for k, v in obj.items()}
@@ -651,10 +663,62 @@ def ravel_np(tree) -> np.ndarray:
     )
 
 
+class PendingPiece:
+    """A `LeafVector` piece that may still be on its way: `size`
+    float32 elements, known when the frame is built, and `wait`, which
+    blocks until they have landed and hands them over as a flat
+    C-contiguous float32 array (`wait(timeout)`: seconds or None;
+    `TimeoutError` when they pass, whatever else went wrong with the
+    copy as it was raised). The frame's header and length need only the
+    size, so a carrier that writes to a socket sends what lies before
+    the piece and waits for it there (`part_bytes`), inside the call's
+    deadline. What landed is kept: a retry, or a join for a carrier
+    that needs one buffer, reads the same host copy and waits for
+    nothing."""
+
+    __slots__ = ("size", "_wait", "_array")
+
+    def __init__(self, size: int, wait):
+        self.size = int(size)
+        self._wait = wait
+        self._array = None
+
+    def peek(self):
+        """The array if it has been waited for and landed, else None."""
+        return self._array
+
+    def landed(self, timeout=None) -> np.ndarray:
+        arr = self._array
+        if arr is None:
+            arr = self._wait(timeout)
+            _check_piece(arr)
+            if arr.size != self.size:
+                raise ValueError(
+                    f"a piece of {self.size} elements landed as {arr.size}"
+                )
+            self._array, self._wait = arr, None
+        return arr
+
+
+def _check_piece(p) -> None:
+    if p.dtype != np.float32 or p.ndim != 1 or not p.flags.c_contiguous:
+        raise TypeError("a LeafVector piece is a flat float32 array")
+
+
+def part_bytes(part, timeout=None):
+    """A frame part as the buffer that goes on the wire: itself, or,
+    for a `PendingPiece`, the bytes it has landed as (waited for, at
+    most `timeout` seconds)."""
+    if isinstance(part, PendingPiece):
+        return part.landed(timeout).view(np.uint8)
+    return part
+
+
 class LeafVector:
     """One float32 vector that lies in several arrays: what `ravel_np`
     of a tree would concatenate, without concatenating. `pieces` are
-    flat C-contiguous float32 arrays, in order; whoever builds one
+    flat C-contiguous float32 arrays, in order, or `PendingPiece`s that
+    will land as such; whoever builds one
     answers for their staying as they are until the frame has left (a
     read-only leaf that is replaced and never written, or a copy of its
     own). In a v2 frame it is the vector: the header entry an ndarray
@@ -668,13 +732,18 @@ class LeafVector:
     def __init__(self, pieces):
         self.pieces = list(pieces)
         for p in self.pieces:
-            if p.dtype != np.float32 or p.ndim != 1 or not p.flags.c_contiguous:
-                raise TypeError("a LeafVector piece is a flat float32 array")
+            if not isinstance(p, PendingPiece):
+                _check_piece(p)
         self.size = sum(p.size for p in self.pieces)
 
     def __array__(self, dtype=None, copy=None):
         vec = (
-            np.concatenate(self.pieces)
+            np.concatenate(
+                [
+                    p.landed() if isinstance(p, PendingPiece) else p
+                    for p in self.pieces
+                ]
+            )
             if self.pieces
             else np.zeros(0, np.float32)
         )
@@ -732,9 +801,10 @@ def unravel_np(vec: np.ndarray, template) -> Any:
 def dumps_parts(obj: Any):
     """Serialize a pytree as an ordered list of v2-frame parts (bytes
     for the prefix/header/pads, flat uint8 views of the source arrays,
-    which keep them alive) and the total frame length.
-    `b"".join(parts)` IS the frame; a carrier that writes to a socket
-    sends the parts and never joins."""
+    which keep them alive, and a `LeafVector`'s `PendingPiece`s as
+    themselves) and the total frame length. The parts' bytes
+    (`part_bytes`), joined, ARE the frame; a carrier that writes to a
+    socket sends the parts and never joins."""
     builder = _FrameBuilder()
     tree = _build_frame_tree(obj, builder)
     header = msgpack.packb(
@@ -761,7 +831,7 @@ def dumps(obj: Any) -> bytes:
     the frame as buffer views; the single full-size copy is this
     join."""
     parts, _ = dumps_parts(obj)
-    return b"".join(parts)
+    return b"".join(map(part_bytes, parts))
 
 
 def dumps_v1(obj: Any) -> bytes:

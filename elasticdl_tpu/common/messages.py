@@ -12,6 +12,7 @@ Redis side channel (elasticdl/python/master/embedding_service.py:270-357).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, Optional
 
 from elasticdl_tpu.common import codec
@@ -555,18 +556,25 @@ def pack(obj: Any) -> bytes:
 
 class PackedParts:
     """A packed frame that has not been joined: the ordered parts
-    `codec.dumps_parts` makes (bytes and flat uint8 views of the source
-    arrays, which the views keep alive) and their total length, which
+    `codec.dumps_parts` makes (bytes, flat uint8 views of the source
+    arrays, which the views keep alive, and `codec.PendingPiece`s whose
+    bytes may still be on their way: `pending` says there are such)
+    and their total length, which
     `len()` answers. A carrier that writes to a socket sends the parts
-    as they lie; one that needs a single buffer (gRPC, inproc) asks
-    `contiguous()`, which joins once however often it is asked, so a
-    retry resends what the first attempt sent."""
+    as they lie and waits at a pending one for its bytes; one that
+    needs a single buffer (gRPC, inproc) asks
+    `contiguous()`, which waits for every piece and joins once however
+    often it is asked, so a
+    retry resends what the first attempt sent. `waited` is the seconds
+    whoever sent or joined the frame stood waiting for a piece."""
 
-    __slots__ = ("parts", "nbytes", "_data")
+    __slots__ = ("parts", "nbytes", "pending", "waited", "_data")
 
     def __init__(self, parts, nbytes: int):
         self.parts = parts
         self.nbytes = nbytes
+        self.pending = any(isinstance(p, codec.PendingPiece) for p in parts)
+        self.waited = 0.0
         self._data = None
 
     def __len__(self) -> int:
@@ -577,9 +585,30 @@ class PackedParts:
         """Whether a carrier asked for the one buffer."""
         return self._data is not None
 
-    def contiguous(self) -> bytes:
+    @property
+    def streamed(self) -> bool:
+        """Whether the frame went out as its pieces landed: it has
+        pending pieces and no carrier asked for the one buffer."""
+        return self.pending and self._data is None
+
+    def contiguous(self, timeout=None) -> bytes:
+        """The frame in one buffer; `timeout` bounds the wait for the
+        pending pieces together (`TimeoutError`)."""
         if self._data is None:
             parts = self.parts
+            if self.pending:
+                t0 = time.monotonic()
+                try:
+                    parts = [
+                        codec.part_bytes(
+                            p,
+                            None if timeout is None
+                            else max(0.0, t0 + timeout - time.monotonic()),
+                        )
+                        for p in parts
+                    ]
+                finally:
+                    self.waited += time.monotonic() - t0
             self._data = parts[0] if len(parts) == 1 else b"".join(parts)
         return self._data
 

@@ -215,9 +215,18 @@ class RpcClient:
         # carriers), and the socket buffers as the kernel granted them
         link = {"recv_reused": False}
 
+        def sent_how():
+            # read when the call has settled: whether the frame left as
+            # its pieces landed, and how long it stood waiting for one
+            return {
+                "joined": payload.joined,
+                "streamed": payload.streamed,
+                "waited_ms": round(payload.waited * 1e3, 3),
+            }
+
         def over_grpc(remaining):
             self.wire.record(method, sent=len(payload))
-            resp_bytes = stub(payload.contiguous(), timeout=remaining)
+            resp_bytes = stub(payload.contiguous(remaining), timeout=remaining)
             self.wire.record(method, received=len(resp_bytes))
             return resp_bytes
 
@@ -273,14 +282,14 @@ class RpcClient:
         finally:
             if not timeline:
                 if tspan is not None:
-                    tspan.end(transport=tier, joined=payload.joined, **link)
+                    tspan.end(transport=tier, **sent_how(), **link)
             elif not settled:  # the call raised: no response to date it by
                 obs_trace.record_phase(
                     f"rpc.client.{method}", t_sent, time.time() - t_sent,
                     {
                         "bytes": len(payload),
                         "transport": tier,
-                        "joined": payload.joined,
+                        **sent_how(),
                         "failed": True,
                         **link,
                     },
@@ -299,7 +308,7 @@ class RpcClient:
             {
                 "bytes": len(payload),
                 "transport": tier,
-                "joined": payload.joined,
+                **sent_how(),
                 "version": out.get("version") if isinstance(out, dict) else None,
                 **link,
             },

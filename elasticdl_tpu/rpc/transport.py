@@ -16,7 +16,10 @@ override (and the test handle):
   receiver hands the codec one contiguous buffer to build
   `np.frombuffer` views over — the zero-copy contract of codec v2
   holds end to end. The bytes on the socket are the frame
-  `codec.dumps` would have made: the receiving side cannot tell.
+  `codec.dumps` would have made: the receiving side cannot tell. A
+  part may be a `codec.PendingPiece` whose bytes have not landed yet
+  (a slice of a window's delta still on its way off the device): the
+  send waits for it there, inside the call's deadline.
 - **inproc** — when the serving `RpcServer` lives in the SAME
   interpreter (bench/test mode, `PSShardGroup` inproc shards), the call
   dispatches directly into the server's handler table: the frame, which
@@ -100,7 +103,7 @@ from typing import Callable, Dict, Optional
 import grpc
 import numpy as np
 
-from elasticdl_tpu.common import messages
+from elasticdl_tpu.common import codec, messages
 from elasticdl_tpu.common.constants import (
     ENV_TRANSPORT,
     ENV_UDS_DIR,
@@ -491,7 +494,7 @@ class InprocTransport:
             )
         # the dispatcher decodes from one buffer, and so does the caller
         resp = dispatcher.dispatch(
-            method, payload.contiguous(), TRANSPORT_INPROC
+            method, payload.contiguous(timeout), TRANSPORT_INPROC
         ).contiguous()
         transport_faults_after(after, method)
         return resp
@@ -704,7 +707,7 @@ def _cut_to(bufs, room: int):
     return turn
 
 
-def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
+def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None) -> float:
     """Write `head`, then a frame's parts in order, gathered by
     `sendmsg` from where they lie: no buffer the size of the frame,
     and a frame that fits the socket buffer is one system call, header
@@ -719,9 +722,34 @@ def _send_parts(conn: socket.socket, head: bytes, parts, deadline=None):
     timeout stands (a server's connection blocks). The bytes on the socket are
     `head + b"".join(parts)`. (One `sendall` a long part, short parts
     joined, read 12-28 ms more wire a 649 MB sync on the chip's host:
-    PERF.md, PR 30.)"""
+    PERF.md, PR 30.)
+
+    A part may be a `codec.PendingPiece` whose bytes have not landed:
+    what lies before it leaves first, then the call waits for it,
+    inside the same `deadline` (`TimeoutError`, which is the socket's
+    own), and goes on. One that has landed (a retry's) is gathered
+    like any other part. Returns the seconds spent waiting so; a frame
+    with no such part makes the system calls it always made."""
     bufs = [memoryview(head)]
-    bufs += [memoryview(part) for part in parts if len(part)]
+    waited = 0.0
+    for part in parts:
+        if isinstance(part, codec.PendingPiece):
+            if part.peek() is None:
+                _send_bufs(conn, bufs, deadline)
+                bufs = []
+                t0 = time.monotonic()
+                part.landed(None if deadline is None else max(0.0, deadline - t0))
+                waited += time.monotonic() - t0
+            part = codec.part_bytes(part)
+        if len(part):
+            bufs.append(memoryview(part))
+    _send_bufs(conn, bufs, deadline)
+    return waited
+
+
+def _send_bufs(conn: socket.socket, bufs, deadline) -> None:
+    """`_send_parts`' turns over buffers that are all there; `bufs` is
+    consumed."""
     i, left = 0, sum(buf.nbytes for buf in bufs)
     while i < len(bufs):
         if deadline is not None:
@@ -1112,7 +1140,7 @@ class UdsTransport:
                 conn.large = True
             after = transport_faults_before(self._plan, method, "client")
             mb = method.encode("utf-8")
-            _send_parts(
+            payload.waited += _send_parts(
                 conn,
                 _REQ_HEADER.pack(len(mb), len(payload)) + mb,
                 payload.parts,
@@ -1147,6 +1175,16 @@ class UdsTransport:
             raise PolicyRpcError(
                 grpc.StatusCode.UNAVAILABLE, f"uds {self._path}: {e}"
             )
+        except PolicyRpcError:
+            raise  # a drawn fault or the server's answer: a frame's edge
+        except BaseException:
+            # a piece of the frame did not land: the frame is cut
+            # short and so is the connection, for the far end to read
+            # "peer closed mid-frame" and apply nothing
+            if conn is not None:
+                conn.close()
+                conn = None
+            raise
         finally:
             if conn is not None:
                 self._checkin(conn)

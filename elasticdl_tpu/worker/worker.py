@@ -67,6 +67,7 @@ from elasticdl_tpu.common.timing import PhaseTimers
 from elasticdl_tpu.obs import hlo_scopes
 from elasticdl_tpu.obs import trace as obs_trace
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
+from elasticdl_tpu.worker import delta_stream
 from elasticdl_tpu.worker.task_data_service import (
     PrefetchParser,
     ReaderCache,
@@ -341,6 +342,8 @@ class Worker:
         self._opt_state = None
         self._base_flat = None  # device copy of params at last sync
         self._subtract_into_base = None  # jitted on the serial chain
+        self._subtract_in_slices = None  # jitted on the overlapped chain
+        self._slice_programs = {}  # slice length -> jitted `delta_slice`
         self._base_version = -1
         self._pending_steps = 0
         self._sync_thread = None  # tail of the chained async delta pushes
@@ -1893,12 +1896,29 @@ class Worker:
             self._flush_deferred_reports()
             return
         t_spawn = time.time()  # `worker.window_sync` starts here
-        with self._first_call("jit_subtract"):
-            delta_dev = self._delta_from_base()
+        delta_f32_bytes = int(self._flat.shape[0]) * 4
+        # a plain float32 delta for the single master, longer than one
+        # slice, leaves the device in slices (worker/delta_stream.py)
+        slice_bounds = cut_ahead = None
+        if (
+            delta_f32_bytes > delta_stream.DELTA_SLICE_BYTES
+            and self._transport_dtype == "float32"
+            and not self._lossy_sync()
+            and not self._ps_endpoints
+        ):
+            slice_bounds = delta_stream.slice_bounds(delta_f32_bytes // 4)
+        if slice_bounds is not None and self._max_inflight_syncs:
+            # the step loop gives the device its next window before
+            # this sync is over, and a program asked for then would
+            # wait for that window's end: the delta is formed in its
+            # slices here, in the step loop's own order
+            cut_ahead, delta_dev = self._delta_in_slices(slice_bounds), None
+        else:
+            with self._first_call("jit_subtract"):
+                delta_dev = self._delta_from_base()
         wire_meta = None
         wire_form = None
         link_mbps = None
-        delta_f32_bytes = int(delta_dev.shape[0]) * 4
         if self._sync_adaptive:
             # per-round wire-form pick from the passive link estimate
             # (sync_policy.decide is pure; LinkWeather holds the push
@@ -2025,8 +2045,7 @@ class Worker:
             # grads + the window's task losses — per-item np.asarray
             # would cost a full round-trip each over a high-latency
             # host<->TPU link.
-            fetch = (
-                delta_dev,
+            small = (
                 aux_dev or None,
                 [l for _, l in losses],
                 step_loss,
@@ -2035,12 +2054,36 @@ class Worker:
             with self._chain_span("worker.delta_wait"):
                 # the device finishes the window and the delta; what
                 # follows is the copy out alone
-                jax.block_until_ready(fetch)
-            self._first_run_settled()
-            with self._chain_span("worker.d2h", bytes=delta_f32_bytes):
-                delta_h, aux_h, loss_h, step_loss_h, gbets_h = (
-                    jax.device_get(fetch)
+                jax.block_until_ready(
+                    (delta_dev if cut_ahead is None else list(cut_ahead), small)
                 )
+            self._first_run_settled()
+            stream = None
+            if slice_bounds is None:
+                with self._chain_span(
+                    "worker.d2h", bytes=delta_f32_bytes, slices=1
+                ):
+                    delta_h, small_h = jax.device_get((delta_dev, small))
+            else:
+                # the slices' copies start now and land behind the
+                # request, which sends each as it does; on the serial
+                # chain the device stands still for this sync, so a
+                # slice is cut only as its copy is asked for and the
+                # device never holds more than those in flight beside
+                # the delta
+                stream = delta_stream.DeltaStream(
+                    slice_bounds,
+                    (cut_ahead.popleft() for _ in slice_bounds)
+                    if cut_ahead is not None
+                    else (
+                        self._delta_slice(delta_dev, lo, hi)
+                        for lo, hi in slice_bounds
+                    ),
+                )
+                stream.start()
+                delta_h = stream.vector()
+                small_h = jax.device_get(small)  # beside the slices
+            aux_h, loss_h, step_loss_h, gbets_h = small_h
             stats = (aux_h or {}).get(WINDOW_STATS)
             if stats:
                 # what the model's last step of the window left in its
@@ -2156,7 +2199,17 @@ class Worker:
             else:
                 versions = None
                 push_t0 = time.monotonic()
-                resp = self._call_master("ReportLocalUpdate", req)
+                try:
+                    resp = self._call_master("ReportLocalUpdate", req)
+                finally:
+                    if stream is not None:
+                        # ONE `worker.d2h` a sync, on the sync's own
+                        # thread: first slice asked to last landed
+                        t_asked, t_landed = stream.settle()
+                        self.timers.record_span(
+                            "worker.d2h", t_asked, t_landed,
+                            bytes=delta_f32_bytes, slices=len(slice_bounds),
+                        )
                 self._observe_push(delta_h, push_t0, wire_form)
             with self._report_lock:
                 if epoch != self._sync_epoch:
@@ -2270,6 +2323,47 @@ class Worker:
             self._subtract_into_base = jax.jit(subtract, donate_argnums=(1,))
         base, self._base_flat = self._base_flat, None
         return self._subtract_into_base(self._flat, base)
+
+    def _delta_in_slices(self, bounds):
+        """flat - base formed in the slices it will leave the device
+        in, each a buffer of its own, by one program (the device
+        trace's `jit_subtract` still, with as many results as slices):
+        the overlapped chain's form of a delta that `delta_stream`
+        carries. The device then holds one delta's worth, as it did;
+        slices cut from a whole delta would be allocated beside it for
+        every window the step loop runs ahead of the device (three
+        deltas more at the peak: PERF.md, PR 45). Nothing is donated:
+        with syncs in flight the base may still be a snapshot."""
+        if self._subtract_in_slices is None:
+            bounds = tuple(bounds)
+
+            def subtract(flat, base):
+                return tuple(flat[lo:hi] - base[lo:hi] for lo, hi in bounds)
+
+            self._subtract_in_slices = jax.jit(subtract)
+        args = (self._flat, self._base_flat)
+        with self._first_call(self._subtract_in_slices, args):
+            return deque(self._subtract_in_slices(*args))
+
+    def _delta_slice(self, delta_dev, lo: int, hi: int):
+        """Elements [lo, hi) of the device's delta in a buffer of their
+        own, for `delta_stream` on the serial chain, where the delta
+        lies whole in the donated base's place and the device stands
+        still for the sync: one jitted program a slice length (all
+        equal slices share one, the tail has its own), the start
+        traced, so a job compiles two in its first sync and none
+        after."""
+        size = hi - lo
+        program = self._slice_programs.get(size)
+        if program is None:
+
+            def delta_slice(delta, start):  # the trace's `jit_delta_slice`
+                return jax.lax.dynamic_slice(delta, (start,), (size,))
+
+            program = self._slice_programs[size] = jax.jit(delta_slice)
+        args = (delta_dev, np.int32(lo))
+        with self._first_call(program, args):
+            return program(*args)
 
     @property
     def sync_decisions(self):

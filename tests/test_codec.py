@@ -214,3 +214,113 @@ def test_int8_wire_bytes_are_quarter_of_f32():
     f32_bytes = len(codec.dumps({"d": v}))
     int8_bytes = len(codec.dumps({"d": qd}))
     assert int8_bytes < f32_bytes / 3.5, (f32_bytes, int8_bytes)
+
+
+# -- a LeafVector piece that may still be on its way (PR 45) ------------------
+
+
+class _Lander:
+    """Pieces of a float32 vector that land when told to, each waited
+    for through `codec.PendingPiece`; counts the waits."""
+
+    def __init__(self, vec, cuts):
+        import threading
+
+        self.vec = vec
+        self.bounds = list(zip([0] + cuts, cuts + [vec.size]))
+        self.events = [threading.Event() for _ in self.bounds]
+        self.waits = [0] * len(self.bounds)
+
+    def _wait(self, i, timeout):
+        self.waits[i] += 1
+        if not self.events[i].wait(timeout):
+            raise TimeoutError(f"piece {i}")
+        lo, hi = self.bounds[i]
+        return self.vec[lo:hi]
+
+    def vector(self, plain=()):
+        """The vector as pending pieces; those in `plain` as arrays."""
+        return codec.LeafVector([
+            self.vec[lo:hi] if i in plain
+            else codec.PendingPiece(hi - lo, lambda t, i=i: self._wait(i, t))
+            for i, (lo, hi) in enumerate(self.bounds)
+        ])
+
+    def land(self, *which):
+        for i in which or range(len(self.events)):
+            self.events[i].set()
+
+
+def test_a_frame_of_pending_pieces_is_the_plain_vector_s_frame():
+    """The header, the part list's layout and the length are made from
+    the pieces' sizes alone, before a byte of them has landed; once
+    they have, the parts' bytes are `dumps` of the vector itself."""
+    vec = np.random.default_rng(45).standard_normal(10007).astype(np.float32)
+    lander = _Lander(vec, [4000, 4001, 9000])
+    request = {"delta_flat": None, "steps": 16, "k": np.arange(5)}
+    want = codec.dumps({**request, "delta_flat": vec})
+    parts, total = codec.dumps_parts(
+        {**request, "delta_flat": lander.vector(plain={1})}
+    )
+    assert total == len(want) and lander.waits == [0, 0, 0, 0]
+    pending = [p for p in parts if isinstance(p, codec.PendingPiece)]
+    assert [p.size for p in pending] == [4000, 4999, 1007]
+    assert all(p.peek() is None for p in pending)
+    # everything before the first pending piece is the plain frame's start
+    head = b"".join(
+        bytes(p) for p in parts[:parts.index(pending[0])]
+    )
+    assert want.startswith(head) and len(head) >= 64
+    with pytest.raises(TimeoutError):
+        codec.part_bytes(pending[0], 0.01)
+    lander.land()
+    assert b"".join(bytes(codec.part_bytes(p)) for p in parts) == want
+    got = codec.loads(want)["delta_flat"]
+    assert got.dtype == np.float32 and np.array_equal(got, vec)
+    # what landed is kept: a second read waits for nothing
+    assert [p.peek() is not None for p in pending] == [True] * 3
+    assert b"".join(bytes(codec.part_bytes(p)) for p in parts) == want
+    assert lander.waits == [2, 0, 1, 1]  # piece 0: the timeout, then once
+
+
+def test_dumps_and_asarray_wait_for_the_pieces():
+    import threading
+
+    vec = np.arange(999, dtype=np.float32)
+    lander = _Lander(vec, [10, 500])
+    threading.Timer(0.05, lander.land).start()
+    assert codec.dumps({"v": lander.vector()}) == codec.dumps({"v": vec})
+    assert np.array_equal(np.asarray(lander.vector()), vec)
+    assert np.asarray(lander.vector(), dtype=np.float64).dtype == np.float64
+
+
+def test_a_delta_s_size_is_counted_without_waiting_or_joining():
+    lander = _Lander(np.zeros(300, np.float32), [100])
+    vector = lander.vector()
+    assert codec.delta_length(vector) == 300
+    assert codec.delta_nbytes(vector) == 1200
+    assert lander.waits == [0, 0]
+
+
+@pytest.mark.parametrize("landed,error", [
+    (np.zeros(7, np.float64), TypeError),
+    (np.zeros((7, 1), np.float32), TypeError),
+    (np.zeros(14, np.float32)[::2], TypeError),
+    (np.zeros(6, np.float32), ValueError),
+])
+def test_a_piece_that_lands_as_something_else_is_refused(landed, error):
+    piece = codec.PendingPiece(7, lambda timeout: landed)
+    with pytest.raises(error):
+        piece.landed()
+    assert piece.peek() is None
+
+
+def test_a_piece_s_own_failure_comes_out_as_it_was_raised():
+    def wait(timeout):
+        raise RuntimeError("the copy failed")
+
+    vector = codec.LeafVector([np.zeros(3, np.float32),
+                               codec.PendingPiece(4, wait)])
+    assert vector.size == 7
+    with pytest.raises(RuntimeError, match="the copy failed"):
+        codec.dumps({"v": vector})

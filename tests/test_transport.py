@@ -16,6 +16,7 @@ describe, and the resource lifecycle of the socket listener.
 
 import os
 import socket
+import threading
 import time
 import weakref
 
@@ -1562,10 +1563,10 @@ def joins(monkeypatch):
         made.append(real_pack(obj))
         return made[-1]
 
-    def contiguous(self):
+    def contiguous(self, *timeout):
         if not self.joined:
             counts.append(id(self))
-        return real_join(self)
+        return real_join(self, *timeout)
 
     monkeypatch.setattr(messages, "pack_parts", pack_parts)
     monkeypatch.setattr(messages.PackedParts, "contiguous", contiguous)
@@ -1683,6 +1684,338 @@ def test_a_prepacked_request_passes_through_unjoined(keeper):
     client.call("Push", payload, 10.0)
     assert dispatcher.frames == [("Push", frame)] and not payload.joined
     assert payload.contiguous() is frame
+
+
+# -- a piece of the frame may still be on its way (PR 45) ---------------------
+
+
+class _Slices:
+    """A float32 vector in pieces that land one by one, in order, from
+    a thread of their own and at their own pace: what
+    `worker/delta_stream.py` is to a request, without a device."""
+
+    def __init__(self, vec, cuts, pace=0.0, fail_at=None, stop_at=None):
+        self.vec = vec
+        self.bounds = list(zip([0] + cuts, cuts + [vec.size]))
+        self.events = [threading.Event() for _ in self.bounds]
+        self.error = None
+        self._pace, self._fail_at, self._stop_at = pace, fail_at, stop_at
+        self.thread = threading.Thread(target=self._land, daemon=True)
+
+    def _land(self):
+        for i, event in enumerate(self.events):
+            time.sleep(self._pace * (1 + i % 3))  # out of step with the send
+            if i == self._stop_at:
+                return  # this one and the rest never land
+            if i == self._fail_at:
+                self.error = RuntimeError(f"the copy of slice {i} failed")
+                for e in self.events:
+                    e.set()
+                return
+            event.set()
+
+    def _wait(self, i, timeout):
+        if not self.events[i].wait(timeout):
+            raise TimeoutError(f"slice {i} has not landed")
+        if self.error is not None and i >= self._fail_at:
+            raise self.error
+        lo, hi = self.bounds[i]
+        return self.vec[lo:hi]
+
+    def request(self):
+        pieces = [
+            codec.PendingPiece(hi - lo, lambda t, i=i: self._wait(i, t))
+            for i, (lo, hi) in enumerate(self.bounds)
+        ]
+        return {"delta_flat": codec.LeafVector(pieces), "steps": 16,
+                "aux_state": {"m": np.ones(5, np.float32)}, "report_key": "k"}
+
+    def plain(self):
+        return {**self.request(), "delta_flat": self.vec}
+
+
+def _late(n=300_003, cuts=(70_001, 70_002, 200_000), **kw):
+    return _Slices(_rng(45).standard_normal(n, dtype=np.float32), list(cuts), **kw)
+
+
+@pytest.mark.parametrize("max_call", [None, 100_000], ids=["whole", "cut"])
+def test_pieces_that_land_late_arrive_as_the_frame(keeper, monkeypatch, max_call):
+    """What the listener reads is `head + b"".join(parts)` of the
+    vector itself, though the pieces landed from another thread while
+    the frame was leaving; with the bytes a call may move lowered, a
+    piece is cut across turns like any long part."""
+    if max_call:
+        monkeypatch.setattr(transport, "MAX_CALL_BYTES", max_call)
+    dispatcher, client = keeper
+    slices = _late(pace=0.02)
+    payload = messages.pack_parts(slices.request())
+    frame = codec.dumps(slices.plain())
+    assert payload.pending and len(payload) == len(frame)
+    slices.thread.start()
+    resp = client.call("ReportLocalUpdate", payload, 10.0)
+    assert messages.unpack(resp) == {"ok": 1}
+    assert dispatcher.frames == [("ReportLocalUpdate", frame)]
+    assert payload.streamed and not payload.joined
+    assert payload.waited > 0.02  # it stood waiting, and says for how long
+    # sent again (a retry): from the host copies, waiting for nothing
+    waited = payload.waited
+    client.call("ReportLocalUpdate", payload, 10.0)
+    assert dispatcher.frames[1] == ("ReportLocalUpdate", frame)
+    assert payload.waited == waited
+
+
+def test_a_retry_after_a_broken_connection_resends_the_same_bytes(keeper):
+    """The first attempt's connection breaks with the frame half
+    gone; the second goes out whole from what had landed by then and
+    what lands still."""
+    dispatcher, client = keeper
+    slices = _late(pace=0.01)
+    payload = messages.pack_parts(slices.request())
+    real_checkout, broken = client._checkout, []
+
+    class _Breaks:
+        def __init__(self, conn):
+            self._conn, self._turns = conn, 0
+
+        def sendmsg(self, bufs):
+            self._turns += 1
+            if self._turns == 3:
+                raise ConnectionResetError("cut")
+            return self._conn.sendmsg(bufs)
+
+        def __getattr__(self, name):
+            return getattr(self._conn, name)
+
+    def checkout(large=False):
+        conn = real_checkout(large)
+        if not broken:
+            broken.append(conn)
+            return _Breaks(conn)
+        return conn
+
+    client._checkout = checkout
+    slices.thread.start()
+    with pytest.raises(PolicyRpcError) as ei:
+        client.call("ReportLocalUpdate", payload, 10.0)
+    assert ei.value.code() == grpc.StatusCode.UNAVAILABLE
+    assert broken[0].fileno() == -1 and dispatcher.frames == []
+    client.call("ReportLocalUpdate", payload, 10.0)
+    assert dispatcher.frames == [
+        ("ReportLocalUpdate", codec.dumps(slices.plain()))
+    ]
+
+
+@pytest.mark.parametrize("kind", ["error", "drop"])
+def test_a_drawn_fault_s_retry_resends_the_landed_pieces(unset_env, kind):
+    """Through `RpcClient` and its policy: the dropped response costs
+    a whole second frame, and the server decodes the same delta both
+    times."""
+    seen = []
+
+    def update(req):
+        seen.append(np.array(req["delta_flat"]))
+        return {"version": len(seen)}
+
+    server = RpcServer({"ReportLocalUpdate": update}, port=0)
+    server.start()
+    plan = FaultPlan.from_spec(
+        {"faults": [{"kind": kind, "methods": ["ReportLocalUpdate"], "nth": 1}]}
+    )
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(), fault_plan=plan
+    )
+    slices = _late(pace=0.01)
+    slices.thread.start()
+    try:
+        resp = client.call(
+            "ReportLocalUpdate", slices.request(), timeout=10, idempotent=True
+        )
+    finally:
+        client.close()
+        server.stop()
+    assert resp == {"version": len(seen)} and len(seen) == (2 if kind == "drop" else 1)
+    for got in seen:
+        assert got.dtype == np.float32 and np.array_equal(got, slices.vec)
+
+
+@pytest.mark.parametrize("env_fixture", ["inproc_env", "grpc_env"])
+def test_a_one_buffer_carrier_waits_for_every_piece_and_joins_once(
+    env_fixture, request, timeline_spans, joins
+):
+    """gRPC and `inproc` need the frame in one buffer: they wait for
+    the pieces (inside the call's deadline) and join, which is what
+    they did with a delta that was there; the span says `streamed:
+    false` and how long the join waited."""
+    request.getfixturevalue(env_fixture)
+    made, counts = joins
+    seen = []
+
+    def update(req):
+        seen.append(np.array(req["delta_flat"]))
+        return {"version": 1}
+
+    server = RpcServer({"ReportLocalUpdate": update}, port=0)
+    server.start()
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(),
+        timeline=("ReportLocalUpdate",),
+    )
+    slices = _late(pace=0.02)
+    slices.thread.start()
+    try:
+        tier = _tier(client)
+        client.call("ReportLocalUpdate", slices.request(), timeout=10)
+    finally:
+        client.close()
+        server.stop()
+    assert np.array_equal(seen[0], slices.vec)
+    assert counts == [id(made[0]), id(made[1])]  # request, response: once each
+    assert made[0].pending and made[0].joined and not made[0].streamed
+    trip = _span_args(timeline_spans, "rpc.client.ReportLocalUpdate")
+    assert (trip["transport"], trip["joined"], trip["streamed"]) == (
+        tier, True, False
+    )
+    assert trip["waited_ms"] > 20
+
+
+def test_the_socket_s_span_says_streamed_and_how_long_it_waited(
+    unset_env, timeline_spans
+):
+    server = RpcServer({"ReportLocalUpdate": lambda req: {"version": 3}}, port=0)
+    server.start()
+    client = RpcClient(
+        f"localhost:{server.port}", policy=fast_policy(),
+        timeline=("ReportLocalUpdate",),
+    )
+    slices = _late(pace=0.02)
+    slices.thread.start()
+    try:
+        client.call("ReportLocalUpdate", slices.request(), timeout=10)
+        client.call("ReportLocalUpdate", slices.plain(), timeout=10)
+    finally:
+        client.close()
+        server.stop()
+    late, plain = [s["args"] for s in timeline_spans("rpc.client.ReportLocalUpdate")]
+    assert (late["transport"], late["joined"], late["streamed"]) == ("uds", False, True)
+    assert late["waited_ms"] > 20 and late["version"] == 3
+    assert (plain["joined"], plain["streamed"], plain["waited_ms"]) == (False, False, 0.0)
+
+
+def test_a_piece_that_fails_to_land_closes_the_connection(keeper):
+    """The error comes out of the call as it was raised; the listener
+    reads a peer that closed mid-frame, hands its dispatcher nothing,
+    and serves the next frame, which comes by another connection."""
+    dispatcher, client = keeper
+    slices = _late(pace=0.01, fail_at=2)
+    payload = messages.pack_parts(slices.request())
+    conns, real_checkout = [], client._checkout
+
+    def checkout(large=False):
+        conns.append(real_checkout(large))
+        return conns[-1]
+
+    client._checkout = checkout
+    slices.thread.start()
+    with pytest.raises(RuntimeError, match="the copy of slice 2 failed"):
+        client.call("ReportLocalUpdate", payload, 10.0)
+    assert conns[0].fileno() == -1 and client._pool == []
+    after = {"delta_flat": np.ones(9, np.float32)}
+    resp = client.call("ReportLocalUpdate", messages.pack_parts(after), 10.0)
+    assert messages.unpack(resp) == {"ok": 1}
+    assert dispatcher.frames == [("ReportLocalUpdate", codec.dumps(after))]
+    assert conns[1] is not conns[0]
+
+
+def test_the_call_s_deadline_covers_the_waiting(keeper):
+    """A piece that never lands fails the call when the call's budget
+    is spent, as a peer that stops reading does: DEADLINE_EXCEEDED,
+    the connection closed, nothing applied."""
+    dispatcher, client = keeper
+    slices = _late(pace=0.01, stop_at=1)
+    slices.thread.start()
+    t0 = time.monotonic()
+    with pytest.raises(PolicyRpcError) as ei:
+        client.call(
+            "ReportLocalUpdate", messages.pack_parts(slices.request()), 0.4
+        )
+    assert ei.value.code() == grpc.StatusCode.DEADLINE_EXCEEDED
+    assert 0.35 < time.monotonic() - t0 < 3.0
+    assert client._pool == [] and dispatcher.frames == []
+
+
+def _parent_send_parts(conn, head, parts, deadline=None):
+    """`transport._send_parts` as the parent commit (e7e4fb3) has it:
+    the reference for the system calls a frame with every piece there
+    makes."""
+    bufs = [memoryview(head)]
+    bufs += [memoryview(part) for part in parts if len(part)]
+    i, left = 0, sum(buf.nbytes for buf in bufs)
+    while i < len(bufs):
+        if deadline is not None:
+            conn.settimeout(max(0.001, deadline - time.monotonic()))
+        turn = bufs[i:i + transport._IOV_MAX]
+        if left > transport.MAX_CALL_BYTES:
+            turn = transport._cut_to(turn, transport.MAX_CALL_BYTES)
+        sent = conn.sendmsg(turn)
+        left -= sent
+        while sent:
+            n = bufs[i].nbytes
+            if sent < n:
+                bufs[i] = bufs[i][sent:]
+                break
+            sent -= n
+            i += 1
+
+
+class _CountingSocket:
+    """Takes a fixed number of bytes a call, like a full socket
+    buffer, and keeps what each call offered and what it took."""
+
+    def __init__(self, room):
+        self.room, self.calls, self.taken = room, [], bytearray()
+
+    def settimeout(self, _):
+        pass
+
+    def sendmsg(self, bufs):
+        self.calls.append([b.nbytes for b in bufs])
+        data = b"".join(bytes(b) for b in bufs)[:self.room]
+        self.taken += data
+        return len(data)
+
+
+@pytest.mark.parametrize("tree", ["perstep", "landed", "long"])
+def test_a_frame_with_every_piece_there_makes_the_parent_s_system_calls(
+    monkeypatch, tree
+):
+    """B's 270-part per-step frame, a retry's frame whose pieces have
+    all landed, and a frame longer than a call moves: gathered turn
+    by turn exactly as before."""
+    if tree == "long":
+        monkeypatch.setattr(transport, "MAX_CALL_BYTES", 50_000)
+    if tree == "landed":
+        slices = _late()
+        slices.thread.start()
+        request = slices.request()
+        np.asarray(request["delta_flat"])  # every piece waited for
+        parts = messages.pack_parts(request).parts
+        reference = messages.pack_parts(slices.plain()).parts
+    else:
+        parts = reference = messages.pack_parts(
+            {"gradient": _leaf_model(269), "version": 4}
+        ).parts
+        assert len(parts) > 270
+    head = transport._REQ_HEADER.pack(4, 1) + b"Push"
+    new, old = _CountingSocket(65_536), _CountingSocket(65_536)
+    assert transport._send_parts(new, head, parts, time.monotonic() + 5) == 0.0
+    _parent_send_parts(old, head, reference, time.monotonic() + 5)
+    assert bytes(new.taken) == bytes(old.taken)
+    if tree == "landed":
+        # the same bytes in the same turns; the landed pieces are the
+        # buffers the plain vector was one of
+        assert [sum(c) for c in new.calls] == [sum(c) for c in old.calls]
+    else:
+        assert new.calls == old.calls
 
 
 # -- a response reaches the socket as its parts, a model as its leaves --------

@@ -114,3 +114,55 @@ def test_schema_fields_are_unique_per_method():
     by method, so aliasing would hide a drift."""
     classes = list(WIRE_SCHEMAS.values())
     assert len(classes) == len(set(classes))
+
+
+# -- a packed frame whose pieces may still be on their way (PR 45) ------------
+
+
+def _late_vector(vec, cut, events):
+    """`vec` in two pending pieces, each landing with its event."""
+    def wait(i, lo, hi, timeout):
+        if not events[i].wait(timeout):
+            raise TimeoutError(f"piece {i} has not landed")
+        return vec[lo:hi]
+
+    return codec.LeafVector([
+        codec.PendingPiece(hi - lo, lambda t, a=(i, lo, hi): wait(*a, t))
+        for i, (lo, hi) in enumerate([(0, cut), (cut, vec.size)])
+    ])
+
+
+def test_packed_parts_says_pending_and_joins_once_after_waiting():
+    import threading
+
+    vec = np.arange(5000, dtype=np.float32)
+    events = [threading.Event(), threading.Event()]
+    request = {"delta_flat": _late_vector(vec, 1234, events), "steps": 4}
+    payload = M.pack_parts(request)
+    want = M.pack({"delta_flat": vec, "steps": 4})
+    assert payload.pending and payload.streamed and not payload.joined
+    assert len(payload) == len(want) and payload.waited == 0.0
+    events[0].set()
+    # a carrier that needs one buffer waits, inside the call's budget
+    with pytest.raises(TimeoutError):
+        payload.contiguous(0.05)
+    assert not payload.joined and payload.waited >= 0.04
+    threading.Timer(0.05, events[1].set).start()
+    data = payload.contiguous(5.0)
+    assert data == want and payload.contiguous() is data
+    assert payload.joined and not payload.streamed
+    waited = payload.waited
+    payload.contiguous(0.0)  # joined already: nothing to wait for
+    assert payload.waited == waited
+    assert M.unpack(data)["steps"] == 4
+
+
+@pytest.mark.parametrize("obj", [
+    {"worker_id": 3},
+    {"params_flat": codec.LeafVector([np.ones(4, np.float32)])},
+    M.Prepacked(codec.dumps({"x": 1})),
+])
+def test_a_frame_with_every_piece_there_is_not_pending(obj):
+    payload = M.pack_parts(obj)
+    assert not payload.pending and not payload.streamed
+    assert payload.contiguous(0.0) == M.pack(obj) and payload.waited == 0.0
