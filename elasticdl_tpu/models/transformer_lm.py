@@ -248,6 +248,8 @@ _FLOAT32_LEAVES = ("router", "router_bias", "dt_bias", "q_norm", "k_norm")
 _OVER_LAYERS = {
     "held_share": jnp.mean,
     "router_entropy": jnp.mean,
+    "route_rows": jnp.mean,
+    "route_full": jnp.sum,
     "router_bias_absmax": jnp.max,
     "kda_log_decay_min": jnp.min,
     "shortconv_gate_absmax": jnp.max,
@@ -892,9 +894,11 @@ def plain_forward_stats(
     cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray
 ):
     """`plain_forward` and, third, what the expert layers' routers did
-    with this batch: `expert_tokens` [expert layers, held], and the
-    layers' mean `held_share` and `router_entropy` ({} without
-    `moe_top_k`). The zoo adapter leaves it in `window_stats`."""
+    with this batch: `expert_tokens` [expert layers, held], the
+    layers' mean `held_share`, `router_entropy` and `route_rows` (the
+    length of the sorted buffer a layer took) and `route_full`, how
+    many layers took the full one ({} without `moe_top_k`). The zoo
+    adapter leaves it in `window_stats`."""
     from elasticdl_tpu.parallel.moe import moe_ffn_local, moe_topk_held
 
     stored = params

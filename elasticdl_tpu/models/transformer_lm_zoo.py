@@ -62,6 +62,8 @@ class TransformerLM:
                 ),
                 "held_share": np.zeros((), np.float32),
                 "router_entropy": np.zeros((), np.float32),
+                "route_rows": np.zeros((), np.float32),
+                "route_full": np.zeros((), np.float32),
             }
             # what a layer kind adds to the routing's three
             if self.cfg.moe_score == "sigmoid":

@@ -17,6 +17,7 @@ auxiliary loss alongside the output.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -191,12 +192,12 @@ def _take_sorted(sorted_rows: jnp.ndarray, pos: jnp.ndarray):
     return jnp.where((pos < rows)[:, None], taken, 0)
 
 
-# The two moves between token order and expert order. `order[r]` is the
-# assignment (token x k + choice) that sorted row r holds and `pos` is
-# its inverse, so each move is a gather and its transpose is the OTHER
-# move, again a gather: differentiated as they stand, both would
-# transpose into scatter-adds of T k rows of d, which the TPU walks row
-# by row.
+# The two moves between token order and expert order on the full
+# buffer. `order[r]` is the assignment (token x k + choice) that sorted
+# row r holds and `pos` is its inverse, so each move is a gather and its
+# transpose is a gather the other way: differentiated as they stand,
+# both would transpose into scatter-adds of T k rows of d, which the TPU
+# walks row by row.
 
 
 @jax.custom_vjp
@@ -218,21 +219,223 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _collect(sorted_rows, order, pos):
-    """Expert order back to assignments: [R, d] -> [T k, d], zeros for
-    an assignment whose row lies behind the buffer."""
-    return _take_sorted(sorted_rows, pos)
+def _collect(sorted_rows, weight, order, pos):
+    """Expert order back to tokens, weighted: sum over a token's k
+    assignments of weight x its sorted row (zeros for an assignment
+    whose row lies behind the buffer): [R, d], [T, k] f32 -> [T, d]."""
+    t, k = weight.shape
+    picked = _take_sorted(sorted_rows, pos).reshape(t, k, -1)
+    return jnp.sum(
+        weight[:, :, None] * picked.astype(jnp.float32), axis=1
+    ).astype(sorted_rows.dtype)
 
 
-def _collect_fwd(sorted_rows, order, pos):
-    return _take_sorted(sorted_rows, pos), order
+def _collect_fwd(sorted_rows, weight, order, pos):
+    return _collect(sorted_rows, weight, order, pos), (
+        sorted_rows, weight, order, pos,
+    )
 
 
-def _collect_bwd(order, g):
-    return g[order], None, None
+def _collect_bwd(saved, g):
+    # neither the T k picked rows nor their T k cotangents are kept or
+    # formed (0.2 GB each at 49,152 rows of 2048): the rows' cotangent
+    # is a gather of R rows of g, the weights' picks the rows once more
+    sorted_rows, weight, order, pos = saved
+    t, k = weight.shape
+    theirs = g[order // k].astype(jnp.float32)  # [R, d]
+    picked = _take_sorted(sorted_rows, pos).reshape(t, k, -1)
+    return (
+        (weight.reshape(-1)[order][:, None] * theirs).astype(sorted_rows.dtype),
+        jnp.sum(
+            picked.astype(jnp.float32) * g.astype(jnp.float32)[:, None, :], axis=-1
+        ),
+        None,
+        None,
+    )
 
 
 _collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+# The same two moves on a rung of R' rows, far fewer than T k: there
+# both walk the R' rows that came and not the T k that could. `tok[r]`
+# is the token sorted row r belongs to. Rows to expert order is a
+# gather of R' rows and its transpose a sum of R' rows into their
+# tokens; the weighted sum back into tokens is that sum and its
+# transpose that gather. A token's at most min(k, n) terms are added in
+# float32 and cast once.
+
+
+def _sum_by_token(rows, tok, t: int):
+    """rows [R', d] float32 summed into their tokens: -> [t, d] f32."""
+    return jax.ops.segment_sum(rows, tok, num_segments=t)
+
+
+@jax.custom_vjp
+def _to_experts(xf, tok):
+    """Token rows to expert order: xf [T, d] -> [R', d]."""
+    return xf[tok]
+
+
+def _to_experts_fwd(xf, tok):
+    return xf[tok], (tok, xf.shape[0])
+
+
+def _to_experts_bwd(saved, g):
+    tok, t = saved
+    return _sum_by_token(g.astype(jnp.float32), tok, t).astype(g.dtype), None
+
+
+_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_tokens(sorted_rows, gate, tok, t: int):
+    """Expert order back to tokens, weighted: sum over a token's rows
+    of gate[r] x sorted_rows[r]: [R', d], [R'] f32 -> [t, d]."""
+    weighted = gate[:, None] * sorted_rows.astype(jnp.float32)
+    return _sum_by_token(weighted, tok, t).astype(sorted_rows.dtype)
+
+
+def _to_tokens_fwd(sorted_rows, gate, tok, t):
+    return _to_tokens(sorted_rows, gate, tok, t), (sorted_rows, gate, tok)
+
+
+def _to_tokens_bwd(_t, saved, g):
+    sorted_rows, gate, tok = saved
+    theirs = g[tok].astype(jnp.float32)  # [R', d]
+    return (
+        (gate[:, None] * theirs).astype(sorted_rows.dtype),
+        jnp.sum(sorted_rows.astype(jnp.float32) * theirs, axis=-1),
+        None,
+    )
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The ladder: the buffer's length follows the rows that came
+
+# Measured (scripts/route_probe.py; PERF.md, PR 46): off the full buffer
+# a layer's cost hardly follows the rung, so three lower rungs are
+# enough, and 3/8 catches what 2/8 just misses for less than 4/8 would.
+ROW_TILE = 256  # every rung is whole tiles of rows, as the grouped matmul walks them
+_RUNG_EIGHTHS = (1, 2, 3)  # the lower rungs, in eighths of the full buffer
+
+
+def route_rungs(t: int, top_k: int, n: int) -> Tuple[int, ...]:
+    """The lengths the sorted buffer can take for `t` tokens, ascending,
+    from the shapes alone. The last is the full buffer, t x min(k, n)
+    rows, which holds whatever the router does."""
+    full = t * min(top_k, n)
+    tiles = 8 * ROW_TILE
+    lower = {-(-full * e // tiles) * ROW_TILE for e in _RUNG_EIGHTHS}
+    return tuple(sorted(r for r in lower if r < full)) + (full,)
+
+
+def _rung_taken(rungs: Tuple[int, ...], sizes):
+    """Index of the smallest rung that holds the sum(sizes) rows."""
+    lower = jnp.asarray(rungs[:-1], jnp.int32)
+    return jnp.sum(jnp.sum(sizes) > lower).astype(jnp.int32)
+
+
+def _swiglu_groups(rows, experts, sizes):
+    wg, wu, wd = experts
+    hidden = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) * lax.ragged_dot(
+        rows, wu, sizes
+    )
+    return lax.ragged_dot(hidden, wd, sizes)
+
+
+def _on_rung(rung: int, xf, weight, experts, order, sizes):
+    """The held experts' part of the layer on a buffer of `rung` rows,
+    which must hold sum(sizes): xf [T, d], weight [T, k] f32 (0 for an
+    assignment not held), order [T k] -> [T, d]."""
+    t, k = weight.shape
+    with jax.named_scope("route"):
+        taken = order[:rung]
+        tok = taken // k
+        used = jnp.arange(rung) < jnp.sum(sizes)
+        # rows behind the groups are zeros going in and coming out:
+        # what a grouped matmul leaves there is not specified
+        rows = jnp.where(used[:, None], _to_experts(xf, tok), 0)
+        gate = jnp.where(used, weight.reshape(-1)[taken], 0.0)
+    with jax.named_scope("experts"):
+        out = _swiglu_groups(rows, experts, sizes)
+    with jax.named_scope("route"):
+        return _to_tokens(jnp.where(used[:, None], out, 0), gate, tok, t)
+
+
+def _on_full_buffer(xf, weight, experts, order, sizes):
+    """The top rung: T x min(k, n) rows, every move a gather of that
+    many rows or of T k, whatever came."""
+    t, k = weight.shape
+    with jax.named_scope("route"):
+        pos = jnp.argsort(order).astype(jnp.int32)  # its inverse
+        order = order[: t * min(k, sizes.shape[0])]
+        used = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
+        rows = jnp.where(used, _dispatch(xf, order, pos), 0)
+    with jax.named_scope("experts"):
+        out = _swiglu_groups(rows, experts, sizes)
+    with jax.named_scope("route"):
+        return _collect(jnp.where(used, out, 0), weight, order, pos)
+
+
+def _branches(weight, sizes):
+    """(index of the rung to take, one function a rung)."""
+    t, k = weight.shape
+    rungs = route_rungs(t, k, sizes.shape[0])
+    return _rung_taken(rungs, sizes), [
+        functools.partial(_on_rung, rung) for rung in rungs[:-1]
+    ] + [_on_full_buffer]
+
+
+# One custom_vjp round the switch, each rule choosing the rung anew: a
+# `lax.switch` differentiated as it stands hands the backward pass the
+# union of every branch's residuals, the full buffer's among them, and
+# writes zeros for the branches not taken. Here nothing sized by a rung
+# leaves a branch: the backward rule's branch recomputes its own
+# forward pass (under the layer's `_remat` that is the recomputation
+# the layer would have made anyway, and the forward rule's switch, whose
+# result nothing reads there, is dropped).
+
+
+@jax.custom_vjp
+def _held_experts(xf, weight, experts, order, sizes):
+    """sum_{e held} weight_e E_e(x) on the smallest rung that holds the
+    rows that came; the chip runs that branch alone."""
+    taken, branches = _branches(weight, sizes)
+    return lax.switch(taken, branches, xf, weight, experts, order, sizes)
+
+
+def _held_experts_fwd(xf, weight, experts, order, sizes):
+    saved = (xf, weight, experts, order, sizes)
+    return _held_experts(*saved), saved
+
+
+def _held_experts_bwd(saved, g):
+    xf, weight, experts, order, sizes = saved
+    taken, branches = _branches(weight, sizes)
+
+    def backward(branch):
+        def run(xf, weight, experts, g):
+            _out, pull = jax.vjp(
+                jax.checkpoint(lambda *held: branch(*held, order, sizes)),
+                xf, weight, experts,
+            )
+            return pull(g)
+
+        return run
+
+    grads = lax.switch(
+        taken, [backward(branch) for branch in branches],
+        xf, weight, experts, g,
+    )
+    return (*grads, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_topk_held(
@@ -266,7 +469,7 @@ def moe_topk_held(
     side), None for a layer without one. -> (y [B, S, d], the
     sequence-wise balance term (unweighted, f32), stats of the
     routing: `expert_tokens` [n] (counts, float32), `held_share`,
-    `router_entropy`).
+    `router_entropy`, `route_rows`, `route_full`).
 
     This is one chip's part of an expert-parallel layer, computed
     without the exchange: what the experts held elsewhere would add is
@@ -275,15 +478,18 @@ def moe_topk_held(
     sorted by expert, those to experts not held behind the last held
     group, and the held groups go through `lax.ragged_dot` (on the TPU
     a grouped matmul that walks whole tiles of rows and skips what lies
-    behind the groups). The sorted buffer has T x min(k, n) rows: a
-    token takes k DIFFERENT experts, so at most min(k, n) of its
-    assignments can be held here, and a router that sends every token
-    to held experts fills every row. Anything shorter drops under
-    skew. At uniform routing n / E of the rows are used."""
+    behind the groups). A token takes k DIFFERENT experts, so at most
+    T x min(k, n) assignments can be held here, and a router that sends
+    every token to held experts sends that many; at uniform routing
+    n / E of them come. The sorted buffer is as long as the smallest
+    rung of `route_rungs` that holds the rows that came (sum(sizes),
+    chosen inside the program), and the moves between token order and
+    expert order walk that many rows; the top rung is the full
+    T x min(k, n), so nothing drops under any skew. `route_rows` of the
+    stats is the rung taken, `route_full` 1.0 where it was the top."""
     b, s, d = x.shape
     t = b * s
     first, n = held
-    wg, wu, wd = experts
     xf = x.reshape(t, d)
     with jax.named_scope("route"):
         if score == "softmax":
@@ -298,38 +504,26 @@ def moe_topk_held(
         # inside a group and puts every absent assignment last
         group = jnp.where(here, local, n).reshape(t * top_k)
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        pos = jnp.argsort(order).astype(jnp.int32)  # its inverse
-        order = order[: t * min(top_k, n)]
         sizes = jnp.sum(
             jax.nn.one_hot(group, n + 1, dtype=jnp.int32), axis=0
         )[:n]  # [n] rows of each held expert
-        used = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
-        # rows behind the groups are zeros going in and coming out:
-        # what a grouped matmul leaves there is not specified
-        rows = jnp.where(used, _dispatch(xf, order, pos), 0)
-    with jax.named_scope("experts"):
-        hidden = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) * lax.ragged_dot(
-            rows, wu, sizes
-        )
-        out = lax.ragged_dot(hidden, wd, sizes)
-    with jax.named_scope("route"):
-        picked = _collect(jnp.where(used, out, 0), order, pos)
         weight = jnp.where(here, gate * scaling, 0.0)  # [T, k] f32
-        routed = jnp.sum(
-            weight[:, :, None]
-            * picked.reshape(t, top_k, d).astype(jnp.float32),
-            axis=1,
-        ).astype(x.dtype)
+    routed = _held_experts(xf, weight, tuple(experts), order, sizes)
+    with jax.named_scope("route"):
         balance_term = sequence_balance_loss(
             probs.reshape(b, s, -1), chosen.reshape(b, s, top_k)
         ) if balance else jnp.zeros((), jnp.float32)
         if score != "softmax":  # the entropy of the scores' shares
             probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
         entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1)
+        rungs = route_rungs(t, top_k, n)
+        taken = _rung_taken(rungs, sizes)
         stats = {
             "expert_tokens": sizes.astype(jnp.float32),
             "held_share": jnp.sum(sizes) / jnp.float32(t * top_k),
             "router_entropy": jnp.mean(entropy),
+            "route_rows": jnp.asarray(rungs, jnp.float32)[taken],
+            "route_full": (taken == len(rungs) - 1).astype(jnp.float32),
         }
         if bias is not None:
             stats["router_bias_absmax"] = jnp.max(jnp.abs(bias))

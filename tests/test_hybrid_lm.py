@@ -467,10 +467,11 @@ def test_the_zoo_adapter_states_the_hybrid_s_window_stats():
 
     stats = zoo.custom_model().init(jax.random.PRNGKey(0), None)[WINDOW_STATS]
     assert sorted(stats) == [
-        "expert_tokens", "held_share", "kda_log_decay_min",
-        "router_bias_absmax", "router_entropy",
+        "expert_tokens", "held_share", "kda_log_decay_min", "route_full",
+        "route_rows", "router_bias_absmax", "router_entropy",
     ]
     assert stats["expert_tokens"].shape == (4, 4)
+    assert stats["route_rows"].shape == stats["route_full"].shape == ()
 
 
 @pytest.mark.parametrize("setting", [

@@ -68,7 +68,9 @@ def test_routed_lm_trains_through_master_main_on_the_serial_chain(
         scopes = json.load(f)
     assert scopes["program"] == "jit_window"
     paths = list(scopes["instructions"].values())
-    for want in ("mla", "moe/route", "moe/experts", "moe/shared", "mlp"):
+    for want in ("mla", "moe/route", "moe/cond/branch_0_fun/experts",
+                 "moe/cond/branch_3_fun/experts", "moe/shared", "mlp"):
+        # (the experts run inside the switch over the ladder's rungs)
         assert any(want in p for p in paths), want
     spans = []
     for path in glob.glob(os.path.join(logs, "worker-0.spans.jsonl")):
@@ -84,6 +86,16 @@ def test_routed_lm_trains_through_master_main_on_the_serial_chain(
         sum(map(sum, tokens)) / (2 * routed), abs=1e-4
     )
     assert 0.0 < args["router_entropy"] <= math.log(16) + 1e-4
+    # the sorted buffer each layer took: a rung of the ladder that holds
+    # the rows that came, the mean over the layers; how many took the top
+    from elasticdl_tpu.parallel.moe import route_rungs
+
+    rungs = route_rungs(MINIBATCH * SEQ, 3, 4)
+    assert len(rungs) == 4 and rungs[-1] == routed
+    came = [sum(layer) for layer in tokens]
+    taken = [min(r for r in rungs if r >= rows) for rows in came]
+    assert args["route_rows"] == pytest.approx(sum(taken) / len(taken))
+    assert args["route_full"] == sum(r == rungs[-1] for r in taken)
 
 
 @pytest.mark.parametrize("setting", [

@@ -124,10 +124,14 @@ def test_the_gate_s_largest_magnitude_is_reported_over_the_conv_layers():
     _out, state = model.apply(variables, tokens, mutable=[WINDOW_STATS])
     stats = state[WINDOW_STATS]
     assert sorted(stats) == [
-        "expert_tokens", "held_share", "router_bias_absmax", "router_entropy",
-        "shortconv_gate_absmax",
+        "expert_tokens", "held_share", "route_full", "route_rows",
+        "router_bias_absmax", "router_entropy", "shortconv_gate_absmax",
     ]
     assert stats["expert_tokens"].shape == (4, 4)
+    # 40 tokens, top-3 with 4 experts held: at most 120 rows, less than
+    # a row tile, so the ladder is the full buffer alone in all 4 layers
+    assert float(stats["route_rows"]) == 120.0
+    assert float(stats["route_full"]) == 4.0
     # a layer whose output projection is scaled up does not move it; one
     # whose gates are does
     louder = jax.tree_util.tree_map(jnp.asarray, variables["params"])

@@ -80,7 +80,9 @@ def test_shortconv_lm_trains_through_master_main_on_the_serial_chain(
     paths = list(scopes["instructions"].values())
     for want in ("conv/shortconv/in_proj", "conv/shortconv/gate_conv",
                  "conv/shortconv/out_proj", "attention", "moe/route",
-                 "moe/experts", "mlp", "head"):
+                 "moe/cond/branch_0_fun/experts",
+                 "moe/cond/branch_3_fun/experts", "mlp", "head"):
+        # (the experts run inside the switch over the ladder's rungs)
         assert any(want in p for p in paths), want
     assert not any("moe/shared" in p for p in paths)
     spans = []
@@ -97,6 +99,16 @@ def test_shortconv_lm_trains_through_master_main_on_the_serial_chain(
         sum(map(sum, tokens)) / (4 * routed), abs=1e-4
     )
     assert 0.0 < args["router_entropy"] <= math.log(16) + 1e-4
+    # the sorted buffer each layer took: a rung of the ladder that holds
+    # the rows that came, the mean over the layers; how many took the top
+    from elasticdl_tpu.parallel.moe import route_rungs
+
+    rungs = route_rungs(MINIBATCH * SEQ, 3, 4)
+    assert len(rungs) == 4 and rungs[-1] == routed
+    came = [sum(layer) for layer in tokens]
+    taken = [min(r for r in rungs if r >= rows) for rows in came]
+    assert args["route_rows"] == pytest.approx(sum(taken) / len(taken))
+    assert args["route_full"] == sum(r == rungs[-1] for r in taken)
     assert args["shortconv_gate_absmax"] > 0.0
     assert args["router_bias_absmax"] == 0.0
     programs = {s["args"].get("program") for s in spans
