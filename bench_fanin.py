@@ -40,8 +40,8 @@ bar is the N=256 speedup of loop_combine over blocking on the same
 machine (>= 4x on the best cell; the top-k cell is the headline — that
 is the wire form fan-in-at-scale deployments ship).
 
-Prints ONE JSON line; also importable (`run_suite`) so bench.py embeds
-the numbers in its own JSON record.
+Prints ONE JSON line; also importable (`run_suite`,
+tests/test_fanin_bench.py).
 """
 
 from __future__ import annotations

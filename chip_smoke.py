@@ -374,7 +374,7 @@ def run_child(name, cpu):
 def child_kernels(cpu):
     """value_and_grad through flash_attention — forward, dq and dk/dv
     kernels — against reference_attention in f32, at the head shapes
-    bench_transformer.py's configs produce."""
+    a transformer's configs produce."""
     from elasticdl_tpu.common.args import enable_compile_cache
     from elasticdl_tpu.common.device import require_device
     from elasticdl_tpu.ops.flash_attention import (
@@ -406,7 +406,7 @@ def child_kernels(cpu):
 def child_meshes(cpu):
     """One transformer_lm.build_train_step step on each of the two
     four-device meshes __graft_entry__.dryrun_multichip builds — (pp 2,
-    sp 2) and (sp 2, tp 2) — at bench_transformer.py's TPU widths."""
+    sp 2) and (sp 2, tp 2)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -1,9 +1,9 @@
 """Trace-driven churn scenarios: spot-market failure shapes as data.
 
 The chaos plane (rpc/chaos.py) injects single faults at the RPC layer;
-the benches hard-code one churn shape each (bench_elastic's kill
-waves, its --sched preemption). What neither covers is the thing a
-spot-market deployment actually faces: *composed* failure sequences —
+the e2e tests hard-code one churn shape each (a kill, a preemption).
+What neither covers is the thing a spot-market deployment
+actually faces: *composed* failure sequences —
 a kill wave landing during a drain, a flash crowd of job arrivals on a
 saturated host, a whole node taking an aggregator down with its
 workers. This module makes those sequences declarative:
@@ -57,8 +57,8 @@ explained record-for-record by the recompute counter (asserted by
 
 Run a packaged trace::
 
-    python bench_elastic.py --trace preemption-storm
-    EDL_ELASTIC_BENCH_TRACE=rolling-node-failure python bench_elastic.py
+    python -m elasticdl_tpu.chaos preemption-storm
+    python -m elasticdl_tpu.chaos /path/to/trace.json --scale 0.5
 
 Reference: ElasticDL documents pod-kill drills manually
 (elasticdl/doc/elastic_scheduling.md); here the drill is a versioned
@@ -759,8 +759,8 @@ class JobRun:
 
     def alive_workers(self) -> List[int]:
         """Live, active, pid-backed workers — the kill-eligible pool
-        (same definition as bench_elastic's kill waves: a pid-less
-        victim would silently shrink the killed fraction)."""
+        (a pid-less victim would silently shrink the killed
+        fraction)."""
         from elasticdl_tpu.cluster.pod_backend import PodPhase
 
         return [

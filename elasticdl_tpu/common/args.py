@@ -579,9 +579,8 @@ def compile_cache_dir() -> str:
 
 
 def enable_compile_cache():
-    """The same placement for a process that compiles itself (bench
-    scripts, chip_smoke's kernel child): call before the first
-    compile."""
+    """The same placement for a process that compiles itself
+    (chip_smoke's children): call before the first compile."""
     import jax
 
     jax.config.update("jax_compilation_cache_dir", compile_cache_dir())

@@ -98,10 +98,8 @@ ENV_FANIN_WAIT_MS = "EDL_FANIN_WAIT_MS"
 ENV_AGG_BATCH = "EDL_AGG_BATCH"
 ENV_AGG_WAIT_MS = "EDL_AGG_WAIT_MS"
 ENV_AGG_UPSTREAM_TIER = "EDL_AGG_UPSTREAM_TIER"
-ENV_BENCH_LINK_FLOOR = "EDL_BENCH_LINK_FLOOR"
 ENV_OPT_MIRROR_SECS = "EDL_OPT_MIRROR_SECS"
 ENV_BET_PREFETCH = "EDL_BET_PREFETCH"
-ENV_BENCH_MFU = "EDL_BENCH_MFU"
 ENV_WORKER_LOG_DIR = "EDL_WORKER_LOG_DIR"
 ENV_TB_BACKEND = "EDL_TPU_TB_BACKEND"
 ENV_NO_NATIVE_KV = "EDL_TPU_NO_NATIVE_KV"
@@ -126,8 +124,6 @@ ENV_FLIGHT_RECORDER_EVENTS = "EDL_FLIGHT_RECORDER_EVENTS"
 ENV_FLIGHT_DIR = "EDL_FLIGHT_DIR"
 ENV_TRACE_SEED = "EDL_TRACE_SEED"
 ENV_TRACE_PROBE_SECS = "EDL_TRACE_PROBE_SECS"
-ENV_ELASTIC_BENCH_TRACE = "EDL_ELASTIC_BENCH_TRACE"
-ENV_ELASTIC_BENCH_TRACE_SCALE = "EDL_ELASTIC_BENCH_TRACE_SCALE"
 ENV_K8S_TESTS = "K8S_TESTS"
 ENV_K8S_TEST_IMAGE = "K8S_TEST_IMAGE"
 ENV_K8S_TEST_NAMESPACE = "K8S_TEST_NAMESPACE"
@@ -268,11 +264,6 @@ ENV_REGISTRY = {
         "EDL_TRANSPORT) — the worker->aggregator leg keeps following "
         "EDL_TRANSPORT"
     ),
-    ENV_BENCH_LINK_FLOOR: (
-        "bench.py: probed link-bandwidth floor in MB/s below which a "
-        "window run is marked link_degraded and excluded from best-of "
-        "selection (default 8.0)"
-    ),
     ENV_OPT_MIRROR_SECS: (
         "recovery plane: seconds between PS optimizer-state mirror "
         "snapshots (bounded-staleness restore ring, master/recovery.py; "
@@ -282,7 +273,6 @@ ENV_REGISTRY = {
         "0 disables the batched-embedding-training lookup prefetch "
         "overlap (default on)"
     ),
-    ENV_BENCH_MFU: "1 prints per-step MFU accounting from the worker hot loop",
     ENV_WORKER_LOG_DIR: (
         "directory for per-worker log files under the ProcessBackend "
         "(empty = inherit stdio)"
@@ -395,16 +385,6 @@ ENV_REGISTRY = {
     ENV_TRACE_PROBE_SECS: (
         "churn harness: seconds between mid-run exactness probes "
         "against GetSchedStats (chaos/scenario.py; default 0.5)"
-    ),
-    ENV_ELASTIC_BENCH_TRACE: (
-        "bench_elastic.py: run the named churn trace (packaged name "
-        "like preemption-storm, or a /path/to/trace.json) instead of "
-        "the kill-wave benchmark; same as --trace"
-    ),
-    ENV_ELASTIC_BENCH_TRACE_SCALE: (
-        "bench_elastic.py --trace: float multiplier on every job's "
-        "record count (default 1.0; CI uses <1 for short runs — "
-        "reported so shrunken runs are not mistaken for full ones)"
     ),
     ENV_K8S_TESTS: "1 enables live-cluster tests (tests/test_cluster_gated.py)",
     ENV_K8S_TEST_IMAGE: "worker image for the live-cluster tests",

@@ -6,8 +6,8 @@ backend (`pin_cpu`); each worker process holds the chips its launcher
 gave it (`chip_env`) and nothing else; a process that needs the chip
 count without holding a chip asks a short-lived child (`probe_device`).
 The CPU is a device only when it was asked for (`JAX_PLATFORMS=cpu`):
-`require_device` is the one rule the worker and the bench scripts
-share — never compute on the CPU by accident.
+`require_device` is the one rule the worker and chip_smoke.py share —
+never compute on the CPU by accident.
 """
 
 from __future__ import annotations

@@ -10,8 +10,7 @@ a median-of-recent estimate that is robust to the occasional stalled
 push.
 
 The pure per-round wire-form decision lives in sync_policy.decide();
-this module only measures. (The active h2d probe the bench brackets
-its runs with lives with the bench: bench.py `_probe_link_mbps`.)
+this module only measures.
 """
 
 from __future__ import annotations
@@ -60,7 +59,7 @@ class LinkWeather:
 
     def history(self) -> list[float]:
         """Recent raw samples, oldest first (for decide()'s hysteresis
-        and the bench decision log)."""
+        and the worker's decision log)."""
         with self._lock:
             return list(self._samples)
 

@@ -81,7 +81,7 @@ class TaskDispatcher:
         # cumulative records successfully trained (across epochs) —
         # progress/throughput introspection for benches and logs
         self._completed_records = 0
-        # -- goodput accounting (chaos/scenario.py, bench_elastic) ----
+        # -- goodput accounting (chaos/scenario.py) -------------------
         # Goodput = useful records/sec after subtracting recomputation:
         # a task requeued by a death/failure is RE-trained from scratch,
         # so every prior dispatch of a task that eventually completes is

@@ -1,4 +1,4 @@
-"""Span-derived sync critical-path breakdown (bench.py's consumer).
+"""Span-derived sync critical-path breakdown.
 
 Given the recorder-shaped span dicts of a traced run, decompose the
 worker sync chain's wall time into where it went:
@@ -26,8 +26,8 @@ worker sync chain's wall time into where it went:
 
 The decomposition is validated against the independently span-measured
 chain wall (the ``worker.window_sync`` roots): ``sum_fraction``
-reports component-sum / sync_wait and bench.py asserts it stays within
-10% of 1 — a drifting fraction means a hop joined the sync chain
+reports component-sum / sync_wait, which stays within 10% of 1
+(tests/test_obs.py) — a drifting fraction means a hop joined the sync chain
 without instrumentation (or one got double-billed).
 """
 
@@ -121,8 +121,7 @@ def sync_exposed_fraction_from_spans(
     where sync time GOES, this measures how much of it stayed ON the
     step loop's critical path. overlap_sync=off exposes every window's
     full sync wall; =on should leave only residual stalls (final
-    drain, beyond-depth backpressure), so bench.py's A/B asserts the
-    fraction drops.
+    drain, beyond-depth backpressure), so the fraction drops.
 
     The stall spans are on the always-on phase timeline, so every
     stall counts whatever the sample rate. Returns None when the span

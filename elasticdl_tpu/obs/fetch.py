@@ -1,10 +1,10 @@
-"""Operator/bench-side consumers of the observability RPCs.
+"""Operator-side consumers of the observability RPCs.
 
 Every servicer (master, PS shards, KV shards) answers ``GetTrace`` and
 ``GetMetrics`` for its *process* — both deliberately unfenced, so a
 fenced-out shard can still be asked what happened. These helpers wrap
 the calls for the consumers that sit outside the package's RPC plumbing
-(bench.py, CI artifact capture, tests).
+(CI artifact capture, tests).
 """
 
 from __future__ import annotations

@@ -43,7 +43,6 @@ from elasticdl_tpu.api.layers import (
 )
 from elasticdl_tpu.api.model_spec import ModelSpec
 from elasticdl_tpu.common.constants import (
-    ENV_BENCH_MFU,
     ENV_BET_PREFETCH,
     ENV_OVERLAP_SYNC,
     ENV_SCHED_PHASE_SECS,
@@ -407,7 +406,7 @@ class Worker:
         self._link_weather = LinkWeather()
         # per-round decision log: {round, form, link_mbps, delta_bytes,
         # steps}. Appended at sync SPAWN (spawns are sequential, like
-        # the EF residual handoff) and read by bench.py's decision log
+        # the EF residual handoff) and read through `sync_decisions`
         # after the chain settles.
         self._sync_decisions: list = []
         # Bucketed delta push (--sync_bucket_bytes /
@@ -1801,7 +1800,7 @@ class Worker:
             # at most one batch. EDL_SYNC_DEPTH=0 (the serialized
             # bit-parity mode) disables prefetch so each flush still
             # lands before the next lookup. EDL_BET_PREFETCH=0 turns
-            # the overlap off (bench A/B knob).
+            # the overlap off (A/B knob).
             prefetch_on = (
                 self._overlap_sync
                 and self._max_inflight_syncs > 0
@@ -1932,7 +1931,7 @@ class Worker:
         wspan_args = {"worker": self._id}
         if wire_form is not None:
             # the round's decision rides the window span for the
-            # critical-path/decision audits (bench decision log)
+            # critical-path/decision audits
             wspan_args["wire_form"] = wire_form
             if link_mbps is not None:
                 wspan_args["link_mbps"] = round(link_mbps, 2)
@@ -2367,8 +2366,8 @@ class Worker:
 
     @property
     def sync_decisions(self):
-        """Copy of the adaptive plane's per-round decision log (bench
-        decision JSON / CI artifact). Empty unless --sync_adaptive on."""
+        """Copy of the adaptive plane's per-round decision log. Empty
+        unless --sync_adaptive on."""
         return [dict(d) for d in self._sync_decisions]
 
     def _observe_push(self, delta_h, t0, wire_form):
@@ -2888,7 +2887,7 @@ class Worker:
         root spans so `sync_exposed_fraction_from_spans`
         (obs/critical_path.py) can sum exactly the sync wall that
         stayed ON the critical path — the quantity the overlap plane
-        exists to shrink, and the bench A/B's acceptance metric."""
+        exists to shrink."""
         with self._chain_span("worker.sync_exposed", root=True, reason=reason):
             yield
 
@@ -3479,10 +3478,8 @@ class Worker:
         """AOT warm-up of the scanned-window path for stacked
         [W, B, ...] shapes: init/pull the model, build the window fn,
         and execute it once on throwaway copies so the hot loop never
-        compiles. Benches call this before their timed region — the
-        reference's 23.8 s figure is likewise steady-state (measured
-        after `tf.function` tracing,
-        doc/worker_optimization_design.md:186-191)."""
+        compiles. A warm standby calls this before it is promoted
+        (`_standby_warmup`)."""
         assert self._local_updates > 1, "window warm-up needs local mode"
         first = jax.tree_util.tree_map(lambda a: a[0], features)
         if self._emb_specs:
@@ -3496,27 +3493,6 @@ class Worker:
             self._local_window_fn = self._build_local_window_fn()
         tx = self._spec.optimizer()
         opt_state = tx.init(self._flat)
-        self.window_flops = None
-        if os.environ.get(ENV_BENCH_MFU) == "1":
-            # XLA's own FLOP count for the compiled window — benches
-            # report MFU from it (SURVEY §6: MFU is part of the perf
-            # contract). Opt-in: .lower().compile() builds a SECOND
-            # executable (the AOT stage does not seed the jit call
-            # cache), so an elastic relaunch must not pay it — only
-            # bench.py sets the flag. XLA counts a lax.scan (while-loop)
-            # body ONCE regardless of trip count, so the W-step window
-            # program reports ~1 step's FLOPs; lower a W=1 window and
-            # scale by the window length instead.
-            one = jax.tree_util.tree_map(lambda a: a[:1], (features, labels))
-            cost = (
-                self._local_window_fn.lower(
-                    jnp.copy(self._flat), opt_state, self._aux,
-                    one[0], one[1],
-                )
-                .compile()
-                .cost_analysis()
-            )
-            self.window_flops = float(cost["flops"]) * self._local_updates
         out = self._local_window_fn(
             jnp.copy(self._flat), opt_state, self._aux, features, labels
         )
@@ -3531,7 +3507,6 @@ class Worker:
         self._warmup_params(features)
         if self._local_step_fn is None:
             self._local_step_fn = self._build_local_emb_step()
-        self.window_flops = None
         embs = self._prepare_embeddings(features)
         bets = {k: b.bet for k, b in embs.items()}
         bet_aux = {k: (b.inverse, b.mask) for k, b in embs.items()}

@@ -192,19 +192,6 @@ def test_no_chip_and_no_cpu_request_exits(monkeypatch):
     assert report["platform"] == "cpu" and report["chips"]
 
 
-def test_peak_lookup_raises_on_an_unknown_device():
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench.peak_bf16_tflops("TPU v5 lite") == 197.0
-    with pytest.raises(KeyError, match="no published bf16 peak"):
-        bench.peak_bf16_tflops("TPU v9")
-    cpu = {"platform": "cpu", "device_kind": "cpu", "chips": [0]}
-    assert bench.mfu_of(10.0, cpu) is None  # not a device metric
-    tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "chips": [0]}
-    assert bench.mfu_of(19.7, tpu) == pytest.approx(0.1)
-
-
 def test_compiled_kernels_refuse_the_cpu():
     import jax.numpy as jnp
 

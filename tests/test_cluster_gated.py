@@ -164,18 +164,32 @@ def test_tpu_window_hot_loop():
     must complete, converge, and report a throughput number. Run in a
     subprocess because conftest pins this process to the CPU backend."""
     code = """
-import json, os, sys, tempfile
+import json, os, sys, tempfile, time
 sys.path.insert(0, %r)
-from bench import run_job
+from elasticdl_tpu.api.model_spec_helpers import spec_from_module
+from elasticdl_tpu.master.ps_optimizer import PSOptimizer
+from elasticdl_tpu.master.servicer import MasterServicer
+from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
 from elasticdl_tpu.models import cifar10_functional_api as M
 from elasticdl_tpu.models.record_codec import write_synthetic_image_records
+from elasticdl_tpu.testing import InProcessMaster
+from elasticdl_tpu.worker.worker import Worker
 tmp = tempfile.mkdtemp()
 path = os.path.join(tmp, "x.rio")
 write_synthetic_image_records(path, 8192, (32, 32, 3), 10)
-ips, worker, _ = run_job(
-    M, path, 8192, minibatch=128, records_per_task=4096, epochs=1,
-    local_updates=32, grads_to_wait=1,
+dispatcher = TaskDispatcher({path: 8192}, {}, {}, 4096, 1)
+servicer = MasterServicer(
+    grads_to_wait=1, optimizer=PSOptimizer(M.optimizer()),
+    task_dispatcher=dispatcher,
 )
+worker = Worker(
+    0, InProcessMaster(servicer), spec_from_module(M),
+    minibatch_size=128, local_updates=32,
+)
+t0 = time.time()
+assert worker.run() and dispatcher.finished()
+ips = 8192 / (time.time() - t0)
+worker.close()
 print(json.dumps({"ips": ips, "losses": worker.task_losses}))
 """ % (REPO,)
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}

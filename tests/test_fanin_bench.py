@@ -2,19 +2,16 @@
 gate (e2e-marked; CI runs them as a dedicated job). The smoke tier
 (perf) proves the harness end to end at N=8: both cores complete
 reports, accounting is exact (version == applied pushes), the combine
-stage actually batches, and the suite JSON carries the headline
-contract bench.py embeds. The stress tier (slow) drives N=64 through
-the loop+combine core and holds the exactness bar under real
-contention."""
-
-import os
+stage actually batches, and the suite JSON carries its headline
+contract. The stress tier (slow) drives N=64 through the loop+combine
+core and holds the exactness bar under real contention."""
 
 import pytest
 
 from bench_fanin import DEFAULT_SLICE, run_cell, run_suite
 
 # short windows: these are harness/contract checks, not measurements —
-# the real numbers come from bench.py's JSON (docs/performance.md)
+# the study's own numbers are in docs/performance.md
 WARMUP_S = 0.2
 WINDOW_S = 0.6
 
@@ -47,8 +44,8 @@ def test_fanin_smoke_n8_both_cores_exact():
 @pytest.mark.e2e
 @pytest.mark.perf
 def test_fanin_smoke_suite_json_contract():
-    """The suite shape bench.py embeds under its "fanin" key: cells
-    indexed [tier][wire][N], speedups at max N, and a headline value."""
+    """The suite's JSON shape: cells indexed [tier][wire][N], speedups
+    at max N, and a headline value."""
     suite = run_suite(
         ns=(8,),
         grid=(("inproc", ("topk",)),),
@@ -72,152 +69,6 @@ def test_fanin_smoke_suite_json_contract():
     assert suite["headline_cell"] == key
     assert suite["value"] == suite["speedup_at_max_n"][key]
     assert "protocol" in suite
-
-
-@pytest.mark.e2e
-@pytest.mark.perf
-def test_overlap_smoke_window_job_on_vs_off(tmp_path):
-    """The overlap-plane smoke cell riding the fanin-bench CI job: the
-    bench.py window-mode A/B in miniature (8 windows of the cifar CNN
-    over a real localhost RpcServer), overlap_sync off vs on.
-    Exactness (final PS version == sync pushes x window) is asserted in
-    EVERY cell, and the overlap-on sustained img/s must not lose to
-    the serial chain — best-of-3 per mode, because these are short
-    windows on a shared CI host."""
-    from bench import run_job
-    from elasticdl_tpu.models import cifar10_functional_api as model_module
-    from elasticdl_tpu.models.record_codec import (
-        write_synthetic_image_records,
-    )
-
-    path = str(tmp_path / "cifar.rio")
-    write_synthetic_image_records(path, 512, (32, 32, 3), 10)
-    window = 2
-
-    def best(mode):
-        rps = []
-        for _ in range(3):
-            imgs_per_sec, worker, _wall = run_job(
-                model_module,
-                path,
-                512,
-                minibatch=64,
-                records_per_task=128,
-                epochs=1,
-                local_updates=window,
-                grads_to_wait=1,
-                sync_dtype="bfloat16",
-                overlap_sync=mode,
-            )
-            ws = worker.wire_summary
-            assert ws["sync_calls"] == 4  # 8 steps / W=2, no ragged tails
-            assert worker.final_version == ws["sync_calls"] * window, (
-                mode, worker.final_version, ws,
-            )
-            rps.append(imgs_per_sec)
-        return max(rps)
-
-    off_rps = best("off")
-    on_rps = best("on")
-    assert on_rps >= off_rps, (on_rps, off_rps)
-
-
-@pytest.mark.e2e
-@pytest.mark.perf
-def test_mfu_ladder_smoke_adaptive_vs_f32_serial(tmp_path, monkeypatch):
-    """The mfu-ladder smoke cell riding the fanin-bench CI job: the
-    adaptive sync ladder vs the fixed-f32 serial chain at N=8 windows
-    of the cifar CNN (bench.py's adaptive_sync_ab in miniature).
-    Exactness (final PS version == sync pushes x window) is asserted
-    in EVERY cell, every adaptive round must have logged a decision
-    from the ladder's vocabulary, and adaptive must not lose to f32 —
-    in-process pushes are sub-ms so the passive probe never rises
-    above cold start and every round rides the bf16 rung, i.e. half
-    the wire bytes for free. Best-of-3 per mode (short windows on a
-    shared CI host). The per-round decision log is written as JSON for
-    CI to upload as an artifact (EDL_MFU_LADDER_LOG_DIR, else
-    tmp_path).
-
-    Held on the link the ladder was built to fight: gRPC. On the
-    carrier a local peer now gets by default the bytes are too cheap
-    for the cold start's bf16 cast to pay (this host's CPU: adaptive
-    0.90-0.95 of f32 in two runs, 1.0+ on gRPC) — ROADMAP D3's
-    verdict to reach, not this smoke's."""
-    import json
-
-    from elasticdl_tpu.common.constants import ENV_TRANSPORT
-
-    monkeypatch.setenv(ENV_TRANSPORT, "grpc")
-
-    from bench import run_job
-    from elasticdl_tpu.common.sync_policy import WIRE_FORMS
-    from elasticdl_tpu.models import cifar10_functional_api as model_module
-    from elasticdl_tpu.models.record_codec import (
-        write_synthetic_image_records,
-    )
-
-    path = str(tmp_path / "cifar.rio")
-    write_synthetic_image_records(path, 512, (32, 32, 3), 10)
-    window = 2
-    n_windows = 8  # 512 records / mb 32 = 16 steps / W=2
-
-    def best(adaptive):
-        rps, logs = [], []
-        for _ in range(3):
-            imgs_per_sec, worker, _wall = run_job(
-                model_module,
-                path,
-                512,
-                minibatch=32,
-                records_per_task=128,
-                epochs=1,
-                local_updates=window,
-                grads_to_wait=1,
-                sync_dtype=None,
-                sync_adaptive="on" if adaptive else "off",
-                overlap_sync="off",
-            )
-            ws = worker.wire_summary
-            assert ws["sync_calls"] == n_windows
-            assert worker.final_version == ws["sync_calls"] * window, (
-                adaptive, worker.final_version, ws,
-            )
-            log = worker.decision_log
-            if adaptive:
-                # one decision per window, every form from the ladder
-                assert len(log) == n_windows, log
-                assert all(d["form"] in WIRE_FORMS for d in log), log
-                # per-form wire accounting rode WireStats
-                assert ws["wire_forms"], ws
-            else:
-                assert log == [] and ws["wire_forms"] == {}
-            rps.append(imgs_per_sec)
-            logs.append(log)
-        return max(rps), logs
-
-    f32_rps, _ = best(False)
-    adaptive_rps, adaptive_logs = best(True)
-    out_dir = os.environ.get("EDL_MFU_LADDER_LOG_DIR") or str(tmp_path)
-    os.makedirs(out_dir, exist_ok=True)
-    with open(
-        os.path.join(out_dir, "mfu-ladder-decision-log.json"), "w"
-    ) as f:
-        json.dump(
-            {
-                "cell": "mfu-ladder smoke (adaptive vs f32-serial, N=8)",
-                "f32_images_per_sec": round(f32_rps, 1),
-                "adaptive_images_per_sec": round(adaptive_rps, 1),
-                # per-run, per-round: form + probed Mbps, verbatim
-                "decision_log_per_run": adaptive_logs,
-            },
-            f,
-            indent=2,
-        )
-    # link-bound hosts must win outright (bf16 cold-start halves the
-    # wire bytes); compute-bound in-process cells tie within scheduler
-    # noise, so the gate carries the same 5% tolerance as bench.py's
-    # per_link_ratio_adaptive_vs_f32 headline.
-    assert adaptive_rps >= 0.95 * f32_rps, (adaptive_rps, f32_rps)
 
 
 @pytest.mark.e2e

@@ -3,13 +3,17 @@ deterministic scheduling, and goodput arithmetic.
 
 The tier-1 portion never boots a fleet: parsing and scheduling are
 pure, and the dispatcher-accounting tests drive a real TaskDispatcher
-in-process. The full trace replays are e2e-marked (and run in CI's
-churn-scenario job via `bench_elastic.py --trace`)."""
+in-process, and the entry point's tests stub the runner to a canned
+report. The full trace replays are e2e-marked (and run in CI's
+churn-scenario job via `python -m elasticdl_tpu.chaos <trace>`)."""
 
+import copy
 import json
+import tempfile
 
 import pytest
 
+from elasticdl_tpu.chaos.__main__ import main as chaos_main
 from elasticdl_tpu.chaos.scenario import (
     JobRun,
     JobSpec,
@@ -491,6 +495,139 @@ def test_jobrun_stop_is_safe_on_unbooted_run(tmp_path):
         worker_env={},
     )
     run.stop()
+
+
+# -- the entry point: python -m elasticdl_tpu.chaos ---------------------------
+
+NO_FAILOVER = "no kill_master event"
+REPORT = {
+    "metric": "churn_scenario",
+    "trace": "t",
+    "scale": 1.0,
+    "retention": 0.9,
+    "baseline_images_per_sec": 100.0,
+    "jobs": {
+        "main": {
+            "goodput": {"goodput_fraction": 0.5, "gap_explained": 1.0},
+            "relaunches": 2,
+        }
+    },
+    "events": [],
+}
+
+
+@pytest.fixture
+def replay(monkeypatch, tmp_path, capsys):
+    """Run the entry with `ScenarioRunner.run` stubbed to `report`:
+    -> (exit code, the parsed stdout lines, the runners that ran)."""
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+
+    def run(argv, report=REPORT):
+        ran = []
+
+        def fake_run(runner):
+            ran.append(runner)
+            return copy.deepcopy(report)
+
+        monkeypatch.setattr(ScenarioRunner, "run", fake_run)
+        rc = chaos_main(argv)
+        out = capsys.readouterr().out.splitlines()
+        return rc, [json.loads(line) for line in out], ran
+
+    return run
+
+
+def test_entry_unknown_trace_exits_nonzero_naming_the_packaged(capsys):
+    with pytest.raises(SystemExit) as exc:
+        chaos_main(["no-such-trace"])
+    assert exc.value.code != 0
+    captured = capsys.readouterr()
+    assert captured.out == ""
+    for name in list_traces():
+        assert name in captured.err
+
+
+def test_entry_list_prints_exactly_the_packaged_traces(capsys):
+    assert chaos_main(["--list"]) == 0
+    assert capsys.readouterr().out.splitlines() == list_traces()
+
+
+def test_entry_refuses_a_gap_the_recompute_counter_leaves_open(replay):
+    report = copy.deepcopy(REPORT)
+    report["jobs"]["main"]["goodput"]["gap_explained"] = 0.97
+    with pytest.raises(AssertionError, match="not explained.*0.97"):
+        replay(["preemption-storm"], report)
+
+
+@pytest.mark.parametrize(
+    "trace, top, job, goodput, expected",
+    [
+        # no master died: both headline fields null, and saying why
+        (
+            "preemption-storm", {}, {}, {},
+            {
+                "time_to_adopt_secs": None,
+                "failover_mode": None,
+                "time_to_adopt_secs_skipped_reason": NO_FAILOVER,
+                "failover_mode_skipped_reason": NO_FAILOVER,
+            },
+        ),
+        # the anchor job's failover is hoisted to the top level
+        (
+            "master-failover-drain", {},
+            {"master_failover": {"time_to_adopt_secs": 1.25, "mode": "drain"}},
+            {},
+            {"time_to_adopt_secs": 1.25, "failover_mode": "drain"},
+        ),
+        # no fault-free twin, nothing recomputed: nulls with reasons
+        (
+            "flash-crowd", {"retention": None}, {}, {"gap_explained": None},
+            {
+                "retention": None,
+                "retention_skipped_reason": "baseline=false",
+                "gap_explained": None,
+                "gap_explained_skipped_reason": "zero records were recomputed",
+            },
+        ),
+    ],
+    ids=["no-failover", "failover-hoisted", "no-baseline-no-gap"],
+)
+def test_entry_prints_one_json_line_whose_nulls_say_why(
+    replay, trace, top, job, goodput, expected
+):
+    report = {**copy.deepcopy(REPORT), **top}
+    anchor = load_trace(trace).jobs[0].tag
+    report["jobs"][anchor] = {**report["jobs"].pop("main"), **job}
+    report["jobs"][anchor]["goodput"].update(goodput)
+    rc, lines, _ = replay([trace], report)
+    assert rc == 0 and len(lines) == 1
+    (line,) = lines
+    # the report goes out whole, with the hoisted pair beside it
+    assert set(line) >= set(REPORT) | {"time_to_adopt_secs", "failover_mode"}
+    flat = {**line, **line["jobs"][anchor]["goodput"]}
+    for key, value in expected.items():
+        if key.endswith("_skipped_reason"):
+            assert value in flat[key], (key, flat[key])
+        else:
+            assert flat[key] == value, key
+    # a field that has a value has no reason beside it
+    assert not [
+        k for k in flat
+        if k.endswith("_skipped_reason")
+        and flat[k[: -len("_skipped_reason")]] is not None
+    ]
+
+
+@pytest.mark.parametrize(
+    "argv, scale",
+    [(["preemption-storm"], 1.0), (["preemption-storm", "--scale", "0.5"], 0.5)],
+    ids=["default", "half"],
+)
+def test_entry_scale_reaches_the_runner(replay, argv, scale):
+    rc, _, (runner,) = replay(argv)
+    assert rc == 0
+    assert runner.scale == scale
+    assert runner.trace.name == "preemption-storm"
 
 
 # -- e2e: one real scenario replay -------------------------------------------
