@@ -27,6 +27,7 @@ local layers.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -119,7 +120,8 @@ class TransformerConfig:
     # projected
     mla_rope: bool = True
     # the mixer of every layer of the stack, dense layers first:
-    # "mha", "mla", "kda" or "conv"; None = `attention` in every layer.
+    # "mha", "swa", "mla", "kda" or "conv"; None = `attention` in
+    # every layer.
     # Layers that follow each other with one mixer and one kind of MLP
     # are one scanned run
     layer_types: Optional[Tuple[str, ...]] = None
@@ -147,10 +149,29 @@ class TransformerConfig:
     qk_norm: bool = False
     # the logits read the embedding transposed; no "head" leaf
     tie_embeddings: bool = False
+    # a head's width where it is not d_model // n_heads (the
+    # projections are then not square: 48 heads of 128 over 2048)
+    head_width: Optional[int] = None
+    # "mha" and "swa": a per-head gate on the attention's output,
+    # o_h * sigmoid(x . wgate_h), before the output projection
+    attn_gate: bool = False
+    # "mha": only a head's first `rope_dim` columns turn (None: all of
+    # them), by `rope_yarn`'s blended frequencies where that is set,
+    # the angles' cosine and sine times `rope_factor` (YaRN's
+    # attention factor, laid on the rotation and not on the scores)
+    rope_dim: Optional[int] = None
+    rope_factor: float = 1.0
+    # "swa": "mha" under a window (the query at t sees the keys u with
+    # 0 <= t - u < `swa_window`), with a head count and a rotary base
+    # of its own, the whole head turned; the key-value heads and the
+    # head's width are the stack's
+    swa_heads: int = 0
+    swa_window: int = 0
+    swa_rope_base: float = 10000.0
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
 
     @property
     def kv_heads(self) -> int:
@@ -182,6 +203,21 @@ class TransformerConfig:
     def looped(self) -> bool:
         return self.n_loops > 1
 
+    def attention_shape(self, mixer: str) -> "AttentionShape":
+        """What parts an "mha" layer from a "swa" one. In a stack that
+        holds both, each kind has a scope of its own under
+        `attention`."""
+        if mixer == "swa":
+            return AttentionShape(
+                self.swa_heads, self.swa_window, self.swa_rope_base,
+                None, None, 1.0, "swa",
+            )
+        return AttentionShape(
+            self.n_heads, None, self.rope_base, self.rope_dim,
+            self.rope_yarn, self.rope_factor,
+            "global" if "swa" in self.mixers else None,
+        )
+
     @property
     def held(self) -> Tuple[int, int]:
         return self.held_experts or (0, self.n_experts)
@@ -196,6 +232,18 @@ class YarnScaling(NamedTuple):
     original_length: int
     mscale: float
     mscale_all_dim: float
+
+
+class AttentionShape(NamedTuple):
+    """One kind of attention layer: `TransformerConfig.attention_shape`."""
+
+    heads: int
+    window: Optional[int]
+    rope_base: float
+    rope_dim: Optional[int]  # None: the whole head turns
+    rope_yarn: Optional[YarnScaling]
+    rope_factor: float
+    scope: Optional[str]  # its scope under `attention`
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -253,6 +301,7 @@ _OVER_LAYERS = {
     "router_bias_absmax": jnp.max,
     "kda_log_decay_min": jnp.min,
     "shortconv_gate_absmax": jnp.max,
+    "attn_gate_mean": jnp.mean,
 }
 
 
@@ -313,22 +362,28 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
     return params
 
 
-def _init_mha(norm, cfg: TransformerConfig, L: int) -> Dict:
+def _init_mha(norm, cfg: TransformerConfig, L: int, mixer="mha") -> Dict:
     """A multi-head attention layer's leaves but its MLP's, for L
-    stacked layers: `n_kv_heads` key-value heads, and under `qk_norm`
-    the two norms' weights."""
+    stacked layers of `mixer` ("mha" or "swa": its own head count):
+    `n_kv_heads` key-value heads, under `qk_norm` the two norms'
+    weights, under `attn_gate` the gate's."""
     d, hd = cfg.d_model, cfg.head_dim
+    heads = cfg.attention_shape(mixer).heads
     tree = {
         "ln1": np.ones((L, d), np.float32),
-        "wq": norm(L, d, cfg.n_heads * hd),
+        "wq": norm(L, d, heads * hd),
         "wk": norm(L, d, cfg.kv_heads * hd),
         "wv": norm(L, d, cfg.kv_heads * hd),
-        "wo": norm(L, cfg.n_heads * hd, d),
+        "wo": norm(L, heads * hd, d),
         "ln2": np.ones((L, d), np.float32),
     }
     if cfg.qk_norm:
         tree["q_norm"] = np.ones((L, hd), np.float32)
         tree["k_norm"] = np.ones((L, hd), np.float32)
+    if cfg.attn_gate:
+        # [heads, d], not [d, heads]: a leaf that ends in a narrow dim
+        # makes the v5e compiler pad the flat vector (`wbeta`, below)
+        tree["wgate"] = norm(L, heads, d, scale=1.0 / math.sqrt(d))
     return tree
 
 
@@ -341,15 +396,15 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     d = cfg.d_model
     if not (
         cfg.moe_top_k and cfg.mlp == "swiglu"
-        and set(cfg.mixers) <= {"mla", "kda", "conv", "mha"}
+        and set(cfg.mixers) <= {"mla", "kda", "conv", "mha", "swa"}
         and len(cfg.mixers) == cfg.n_layers
     ):
         raise NotImplementedError(
             "the routed stack is built with latent attention, delta-rule "
             "attention or short convolutions beside grouped-query "
             "attention, top-k experts and SwiGLU MLPs together (attention "
-            "'mla' or 'kda', or layer_types of 'mla', 'kda', 'conv' and "
-            "'mha', one a layer; moe_top_k > 0, mlp='swiglu')"
+            "'mla' or 'kda', or layer_types of 'mla', 'kda', 'conv', 'mha' "
+            "and 'swa', one a layer; moe_top_k > 0, mlp='swiglu')"
         )
 
     def mla(L):
@@ -405,6 +460,7 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     mixer_trees = {
         "mla": mla, "kda": kda, "conv": conv,
         "mha": lambda L: _init_mha(norm, cfg, L),
+        "swa": lambda L: _init_mha(norm, cfg, L, "swa"),
     }
     (_first, held) = cfg.held
     f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
@@ -501,35 +557,49 @@ def _require_mesh_support(cfg: TransformerConfig):
         cfg.mlp != "gelu" or cfg.sandwich_norm or cfg.looped
         or cfg.attention != "mha" or cfg.n_dense_layers or cfg.moe_top_k
         or cfg.layer_types or cfg.n_kv_heads or cfg.qk_norm
-        or cfg.tie_embeddings
+        or cfg.tie_embeddings or cfg.head_width or cfg.attn_gate
+        or cfg.rope_dim or cfg.rope_factor != 1.0 or cfg.swa_heads
+        or cfg.swa_window
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
             "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
             "attention='mla' and 'kda', layer_types, n_dense_layers, "
-            "moe_top_k, n_kv_heads, qk_norm and tie_embeddings exist on "
-            "the unsharded path (plain_forward) only"
+            "moe_top_k, n_kv_heads, qk_norm, tie_embeddings, head_width, "
+            "attn_gate, rope_dim, rope_factor and the windowed mixer "
+            "(swa_heads, swa_window) exist on the unsharded path "
+            "(plain_forward) only"
         )
 
 
 def _rope(
     x: jnp.ndarray, positions: jnp.ndarray, base: float = 10000.0,
-    freqs=None,
+    freqs=None, rot: Optional[int] = None, factor: float = 1.0,
 ) -> jnp.ndarray:
     """Rotary embedding; x: [B, L, H, D], positions: [L] global. The
     angles are float32 whatever x is (bfloat16 holds no whole number
     above 256 exactly: position 2047 would turn as 2048); their cosine
     and sine are cast to x's dtype. Pair i is (x[i], x[i + D/2]).
-    `freqs` [D/2] float32 replaces base^(-2i/D) (`yarn_frequencies`)."""
-    d = x.shape[-1]
+    `freqs` [D/2] float32 replaces base^(-2i/D) (`yarn_frequencies`).
+    With `rot` only the first `rot` columns turn (pair i is (x[i],
+    x[i + rot/2]), D read as `rot` above) and the rest pass as they
+    are; `factor` multiplies cosine and sine, so it scales what turns
+    and nothing else."""
+    d = x.shape[-1] if rot is None else rot
     half = d // 2
     if freqs is None:
         freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [L, half]
-    cos = jnp.cos(ang).astype(x.dtype)[None, :, None, :]
-    sin = jnp.sin(ang).astype(x.dtype)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    cos = cos.astype(x.dtype)[None, :, None, :]
+    sin = sin.astype(x.dtype)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:d]
+    turned = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if rot is not None and rot < x.shape[-1]:
+        turned.append(x[..., rot:])
+    return jnp.concatenate(turned, axis=-1)
 
 
 def _block(cfg: TransformerConfig, lp: Dict, h: jnp.ndarray, positions) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -768,22 +838,62 @@ def _causal_conv(x: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
     return sum(padded[:, i:i + length] * taps[i] for i in range(n))
 
 
+def _scope(name: Optional[str]):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
 def _mha(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
-    """Multi-head attention on the normed x [B, L, d] -> [B, L, d]:
-    `n_heads` queries over `kv_heads` keys and values (the dispatcher
-    widens them), under `qk_norm` queries and keys normed per head,
-    then rotated."""
+    """An "mha" layer's attention on the normed x [B, L, d] ->
+    [B, L, d] (`_attend` without its stats)."""
+    return _attend(cfg, lp, x, positions, "mha")[0]
+
+
+def _head_gate(lp: Dict, x: jnp.ndarray) -> jnp.ndarray:
+    """sigmoid(x . wgate_h), [B, L, heads] float32: the product
+    accumulates in float32 and the sigmoid is taken there."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "bld,hd->blh", x, lp["wgate"], preferred_element_type=jnp.float32
+    ))
+
+
+def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
+            mixer: str):
+    """Multi-head attention on the normed x [B, L, d] -> ([B, L, d],
+    its stats): the queries of `mixer`'s kind of layer
+    (`cfg.attention_shape`: head count, window, rotation) over
+    `kv_heads` keys and values (the dispatcher widens them), under
+    `qk_norm` queries and keys normed per head, then rotated; under
+    `attn_gate` each head's output times sigmoid(x . wgate_h), the
+    sigmoid in float32 (stat `attn_gate_mean`, its mean)."""
     from elasticdl_tpu.ops.flash_attention import attention
 
     b, l, _ = x.shape
-    q = (x @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-    k = (x @ lp["wk"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
-    v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q, k = _qk_norm(lp, q, k, cfg.norm_eps)
-    q = _rope(q, positions, cfg.rope_base)
-    k = _rope(k, positions, cfg.rope_base)
-    return attention(q, k, v, causal=True).reshape(b, l, -1) @ lp["wo"]
+    shape = cfg.attention_shape(mixer)
+    inner = shape.scope is not None  # `rope` and `gate` beside `swa`
+    stats = {}
+    with _scope(shape.scope):
+        q = (x @ lp["wq"]).reshape(b, l, shape.heads, cfg.head_dim)
+        k = (x @ lp["wk"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
+        v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q, k = _qk_norm(lp, q, k, cfg.norm_eps)
+        with _scope(inner and "rope"):
+            turn = dict(
+                freqs=shape.rope_yarn and yarn_frequencies(
+                    shape.rope_dim or cfg.head_dim, shape.rope_base,
+                    shape.rope_yarn,
+                ),
+                rot=shape.rope_dim, factor=shape.rope_factor,
+            )
+            q = _rope(q, positions, shape.rope_base, **turn)
+            k = _rope(k, positions, shape.rope_base, **turn)
+        out = attention(q, k, v, causal=True, window=shape.window)
+        if cfg.attn_gate:
+            with _scope(inner and "gate"):
+                gate = _head_gate(lp, x)
+                out = out * gate[..., None].astype(out.dtype)
+                stats["attn_gate_mean"] = jnp.mean(gate)
+        return out.reshape(b, l, -1) @ lp["wo"], stats
 
 
 def _qk_norm(lp: Dict, q: jnp.ndarray, k: jnp.ndarray, eps: float):
@@ -920,7 +1030,7 @@ def plain_forward_stats(
         if mixer == "conv":
             out, gate_absmax = _conv(cfg, lp, x)
             return out, {"shortconv_gate_absmax": gate_absmax}
-        return _mha(cfg, lp, x, positions), {}
+        return _attend(cfg, lp, x, positions, mixer)
 
     def layer(mixer: str, experts: bool):
         """The scanned body of a layer with `mixer` and the dense MLP,
@@ -928,7 +1038,9 @@ def plain_forward_stats(
 
         def body(carry, lp):
             h, aux = carry
-            with jax.named_scope("attention" if mixer == "mha" else mixer):
+            with jax.named_scope(
+                "attention" if mixer in ("mha", "swa") else mixer
+            ):
                 out, stats = attend(mixer, lp, rms_norm(h, lp["ln1"], eps))
                 if cfg.sandwich_norm:
                     out = rms_norm(out, lp["ln1b"], eps)

@@ -72,6 +72,8 @@ class TransformerLM:
                 stats["kda_log_decay_min"] = np.zeros((), np.float32)
             if "conv" in self.cfg.mixers:
                 stats["shortconv_gate_absmax"] = np.zeros((), np.float32)
+            if self.cfg.attn_gate:
+                stats["attn_gate_mean"] = np.zeros((), np.float32)
             return {"params": params, WINDOW_STATS: stats}
         return {"params": params}
 

@@ -1,4 +1,5 @@
-"""Fused causal attention as Pallas TPU kernels (fwd + bwd).
+"""Fused attention, causal and optionally banded, as Pallas TPU kernels
+(fwd + bwd).
 
 The hot op of the transformer (models/transformer_lm.py) and of a
 one-device ring (parallel/ring_attention.py) is softmax(QK^T)V. From
@@ -19,7 +20,12 @@ the kernels ran at half XLA's speed at 2048 tokens, at 1024 x 1024 at
 2.1 to 2.2 times it (FLASH_MIN_LENGTH has the numbers). Under the
 causal mask a step above the diagonal runs nothing and fetches
 nothing (its block index is held at the nearest visible tile's), and
-only a tile the diagonal crosses is masked. A head width of a multiple
+only a tile the diagonal crosses is masked. Under a `window` (the
+query at t sees the keys u with 0 <= t - u < window) the inner grid axis
+is only as long as the tiles a band crosses and starts at the band's
+first tile (`_Band`), so a tile wholly left of the band costs no step
+at all, and a tile the band's left edge crosses is masked on that edge.
+A head width of a multiple
 of 128 is read where it lies in [B, L, H, D]; a narrower one is folded
 through memory (`_Layout`). VMEM use is O(tile) whatever L is. The
 forward also emits the per-row logsumexp; the backward is the standard
@@ -60,49 +66,78 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _NEG_INF = -1e30
 
 
-def reference_attention(q, k, v, causal: bool = True, scale=None):
+def reference_attention(q, k, v, causal: bool = True, scale=None,
+                        window=None):
     """Plain-XLA causal attention, [B, L, H, D] -> [B, L, H, Dv]: `v`
     may be of another width than `q` and `k` (latent attention: 192-wide
     queries and keys, 128-wide values). `scale` multiplies the scores
-    where 1/sqrt(D) is not the model's (YaRN's softmax scale)."""
+    where 1/sqrt(D) is not the model's (YaRN's softmax scale). Under
+    `window` the causal mask is a band: the query at t sees the keys u
+    with 0 <= t - u < window."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     s = s / math.sqrt(d) if scale is None else s * scale
     if causal:
         L = q.shape[1]
         mask = jnp.tril(jnp.ones((L, L), dtype=bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((L, L), dtype=bool), -window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def pick_tiles(L: int):
+def pick_tiles(L: int, window=None):
     """(q edge, k edge) of the tiles the kernels cut a sequence of `L`
     into, or None where not even BLOCK divides it (the caller's
-    fallback is `reference_attention`)."""
-    edge = next((t for t in TILE_LADDER if L > 0 and L % t == 0), None)
+    fallback is `reference_attention`). Under a `window` the edge is no
+    longer than the window (and no shorter than BLOCK): a band runs the
+    tiles it crosses whole, so a longer tile multiplies pairs nobody
+    sees. On the chip (2026-10-01, `scripts/swa_kernel_sweep.py`) at
+    (1, 8192, 64, 128), window 512, forward and backward, ms a call by
+    q x k edge: 128 x 128 41.9, 256 x 256 23.4, 512 x 512 16.4,
+    1024 x 1024 20.8, 256 x 512 19.8, 512 x 256 22.2, 1024 x 512 21.0;
+    the causal call of those shapes 42.2 (and at 48 heads 31.7 at
+    1024 x 1024, 41.9 at 512 x 512). Other windows are not measured."""
+    most = max(window or TILE_LADDER[0], BLOCK)
+    edge = next(
+        (t for t in TILE_LADDER if t <= most and L > 0 and L % t == 0), None
+    )
     return edge and (edge, edge)
 
 
-def _on_visible_tiles(qi, kj, bq: int, bk: int, causal: bool, body):
+def _on_visible_tiles(qi, kj, bq: int, bk: int, causal: bool, body,
+                      window=None, n_q=None):
     """Run `body(masked)` for the (qi, kj) tile pair: nothing for a
-    tile wholly above the diagonal, `masked` only where the diagonal
-    crosses the tile."""
+    tile wholly above the diagonal or, under `window`, wholly left of
+    the band (or below the sequence's end: `n_q` q tiles, where the
+    caller's steps can pass it); `masked` only where the diagonal or
+    the band's left edge crosses the tile."""
     if not causal:
         body(False)
         return
     visible = kj * bk <= qi * bq + bq - 1  # its first key, its last query
     crossed = kj * bk + bk - 1 > qi * bq  # its last key, its first query
+    if window is not None:
+        # its last key, the first key its first query sees
+        visible &= kj * bk + bk - 1 >= qi * bq - (window - 1)
+        # its first key, the first key its last query sees
+        crossed |= kj * bk < qi * bq + bq - window
+    if n_q is not None:
+        visible &= qi < n_q
     pl.when(visible & crossed)(lambda: body(True))
     pl.when(visible & jnp.logical_not(crossed))(lambda: body(False))
 
 
-def _mask(s, qi, kj, bq: int, bk: int, q_axis: int):
+def _mask(s, qi, kj, bq: int, bk: int, q_axis: int, window=None):
     """Mask a score tile by global position; queries run along
     `q_axis` of `s`, keys along the other."""
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _tile_picks(bq: int, bk: int, causal: bool):
@@ -118,6 +153,70 @@ def _tile_picks(bq: int, bk: int, causal: bool):
     seen_k = lambda j, t: jnp.minimum(t, ((j + 1) * bq - 1) // bk)  # noqa: E731
     seen_q = lambda j, t: jnp.maximum(t, (j * bk) // bq)  # noqa: E731
     return own, seen_k, seen_q
+
+
+class _Band:
+    """The tiles a banded call (`window` < L) walks. A q tile's band
+    crosses a few k tiles however long the sequence is, so the inner
+    grid axis is as long as the most tiles a band can cross (`k_steps`
+    under a q tile, `q_steps` over a k tile) and step t of q tile j
+    reads k tile `first_k(j) + t`: no step is spent left of the band.
+    A step past the row's last visible tile (the sequence's first rows,
+    whose band the start cuts; the last columns, whose band the end
+    cuts) is held there and runs nothing. Measured against the causal
+    call's whole row held at the band's nearest tile on both sides, on
+    the chip at (1, 8192, 64, 128), window 512, 512 x 512 tiles,
+    forward and backward: 16.4 ms against 27.7 (`pick_tiles`)."""
+
+    def __init__(self, L: int, bq: int, bk: int, window: int):
+        self._key = (L, bq, bk, window)
+        self.bq, self.bk, self.window = bq, bk, window
+        self.nq = L // bq
+        self.k_steps = max(
+            self.last_k(j) - self.first_k(j, max) + 1 for j in range(self.nq)
+        )
+        self.q_steps = max(
+            self.last_q(j, min) - self.first_q(j) + 1 for j in range(L // bk)
+        )
+
+    def __eq__(self, other):
+        return isinstance(other, _Band) and self._key == other._key
+
+    def __hash__(self):
+        return hash(self._key)
+
+    # `least` / `most` are min / max for whole numbers and jnp.minimum /
+    # jnp.maximum for a grid step's index
+    def first_k(self, j, most=jnp.maximum):
+        return most(j * self.bq - (self.window - 1), 0) // self.bk
+
+    def last_k(self, j):
+        return ((j + 1) * self.bq - 1) // self.bk
+
+    def first_q(self, j):
+        return (j * self.bk) // self.bq
+
+    def last_q(self, j, least=jnp.minimum):
+        return least(
+            ((j + 1) * self.bk - 1 + self.window - 1) // self.bq, self.nq - 1
+        )
+
+    def k_tile(self, j, t):
+        """The k tile step t of q tile j computes on (past the row's
+        last visible one: none)."""
+        return self.first_k(j) + t
+
+    def q_tile(self, j, t):
+        return self.first_q(j) + t
+
+    def picks(self):
+        """`_tile_picks` for the band: a step past it is held at its
+        last tile."""
+        return (
+            lambda j, t: j,
+            lambda j, t: jnp.minimum(self.k_tile(j, t), self.last_k(j)),
+            lambda j, t: jnp.minimum(self.q_tile(j, t), self.last_q(j)),
+        )
 
 
 class _Layout:
@@ -177,17 +276,22 @@ def _dot(a, b, dims):
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-               *, bq: int, bk: int, causal: bool, scale: float):
+               *, bq: int, bk: int, causal: bool, scale: float, band=None):
     """Streaming forward: grid (head, q tile, k tile), k innermost. One
     tile per input is resident; the online-softmax state (acc/m/l)
     lives in VMEM scratch across the k sweep; o/lse write once at the
     sweep's end (their block index is constant over kj, so Mosaic keeps
     them in VMEM until then). A step above the diagonal runs nothing
     and, its index clamped to the row's last visible tile, fetches
-    nothing."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
+    nothing. Under a `band` the sweep is its steps (`_Band`). A row
+    whose keys in a tile are all left of the band adds exp(0) there;
+    the tile on the diagonal, which every row sees and which comes
+    last, wipes that with exp(-1e30 - m) = 0."""
+    qi, step = pl.program_id(1), pl.program_id(2)
+    kj = step if band is None else band.k_tile(qi, step)
+    window = band and band.window
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -198,7 +302,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # operands in the input dtype: bf16 MXU passes, f32 accumulation
         s = _dot(q_ref[0], k_ref[0], _NT) * scale  # [BQ, BK]
         if masked:
-            s = _mask(s, qi, kj, bq, bk, q_axis=0)
+            s = _mask(s, qi, kj, bq, bk, 0, window)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -207,20 +311,20 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(vb.dtype), vb, _NN)
 
-    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+    _on_visible_tiles(qi, kj, bq, bk, causal, body, window)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])  # [BQ, 1]
 
 
-def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles):
+def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band=None):
     """Returns (o [B,L,H,D], lse [B*H, L, 1])."""
     b, L, h, d = q.shape
     bq, bk = tiles
     lay = _Layout(b, L, h, d)
-    own, seen_k, _ = _tile_picks(bq, bk, causal)
+    own, seen_k, _ = band.picks() if band else _tile_picks(bq, bk, causal)
     q_spec, kv_spec = lay.spec(bq, own), lay.spec(bk, seen_k)
     # rows ([B*H, L, 1]) carry a trailing singleton so Mosaic's tiling
     # rule holds: block (1, BQ, 1) -> last two dims (BQ, 1) are
@@ -228,13 +332,14 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles):
     lse_spec = pl.BlockSpec((1, bq, 1), lambda i, j, t: (i, j, 0))
     out, lse = pl.pallas_call(
         functools.partial(
-            _fa_kernel, bq=bq, bk=bk, causal=causal, scale=1.0 / math.sqrt(d)
+            _fa_kernel, bq=bq, bk=bk, causal=causal,
+            scale=1.0 / math.sqrt(d), band=band,
         ),
         out_shape=[
             jax.ShapeDtypeStruct(lay.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32),
         ],
-        grid=(b * h, L // bq, L // bk),
+        grid=(b * h, L // bq, band.k_steps if band else L // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, lse_spec],
         scratch_shapes=[
@@ -251,13 +356,16 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, bq: int, bk: int, causal: bool, scale: float):
+               acc_ref, *, bq: int, bk: int, causal: bool, scale: float,
+               band=None):
     """Streaming dq: grid (head, q tile, k tile), k innermost. Re-forms
     p = exp(s - lse), ds = p * (do v^T - delta) * scale, accumulates
     dq += ds k in VMEM scratch across the k sweep."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
+    kj = step if band is None else band.k_tile(qi, step)
+    window = band and band.window
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -265,22 +373,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         kb = k_ref[0]
         s = _dot(q_ref[0], kb, _NT) * scale  # [BQ, BK]
         if masked:
-            s = _mask(s, qi, kj, bq, bk, q_axis=0)
+            s = _mask(s, qi, kj, bq, bk, 0, window)
         p = jnp.exp(s - lse_ref[0])  # lse, delta: [BQ, 1]
         dp = _dot(do_ref[0], v_ref[0], _NT)
         ds = (p * (dp - delta_ref[0]) * scale).astype(kb.dtype)
         acc_ref[...] = acc_ref[...] + _dot(ds, kb, _NN)
 
-    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+    _on_visible_tiles(qi, kj, bq, bk, causal, body, window)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc, dv_acc, *, bq: int, bk: int, causal: bool,
-                scale: float):
+                scale: float, band=None):
     """Streaming dk/dv: grid (head, k tile, q tile), q innermost. The
     owned k/v tiles stay resident (their index is constant over qi);
     q/do/lse/delta tiles stream past; dk/dv accumulate in VMEM scratch.
@@ -288,9 +396,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     formed transposed, keys down and queries across ([BK, BQ]), so
     p^T do and ds^T q are plain products and lse/delta come as rows
     ([1, BQ], one dense line each) that broadcast down the tile."""
-    kj, qi = pl.program_id(1), pl.program_id(2)
+    kj, step = pl.program_id(1), pl.program_id(2)
+    qi = step if band is None else band.q_tile(kj, step)
+    window = band and band.window
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -300,23 +410,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         do = do_ref[0]
         st = _dot(k_ref[0], qb, _NT) * scale  # [BK, BQ]
         if masked:
-            st = _mask(st, qi, kj, bq, bk, q_axis=1)
+            st = _mask(st, qi, kj, bq, bk, 1, window)
         pt = jnp.exp(st - lse_ref[0])  # lse, delta: [1, BQ]
         dv_acc[...] = dv_acc[...] + _dot(pt.astype(do.dtype), do, _NN)
         dpt = _dot(v_ref[0], do, _NT)
         dst = (pt * (dpt - delta_ref[0]) * scale).astype(qb.dtype)
         dk_acc[...] = dk_acc[...] + _dot(dst, qb, _NN)
 
-    _on_visible_tiles(qi, kj, bq, bk, causal, body)
+    _on_visible_tiles(
+        qi, kj, bq, bk, causal, body, window, band and band.nq
+    )
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
-                    tiles):
+                    tiles, band=None):
     b, L, h, d = q.shape
     bq, bk = tiles
     scale = 1.0 / math.sqrt(d)
@@ -326,14 +438,16 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1).reshape(b * h, L, 1)
-    own, seen_k, seen_q = _tile_picks(bq, bk, causal)
+    own, seen_k, seen_q = (
+        band.picks() if band else _tile_picks(bq, bk, causal)
+    )
     column = pl.BlockSpec((1, bq, 1), lambda i, j, t: (i, j, 0))
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale
+            _dq_kernel, bq=bq, bk=bk, causal=causal, scale=scale, band=band
         ),
         out_shape=jax.ShapeDtypeStruct(lay.shape, q.dtype),
-        grid=(b * h, L // bq, L // bk),
+        grid=(b * h, L // bq, band.k_steps if band else L // bk),
         in_specs=[
             lay.spec(bq, own), lay.spec(bk, seen_k), lay.spec(bk, seen_k),
             lay.spec(bq, own), column, column,
@@ -345,13 +459,13 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
     row = pl.BlockSpec((1, 1, bq), lambda i, j, t: (i, 0, seen_q(j, t)))
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale
+            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale, band=band
         ),
         out_shape=[
             jax.ShapeDtypeStruct(lay.shape, k.dtype),
             jax.ShapeDtypeStruct(lay.shape, v.dtype),
         ],
-        grid=(b * h, L // bk, L // bq),
+        grid=(b * h, L // bk, band.q_steps if band else L // bq),
         in_specs=[
             lay.spec(bq, seen_q), lay.spec(bk, own), lay.spec(bk, own),
             lay.spec(bq, seen_q), row, row,
@@ -366,34 +480,40 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
     return tuple(lay.unview(x) for x in (dq, dk, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles):
-    return _flash_forward(q, k, v, causal, interpret, tiles)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles,
+                     band=None):
+    return _flash_forward(q, k, v, causal, interpret, tiles, band)[0]
 
 
-def _fa_fwd(q, k, v, causal, interpret, tiles):
-    o, lse = _flash_forward(q, k, v, causal, interpret, tiles)
+def _fa_fwd(q, k, v, causal, interpret, tiles, band):
+    o, lse = _flash_forward(q, k, v, causal, interpret, tiles, band)
     return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(causal, interpret, tiles, residuals, g):
+def _fa_bwd(causal, interpret, tiles, band, residuals, g):
     # two-kernel flash backward (dq; dk+dv) from O(L*D) residuals —
     # the [L, L] score matrix is re-formed tile by tile in VMEM, never
     # materialized in HBM
     q, k, v, o, lse = residuals
-    return _flash_backward(q, k, v, o, lse, g, causal, interpret, tiles)
+    return _flash_backward(
+        q, k, v, o, lse, g, causal, interpret, tiles, band
+    )
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
-                    tiles=None):
+                    tiles=None, window=None):
     """Differentiable fused attention, [B, L, H, D] -> [B, L, H, D].
     `interpret=True` runs the kernel in the Pallas interpreter and is
     for tests only (no model path passes it); compiled, the kernels
     are Mosaic programs and exist on the TPU alone. `tiles` = (q edge,
-    k edge) in place of `pick_tiles(L)`: the tests' and the sweep's."""
+    k edge) in place of `pick_tiles(L, window)`: the tests' and the
+    sweep's. `window`: the causal mask as a band, the query at t seeing
+    the keys u with 0 <= t - u < window; a window that holds the whole
+    sequence is the causal call itself."""
     if not interpret and jax.default_backend() != "tpu":
         raise RuntimeError(
             "flash_attention compiles for the TPU only (default backend "
@@ -401,10 +521,15 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
             "tests pass interpret=True"
         )
     L = q.shape[1]
-    tiles = tuple(tiles or pick_tiles(L) or ())
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"window={window} needs causal=True and a key to see")
+    if window is not None and window >= L:
+        window = None
+    tiles = tuple(tiles or pick_tiles(L, window) or ())
     if len(tiles) != 2 or L % tiles[0] or L % tiles[1]:
         raise ValueError(f"no tile of {tiles or TILE_LADDER} divides L={L}")
-    return _flash_attention(q, k, v, causal, interpret, tiles)
+    band = window and _Band(L, *tiles, window)
+    return _flash_attention(q, k, v, causal, interpret, tiles, band)
 
 
 # check_against_reference's bound on max|kernel - ref| / max|ref|: bf16
@@ -415,7 +540,8 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
 REFERENCE_TOLERANCE = 2.0**-6
 
 
-def check_against_reference(shape, interpret: bool = False, seed: int = 0):
+def check_against_reference(shape, interpret: bool = False, seed: int = 0,
+                            window=None):
     """Forward and all three backward kernels at one [B, L, H, D] bf16
     shape against `reference_attention` in true f32 — chip_smoke.py's
     kernel phase and the gated chip tests. Returns, for o/dq/dk/dv,
@@ -439,9 +565,11 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0):
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
 
     (_, o), grads = through(
-        lambda q, k, v: flash_attention(q, k, v, interpret=interpret)
+        lambda q, k, v: flash_attention(
+            q, k, v, interpret=interpret, window=window
+        )
     )(q, k, v, w)
-    ref_fn = through(reference_attention)
+    ref_fn = through(functools.partial(reference_attention, window=window))
     refs = []
     with jax.default_matmul_precision("highest"):
         for h in range(shape[2]):
@@ -476,8 +604,11 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0):
 FLASH_MIN_LENGTH = 2048
 
 
-def attention(q, k, v, causal: bool = True, scale=None):
-    """Dispatcher, the single entry point for model code.
+def attention(q, k, v, causal: bool = True, scale=None, window=None):
+    """Dispatcher, the single entry point for model code: causal,
+    optionally banded (`window`: the query at t sees the keys u with
+    0 <= t - u < window; None, or a window that holds the sequence, is
+    the causal call and traces as it did).
 
     On a TPU the Pallas kernels take a call whose sequence the tile
     ladder divides and that is at least FLASH_MIN_LENGTH long, at
@@ -510,6 +641,8 @@ def attention(q, k, v, causal: bool = True, scale=None):
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     L = q.shape[1]
+    if window is not None and window >= L:
+        window = None
     flag = os.environ.get(ENV_TPU_FLASH)
     kernel_shapes = scale is None and v.shape[-1] == q.shape[-1]
     if (
@@ -519,5 +652,5 @@ def attention(q, k, v, causal: bool = True, scale=None):
         and flag != "0"
         and (flag == "1" or L >= FLASH_MIN_LENGTH)
     ):
-        return flash_attention(q, k, v, causal)
-    return reference_attention(q, k, v, causal, scale)
+        return flash_attention(q, k, v, causal, window=window)
+    return reference_attention(q, k, v, causal, scale, window)

@@ -457,8 +457,8 @@ def moe_topk_held(
     and no `shared` scope when `shared` is None).
 
     `score` "softmax" routes by `route_topk`; "sigmoid" by
-    `route_sigmoid_topk` with the selection `bias` and, under
-    `renormalize`, gates that sum to one over a token's k experts
+    `route_sigmoid_topk` with the selection `bias`; under
+    `renormalize` either's gates sum to one over a token's k experts
     (then times `scaling`). `balance` False leaves the balance term out
     (0): a layer balanced by its selection bias has none in its loss.
 
@@ -494,6 +494,8 @@ def moe_topk_held(
     with jax.named_scope("route"):
         if score == "softmax":
             probs, gate, chosen = route_topk(xf, router_w, top_k)
+            if renormalize:  # over all k chosen, held here or not
+                gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
         else:
             probs, gate, chosen = route_sigmoid_topk(
                 xf, router_w, bias, top_k, renormalize

@@ -269,3 +269,168 @@ def test_the_rule_reads_shapes_and_engages_where_the_chip_said(
     )
     assert out.shape == v.shape
     assert bool(called) is kernels, case
+
+
+# ------------------------------------------------------------ the band
+
+# (window, what it is to tiles of 128): under a tile, a tile, not a
+# multiple of the tile, two tiles, one key, the whole sequence and more
+WINDOWS = [
+    (40, "under-a-tile"), (128, "a-tile"), (200, "not-a-multiple"),
+    (256, "two-tiles"), (1, "one-key"), (511, "all-but-one"),
+]
+BAND_TILES = [(128, 128), (256, 128), (128, 256), (256, 256), (512, 128)]
+
+
+def _through(attn, w):
+    def loss(q, k, v):
+        o = attn(q, k, v)
+        return jnp.sum(o * w), o
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+
+@pytest.mark.parametrize("tiles", BAND_TILES, ids=lambda t: f"q{t[0]}k{t[1]}")
+@pytest.mark.parametrize("window", [w for w, _ in WINDOWS],
+                         ids=[name for _, name in WINDOWS])
+def test_the_banded_kernels_match_the_reference_tile_pair_by_tile_pair(
+    window, tiles
+):
+    """Forward, dq, dk and dv of the banded call at L = 512 against
+    `reference_attention`'s banded mask and a generic cotangent, over
+    tile pairs that put into each kernel's sweep a tile the band's left
+    edge crosses, one the diagonal crosses, one both cross, one wholly
+    inside the band and steps past the row's (the column's) last
+    tile."""
+    q, k, v = _qkv(b=1, L=4 * BLOCK, h=2, d=32, seed=11)
+    w = _qkv(b=1, L=4 * BLOCK, h=2, d=32, seed=12)[0]
+    (_, o), grads = _through(
+        lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, tiles=tiles, window=window
+        ), w,
+    )(q, k, v)
+    (_, o_ref), grads_ref = _through(
+        lambda q, k, v: reference_attention(q, k, v, window=window), w
+    )(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for name, got, want in zip(("dq", "dk", "dv"), grads, grads_ref):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=5e-5, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("window", [512, 513, 4096])
+def test_a_window_that_holds_the_sequence_is_the_causal_call_bit_for_bit(
+    window
+):
+    q, k, v = _qkv(b=1, L=4 * BLOCK, h=2, d=128, seed=13)
+    w = _qkv(b=1, L=4 * BLOCK, h=2, d=128, seed=14)[0]
+    tiles = (256, 128)
+
+    def call(window):
+        return _through(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=True, tiles=tiles, window=window
+            ), w,
+        )
+
+    (_, o), grads = call(window)(q, k, v)
+    (_, o_causal), grads_causal = call(None)(q, k, v)
+    for got, want in zip((o, *grads), (o_causal, *grads_causal)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    # and the same traced program: no band is built
+    assert str(jax.make_jaxpr(call(window))(q, k, v)) == str(
+        jax.make_jaxpr(call(None))(q, k, v)
+    )
+    assert str(jax.make_jaxpr(call(200))(q, k, v)) != str(
+        jax.make_jaxpr(call(None))(q, k, v)
+    )
+
+
+def test_the_band_forgets_a_key_that_left_the_window():
+    """Perturbing a key and value changes exactly the `window` queries
+    that see it (3 tiles deep, window 100)."""
+    q, k, v = _qkv(L=3 * BLOCK)
+    at, window = 130, 100
+    out1 = flash_attention(q, k, v, interpret=True, window=window)
+    out2 = flash_attention(
+        q, k.at[:, at].set(100.0), v.at[:, at].set(-100.0), interpret=True,
+        window=window,
+    )
+    changed = np.any(
+        np.abs(np.asarray(out1) - np.asarray(out2)) > 1e-4, axis=(0, 2, 3)
+    )
+    assert np.array_equal(np.flatnonzero(changed), np.arange(at, at + window))
+
+
+@pytest.mark.parametrize("L, window, want", [
+    (8192, 512, (512, 512)), (8192, 513, (512, 512)), (8192, 300, (256, 256)),
+    (8192, 1024, (1024, 1024)), (8192, 4096, (1024, 1024)),
+    (8192, 8, (128, 128)), (384, 200, (128, 128)), (8192, None, (1024, 1024)),
+    (96, 8, None),
+])
+def test_a_band_s_tiles_are_no_longer_than_its_window(L, window, want):
+    assert pick_tiles(L, window) == want
+
+
+@pytest.mark.parametrize("L, tiles, window, k_steps, q_steps", [
+    (8192, (512, 512), 512, 2, 2),  # the cell's: 2 of a row's 16 tiles
+    (8192, (256, 256), 512, 3, 3),
+    (8192, (1024, 1024), 512, 2, 2),
+    (8192, (1024, 512), 512, 3, 2),
+    (512, (128, 128), 1, 1, 1),
+    (512, (128, 128), 200, 3, 3),
+])
+def test_a_band_s_inner_axis_is_as_long_as_the_tiles_it_crosses(
+    L, tiles, window, k_steps, q_steps
+):
+    from elasticdl_tpu.ops.flash_attention import _Band
+
+    band = _Band(L, *tiles, window)
+    assert (band.k_steps, band.q_steps) == (k_steps, q_steps)
+    bq, bk = tiles
+    for j in range(L // bq):  # every visible pair lies in a walked tile
+        first, last = int(band.first_k(j, max)), band.last_k(j)
+        assert first * bk <= max(j * bq - window + 1, 0) < (first + 1) * bk
+        assert last * bk <= j * bq + bq - 1 < (last + 1) * bk
+        assert last - first + 1 <= k_steps
+    assert band == _Band(L, *tiles, window) and hash(band) == hash(
+        _Band(L, *tiles, window)
+    )
+
+
+def test_a_window_needs_the_causal_mask_and_a_key():
+    q, k, v = _qkv(b=1, L=BLOCK)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=False, interpret=True, window=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, interpret=True, window=0)
+
+
+@pytest.mark.parametrize("case, length, window, kernels, passed", [
+    ("banded-8192", 8192, 512, True, 512),
+    ("holds-the-sequence", 8192, 8192, True, None),
+    ("under-min-length", 1024, 512, False, 512),
+    ("none", 2048, None, True, None),
+])
+def test_the_dispatcher_hands_the_window_to_whichever_path_takes_the_call(
+    monkeypatch, case, length, window, kernels, passed
+):
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+    seen = {}
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda q, k, v, causal, window=None: seen.update(kernel=window) or v,
+    )
+    monkeypatch.setattr(
+        fa, "reference_attention",
+        lambda q, k, v, causal, scale, window: seen.update(xla=window) or v,
+    )
+    x = jax.ShapeDtypeStruct((1, length, 8, 128), jnp.bfloat16)
+    jax.eval_shape(
+        lambda q, k, v: fa.attention(q, k, v, window=window), x, x, x
+    )
+    assert seen == {("kernel" if kernels else "xla"): passed}, case

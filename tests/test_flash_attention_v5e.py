@@ -47,15 +47,20 @@ def no_compile_cache():
     (1, 16384, 8, 64),  # the long sequence of test_cluster_gated.py
     (1, 384, 2, 64),  # a length only BLOCK divides
     (1, 2048, 4, 256),  # the widest head: the tiles' VMEM at its largest
-], ids=lambda s: "x".join(map(str, s)))
+    (1, 8192, 48, 128),  # laguna-xs2's full layers
+    (1, 8192, 64, 128, 512),  # its sliding layers: banded, 512 x 512 tiles
+    (1, 2048, 4, 128, 300),  # a window no tile divides
+    (1, 8192, 8, 128, 513),  # one key more than the tile
+], ids=lambda s: "x".join(map(str, s[:4])) + "".join(f"-w{w}" for w in s[4:]))
 def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
+    shape, window = shape[:4], (*shape[4:], None)[0]
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    tiles = fa.pick_tiles(shape[1], window)
+    band = window and fa._Band(shape[1], *tiles, window)
 
     def loss(q, k, v, w):
         with jax.named_scope("attention"):
-            o = fa._flash_attention(
-                q, k, v, True, False, fa.pick_tiles(shape[1])
-            )
+            o = fa._flash_attention(q, k, v, True, False, tiles, band)
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
 
     compiled = (
