@@ -389,11 +389,18 @@ def child_kernels(cpu):
         if cpu
         else [(1, 2048, 8, 64), (1, 8192, 8, 64), (1, 2048, 8, 128)]
     )
+    # latent attention as the routed cell calls it: values of 128 under
+    # keys of 192 and deepseek-v2-lite's `mla_softmax_scale`
+    latent = {"v_width": 128, "scale": 0.11472138679292611}
+    cases = [(shape, {}) for shape in shapes] + [
+        ((1, 256, 2, 192) if cpu else (4, 2048, 16, 192), latent)
+    ]
     errors = {}
-    for shape in shapes:
-        errors["x".join(map(str, shape))] = check_against_reference(
-            shape, interpret=cpu
+    for shape, how in cases:
+        name = "x".join(map(str, shape)) + "".join(
+            f"-{key}{value:.4g}" for key, value in how.items()
         )
+        errors[name] = check_against_reference(shape, interpret=cpu, **how)
     print(json.dumps({
         "platform": device["platform"], "interpret": cpu,
         "tolerance": REFERENCE_TOLERANCE, "max_error_over_max_ref": errors,

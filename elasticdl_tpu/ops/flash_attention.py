@@ -26,8 +26,12 @@ is only as long as the tiles a band crosses and starts at the band's
 first tile (`_Band`), so a tile wholly left of the band costs no step
 at all, and a tile the band's left edge crosses is masked on that edge.
 A head width of a multiple
-of 128 is read where it lies in [B, L, H, D]; a narrower one is folded
-through memory (`_Layout`). VMEM use is O(tile) whatever L is. The
+of 128 is read where it lies in [B, L, H, D]; any other is folded
+through memory (`_Layout`). Values, the output and its cotangent may be
+of another width than queries and keys (latent attention: 192 | 128)
+and are laid out by their own; the scores' scale is 1/sqrt(D) or the
+caller's, a Python number compiled in. VMEM use is O(tile) whatever L
+is. The
 forward also emits the per-row logsumexp; the backward is the standard
 two-kernel flash scheme re-forming p = exp(s - lse) from O(L*D)
 residuals: nothing quadratic is ever saved, and no atomics, each
@@ -37,8 +41,9 @@ tests/test_flash_attention.py in Pallas interpret mode on CPU, and
 compiled on the chip by chip_smoke.py and under EDL_TPU_TESTS=1
 (`check_against_reference`).
 
-Layout contract: [B, L, H, D] ("blhd", matching transformer_lm), any
-float dtype; scores, softmax and accumulators are f32. L must divide
+Layout contract: q and k [B, L, H, D], v [B, L, H, Dv] ("blhd",
+matching transformer_lm), any float dtype; scores, softmax and
+accumulators are f32. L must divide
 by the 128 block; callers with ragged L use the jnp fallback
 (`reference_attention`).
 """
@@ -222,9 +227,12 @@ class _Band:
 class _Layout:
     """How the kernels see [B, L, H, D]: one (head, rows) tile of width
     D at a time. A head width the lanes divide is read where it lies,
-    as columns h*D.. of [B, L, H*D]; a narrower head cannot be a block
-    of that array (its last edge must be a multiple of 128 or the whole
-    of it), so it is folded to [B*H, L, D] through memory."""
+    as columns h*D.. of [B, L, H*D]; any other (64, or latent
+    attention's 192) cannot be a block of that array (its last edge
+    must be a multiple of 128 or the whole of it), so it is folded to
+    [B*H, L, D] through memory. The other way with 192, zeros padded to
+    256 and read in place, measured the same (FLASH_MIN_LENGTH's
+    table) and is not built."""
 
     def __init__(self, b, L, h, d):
         self.b, self.L, self.h, self.d = b, L, h, d
@@ -315,17 +323,31 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])  # [BQ, 1]
+        l = l_ref[...]  # [BQ, 1]
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        # log(l), then one Newton step on exp(-y) l = 1: Mosaic's log
+        # read up to 1.1e-4 off on the chip where its exp is good to
+        # 5e-6, and the backward kernels' p = exp(s - lse) carries lse's
+        # error into a whole row (float32 dq, dk, dv against the
+        # reference 2.7e-5 before the step, 2e-6 after: PERF.md, PR 49)
+        y = jnp.log(l)
+        lse_ref[0] = m_ref[...] + (y - 1.0 + l * jnp.exp(-y))
 
 
-def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band=None):
-    """Returns (o [B,L,H,D], lse [B*H, L, 1])."""
+def _layouts(q, v):
+    """(queries' and keys' layout, values' layout), each by its own
+    width; the output and its cotangent lie as the values do."""
+    b, L, h, d = q.shape
+    return _Layout(b, L, h, d), _Layout(b, L, h, v.shape[-1])
+
+
+def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band,
+                   scale: float):
+    """Returns (o [B,L,H,Dv], lse [B*H, L, 1])."""
     b, L, h, d = q.shape
     bq, bk = tiles
-    lay = _Layout(b, L, h, d)
+    lay, vlay = _layouts(q, v)
     own, seen_k, _ = band.picks() if band else _tile_picks(bq, bk, causal)
-    q_spec, kv_spec = lay.spec(bq, own), lay.spec(bk, seen_k)
     # rows ([B*H, L, 1]) carry a trailing singleton so Mosaic's tiling
     # rule holds: block (1, BQ, 1) -> last two dims (BQ, 1) are
     # (div-by-8, equal-to-array)
@@ -333,23 +355,25 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band=None):
     out, lse = pl.pallas_call(
         functools.partial(
             _fa_kernel, bq=bq, bk=bk, causal=causal,
-            scale=1.0 / math.sqrt(d), band=band,
+            scale=scale, band=band,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(lay.shape, q.dtype),
+            jax.ShapeDtypeStruct(vlay.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32),
         ],
         grid=(b * h, L // bq, band.k_steps if band else L // bk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, lse_spec],
+        in_specs=[
+            lay.spec(bq, own), lay.spec(bk, seen_k), vlay.spec(bk, seen_k)
+        ],
+        out_specs=[vlay.spec(bq, own), lse_spec],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, vlay.d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         **_params(interpret),
-    )(lay.view(q), lay.view(k), lay.view(v))
-    return lay.unview(out), lse
+    )(lay.view(q), lay.view(k), vlay.view(v))
+    return vlay.unview(out), lse
 
 
 # ---------------------------------------------------------------- backward
@@ -428,12 +452,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
-                    tiles, band=None):
+                    tiles, band, scale: float):
     b, L, h, d = q.shape
     bq, bk = tiles
-    scale = 1.0 / math.sqrt(d)
-    lay = _Layout(b, L, h, d)
-    qf, kf, vf, gf = (lay.view(x) for x in (q, k, v, g))
+    lay, vlay = _layouts(q, v)
+    qf, kf, vf, gf = lay.view(q), lay.view(k), vlay.view(v), vlay.view(g)
     # delta_i = rowsum(do_i * o_i): tiny elementwise+reduce, XLA fuses
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
@@ -449,8 +472,8 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
         out_shape=jax.ShapeDtypeStruct(lay.shape, q.dtype),
         grid=(b * h, L // bq, band.k_steps if band else L // bk),
         in_specs=[
-            lay.spec(bq, own), lay.spec(bk, seen_k), lay.spec(bk, seen_k),
-            lay.spec(bq, own), column, column,
+            lay.spec(bq, own), lay.spec(bk, seen_k), vlay.spec(bk, seen_k),
+            vlay.spec(bq, own), column, column,
         ],
         out_specs=lay.spec(bq, own),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -463,41 +486,41 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
         ),
         out_shape=[
             jax.ShapeDtypeStruct(lay.shape, k.dtype),
-            jax.ShapeDtypeStruct(lay.shape, v.dtype),
+            jax.ShapeDtypeStruct(vlay.shape, v.dtype),
         ],
         grid=(b * h, L // bk, band.q_steps if band else L // bq),
         in_specs=[
-            lay.spec(bq, seen_q), lay.spec(bk, own), lay.spec(bk, own),
-            lay.spec(bq, seen_q), row, row,
+            lay.spec(bq, seen_q), lay.spec(bk, own), vlay.spec(bk, own),
+            vlay.spec(bq, seen_q), row, row,
         ],
-        out_specs=[lay.spec(bk, own), lay.spec(bk, own)],
+        out_specs=[lay.spec(bk, own), vlay.spec(bk, own)],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, vlay.d), jnp.float32),
         ],
         **_params(interpret),
     )(qf, kf, vf, gf, lse.reshape(b * h, 1, L), delta.reshape(b * h, 1, L))
-    return tuple(lay.unview(x) for x in (dq, dk, dv))
+    return lay.unview(dq), lay.unview(dk), vlay.unview(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles,
-                     band=None):
-    return _flash_forward(q, k, v, causal, interpret, tiles, band)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles, band,
+                     scale: float):
+    return _flash_forward(q, k, v, causal, interpret, tiles, band, scale)[0]
 
 
-def _fa_fwd(q, k, v, causal, interpret, tiles, band):
-    o, lse = _flash_forward(q, k, v, causal, interpret, tiles, band)
+def _fa_fwd(q, k, v, causal, interpret, tiles, band, scale):
+    o, lse = _flash_forward(q, k, v, causal, interpret, tiles, band, scale)
     return o, (q, k, v, o, lse)
 
 
-def _fa_bwd(causal, interpret, tiles, band, residuals, g):
+def _fa_bwd(causal, interpret, tiles, band, scale, residuals, g):
     # two-kernel flash backward (dq; dk+dv) from O(L*D) residuals —
     # the [L, L] score matrix is re-formed tile by tile in VMEM, never
     # materialized in HBM
     q, k, v, o, lse = residuals
     return _flash_backward(
-        q, k, v, o, lse, g, causal, interpret, tiles, band
+        q, k, v, o, lse, g, causal, interpret, tiles, band, scale
     )
 
 
@@ -505,21 +528,32 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
-                    tiles=None, window=None):
-    """Differentiable fused attention, [B, L, H, D] -> [B, L, H, D].
+                    tiles=None, window=None, scale=None):
+    """Differentiable fused attention, [B, L, H, D] -> [B, L, H, Dv]:
+    `v` may be of another width than `q` and `k` (latent attention),
+    each laid out by its own width (`_Layout`).
     `interpret=True` runs the kernel in the Pallas interpreter and is
     for tests only (no model path passes it); compiled, the kernels
     are Mosaic programs and exist on the TPU alone. `tiles` = (q edge,
     k edge) in place of `pick_tiles(L, window)`: the tests' and the
     sweep's. `window`: the causal mask as a band, the query at t seeing
     the keys u with 0 <= t - u < window; a window that holds the whole
-    sequence is the causal call itself."""
+    sequence is the causal call itself. `scale` multiplies the scores
+    in place of 1/sqrt(D): a Python number, compiled into the kernels
+    (a traced value is refused). A band rides the same index maps as
+    the causal call, so `window` goes with either."""
     if not interpret and jax.default_backend() != "tpu":
         raise RuntimeError(
             "flash_attention compiles for the TPU only (default backend "
             f"{jax.default_backend()!r}); model code calls attention(), "
             "tests pass interpret=True"
         )
+    if isinstance(scale, jax.core.Tracer):
+        raise TypeError(
+            "scale is compiled into the kernels: a Python number, not a "
+            f"traced value ({scale})"
+        )
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale)
     L = q.shape[1]
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"window={window} needs causal=True and a key to see")
@@ -529,7 +563,7 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
     if len(tiles) != 2 or L % tiles[0] or L % tiles[1]:
         raise ValueError(f"no tile of {tiles or TILE_LADDER} divides L={L}")
     band = window and _Band(L, *tiles, window)
-    return _flash_attention(q, k, v, causal, interpret, tiles, band)
+    return _flash_attention(q, k, v, causal, interpret, tiles, band, scale)
 
 
 # check_against_reference's bound on max|kernel - ref| / max|ref|: bf16
@@ -541,20 +575,23 @@ REFERENCE_TOLERANCE = 2.0**-6
 
 
 def check_against_reference(shape, interpret: bool = False, seed: int = 0,
-                            window=None):
+                            window=None, v_width=None, scale=None):
     """Forward and all three backward kernels at one [B, L, H, D] bf16
     shape against `reference_attention` in true f32 — chip_smoke.py's
     kernel phase and the gated chip tests. Returns, for o/dq/dk/dv,
     max|kernel - ref| / max|ref|. The cotangent is a fixed random
     tensor, so each gradient is checked against a generic direction.
     The reference runs one head at a time: its [L, L] scores and their
-    backward copies would not fit beside each other at L=8192."""
+    backward copies would not fit beside each other at L=8192.
+    `v_width`: values (and the cotangent) of another width than D;
+    `scale`: in place of 1/sqrt(D), for both sides."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
+    v_shape = (*shape[:3], v_width or shape[3])
     q, k, v, w = (
-        jnp.asarray(rng.standard_normal(shape), dtype=jnp.bfloat16)
-        for _ in range(4)
+        jnp.asarray(rng.standard_normal(s), dtype=jnp.bfloat16)
+        for s in (shape, shape, v_shape, v_shape)
     )
 
     def through(attn):
@@ -566,10 +603,12 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 
     (_, o), grads = through(
         lambda q, k, v: flash_attention(
-            q, k, v, interpret=interpret, window=window
+            q, k, v, interpret=interpret, window=window, scale=scale
         )
     )(q, k, v, w)
-    ref_fn = through(functools.partial(reference_attention, window=window))
+    ref_fn = through(
+        functools.partial(reference_attention, window=window, scale=scale)
+    )
     refs = []
     with jax.default_matmul_precision("highest"):
         for h in range(shape[2]):
@@ -601,6 +640,23 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 # 0.079 | 0.166, 0.104 | 0.234); from 2048 it writes them out, ten times
 # the time for four times the work. Lengths between the two are not
 # measured and stay with XLA.
+# Latent attention, queries and keys 192 wide over values of 128 under
+# deepseek-v2-lite's scale 0.11472 (2026-10-01, the same chip kind and
+# versions, `scripts/swa_kernel_sweep.py --cases latent`), ms a call,
+# forward + backward (forward alone): XLA | the kernels with q and k
+# folded to [B*H, L, 192] | with q and k padded to 256 and read in place:
+#   (4, 2048, 16, 192 | 128) at 1024 x 1024
+#       7.534 (2.587) | 6.156 (1.677) | 6.220 (1.813)
+#   (2, 2048, 32, 192 | 128) at 1024 x 1024
+#       7.426 (2.589) | 6.255 (1.676) | 6.148 (1.822)
+#   at 512 x 512        - | 6.874 (2.258), 6.978 (2.234) | 6.983, 6.858
+#   (2, 2048, 16, 128) that day: 3.788 (1.276) | 2.071 (0.604)
+# Folded and padded tie forward + backward (the multiplier is 128 x 128:
+# 192 costs the two passes 256 does, and a pad moves what a transpose
+# moves); folded is 8 % ahead in the forward pass, which a step that
+# recomputes runs twice, and is what `_Layout` does with any width the
+# lanes do not divide: kept. 192 | 128 costs 1.49 x the equal-width
+# call a head (14 passes of the multiplier a tile pair for 9).
 FLASH_MIN_LENGTH = 2048
 
 
@@ -612,15 +668,16 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
 
     On a TPU the Pallas kernels take a call whose sequence the tile
     ladder divides and that is at least FLASH_MIN_LENGTH long, at
-    either head width measured (the rule reads nothing but the call's
+    every head width measured (the rule reads nothing but the call's
     own shapes); XLA's attention takes the rest.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
     EDL_TPU_FLASH=0 forces them off. The kernels hold their scores in
     float32 where XLA's path rounds them to the inputs' dtype
     (tests/test_flash_attention.py holds both to the float32 math).
-    The kernels know one head width and the scale 1/sqrt(D): values of
-    another width than the queries (latent attention) or a `scale` of
-    the caller's never reach them, whatever the flag says.
+    Values of another width than the queries and keys (latent
+    attention: 192 | 128) and a `scale` of the caller's in place of
+    1/sqrt(D) go where an equal-width call of that length goes;
+    `scale` is a Python number, never a traced value.
 
     `k` and `v` may have fewer heads than `q` (grouped-query attention,
     the query heads a multiple): query head i reads key-value head
@@ -644,13 +701,11 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
     if window is not None and window >= L:
         window = None
     flag = os.environ.get(ENV_TPU_FLASH)
-    kernel_shapes = scale is None and v.shape[-1] == q.shape[-1]
     if (
-        kernel_shapes
-        and jax.default_backend() == "tpu"
+        jax.default_backend() == "tpu"
         and pick_tiles(L) is not None
         and flag != "0"
         and (flag == "1" or L >= FLASH_MIN_LENGTH)
     ):
-        return flash_attention(q, k, v, causal, window=window)
+        return flash_attention(q, k, v, causal, window=window, scale=scale)
     return reference_attention(q, k, v, causal, scale, window)

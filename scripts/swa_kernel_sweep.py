@@ -1,19 +1,31 @@
-"""The banded attention kernels standalone on the chip: forward and
-backward together on bf16 inputs inside one program, ms a call, over
-the tile ladder, beside the causal call of the same shapes, and the
-kernels against the float32 reference at a length that fits. (PR 48
-also ran it with the other way of walking a band, the causal call's
-whole row held at the band's nearest tile on both sides, which lost
-and went: `ops/flash_attention.py:_Band` has the numbers.)
+"""The attention kernels standalone on the chip: forward and backward
+together on bf16 inputs inside one program, ms a call, and the kernels
+against the float32 reference at a length that fits.
 
-    chiprun -- python scripts/swa_kernel_sweep.py
+`--cases band` (PR 48): the banded call over the tile ladder, beside
+the causal call of the same shapes. (PR 48 also ran it with the other
+way of walking a band, the causal call's whole row held at the band's
+nearest tile on both sides, which lost and went:
+`ops/flash_attention.py:_Band` has the numbers.)
 
-Writes chiprun_out/swa_kernel_sweep.json. `--compile_only` lowers and
-compiles every case for a described v5e (no chip) and times nothing.
+`--cases latent` (PR 49): 192-wide queries and keys over 128-wide
+values under the routed cell's softmax scale, at the ladder's tiles:
+XLA's materialised path | the kernels with q and k folded to
+[B*H, L, 192] through memory (what `_Layout` does with a width the
+lanes do not divide) | q and k padded with zeros to 256 and read in
+place (`padded`, stated here: the other honest layout; zeros add
+nothing to a score, dq and dk are cut back by the pad's own gradient).
+
+    chiprun -- python scripts/swa_kernel_sweep.py --cases latent
+
+Writes chiprun_out/swa_kernel_sweep.<cases>.json. `--compile_only`
+lowers and compiles every case for a described v5e (no chip) and
+times nothing.
 """
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -31,31 +43,65 @@ from elasticdl_tpu.ops import flash_attention as fa  # noqa: E402
 BANDED = (1, 8192, 64, 128)
 CAUSAL = (1, 8192, 48, 128)
 WINDOW = 512
-# (shape, window, tiles)
-CASES = [
-    (BANDED, WINDOW, (bq, bk))
-    for bq, bk in (
-        (128, 128), (256, 256), (512, 512), (1024, 1024), (256, 128),
-        (512, 128), (512, 256), (1024, 256), (1024, 512), (256, 512),
-        (128, 512), (2048, 512),
-    )
-] + [
-    (CAUSAL, None, (e, e)) for e in (512, 1024)
-] + [(BANDED, None, (1024, 1024))]
+# the routed cell's calls, and the hybrid cell's
+LATENT = ((4, 2048, 16, 192), (2, 2048, 32, 192))
+V_WIDTH = 128
+# deepseek-v2-lite's `mla_softmax_scale`: 192^-0.5 x mscale(40, 0.707)^2
+LATENT_SCALE = 192**-0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2
+# (shape, window, tiles, v_width, scale, path)
+CASES = {
+    "band": [
+        (BANDED, WINDOW, (bq, bk), None, None, "kernels")
+        for bq, bk in (
+            (128, 128), (256, 256), (512, 512), (1024, 1024), (256, 128),
+            (512, 128), (512, 256), (1024, 256), (1024, 512), (256, 512),
+            (128, 512), (2048, 512),
+        )
+    ] + [
+        (CAUSAL, None, (e, e), None, None, "kernels") for e in (512, 1024)
+    ] + [(BANDED, None, (1024, 1024), None, None, "kernels")],
+    "latent": [
+        (shape, None, tiles, V_WIDTH, LATENT_SCALE, path)
+        for shape in LATENT
+        for tiles, path in (
+            (None, "xla"), ((1024, 1024), "folded"), ((1024, 1024), "padded"),
+            ((512, 512), "folded"), ((512, 512), "padded"),
+        )
+    ] + [  # the equal-width call beside them: FLASH_MIN_LENGTH's row
+        ((2, 2048, 16, 128), None, t, None, None, p)
+        for t, p in ((None, "xla"), ((1024, 1024), "kernels"))
+    ],
+}
 
 
-def program(window, tiles):
+def attend(window, tiles, scale, path):
+    """q, k, v -> o by one of the paths a case names."""
+    if path == "xla":
+        return lambda q, k, v: fa.reference_attention(
+            q, k, v, True, scale, window
+        )
+
+    def kernels(q, k, v):
+        if path == "padded":
+            pad = ((0, 0),) * 3 + ((0, -q.shape[-1] % 128),)
+            q, k = jnp.pad(q, pad), jnp.pad(k, pad)
+        return fa.flash_attention(
+            q, k, v, tiles=tiles, window=window, scale=scale
+        )
+
+    return kernels
+
+
+def program(*case):
     def loss(q, k, v, w):
-        o = fa.flash_attention(q, k, v, tiles=tiles, window=window)
+        o = attend(*case)(q, k, v)
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
 
-def forward_program(window, tiles):
-    return jax.jit(
-        lambda q, k, v: fa.flash_attention(q, k, v, tiles=tiles, window=window)
-    )
+def forward_program(*case):
+    return jax.jit(attend(*case))
 
 
 def timed(fn, args, repeats=10):
@@ -71,7 +117,9 @@ def timed(fn, args, repeats=10):
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--compile_only", action="store_true")
+    parser.add_argument("--cases", choices=sorted(CASES), default="band")
     args = parser.parse_args()
+    cases = CASES[args.cases]
     if args.compile_only:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
@@ -81,43 +129,58 @@ def main():
             platform="tpu", topology_name="v5e:2x2"
         )
         chip = SingleDeviceSharding(topo.devices[0])
-        for shape, window, tiles in CASES:
+        for shape, window, tiles, v_width, scale, path in cases:
             x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+            v = jax.ShapeDtypeStruct(
+                (*shape[:3], v_width or shape[3]), jnp.bfloat16, sharding=chip
+            )
             t0 = time.perf_counter()
-            program(window, tiles).lower(x, x, x, x).compile()
-            print(shape, window, tiles, "compiles",
+            program(window, tiles, scale, path).lower(x, x, v, v).compile()
+            print(shape, v_width, window, tiles, path, "compiles",
                   f"{time.perf_counter() - t0:.1f}s", flush=True)
         return
     rng = np.random.default_rng(0)
     results = {"device": jax.devices()[0].device_kind, "cases": []}
     # the kernels against the float32 reference, a head at a time
-    for window, tiles_len in ((WINDOW, 2048), (300, 2048), (None, 2048)):
-        errors = fa.check_against_reference(
-            (1, tiles_len, 4, 128), window=window
-        )
-        print("check", window, errors, flush=True)
+    checks = {
+        "band": [
+            ((1, 2048, 4, 128), {"window": w}) for w in (WINDOW, 300, None)
+        ],
+        "latent": [
+            (shape, {"v_width": V_WIDTH, "scale": LATENT_SCALE})
+            for shape in LATENT
+        ],
+    }[args.cases]
+    for shape, how in checks:
+        errors = fa.check_against_reference(shape, **how)
+        print("check", shape, how, errors, flush=True)
         results.setdefault("checks", []).append(
-            {"window": window, "L": tiles_len, "errors": errors}
+            {"shape": shape, **how, "errors": errors}
         )
     arrays = {}
-    for shape, window, tiles in CASES:
+    for shape, window, tiles, v_width, scale, path in cases:
+        v_shape = (*shape[:3], v_width or shape[3])
         if shape not in arrays:
             arrays[shape] = [
-                jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-                for _ in range(4)
+                jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+                for s in (shape, shape, v_shape, v_shape)
             ]
         q, k, v, w = arrays[shape]
+        case = (window, tiles, scale, path)
         try:
-            both = timed(program(window, tiles), (q, k, v, w))
-            fwd = timed(forward_program(window, tiles), (q, k, v))
+            both = timed(program(*case), (q, k, v, w))
+            fwd = timed(forward_program(*case), (q, k, v))
         except Exception as e:  # a tile pair Mosaic refuses
-            print(shape, window, tiles, "FAILED", repr(e)[:300], flush=True)
+            print(shape, *case, "FAILED", repr(e)[:300], flush=True)
             continue
-        case = {"shape": shape, "window": window, "tiles": tiles,
-                "fwd_bwd_ms": both, "fwd_ms": fwd}
+        case = {"shape": shape, "v_width": v_width, "window": window,
+                "tiles": tiles, "path": path, "fwd_bwd_ms": both,
+                "fwd_ms": fwd}
         print(json.dumps(case), flush=True)
         results["cases"].append(case)
-    out = os.path.join(ROOT, "chiprun_out", "swa_kernel_sweep.json")
+    out = os.path.join(
+        ROOT, "chiprun_out", f"swa_kernel_sweep.{args.cases}.json"
+    )
     os.makedirs(os.path.dirname(out), exist_ok=True)
     with open(out, "w") as f:
         json.dump(results, f, indent=1)

@@ -214,7 +214,13 @@ def test_tpu_flash_attention_compiled():
 import json, sys
 sys.path.insert(0, %r)
 from elasticdl_tpu.ops.flash_attention import BLOCK, check_against_reference
-print(json.dumps(check_against_reference((2, 2 * BLOCK, 4, 64))))
+print(json.dumps([
+    check_against_reference((2, 2 * BLOCK, 4, 64)),
+    # the routed cell's latent attention: 192 | 128, its own scale
+    check_against_reference(
+        (4, 2048, 16, 192), v_width=128, scale=0.11472138679292611
+    ),
+]))
 """ % (REPO,)
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
     env.pop("XLA_FLAGS", None)
@@ -225,9 +231,9 @@ print(json.dumps(check_against_reference((2, 2 * BLOCK, 4, 64))))
     assert out.returncode == 0, out.stderr[-2000:]
     from elasticdl_tpu.ops.flash_attention import REFERENCE_TOLERANCE
 
-    errors = json.loads(out.stdout.strip().splitlines()[-1])
-    assert set(errors) == {"o", "dq", "dk", "dv"}
-    assert max(errors.values()) <= REFERENCE_TOLERANCE, errors
+    for errors in json.loads(out.stdout.strip().splitlines()[-1]):
+        assert set(errors) == {"o", "dq", "dk", "dv"}
+        assert max(errors.values()) <= REFERENCE_TOLERANCE, errors
 
 
 @pytest.mark.skipif(not TPU, reason="EDL_TPU_TESTS=1 needs the real chip")

@@ -188,42 +188,148 @@ def test_dispatcher_falls_back_off_tpu():
 
 @pytest.mark.parametrize("forced", ["1", None], ids=["flash-forced", "auto"])
 @pytest.mark.parametrize("case", ["wider-keys", "own-scale"])
-def test_values_of_another_width_or_a_scale_never_reach_the_kernels(
+def test_values_of_another_width_or_a_scale_reach_the_kernels(
     monkeypatch, forced, case
 ):
     """Latent attention: 192-wide queries and keys beside 128-wide
-    values, and a softmax scale of the model's own. `attention` and
-    `reference_attention` take both; the Pallas kernels know one width
-    and 1/sqrt(D), so such shapes stay on XLA's path even on a TPU with
-    the kernels forced on. Same-width calls without a scale go where
-    they went."""
-    import math
-
+    values, and a softmax scale of the model's own. On a TPU
+    `attention` hands such a call to the Pallas kernels under the rule
+    an equal-width call goes by: the traced program of a 2048-token
+    call and its gradient holds the three `pallas_call`s, that of a
+    1024-token call none unless the flag forces them. Traced only."""
     from elasticdl_tpu.ops import flash_attention as fa
 
-    rng = np.random.default_rng(0)
-    b, L, h, dk, dv = 1, 128, 2, 48, 32
-    q = jnp.asarray(rng.standard_normal((b, L, h, dk)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, L, h, dk)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, L, h, dv if case == "wider-keys" else dk)), jnp.float32)
+    dk, dv = (192, 128) if case == "wider-keys" else (128, 128)
     scale = 0.5 * dk**-0.5 if case == "own-scale" else None
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if forced:
         monkeypatch.setenv("EDL_TPU_FLASH", forced)
-    called = []
-    monkeypatch.setattr(
-        fa, "flash_attention", lambda *a, **kw: called.append(a) or a[2]
+    else:
+        monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+
+    def kernels_of(length):
+        q = jax.ShapeDtypeStruct((1, length, 2, dk), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, length, 2, dv), jnp.bfloat16)
+
+        def loss(q, k, v):
+            o = fa.attention(q, k, v, causal=True, scale=scale)
+            assert o.shape == v.shape
+            return jnp.sum(o.astype(jnp.float32))
+
+        return str(
+            jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+        ).count("pallas_call")
+
+    assert kernels_of(2048) == 3  # forward, dq, dk+dv
+    assert kernels_of(1024) == (3 if forced else 0)
+
+
+# (dk, dv): folded | in place (the routed cell's), both folded, both in
+# place, in place | folded
+LATENT_WIDTHS = [(192, 128), (48, 32), (256, 128), (128, 64)]
+
+
+@pytest.mark.parametrize("L, tiles, window", [
+    (2 * BLOCK, (128, 128), None), (4 * BLOCK, (256, 128), None),
+    (4 * BLOCK, (128, 256), None), (4 * BLOCK, (128, 128), 200),
+], ids=["L256-q128k128", "L512-q256k128", "L512-q128k256", "L512-band200"])
+@pytest.mark.parametrize("dk, dv", LATENT_WIDTHS,
+                         ids=[f"{a}-over-{b}" for a, b in LATENT_WIDTHS])
+def test_a_value_width_and_a_scale_of_the_caller_s_match_the_float32_math(
+    dk, dv, L, tiles, window
+):
+    """Forward, dq, dk and dv with values of another width than the
+    queries and keys under `scale = 0.5 * dk**-0.5`, against the float32
+    math and a generic cotangent, tile pair by tile pair; each operand
+    laid out by its own width (192 folded beside 128 in place), and dq
+    and dk come back as wide as q and k. A band rides the same index
+    maps."""
+    q, k, _ = _qkv(b=1, L=L, h=2, d=dk, seed=21)
+    v, w, _ = _qkv(b=1, L=L, h=2, d=dv, seed=22)
+    scale = 0.5 * dk**-0.5
+    (_, o), grads = _through(
+        lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, tiles=tiles, scale=scale, window=window
+        ), w,
+    )(q, k, v)
+    (_, o_ref), grads_ref = _through(
+        lambda q, k, v: reference_attention(
+            q, k, v, scale=scale, window=window
+        ), w,
+    )(q, k, v)
+    assert o.shape == v.shape
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for name, got, want in zip(("dq", "dk", "dv"), grads, grads_ref):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=5e-5, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("dk, dv, scale", [(192, 128, 0.1147), (64, 64, None)])
+def test_the_forward_s_row_residual_is_the_logsumexp(dk, dv, scale):
+    """What the backward kernels re-form p from: log(l) after one
+    Newton step on exp(-y) l = 1 is still the logsumexp of the scaled,
+    masked scores."""
+    import math
+
+    from elasticdl_tpu.ops.flash_attention import _flash_forward
+
+    q, k, _ = _qkv(b=1, L=2 * BLOCK, h=2, d=dk, seed=23)
+    v = _qkv(b=1, L=2 * BLOCK, h=2, d=dv, seed=24)[0]
+    scale = scale or 1.0 / math.sqrt(dk)
+    _, lse = _flash_forward(q, k, v, True, True, (128, 128), None, scale)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    want = jax.nn.logsumexp(s, axis=-1).reshape(lse.shape)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-6)
+
+
+def test_a_traced_scale_is_refused():
+    q, k, v = _qkv(b=1, L=BLOCK)
+    with pytest.raises(TypeError, match="scale"):
+        jax.jit(
+            lambda s: flash_attention(q, k, v, interpret=True, scale=s)
+        )(0.1)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_the_scale_one_over_sqrt_d_is_the_call_without_a_scale_bit_for_bit(d):
+    import math
+
+    q, k, v = _qkv(b=1, L=2 * BLOCK, h=2, d=d, seed=15)
+    w = _qkv(b=1, L=2 * BLOCK, h=2, d=d, seed=16)[0]
+
+    def call(scale):
+        return _through(
+            lambda q, k, v: flash_attention(
+                q, k, v, interpret=True, scale=scale
+            ), w,
+        )
+
+    (_, o), grads = call(1.0 / math.sqrt(d))(q, k, v)
+    (_, o_none), grads_none = call(None)(q, k, v)
+    for got, want in zip((o, *grads), (o_none, *grads_none)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert str(jax.make_jaxpr(call(1.0 / math.sqrt(d)))(q, k, v)) == str(
+        jax.make_jaxpr(call(None))(q, k, v)
     )
-    out = fa.attention(q, k, v, causal=True, scale=scale)
-    assert not called
-    assert out.shape == (b, L, h, v.shape[-1])
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (scale or 1 / math.sqrt(dk))
-    s = jnp.where(jnp.tril(jnp.ones((L, L), bool))[None, None], s, -jnp.inf)
-    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
-    if forced:  # the same call with one width and no scale still takes them
-        fa.attention(q, k, k, causal=True)
-        assert len(called) == 1
+
+
+def test_the_chip_s_check_takes_a_value_width_and_a_scale():
+    """`check_against_reference` as chip_smoke.py calls it for the
+    routed cell's shape, cut down for the interpreter."""
+    from elasticdl_tpu.ops.flash_attention import (
+        REFERENCE_TOLERANCE,
+        check_against_reference,
+    )
+
+    errors = check_against_reference(
+        (1, 2 * BLOCK, 2, 192), interpret=True, v_width=128,
+        scale=0.5 * 192**-0.5,
+    )
+    assert set(errors) == {"o", "dq", "dk", "dv"}
+    assert max(errors.values()) <= REFERENCE_TOLERANCE, errors
 
 
 @pytest.mark.parametrize("case, q_shape, v_width, scale, flag, kernels", [
@@ -235,8 +341,12 @@ def test_values_of_another_width_or_a_scale_never_reach_the_kernels(
     ("unmeasured-1536-stays", (1, 1536, 8, 64), 64, None, None, False),
     ("forced-on-at-1024", (2, 1024, 12, 64), 64, None, "1", True),
     ("forced-off-at-2048", (2, 2048, 12, 64), 64, None, "0", False),
-    ("latent-192-over-128", (4, 2048, 16, 192), 128, None, None, False),
-    ("own-scale", (2, 2048, 16, 128), 128, 0.05, None, False),
+    ("latent-192-over-128", (4, 2048, 16, 192), 128, None, None, True),
+    ("own-scale", (2, 2048, 16, 128), 128, 0.05, None, True),
+    ("latent-own-scale", (4, 2048, 16, 192), 128, 0.1147, None, True),
+    ("hybrid-latent", (2, 2048, 32, 192), 128, None, None, True),
+    ("latent-xla-wins-at-1024", (4, 1024, 16, 192), 128, 0.1147, None, False),
+    ("latent-forced-off", (4, 2048, 16, 192), 128, 0.1147, "0", False),
     ("no-tile-divides-96", (2, 96, 12, 64), 64, None, "1", False),
     ("no-tile-divides-2080", (1, 2080, 8, 64), 64, None, None, False),
 ])
@@ -246,7 +356,7 @@ def test_the_rule_reads_shapes_and_engages_where_the_chip_said(
     """On a TPU `attention` hands a call to the kernels from what it
     sees in its arguments: a length the ladder divides, at least
     FLASH_MIN_LENGTH (measured: XLA wins at 1024, the kernels at 2048,
-    at both head widths), one head width and no scale of the caller's.
+    at both head widths), whatever the values' width and the scale.
     Traced only (`eval_shape`): nothing of these sizes is computed."""
     from elasticdl_tpu.ops import flash_attention as fa
 
@@ -423,7 +533,8 @@ def test_the_dispatcher_hands_the_window_to_whichever_path_takes_the_call(
     seen = {}
     monkeypatch.setattr(
         fa, "flash_attention",
-        lambda q, k, v, causal, window=None: seen.update(kernel=window) or v,
+        lambda q, k, v, causal, window=None, scale=None:
+            seen.update(kernel=window) or v,
     )
     monkeypatch.setattr(
         fa, "reference_attention",

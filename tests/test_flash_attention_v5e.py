@@ -51,20 +51,32 @@ def no_compile_cache():
     (1, 8192, 64, 128, 512),  # its sliding layers: banded, 512 x 512 tiles
     (1, 2048, 4, 128, 300),  # a window no tile divides
     (1, 8192, 8, 128, 513),  # one key more than the tile
-], ids=lambda s: "x".join(map(str, s[:4])) + "".join(f"-w{w}" for w in s[4:]))
+    # latent attention, values 128 wide in place beside keys of 192
+    # folded, under a scale of the model's own:
+    (4, 2048, 16, 192, None, 128),  # deepseek-v2-lite
+    (2, 2048, 32, 192, None, 128),  # kimi-linear-48b-a3b
+    (1, 2048, 4, 192, 512, 128),  # and under a band, which no cell runs
+], ids=lambda s: "x".join(map(str, s[:4])) + "".join(
+    f"-{n}{x}" for n, x in zip("wv", s[4:]) if x
+))
 def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
-    shape, window = shape[:4], (*shape[4:], None)[0]
+    shape, (window, v_width) = shape[:4], (*shape[4:], None, None)[:2]
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(
+        (*shape[:3], v_width or shape[3]), jnp.bfloat16, sharding=one_chip
+    )
     tiles = fa.pick_tiles(shape[1], window)
     band = window and fa._Band(shape[1], *tiles, window)
+    # the routed cell's where the widths differ, else 1/sqrt(D)
+    scale = 0.11472 if v_width else shape[3] ** -0.5
 
     def loss(q, k, v, w):
         with jax.named_scope("attention"):
-            o = fa._flash_attention(q, k, v, True, False, tiles, band)
+            o = fa._flash_attention(q, k, v, True, False, tiles, band, scale)
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
 
     compiled = (
-        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x, x).compile()
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, v, v).compile()
     )
     # forward, dq, dk+dv, each under the scope it was traced in
     assert hlo_scopes.kernels(compiled.as_text()) == {"attention": 3}
