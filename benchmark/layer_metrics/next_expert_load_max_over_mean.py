@@ -1,0 +1,15 @@
+"""How unevenly the softmax router loaded the eight experts held
+here: the fullest expert's tokens over the held experts' mean, averaged
+over the four expert layers and over the window's
+`worker.window_stats` spans. 1 is even, 8 is everything on one expert.
+The configuration's balance term (`config.json`, `assumed`: 0.001 x the
+ten choices) evens the router over all 512 experts; this, `held_share`
+and `route_rows` are where a drift to the held experts would show
+(`_moe.py`'s reading as it is)."""
+
+from benchmark.layer_metrics import _moe
+
+
+def read(run):
+    loads = _moe.expert_tokens(run, __file__)
+    return None if loads is None else _moe.load_max_over_mean(loads)

@@ -120,8 +120,8 @@ class TransformerConfig:
     # projected
     mla_rope: bool = True
     # the mixer of every layer of the stack, dense layers first:
-    # "mha", "swa", "mla", "kda" or "conv"; None = `attention` in
-    # every layer.
+    # "mha", "swa", "mla", "kda", "gdn" or "conv"; None = `attention`
+    # in every layer.
     # Layers that follow each other with one mixer and one kind of MLP
     # are one scanned run
     layer_types: Optional[Tuple[str, ...]] = None
@@ -168,6 +168,24 @@ class TransformerConfig:
     swa_heads: int = 0
     swa_window: int = 0
     swa_rope_base: float = 10000.0
+    # "gdn": the gated delta rule under ONE decay a head (Gated
+    # DeltaNet): `gdn_key_heads` heads of q and k under `gdn_value_heads`
+    # of v (a multiple: value head j reads key head j // group), all of
+    # `gdn_head_dim`; one causal depthwise convolution of `gdn_conv`
+    # taps over q | k | v; the decay and the write strength from one
+    # [d, 2 x value heads] projection; the output normed per head and
+    # gated by SiLU of a full-width z; chunks of `kda_chunk`
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_head_dim: int = 0
+    gdn_conv: int = 4
+    # "mha": a gate per output CHANNEL, o * sigmoid(gate), the gate the
+    # second half of a query projection twice as wide (`attn_gate` is
+    # one number a head from a leaf of its own)
+    attn_channel_gate: bool = False
+    # the shared expert behind a gate of its own, one number a token:
+    # y = routed + sigmoid(x . sgate) S(x)
+    shared_expert_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -290,7 +308,9 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
 
 # a run's leaves that the forward pass reads as stored (float32) and not
 # as cast to the compute dtype
-_FLOAT32_LEAVES = ("router", "router_bias", "dt_bias", "q_norm", "k_norm")
+_FLOAT32_LEAVES = (
+    "router", "router_bias", "dt_bias", "q_norm", "k_norm", "out_norm",
+)
 # how a routed stack's stats, one a layer, become one number a step; a
 # stat without a rule here is a KeyError when the program is traced
 _OVER_LAYERS = {
@@ -302,6 +322,9 @@ _OVER_LAYERS = {
     "kda_log_decay_min": jnp.min,
     "shortconv_gate_absmax": jnp.max,
     "attn_gate_mean": jnp.mean,
+    "gdn_log_decay_min": jnp.min,
+    "gdn_beta_mean": jnp.mean,
+    "shared_gate_mean": jnp.mean,
 }
 
 
@@ -328,7 +351,7 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
     L, d = cfg.n_layers, cfg.d_model
     if (
         cfg.moe_top_k or cfg.n_dense_layers or cfg.layer_types
-        or cfg.attention in ("mla", "kda")
+        or cfg.attention in ("mla", "kda", "gdn")
     ):
         return _init_routed_params(norm, rng, cfg)
     layers = _init_mha(norm, cfg, L)
@@ -366,12 +389,13 @@ def _init_mha(norm, cfg: TransformerConfig, L: int, mixer="mha") -> Dict:
     """A multi-head attention layer's leaves but its MLP's, for L
     stacked layers of `mixer` ("mha" or "swa": its own head count):
     `n_kv_heads` key-value heads, under `qk_norm` the two norms'
-    weights, under `attn_gate` the gate's."""
+    weights, under `attn_gate` the gate's; under `attn_channel_gate`
+    `wq` is twice as wide, the queries' columns then the gate's."""
     d, hd = cfg.d_model, cfg.head_dim
     heads = cfg.attention_shape(mixer).heads
     tree = {
         "ln1": np.ones((L, d), np.float32),
-        "wq": norm(L, d, heads * hd),
+        "wq": norm(L, d, (1 + cfg.attn_channel_gate) * heads * hd),
         "wk": norm(L, d, cfg.kv_heads * hd),
         "wv": norm(L, d, cfg.kv_heads * hd),
         "wo": norm(L, heads * hd, d),
@@ -396,15 +420,16 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     d = cfg.d_model
     if not (
         cfg.moe_top_k and cfg.mlp == "swiglu"
-        and set(cfg.mixers) <= {"mla", "kda", "conv", "mha", "swa"}
+        and set(cfg.mixers) <= {"mla", "kda", "gdn", "conv", "mha", "swa"}
         and len(cfg.mixers) == cfg.n_layers
     ):
         raise NotImplementedError(
             "the routed stack is built with latent attention, delta-rule "
             "attention or short convolutions beside grouped-query "
             "attention, top-k experts and SwiGLU MLPs together (attention "
-            "'mla' or 'kda', or layer_types of 'mla', 'kda', 'conv', 'mha' "
-            "and 'swa', one a layer; moe_top_k > 0, mlp='swiglu')"
+            "'mla', 'kda' or 'gdn', or layer_types of 'mla', 'kda', 'gdn', "
+            "'conv', 'mha' and 'swa', one a layer; moe_top_k > 0, "
+            "mlp='swiglu')"
         )
 
     def mla(L):
@@ -446,6 +471,23 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         )
         return block
 
+    def gdn(L):
+        kh, vh, hd = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
+        qkv = (2 * kh + vh) * hd
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            # the columns: q | k | v | z
+            "wqkvz": norm(L, d, qkv + vh * hd),
+            # a tap's weights; the taps sum like a fan-in
+            "conv": norm(L, cfg.gdn_conv, qkv, scale=1.0 / math.sqrt(cfg.gdn_conv)),
+            # [2 x value heads, d], the write strength's rows then the
+            # decay's: not [d, 64] (`wbeta`)
+            "wba": norm(L, 2 * vh, d, scale=1.0 / math.sqrt(d)),
+            "out_norm": np.ones((L, hd), np.float32),
+            "wo": norm(L, vh * hd, d),
+            "ln2": np.ones((L, d), np.float32),
+        }
+
     def conv(L):
         taps = cfg.conv_taps
         return {
@@ -458,7 +500,7 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         }
 
     mixer_trees = {
-        "mla": mla, "kda": kda, "conv": conv,
+        "mla": mla, "kda": kda, "gdn": gdn, "conv": conv,
         "mha": lambda L: _init_mha(norm, cfg, L),
         "swa": lambda L: _init_mha(norm, cfg, L, "swa"),
     }
@@ -477,6 +519,8 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
                 tree.update(
                     sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d)
                 )
+                if cfg.shared_expert_gate:  # [1, d], not [d, 1] (`wbeta`)
+                    tree["sgate"] = norm(L, 1, d, scale=1.0 / math.sqrt(d))
             if cfg.moe_score == "sigmoid":
                 tree["router_bias"] = np.zeros((L, cfg.n_experts), np.float32)
         else:
@@ -514,6 +558,16 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         params["kda_a_log"] = np.log(
             rng.uniform(1.0, 16.0, (n_kda * cfg.kda_heads,))
         ).astype(np.float32)
+    n_gdn = cfg.mixers.count("gdn")
+    if n_gdn:
+        # one decay a VALUE HEAD: its rate exp(a_log), uniform in
+        # (0, 16), and its step's bias, 1. ONE flat leaf for all GDN
+        # layers, [a_log | dt_bias] each in stack order [GDN layers x
+        # value heads], as `kda_a_log` and for its reason
+        n = n_gdn * cfg.gdn_value_heads
+        params["gdn_decay"] = np.concatenate([
+            np.log(rng.uniform(1e-3, 16.0, (n,))), np.ones((n,)),
+        ]).astype(np.float32)
     return params
 
 
@@ -559,16 +613,20 @@ def _require_mesh_support(cfg: TransformerConfig):
         or cfg.layer_types or cfg.n_kv_heads or cfg.qk_norm
         or cfg.tie_embeddings or cfg.head_width or cfg.attn_gate
         or cfg.rope_dim or cfg.rope_factor != 1.0 or cfg.swa_heads
-        or cfg.swa_window
+        or cfg.swa_window or cfg.gdn_key_heads or cfg.gdn_value_heads
+        or cfg.gdn_head_dim or cfg.attn_channel_gate
+        or cfg.shared_expert_gate
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
             "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
-            "attention='mla' and 'kda', layer_types, n_dense_layers, "
-            "moe_top_k, n_kv_heads, qk_norm, tie_embeddings, head_width, "
-            "attn_gate, rope_dim, rope_factor and the windowed mixer "
-            "(swa_heads, swa_window) exist on the unsharded path "
-            "(plain_forward) only"
+            "attention='mla', 'kda' and 'gdn', layer_types, "
+            "n_dense_layers, moe_top_k, n_kv_heads, qk_norm, "
+            "tie_embeddings, head_width, attn_gate, attn_channel_gate, "
+            "shared_expert_gate, rope_dim, rope_factor, the windowed "
+            "mixer (swa_heads, swa_window) and the scalar-decay delta "
+            "rule (gdn_key_heads, gdn_value_heads, gdn_head_dim) exist on "
+            "the unsharded path (plain_forward) only"
         )
 
 
@@ -856,6 +914,12 @@ def _head_gate(lp: Dict, x: jnp.ndarray) -> jnp.ndarray:
     ))
 
 
+def _channel_gate(projected: jnp.ndarray) -> jnp.ndarray:
+    """sigmoid of the gate's columns [B, L, heads x head_dim],
+    float32."""
+    return jax.nn.sigmoid(projected.astype(jnp.float32))
+
+
 def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
             mixer: str):
     """Multi-head attention on the normed x [B, L, d] -> ([B, L, d],
@@ -864,7 +928,10 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
     `kv_heads` keys and values (the dispatcher widens them), under
     `qk_norm` queries and keys normed per head, then rotated; under
     `attn_gate` each head's output times sigmoid(x . wgate_h), the
-    sigmoid in float32 (stat `attn_gate_mean`, its mean)."""
+    sigmoid in float32 (stat `attn_gate_mean`, its mean); under
+    `attn_channel_gate` every output channel times the sigmoid of its
+    own gate, the second half of `wq`'s columns (scope `gate`; the same
+    stat, the mean over channels)."""
     from elasticdl_tpu.ops.flash_attention import attention
 
     b, l, _ = x.shape
@@ -872,7 +939,10 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
     inner = shape.scope is not None  # `rope` and `gate` beside `swa`
     stats = {}
     with _scope(shape.scope):
-        q = (x @ lp["wq"]).reshape(b, l, shape.heads, cfg.head_dim)
+        q = x @ lp["wq"]
+        if cfg.attn_channel_gate:
+            q, channel_gate = jnp.split(q, 2, axis=-1)
+        q = q.reshape(b, l, shape.heads, cfg.head_dim)
         k = (x @ lp["wk"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
         v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
         if cfg.qk_norm:
@@ -892,6 +962,11 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
             with _scope(inner and "gate"):
                 gate = _head_gate(lp, x)
                 out = out * gate[..., None].astype(out.dtype)
+                stats["attn_gate_mean"] = jnp.mean(gate)
+        if cfg.attn_channel_gate:
+            with jax.named_scope("gate"):
+                gate = _channel_gate(channel_gate)
+                out = out * gate.reshape(out.shape).astype(out.dtype)
                 stats["attn_gate_mean"] = jnp.mean(gate)
         return out.reshape(b, l, -1) @ lp["wo"], stats
 
@@ -962,6 +1037,77 @@ def _kda(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
         return (o * gate).reshape(b, l, heads * hd) @ lp["wo"], log_decay_min
 
 
+def _unit_length(y: jnp.ndarray) -> jnp.ndarray:
+    """Every head's vector [..., D] over its length, float32."""
+    return y * lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+
+
+def _gdn_out_gate(z: jnp.ndarray) -> jnp.ndarray:
+    """SiLU of the output gate's columns, float32."""
+    return jax.nn.silu(z.astype(jnp.float32))
+
+
+def _gdn_qkv(cfg: TransformerConfig, projected: jnp.ndarray, taps: jnp.ndarray):
+    """The projected q | k | v [B, L, (2 kh + vh) hd] -> (q, k
+    [B, L, kh, hd], v [B, L, vh, hd]) float32: the convolution, SiLU,
+    and q and k scaled to unit length per head (q by d^-1/2 more)."""
+    b, l, _ = projected.shape
+    kh, vh, hd = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
+    qkv = jax.nn.silu(_causal_conv(projected, taps)).astype(jnp.float32)
+    q, k, v = jnp.split(qkv, [kh * hd, 2 * kh * hd], axis=-1)
+    q, k = (y.reshape(b, l, kh, hd) for y in (q, k))
+    return (
+        _unit_length(q) * hd**-0.5, _unit_length(k), v.reshape(b, l, vh, hd)
+    )
+
+
+def _gdn_out(cfg: TransformerConfig, o: jnp.ndarray, z: jnp.ndarray, weight):
+    """The scan's output [B, L, vh, hd] float32 normed per head and
+    gated by SiLU(z), in float32 -> [B, L, vh x hd] in z's dtype."""
+    o = rms_norm(o, weight.astype(jnp.float32), cfg.norm_eps)
+    o = o * _gdn_out_gate(z).reshape(o.shape)
+    return o.astype(z.dtype).reshape(z.shape)
+
+
+def _gdn(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
+    """Gated DeltaNet on the normed x [B, L, d] -> ([B, L, d], its
+    stats). (q, k, v, z) = x W_qkvz; q | k | v pass ONE causal depthwise
+    convolution and SiLU; per head q and k are scaled to unit length (q
+    by d^-1/2 more); the write strength is sigmoid(b) and the log-decay
+    -exp(a_log) softplus(a + dt_bias), ONE number a value head and
+    token, (b, a) = x W_ba; value head j reads q and k of key head
+    j // group; the recurrence is `ops/kda.py`'s under the scalar
+    decay; its output is RMS-normed per head, gated by SiLU(z) and
+    projected. `a_log`, `dt_bias` and `out_norm` are the float32
+    leaves; decay, lengths, the recurrence, the norm and its gate are
+    float32 whatever `cfg.dtype` is. The two elementwise stages round
+    the scan are recomputed in the backward pass from the projection
+    and from the scan's output (`jax.checkpoint`): at 8192 tokens their
+    float32 intermediates, 8192 and 4096 wide, were 0.9 GB of a layer's
+    backward pass (the v5e compiler's rehearsal, PR 52)."""
+    from elasticdl_tpu.ops.kda import kda_chunked
+
+    kh, vh, hd = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("conv"):
+        qkv, z = jnp.split(x @ lp["wqkvz"], [(2 * kh + vh) * hd], axis=-1)
+        q, k, v = jax.checkpoint(partial(_gdn_qkv, cfg))(qkv, lp["conv"])
+    with jax.named_scope("gates"):
+        ba = (x @ lp["wba"].T).astype(f32)  # [B, L, 2 vh]
+        beta = jax.nn.sigmoid(ba[..., :vh])
+        g = -jnp.exp(lp["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., vh:] + lp["dt_bias"].astype(f32)
+        )
+    with jax.named_scope("scan"):
+        o, log_decay_min = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+    with jax.named_scope("out"):
+        o = jax.checkpoint(partial(_gdn_out, cfg))(o, z, lp["out_norm"])
+        return o @ lp["wo"], {
+            "gdn_log_decay_min": log_decay_min,
+            "gdn_beta_mean": jnp.mean(beta),
+        }
+
+
 def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     """Vectorized unsharded forward — the same math as the sharded path
     restricted to a 1-device mesh, without the machinery: `lax.scan`
@@ -1027,6 +1173,8 @@ def plain_forward_stats(
         if mixer == "kda":
             out, log_decay_min = _kda(cfg, lp, x)
             return out, {"kda_log_decay_min": log_decay_min}
+        if mixer == "gdn":
+            return _gdn(cfg, lp, x)
         if mixer == "conv":
             out, gate_absmax = _conv(cfg, lp, x)
             return out, {"shortconv_gate_absmax": gate_absmax}
@@ -1058,6 +1206,7 @@ def plain_forward_stats(
                         score=cfg.moe_score, bias=lp.get("router_bias"),
                         renormalize=cfg.moe_renormalize,
                         balance=bool(cfg.aux_weight),
+                        shared_gate=lp.get("sgate"),
                     )
                     stats = {**stats, **routing}
                 elif experts:
@@ -1108,6 +1257,17 @@ def plain_forward_stats(
         for (mixer, _experts, layers), tree in zip(runs, trees):
             if mixer == "kda":
                 tree["a_log"] = a_log[seen:seen + layers]
+                seen += layers
+
+    if "gdn" in cfg.mixers:  # as `kda_a_log`: [a_log | dt_bias]
+        decay = lax.optimization_barrier(stored["gdn_decay"]).reshape(
+            2, -1, cfg.gdn_value_heads
+        )
+        seen = 0
+        for (mixer, _experts, layers), tree in zip(runs, trees):
+            if mixer == "gdn":
+                tree["a_log"] = decay[0, seen:seen + layers]
+                tree["dt_bias"] = decay[1, seen:seen + layers]
                 seen += layers
 
     def stack(carry):

@@ -72,8 +72,13 @@ class TransformerLM:
                 stats["kda_log_decay_min"] = np.zeros((), np.float32)
             if "conv" in self.cfg.mixers:
                 stats["shortconv_gate_absmax"] = np.zeros((), np.float32)
-            if self.cfg.attn_gate:
+            if "gdn" in self.cfg.mixers:
+                stats["gdn_log_decay_min"] = np.zeros((), np.float32)
+                stats["gdn_beta_mean"] = np.zeros((), np.float32)
+            if self.cfg.attn_gate or self.cfg.attn_channel_gate:
                 stats["attn_gate_mean"] = np.zeros((), np.float32)
+            if self.cfg.shared_expert_gate:
+                stats["shared_gate_mean"] = np.zeros((), np.float32)
             return {"params": params, WINDOW_STATS: stats}
         return {"params": params}
 

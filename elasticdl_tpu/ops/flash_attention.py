@@ -657,6 +657,17 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 # recomputes runs twice, and is what `_Layout` does with any width the
 # lanes do not divide: kept. 192 | 128 costs 1.49 x the equal-width
 # call a head (14 passes of the multiplier a tile pair for 9).
+# Heads of 256, read where they lie (2026-10-02, the same chip kind and
+# versions, `scripts/swa_kernel_sweep.py --cases wide`), causal at
+# (1, 8192, 16, 256), ms a call, forward + backward (forward alone), by
+# q x k edge: 1024 x 1024 19.24 (5.06), 1024 x 512 19.90 (5.35),
+# 2048 x 1024 20.17 (5.26), 512 x 1024 20.47 (5.53), 1024 x 2048 20.80
+# (5.60), 512 x 512 21.15 (5.88), 256 x 256 33.31 (11.51); the same
+# columns as 32 heads of 128 at 1024 x 1024: 21.15 (5.80). A contraction
+# of 256 is two passes of the 128-wide multiplier and runs 9 % ahead of
+# twice the heads at 128; the ladder's first edge wins, so `pick_tiles`
+# reads no width. Against the float32 reference there: o 0.21 %, dq
+# 0.24 %, dk 0.30 %, dv 0.28 % of the largest entry.
 FLASH_MIN_LENGTH = 2048
 
 
@@ -668,8 +679,9 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
 
     On a TPU the Pallas kernels take a call whose sequence the tile
     ladder divides and that is at least FLASH_MIN_LENGTH long, at
-    every head width measured (the rule reads nothing but the call's
-    own shapes); XLA's attention takes the rest.
+    every head width measured (64, 128, 192 | 128 and, since 2026-10-02,
+    256 at 8192 tokens: FLASH_MIN_LENGTH's table; the rule reads nothing
+    but the call's own shapes); XLA's attention takes the rest.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
     EDL_TPU_FLASH=0 forces them off. The kernels hold their scores in
     float32 where XLA's path rounds them to the inputs' dtype

@@ -1,8 +1,11 @@
-"""Kimi Delta Attention's recurrence, computed in chunks.
+"""The gated delta rule's recurrence, computed in chunks, under either
+decay: one number a KEY CHANNEL (Kimi Delta Attention) or one number a
+HEAD (Gated DeltaNet).
 
 Per head, with a state S [dk, dv] that starts at zero, a log-decay
-g_t <= 0 per key channel, alpha_t = exp(g_t), and a write strength
-beta_t:
+g_t <= 0 per key channel (a head's one number is the same on every
+channel: Diag(alpha) = exp(g) I), alpha_t = exp(g_t), and a write
+strength beta_t:
 
     S' = Diag(alpha_t) S_{t-1}
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
@@ -59,11 +62,29 @@ products, no second substitution). The rest is jax's; the pass over
 the chunks recomputes a chunk's four products from the state the chunk
 started from, which is all it keeps.
 
-On a TPU, at head widths the lanes divide, `intra` runs as the Pallas
-kernels of `ops/kda_kernels.py` (`takes_kernels`: the same mathematics,
-a chunk's intermediates never leaving the chip); `intra_stage` below is
-the path of every other call and the kernels' oracle. The pass over the
-chunks is this file's `lax.scan` on either path.
+On a TPU, at head widths the lanes divide, the per-channel `intra`
+runs as the Pallas kernels of `ops/kda_kernels.py` (`takes_kernels`:
+the same mathematics, a chunk's intermediates never leaving the chip);
+`intra_stage` below is the path of every other per-channel call and the
+kernels' oracle.
+
+Which stage serves which decay is read from the shape of `g` alone
+(`kda_chunked`): [B, L, H, dk] is the per-channel decay and takes the
+stages above; [B, L, H] (or a trailing 1) is one number a head. On a
+TPU at those widths that is the same kernels under `scalar=True`
+(`kda_kernels.scalar_intra_stage`): there the factor exp(G_r - G_i)
+does not depend on the channel, so it leaves the sums, A = tril(K K^T *
+E, -1) and B = tril(Q K^T * E) with E_ri = exp(G_r - G_i), two
+[chunk, dk] x [dk, chunk] products on the multiplier and one
+[chunk, chunk] exponential a head, masked before it is taken; no
+[sub, sub, d] intermediate and no sub-blocks of pairs, and a key head's
+rows are read where they lie by every value head that shares them
+(value head j reads q and k of key head j // group). Everywhere else
+one decay a head is `intra_stage` told that decay on every channel,
+q and k widened to the value heads: right, slower (on the chip the
+scalar form in plain jax was slower still: PERF.md, PR 52), and the
+scalar kernels' oracle. The pass over the chunks is this file's
+`lax.scan` (`chunk_step`) under either decay.
 """
 
 from __future__ import annotations
@@ -290,18 +311,29 @@ def takes_kernels(dk: int, dv: int, chunk: int, sub: int, backend=None) -> bool:
 
 def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
                 interpret: bool = False):
-    """q, k [B, L, H, dk], v [B, L, H, dv], g [B, L, H, dk] (log-decay,
-    <= 0), beta [B, L, H] -> (o [B, L, H, dv] float32, the most negative
-    cumulative log-decay inside a chunk, a float32 scalar). L need not
-    be a multiple of `chunk`: the tail is padded with tokens that write
-    nothing (k = 0, beta = 0) and do not decay (g = 0). `interpret=True`
-    runs the `intra` stage as the kernels in the Pallas interpreter and
-    is for tests only (no model path passes it)."""
-    B, L, H, dk = q.shape
-    dv = v.shape[-1]
+    """q, k [B, L, Hk, dk], v [B, L, H, dv], g (log-decay, <= 0),
+    beta [B, L, H] -> (o [B, L, H, dv] float32, the most negative
+    cumulative log-decay inside a chunk, a float32 scalar). The decay's
+    kind is read from `g`'s shape: [B, L, H, dk] is one a key channel
+    (Hk = H); [B, L, H] or [B, L, H, 1] is one a head, and then H may be
+    a multiple of Hk (value head j reads key head j // (H / Hk)). L need
+    not be a multiple of `chunk`: the tail is padded with tokens that
+    write nothing (k = 0, beta = 0) and do not decay (g = 0).
+    `interpret=True` runs the `intra` stage as the kernels in the Pallas
+    interpreter and is for tests only (no model path passes it)."""
+    B, L, Hk, dk = q.shape
+    H, dv = v.shape[2:]
     pad = -L % chunk
     f32 = jnp.float32
     sub = min(sub, chunk)
+    scalar = g.ndim == 3 or g.shape[-1] == 1
+    if scalar:
+        g = g.reshape(B, L, H, 1)
+    elif Hk != H:
+        raise ValueError(
+            f"{Hk} key heads under {H} value heads need one decay "
+            f"a head, g [B, L, H]; got g {g.shape}"
+        )
 
     def padded(x):  # [B, L, H, ...] -> [B, L + pad, H, ...] float32
         x = x.astype(f32)
@@ -315,7 +347,15 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
 
     q, k, v, g, beta = (padded(x) for x in (q, k, v, g, beta))
     with jax.named_scope("intra"):
-        if interpret or takes_kernels(dk, dv, chunk, sub):
+        kernels = interpret or takes_kernels(dk, dv, chunk, sub)
+        if scalar and kernels:
+            from elasticdl_tpu.ops import kda_kernels
+
+            U, Wt, q_in, Bqk, k_out, total = kda_kernels.scalar_intra_stage(
+                q, k, v, g[..., 0], beta, chunk, sub, interpret
+            )
+            k_out = jnp.swapaxes(k_out, -1, -2)
+        elif kernels:
             from elasticdl_tpu.ops import kda_kernels
 
             U, Wt, q_in, Bqk, k_out, total = kda_kernels.intra_stage(
@@ -323,6 +363,9 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
             )
             k_out = jnp.swapaxes(k_out, -1, -2)  # a layout, not a copy
         else:
+            if scalar:  # the per-channel stage, told the one decay
+                q, k = (jnp.repeat(x, H // Hk, axis=2) for x in (q, k))
+                g = jnp.broadcast_to(g, g.shape[:-1] + (dk,))
             U, Wt, q_in, Bqk, k_out, total = intra_stage(
                 chunks(q), chunks(k), chunks(v), chunks(g),
                 chunks(beta)[..., None], sub,
@@ -343,9 +386,16 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
 
 def kda_recurrent(q, k, v, g, beta):
     """The recurrence itself, a token at a time (tests hold
-    `kda_chunked` to it): same arguments, -> o [B, L, H, dv] float32."""
+    `kda_chunked` to it): same arguments, -> o [B, L, H, dv] float32.
+    g [B, L, H] is one decay a head; fewer key heads than value heads
+    are widened in front (value head j reads key head j // group)."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if g.ndim == 3:
+        g = g[..., None]
+    group = v.shape[2] // q.shape[2]
+    if group > 1:
+        q, k = (jnp.repeat(x, group, axis=2) for x in (q, k))
 
     def step(S, xs):  # S [B, H, dk, dv]
         q_t, k_t, v_t, g_t, b_t = xs

@@ -46,6 +46,16 @@ multiples of 128: `flash_attention._Layout`'s rule). The outputs are
 written as the `lax.scan` over the chunks reads them, [n, B, H, ...].
 `interpret=True` runs the kernels in the Pallas interpreter: the tests'
 entry, on the CPU.
+
+Under ONE decay a head (Gated DeltaNet; `scalar_intra_stage`, at the
+end of the file) the same two kernels run with `scalar=True`: the
+factor exp(G_r - G_i) leaves the sums over the channels, so a chunk's A
+and B are two products on the multiplier times one [C, C] matrix of
+exponentials (`_scalar_pairs`) where `_channel_pairs` walks the diagonal
+blocks a column at a time on the vector unit, and their transposes are
+products too; the inverse, the weave and the rest of a tile are shared.
+g goes in and dg comes out as rows along the lanes, as beta does, and
+fewer key heads than value heads are read where they lie.
 """
 
 from __future__ import annotations
@@ -113,14 +123,13 @@ def _block_decay(Gb, j):
     return jnp.exp(jnp.where(rows >= j, Gb - Gb[:, j:j + 1, :], -jnp.inf))
 
 
-def _tile(q, k, g, beta, sub):
-    """What both passes form of a chunk (a generator: `_weave`): q, k,
-    g [C, d], beta [C, 1] -> G [C, d], A, B [C, C], Y and D [C, C] with
-    (I + beta A)^-1 = Y D, and the factors the backward pass transposes
-    (`within`, `right` of each block of rows below the first)."""
+def _channel_pairs(q, k, G, sub):
+    """A, B [C, C] under a decay a key channel (a generator: `_weave`),
+    with the columns of A's diagonal blocks, its blocks below them and
+    the factors the backward pass transposes (`within`, `right` of each
+    block of rows below the first)."""
     C, d = k.shape
     n = C // sub
-    G = _cumsum(g)  # the cumulative log-decay
     qb, kb, Gb = (_blocks(x, n, sub) for x in (q, k, G))
     rows3 = _iota((1, sub, 1), 1)
     lanes = _iota((n, sub, C), 2)
@@ -160,6 +169,56 @@ def _tile(q, k, g, beta, sub):
         yield
     A = jnp.concatenate(rows_a, axis=0)
     B = jnp.concatenate(rows_b, axis=0)
+    return A, B, columns, below_a, factors
+
+
+def _scalar_pairs(q, k, G, sub):
+    """`_channel_pairs` under ONE decay a head (G's lanes all alike):
+    exp(G_r - G_i) leaves the sums over the channels, so A and B are two
+    products on the multiplier times one [C, C] matrix of exponentials,
+    every exponent a difference <= 0 masked before it is taken. The
+    factors the backward pass transposes are that matrix and the
+    products."""
+    C = k.shape[0]
+    n = C // sub
+    Gc = jnp.max(G, axis=-1, keepdims=True)  # [C, 1]: any lane's
+    rows, cols = _iota((C, C), 0), _iota((C, C), 1)
+    E = jnp.exp(jnp.where(rows >= cols, Gc - _row(Gc), -jnp.inf))
+    pairs = _dot(jnp.concatenate([k, q], axis=0), k, _NT)  # [2 C, C]
+    yield
+    A = jnp.where(rows > cols, pairs[:C] * E, 0.0)
+    B = pairs[C:] * E
+    A3 = _blocks(A, n, sub)
+    lanes = _iota((n, sub, C), 2)
+    own = _iota((n, sub, C), 0) * sub
+    columns = [
+        jnp.sum(jnp.where(lanes == own + j, A3, 0.0), axis=-1, keepdims=True)
+        for j in range(sub)
+    ]
+    before = _iota((sub, C), 1)
+    below_a = [None] + [
+        jnp.where(before < m * sub, A[m * sub:(m + 1) * sub], 0.0)
+        for m in range(1, n)
+    ]
+    yield
+    return A, B, columns, below_a, (E, pairs)
+
+
+def _tile(q, k, g, beta, sub, scalar=False):
+    """What both passes form of a chunk (a generator: `_weave`): q, k,
+    g [C, d], beta [C, 1] -> G [C, d], A, B [C, C], Y and D [C, C] with
+    (I + beta A)^-1 = Y D, and the factors the backward pass transposes.
+    `scalar`: g holds one decay a head on every lane, and the pairs
+    come from `_scalar_pairs`."""
+    C, d = k.shape
+    n = C // sub
+    G = _cumsum(g)  # the cumulative log-decay
+    rows3 = _iota((1, sub, 1), 1)
+    lanes = _iota((n, sub, C), 2)
+    own = _iota((n, sub, C), 0) * sub  # a block's first column
+    A, B, columns, below_a, factors = yield from (
+        _scalar_pairs if scalar else _channel_pairs
+    )(q, k, G, sub)
     # (I + N)^-1, N = beta A, by substitution. D = every diagonal
     # block's inverse on the diagonal, a column at a time (D[r] -= N[r,
     # j] D[j] for r > j, all blocks at once, formed where they lie);
@@ -210,10 +269,10 @@ def _weave(tiles):
     return results
 
 
-def _forward_tile(q, k, v, g, beta, sub):
+def _forward_tile(q, k, v, g, beta, sub, scalar=False):
     """-> U, Wt, q_in, Bqk, k_out^T [C, dk], total [1, dk]."""
     C = k.shape[0]
-    G, _A, B, Y, D, _ = yield from _tile(q, k, g, beta, sub)
+    G, _A, B, Y, D, _ = yield from _tile(q, k, g, beta, sub, scalar)
     decay = jnp.exp(G)
     total = G[C - 1:C, :]
     dv = v.shape[-1]
@@ -225,13 +284,15 @@ def _forward_tile(q, k, v, g, beta, sub):
     )
 
 
-def _backward_tile(q, k, v, g, beta, dU, dWt, dq_in, dB, dko, dtotal, sub):
+def _backward_tile(q, k, v, g, beta, dU, dWt, dq_in, dB, dko, dtotal, sub,
+                   scalar=False):
     """The transposes of `_forward_tile`, the tile recomputed: dko is
-    the cotangent of k_out^T [C, dk] -> dq, dk, dv, dg, dbeta [C, 1]."""
+    the cotangent of k_out^T [C, dk] -> dq, dk, dv, dg, dbeta [C, 1];
+    under `scalar` dg is [C, 1] too, the lanes' sum."""
     C, d = k.shape
     n = C // sub
     dv_width = v.shape[-1]
-    G, A, _B, Y, D, factors = yield from _tile(q, k, g, beta, sub)
+    G, A, _B, Y, D, factors = yield from _tile(q, k, g, beta, sub, scalar)
     T = _dot(Y, D, _NN)
     yield
     decay = jnp.exp(G)
@@ -267,6 +328,24 @@ def _backward_tile(q, k, v, g, beta, dU, dWt, dq_in, dB, dko, dtotal, sub):
     dtotal = dtotal + jnp.sum(through_ex, axis=0, keepdims=True)
     tokens = _iota((C, 1), 0)
     dG = dG + jnp.where(tokens == C - 1, dtotal, 0.0)
+    if scalar:
+        # A = strict (K K^T) E, B = (Q K^T) E: the products' transposes
+        # are products, and the exponent's is the rows' sums less the
+        # columns' of dE E. That part of the decay's gradient is one
+        # number a token: it is laid on lane 0, and the lanes are summed
+        E, pairs = factors
+        Ma = jnp.where(_iota((C, C), 0) > _iota((C, C), 1), dA * E, 0.0)
+        Mb = dB * E
+        dk = dk + _dot(Ma, k, _NN) + _dot(Ma, k, _TN) + _dot(Mb, q, _TN)
+        dq = dq + _dot(Mb, k, _NN)
+        yield
+        P = Ma * pairs[:C] + Mb * pairs[C:]
+        dGc = jnp.sum(P, axis=-1, keepdims=True) - _column(
+            jnp.sum(P, axis=0, keepdims=True)
+        )
+        dG = dG + jnp.where(_iota((1, d), 1) == 0, dGc, 0.0)
+        dg = jnp.sum(_cumsum(dG, reverse=True), axis=-1, keepdims=True)
+        return dq, dk, beta * dX, dg, dbeta
     # the blocks below the diagonal: below = [k within; q within] right^T
     qb, kb, Gb = (_blocks(x, n, sub) for x in (q, k, G))
     none = jnp.zeros((sub, d), _F32)  # the first block has no rows below
@@ -335,13 +414,24 @@ def _backward_tile(q, k, v, g, beta, dU, dWt, dq_in, dB, dko, dtotal, sub):
 # ----------------------------------------------------------------- kernels
 
 
+def _decay_of(g_ref, t, at, width, scalar):
+    """A chunk's log-decay [C, width]: read where it lies, or under
+    `scalar` its one number a token, a row along the lanes in memory as
+    beta is, laid on every lane."""
+    if not scalar:
+        return g_ref[0, at[t], :]
+    column = _column(g_ref[0, 0, t])
+    return jnp.broadcast_to(column, (column.shape[0], width))
+
+
 def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *out_refs, chunk,
-                    sub, tiles):
+                    sub, tiles, scalar=False):
     at = [slice(t * chunk, (t + 1) * chunk) for t in range(tiles)]
     results = _weave([
         _forward_tile(
             q_ref[0, at[t], :], k_ref[0, at[t], :], v_ref[0, at[t], :],
-            g_ref[0, at[t], :], _column(beta_ref[0, 0, t]), sub,
+            _decay_of(g_ref, t, at, k_ref.shape[-1], scalar),
+            _column(beta_ref[0, 0, t]), sub, scalar,
         ) for t in range(tiles)
     ])
     for t, result in enumerate(results):
@@ -351,21 +441,26 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *out_refs, chunk,
 
 def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, dU_ref, dWt_ref,
                      dq_in_ref, dB_ref, dk_out_ref, dtotal_ref, dq_ref, dk_ref,
-                     dv_ref, dg_ref, dbeta_ref, *, chunk, sub, tiles):
+                     dv_ref, dg_ref, dbeta_ref, *, chunk, sub, tiles,
+                     scalar=False):
     at = [slice(t * chunk, (t + 1) * chunk) for t in range(tiles)]
     results = _weave([
         _backward_tile(
             q_ref[0, at[t], :], k_ref[0, at[t], :], v_ref[0, at[t], :],
-            g_ref[0, at[t], :], _column(beta_ref[0, 0, t]), dU_ref[t, 0, 0],
+            _decay_of(g_ref, t, at, k_ref.shape[-1], scalar),
+            _column(beta_ref[0, 0, t]), dU_ref[t, 0, 0],
             dWt_ref[t, 0, 0], dq_in_ref[t, 0, 0], dB_ref[t, 0, 0],
-            dk_out_ref[t, 0, 0], dtotal_ref[t, 0, 0], sub,
+            dk_out_ref[t, 0, 0], dtotal_ref[t, 0, 0], sub, scalar,
         ) for t in range(tiles)
     ])
     for t, (dq, dk, dv, dg, dbeta) in enumerate(results):
         dq_ref[0, at[t], :] = dq
         dk_ref[0, at[t], :] = dk
         dv_ref[0, at[t], :] = dv
-        dg_ref[0, at[t], :] = dg
+        if scalar:
+            dg_ref[0, 0, t] = _row(dg)
+        else:
+            dg_ref[0, at[t], :] = dg
         dbeta_ref[0, 0, t] = _row(dbeta)
 
 
@@ -404,10 +499,12 @@ class _Specs:
         self.n = self.L // chunk
         self.grid = (self.B, self.H, self.n // tiles)
 
-    def where_it_lies(self, d):
-        """A head's rows of [B, L, H * d]."""
+    def where_it_lies(self, d, group=1):
+        """A head's rows of [B, L, H * d]; with `group` the rows of the
+        key head that value head h reads, h // group of H / group."""
         return pl.BlockSpec(
-            (1, self.tiles * self.chunk, d), lambda b, h, c: (b, c, h)
+            (1, self.tiles * self.chunk, d),
+            lambda b, h, c: (b, c, h // group),
         )
 
     def rows(self):
@@ -519,3 +616,97 @@ def _intra_bwd(chunk, sub, interpret, saved, cotangents):
 
 
 intra_stage.defvjp(_intra_fwd, _intra_bwd)
+
+
+# ------------------------------------------- the stage under one decay a head
+#
+# The same two kernels with `scalar=True` (`_scalar_pairs` for the
+# column loops of `_channel_pairs`; the rest of a tile as it is): g
+# [B, L, H], one number a value head and token, goes in and its
+# gradient comes out as rows along the lanes, as beta does; q and k may
+# have fewer heads than v, and the grid step of value head h then reads
+# the rows of key head h // group where they lie. dq and dk are written
+# a value head each and summed over a group here.
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _scalar_forward(q, k, v, g, beta, chunk, sub, tiles, interpret):
+    B, L, H, dv = v.shape
+    dk = q.shape[-1]
+    sp = _Specs((B, L, H, dk), dv, chunk, tiles)
+    key = sp.where_it_lies(dk, H // q.shape[2])
+    return pl.pallas_call(
+        functools.partial(
+            _forward_kernel, chunk=chunk, sub=sub, tiles=sp.tiles, scalar=True
+        ),
+        out_shape=[sp.scanned_shape(*o) for o in sp.outputs()],
+        grid=sp.grid,
+        in_specs=[key, key, sp.where_it_lies(dv), sp.rows(), sp.rows()],
+        out_specs=[sp.scanned(*o) for o in sp.outputs()],
+        **_params(interpret),
+    )(_flat(q), _flat(k), _flat(v), _beta_rows(g, chunk),
+      _beta_rows(beta, chunk))
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _scalar_backward(q, k, v, g, beta, cotangents, chunk, sub, tiles,
+                     interpret):
+    B, L, H, dv = v.shape
+    heads, dk = q.shape[2:]
+    group = H // heads
+    sp = _Specs((B, L, H, dk), dv, chunk, tiles)
+    key, wide, value = (
+        sp.where_it_lies(dk, group), sp.where_it_lies(dk), sp.where_it_lies(dv)
+    )
+    flat = lambda d: jax.ShapeDtypeStruct((B, L, H * d), _F32)  # noqa: E731
+    rows = jax.ShapeDtypeStruct((B, H, L // chunk, 1, chunk), _F32)
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(
+            _backward_kernel, chunk=chunk, sub=sub, tiles=sp.tiles, scalar=True
+        ),
+        out_shape=[flat(dk), flat(dk), flat(dv), rows, rows],
+        grid=sp.grid,
+        in_specs=[key, key, value, sp.rows(), sp.rows()]
+        + [sp.scanned(*o) for o in sp.outputs()],
+        out_specs=[wide, wide, value, sp.rows(), sp.rows()],
+        **_params(interpret),
+    )(_flat(q), _flat(k), _flat(v), _beta_rows(g, chunk),
+      _beta_rows(beta, chunk), *cotangents)
+
+    def a_key_head(x):  # the value heads that read it, summed
+        return x.reshape(B, L, heads, group, dk).sum(axis=3)
+
+    def a_token(rows):
+        return jnp.swapaxes(rows.reshape(B, H, L), 1, 2)
+
+    return (
+        a_key_head(dq), a_key_head(dk_), dv_.reshape(v.shape),
+        a_token(dg), a_token(dbeta),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def scalar_intra_stage(q, k, v, g, beta, chunk: int, sub: int,
+                       interpret: bool):
+    """`intra_stage` under one decay a head: q, k [B, L, Hk, dk], v
+    [B, L, H, dv], g and beta [B, L, H], float32, H a multiple of Hk
+    (value head j reads key head j // (H / Hk)), L a multiple of
+    `chunk` -> the same six, each [n, B, H, ...]; `total` [.., 1, dk]
+    holds the head's one sum on every lane."""
+    tiles = pick_tiles(q.shape[1] // chunk)
+    return tuple(
+        _scalar_forward(q, k, v, g, beta, chunk, sub, tiles, interpret)
+    )
+
+
+def _scalar_fwd(q, k, v, g, beta, chunk, sub, interpret):
+    out = scalar_intra_stage(q, k, v, g, beta, chunk, sub, interpret)
+    return out, (q, k, v, g, beta)
+
+
+def _scalar_bwd(chunk, sub, interpret, saved, cotangents):
+    tiles = pick_tiles(saved[0].shape[1] // chunk)
+    return _scalar_backward(*saved, cotangents, chunk, sub, tiles, interpret)
+
+
+scalar_intra_stage.defvjp(_scalar_fwd, _scalar_bwd)

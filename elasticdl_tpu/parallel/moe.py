@@ -438,6 +438,13 @@ def _held_experts_bwd(saved, g):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def _shared_gate(xf: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """sigmoid(x . w), [T, 1] float32; w [1, d]."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "td,gd->tg", xf, w, preferred_element_type=jnp.float32
+    ))
+
+
 def moe_topk_held(
     x: jnp.ndarray,
     router_w: jnp.ndarray,
@@ -451,10 +458,15 @@ def moe_topk_held(
     bias=None,
     renormalize: bool = False,
     balance: bool = True,
+    shared_gate=None,
 ):
     """A top-k dropless expert layer that is told which experts it
     holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x) (no S(x)
-    and no `shared` scope when `shared` is None).
+    and no `shared` scope when `shared` is None). With `shared_gate`
+    [1, d] the shared expert stands behind a gate of its own, one
+    number a token: + sigmoid(x . shared_gate) S(x), the product
+    accumulated and the sigmoid taken in float32 (scope `shared/gate`,
+    stat `shared_gate_mean`).
 
     `score` "softmax" routes by `route_topk`; "sigmoid" by
     `route_sigmoid_topk` with the selection `bias`; under
@@ -469,7 +481,8 @@ def moe_topk_held(
     side), None for a layer without one. -> (y [B, S, d], the
     sequence-wise balance term (unweighted, f32), stats of the
     routing: `expert_tokens` [n] (counts, float32), `held_share`,
-    `router_entropy`, `route_rows`, `route_full`).
+    `router_entropy`, `route_rows`, `route_full`, and under
+    `shared_gate` `shared_gate_mean`).
 
     This is one chip's part of an expert-parallel layer, computed
     without the exchange: what the experts held elsewhere would add is
@@ -533,7 +546,13 @@ def moe_topk_held(
         y = routed
     else:
         with jax.named_scope("shared"):
-            y = routed + swiglu(xf, *shared)
+            out = swiglu(xf, *shared)
+            if shared_gate is not None:
+                with jax.named_scope("gate"):
+                    gate = _shared_gate(xf, shared_gate)  # [T, 1] f32
+                    out = out * gate.astype(out.dtype)
+                    stats["shared_gate_mean"] = jnp.mean(gate)
+            y = routed + out
     return y.reshape(b, s, d), balance_term, jax.tree_util.tree_map(
         lax.stop_gradient, stats
     )

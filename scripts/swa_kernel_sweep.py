@@ -16,6 +16,9 @@ lanes do not divide) | q and k padded with zeros to 256 and read in
 place (`padded`, stated here: the other honest layout; zeros add
 nothing to a score, dq and dk are cut back by the pad's own gradient).
 
+`--cases wide` (PR 52): the causal call at heads of 256, (1, 8192, 16,
+256), over the tile ladder, beside the same pairs at 32 heads of 128.
+
     chiprun -- python scripts/swa_kernel_sweep.py --cases latent
 
 Writes chiprun_out/swa_kernel_sweep.<cases>.json. `--compile_only`
@@ -48,8 +51,16 @@ LATENT = ((4, 2048, 16, 192), (2, 2048, 32, 192))
 V_WIDTH = 128
 # deepseek-v2-lite's `mla_softmax_scale`: 192^-0.5 x mscale(40, 0.707)^2
 LATENT_SCALE = 192**-0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2
+WIDE = (1, 8192, 16, 256)
 # (shape, window, tiles, v_width, scale, path)
 CASES = {
+    "wide": [
+        (WIDE, None, tiles, None, None, "kernels")
+        for tiles in (
+            (1024, 1024), (512, 512), (256, 256), (512, 1024), (1024, 512),
+            (2048, 1024), (1024, 2048),
+        )
+    ] + [((1, 8192, 32, 128), None, (1024, 1024), None, None, "kernels")],
     "band": [
         (BANDED, WINDOW, (bq, bk), None, None, "kernels")
         for bq, bk in (
@@ -150,6 +161,7 @@ def main():
             (shape, {"v_width": V_WIDTH, "scale": LATENT_SCALE})
             for shape in LATENT
         ],
+        "wide": [((1, 2048, 16, 256), {}), (WIDE, {})],
     }[args.cases]
     for shape, how in checks:
         errors = fa.check_against_reference(shape, **how)

@@ -347,6 +347,8 @@ def test_the_chip_s_check_takes_a_value_width_and_a_scale():
     ("hybrid-latent", (2, 2048, 32, 192), 128, None, None, True),
     ("latent-xla-wins-at-1024", (4, 1024, 16, 192), 128, 0.1147, None, False),
     ("latent-forced-off", (4, 2048, 16, 192), 128, 0.1147, "0", False),
+    ("gated-attention-256", (1, 8192, 16, 256), 256, None, None, True),
+    ("heads-of-256-xla-wins-at-1024", (1, 1024, 16, 256), 256, None, None, False),
     ("no-tile-divides-96", (2, 96, 12, 64), 64, None, "1", False),
     ("no-tile-divides-2080", (1, 2080, 8, 64), 64, None, None, False),
 ])
@@ -545,3 +547,59 @@ def test_the_dispatcher_hands_the_window_to_whichever_path_takes_the_call(
         lambda q, k, v: fa.attention(q, k, v, window=window), x, x, x
     )
     assert seen == {("kernel" if kernels else "xla"): passed}, case
+
+
+# ------------------------------------------------------- heads of 256
+
+
+@pytest.mark.parametrize("L, tiles", [
+    (2 * BLOCK, (128, 128)), (4 * BLOCK, (256, 128)), (4 * BLOCK, (128, 256)),
+], ids=["L256-q128k128", "L512-q256k128", "L512-q128k256"])
+def test_heads_of_256_under_a_group_of_eight_match_the_float32_math(L, tiles):
+    """Qwen3-Next's gated attention: 8 query heads of 256 over ONE
+    key-value head (the cell's 16 over 2, halved), widened in front of
+    the kernels as the dispatcher widens them; forward, dq and, summed
+    over the group by the widening's own transpose, dk and dv, against
+    the float32 math and a generic cotangent, tile pair by tile pair. A
+    width of 256 is read where it lies (`_Layout`), two passes of the
+    128-wide multiplier a contraction."""
+    q, w, _ = _qkv(b=1, L=L, h=8, d=256, seed=31)
+    k, v, _ = _qkv(b=1, L=L, h=1, d=256, seed=32)
+
+    def widened(attend):
+        def run(q, k, v):
+            k, v = (jnp.repeat(x, 8, axis=2) for x in (k, v))
+            return attend(q, k, v)
+
+        return run
+
+    (_, o), grads = _through(widened(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True, tiles=tiles)
+    ), w)(q, k, v)
+    (_, o_ref), grads_ref = _through(widened(reference_attention), w)(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+    for name, got, want in zip(("dq", "dk", "dv"), grads, grads_ref):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4, err_msg=name
+        )
+
+
+def test_the_dispatcher_widens_a_group_of_eight_at_256(monkeypatch):
+    """On a TPU the call of the cell's shape, 16 query heads of 256 over
+    2 key-value heads at 8192 tokens, reaches the kernels with k and v
+    widened to 16 heads, at the ladder's tiles. Traced only."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+    seen = []
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda q, k, v, *a, **kw: seen.append((q.shape, k.shape, v.shape)) or q,
+    )
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: fa.attention(q, k, v), q, kv, kv)
+    assert seen == [((1, 8192, 16, 256),) * 3]
+    assert fa.pick_tiles(8192) == (1024, 1024)

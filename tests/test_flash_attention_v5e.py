@@ -47,6 +47,7 @@ def no_compile_cache():
     (1, 16384, 8, 64),  # the long sequence of test_cluster_gated.py
     (1, 384, 2, 64),  # a length only BLOCK divides
     (1, 2048, 4, 256),  # the widest head: the tiles' VMEM at its largest
+    (1, 8192, 16, 256),  # qwen3-next-80b-a3b's gated attention layer
     (1, 8192, 48, 128),  # laguna-xs2's full layers
     (1, 8192, 64, 128, 512),  # its sliding layers: banded, 512 x 512 tiles
     (1, 2048, 4, 128, 300),  # a window no tile divides

@@ -5,7 +5,10 @@ matrices and the five input gradients against the jax stage
 against the recurrence a token at a time at the tolerances
 `test_hybrid_lm.py` holds the jax form to, the dispatcher's rule, and
 the kernels compiled for a described TPU v5e at the width the hybrid
-cell runs (nothing executes there).
+cell runs (nothing executes there); and the same kernels under one
+decay a head (`scalar_intra_stage`: Gated DeltaNet's) against
+`kda.intra_stage` told that decay on every channel with q and k widened
+(the path off the TPU), fewer key heads than value heads among them.
 
 One shape throughout, (1, 128, 2, 128) in chunks of 64, a chunk a grid
 step (`one_chunk_a_step`: the interpreter's XLA:CPU compile of the
@@ -213,6 +216,99 @@ def test_the_chip_check_reports_which_path_it_held_to_the_recurrence():
     assert kda.check_against_recurrence((1, 64, 1, 16))["kernels"] is False
 
 
+# ------------------------------------------------- one decay a head
+
+
+def scalar_inputs(decay, key_heads=1, seed=0):
+    """`recurrence_inputs` at the kernels' width with one decay a head
+    and `key_heads` key heads under 2 value heads."""
+    q, k, v, g, beta = recurrence_inputs(decay, seed=seed, **WIDE)
+    return q[:, :, :key_heads], k[:, :, :key_heads], v, g[..., 0], beta
+
+
+def scalar_jax_stage(q, k, v, g, beta):
+    """The per-channel jax stage told the one decay on every channel,
+    q and k widened to the value heads: what `kda_chunked` runs off the
+    TPU, and the scalar kernels' oracle."""
+    q, k = (jnp.repeat(x, v.shape[2] // x.shape[2], axis=2) for x in (q, k))
+    wide = jnp.broadcast_to(g[..., None], g.shape + (q.shape[-1],))
+    return kda.intra_stage(
+        _chunks(q), _chunks(k), _chunks(v), _chunks(wide),
+        _chunks(beta)[..., None], 16,
+    )
+
+
+def scalar_kernel_stage(q, k, v, g, beta):
+    U, Wt, q_in, Bqk, k_out, total = kda_kernels.scalar_intra_stage(
+        q, k, v, g, beta, 64, 16, True
+    )
+    # the head's one sum lies on every lane, as the per-channel stage's
+    assert total.shape[-1] == q.shape[-1]
+    return U, Wt, q_in, Bqk, jnp.swapaxes(k_out, -1, -2), total
+
+
+SCALAR_JAX = jax.jit(scalar_jax_stage)
+SCALAR_KERNEL = jax.jit(scalar_kernel_stage)
+SCALAR_JAX_GRADS = _stage_gradients(scalar_jax_stage)
+SCALAR_KERNEL_GRADS = _stage_gradients(scalar_kernel_stage)
+
+
+@pytest.mark.parametrize("key_heads", [2, 1])
+@pytest.mark.parametrize("decay", [1e-4, 0.3, 20.0])
+def test_the_scalar_kernel_s_six_matrices_are_the_jax_stage_s_told_one_decay(
+    decay, key_heads
+):
+    """Under one decay a head the pairs are products times a [C, C]
+    matrix of exponentials (`_scalar_pairs`); with one key head under
+    two value heads both grid steps read the one key head's rows."""
+    args = scalar_inputs(decay, key_heads)
+    got, want = SCALAR_KERNEL(*args), SCALAR_JAX(*args)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert close(a, b, 2e-5), name
+
+
+@pytest.mark.parametrize("key_heads", [2, 1])
+@pytest.mark.parametrize("decay", [1e-4, 1.0, 20.0])
+def test_the_scalar_kernels_gradients_are_jax_s_through_the_stage_told_one_decay(
+    decay, key_heads
+):
+    args = scalar_inputs(decay, key_heads)
+    weights = _weights(SCALAR_JAX(*args))
+    got = SCALAR_KERNEL_GRADS(weights, *args)
+    want = SCALAR_JAX_GRADS(weights, *args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.shape == b.shape, name
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert close(a, b, 2e-5, floor=1e-3), name
+
+
+@pytest.mark.parametrize("decay", [1e-2, 20.0])
+def test_the_scalar_scan_through_the_kernels_is_the_recurrence(decay):
+    """Forward and every gradient, one key head under two value heads,
+    a length that is padded to the chunk."""
+    q, k, v, g, beta = scalar_inputs(decay, 1)
+    args = tuple(x[:, :100] for x in (q, k, v, g, beta))
+    through = lambda *a: kda.kda_chunked(*a, interpret=True)[0]  # noqa: E731
+    assert "pallas_call" in str(jax.make_jaxpr(through)(*args))
+    assert close(jax.jit(through)(*args), RECURRENT(*args), 2e-5)
+    got = _scan_gradients(through)(*args)
+    want = RECURRENT_GRADS(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.shape == b.shape, name
+        assert close(a, b, 1e-4, floor=1e-3), name
+
+
+def test_the_per_channel_kernels_trace_as_they_did():
+    """`scalar` defaults to off and adds no operation: the per-channel
+    kernel's jaxpr holds no [C, C] exponential of the scalar pairs."""
+    args = recurrence_inputs(0.3, **WIDE)
+    per_channel = str(jax.make_jaxpr(kernel_stage)(*args))
+    scalar = str(jax.make_jaxpr(scalar_kernel_stage)(*scalar_inputs(0.3)))
+    assert "reduce_max" in scalar and "reduce_max" not in per_channel
+
+
 # ------------------------------------------ compiled for a described v5e
 
 
@@ -242,6 +338,29 @@ def test_mosaic_takes_the_kernels_at_the_hybrid_cell_s_width(one_chip, pass_):
 
     def stage(*a):
         return kda_kernels.intra_stage(*a, 64, 16, False)
+
+    def transposed(*a):
+        out, vjp = jax.vjp(stage, *a)
+        return vjp(out)
+
+    traced = stage if pass_ == "forward" else transposed
+    text = jax.jit(traced).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_mosaic_takes_the_scalar_kernels_at_the_qwen3_next_cell_s_heads(
+    one_chip, pass_
+):
+    """(1, 1024) tokens, 2 key heads under 4 value heads of 128 (the
+    cell: 16 under 32 at 8192), one decay a head, four chunks a grid
+    step."""
+    like = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    key, value, rows = like(1, 1024, 2, 128), like(1, 1024, 4, 128), like(1, 1024, 4)
+    args = (key, key, value, rows, rows)
+
+    def stage(*a):
+        return kda_kernels.scalar_intra_stage(*a, 64, 16, False)
 
     def transposed(*a):
         out, vjp = jax.vjp(stage, *a)
