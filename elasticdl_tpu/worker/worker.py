@@ -110,6 +110,32 @@ def _compiles_into(info: dict):
 
 _NO_SPAN = contextlib.nullcontext()
 
+# What the window's loop carries (`_build_local_window_fn`): the model
+# and its moments as the template's leaves where the MEAN leaf has at
+# least this many elements (4 MiB of float32), else the flat vectors.
+# The flat vector costs per byte, every step (the gradient assembled
+# into one float32 vector, a concatenate, a cast of the whole model);
+# the leaves cost per array, once (trace, lowering and load of a loop
+# that carries them all). The two sides (ledger, PR 50, which carried
+# leaves everywhere): ResNet-50, 161 leaves of 159 K elements, +4.2 s
+# of `setup_programs_s` for +0.0 % of `goodput`; the LM cells, 11 to 86
+# leaves of 7.0 to 19.8 M elements, +0.9 to 1.6 s for +2 to 15 %.
+CARRY_LEAVES_MIN_MEAN_ELEMENTS = 2**20
+
+
+def carries_leaves(template) -> bool:
+    """The rule of `CARRY_LEAVES_MIN_MEAN_ELEMENTS` on a parameter
+    template: its leaves' shapes decide, nothing else of the job. (A
+    leaf that is not float32 keeps the flat vector: cutting a float32
+    moment to such a leaf's dtype would round it.)"""
+    leaves = jax.tree_util.tree_leaves(template)
+    return (
+        bool(leaves)
+        and all(leaf.dtype == np.float32 for leaf in leaves)
+        and sum(int(np.prod(leaf.shape)) for leaf in leaves)
+        >= CARRY_LEAVES_MIN_MEAN_ELEMENTS * len(leaves)
+    )
+
 
 def _program_name(program) -> str:
     """The name jax gives a jitted callable's program, as the device
@@ -338,6 +364,9 @@ class Worker:
         self._local_updates = local_updates
         self._local_step_fn = None
         self._local_window_fn = None  # scanned whole-window step
+        self._window_cut_fn = None  # leaves carry: jit_cut, jit_join
+        self._window_join_fn = None
+        self._window_ahead = None  # its loss: the window a cut waits for
         self._opt_state = None
         self._base_flat = None  # device copy of params at last sync
         self._subtract_into_base = None  # jitted on the serial chain
@@ -1532,31 +1561,35 @@ class Worker:
             donate_argnums=(0, 1),
         )
 
-    def _local_step_core(self):
+    def _local_step_core(self, leaves: bool = False):
         """The single-minibatch local update:
-        (flat, opt_state, aux, f, l) -> (flat', opt_state', aux', loss).
+        (model, opt_state, aux, f, l) -> (model', opt_state', aux', loss).
         One definition shared by the per-step jit and the window scan,
-        so the two paths cannot drift apart mathematically."""
+        so the two paths cannot drift apart mathematically. The model
+        is the flat vector, cut into the template's leaves inside the
+        differentiated function (the gradient is then one vector too),
+        or, with `leaves`, the template's tree itself with moments of
+        its structure, differentiated as a tree: the same loss, the
+        same `tx.update` and add."""
         spec = self._spec
         tx = spec.optimizer()
-        unravel = self._unravel
+        cut = (lambda tree: tree) if leaves else self._unravel
 
-        def step(flat, opt_state, aux, features, labels):
-            def loss_fn(flat):
-                params = unravel(flat)
-                variables = {"params": params, **aux}
+        def step(model, opt_state, aux, features, labels):
+            def loss_fn(model):
+                variables = {"params": cut(model), **aux}
                 outputs, new_aux = self._apply_model(
                     variables, features, None, train=True
                 )
                 return spec.loss(outputs, labels), new_aux
 
             (loss, new_aux), grad = jax.value_and_grad(loss_fn, has_aux=True)(
-                flat
+                model
             )
             with jax.named_scope("optimizer"):
-                updates, opt_state = tx.update(grad, opt_state, flat)
-                flat = flat + updates
-            return flat, opt_state, new_aux if new_aux else aux, loss
+                updates, opt_state = tx.update(grad, opt_state, model)
+                model = jax.tree_util.tree_map(jnp.add, model, updates)
+            return model, opt_state, new_aux if new_aux else aux, loss
 
         return step
 
@@ -1689,6 +1722,10 @@ class Worker:
             if self._local_step_fn is None:
                 self._local_step_fn = self._build_local_step()
             self._first_run_begins("step")
+            if self._window_join_fn is not None:  # a tail behind windows
+                self._opt_state = self._vector_state(
+                    self._opt_state, self._flat
+                )
             args = (self._flat, self._opt_state, self._aux, features, labels)
             with self._first_call(self._local_step_fn, args):
                 self._flat, self._opt_state, new_aux, loss = (
@@ -1712,9 +1749,15 @@ class Worker:
         the TPU-first shape of the local-update loop — W-fold fewer
         host->device dispatches and one bulk feature transfer per
         window instead of per minibatch; math is identical to W calls
-        of the per-step path (same carry: flat params, opt state, aux)."""
+        of the per-step path. The loop carries the flat vectors as the
+        per-step program does, or, where the template's leaves are
+        large (`carries_leaves`), the template's trees: the model and
+        the moments differentiated and updated as leaves, so that no
+        step assembles a whole-model vector. `_run_window` calls either
+        form on the worker's flat state."""
         assert self._use_flat(), "local mode requires flat transport"
-        step = self._local_step_core()
+        leaves = carries_leaves(self._template)
+        step = self._local_step_core(leaves)
         # XLA:CPU executes convolution *gradients* inside a while-loop
         # body through a ~40-140x slower fallback path (measured: 48ms
         # standalone vs 6.7s/step under lax.scan on this image). On CPU
@@ -1731,18 +1774,18 @@ class Worker:
             else 1
         )
 
-        def window(flat, opt_state, aux, features, labels):
+        def window(model, state, aux, features, labels):
             def body(carry, xs):
-                flat, opt_state, aux = carry
+                model, state, aux = carry
                 f, l = xs
-                flat, opt_state, aux, loss = step(flat, opt_state, aux, f, l)
-                return (flat, opt_state, aux), loss
+                model, state, aux, loss = step(model, state, aux, f, l)
+                return (model, state, aux), loss
 
-            (flat, opt_state, aux), losses = jax.lax.scan(
-                body, (flat, opt_state, aux), (features, labels),
+            (model, state, aux), losses = jax.lax.scan(
+                body, (model, state, aux), (features, labels),
                 unroll=unroll,
             )
-            return flat, opt_state, aux, losses[-1]
+            return model, state, aux, losses[-1]
 
         if self._mesh is None or self._mesh.size <= 1:
             return jax.jit(window, donate_argnums=(0, 1))
@@ -1758,6 +1801,116 @@ class Worker:
             donate_argnums=(0, 1),
         )
 
+    def _build_cut_and_join(self):
+        """The leaves carry's two small programs: `jit_cut`, a flat
+        vector to the template's tree, and `jit_join`, back, with the
+        one `ravel_pytree` pair the worker holds. They run round
+        `jit_window`, NOT inside it: a program keeps its donated
+        arguments for as long as it runs, so a window that cut them
+        itself would hold the three vectors, dead, beside their leaves
+        through every step (12 B a parameter: 14.0 GB of temporaries
+        against 7.29 in the Qwen3-Next cell, by the v5e compiler)."""
+        from jax.flatten_util import ravel_pytree
+
+        unravel = self._unravel
+
+        def cut(vector):
+            return unravel(vector)
+
+        def join(tree):
+            return ravel_pytree(tree)[0]
+
+        return jax.jit(cut), jax.jit(join)
+
+    def _vector_state(self, state, flat):
+        """An optimizer state the leaves carry left as trees, in the
+        form `tx.init(flat)` gives: what the per-step program of a
+        ragged tail takes. A state in that form comes back as it is."""
+        like = jax.eval_shape(
+            self._spec.optimizer().init,
+            jax.ShapeDtypeStruct(flat.shape, flat.dtype),
+        )
+        if jax.tree_util.tree_structure(state) == (
+            jax.tree_util.tree_structure(like)
+        ):
+            return state
+        return jax.tree_util.tree_map(
+            lambda a, tree: (
+                self._window_join_fn(tree) if a.shape == flat.shape else tree
+            ),
+            like, state,
+        )
+
+    def _run_window(self, flat, opt_state, aux, features, labels, timed=True):
+        """One call of `jit_window` on the worker's state:
+        (flat, opt_state, aux, f, l) -> (flat, opt_state, aux, loss),
+        the model's vector given up as a donation gives it up. `timed`:
+        the programs' first calls are `setup.program` spans (not in a
+        warm-up); `jit_window`'s says what the loop carries: `carry`
+        (`leaves` | `flat`) and `carried`, how many arrays that is for
+        the model and the optimizer's state.
+
+        Where the loop carries leaves, the model goes in as its tree,
+        cut from the vector before the call and joined after it, and
+        every state array of its shape (Adam's two moments, not its
+        count) is cut ONCE, when `tx.init(flat)` has just made it, and
+        stays a tree from window to window: only the model's vector is
+        ever needed between them (the delta, the base). The device
+        allocates a program's results when the program is asked for,
+        not when it runs, so what is asked for ahead of the device
+        lies beside what is running. On the serial chain, whose step
+        loop waits for the window's sync next anyway, the join waits
+        for the window's end: its vector never lies beside the
+        window's temporaries. With syncs in flight the step loop stays
+        ahead, but by one window: a cut waits for the window before
+        it."""
+        window = self._local_window_fn
+        first_call = self._first_call if timed else (lambda *a, **k: _NO_SPAN)
+        leaves = carries_leaves(self._template)
+        if leaves:
+            if self._window_cut_fn is None:
+                self._window_cut_fn, self._window_join_fn = (
+                    self._build_cut_and_join()
+                )
+            cut_fn, join_fn = self._window_cut_fn, self._window_join_fn
+
+            def cut(vector):
+                with first_call(cut_fn, (vector,)):
+                    tree = cut_fn(vector)
+                vector.delete()
+                return tree
+
+            if self._window_ahead is not None:
+                jax.block_until_ready(self._window_ahead)
+            vector = flat.shape
+            model = cut(flat)
+            state = jax.tree_util.tree_map(  # each let go before the next
+                lambda a: (
+                    jax.block_until_ready(cut(a))
+                    if np.shape(a) == vector else a
+                ),
+                opt_state,
+            )
+        else:
+            model, state = flat, opt_state
+        args = (model, state, aux, features, labels)
+        with first_call(
+            window, args, carry="leaves" if leaves else "flat",
+            carried=len(jax.tree_util.tree_leaves((model, state))),
+        ):
+            model, state, aux, loss = window(*args)
+        if not leaves:
+            return model, state, aux, loss
+        if self._max_inflight_syncs:
+            self._window_ahead = loss
+        else:
+            jax.block_until_ready(loss)
+        with first_call(join_fn, (model,)):
+            flat = join_fn(model)
+        for leaf in jax.tree_util.tree_leaves(model):
+            leaf.delete()
+        return flat, state, aux, loss
+
     def _local_window(self, features, labels, task: Task):
         """features/labels stacked [W, B, ...] with W == local_updates."""
         first = jax.tree_util.tree_map(lambda a: a[0], features)
@@ -1765,11 +1918,9 @@ class Worker:
         if self._local_window_fn is None:
             self._local_window_fn = self._build_local_window_fn()
         self._first_run_begins("window")
-        args = (self._flat, self._opt_state, self._aux, features, labels)
-        with self._first_call(self._local_window_fn, args):
-            self._flat, self._opt_state, new_aux, loss = self._local_window_fn(
-                *args
-            )
+        self._flat, self._opt_state, new_aux, loss = self._run_window(
+            self._flat, self._opt_state, self._aux, features, labels
+        )
         self._aux = new_aux or self._aux
         self._pending_steps += self._local_updates
         self._latest_step_loss = loss
@@ -2829,13 +2980,14 @@ class Worker:
                 exc_info=True,
             )
 
-    def _first_call(self, program, args=None):
+    def _first_call(self, program, args=None, **attrs):
         """`setup.program` around the FIRST call of a jitted program
         (trace, lower, compile or load from the compile cache,
         dispatch), at its call site: `program` is the jitted callable,
         with the call's arguments, or, for an eager op, the name jax
-        gives its program. On the way out a callable's map is written
-        (`_write_scope_map`). Later calls get a shared null context."""
+        gives its program; `attrs` is what else the span says. On the
+        way out a callable's map is written (`_write_scope_map`). Later
+        calls get a shared null context."""
         called = self._programs_called
         if called is None:
             called = self._programs_called = set()
@@ -2845,17 +2997,19 @@ class Worker:
         called.add(key)
         if isinstance(program, str):
             return self._program_span(program)
-        return self._first_call_of(program, args)
+        return self._first_call_of(program, args, attrs)
 
     @contextlib.contextmanager
-    def _first_call_of(self, program, args):
-        with self._program_span(_program_name(program)):
+    def _first_call_of(self, program, args, attrs):
+        with self._program_span(_program_name(program), **attrs):
             yield
         self._write_scope_map(program, args)
 
     @contextlib.contextmanager
-    def _program_span(self, program: str):
-        with self.timers.span("setup.program", program=program) as info:
+    def _program_span(self, program: str, **attrs):
+        with self.timers.span(
+            "setup.program", program=program, **attrs
+        ) as info:
             with _compiles_into(info):
                 yield
 
@@ -3493,8 +3647,9 @@ class Worker:
             self._local_window_fn = self._build_local_window_fn()
         tx = self._spec.optimizer()
         opt_state = tx.init(self._flat)
-        out = self._local_window_fn(
-            jnp.copy(self._flat), opt_state, self._aux, features, labels
+        out = self._run_window(
+            jnp.copy(self._flat), opt_state, self._aux, features, labels,
+            timed=False,
         )
         # a d2h of the loss forces completion
         jax.device_get(out[3])
