@@ -19,11 +19,16 @@ inclusive; a reader charges a moment to the innermost span open then.
 Thread-safe: the worker's chained sync threads log summaries (and may
 time their own phases) while the main thread is inside `phase()` —
 the totals are lock-guarded and the nesting stack is thread-local.
+
+`DeviceRuns` lays the device's own busy intervals on the same clock:
+one `worker.device_run` span a call of a training program, from the
+moment the device could begin it to the moment the host saw its result.
 """
 
 from __future__ import annotations
 
 import os
+import queue
 import statistics
 import threading
 import time
@@ -176,3 +181,103 @@ class PhaseTimers:
         with self._lock:
             self._seconds.clear()
             self._counts.clear()
+
+
+DEVICE_RUN = "worker.device_run"
+
+
+class DeviceRuns:
+    """One `worker.device_run` span a call of a training program
+    (`jit_window`, `jit_step`), on `timers`' timeline: `ts` is the later
+    of the moment the call was asked for and the end of the run before
+    it (a device runs one program at a time, in the order asked), and
+    `ts + dur` the moment the host saw the program's result ready. The
+    call is asked for once it has RETURNED: its arguments staged and
+    the program in the device's queue, which is the first moment the
+    device could begin it (stamped before the call, a per-step batch's
+    41 ms of staging counted as the device's: PERF.md, PR 54). The
+    span says `program`, `steps`, `seq` (the call's number in this
+    process, from 1), `asked` (that moment's `time.time()`),
+    `queued_ms` (how long it stood behind the run before it: above 0
+    the host was ahead and the device paces; 0 with a gap before `ts`,
+    the device waited for the host) and, where the backend reports
+    them, `bytes_in_use` and `bytes_reserved` of ONE `memory_stats()`
+    read at that same moment: the device allocates a program's results
+    when the program is asked for, so the two are of one moment inside
+    the run's life.
+
+    The call site says when the call has returned and where the result
+    is seen:
+
+        ... = program(*args)
+        run = runs.asked("jit_window", steps)
+        block_until_ready(result); runs.ready(run)  # a wait that stands there
+        # or, where no wait of the caller's own is reached in time:
+        runs.watch(run, loss)  # one daemon thread waits and stamps
+
+    The watcher blocks on what it is handed and on nothing else: hand
+    it a result no later program is given as a donation (the loss).
+    It holds that one reference until the device is done with the run.
+    Nothing here wraps the program or touches its arguments."""
+
+    def __init__(self, timers, wait, memory_stats=None):
+        self._timers = timers
+        self._wait = wait  # blocks until the result it is given is ready
+        self._memory_stats = memory_stats  # () -> dict | None
+        self._lock = threading.Lock()  # the watcher and a caller's wait
+        self._last_end = 0.0
+        self._watched = None  # the watcher's queue, made with its thread
+        self.seq = 0  # of the last call asked for
+
+    def asked(self, program: str, steps: int) -> dict:
+        self.seq += 1
+        run = {
+            "program": program, "steps": steps, "seq": self.seq,
+            "asked": time.time(),
+        }
+        stats = self._memory_stats() if self._memory_stats else None
+        if stats and "bytes_in_use" in stats:
+            run["bytes_in_use"] = int(stats["bytes_in_use"])
+            run["bytes_reserved"] = int(stats.get("bytes_reserved", 0))
+        return run
+
+    def ready(self, run: dict):
+        with self._lock:  # the clock is read under it: ends never go back
+            end = time.time()
+            begin = min(max(run["asked"], self._last_end), end)
+            self._last_end = end
+        run["queued_ms"] = round((begin - run["asked"]) * 1e3, 3)
+        self._timers.record_span(DEVICE_RUN, begin, end, **run)
+
+    def watch(self, run: dict, result):
+        if self._watched is None:
+            self._watched = queue.SimpleQueue()
+            threading.Thread(
+                target=self._watch, args=(self._watched,), daemon=True,
+                name="edl-device-runs",
+            ).start()
+        self._watched.put((run, result))
+
+    def _watch(self, watched):
+        while True:
+            item = watched.get()
+            if item is None:
+                return
+            run, result = item
+            try:
+                self._wait(result)
+            except Exception:  # a reader's aid must not stop training
+                logger.warning(
+                    "no worker.device_run for %s %d: its result cannot be "
+                    "waited for", run["program"], run["seq"], exc_info=True,
+                )
+                continue
+            finally:
+                del item, result
+            self.ready(run)
+
+    def close(self):
+        """Let the watcher go once it has stamped what it holds."""
+        if self._watched is not None:
+            self._watched.put(None)
+            self._watched = None

@@ -25,7 +25,10 @@ trace covers it (:func:`child_context`), the phase span carries that
 trace's ids and serves both readers; otherwise it has no ``trace_id``.
 A process that owns a log directory appends them to a JSON-lines file
 as it goes (:class:`SpanFile`), so a SIGKILL loses at most one flush
-period.
+period. The same process says on that timeline when it was itself
+held up: ``proc.stall`` (the file's thread woke late from a wait that
+timed out: the process was stopped, starved or held the GIL elsewhere)
+and ``proc.gc`` (a collection that took long).
 
 Export is Chrome trace-event JSON ("X" complete events, wall-clock
 microsecond timestamps so spans from different processes align on one
@@ -39,6 +42,7 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -66,6 +70,11 @@ _tls = threading.local()
 
 # `cat` of the always-on phase timeline (record_phase)
 PHASE_CAT = "phase"
+
+# a wait of the span file's thread that timed out and came back this
+# much late is a `proc.stall`; a collection this long a `proc.gc`
+STALL_SECS = 0.25
+GC_SECS = 0.05
 
 # Resolved sampling probability; None = not yet read from the env.
 # Kept module-global so the disabled fast path is one float compare.
@@ -136,7 +145,10 @@ class SpanRecorder:
     ``drain`` hands out what was recorded since the last drain (each
     span once) and leaves the ring as it is for ``snapshot``;
     ``pressure`` is set when half a stripe waits to be drained, so a
-    drainer that waits on it loses nothing to a burst.
+    drainer that waits on it loses nothing to a burst. ``held`` takes
+    a span from where no lock may be taken (a gc callback runs on
+    whatever thread allocated, a stripe's lock perhaps in its hand);
+    a reader moves them into the ring first.
     """
 
     def __init__(
@@ -145,6 +157,7 @@ class SpanRecorder:
         per = max(1, capacity // max(1, stripes))
         self._half = max(1, per // 2)
         self.pressure = threading.Event()
+        self.held: deque = deque(maxlen=64)
         # counts: [dropped, recorded, drained]
         self._stripes = [
             (threading.Lock(), deque(maxlen=per), [0, 0, 0])
@@ -164,7 +177,15 @@ class SpanRecorder:
             if counts[1] - counts[2] >= self._half:
                 self.pressure.set()
 
+    def _take_held(self) -> None:
+        while self.held:
+            try:
+                self.record(self.held.popleft())
+            except IndexError:  # another reader took it
+                return
+
     def snapshot(self) -> List[Dict[str, Any]]:
+        self._take_held()
         out: List[Dict[str, Any]] = []
         for lock, ring, _counts in self._stripes:
             with lock:
@@ -176,6 +197,7 @@ class SpanRecorder:
         """The spans recorded since the last drain, oldest first. One
         that the ring evicted before it was drained is lost (and
         counted in ``dropped``)."""
+        self._take_held()
         out: List[Dict[str, Any]] = []
         for lock, ring, counts in self._stripes:
             with lock:
@@ -187,6 +209,7 @@ class SpanRecorder:
         return out
 
     def clear(self) -> None:
+        self.held.clear()
         for lock, ring, counts in self._stripes:
             with lock:
                 ring.clear()
@@ -374,11 +397,20 @@ def record_phase(
     ``EDL_TRACE_SAMPLE`` says. ``begin`` is ``time.time()`` at entry.
     With ``ctx`` (a sampled trace covers the interval) the span carries
     the trace's ids; without, it has none."""
+    span = _phase_span(name, begin, dur, args)
+    if ctx is not None:
+        span["trace_id"] = ctx.trace_id
+        span["span_id"] = ctx.span_id
+        span["parent_id"] = ctx.parent_id
+    (RECORDER if recorder is None else recorder).record(span)
+
+
+def _phase_span(name, begin, dur, args=None) -> Dict[str, Any]:
     thread = threading.current_thread()
     full = {"thread": thread.name}
     if args:
         full.update(args)
-    span = {
+    return {
         "name": name,
         "cat": PHASE_CAT,
         "ts": begin,
@@ -387,11 +419,30 @@ def record_phase(
         "tid": thread.ident,
         "args": full,
     }
-    if ctx is not None:
-        span["trace_id"] = ctx.trace_id
-        span["span_id"] = ctx.span_id
-        span["parent_id"] = ctx.parent_id
-    (recorder or RECORDER).record(span)
+
+
+_gc_began = [0.0]  # collections do not nest
+
+
+def _on_gc(phase, info):
+    """``proc.gc`` for a collection over ``GC_SECS``, on the thread it
+    ran on: into ``RECORDER.held``, no lock taken."""
+    if phase == "start":
+        _gc_began[0] = time.time()
+        return
+    took = time.time() - _gc_began[0]
+    if took > GC_SECS:
+        RECORDER.held.append(_phase_span(
+            "proc.gc", _gc_began[0], took,
+            {"generation": info.get("generation"),
+             "collected": info.get("collected")},
+        ))
+
+
+def watch_gc() -> None:
+    """Have this process record its long collections (once)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def extract(req: Any) -> Optional[TraceContext]:
@@ -509,17 +560,25 @@ class SpanFile:
     thread drains the recorder every ``period_secs`` and appends each
     span as a JSON line to ``path`` (line-buffered, so a line is whole
     or absent). A relaunched process appends to the same file under
-    its new ``pid``."""
+    its new ``pid``. The thread is also the process's stall detector:
+    a wait that timed out and returned more than ``STALL_SECS`` late
+    (by ``clock``, which runs on while the process is stopped) is a
+    ``proc.stall`` span with ``late_ms`` and the process's ``role``."""
 
     def __init__(
         self,
         path: str,
         period_secs: float = 2.0,
         recorder: Optional[SpanRecorder] = None,
+        role: str = "",
+        clock=time.monotonic,
     ):
         self.path = path
         self._period = float(period_secs)
-        self._recorder = recorder or RECORDER
+        # not `or`: a recorder with nothing in it yet is falsy (`__len__`)
+        self._recorder = RECORDER if recorder is None else recorder
+        self._role = role
+        self._clock = clock
         self._file = open(path, "a", buffering=1)
         self._lock = threading.Lock()  # flush() from the thread and stop()
         self._stop = threading.Event()
@@ -535,7 +594,16 @@ class SpanFile:
     def _loop(self):
         pressure = self._recorder.pressure
         while not self._stop.is_set():
-            pressure.wait(self._period)  # a burst does not wait a period
+            due = self._clock() + self._period
+            # a burst does not wait a period
+            if not pressure.wait(self._period):
+                late = self._clock() - due
+                if late > STALL_SECS:
+                    record_phase(
+                        "proc.stall", time.time() - late, late,
+                        {"late_ms": round(late * 1e3, 1), "role": self._role},
+                        recorder=self._recorder,
+                    )
             pressure.clear()
             self.flush()
 
@@ -560,10 +628,13 @@ class SpanFile:
 def start_span_file(directory: str, basename: str) -> Optional[SpanFile]:
     """``<directory>/<basename>.spans.jsonl``, flushed every
     ``EDL_SCHED_PHASE_SECS`` (2 s by default, and where that knob turns
-    the phase stats off). With no directory nothing is written and the
-    ring is all there is."""
+    the phase stats off); ``basename`` is the role its ``proc.stall``
+    spans name, and the process's long collections are recorded from
+    here on (``proc.gc``). With no directory nothing is written and
+    the ring is all there is."""
     if not directory:
         return None
+    watch_gc()
     try:
         period = float(os.environ.get(ENV_SCHED_PHASE_SECS, "") or 2.0)
     except ValueError:
@@ -572,7 +643,7 @@ def start_span_file(directory: str, basename: str) -> Optional[SpanFile]:
         period = 2.0
     os.makedirs(directory, exist_ok=True)
     path = os.path.join(directory, f"{basename}.spans.jsonl")
-    return SpanFile(path, period).start()
+    return SpanFile(path, period, role=basename).start()
 
 
 def load_span_file(path: str) -> List[Dict[str, Any]]:

@@ -126,6 +126,10 @@ TRANSPORT_INPROC = "inproc"
 #: not a tier.
 TRANSPORT_TIERS = (TRANSPORT_GRPC, TRANSPORT_UDS, TRANSPORT_INPROC)
 
+# a served call this long, frame received to response packed, is one
+# `rpc.server.slow` span on the serving process's timeline
+SLOW_CALL_SECS = 0.25
+
 _LOCAL_HOSTS = frozenset(
     {"localhost", "127.0.0.1", "[::1]", "::1", "0.0.0.0", "[::]", ""}
 )
@@ -345,6 +349,34 @@ class ServerDispatcher:
         self, method: str, request_bytes, transport: str, t_admit=None,
         recv_reused=False,
     ) -> messages.PackedParts:
+        """Unpack, handle, pack. A call of any method that takes over
+        `SLOW_CALL_SECS` from the frame's receipt (the loop core: from
+        admission) to the packed response, or to the error that ends
+        it, leaves one `rpc.server.slow` span on the process's
+        timeline: `queued_ms` up to the handler's start, `handled_ms`
+        inside it. A call under it leaves nothing."""
+        t_recv = time.time() if t_admit is None else t_admit
+        handler = []  # `_handle` leaves the handler's start and end here
+        try:
+            return self._handle(
+                method, request_bytes, transport, t_admit, recv_reused, handler
+            )
+        finally:
+            now = time.time()
+            if now - t_recv > SLOW_CALL_SECS:
+                begun, done = (handler + [now, now])[:2]  # as far as it came
+                obs_trace.record_phase(
+                    "rpc.server.slow", t_recv, now - t_recv,
+                    {
+                        "method": method,
+                        "queued_ms": round((begun - t_recv) * 1e3, 1),
+                        "handled_ms": round((done - begun) * 1e3, 1),
+                    },
+                )
+
+    def _handle(
+        self, method, request_bytes, transport, t_admit, recv_reused, handler
+    ) -> messages.PackedParts:
         from elasticdl_tpu.rpc.fencing import EpochFencedError
 
         fn = self._handlers.get(method)
@@ -389,6 +421,7 @@ class ServerDispatcher:
                     args={"method": method},
                 )
         prev_ctx = obs_trace.bind(sp.ctx) if sp is not None else None
+        handler.append(time.time())  # begins
         try:
             try:
                 resp = fn(req) if req is not None else fn({})
@@ -414,6 +447,7 @@ class ServerDispatcher:
                     grpc.StatusCode.INTERNAL, _sanitized_detail(e)
                 )
         finally:
+            handler.append(time.time())  # and ends
             if sp is not None:
                 obs_trace.bind(prev_ctx)
                 sp.end()

@@ -62,7 +62,7 @@ from elasticdl_tpu.common import sync_policy
 from elasticdl_tpu.common.linkprobe import LinkWeather
 from elasticdl_tpu.common.device import device_report
 from elasticdl_tpu.common.log_util import get_logger
-from elasticdl_tpu.common.timing import PhaseTimers
+from elasticdl_tpu.common.timing import DeviceRuns, PhaseTimers
 from elasticdl_tpu.obs import hlo_scopes
 from elasticdl_tpu.obs import trace as obs_trace
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
@@ -541,6 +541,15 @@ class Worker:
         # timeline (docs/observability.md): the sink is handed down
         # here, common/timing.py knows nothing of obs/.
         self.timers = PhaseTimers(sink=obs_trace.record_phase)
+        # one `worker.device_run` a call of a training program; the
+        # memory is the first device's of the mesh, or of the process
+        self._device_runs = DeviceRuns(
+            self.timers, jax.block_until_ready,
+            (
+                mesh.devices.flat[0] if mesh is not None
+                else jax.local_devices()[0]
+            ).memory_stats,
+        )
         self._first_run = deque(maxlen=1)
         # policy-plane telemetry: the run loop ships cumulative timer
         # snapshots to the master every N seconds (ReportPhaseStats —
@@ -917,6 +926,7 @@ class Worker:
         loss=None,
         version=None,
         shard_base=None,
+        run=None,
     ):
         """Returns (response, loss_value). ONE batched d2h round
         (device_get) moves gradient + aux + loss together — per-item
@@ -927,7 +937,8 @@ class Worker:
         values captured at COMPUTE time — the pipelined path absorbs a
         newer model between compute and send, and reporting the newer
         version for an older gradient would corrupt the PS's staleness
-        accounting."""
+        accounting. `run` is the step's `worker.device_run`, stamped
+        where this waits for the step anyway."""
         wire_meta = None
         if flat and self._sync_dtype in ("bfloat16", "int8"):
             # quantize ON DEVICE before the d2h round: shrinks the
@@ -936,6 +947,8 @@ class Worker:
         fetch = (grads, aux_state or None, loss)
         with self.timers.span("worker.delta_wait"):
             jax.block_until_ready(fetch)  # the step itself
+            if run is not None:
+                self._device_runs.ready(run)
         self._first_run_settled()
         with self.timers.span("worker.d2h"):
             grads_h, aux_h, loss_h = jax.device_get(fetch)
@@ -1731,6 +1744,8 @@ class Worker:
                 self._flat, self._opt_state, new_aux, loss = (
                     self._local_step_fn(*args)
                 )
+        # no wait for a step's end stands on this path
+        self._device_runs.watch(self._device_runs.asked("jit_step", 1), loss)
         self._aux = new_aux or self._aux
         self._pending_steps += 1
         self._latest_step_loss = loss
@@ -1860,10 +1875,16 @@ class Worker:
         not when it runs, so what is asked for ahead of the device
         lies beside what is running. On the serial chain, whose step
         loop waits for the window's sync next anyway, the join waits
-        for the window's end: its vector never lies beside the
-        window's temporaries. With syncs in flight the step loop stays
-        ahead, but by one window: a cut waits for the window before
-        it."""
+        for the window's end (`worker.window_wait`): its vector never
+        lies beside the window's temporaries. With syncs in flight the
+        step loop stays ahead, but by one window: a cut waits for the
+        window before it.
+
+        The call's `worker.device_run` is stamped at that wait of the
+        serial chain, which is reached before the device ends; every
+        other form hands the loss to the watcher (`DeviceRuns.watch`):
+        the flat carry waits nowhere, and the overlapped chain's wait
+        for `_window_ahead` comes a window's staging late."""
         window = self._local_window_fn
         first_call = self._first_call if timed else (lambda *a, **k: _NO_SPAN)
         leaves = carries_leaves(self._template)
@@ -1899,12 +1920,18 @@ class Worker:
             carried=len(jax.tree_util.tree_leaves((model, state))),
         ):
             model, state, aux, loss = window(*args)
+        runs = self._device_runs
+        run = runs.asked("jit_window", self._local_updates)
         if not leaves:
+            runs.watch(run, loss)
             return model, state, aux, loss
         if self._max_inflight_syncs:
             self._window_ahead = loss
+            runs.watch(run, loss)
         else:
-            jax.block_until_ready(loss)
+            with self.timers.span("worker.window_wait", seq=run["seq"]):
+                jax.block_until_ready(loss)
+                runs.ready(run)
         with first_call(join_fn, (model,)):
             flat = join_fn(model)
         for leaf in jax.tree_util.tree_leaves(model):
@@ -2046,6 +2073,10 @@ class Worker:
             self._flush_deferred_reports()
             return
         t_spawn = time.time()  # `worker.window_sync` starts here
+        # the last `worker.device_run` this sync carries: the whole and
+        # every part of it say so, and a reader joins them by it (a
+        # finished sync's thread hands its id on to a later one)
+        run_seq = self._device_runs.seq
         delta_f32_bytes = int(self._flat.shape[0]) * 4
         # a plain float32 delta for the single master, longer than one
         # slice, leaves the device in slices (worker/delta_stream.py)
@@ -2079,7 +2110,7 @@ class Worker:
             wire_form = sync_policy.decide(
                 link_mbps, delta_f32_bytes, self._sync_decisions
             )
-        wspan_args = {"worker": self._id}
+        wspan_args = {"worker": self._id, "seq": run_seq}
         if wire_form is not None:
             # the round's decision rides the window span for the
             # critical-path/decision audits
@@ -2097,7 +2128,7 @@ class Worker:
             # consumes the residual its predecessor left — the wire
             # carries bf16/int8/top-k but the SUM of what the PS
             # applies tracks the f32 trajectory (see _ef_quantize_delta)
-            with self._chain_span("worker.quantize", parent=wctx):
+            with self._chain_span("worker.quantize", parent=wctx, seq=run_seq):
                 wire_meta, delta_dev = self._ef_quantize_delta(
                     delta_dev, form=wire_form
                 )
@@ -2182,7 +2213,7 @@ class Worker:
 
         def do_sync_work():
             if prev is not None:
-                with self.timers.span("worker.chain_wait"):
+                with self.timers.span("worker.chain_wait", seq=run_seq):
                     prev.join()
             with self._report_lock:
                 if self._sync_error is not None or epoch != self._sync_epoch:
@@ -2201,7 +2232,7 @@ class Worker:
                 step_loss,
                 [g for _, g in pending_edl],
             )
-            with self._chain_span("worker.delta_wait"):
+            with self._chain_span("worker.delta_wait", seq=run_seq):
                 # the device finishes the window and the delta; what
                 # follows is the copy out alone
                 jax.block_until_ready(
@@ -2211,7 +2242,7 @@ class Worker:
             stream = None
             if slice_bounds is None:
                 with self._chain_span(
-                    "worker.d2h", bytes=delta_f32_bytes, slices=1
+                    "worker.d2h", bytes=delta_f32_bytes, slices=1, seq=run_seq
                 ):
                     delta_h, small_h = jax.device_get((delta_dev, small))
             else:
@@ -2241,7 +2272,7 @@ class Worker:
                 # LM's mean exit distribution), on the timeline
                 now = time.time()
                 self.timers.record_span(
-                    "worker.window_stats", now, now, steps=steps, **{
+                    "worker.window_stats", now, now, steps=steps, seq=run_seq, **{
                         k: np.asarray(v, np.float64).round(6).tolist()
                         for k, v in stats.items()
                     },
@@ -2249,7 +2280,7 @@ class Worker:
             if wire_meta is not None:
                 # compressed payload: build the codec wire object
                 # from the host copies (device math ran at spawn)
-                with self._chain_span("worker.encode"):
+                with self._chain_span("worker.encode", seq=run_seq):
                     delta_h = self._materialize_wire_delta(
                         wire_meta, delta_h
                     )
@@ -2359,6 +2390,7 @@ class Worker:
                         self.timers.record_span(
                             "worker.d2h", t_asked, t_landed,
                             bytes=delta_f32_bytes, slices=len(slice_bounds),
+                            seq=run_seq,
                         )
                 self._observe_push(delta_h, push_t0, wire_form)
             with self._report_lock:
@@ -2410,12 +2442,14 @@ class Worker:
                     if k <= seq and k != pending:
                         del self._base_snapshots[k]
             self._record_synced_losses(losses, loss_h, resp["version"])
-            with self.timers.span("worker.flush_reports"):
+            with self.timers.span("worker.flush_reports", seq=run_seq):
                 self._flush_deferred_reports()
 
         # the step loop's own part of the sync: the delta and the new
         # base dispatched, the quantize, the bookkeeping above
-        self.timers.record_span("worker.sync_spawn", t_spawn, time.time())
+        self.timers.record_span(
+            "worker.sync_spawn", t_spawn, time.time(), seq=run_seq
+        )
         if blocking:
             try:
                 with self._sync_exposed("flush"):
@@ -3037,9 +3071,8 @@ class Worker:
     @contextlib.contextmanager
     def _sync_exposed(self, reason: str):
         """Span-mark wall time the STEP LOOP is blocked on the sync
-        plane (joins, blocking pulls, backpressure, drains). These are
-        root spans so `sync_exposed_fraction_from_spans`
-        (obs/critical_path.py) can sum exactly the sync wall that
+        plane (joins, blocking pulls, backpressure, drains): root
+        spans, so that a reader can sum exactly the sync wall that
         stayed ON the critical path — the quantity the overlap plane
         exists to shrink."""
         with self._chain_span("worker.sync_exposed", root=True, reason=reason):
@@ -3284,6 +3317,7 @@ class Worker:
             loss, gparams, gbets, new_aux = step(
                 self._step_params(), self._aux, embs, features, labels
             )
+            run = self._device_runs.asked("jit_step", 1)
             edl_grads = {
                 name: extract_indexed_grads(
                     self._emb_specs[name], np.asarray(gbets[name]), embs[name]
@@ -3295,7 +3329,8 @@ class Worker:
                 # device arrays go straight into the batched d2h inside
                 # report_gradient (gradient + aux + loss in one round)
                 resp, loss_h = self.report_gradient(
-                    gparams, edl_grads, new_aux, flat=flat, loss=loss
+                    gparams, edl_grads, new_aux, flat=flat, loss=loss,
+                    run=run,
                 )
             self._absorb_report_response(resp)
             if resp["accepted"]:
@@ -3343,6 +3378,7 @@ class Worker:
         loss, gparams, _gbets, new_aux = step(
             self._step_params(), self._aux, embs, features, labels
         )
+        run = self._device_runs.asked("jit_step", 1)
         with self._report_lock:
             compute_version = self._version
             shard_base = (
@@ -3360,6 +3396,7 @@ class Worker:
                     loss=loss,
                     version=compute_version,
                     shard_base=shard_base,
+                    run=run,
                 )
             except Exception as e:  # re-raised at the next join
                 box["err"] = e
@@ -3675,6 +3712,7 @@ class Worker:
             features,
             labels,
         )
+        self._device_runs.watch(self._device_runs.asked("jit_step", 1), out[3])
         jax.device_get(out[3])
 
     def warmup_sync_step(self, features, labels):
@@ -3688,6 +3726,7 @@ class Worker:
         out = self._train_step(
             self._step_params(), self._aux, {}, features, labels
         )
+        self._device_runs.watch(self._device_runs.asked("jit_step", 1), out[0])
         jax.device_get(out[0])
 
     def _warmup_params(self, features):
@@ -3887,3 +3926,4 @@ class Worker:
                 self._ps.close()
             if self._kv is not None:
                 self._kv.close()
+            self._device_runs.close()

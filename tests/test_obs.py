@@ -1,8 +1,8 @@
 """Observability plane (elasticdl_tpu/obs/): span propagation across
 every transport tier, SpanRecorder ring bounds under concurrent
 writers, the Prometheus text golden, flight-recorder causal order,
-the GetTrace/GetMetrics RPC surface, the span-derived critical-path
-decomposition, and the disabled-path overhead guard.
+the GetTrace/GetMetrics RPC surface, and the overhead guards: the
+disabled path, a phase with a sink, one `worker.device_run`.
 """
 
 import json
@@ -13,7 +13,6 @@ import pytest
 
 from elasticdl_tpu.common.constants import ENV_TRANSPORT, ENV_UDS_DIR
 from elasticdl_tpu.obs import flight, metrics, trace
-from elasticdl_tpu.obs.critical_path import sync_critical_path_from_spans
 from elasticdl_tpu.obs.fetch import fetch_metrics, fetch_trace
 from elasticdl_tpu.rpc.client import RpcClient
 from elasticdl_tpu.rpc.server import RpcServer
@@ -296,67 +295,6 @@ def test_crash_dump_on_thread_exception(tmp_path):
     )
 
 
-# -- critical-path decomposition ---------------------------------------------
-
-
-def _span(name, dur, trace_id="t1", span_id="s", parent=None):
-    return {
-        "name": name,
-        "cat": "test",
-        "ts": 0.0,
-        "dur": dur,
-        "trace_id": trace_id,
-        "span_id": span_id,
-        "parent_id": parent,
-        "pid": 1,
-        "tid": 1,
-        "args": {},
-    }
-
-
-def test_sync_critical_path_components_sum_within_bound():
-    spans = [
-        _span("worker.window_sync", 1.0),
-        _span("worker.quantize", 0.10),
-        _span("worker.encode", 0.30),
-        _span("rpc.client.ReportLocalUpdate", 0.55),
-        _span("rpc.server.ReportLocalUpdate", 0.40),
-        _span("rpc.admission_wait", 0.05),
-        _span("ps.apply", 0.35),
-        # a separate pull trace must NOT leak into the chain accounting
-        _span("worker.pull", 5.0, trace_id="t2"),
-        _span("rpc.client.GetModel", 4.0, trace_id="t2"),
-    ]
-    cp = sync_critical_path_from_spans(spans)
-    assert cp["rounds"] == 1
-    assert cp["encode_s"] == pytest.approx(0.40)
-    assert cp["queue_wait_s"] == pytest.approx(0.05)
-    assert cp["apply_s"] == pytest.approx(0.35)
-    assert cp["wire_s"] == pytest.approx(0.10)
-    assert cp["combine_s"] is None
-    assert "combine_s_skipped_reason" in cp
-    assert 0.9 <= cp["sum_fraction"] <= 1.1
-
-
-def test_sync_critical_path_fanin_combine_component():
-    spans = [
-        _span("worker.window_sync", 1.0),
-        _span("worker.encode", 0.20),
-        _span("rpc.client.ReportLocalUpdate", 0.75),
-        _span("rpc.server.ReportLocalUpdate", 0.70),
-        _span("fanin.park", 0.65),
-        _span("ps.apply", 0.40),
-    ]
-    cp = sync_critical_path_from_spans(spans)
-    assert cp["combine_s"] == pytest.approx(0.25)  # park minus apply
-    assert "combine_s_skipped_reason" not in cp
-    assert 0.9 <= cp["sum_fraction"] <= 1.1
-
-
-def test_sync_critical_path_none_without_roots():
-    assert sync_critical_path_from_spans([_span("ps.apply", 1.0)]) is None
-
-
 # -- disabled-path overhead guard --------------------------------------------
 
 
@@ -407,4 +345,30 @@ def test_tracing_off_is_near_free():
     phase_cost = (time.perf_counter() - t0) / n
     assert phase_cost < 50e-6, f"phase() with a sink {phase_cost * 1e6:.1f}us"
     assert len(trace.RECORDER) <= trace._DEFAULT_CAPACITY  # still bounded
+    trace.RECORDER.clear()
+
+
+@pytest.mark.perf
+def test_a_device_run_costs_its_caller_microseconds():
+    """`worker.device_run` is always on, once a call of a training
+    program: `asked` (one `memory_stats()`) and `ready` are a few dict
+    writes, two `time.time()`, one lock and one striped append. The same loose bound as a phase with a sink; the chip's
+    own `memory_stats()` is measured there (CHANGES.md, PR 54)."""
+    from elasticdl_tpu.common.timing import DeviceRuns, PhaseTimers
+
+    stats = {"bytes_in_use": 1, "bytes_reserved": 2}
+    runs = DeviceRuns(
+        PhaseTimers(sink=trace.record_phase), lambda result: None,
+        lambda: stats,
+    )
+    n = 20_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        runs.ready(runs.asked("jit_window", 8))
+    cost = (time.perf_counter() - t0) / n
+    assert cost < 50e-6, f"one worker.device_run {cost * 1e6:.1f}us"
+    (last,) = [s for s in trace.RECORDER.snapshot()
+               if s["args"].get("seq") == n]
+    assert last["args"]["bytes_in_use"] == 1
+    assert len(trace.RECORDER) <= trace._DEFAULT_CAPACITY
     trace.RECORDER.clear()

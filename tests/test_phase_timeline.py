@@ -578,6 +578,60 @@ def test_jitted_programs_keep_the_names_the_device_trace_shows():
     }
     assert names == {"run"}  # the closures that call jit_step
     assert (jnp.copy(flat) - flat).shape == (2,)  # jit_copy, jit_subtract: eager
+    # `worker.device_run` stands beside the call, round nothing: after a
+    # call the worker still holds the jitted callable itself, and the
+    # span's `program` is the name the lowered module carries
+    worker._flat, worker._opt_state = flat, opt_state
+    worker._local_window_fn = window
+    worker._run_window(flat, opt_state, worker._aux, stacked, stacked)
+    worker._device_runs.close()
+    assert worker._local_window_fn is window
+    deadline = time.time() + 10
+    while not _spans("worker.device_run") and time.time() < deadline:
+        time.sleep(0.01)
+    (run,) = _spans("worker.device_run")
+    assert "module @" + run["args"]["program"] in lowered.as_text()
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """jax's persistent compile cache in a directory of the test's own,
+    taking every program however small."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path / "cache"), 0, -1)):
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("local_updates", [2, 0])
+def test_a_second_worker_s_training_program_is_a_compile_cache_hit(
+    tmp_path, monkeypatch, compile_cache, local_updates
+):
+    """The cache keys on the module: a second worker of this process
+    traces `jit_window` / `jit_step` anew and is served the first one's
+    executable, device runs recorded or not."""
+    hits = []
+    for _ in range(2):
+        trace.RECORDER.clear()
+        _train(tmp_path, monkeypatch, local_updates)
+        (program,) = [
+            s["args"] for s in _spans("setup.program")
+            if s["args"]["program"] in ("jit_window", "jit_step")
+        ]
+        assert program["compiles"] == 1
+        hits.append(program["cache_hit"])
+        assert _spans("worker.device_run")
+    assert hits == [False, True]
 
 
 def test_a_program_s_first_call_is_one_setup_span_at_its_call_site():
@@ -678,15 +732,19 @@ def test_a_worker_s_run_is_on_the_timeline(tmp_path, monkeypatch, local_updates)
         spawns = {s["ts"] for s in _spans("worker.sync_spawn")}
         assert {s["ts"] for s in syncs} == spawns
         assert len(_spans("apply")) == len(syncs)  # the master's side
-        # inside one sync its parts follow each other, none in another
+        # inside one sync its parts follow each other, none in another;
+        # they are the sync's by `seq`, the last `worker.device_run` it
+        # carries (a thread id is handed on by a finished sync's thread)
         sync = syncs[-1]
+        runs = _spans("worker.device_run")
+        assert sync["args"]["seq"] == max(r["args"]["seq"] for r in runs)
         parts = sorted(
-            (s for s in _spans() if s["tid"] == sync["tid"]
-             and s["name"].startswith("worker.")  # not the in-process
-             and s["name"] != "worker.window_sync"  # master's own spans
-             and sync["ts"] <= s["ts"] <= sync["ts"] + sync["dur"]),
+            (s for s in _spans() if s["args"].get("seq") == sync["args"]["seq"]
+             and s["name"] not in ("worker.window_sync", "worker.device_run",
+                                   "worker.sync_spawn", "worker.window_wait")),
             key=lambda s: s["ts"],
         )
+        assert {s["tid"] for s in parts} == {sync["tid"]}
         assert [s["name"] for s in parts if s["name"] != "worker.chain_wait"] == [
             "worker.delta_wait", "worker.d2h", "worker.flush_reports",
         ]  # an in-process master: no wire, so no rpc.client.*
@@ -731,11 +789,6 @@ def test_phase_stats_payload_is_what_it_was(monkeypatch):
 def test_a_sampled_interval_is_recorded_once_and_serves_both_readers(
     tmp_path, monkeypatch
 ):
-    from elasticdl_tpu.obs.critical_path import (
-        sync_critical_path_from_spans,
-        sync_exposed_fraction_from_spans,
-    )
-
     trace.configure(1.0)  # every chain is sampled
     _train(tmp_path, monkeypatch, local_updates=2)
     spans = _spans()
@@ -757,33 +810,36 @@ def test_a_sampled_interval_is_recorded_once_and_serves_both_readers(
         assert s["parent_id"] == roots[s["trace_id"]]
     # the step loop's phases belong to no trace
     assert "trace_id" not in by_name["compute"][0]
-    # both readers of the sync chain work on the ring as it is, and
-    # `critical_path` takes its roots by trace id
-    cp = sync_critical_path_from_spans(spans)
-    assert cp["rounds"] == len(syncs)
-    assert cp["sync_wait_s"] == pytest.approx(sum(s["dur"] for s in syncs), abs=1e-5)
-    assert cp["encode_s"] == pytest.approx(sum(
-        s["dur"] for n in ("worker.delta_wait", "worker.d2h")
-        for s in by_name[n]
-    ), abs=1e-5)
-    exposed = sync_exposed_fraction_from_spans(spans, total_wall_s=10.0)
-    assert exposed["stalls"] == len(by_name["worker.sync_exposed"])
+    # both ways of joining a sync's chain work on the ring as it is:
+    # the trace's ids and the timeline's `seq` name the same parts
+    for sync in syncs:
+        by_trace = {
+            (s["name"], s["ts"]) for s in spans
+            if s.get("trace_id") == sync["trace_id"]
+            and s["name"] in ("worker.delta_wait", "worker.d2h")
+        }
+        by_seq = {
+            (s["name"], s["ts"]) for s in spans
+            if s["args"].get("seq") == sync["args"]["seq"]
+            and s["name"] in ("worker.delta_wait", "worker.d2h")
+        }
+        assert by_trace == by_seq and len(by_seq) == 2
+    assert len({s["args"]["seq"] for s in syncs}) == len(syncs)
     # with sampling off the same spans are there and start no trace
     trace.RECORDER.clear()
     trace.configure(0.0)
     _train(tmp_path, monkeypatch, local_updates=2)
     assert _spans("worker.window_sync")
-    assert sync_critical_path_from_spans(_spans()) is None
+    assert not [s for s in _spans() if s.get("trace_id")]
 
 
-def test_critical_path_still_sums_within_its_gate_over_the_wire(tmp_path):
-    """A sampled sync over a real gRPC hop: the chain's components
-    (encode incl. the device wait and the copy out, the client's pack,
-    round trip and unpack, the master's apply) sum within 10 % of the
-    chain's wall, with the queue behind earlier syncs taken out."""
+def test_a_sync_s_parts_by_seq_sum_within_its_gate_over_the_wire(tmp_path):
+    """A sync over a real gRPC hop: its parts, joined by `seq` (the
+    device wait, the copy out, the report flush) and, for the round
+    trip, which carries none, by its thread between them, sum within
+    10 % of the sync's wall, the queue behind earlier syncs taken out."""
     from elasticdl_tpu.api.model_spec_helpers import spec_from_module
     from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
-    from elasticdl_tpu.obs.critical_path import sync_critical_path_from_spans
     from elasticdl_tpu.testing import write_linear_records
     from elasticdl_tpu.worker.worker import Worker
     from tests.fixtures import linear_module
@@ -818,14 +874,27 @@ def test_critical_path_still_sums_within_its_gate_over_the_wire(tmp_path):
     syncs = [s for s in spans if s["name"] == "worker.window_sync"]
     assert len(syncs) == 256 // 32
     assert len({s["trace_id"] for s in syncs}) == len(syncs)
-    queued = sum(s["dur"] for s in spans if s["name"] == "worker.chain_wait")
-    cp = sync_critical_path_from_spans(spans)
-    parts = sum(cp[k] or 0.0 for k in (
-        "encode_s", "queue_wait_s", "combine_s", "apply_s", "wire_s",
-        "serve_other_s",
-    ))
-    own = cp["sync_wait_s"] - queued
-    assert 0.9 <= parts / own <= 1.1, (cp, queued)
+    own = parts = 0.0
+    for sync in syncs:
+        mine = {
+            s["name"]: s for s in spans
+            if s["args"].get("seq") == sync["args"]["seq"]
+        }
+        assert {"worker.sync_spawn", "worker.delta_wait", "worker.d2h",
+                "worker.flush_reports"} <= set(mine)
+        (trip,) = [
+            s for s in spans if s["name"] == "rpc.client.ReportLocalUpdate"
+            and s["tid"] == sync["tid"]
+            and mine["worker.d2h"]["ts"] <= s["ts"]
+            <= mine["worker.flush_reports"]["ts"]
+        ]
+        queued = mine.get("worker.chain_wait", {"dur": 0.0})["dur"]
+        own += sync["dur"] - queued
+        parts += trip["dur"] + sum(
+            mine[n]["dur"] for n in ("worker.sync_spawn", "worker.delta_wait",
+                                     "worker.d2h", "worker.flush_reports")
+        )
+    assert 0.9 <= parts / own <= 1.1, (parts, own)
 
 
 def test_a_sampled_update_rpc_is_one_client_span_with_the_trace_s_ids():
@@ -849,7 +918,7 @@ def test_a_sampled_update_rpc_is_one_client_span_with_the_trace_s_ids():
     assert served["trace_id"] == pull["trace_id"]
     assert served["parent_id"] == pull["span_id"]
     # the pack and the unpack are the client span's children: the trace
-    # still accounts for them (`critical_path` sums `rpc.client.*`)
+    # still accounts for them
     for name in ("rpc.client.encode", "rpc.client.decode"):
         (part,) = _spans(name)
         assert part["trace_id"] == pull["trace_id"]
