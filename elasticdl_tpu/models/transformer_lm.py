@@ -65,10 +65,6 @@ class TransformerConfig:
     # per-layer carry, buying ~3x larger batch/depth per chip for ~1/3
     # extra forward FLOPs — the standard HBM<->FLOPs trade
     remat: bool = False
-    # selective remat: "dots" saves matmul outputs and recomputes only
-    # the cheap elementwise ops (gelu/layernorm/softmax) — most of full
-    # remat's memory win at a few percent of its recompute cost
-    remat_policy: str = ""  # "" (full) | "dots" 
     # -- the unsharded block's further settings (plain_forward only; the
     # mesh path refuses them, `_require_mesh_support`) ----------------
     rope_base: float = 10000.0
@@ -328,14 +324,19 @@ _OVER_LAYERS = {
 }
 
 
-def _remat(body, cfg: "TransformerConfig"):
-    """Per-layer rematerialization with the configured policy."""
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    return jax.checkpoint(body)
+def _remat(body):
+    """Per-layer rematerialization: the backward pass recomputes the
+    layer from its input, all but the attention kernels' output and
+    log-sum-exp, which it keeps (`flash_attention.RESIDUAL_NAMES`: at
+    most four times the layer input, and the forward kernel is not run
+    a second time). Off the kernels no such name exists and nothing is
+    kept."""
+    from elasticdl_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    return jax.checkpoint(
+        body,
+        policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES),
+    )
 
 
 # ------------------------------------------------------------------- params
@@ -728,7 +729,7 @@ def _stage(cfg, stage_params, x, positions):
         return (h, aux + a), None
 
     if cfg.remat:
-        body = _remat(body, cfg)
+        body = _remat(body)
 
     # promote the carry to the block output's varying axes (params vary
     # over pp, so the first block output does too); probe is DCE'd
@@ -1230,7 +1231,7 @@ def plain_forward_stats(
             return (h, aux), stats
 
         if cfg.remat or cfg.looped:
-            return _remat(body, cfg)
+            return _remat(body)
         return body
 
     def run_trees(tree):

@@ -55,6 +55,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -68,6 +69,13 @@ TILE_LADDER = (1024, 512, 256, 128)
 # (the pipeline's two buffers), its accumulators and the float32 score
 # tile with its copies (s, p, dp, ds: 4 MiB each at 1024 x 1024).
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# The names (`jax.ad_checkpoint.checkpoint_name`) of the forward
+# kernel's output and log-sum-exp among its backward rule's residuals:
+# O(L*Dv) a head. A `jax.checkpoint` whose policy saves these names
+# (`transformer_lm._remat`) keeps the two and its backward pass does not
+# run the forward kernel again; q, k and v are recomputed as the rest of
+# the layer is. Under no `jax.checkpoint` a name is an identity.
+RESIDUAL_NAMES = ("flash_attention_out", "flash_attention_lse")
 _NEG_INF = -1e30
 
 
@@ -510,7 +518,11 @@ def _flash_attention(q, k, v, causal: bool, interpret: bool, tiles, band,
 
 
 def _fa_fwd(q, k, v, causal, interpret, tiles, band, scale):
-    o, lse = _flash_forward(q, k, v, causal, interpret, tiles, band, scale)
+    o, lse = map(
+        checkpoint_name,
+        _flash_forward(q, k, v, causal, interpret, tiles, band, scale),
+        RESIDUAL_NAMES,
+    )
     return o, (q, k, v, o, lse)
 
 

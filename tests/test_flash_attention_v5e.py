@@ -84,3 +84,38 @@ def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
     # nothing quadratic in L is set aside: O(L*D) residuals and copies
     b, L, h, d = shape
     assert compiled.memory_analysis().temp_size_in_bytes < 20 * b * L * h * max(d, 128)
+
+
+@pytest.mark.parametrize("remat, calls", [("_remat", 3), ("checkpoint", 4)])
+def test_a_rematerialised_layer_keeps_the_forward_kernels_outputs(
+    one_chip, no_compile_cache, remat, calls
+):
+    """Under `transformer_lm._remat` the backward pass recomputes the
+    projections and holds the two backward kernels; under
+    `jax.checkpoint` alone it holds the forward kernel a second time."""
+    from elasticdl_tpu.models import transformer_lm as lm
+
+    b, L, h, d = 2, 2048, 4, 128  # read in place, 1024 x 1024 tiles
+    x = jax.ShapeDtypeStruct((b, L, h * d), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, h * d, h * d), jnp.bfloat16, sharding=one_chip)
+    tiles = fa.pick_tiles(L)
+
+    def body(w, x):
+        with jax.named_scope("attention"):
+            q, k, v = ((x @ w[i]).reshape(b, L, h, d) for i in range(3))
+            o = fa._flash_attention(q, k, v, True, False, tiles, None, d ** -0.5)
+            return x + o.reshape(b, L, h * d) @ w[3]
+
+    wrap = lm._remat if remat == "_remat" else jax.checkpoint
+
+    def loss(w, x):
+        return jnp.sum(wrap(body)(w, x).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(w, x).compile().as_text()
+    assert hlo_scopes.kernels(text) == {"attention": calls}
+    recomputed = [
+        line for line in text.splitlines() if "rematted_computation" in line
+    ]
+    assert recomputed  # the projections, a second time
+    again = [line for line in recomputed if hlo_scopes._KERNEL in line]
+    assert len(again) == calls - 3
