@@ -69,7 +69,9 @@ class TransformerConfig:
     # mesh path refuses them, `_require_mesh_support`) ----------------
     rope_base: float = 10000.0
     norm_eps: float = 1e-6
-    # "gelu": w2(gelu(w1 x)); "swiglu": wd(silu(wg x) * wu x), no bias
+    # "gelu": w2(gelu(w1 x)); "swiglu": wd(silu(wg x) * wu x), no bias;
+    # "relu2": wd relu(wu x)^2, two matrices and no gate (the routed
+    # stack's experts and shared expert only)
     mlp: str = "gelu"
     # four norms a layer: h + ln1b(attn(ln1(h))), h + ln2b(mlp(ln2(h)))
     sandwich_norm: bool = False
@@ -116,11 +118,18 @@ class TransformerConfig:
     # projected
     mla_rope: bool = True
     # the mixer of every layer of the stack, dense layers first:
-    # "mha", "swa", "mla", "kda", "gdn" or "conv"; None = `attention`
-    # in every layer.
-    # Layers that follow each other with one mixer and one kind of MLP
-    # are one scanned run
+    # "mha", "swa", "mla", "kda", "gdn", "conv" or "mamba2"; None =
+    # `attention` in every layer.
+    # Layers that follow each other with one mixer and one kind of
+    # feed-forward part are one scanned run. A layer named in
+    # `bare_layers` (its index in the stack) is its mixer ALONE,
+    # h + mixer(ln1(h)): no feed-forward part, no `ln2`, no `mlp` or
+    # `moe` scope. (A model whose published blocks are each a mixer OR a
+    # feed-forward part is written so: a mixer block and the
+    # feed-forward block behind it are one layer here, a mixer block
+    # that no feed-forward block follows a bare one)
     layer_types: Optional[Tuple[str, ...]] = None
+    bare_layers: Tuple[int, ...] = ()
     # "kda": gated delta-rule linear attention (Kimi Delta Attention):
     # `kda_heads` heads of `kda_head_dim` (keys and values alike), a
     # causal depthwise convolution of `kda_conv` taps on q, k and v, a
@@ -182,6 +191,27 @@ class TransformerConfig:
     # the shared expert behind a gate of its own, one number a token:
     # y = routed + sigmoid(x . sgate) S(x)
     shared_expert_gate: bool = False
+    # "mha" and "swa": False turns nothing, queries and keys enter the
+    # scores as projected (`mla_rope` is latent attention's)
+    rope: bool = True
+    # "mamba2": the selective state-space mixer (Mamba-2): `ssm_heads`
+    # heads of `ssm_head_dim` (the inner width their product) over a
+    # state of `ssm_state`, B and C in `ssm_groups` groups (head j reads
+    # group j // (heads / groups)); ONE projection z | x | B | C | dt, a
+    # causal depthwise convolution of `ssm_conv` taps WITH a bias over
+    # x | B | C, then SiLU; dt = softplus(dt + dt_bias), the log-decay
+    # dt x -exp(A_log), one number a head and token; the recurrence in
+    # chunks of `ssm_chunk` (`ops/ssd.py`) plus D x; the output times
+    # SiLU(z), then RMS-normed over each group's channels (one weight of
+    # the inner width), then projected. `ssm_residual_blocks` > 0
+    # divides the output projection's initial values by its root
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_residual_blocks: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -196,11 +226,14 @@ class TransformerConfig:
         return tuple(self.layer_types or (self.attention,) * self.n_layers)
 
     @property
-    def runs(self) -> Tuple[Tuple[str, bool, int], ...]:
-        """The stack as scanned runs: (mixer, expert layer?, layers)."""
+    def runs(self) -> Tuple[Tuple[str, Optional[bool], int], ...]:
+        """The stack as scanned runs: (mixer, expert layer?, layers);
+        the second is None for layers with no feed-forward part."""
         runs = []
         for i, mixer in enumerate(self.mixers):
-            kind = (mixer, bool(self.n_experts) and i >= self.n_dense_layers)
+            kind = (mixer, None if i in self.bare_layers else (
+                bool(self.n_experts) and i >= self.n_dense_layers
+            ))
             if runs and runs[-1][:2] == kind:
                 runs[-1] = kind + (runs[-1][2] + 1,)
             else:
@@ -302,10 +335,22 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
     return scale
 
 
+def _expert_leaves(mlp: str):
+    """(a routed expert's leaves, the shared expert's), in the order
+    the layer takes them: a SwiGLU's gate, up and down, a squared-ReLU
+    expert's up and down."""
+    if mlp == "relu2":
+        return ("eu", "ed"), ("su", "sd")
+    return ("eg", "eu", "ed"), ("sg", "su", "sd")
+
+
+# every mixer the routed stack builds (`_init_routed_params`)
+ROUTED_MIXERS = ("mla", "kda", "gdn", "conv", "mamba2", "mha", "swa")
 # a run's leaves that the forward pass reads as stored (float32) and not
 # as cast to the compute dtype
 _FLOAT32_LEAVES = (
     "router", "router_bias", "dt_bias", "q_norm", "k_norm", "out_norm",
+    "ssm_norm",
 )
 # how a routed stack's stats, one a layer, become one number a step; a
 # stat without a rule here is a KeyError when the program is traced
@@ -321,6 +366,8 @@ _OVER_LAYERS = {
     "gdn_log_decay_min": jnp.min,
     "gdn_beta_mean": jnp.mean,
     "shared_gate_mean": jnp.mean,
+    "ssm_log_decay_min": jnp.min,
+    "ssm_dt_mean": jnp.mean,
 }
 
 
@@ -352,7 +399,7 @@ def init_params(rng: np.random.Generator, cfg: TransformerConfig) -> Dict:
     L, d = cfg.n_layers, cfg.d_model
     if (
         cfg.moe_top_k or cfg.n_dense_layers or cfg.layer_types
-        or cfg.attention in ("mla", "kda", "gdn")
+        or cfg.attention in ("mla", "kda", "gdn", "mamba2")
     ):
         return _init_routed_params(norm, rng, cfg)
     layers = _init_mha(norm, cfg, L)
@@ -419,18 +466,21 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     "dense", then the expert layers under "layers"; with mixers that
     differ the runs lie in order under "stack"."""
     d = cfg.d_model
+    dense = any(experts is False for _mixer, experts, _layers in cfg.runs)
     if not (
-        cfg.moe_top_k and cfg.mlp == "swiglu"
-        and set(cfg.mixers) <= {"mla", "kda", "gdn", "conv", "mha", "swa"}
+        cfg.moe_top_k and cfg.mlp in ("swiglu", "relu2")
+        and not (dense and cfg.mlp == "relu2")
+        and set(cfg.mixers) <= set(ROUTED_MIXERS)
         and len(cfg.mixers) == cfg.n_layers
     ):
         raise NotImplementedError(
             "the routed stack is built with latent attention, delta-rule "
-            "attention or short convolutions beside grouped-query "
-            "attention, top-k experts and SwiGLU MLPs together (attention "
-            "'mla', 'kda' or 'gdn', or layer_types of 'mla', 'kda', 'gdn', "
-            "'conv', 'mha' and 'swa', one a layer; moe_top_k > 0, "
-            "mlp='swiglu')"
+            "attention, short convolutions or state-space mixers beside "
+            "grouped-query attention, top-k experts and gated or "
+            "squared-ReLU MLPs together (attention or layer_types of "
+            + ", ".join(repr(m) for m in ROUTED_MIXERS)
+            + ", one a layer; moe_top_k > 0; mlp='swiglu', or mlp='relu2' "
+            "in a stack with no dense layer)"
         )
 
     def mla(L):
@@ -500,28 +550,50 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
             "ln2": np.ones((L, d), np.float32),
         }
 
+    def mamba2(L):
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        bc = 2 * cfg.ssm_groups * cfg.ssm_state
+        out_proj = norm(L, inner, d)
+        if cfg.ssm_residual_blocks:
+            out_proj /= np.float32(math.sqrt(cfg.ssm_residual_blocks))
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            # the columns: z | x | B | C | dt
+            "in_proj": norm(L, d, 2 * inner + bc + cfg.ssm_heads),
+            # a tap's weights; the taps sum like a fan-in
+            "conv": norm(L, cfg.ssm_conv, inner + bc,
+                         scale=1.0 / math.sqrt(cfg.ssm_conv)),
+            "conv_bias": np.zeros((L, inner + bc), np.float32),
+            "ssm_norm": np.ones((L, inner), np.float32),
+            "out_proj": out_proj,
+            "ln2": np.ones((L, d), np.float32),
+        }
+
     mixer_trees = {
-        "mla": mla, "kda": kda, "gdn": gdn, "conv": conv,
+        "mla": mla, "kda": kda, "gdn": gdn, "conv": conv, "mamba2": mamba2,
         "mha": lambda L: _init_mha(norm, cfg, L),
         "swa": lambda L: _init_mha(norm, cfg, L, "swa"),
     }
     (_first, held) = cfg.held
     f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
 
+    routed_leaves, shared_leaves = _expert_leaves(cfg.mlp)
+    shapes = {
+        "eg": (held, d, f), "eu": (held, d, f), "ed": (held, f, d),
+        "sg": (d, fs), "su": (d, fs), "sd": (fs, d),
+    }
+
     def run(mixer, experts, L):
         tree = mixer_trees[mixer](L)
-        if experts:
-            tree.update(
-                router=norm(L, d, cfg.n_experts),
-                eg=norm(L, held, d, f), eu=norm(L, held, d, f),
-                ed=norm(L, held, f, d),
-            )
-            if fs:  # a layer with no shared expert has no such leaves
-                tree.update(
-                    sg=norm(L, d, fs), su=norm(L, d, fs), sd=norm(L, fs, d)
-                )
-                if cfg.shared_expert_gate:  # [1, d], not [d, 1] (`wbeta`)
-                    tree["sgate"] = norm(L, 1, d, scale=1.0 / math.sqrt(d))
+        if experts is None:  # the mixer alone
+            del tree["ln2"]
+        elif experts:
+            tree["router"] = norm(L, d, cfg.n_experts)
+            # a layer with no shared expert has no such leaves
+            for name in routed_leaves + (shared_leaves if fs else ()):
+                tree[name] = norm(L, *shapes[name])
+            if fs and cfg.shared_expert_gate:  # [1, d], not [d, 1] (`wbeta`)
+                tree["sgate"] = norm(L, 1, d, scale=1.0 / math.sqrt(d))
             if cfg.moe_score == "sigmoid":
                 tree["router_bias"] = np.zeros((L, cfg.n_experts), np.float32)
         else:
@@ -568,6 +640,22 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         n = n_gdn * cfg.gdn_value_heads
         params["gdn_decay"] = np.concatenate([
             np.log(rng.uniform(1e-3, 16.0, (n,))), np.ones((n,)),
+        ]).astype(np.float32)
+    n_ssm = cfg.mixers.count("mamba2")
+    if n_ssm:
+        # a head's rate exp(a_log), uniform in (1, 16); its step's bias,
+        # the inverse softplus of a step drawn log-uniform on (0.001,
+        # 0.1) and floored at 1e-4; its skip D = 1 (the family's
+        # initialiser). ONE flat leaf for all Mamba-2 layers, [a_log |
+        # dt_bias | D] each in stack order [layers x heads], as
+        # `kda_a_log` and for its reason
+        n = n_ssm * cfg.ssm_heads
+        dt = np.maximum(
+            np.exp(rng.uniform(math.log(0.001), math.log(0.1), (n,))), 1e-4
+        )
+        params["ssm_decay"] = np.concatenate([
+            np.log(rng.uniform(1.0, 16.0, (n,))),
+            dt + np.log(-np.expm1(-dt)), np.ones((n,)),
         ]).astype(np.float32)
     return params
 
@@ -616,18 +704,21 @@ def _require_mesh_support(cfg: TransformerConfig):
         or cfg.rope_dim or cfg.rope_factor != 1.0 or cfg.swa_heads
         or cfg.swa_window or cfg.gdn_key_heads or cfg.gdn_value_heads
         or cfg.gdn_head_dim or cfg.attn_channel_gate
-        or cfg.shared_expert_gate
+        or cfg.shared_expert_gate or cfg.bare_layers or not cfg.rope
+        or cfg.ssm_heads or cfg.ssm_head_dim or cfg.ssm_state
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
-            "block once: mlp='swiglu', sandwich_norm, n_loops > 1, "
-            "attention='mla', 'kda' and 'gdn', layer_types, "
+            "block once: mlp='swiglu' and 'relu2', sandwich_norm, "
+            "n_loops > 1, attention='mla', 'kda', 'gdn' and 'mamba2', "
+            "layer_types (with 'swa' and 'conv'), bare_layers, "
             "n_dense_layers, moe_top_k, n_kv_heads, qk_norm, "
             "tie_embeddings, head_width, attn_gate, attn_channel_gate, "
-            "shared_expert_gate, rope_dim, rope_factor, the windowed "
-            "mixer (swa_heads, swa_window) and the scalar-decay delta "
-            "rule (gdn_key_heads, gdn_value_heads, gdn_head_dim) exist on "
-            "the unsharded path (plain_forward) only"
+            "shared_expert_gate, rope=False, rope_dim, rope_factor, the "
+            "windowed mixer (swa_heads, swa_window), the scalar-decay "
+            "delta rule (gdn_key_heads, gdn_value_heads, gdn_head_dim) and "
+            "the state-space mixer (ssm_heads, ssm_head_dim, ssm_state) "
+            "exist on the unsharded path (plain_forward) only"
         )
 
 
@@ -888,13 +979,14 @@ def _mla(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions):
     return out.reshape(b, l, heads * cfg.v_head_dim) @ lp["wo"]
 
 
-def _causal_conv(x: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise over time, the taps alone: y_t = sum_i taps[i] *
-    x_{t - (n - 1) + i}, zeros before the sequence's start. x
-    [B, L, C], taps [n, C]."""
+def _causal_conv(x: jnp.ndarray, taps: jnp.ndarray, bias=None) -> jnp.ndarray:
+    """Depthwise over time: y_t = sum_i taps[i] * x_{t - (n - 1) + i}
+    (+ `bias` [C] where one is given), zeros before the sequence's
+    start. x [B, L, C], taps [n, C]."""
     n, length = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
-    return sum(padded[:, i:i + length] * taps[i] for i in range(n))
+    y = sum(padded[:, i:i + length] * taps[i] for i in range(n))
+    return y if bias is None else y + bias
 
 
 def _scope(name: Optional[str]):
@@ -948,7 +1040,7 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
         v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
         if cfg.qk_norm:
             q, k = _qk_norm(lp, q, k, cfg.norm_eps)
-        with _scope(inner and "rope"):
+        with _scope(cfg.rope and inner and "rope"):
             turn = dict(
                 freqs=shape.rope_yarn and yarn_frequencies(
                     shape.rope_dim or cfg.head_dim, shape.rope_base,
@@ -956,8 +1048,9 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
                 ),
                 rot=shape.rope_dim, factor=shape.rope_factor,
             )
-            q = _rope(q, positions, shape.rope_base, **turn)
-            k = _rope(k, positions, shape.rope_base, **turn)
+            if cfg.rope:
+                q = _rope(q, positions, shape.rope_base, **turn)
+                k = _rope(k, positions, shape.rope_base, **turn)
         out = attention(q, k, v, causal=True, window=shape.window)
         if cfg.attn_gate:
             with _scope(inner and "gate"):
@@ -1109,6 +1202,86 @@ def _gdn(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
         }
 
 
+def _ssm_gate_norm(cfg: TransformerConfig, y: jnp.ndarray, z: jnp.ndarray,
+                   weight: jnp.ndarray) -> jnp.ndarray:
+    """The scan's output y [B, L, inner] float32 times SiLU(z), THEN
+    RMS-normed over each group's inner / `ssm_groups` channels, one
+    weight of the inner width; float32 -> z's dtype."""
+    groups = (cfg.ssm_groups, y.shape[-1] // cfg.ssm_groups)
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    y = rms_norm(
+        y.reshape(y.shape[:-1] + groups), weight.reshape(groups), cfg.norm_eps
+    )
+    return y.reshape(z.shape).astype(z.dtype)
+
+
+def _ssm_skip(D: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """D x: a head's input [B, L, H, P] times its skip D [H], float32."""
+    return D[:, None] * x.astype(jnp.float32)
+
+
+def _ssm_conv(cfg: TransformerConfig, xbc: jnp.ndarray, taps, bias):
+    """x | B | C [B, L, inner + 2 G N] through the convolution with its
+    bias and SiLU -> (x [B, L, H, P], B and C [B, L, G, N])."""
+    b, l, _ = xbc.shape
+    inner, gn = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    xbc = jax.nn.silu(_causal_conv(xbc, taps, bias))
+    x, Bm, Cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+    groups = (b, l, cfg.ssm_groups, cfg.ssm_state)
+    return (
+        x.reshape(b, l, cfg.ssm_heads, cfg.ssm_head_dim),
+        Bm.reshape(groups), Cm.reshape(groups),
+    )
+
+
+def _mamba2(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
+    """Mamba-2 on the normed x [B, L, d] -> ([B, L, d], its stats).
+    (z, xBC, dt) = x W_in; xBC through the causal depthwise convolution
+    with its bias, then SiLU, and split x [H, P] | B [G, N] | C [G, N]
+    (head j reads group j // (H / G)); dt = softplus(dt + dt_bias),
+    unclamped; the log-decay dt x -exp(a_log), one number a head and
+    token; y = the recurrence of `ops/ssd.py` + D x; y x SiLU(z), then
+    the RMS norm over each group's channels; out = y W_out. `a_log`,
+    `dt_bias`, `D` and `ssm_norm` are the float32 leaves; the step, the
+    decay, the recurrence's sums and states, the gate and the norm are
+    float32 whatever `cfg.dtype` is. The elementwise stages round the
+    scan are recomputed in the backward pass (`jax.checkpoint`), as
+    `_gdn`'s are."""
+    from elasticdl_tpu.ops import ssd
+
+    b, l, _ = x.shape
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
+    f32 = jnp.float32
+    with jax.named_scope("in_proj"):
+        z, xbc, dt = jnp.split(
+            x @ lp["in_proj"], [inner, 2 * inner + bc], axis=-1
+        )
+    with jax.named_scope("conv"):
+        xs, Bm, Cm = jax.checkpoint(partial(_ssm_conv, cfg))(
+            xbc, lp["conv"], lp["conv_bias"]
+        )
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+        A = -jnp.exp(lp["a_log"].astype(f32))
+        # `ssd_chunked` is looked up here, at the call: the controls of
+        # the benchmark's comparison wrap it
+        y, log_decay_min = ssd.ssd_chunked(
+            xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk
+        )
+        with jax.named_scope("out"):
+            y = y + _ssm_skip(lp["D"].astype(f32), xs)
+    with jax.named_scope("gate_norm"):
+        y = jax.checkpoint(partial(_ssm_gate_norm, cfg))(
+            y.reshape(b, l, inner), z, lp["ssm_norm"].astype(f32)
+        )
+    with jax.named_scope("out_proj"):
+        return y @ lp["out_proj"], {
+            "ssm_log_decay_min": log_decay_min,
+            "ssm_dt_mean": lax.stop_gradient(jnp.mean(dt)),
+        }
+
+
 def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     """Vectorized unsharded forward — the same math as the sharded path
     restricted to a 1-device mesh, without the machinery: `lax.scan`
@@ -1140,8 +1313,10 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     and aux is the summed sequence-wise balance term. Every
     configuration's operations carry `jax.named_scope`s, which a
     reader of a device trace joins to (obs/hlo_scopes.py): `embed`;
-    in each layer `attention` (`mla`) and `mlp` (`moe` > `route`,
-    `experts`, `shared`); `head` round the final norm, the logits and
+    in each layer `attention` (`mla`, `kda`, `gdn`, `conv`,
+    `mamba2` > `run<i>`) and `mlp` (`moe` > `route`, `experts`,
+    `shared`; a layer of `bare_layers` has no such scope); `head` round
+    the final norm, the logits and
     the loss; and round those the looped LM's `looped_stack` and
     `exit_heads`."""
     return plain_forward_stats(cfg, params, tokens)[:2]
@@ -1179,28 +1354,38 @@ def plain_forward_stats(
         if mixer == "conv":
             out, gate_absmax = _conv(cfg, lp, x)
             return out, {"shortconv_gate_absmax": gate_absmax}
+        if mixer == "mamba2":
+            return _mamba2(cfg, lp, x)
         return _attend(cfg, lp, x, positions, mixer)
 
-    def layer(mixer: str, experts: bool):
-        """The scanned body of a layer with `mixer` and the dense MLP,
-        or the configuration's expert layer in its place."""
+    routed_leaves, shared_leaves = _expert_leaves(cfg.mlp)
+
+    def layer(mixer: str, experts: Optional[bool], index: int):
+        """The scanned body of a layer of run `index` with `mixer` and
+        the dense MLP, or the configuration's expert layer in its
+        place, or (`experts` None) no feed-forward part at all."""
 
         def body(carry, lp):
             h, aux = carry
+            # a state-space mixer's operations name their run as well:
+            # a reader of the trace counts the passes of the scan run
+            # by run (`benchmark/layer_metrics/_ssm.py`)
             with jax.named_scope(
                 "attention" if mixer in ("mha", "swa") else mixer
-            ):
+            ), _scope(mixer == "mamba2" and f"run{index}"):
                 out, stats = attend(mixer, lp, rms_norm(h, lp["ln1"], eps))
                 if cfg.sandwich_norm:
                     out = rms_norm(out, lp["ln1b"], eps)
                 h = h + out
+            if experts is None:
+                return (h, aux), stats
             with jax.named_scope("moe" if experts and routed else "mlp"):
                 x = rms_norm(h, lp["ln2"], eps)
                 if experts and routed:
                     out, a, routing = moe_topk_held(
                         x, lp["router"],
-                        (lp["eg"], lp["eu"], lp["ed"]),
-                        (lp["sg"], lp["su"], lp["sd"])
+                        tuple(lp[n] for n in routed_leaves),
+                        tuple(lp[n] for n in shared_leaves)
                         if cfg.n_shared_experts else None,
                         top_k=cfg.moe_top_k, held=cfg.held,
                         scaling=cfg.routed_scaling,
@@ -1271,10 +1456,23 @@ def plain_forward_stats(
                 tree["dt_bias"] = decay[1, seen:seen + layers]
                 seen += layers
 
+    if "mamba2" in cfg.mixers:  # as `kda_a_log`: [a_log | dt_bias | D]
+        decay = lax.optimization_barrier(stored["ssm_decay"]).reshape(
+            3, -1, cfg.ssm_heads
+        )
+        seen = 0
+        for (mixer, _experts, layers), tree in zip(runs, trees):
+            if mixer == "mamba2":
+                for at, name in enumerate(("a_log", "dt_bias", "D")):
+                    tree[name] = decay[at, seen:seen + layers]
+                seen += layers
+
     def stack(carry):
         gathered = {}
-        for (mixer, experts, _layers), tree in zip(runs, trees):
-            carry, stats = lax.scan(layer(mixer, experts), carry, tree)
+        for index, ((mixer, experts, _layers), tree) in enumerate(
+            zip(runs, trees)
+        ):
+            carry, stats = lax.scan(layer(mixer, experts, index), carry, tree)
             for name, value in stats.items():
                 gathered.setdefault(name, []).append(value)
         h, aux = carry

@@ -55,7 +55,9 @@ class TransformerLM:
                 },
             }
         if self.cfg.moe_top_k:
-            layers = self.cfg.n_layers - self.cfg.n_dense_layers
+            layers = sum(
+                layers for _mixer, experts, layers in self.cfg.runs if experts
+            )
             stats = {
                 "expert_tokens": np.zeros(
                     (layers, self.cfg.held[1]), np.float32
@@ -75,6 +77,9 @@ class TransformerLM:
             if "gdn" in self.cfg.mixers:
                 stats["gdn_log_decay_min"] = np.zeros((), np.float32)
                 stats["gdn_beta_mean"] = np.zeros((), np.float32)
+            if "mamba2" in self.cfg.mixers:
+                stats["ssm_log_decay_min"] = np.zeros((), np.float32)
+                stats["ssm_dt_mean"] = np.zeros((), np.float32)
             if self.cfg.attn_gate or self.cfg.attn_channel_gate:
                 stats["attn_gate_mean"] = np.zeros((), np.float32)
             if self.cfg.shared_expert_gate:

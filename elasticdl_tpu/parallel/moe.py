@@ -340,12 +340,33 @@ def _rung_taken(rungs: Tuple[int, ...], sizes):
     return jnp.sum(jnp.sum(sizes) > lower).astype(jnp.int32)
 
 
-def _swiglu_groups(rows, experts, sizes):
+def _relu2(h):
+    """relu(h) squared. Looked up at the call: a control of the
+    benchmark's comparison leaves the square out."""
+    return jnp.square(jax.nn.relu(h))
+
+
+def _expert_groups(rows, experts, sizes):
+    """The held experts on their groups of sorted rows, by the leaves
+    they are given: three (wg, wu, wd) are SwiGLUs, wd(silu(wg x) * wu
+    x); two (wu, wd) are squared-ReLU experts, wd relu(wu x)^2."""
+    if len(experts) == 2:
+        wu, wd = experts
+        return lax.ragged_dot(_relu2(lax.ragged_dot(rows, wu, sizes)), wd, sizes)
     wg, wu, wd = experts
     hidden = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) * lax.ragged_dot(
         rows, wu, sizes
     )
     return lax.ragged_dot(hidden, wd, sizes)
+
+
+def _shared_expert(xf, shared):
+    """The shared expert on every token, by its leaves as
+    `_expert_groups`."""
+    if len(shared) == 2:
+        wu, wd = shared
+        return _relu2(xf @ wu) @ wd
+    return swiglu(xf, *shared)
 
 
 def _on_rung(rung: int, xf, weight, experts, order, sizes):
@@ -362,7 +383,7 @@ def _on_rung(rung: int, xf, weight, experts, order, sizes):
         rows = jnp.where(used[:, None], _to_experts(xf, tok), 0)
         gate = jnp.where(used, weight.reshape(-1)[taken], 0.0)
     with jax.named_scope("experts"):
-        out = _swiglu_groups(rows, experts, sizes)
+        out = _expert_groups(rows, experts, sizes)
     with jax.named_scope("route"):
         return _to_tokens(jnp.where(used[:, None], out, 0), gate, tok, t)
 
@@ -377,7 +398,7 @@ def _on_full_buffer(xf, weight, experts, order, sizes):
         used = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
         rows = jnp.where(used, _dispatch(xf, order, pos), 0)
     with jax.named_scope("experts"):
-        out = _swiglu_groups(rows, experts, sizes)
+        out = _expert_groups(rows, experts, sizes)
     with jax.named_scope("route"):
         return _collect(jnp.where(used, out, 0), weight, order, pos)
 
@@ -448,8 +469,8 @@ def _shared_gate(xf: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 def moe_topk_held(
     x: jnp.ndarray,
     router_w: jnp.ndarray,
-    experts: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
-    shared: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
+    experts: Tuple[jnp.ndarray, ...],
+    shared: Optional[Tuple[jnp.ndarray, ...]] = None,
     *,
     top_k: int,
     held: Tuple[int, int],
@@ -475,11 +496,13 @@ def moe_topk_held(
     (0): a layer balanced by its selection bias has none in its loss.
 
     x [B, S, d]; router_w [d, E] over ALL E experts; `experts` the
-    stacked SwiGLU weights (wg [n, d, f], wu [n, d, f], wd [n, f, d])
-    of the n experts `held = (first, n)` names, experts first ..
-    first + n - 1 of E; `shared` one SwiGLU (the shared experts side by
-    side), None for a layer without one. -> (y [B, S, d], the
-    sequence-wise balance term (unweighted, f32), stats of the
+    stacked weights of the n experts `held = (first, n)` names, experts
+    first .. first + n - 1 of E: three leaves (wg [n, d, f], wu
+    [n, d, f], wd [n, f, d]) are SwiGLUs, two (wu, wd) squared-ReLU
+    experts (`_expert_groups`); `shared` one expert of the same kind
+    (the shared experts side by side), None for a layer without one.
+    -> (y [B, S, d], the sequence-wise balance term (unweighted, f32),
+    stats of the
     routing: `expert_tokens` [n] (counts, float32), `held_share`,
     `router_entropy`, `route_rows`, `route_full`, and under
     `shared_gate` `shared_gate_mean`).
@@ -546,7 +569,7 @@ def moe_topk_held(
         y = routed
     else:
         with jax.named_scope("shared"):
-            out = swiglu(xf, *shared)
+            out = _shared_expert(xf, shared)
             if shared_gate is not None:
                 with jax.named_scope("gate"):
                     gate = _shared_gate(xf, shared_gate)  # [T, 1] f32
