@@ -118,9 +118,13 @@ def add_model_spec_args(parser: argparse.ArgumentParser):
         "--overlap_sync", default="", choices=("", "on", "off"),
         help="worker overlap plane: on (default) pipelines window-delta "
         "encode/push on sync threads, pages model-down in on a "
-        "background thread, and enables BET prefetch; off restores the "
-        "serial blocking sync chain bit-for-bit (A/B + exactness "
-        "audits). EDL_OVERLAP_SYNC overrides.",
+        "background thread, and enables BET prefetch; off is the serial "
+        "chain: no device memory beside a window (16 B a parameter "
+        "resident, 20 at the sync's moment), no second delta on the "
+        "host, the same bytes to the same master in the same order "
+        "(A/B + exactness audits); a worker that is alone goes on once "
+        "the delta has left the chip, so the answer may arrive behind "
+        "the next window. EDL_OVERLAP_SYNC overrides.",
     )
     parser.add_argument("--log_level", default="INFO")
     parser.add_argument(

@@ -66,11 +66,16 @@ class DeltaStream:
     waits for the thread and says when the first slice was asked for
     and when the last one landed (`time.time()`), for the sync's one
     `worker.d2h` span. A copy that fails fails every piece not yet
-    landed, with the error that stopped it."""
+    landed, with the error that stopped it.
 
-    def __init__(self, bounds, slices: Iterator):
+    `on_end`, where given, is called once on the stream's thread when
+    it holds no slice any more: the last one has landed, or a copy
+    failed. The device then keeps nothing for this stream."""
+
+    def __init__(self, bounds, slices: Iterator, on_end=None):
         self._bounds = list(bounds)
         self._slices = slices
+        self._on_end = on_end
         # what the stream's thread hands to whoever waits, under `_cond`
         self._cond = threading.Condition()
         self._host = [None] * len(self._bounds)
@@ -84,9 +89,19 @@ class DeltaStream:
         self._thread.start()
 
     def _run(self):
+        try:
+            # its locals (the slice asked for last, the generator that
+            # cuts them) are gone when it returns
+            self._copy()
+        finally:
+            if self._on_end is not None:
+                self._on_end()
+
+    def _copy(self):
         count = len(self._bounds)
         slices, self._slices = self._slices, None
         in_flight = collections.deque()
+        piece = None
         asked = 0
         with self._cond:
             self._t_first = self._t_last = time.time()
@@ -105,6 +120,10 @@ class DeltaStream:
                     self._t_last = time.time()
                     self._cond.notify_all()
         except BaseException as e:  # handed to whoever waits, not lost
+            # (with its traceback, which keeps this frame: let go here
+            # of the slices it still holds)
+            in_flight.clear()
+            piece = None
             with self._cond:
                 self._error = e
                 self._cond.notify_all()

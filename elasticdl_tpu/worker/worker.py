@@ -203,6 +203,7 @@ class Worker:
             "_sync_error",
             "_base_snapshots",
             "_spawn_abs",
+            "_sync_hold",
         ),
     }
     # phase-timeline state with class defaults: a skeleton built with
@@ -394,9 +395,15 @@ class Worker:
         # default) keeps window-delta encode/push on pipelined sync
         # threads, pages model-down in on a background thread that
         # stages at step boundaries, and runs BET prefetch; off forces
-        # the serial blocking chain (depth 0 = spawn-then-join, no
-        # background pull, no prefetch) — bit-for-bit the pre-overlap
-        # path, for A/B and exactness audits.
+        # the serial chain (depth 0, no background pull, no prefetch):
+        # no device memory beside a window (16 B a parameter resident,
+        # 20 at the sync's moment: the step loop waits until the delta
+        # has left the chip and is deleted there), no second delta on
+        # the host (a sync settles before the next delta is formed),
+        # the same bytes to the same master in the same order as the
+        # pre-overlap path, for A/B and exactness audits. What it no
+        # longer promises a worker that is alone: the answer may arrive
+        # behind the next window (`_sync_hold`).
         if overlap_sync is None:
             overlap_sync = os.environ.get(ENV_OVERLAP_SYNC, "") or "on"
         overlap_sync = str(overlap_sync).strip().lower()
@@ -500,6 +507,21 @@ class Worker:
         self._sync_result = None
         self._base_snapshots: Dict[int, Any] = {}  # seq -> base at spawn
         self._sync_error = None  # exception raised by the async push
+        # Serial chain: why the step loop waits for a WHOLE sync, or
+        # None: it goes on at the release point (the host holds all
+        # the sync reads from the device, the delta is deleted there)
+        # and the rest of the send, the master's apply, the answer and
+        # the reports run behind the next window. By what the worker
+        # has seen, no flag: "first" until a sync of this trajectory
+        # has settled (a worker's first, and the first after a reset),
+        # "merged" while its last settled sync's answer brought a
+        # merged model (another worker writes to the master: the
+        # model is then absorbed before the next window, as ever),
+        # None after an answer without one. Written by the sync
+        # thread under `_report_lock`.
+        self._sync_hold: Optional[str] = "first"
+        # how many serial-chain syncs let the step loop go where
+        self.sync_releases = {"copied": 0, "settled": 0}
         # Per-step pipelining (sync-SGD latency hiding): with
         # `step_pipeline` = k > 0, up to k gradient reports ride the
         # link on background threads while later batches compute on the
@@ -2057,7 +2079,25 @@ class Worker:
         is not measured on this machine). Elastic semantics are
         preserved by deferring ReportTaskResult until the covering sync
         lands (`_defer_report`): work that dies unsynced dies
-        unreported, so the dispatcher requeues it."""
+        unreported, so the dispatcher requeues it.
+
+        The serial chain (`--overlap_sync off`, depth 0) keeps no
+        device memory beside a window and no second delta on the host:
+        the step loop waits after the spawn until the sync's RELEASE
+        POINT, where the host holds all the sync reads from the device
+        (the stream's last slice has landed, or the one `device_get`
+        has returned) and the delta is deleted there. While
+        `_sync_hold` is None it goes on from there and the thread
+        finishes alone, as the overlapped chain's threads do: the rest
+        of the send, the master's apply, the answer, the reports. That
+        sync is settled (`worker.sync_exposed`, `reason="settle"`)
+        before the next delta is formed, so the next subtraction
+        donates its base as ever, and an answer that did bring a
+        merged model is absorbed there, a window late, against its
+        base snapshot. Otherwise (a first sync, a merged answer last
+        time, a sparse plane, a drain) the step loop waits for the
+        whole sync. The span's `released` says which: `"copied"`, or
+        `"settled"` with `why`."""
         if blocking:
             self._join_sync()
         else:
@@ -2072,6 +2112,18 @@ class Worker:
             # their sync's own flush)
             self._flush_deferred_reports()
             return
+        serial = not self._max_inflight_syncs
+        if serial and self._sync_inflight:
+            # the sync the step loop left at its release point has to
+            # have settled before the next delta is formed: no snapshot
+            # then holds the base (`_delta_from_base` donates it) and
+            # the host holds one delta. Its tail is a fraction of a
+            # window; where it ever outlasts one, the wait is the
+            # sync's. Then what it left: its error, or the merged
+            # model its answer brought.
+            with self.timers.phase("sync_wait"):
+                with self._sync_exposed("settle"):
+                    self._join_sync()
         t_spawn = time.time()  # `worker.window_sync` starts here
         # the last `worker.device_run` this sync carries: the whole and
         # every part of it say so, and a reader joins them by it (a
@@ -2110,7 +2162,24 @@ class Worker:
             wire_form = sync_policy.decide(
                 link_mbps, delta_f32_bytes, self._sync_decisions
             )
-        wspan_args = {"worker": self._id, "seq": run_seq}
+        # serial chain: where the step loop is let go, and why not
+        # sooner (on the spans; nothing on the overlapped chain's)
+        released, why, at_release = {}, None, None
+        if serial:
+            with self._report_lock:
+                why = self._sync_hold
+            if blocking:
+                why = "drain"
+            elif self._emb_specs:
+                # a flush of the sparse plane lands before the next
+                # lookup (`_run_local_windows`)
+                why = "sparse"
+            released = {"released": "settled", "why": why} if why else {
+                "released": "copied"
+            }
+            self.sync_releases[released["released"]] += 1
+            at_release = threading.Event()
+        wspan_args = {"worker": self._id, "seq": run_seq, **released}
         if wire_form is not None:
             # the round's decision rides the window span for the
             # critical-path/decision audits
@@ -2195,6 +2264,14 @@ class Worker:
             self._own_steps_abs += steps
             self._spawn_abs[seq] = self._own_steps_abs
 
+        def release():
+            # the serial chain's release point, on the thread that saw
+            # the last of the delta land: nothing of this sync is left
+            # on the device but a few scalars
+            for leaf in jax.tree_util.tree_leaves(delta_dev):
+                leaf.delete()
+            at_release.set()
+
         def do_sync():
             # bind the window's root context so every hop below (client
             # RPC spans, server-side children) chains under it
@@ -2245,13 +2322,18 @@ class Worker:
                     "worker.d2h", bytes=delta_f32_bytes, slices=1, seq=run_seq
                 ):
                     delta_h, small_h = jax.device_get((delta_dev, small))
+                if serial:
+                    release()
             else:
                 # the slices' copies start now and land behind the
                 # request, which sends each as it does; on the serial
                 # chain the device stands still for this sync, so a
                 # slice is cut only as its copy is asked for and the
                 # device never holds more than those in flight beside
-                # the delta
+                # the delta. There the small arrays come first, so
+                # that the stream's end is the release point.
+                if serial:
+                    small_h = jax.device_get(small)
                 stream = delta_stream.DeltaStream(
                     slice_bounds,
                     (cut_ahead.popleft() for _ in slice_bounds)
@@ -2260,10 +2342,12 @@ class Worker:
                         self._delta_slice(delta_dev, lo, hi)
                         for lo, hi in slice_bounds
                     ),
+                    on_end=release if serial else None,
                 )
                 stream.start()
                 delta_h = stream.vector()
-                small_h = jax.device_get(small)  # beside the slices
+                if not serial:
+                    small_h = jax.device_get(small)  # beside the slices
             aux_h, loss_h, step_loss_h, gbets_h = small_h
             stats = (aux_h or {}).get(WINDOW_STATS)
             if stats:
@@ -2398,6 +2482,7 @@ class Worker:
                     return  # reset raced the RPC: discard the response
                 self._synced_seq = max(self._synced_seq, seq)
                 merged_back = resp.get("params_flat") is not None
+                self._sync_hold = "merged" if merged_back else None
                 if versions is not None:
                     self._shard_versions = versions
                 self._version = resp["version"]
@@ -2448,7 +2533,7 @@ class Worker:
         # the step loop's own part of the sync: the delta and the new
         # base dispatched, the quantize, the bookkeeping above
         self.timers.record_span(
-            "worker.sync_spawn", t_spawn, time.time(), seq=run_seq
+            "worker.sync_spawn", t_spawn, time.time(), seq=run_seq, **released
         )
         if blocking:
             try:
@@ -2469,11 +2554,21 @@ class Worker:
                 except Exception as e:  # surfaced by _check_sync_error
                     with self._report_lock:
                         self._sync_error = e
+                finally:
+                    if serial:  # a sync that ended short of its release
+                        at_release.set()
 
             t = threading.Thread(target=thread_main, daemon=True)
             self._sync_thread = t
             self._sync_inflight.append(t)
             t.start()
+            if serial and not why:
+                # to the release point; the thread goes on alone and
+                # is joined before the next delta is formed (above)
+                with self.timers.phase("sync_wait"):
+                    with self._sync_exposed("backpressure"):
+                        at_release.wait()
+                return
             # backpressure: bound in-flight windows (device memory for
             # their feature buffers + requeue exposure on preemption)
             while len(self._sync_inflight) > self._max_inflight_syncs:
@@ -2671,6 +2766,7 @@ class Worker:
             # the diverged local params survive the reset
             self._shard_versions = None
             self._sync_result = None
+            self._sync_hold = "first"  # of the trajectory pulled next
             self._absorb_staged = None  # staged page-in predates the reset
             self._base_snapshots.clear()
             # lineage dies with the trajectory; the forced re-pull is
