@@ -118,10 +118,12 @@ class TransformerConfig:
     # projected
     mla_rope: bool = True
     # the mixer of every layer of the stack, dense layers first:
-    # "mha", "swa", "mla", "kda", "gdn", "conv" or "mamba2"; None =
-    # `attention` in every layer.
+    # "mha", "swa", "mla", "kda", "gdn", "conv", "mamba2", "mamba1", "gmu"
+    # or "cross"; None = `attention` in every layer.
     # Layers that follow each other with one mixer and one kind of
-    # feed-forward part are one scanned run. A layer named in
+    # feed-forward part are one scanned run (`memory_layer` and
+    # `kv_layer` are each a run of their own: what they hand on is one
+    # layer's). A layer named in
     # `bare_layers` (its index in the stack) is its mixer ALONE,
     # h + mixer(ln1(h)): no feed-forward part, no `ln2`, no `mlp` or
     # `moe` scope. (A model whose published blocks are each a mixer OR a
@@ -212,6 +214,47 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 128
     ssm_residual_blocks: int = 0
+    # "mamba1": the selective state-space mixer (Mamba-1): (x | z) = u
+    # W_in, `ssm1_inner` wide each; x through a causal depthwise
+    # convolution of `ssm1_conv` taps WITH a bias, then SiLU; (delta | B
+    # | C) = x W_x, `ssm1_dt_rank` | `ssm1_state` | `ssm1_state`; dt =
+    # softplus(delta W_dt + dt_bias); the decay exp(dt x -exp(a_log)),
+    # one number a channel AND state column (`a_log` [state, inner]);
+    # the recurrence is `ops/selective_scan.py`'s, plus D x; the output
+    # times SiLU(z), projected. The layer `memory_layer` (its index in
+    # the stack) also hands its y, after D x and before the gate, to
+    # the layers behind it as their `memory`.
+    # "gmu": the gated memory unit, W_2 (memory x SiLU(W_1 u)), both
+    # matrices between d_model and `ssm1_inner`, no state of its own
+    ssm1_inner: int = 0
+    ssm1_state: int = 16
+    ssm1_conv: int = 4
+    ssm1_dt_rank: int = 0
+    memory_layer: Optional[int] = None
+    # "mha", "swa" and "cross" as DIFFERENTIAL attention (Ye et al.
+    # 2024): heads come in pairs (2p, 2p + 1), key-value heads too, a
+    # pair's values read as ONE head twice as wide; pair p's output is
+    # softmax(q1 k1) v - lambda softmax(q2 k2) v, lambda = exp(lq1 . lk1)
+    # - exp(lq2 . lk2) + lambda_init with four learned vectors of
+    # head_dim a layer, lambda_init = 0.8 - 0.6 exp(-0.3 depth), depth
+    # the layer's entry of `diff_depths` (its index in the PUBLISHED
+    # stack, one a layer here); then an RMS norm over the pair's 2 x
+    # head_dim columns (one weight of that width a layer) times (1 -
+    # lambda_init). The four vectors and the norm's weight are one leaf
+    # `diff` [6 x head_dim]; `wq`'s and `wk`'s columns hold the pairs'
+    # first members, then their second members.
+    # "cross": such a layer with queries of its own and the keys and
+    # values of the layer `kv_layer` (its index in the stack), full
+    # causal; it has no `wk` and no `wv`
+    diff_attention: bool = False
+    diff_depths: Optional[Tuple[int, ...]] = None
+    kv_layer: Optional[int] = None
+    # "mha", "swa", "cross": a bias on the q, k, v and output projections
+    attn_bias: bool = False
+    # "layer": every norm of the residual stream is a LayerNorm with a
+    # weight and a bias (`ln1_bias`, `ln2_bias`, `ln_f_bias`), its
+    # statistics in float32; "rms": the RMS norm with a weight
+    norm: str = "rms"
 
     @property
     def head_dim(self) -> int:
@@ -230,11 +273,13 @@ class TransformerConfig:
         """The stack as scanned runs: (mixer, expert layer?, layers);
         the second is None for layers with no feed-forward part."""
         runs = []
+        gives = (self.memory_layer, self.kv_layer)
         for i, mixer in enumerate(self.mixers):
             kind = (mixer, None if i in self.bare_layers else (
                 bool(self.n_experts) and i >= self.n_dense_layers
             ))
-            if runs and runs[-1][:2] == kind:
+            alone = i in gives or i - 1 in gives
+            if runs and runs[-1][:2] == kind and not alone:
                 runs[-1] = kind + (runs[-1][2] + 1,)
             else:
                 runs.append(kind + (1,))
@@ -258,6 +303,11 @@ class TransformerConfig:
             return AttentionShape(
                 self.swa_heads, self.swa_window, self.swa_rope_base,
                 None, None, 1.0, "swa",
+            )
+        if mixer == "cross":
+            return AttentionShape(
+                self.n_heads, None, self.rope_base, self.rope_dim,
+                self.rope_yarn, self.rope_factor, "cross",
             )
         return AttentionShape(
             self.n_heads, None, self.rope_base, self.rope_dim,
@@ -345,12 +395,15 @@ def _expert_leaves(mlp: str):
 
 
 # every mixer the routed stack builds (`_init_routed_params`)
-ROUTED_MIXERS = ("mla", "kda", "gdn", "conv", "mamba2", "mha", "swa")
+ROUTED_MIXERS = (
+    "mla", "kda", "gdn", "conv", "mamba2", "mamba1", "gmu", "mha", "swa",
+    "cross",
+)
 # a run's leaves that the forward pass reads as stored (float32) and not
 # as cast to the compute dtype
 _FLOAT32_LEAVES = (
     "router", "router_bias", "dt_bias", "q_norm", "k_norm", "out_norm",
-    "ssm_norm",
+    "ssm_norm", "a_log", "D", "diff",
 )
 # how a routed stack's stats, one a layer, become one number a step; a
 # stat without a rule here is a KeyError when the program is traced
@@ -368,6 +421,10 @@ _OVER_LAYERS = {
     "shared_gate_mean": jnp.mean,
     "ssm_log_decay_min": jnp.min,
     "ssm_dt_mean": jnp.mean,
+    "ssm1_log_decay_min": jnp.min,
+    "ssm1_dt_mean": jnp.mean,
+    "diff_lambda_mean": jnp.mean,
+    "gmu_gate_absmax": jnp.max,
 }
 
 
@@ -449,6 +506,19 @@ def _init_mha(norm, cfg: TransformerConfig, L: int, mixer="mha") -> Dict:
         "wo": norm(L, heads * hd, d),
         "ln2": np.ones((L, d), np.float32),
     }
+    if cfg.attn_bias:
+        for name in ("bq", "bk", "bv", "bo"):
+            tree[name] = np.zeros((L, tree["w" + name[1]].shape[-1]), np.float32)
+    if mixer == "cross":  # its keys and values are another layer's
+        for name in ("wk", "wv", "bk", "bv"):
+            tree.pop(name, None)
+    if cfg.diff_attention:
+        # lq1 | lk1 | lq2 | lk2 (normal at 0.1) | the pair norm's weight
+        # (ones), ONE leaf of 6 x head_dim: five leaves of 64 or 128
+        # would each end in a narrow dim (`kda_a_log`, below)
+        tree["diff"] = np.concatenate([
+            norm(L, 4 * hd, scale=0.1), np.ones((L, 2 * hd), np.float32),
+        ], axis=1)
     if cfg.qk_norm:
         tree["q_norm"] = np.ones((L, hd), np.float32)
         tree["k_norm"] = np.ones((L, hd), np.float32)
@@ -468,20 +538,24 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     d = cfg.d_model
     dense = any(experts is False for _mixer, experts, _layers in cfg.runs)
     if not (
-        cfg.moe_top_k and cfg.mlp in ("swiglu", "relu2")
+        # top-k experts, or none at all: every layer's MLP the dense one
+        (cfg.moe_top_k or not cfg.n_experts)
+        and cfg.mlp in ("swiglu", "relu2")
         and not (dense and cfg.mlp == "relu2")
         and set(cfg.mixers) <= set(ROUTED_MIXERS)
         and len(cfg.mixers) == cfg.n_layers
     ):
         raise NotImplementedError(
             "the routed stack is built with latent attention, delta-rule "
-            "attention, short convolutions or state-space mixers beside "
-            "grouped-query attention, top-k experts and gated or "
-            "squared-ReLU MLPs together (attention or layer_types of "
+            "attention, short convolutions, state-space mixers or gated "
+            "memory units beside grouped-query, differential and cross "
+            "attention, top-k experts and gated or squared-ReLU MLPs "
+            "together (attention or layer_types of "
             + ", ".join(repr(m) for m in ROUTED_MIXERS)
-            + ", one a layer; moe_top_k > 0; mlp='swiglu', or mlp='relu2' "
-            "in a stack with no dense layer)"
+            + ", one a layer; moe_top_k > 0, or n_experts = 0; "
+            "mlp='swiglu', or mlp='relu2' in a stack with no dense layer)"
         )
+    _require_feeders(cfg)
 
     def mla(L):
         heads, rank = cfg.n_heads, cfg.kv_lora_rank
@@ -569,10 +643,51 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
             "ln2": np.ones((L, d), np.float32),
         }
 
+    def mamba1(L):
+        inner, n, rank = cfg.ssm1_inner, cfg.ssm1_state, cfg.ssm1_dt_rank
+        # the family's initialiser: the step's bias the inverse softplus
+        # of a step drawn log-uniform on (0.001, 0.1) and floored at
+        # 1e-4, its projection uniform at rank^-1/2; column n's rate
+        # exp(a_log) = n + 1 on every channel; the skip D = 1
+        dt = np.maximum(np.exp(rng.uniform(
+            math.log(0.001), math.log(0.1), (L, inner)
+        )), 1e-4)
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            "in_proj": norm(L, d, 2 * inner),  # the columns: x | z
+            # a tap's weights; the taps sum like a fan-in
+            "conv": norm(L, cfg.ssm1_conv, inner,
+                         scale=1.0 / math.sqrt(cfg.ssm1_conv)),
+            "conv_bias": np.zeros((L, inner), np.float32),
+            "x_proj": norm(L, inner, rank + 2 * n),  # delta | B | C
+            "dt_proj": rng.uniform(
+                -rank**-0.5, rank**-0.5, (L, rank, inner)
+            ).astype(np.float32),
+            "dt_bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            # [state, inner], not [inner, 16] (`kda_a_log`, below)
+            "a_log": np.broadcast_to(
+                np.log(np.arange(1, n + 1, dtype=np.float32))[None, :, None],
+                (L, n, inner),
+            ).copy(),
+            "D": np.ones((L, inner), np.float32),
+            "out_proj": norm(L, inner, d),
+            "ln2": np.ones((L, d), np.float32),
+        }
+
+    def gmu(L):
+        return {
+            "ln1": np.ones((L, d), np.float32),
+            "w1": norm(L, d, cfg.ssm1_inner),
+            "w2": norm(L, cfg.ssm1_inner, d),
+            "ln2": np.ones((L, d), np.float32),
+        }
+
     mixer_trees = {
         "mla": mla, "kda": kda, "gdn": gdn, "conv": conv, "mamba2": mamba2,
+        "mamba1": mamba1, "gmu": gmu,
         "mha": lambda L: _init_mha(norm, cfg, L),
         "swa": lambda L: _init_mha(norm, cfg, L, "swa"),
+        "cross": lambda L: _init_mha(norm, cfg, L, "cross"),
     }
     (_first, held) = cfg.held
     f, fs = cfg.d_expert, cfg.n_shared_experts * cfg.d_expert
@@ -601,6 +716,10 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
                 wg=norm(L, d, cfg.d_ff), wu=norm(L, d, cfg.d_ff),
                 wd=norm(L, cfg.d_ff, d),
             )
+        if cfg.norm == "layer":
+            for name in ("ln1", "ln2"):
+                if name in tree:
+                    tree[name + "_bias"] = np.zeros((L, d), np.float32)
         return tree
 
     if cfg.mixed:
@@ -616,6 +735,8 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
         **stack,
         "ln_f": np.ones((d,), np.float32),
     }
+    if cfg.norm == "layer":
+        params["ln_f_bias"] = np.zeros((d,), np.float32)
     if not cfg.tie_embeddings:
         params["head"] = norm(d, cfg.vocab)
     n_kda = cfg.mixers.count("kda")
@@ -694,6 +815,34 @@ def param_partition_specs(cfg: TransformerConfig) -> Dict:
 # -------------------------------------------------------------------- model
 
 
+def _require_feeders(cfg: TransformerConfig):
+    """A "gmu" layer reads the memory of `memory_layer` and a "cross"
+    layer the keys and values of `kv_layer`: each an EARLIER layer of
+    the right kind, or the stack is refused."""
+    mixers = cfg.mixers
+    for reader, feeder, at, what in (
+        ("gmu", "mamba1", cfg.memory_layer, "memory_layer"),
+        ("cross", "mha", cfg.kv_layer, "kv_layer"),
+    ):
+        if at is not None and not (
+            0 <= at < len(mixers) and mixers[at] == feeder
+        ):
+            raise ValueError(f"{what} {at} is no {feeder!r} layer of {mixers}")
+        if reader in mixers and (at is None or mixers.index(reader) < at):
+            raise ValueError(
+                f"a {reader!r} layer at {mixers.index(reader)} of {mixers} "
+                f"has no earlier {feeder!r} layer to read ({what} = {at})"
+            )
+    if cfg.diff_attention and (
+        cfg.diff_depths is None or len(cfg.diff_depths) != len(mixers)
+        or cfg.n_heads % 2 or cfg.kv_heads % 2
+    ):
+        raise ValueError(
+            "diff_attention pairs the heads (an even n_heads and "
+            "n_kv_heads) and takes diff_depths, one published index a layer"
+        )
+
+
 def _require_mesh_support(cfg: TransformerConfig):
     """The 4-axis mesh path knows the original block only."""
     if (
@@ -706,6 +855,9 @@ def _require_mesh_support(cfg: TransformerConfig):
         or cfg.gdn_head_dim or cfg.attn_channel_gate
         or cfg.shared_expert_gate or cfg.bare_layers or not cfg.rope
         or cfg.ssm_heads or cfg.ssm_head_dim or cfg.ssm_state
+        or cfg.ssm1_inner or cfg.ssm1_dt_rank or cfg.memory_layer is not None
+        or cfg.diff_attention or cfg.diff_depths or cfg.kv_layer is not None
+        or cfg.attn_bias or cfg.norm != "rms"
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
@@ -716,9 +868,12 @@ def _require_mesh_support(cfg: TransformerConfig):
             "tie_embeddings, head_width, attn_gate, attn_channel_gate, "
             "shared_expert_gate, rope=False, rope_dim, rope_factor, the "
             "windowed mixer (swa_heads, swa_window), the scalar-decay "
-            "delta rule (gdn_key_heads, gdn_value_heads, gdn_head_dim) and "
-            "the state-space mixer (ssm_heads, ssm_head_dim, ssm_state) "
-            "exist on the unsharded path (plain_forward) only"
+            "delta rule (gdn_key_heads, gdn_value_heads, gdn_head_dim), "
+            "the state-space mixers (ssm_heads, ssm_head_dim, ssm_state; "
+            "'mamba1' with ssm1_inner, ssm1_dt_rank, memory_layer and the "
+            "'gmu' that reads it), differential attention (diff_attention, "
+            "diff_depths, 'cross' and kv_layer), attn_bias and "
+            "norm='layer' exist on the unsharded path (plain_forward) only"
         )
 
 
@@ -1011,6 +1166,177 @@ def _channel_gate(projected: jnp.ndarray) -> jnp.ndarray:
     """sigmoid of the gate's columns [B, L, heads x head_dim],
     float32."""
     return jax.nn.sigmoid(projected.astype(jnp.float32))
+
+
+def layer_norm(x: jnp.ndarray, weight, bias, eps: float) -> jnp.ndarray:
+    """LayerNorm over the feature dim, its mean and variance in
+    float32, back in x's dtype."""
+    y = x.astype(jnp.float32)
+    y = y - jnp.mean(y, axis=-1, keepdims=True)
+    y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
+    return (y * weight + bias).astype(x.dtype)
+
+
+def diff_lambda_init(depth) -> float:
+    """Differential attention's lambda_init at a layer's published
+    index: 0.8 - 0.6 exp(-0.3 depth)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+
+def _diff_lambda(diff: jnp.ndarray, lambda_init, hd: int):
+    """exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init from the leaf
+    `diff` = lq1 | lk1 | lq2 | lk2 | ..., float32."""
+    lq1, lk1, lq2, lk2 = (diff[i * hd:(i + 1) * hd] for i in range(4))
+    return jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lambda_init
+
+
+def _diff_pair_norm(o: jnp.ndarray, weight, eps: float) -> jnp.ndarray:
+    """The RMS norm over a pair's 2 x head_dim output columns,
+    float32."""
+    return rms_norm(o, weight, eps)
+
+
+def _diff_combine(cfg: TransformerConfig, out: jnp.ndarray, diff, lambda_init):
+    """The two maps' outputs [B, L, heads, 2 hd] (the pairs' first
+    members, then their second) -> ([B, L, pairs, 2 hd] in out's
+    dtype, lambda): o1 - lambda o2, the pair norm, times (1 -
+    lambda_init); in float32."""
+    hd, pairs = cfg.head_dim, out.shape[2] // 2
+    diff = diff.astype(jnp.float32)
+    lam = _diff_lambda(diff, lambda_init, hd)
+    o = out[:, :, :pairs].astype(jnp.float32) - lam * out[:, :, pairs:].astype(
+        jnp.float32
+    )
+    o = _diff_pair_norm(o, diff[4 * hd:], cfg.norm_eps) * (1.0 - lambda_init)
+    return o.astype(out.dtype), lam
+
+
+def _cross_kv(shared_kv, q: jnp.ndarray):
+    """The keys and values a "cross" layer reads: `kv_layer`'s. (The
+    queries are handed in for the benchmark's comparison, whose control
+    gives the layer keys of its own.)"""
+    return shared_kv
+
+
+def _diff_attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
+                 mixer: str, shared_kv=None):
+    """Differential attention on the normed x [B, L, d] -> ([B, L, d],
+    its stats, (k, v) as this layer read them). Heads come in pairs;
+    `wq`'s and `wk`'s columns hold every pair's first member, then
+    every pair's second, so that ONE call of the dispatcher computes
+    both maps: query head c x pairs + p reads key head c x kv_pairs +
+    p // group, which is its own i // group, and the value heads are
+    the pairs' 2 x head_dim-wide values, once for each map. A "cross"
+    layer projects queries only and reads `shared_kv`. Under `rope`
+    queries and a layer's own keys turn whole (the shared keys were
+    turned where they were made)."""
+    from elasticdl_tpu.ops.flash_attention import attention
+
+    b, l, _ = x.shape
+    shape = cfg.attention_shape(mixer)
+    hd = cfg.head_dim
+
+    def project(w, bias, heads, width):
+        y = x @ lp[w]
+        if cfg.attn_bias:
+            y = y + lp[bias]
+        return y.reshape(b, l, heads, width)
+
+    with _scope(shape.scope):
+        q = project("wq", "bq", shape.heads, hd)
+        if cfg.rope:
+            q = _rope(q, positions, shape.rope_base)
+        if mixer == "cross":
+            k, v = _cross_kv(shared_kv, q)
+        else:
+            k = project("wk", "bk", cfg.kv_heads, hd)
+            v = project("wv", "bv", cfg.kv_heads // 2, 2 * hd)
+            if cfg.rope:
+                k = _rope(k, positions, shape.rope_base)
+        out = attention(
+            q, k, jnp.concatenate([v, v], axis=2), causal=True,
+            window=shape.window,
+        )
+        with jax.named_scope("diff"):
+            out, lam = _diff_combine(cfg, out, lp["diff"], lp["lambda_init"])
+        out = out.reshape(b, l, -1) @ lp["wo"]
+        if cfg.attn_bias:
+            out = out + lp["bo"]
+        return out, {"diff_lambda_mean": lax.stop_gradient(lam)}, (k, v)
+
+
+def _mamba1_step(cfg: TransformerConfig, lp: Dict, xs: jnp.ndarray):
+    """(delta | B | C) = x W_x; dt = softplus(delta W_dt + dt_bias),
+    float32 -> (dt [B, L, inner] float32, B and C [B, L, state])."""
+    n, rank = cfg.ssm1_state, cfg.ssm1_dt_rank
+    delta, Bm, Cm = jnp.split(xs @ lp["x_proj"], [rank, rank + n], axis=-1)
+    dt = jax.nn.softplus(
+        (delta @ lp["dt_proj"]).astype(jnp.float32)
+        + lp["dt_bias"].astype(jnp.float32)
+    )
+    return dt, Bm, Cm
+
+
+def _mamba1_memory(y: jnp.ndarray, gated: jnp.ndarray) -> jnp.ndarray:
+    """What `memory_layer` hands on: y, the scan's output plus D x,
+    BEFORE the gate (`gated` is y x SiLU(z), which it is not)."""
+    return y.astype(gated.dtype)
+
+
+def _mamba1_gate(y: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """y x SiLU(z) in float32 -> z's dtype."""
+    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def _mamba1(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray):
+    """Mamba-1 on the normed x [B, L, d] -> ([B, L, d], its stats, y):
+    y, the scan's output plus D x and BEFORE the gate, in x's dtype, is
+    what `memory_layer` hands on. `a_log` [state, inner], `dt_bias` and
+    `D` are the float32 leaves; the step and its softplus, the rate,
+    every exponential, the state and its sums, D x and the gate are
+    float32 whatever `cfg.dtype` is."""
+    from elasticdl_tpu.ops import selective_scan
+
+    f32 = jnp.float32
+    with jax.named_scope("in_proj"):
+        xs, z = jnp.split(x @ lp["in_proj"], 2, axis=-1)
+    with jax.named_scope("conv"):
+        xs = jax.nn.silu(_causal_conv(xs, lp["conv"], lp["conv_bias"]))
+    with jax.named_scope("step"):
+        dt, Bm, Cm = _mamba1_step(cfg, lp, xs)
+    with jax.named_scope("scan"):
+        A = -jnp.exp(lp["a_log"].astype(f32))
+        # looked up here, at the call: the controls of the benchmark's
+        # comparison wrap it
+        y, _last = selective_scan.selective_scan(xs, dt, A, Bm, Cm)
+        y = y + lp["D"].astype(f32) * xs.astype(f32)
+    with jax.named_scope("gate"):
+        out = _mamba1_gate(y, z)
+    with jax.named_scope("out_proj"):
+        # dt A is most negative where the step is largest and the rate
+        # fastest: a channel's largest step times its fastest column
+        return out @ lp["out_proj"], {
+            "ssm1_log_decay_min": lax.stop_gradient(jnp.min(
+                jnp.max(dt, axis=(0, 1)) * jnp.min(A, axis=0)
+            )),
+            "ssm1_dt_mean": lax.stop_gradient(jnp.mean(dt)),
+        }, _mamba1_memory(y, out)
+
+
+def _gmu(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, memory):
+    """The gated memory unit on the normed x [B, L, d] -> ([B, L, d],
+    its stats): W_2 (memory x SiLU(W_1 x)), the gate and the product in
+    float32; `memory` [B, L, inner] is `memory_layer`'s."""
+    with jax.named_scope("in_proj"):
+        gate = x @ lp["w1"]
+    with jax.named_scope("gate"):
+        gated = memory.astype(jnp.float32) * jax.nn.silu(
+            gate.astype(jnp.float32)
+        )
+        absmax = lax.stop_gradient(jnp.max(jnp.abs(gated)))
+        gated = gated.astype(x.dtype)
+    with jax.named_scope("out_proj"):
+        return gated @ lp["w2"], {"gmu_gate_absmax": absmax}
 
 
 def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
@@ -1342,28 +1668,50 @@ def plain_forward_stats(
     eps = cfg.norm_eps
     routed = bool(cfg.moe_top_k)
 
-    def attend(mixer, lp, x):
-        """-> (the mixer's output, its stats)."""
+    def norm(h, lp, name):
+        """The residual stream's norm `name` of a layer's leaves."""
+        if cfg.norm == "layer":
+            return layer_norm(h, lp[name], lp[name + "_bias"], eps)
+        return rms_norm(h, lp[name], eps)
+
+    def attend(mixer, lp, x, shared):
+        """-> (the mixer's output, its stats, what it could hand to
+        the layers behind it); `shared` is what earlier layers handed
+        on."""
         if mixer == "mla":
-            return _mla(cfg, lp, x, positions), {}
+            return _mla(cfg, lp, x, positions), {}, {}
         if mixer == "kda":
             out, log_decay_min = _kda(cfg, lp, x)
-            return out, {"kda_log_decay_min": log_decay_min}
+            return out, {"kda_log_decay_min": log_decay_min}, {}
         if mixer == "gdn":
-            return _gdn(cfg, lp, x)
+            return _gdn(cfg, lp, x) + ({},)
         if mixer == "conv":
             out, gate_absmax = _conv(cfg, lp, x)
-            return out, {"shortconv_gate_absmax": gate_absmax}
+            return out, {"shortconv_gate_absmax": gate_absmax}, {}
         if mixer == "mamba2":
-            return _mamba2(cfg, lp, x)
-        return _attend(cfg, lp, x, positions, mixer)
+            return _mamba2(cfg, lp, x) + ({},)
+        if mixer == "mamba1":
+            out, stats, y = _mamba1(cfg, lp, x)
+            return out, stats, {"memory": y}
+        if mixer == "gmu":
+            return _gmu(cfg, lp, x, shared["memory"]) + ({},)
+        if cfg.diff_attention:
+            out, stats, kv = _diff_attend(
+                cfg, lp, x, positions, mixer, shared.get("shared_kv")
+            )
+            return out, stats, {"shared_kv": kv}
+        return _attend(cfg, lp, x, positions, mixer) + ({},)
 
     routed_leaves, shared_leaves = _expert_leaves(cfg.mlp)
 
-    def layer(mixer: str, experts: Optional[bool], index: int):
+    def layer(mixer: str, experts: Optional[bool], index: int,
+              shared: Dict, gives: Tuple[str, ...] = ()):
         """The scanned body of a layer of run `index` with `mixer` and
         the dense MLP, or the configuration's expert layer in its
-        place, or (`experts` None) no feed-forward part at all."""
+        place, or (`experts` None) no feed-forward part at all. The
+        body closes over `shared`, what earlier runs handed on, and
+        its scan's `ys` carry, beside the layer's stats, what this run
+        hands on itself (`gives`: "memory", "shared_kv")."""
 
         def body(carry, lp):
             h, aux = carry
@@ -1371,16 +1719,19 @@ def plain_forward_stats(
             # a reader of the trace counts the passes of the scan run
             # by run (`benchmark/layer_metrics/_ssm.py`)
             with jax.named_scope(
-                "attention" if mixer in ("mha", "swa") else mixer
-            ), _scope(mixer == "mamba2" and f"run{index}"):
-                out, stats = attend(mixer, lp, rms_norm(h, lp["ln1"], eps))
+                "attention" if mixer in ("mha", "swa", "cross") else mixer
+            ), _scope(mixer in ("mamba2", "mamba1") and f"run{index}"):
+                out, stats, handed = attend(
+                    mixer, lp, norm(h, lp, "ln1"), shared
+                )
+                handed = {name: handed[name] for name in gives}
                 if cfg.sandwich_norm:
                     out = rms_norm(out, lp["ln1b"], eps)
                 h = h + out
             if experts is None:
-                return (h, aux), stats
+                return (h, aux), (stats, handed)
             with jax.named_scope("moe" if experts and routed else "mlp"):
-                x = rms_norm(h, lp["ln2"], eps)
+                x = norm(h, lp, "ln2")
                 if experts and routed:
                     out, a, routing = moe_topk_held(
                         x, lp["router"],
@@ -1413,7 +1764,7 @@ def plain_forward_stats(
                 h = h + out
                 if experts:
                     aux = aux + a
-            return (h, aux), stats
+            return (h, aux), (stats, handed)
 
         if cfg.remat or cfg.looped:
             return _remat(body)
@@ -1467,17 +1818,39 @@ def plain_forward_stats(
                     tree[name] = decay[at, seen:seen + layers]
                 seen += layers
 
+    if cfg.diff_attention:  # a layer's lambda_init, by its published index
+        seen = 0
+        for (mixer, _experts, layers), tree in zip(runs, trees):
+            if mixer in ("mha", "swa", "cross"):
+                tree["lambda_init"] = jnp.asarray([
+                    diff_lambda_init(depth)
+                    for depth in cfg.diff_depths[seen:seen + layers]
+                ], jnp.float32)
+            seen += layers
+
     def stack(carry):
-        gathered = {}
-        for index, ((mixer, experts, _layers), tree) in enumerate(
+        gathered, shared, first = {}, {}, 0
+        for index, ((mixer, experts, layers), tree) in enumerate(
             zip(runs, trees)
         ):
-            carry, stats = lax.scan(layer(mixer, experts, index), carry, tree)
+            gives = tuple(
+                name for name, at in (
+                    ("memory", cfg.memory_layer), ("shared_kv", cfg.kv_layer)
+                ) if at == first
+            )
+            carry, (stats, handed) = lax.scan(
+                layer(mixer, experts, index, shared, gives), carry, tree
+            )
+            # a giving layer is a run of its own: its `ys` hold one layer
+            shared = {
+                **shared, **jax.tree_util.tree_map(lambda a: a[0], handed)
+            }
+            first += layers
             for name, value in stats.items():
                 gathered.setdefault(name, []).append(value)
         h, aux = carry
         with jax.named_scope("head"):
-            return rms_norm(h, params["ln_f"], eps), aux, {
+            return norm(h, params, "ln_f"), aux, {
                 name: jnp.concatenate(values)
                 for name, values in gathered.items()
             }
@@ -1498,6 +1871,11 @@ def plain_forward_stats(
                     name: _OVER_LAYERS[name](value)
                     for name, value in stats.items()
                 },
+            }
+        elif cfg.mixed:
+            stats = {
+                name: _OVER_LAYERS[name](value)
+                for name, value in stats.items()
             }
         with jax.named_scope("head"):
             return head(h), aux, stats

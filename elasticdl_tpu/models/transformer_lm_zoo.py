@@ -85,7 +85,25 @@ class TransformerLM:
             if self.cfg.shared_expert_gate:
                 stats["shared_gate_mean"] = np.zeros((), np.float32)
             return {"params": params, WINDOW_STATS: stats}
+        stats = self._dense_stack_stats()
+        if stats:
+            return {"params": params, WINDOW_STATS: stats}
         return {"params": params}
+
+    def _dense_stack_stats(self):
+        """The health readings of a mixed stack with no expert layer:
+        what its mixers leave in `window_stats` ({} for any other
+        model)."""
+        if self.cfg.n_experts or not self.cfg.mixed:
+            return {}
+        names = []
+        if "mamba1" in self.cfg.mixers:
+            names += ["ssm1_log_decay_min", "ssm1_dt_mean"]
+        if "gmu" in self.cfg.mixers:
+            names.append("gmu_gate_absmax")
+        if self.cfg.diff_attention:
+            names.append("diff_lambda_mean")
+        return {name: np.zeros((), np.float32) for name in names}
 
     def apply(self, variables, tokens, mutable=None):
         # the vectorized scan-over-layers fast path for dense AND MoE
@@ -112,7 +130,52 @@ class TransformerLM:
             return (logits, self.cfg.aux_weight * aux), {WINDOW_STATS: stats}
         if self.cfg.n_experts:
             return logits, self.cfg.aux_weight * aux
+        names = self._dense_stack_stats() if mutable else ()
+        if names:
+            return logits, {WINDOW_STATS: {n: stats[n] for n in names}}
         return logits
+
+
+def sambay_layers(n_published: int, held=None):
+    """A decoder-hybrid-decoder stack's (SambaY's) layers as
+    `TransformerConfig` settings, from the PUBLISHED depth and the
+    layers held here, `held` = (first, count) of the published ones
+    (None: all). Published layer i's mixer: up to the middle, N / 2,
+    "mamba1" where i is even and windowed attention "swa" where it is
+    odd, the middle one also giving the memory; N / 2 + 1 full
+    attention "mha" that also gives the shared keys and values; behind
+    it "gmu" (even) and "cross" (odd) that read them. -> layer_types,
+    diff_depths (each held layer's published index), memory_layer and
+    kv_layer (their index among the held layers, None where not held:
+    a cut that holds a reader without its feeder is refused when the
+    parameters are built)."""
+    first, count = held or (0, n_published)
+    half = n_published // 2
+    if half % 2 or not 0 <= first <= first + count <= n_published:
+        raise ValueError(
+            f"{n_published} published layers, held {held}: the middle "
+            "layer is a state-space one (N / 2 even) and the held layers "
+            "lie inside the stack"
+        )
+
+    def mixer(i):
+        if i <= half:
+            return "swa" if i % 2 else "mamba1"
+        if i == half + 1:
+            return "mha"
+        return "cross" if i % 2 else "gmu"
+
+    depths = tuple(range(first, first + count))
+
+    def among_held(i):
+        return i - first if i in depths else None
+
+    return dict(
+        layer_types=tuple(mixer(i) for i in depths),
+        diff_depths=depths,
+        memory_layer=among_held(half),
+        kv_layer=among_held(half + 1),
+    )
 
 
 def custom_model(**model_params):

@@ -680,6 +680,20 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 # twice the heads at 128; the ladder's first edge wins, so `pick_tiles`
 # reads no width. Against the float32 reference there: o 0.21 %, dq
 # 0.24 %, dk 0.30 %, dv 0.28 % of the largest entry.
+# Differential attention's call, both members of 20 pairs of heads:
+# queries and keys of 64 folded to [B*H, L, 64], the pairs' values of
+# 128 read where they lie (2026-10-03, the same chip kind and versions,
+# `scripts/swa_kernel_sweep.py --cases diff`), (1, 4096, 40, 64 | 128),
+# ms a call, forward + backward (forward alone), XLA | the kernels at
+# 1024 x 1024 | at 512 x 512:
+#   under a window of 512   17.91 (5.88) | 6.16 (1.69) | 4.93 (1.68)
+#   the whole triangle      17.92 (5.87) | 8.02 (2.28) | 10.03 (3.53)
+# The kernels win both (3.6 x under the band at the 512 x 512 that
+# `pick_tiles` gives a window of 512, 2.2 x in full at the ladder's
+# first edge), so the rule stays as it is: it reads the call's length
+# and nothing of its widths. Against the float32 reference at (1, 2048,
+# 8, 64 | 128), band and full: o 0.24 %, dq 0.53 %, dk 0.37-0.38 %, dv
+# 0.25-0.28 % of the largest entry.
 FLASH_MIN_LENGTH = 2048
 
 
@@ -691,8 +705,9 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
 
     On a TPU the Pallas kernels take a call whose sequence the tile
     ladder divides and that is at least FLASH_MIN_LENGTH long, at
-    every head width measured (64, 128, 192 | 128 and, since 2026-10-02,
-    256 at 8192 tokens: FLASH_MIN_LENGTH's table; the rule reads nothing
+    every head width measured (64, 128, 192 | 128, since 2026-10-02 256
+    at 8192 tokens and since 2026-10-03 64 | 128 at 4096, band and full:
+    FLASH_MIN_LENGTH's table; the rule reads nothing
     but the call's own shapes); XLA's attention takes the rest.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,
     EDL_TPU_FLASH=0 forces them off. The kernels hold their scores in

@@ -19,6 +19,11 @@ nothing to a score, dq and dk are cut back by the pad's own gradient).
 `--cases wide` (PR 52): the causal call at heads of 256, (1, 8192, 16,
 256), over the tile ladder, beside the same pairs at 32 heads of 128.
 
+`--cases diff` (PR 58): differential attention's call, 40 heads of
+64-wide queries and keys over 128-wide values at 4096 tokens, under the
+window of 512 and in full: XLA's materialised path | the kernels at the
+ladder's tiles.
+
     chiprun -- python scripts/swa_kernel_sweep.py --cases latent
 
 Writes chiprun_out/swa_kernel_sweep.<cases>.json. `--compile_only`
@@ -52,6 +57,7 @@ V_WIDTH = 128
 # deepseek-v2-lite's `mla_softmax_scale`: 192^-0.5 x mscale(40, 0.707)^2
 LATENT_SCALE = 192**-0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2
 WIDE = (1, 8192, 16, 256)
+DIFF = (1, 4096, 40, 64)  # both members of 20 pairs of heads
 # (shape, window, tiles, v_width, scale, path)
 CASES = {
     "wide": [
@@ -71,6 +77,13 @@ CASES = {
     ] + [
         (CAUSAL, None, (e, e), None, None, "kernels") for e in (512, 1024)
     ] + [(BANDED, None, (1024, 1024), None, None, "kernels")],
+    "diff": [
+        (DIFF, window, tiles, V_WIDTH, None, path)
+        for window in (WINDOW, None)
+        for tiles, path in (
+            (None, "xla"), ((1024, 1024), "kernels"), ((512, 512), "kernels"),
+        )
+    ],
     "latent": [
         (shape, None, tiles, V_WIDTH, LATENT_SCALE, path)
         for shape in LATENT
@@ -162,6 +175,10 @@ def main():
             for shape in LATENT
         ],
         "wide": [((1, 2048, 16, 256), {}), (WIDE, {})],
+        "diff": [
+            ((1, 2048, 8, 64), {"v_width": V_WIDTH, "window": w})
+            for w in (WINDOW, None)
+        ],
     }[args.cases]
     for shape, how in checks:
         errors = fa.check_against_reference(shape, **how)

@@ -1,5 +1,5 @@
-"""The three Pallas kernels compiled for a TPU v5e that is described,
-not attached (docs/performance.md): what Mosaic refuses (a block that
+"""The three Pallas attention kernels, and the selective scan's two,
+compiled for a TPU v5e that is described, not attached (docs/performance.md): what Mosaic refuses (a block that
 breaks the tiling rule, more VMEM than a kernel may take) is refused
 here, at the shapes the benchmark's cells run, at no chip time. Nothing
 executes. The topology is described inside a fixture, in this file
@@ -57,6 +57,10 @@ def no_compile_cache():
     (4, 2048, 16, 192, None, 128),  # deepseek-v2-lite
     (2, 2048, 32, 192, None, 128),  # kimi-linear-48b-a3b
     (1, 2048, 4, 192, 512, 128),  # and under a band, which no cell runs
+    # differential attention, both members of 20 pairs of heads: queries
+    # and keys of 64 folded, the pairs' values of 128 in place
+    (1, 4096, 40, 64, 512, 128),  # phi-4-mini-flash-reasoning's windowed layer
+    (1, 4096, 40, 64, None, 128),  # its full and cross layers
 ], ids=lambda s: "x".join(map(str, s[:4])) + "".join(
     f"-{n}{x}" for n, x in zip("wv", s[4:]) if x
 ))
@@ -119,3 +123,29 @@ def test_a_rematerialised_layer_keeps_the_forward_kernels_outputs(
     assert recomputed  # the projections, a second time
     again = [line for line in recomputed if hlo_scopes._KERNEL in line]
     assert len(again) == calls - 3
+
+
+def test_the_selective_scan_s_kernels_compile_at_the_cell_s_shape(
+    one_chip, no_compile_cache
+):
+    """Mamba-1's scan (`ops/selective_scan.py`) at (1, 4096, 5120) x 16,
+    bfloat16 x, B and C as the timed program hands them: the forward
+    kernel and the backward one, and nothing of [T, D, N] set aside."""
+    from elasticdl_tpu.ops import selective_scan as ss
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, dt, A, Bm, Cm):
+        with jax.named_scope("scan"):
+            y, _last = ss.selective_scan_kernels(x, dt, A, Bm, Cm)
+        return jnp.sum(y * y)
+
+    b, t, d, n = 1, 4096, 5120, 16
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec((b, t, d), jnp.bfloat16), spec((b, t, d)), spec((n, d)),
+        spec((b, t, n), jnp.bfloat16), spec((b, t, n), jnp.bfloat16),
+    ).compile()
+    assert hlo_scopes.kernels(compiled.as_text()) == {"scan": 2}
+    # the chunk starts are 10 MB; one [T, D, N] tensor would be 1.3 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
