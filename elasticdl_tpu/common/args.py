@@ -119,12 +119,14 @@ def add_model_spec_args(parser: argparse.ArgumentParser):
         help="worker overlap plane: on (default) pipelines window-delta "
         "encode/push on sync threads, pages model-down in on a "
         "background thread, and enables BET prefetch; off is the serial "
-        "chain: no device memory beside a window (16 B a parameter "
-        "resident, 20 at the sync's moment), no second delta on the "
-        "host, the same bytes to the same master in the same order "
-        "(A/B + exactness audits); a worker that is alone goes on once "
-        "the delta has left the chip, so the answer may arrive behind "
-        "the next window. EDL_OVERLAP_SYNC overrides.",
+        "chain: no device memory beside a window but one snapshot of "
+        "the model (16 B a parameter; a delta over 128 MiB is formed "
+        "on the host from that snapshot, a smaller one on the device: "
+        "20 B at the sync's moment), no second delta on the host, the "
+        "same bytes to the same master in the same order (A/B + "
+        "exactness audits); a worker that is alone goes on before its "
+        "sync is over, so the answer may arrive behind the next "
+        "window. EDL_OVERLAP_SYNC overrides.",
     )
     parser.add_argument("--log_level", default="INFO")
     parser.add_argument(

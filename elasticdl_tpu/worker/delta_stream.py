@@ -16,6 +16,22 @@ all of them and joins, as it always joined.
 `DeltaStream` is the copies' side of that: one short-lived thread a
 sync, which asks for the slices in order and hands each to whoever
 waits for it.
+
+What is cut into slices on the device is the delta itself on the
+overlapped chain (`Worker._delta_in_slices`). The serial chain, whose
+device has no room for a second vector beside a window, forms no delta
+on the device at all (PR 59): the base of window n+1 IS the model at
+the end of window n, a snapshot the device holds through window n+1
+anyway, so the stream copies out THAT, in the same slices, while window
+n+1 runs, and the delta is formed here, on the host: a landed slice
+less the slice that landed for the sync before (`base`), float32, into
+memory the worker keeps from sync to sync (`out`); the landed slices
+are the next sync's base (`snapshot()`). The request, the frame, the
+master and the answer see the same float32 delta either way. The
+host's float32 subtraction is IEEE's and so is the device's, bit for
+bit, but for one thing: a TPU flushes a subnormal difference to zero
+(|d| < 1.18e-38) and the host keeps it. Both are exact on the CPU,
+where the tests hold the two forms bit-identical.
 """
 
 from __future__ import annotations
@@ -68,34 +84,34 @@ class DeltaStream:
     `worker.d2h` span. A copy that fails fails every piece not yet
     landed, with the error that stopped it.
 
-    `on_end`, where given, is called once on the stream's thread when
-    it holds no slice any more: the last one has landed, or a copy
-    failed. The device then keeps nothing for this stream."""
+    `base`, where given, makes the slices a SNAPSHOT's and the pieces
+    differences: piece i is what landed less `base[i]` (the host
+    arrays an earlier stream's `snapshot()` gave), formed on the
+    stream's own thread as each slice lands, into `out[lo:hi]` where
+    the caller keeps memory for it (a landed array is the runtime's and
+    read-only, so nothing is subtracted in place). The list is the
+    stream's from then on: each old slice is let go as it is used.
+    `snapshot()` gives the landed slices, the next base, once `settle()`
+    has returned, or None if a copy failed; `subtracting()` says
+    from when to when the subtractions ran and how long they took."""
 
-    def __init__(self, bounds, slices: Iterator, on_end=None):
+    def __init__(self, bounds, slices: Iterator, base=None, out=None):
         self._bounds = list(bounds)
         self._slices = slices
-        self._on_end = on_end
+        self._base, self._out = base, out
+        self._landed = [None] * len(self._bounds) if base is not None else None
+        self._subtracting = [0.0, 0.0, 0.0]  # first began, last ended, busy
         # what the stream's thread hands to whoever waits, under `_cond`
         self._cond = threading.Condition()
         self._host = [None] * len(self._bounds)
         self._error = None
         self._t_first = self._t_last = 0.0
         self._thread = threading.Thread(
-            target=self._run, name="delta-stream", daemon=True
+            target=self._copy, name="delta-stream", daemon=True
         )
 
     def start(self) -> None:
         self._thread.start()
-
-    def _run(self):
-        try:
-            # its locals (the slice asked for last, the generator that
-            # cuts them) are gone when it returns
-            self._copy()
-        finally:
-            if self._on_end is not None:
-                self._on_end()
 
     def _copy(self):
         count = len(self._bounds)
@@ -115,9 +131,14 @@ class DeltaStream:
                 # blocks until the copy has landed; the device's slice
                 # goes with this reference, the host's copy stays
                 host = np.asarray(in_flight.popleft())
+                landed = time.time()
+                snapshot = self._base is not None
+                piece = self._less_base(i, host, landed) if snapshot else host
                 with self._cond:
-                    self._host[i] = host
-                    self._t_last = time.time()
+                    self._host[i] = piece
+                    if snapshot:
+                        self._landed[i] = host
+                    self._t_last = landed
                     self._cond.notify_all()
         except BaseException as e:  # handed to whoever waits, not lost
             # (with its traceback, which keeps this frame: let go here
@@ -127,6 +148,19 @@ class DeltaStream:
             with self._cond:
                 self._error = e
                 self._cond.notify_all()
+
+    def _less_base(self, i: int, new, began: float):
+        """Slice i of the delta: what landed less the base's slice,
+        which is let go."""
+        lo, hi = self._bounds[i]
+        out = None if self._out is None else self._out[lo:hi]
+        old, self._base[i] = self._base[i], None
+        piece = np.subtract(new, old, out=out)
+        ended = time.time()
+        took = self._subtracting
+        took[0] = took[0] or began
+        took[1], took[2] = ended, took[2] + ended - began
+        return piece
 
     def _wait(self, i: int, timeout):
         with self._cond:
@@ -158,3 +192,14 @@ class DeltaStream:
         self._thread.join()
         with self._cond:
             return self._t_first, self._t_last
+
+    def snapshot(self):
+        """The landed slices of a stream that was given a `base`."""
+        with self._cond:
+            if self._error is not None or self._landed is None:
+                return None
+            return None if any(a is None for a in self._landed) else self._landed
+
+    def subtracting(self) -> Tuple[float, float, float]:
+        with self._cond:
+            return tuple(self._subtracting)

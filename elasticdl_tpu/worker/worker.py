@@ -143,6 +143,17 @@ def _program_name(program) -> str:
     return "jit_" + getattr(program, "__name__", "unnamed")
 
 
+def _shifted(base, shift):
+    """A base (one vector, or the serial chain's tuple of slices) moved
+    by an absorbed merged model's `shift`, in the form it came in."""
+    if not isinstance(base, tuple):
+        return base + shift
+    ends = np.cumsum([piece.shape[0] for piece in base]).tolist()
+    return tuple(
+        piece + shift[hi - piece.shape[0]:hi] for piece, hi in zip(base, ends)
+    )
+
+
 def validate_eval_metrics(raw: dict):
     """Only dicts that ARE mergeable states (api/metrics.py) may ride
     the eval wire as states: an arbitrary dict would be example-weight
@@ -204,6 +215,7 @@ class Worker:
             "_base_snapshots",
             "_spawn_abs",
             "_sync_hold",
+            "_host_base",
         ),
     }
     # phase-timeline state with class defaults: a skeleton built with
@@ -369,10 +381,13 @@ class Worker:
         self._window_join_fn = None
         self._window_ahead = None  # its loss: the window a cut waits for
         self._opt_state = None
-        self._base_flat = None  # device copy of params at last sync
+        # device copy of params at last sync: one vector, or, where the
+        # serial chain copies it out in slices (`_base_in_slices`), the
+        # tuple of those slices
+        self._base_flat = None
         self._subtract_into_base = None  # jitted on the serial chain
         self._subtract_in_slices = None  # jitted on the overlapped chain
-        self._slice_programs = {}  # slice length -> jitted `delta_slice`
+        self._snapshot_in_slices = None  # jitted on the serial chain
         self._base_version = -1
         self._pending_steps = 0
         self._sync_thread = None  # tail of the chained async delta pushes
@@ -396,10 +411,12 @@ class Worker:
         # threads, pages model-down in on a background thread that
         # stages at step boundaries, and runs BET prefetch; off forces
         # the serial chain (depth 0, no background pull, no prefetch):
-        # no device memory beside a window (16 B a parameter resident,
-        # 20 at the sync's moment: the step loop waits until the delta
-        # has left the chip and is deleted there), no second delta on
-        # the host (a sync settles before the next delta is formed),
+        # no device memory beside a window but one snapshot of the
+        # model (16 B a parameter resident; 20 at the sync's moment
+        # only where the delta is formed on the device: the step loop
+        # then waits until it has left the chip and is deleted there),
+        # no second delta on the host (a sync settles before the next
+        # delta is formed),
         # the same bytes to the same master in the same order as the
         # pre-overlap path, for A/B and exactness audits. What it no
         # longer promises a worker that is alone: the answer may arrive
@@ -508,10 +525,12 @@ class Worker:
         self._base_snapshots: Dict[int, Any] = {}  # seq -> base at spawn
         self._sync_error = None  # exception raised by the async push
         # Serial chain: why the step loop waits for a WHOLE sync, or
-        # None: it goes on at the release point (the host holds all
-        # the sync reads from the device, the delta is deleted there)
-        # and the rest of the send, the master's apply, the answer and
-        # the reports run behind the next window. By what the worker
+        # None: it goes on at the spawn (a delta in slices: the sync
+        # copies the snapshot out beside the next window) or at the
+        # release point of a delta formed on the device (the host
+        # holds it, it is deleted there), and the send, the master's
+        # apply, the answer and the reports run behind the next
+        # window. By what the worker
         # has seen, no flag: "first" until a sync of this trajectory
         # has settled (a worker's first, and the first after a reset),
         # "merged" while its last settled sync's answer brought a
@@ -521,7 +540,19 @@ class Worker:
         # thread under `_report_lock`.
         self._sync_hold: Optional[str] = "first"
         # how many serial-chain syncs let the step loop go where
-        self.sync_releases = {"copied": 0, "settled": 0}
+        self.sync_releases = {"snapshot": 0, "copied": 0, "settled": 0}
+        # Serial chain, a delta that leaves in slices: the host's copy
+        # of `_base_flat`'s slices, as the last sync's stream landed
+        # them (`delta_stream.DeltaStream.snapshot`), which the next
+        # sync's landed snapshot is subtracted from ON THE HOST; None
+        # where the device's base has been replaced or shifted since
+        # (a pull, an init, a reset, an absorbed merged model): the next
+        # sync then copies the old base out first, and is waited for
+        # whole. `_delta_scratch` is the memory the differences are
+        # written into, one delta's worth kept from sync to sync (a sync
+        # has settled before the next delta is formed).
+        self._host_base = None
+        self._delta_scratch = None
         # Per-step pipelining (sync-SGD latency hiding): with
         # `step_pipeline` = k > 0, up to k gradient reports ride the
         # link on background threads while later batches compute on the
@@ -1725,10 +1756,18 @@ class Worker:
             with self.timers.phase("rebase"):
                 tx = self._spec.optimizer()
                 self._opt_state = tx.init(self._flat)
-                with self._first_call("jit_copy"):
-                    self._base_flat = jnp.copy(self._flat)
+                bounds = self._delta_slice_bounds()
+                if bounds is not None and not self._max_inflight_syncs:
+                    # the serial chain's base is a snapshot in slices;
+                    # the host's copy was of the vector replaced here
+                    self._base_flat = None
+                    self._base_flat = self._base_in_slices(bounds)
+                else:
+                    with self._first_call("jit_copy"):
+                        self._base_flat = jnp.copy(self._flat)
                 with self._report_lock:
                     self._base_version = self._version
+                    self._host_base = None
 
     def _local_minibatch(self, features, labels, task: Task, embs=None):
         self._ensure_local_ready(features, task)
@@ -1925,6 +1964,18 @@ class Worker:
 
             if self._window_ahead is not None:
                 jax.block_until_ready(self._window_ahead)
+            elif isinstance(self._base_flat, tuple):
+                # the serial chain's snapshot, asked for at the sync's
+                # spawn a batch's staging ago: a program's results are
+                # allocated when it is asked for, and a window asked
+                # for while the join and the snapshot still run finds
+                # the vectors they read, given up but not yet free,
+                # beside its own (`window_resident_gb` + a vector:
+                # PERF.md, PR 59). The device has mostly made it by
+                # now; no copy is waited for
+                with self.timers.phase("sync_wait"):
+                    with self._sync_exposed("snapshot"):
+                        jax.block_until_ready(self._base_flat)
             vector = flat.shape
             model = cut(flat)
             state = jax.tree_util.tree_map(  # each let go before the next
@@ -2082,22 +2133,35 @@ class Worker:
         unreported, so the dispatcher requeues it.
 
         The serial chain (`--overlap_sync off`, depth 0) keeps no
-        device memory beside a window and no second delta on the host:
-        the step loop waits after the spawn until the sync's RELEASE
-        POINT, where the host holds all the sync reads from the device
-        (the stream's last slice has landed, or the one `device_get`
-        has returned) and the delta is deleted there. While
-        `_sync_hold` is None it goes on from there and the thread
-        finishes alone, as the overlapped chain's threads do: the rest
-        of the send, the master's apply, the answer, the reports. That
-        sync is settled (`worker.sync_exposed`, `reason="settle"`)
-        before the next delta is formed, so the next subtraction
-        donates its base as ever, and an answer that did bring a
-        merged model is absorbed there, a window late, against its
-        base snapshot. Otherwise (a first sync, a merged answer last
-        time, a sparse plane, a drain) the step loop waits for the
-        whole sync. The span's `released` says which: `"copied"`, or
-        `"settled"` with `why`."""
+        device memory beside a window but one snapshot of the model's
+        vector, and no second delta on the host. A delta that
+        `delta_stream` carries (`_delta_slice_bounds`) is never formed
+        on the device there: the spawn asks for ONE program, the new
+        base as a snapshot of `_flat` in slices (`_base_in_slices`),
+        before the next window's programs, and the sync's thread
+        copies those slices out while that window runs and subtracts
+        the host's copy of the old base from each as it lands
+        (`delta_stream.DeltaStream(base=...)`); what landed is the
+        host's base of the next sync. The step loop goes on at once
+        (`_run_window` waits until the device has made the snapshot
+        before it cuts the vector; for no copy): `released:
+        "snapshot"`. Every other delta of the serial chain
+        (under one slice, another wire form, PS shards) is subtracted
+        on the device into its donated base and fetched by one
+        `device_get`, and the step loop waits until that has returned
+        and the delta is deleted: `released: "copied"`. Either way the
+        thread finishes alone, as the overlapped chain's threads do
+        (the copy and the host's subtraction, the send, the master's
+        apply, the answer, the reports), only while `_sync_hold` is
+        None. That sync is settled (`worker.sync_exposed`,
+        `reason="settle"`) before the next delta is formed, so the
+        host holds one delta and one base, no snapshot holds the old
+        base, and an answer that did bring a merged model is absorbed
+        there, a window late, against its base snapshot. Otherwise (a
+        first sync, a merged answer last time, a sparse plane, a
+        drain, no host base to subtract: it is then copied out first)
+        the step loop waits for the whole sync, of the same form:
+        `released: "settled"` with `why`."""
         if blocking:
             self._join_sync()
         else:
@@ -2130,25 +2194,32 @@ class Worker:
         # finished sync's thread hands its id on to a later one)
         run_seq = self._device_runs.seq
         delta_f32_bytes = int(self._flat.shape[0]) * 4
-        # a plain float32 delta for the single master, longer than one
-        # slice, leaves the device in slices (worker/delta_stream.py)
-        slice_bounds = cut_ahead = None
-        if (
-            delta_f32_bytes > delta_stream.DELTA_SLICE_BYTES
-            and self._transport_dtype == "float32"
-            and not self._lossy_sync()
-            and not self._ps_endpoints
-        ):
-            slice_bounds = delta_stream.slice_bounds(delta_f32_bytes // 4)
-        if slice_bounds is not None and self._max_inflight_syncs:
+        slice_bounds = self._delta_slice_bounds()
+        cut_ahead = delta_dev = snapshot = given_up = None
+        base_on_device = False
+        if slice_bounds is None:
+            with self._first_call("jit_subtract"):
+                delta_dev = self._delta_from_base()
+        elif not serial:
             # the step loop gives the device its next window before
             # this sync is over, and a program asked for then would
             # wait for that window's end: the delta is formed in its
             # slices here, in the step loop's own order
-            cut_ahead, delta_dev = self._delta_in_slices(slice_bounds), None
+            cut_ahead = self._delta_in_slices(slice_bounds)
         else:
-            with self._first_call("jit_subtract"):
-                delta_dev = self._delta_from_base()
+            # no delta on the device: the new base in its slices, one
+            # program asked for here for the same reason, and the old
+            # base let go before it (model, moments, one snapshot)
+            # unless the host has no copy of it to subtract; either
+            # way it is the sync's thread's from here on
+            with self._report_lock:
+                base, self._host_base = self._host_base, None
+            base_on_device = base is None
+            given_up = [self._base_flat if base_on_device else base]
+            base = self._base_flat = None
+            snapshot = self._base_flat = self._base_in_slices(slice_bounds)
+            if self._delta_scratch is None:
+                self._delta_scratch = np.empty(delta_f32_bytes // 4, np.float32)
         wire_meta = None
         wire_form = None
         link_mbps = None
@@ -2174,8 +2245,10 @@ class Worker:
                 # a flush of the sparse plane lands before the next
                 # lookup (`_run_local_windows`)
                 why = "sparse"
+            if base_on_device:
+                why = why or "base"  # it is copied out first, below
             released = {"released": "settled", "why": why} if why else {
-                "released": "copied"
+                "released": "copied" if snapshot is None else "snapshot"
             }
             self.sync_releases[released["released"]] += 1
             at_release = threading.Event()
@@ -2233,8 +2306,9 @@ class Worker:
         # sink attributed to the version this delta produces (task-end
         # losses in `losses` can belong to earlier windows)
         step_loss = self._latest_step_loss
-        with self._first_call("jit_copy"):
-            self._base_flat = jnp.copy(self._flat)
+        if snapshot is None:
+            with self._first_call("jit_copy"):
+                self._base_flat = jnp.copy(self._flat)
         self._pending_steps = 0
         prev = self._sync_thread
         with self._report_lock:
@@ -2265,9 +2339,9 @@ class Worker:
             self._spawn_abs[seq] = self._own_steps_abs
 
         def release():
-            # the serial chain's release point, on the thread that saw
-            # the last of the delta land: nothing of this sync is left
-            # on the device but a few scalars
+            # the serial chain's release point for a delta formed on
+            # the device, on the thread that saw it land: nothing of
+            # this sync is left on the device but a few scalars
             for leaf in jax.tree_util.tree_leaves(delta_dev):
                 leaf.delete()
             at_release.set()
@@ -2310,11 +2384,11 @@ class Worker:
                 [g for _, g in pending_edl],
             )
             with self._chain_span("worker.delta_wait", seq=run_seq):
-                # the device finishes the window and the delta; what
-                # follows is the copy out alone
-                jax.block_until_ready(
-                    (delta_dev if cut_ahead is None else list(cut_ahead), small)
-                )
+                # the device finishes the window and the delta (or the
+                # snapshot); what follows is the copy out alone
+                jax.block_until_ready((
+                    delta_dev, snapshot, cut_ahead and list(cut_ahead), small,
+                ))
             self._first_run_settled()
             stream = None
             if slice_bounds is None:
@@ -2326,28 +2400,34 @@ class Worker:
                     release()
             else:
                 # the slices' copies start now and land behind the
-                # request, which sends each as it does; on the serial
-                # chain the device stands still for this sync, so a
-                # slice is cut only as its copy is asked for and the
-                # device never holds more than those in flight beside
-                # the delta. There the small arrays come first, so
-                # that the stream's end is the release point.
+                # request, which sends each as it does
                 if serial:
-                    small_h = jax.device_get(small)
-                stream = delta_stream.DeltaStream(
-                    slice_bounds,
-                    (cut_ahead.popleft() for _ in slice_bounds)
-                    if cut_ahead is not None
-                    else (
-                        self._delta_slice(delta_dev, lo, hi)
-                        for lo, hi in slice_bounds
-                    ),
-                    on_end=release if serial else None,
-                )
+                    # the snapshot's slices, beside the next window:
+                    # the host subtracts its base from each as it
+                    # lands; the device keeps them, they are its base
+                    base = given_up.pop()
+                    if base_on_device:
+                        # rare (a trajectory's first sync, the sync
+                        # after an absorbed merged model): a held
+                        # sync, which pays one more copy out
+                        with self._chain_span(
+                            "worker.base_d2h", bytes=delta_f32_bytes,
+                            slices=len(slice_bounds), seq=run_seq,
+                        ):
+                            base = jax.device_get(base)
+                    stream = delta_stream.DeltaStream(
+                        slice_bounds, iter(snapshot), base=list(base),
+                        out=self._delta_scratch,
+                    )
+                    del base
+                else:
+                    stream = delta_stream.DeltaStream(
+                        slice_bounds,
+                        (cut_ahead.popleft() for _ in slice_bounds),
+                    )
                 stream.start()
                 delta_h = stream.vector()
-                if not serial:
-                    small_h = jax.device_get(small)  # beside the slices
+                small_h = jax.device_get(small)  # beside the slices
             aux_h, loss_h, step_loss_h, gbets_h = small_h
             stats = (aux_h or {}).get(WINDOW_STATS)
             if stats:
@@ -2476,11 +2556,22 @@ class Worker:
                             bytes=delta_f32_bytes, slices=len(slice_bounds),
                             seq=run_seq,
                         )
+                        if snapshot is not None:
+                            # the host's subtractions, first to last
+                            t_first, t_last, busy = stream.subtracting()
+                            self.timers.record_span(
+                                "worker.host_delta", t_first, t_last,
+                                busy_ms=round(busy * 1e3, 3),
+                                bytes=delta_f32_bytes, seq=run_seq,
+                            )
                 self._observe_push(delta_h, push_t0, wire_form)
             with self._report_lock:
                 if epoch != self._sync_epoch:
                     return  # reset raced the RPC: discard the response
                 self._synced_seq = max(self._synced_seq, seq)
+                if snapshot is not None:
+                    # what landed is what the device's base holds
+                    self._host_base = stream.snapshot()
                 merged_back = resp.get("params_flat") is not None
                 self._sync_hold = "merged" if merged_back else None
                 if versions is not None:
@@ -2563,11 +2654,15 @@ class Worker:
             self._sync_inflight.append(t)
             t.start()
             if serial and not why:
-                # to the release point; the thread goes on alone and
-                # is joined before the next delta is formed (above)
-                with self.timers.phase("sync_wait"):
-                    with self._sync_exposed("backpressure"):
-                        at_release.wait()
+                # the thread goes on alone and is joined before the
+                # next delta is formed (above). A delta formed on the
+                # device holds the step loop to its release point; a
+                # snapshot holds it nowhere here (`_run_window` waits
+                # until the device has made it, before the cut)
+                if snapshot is None:
+                    with self.timers.phase("sync_wait"):
+                        with self._sync_exposed("backpressure"):
+                            at_release.wait()
                 return
             # backpressure: bound in-flight windows (device memory for
             # their feature buffers + requeue exposure on preemption)
@@ -2576,9 +2671,26 @@ class Worker:
                     with self._sync_exposed("backpressure"):
                         self._sync_inflight.popleft().join()
 
+    def _delta_slice_bounds(self):
+        """[lo, hi) of the slices a window's delta leaves in
+        (worker/delta_stream.py): a plain float32 delta for the single
+        master, longer than one slice. None for every other delta,
+        which one `device_get` fetches whole. Fixed for a worker's
+        life by its flags and its model."""
+        n = int(self._flat.shape[0])
+        if (
+            n * 4 > delta_stream.DELTA_SLICE_BYTES
+            and self._transport_dtype == "float32"
+            and not self._lossy_sync()
+            and not self._ps_endpoints
+        ):
+            return delta_stream.slice_bounds(n)
+        return None
+
     def _delta_from_base(self):
-        """flat - base, in a buffer of its own (the sync thread reads it
-        while the step loop goes on). On the serial chain the old base
+        """flat - base, whole, in a buffer of its own (the sync thread
+        reads it while the step loop goes on): the form of every delta
+        that does not leave in slices. On the serial chain the old base
         is DONATED to the subtraction, so the delta lies where the base
         lay and the new base (`jit_copy`, next) is the only buffer the
         sync's moment adds: 20 B a parameter at that moment (flat, two
@@ -2586,7 +2698,9 @@ class Worker:
         flight the base may still be the snapshot an unsettled sync's
         merged model is folded in against (`_base_snapshots`), and a
         job takes one of the two forms for its whole life, so nothing
-        compiles after set-up."""
+        compiles after set-up. A delta that leaves in slices is formed
+        by `_delta_in_slices` on the overlapped chain and, on the
+        serial chain, not on the device at all (`_base_in_slices`)."""
         with self._report_lock:
             held = any(
                 snap is self._base_flat
@@ -2624,25 +2738,27 @@ class Worker:
         with self._first_call(self._subtract_in_slices, args):
             return deque(self._subtract_in_slices(*args))
 
-    def _delta_slice(self, delta_dev, lo: int, hi: int):
-        """Elements [lo, hi) of the device's delta in a buffer of their
-        own, for `delta_stream` on the serial chain, where the delta
-        lies whole in the donated base's place and the device stands
-        still for the sync: one jitted program a slice length (all
-        equal slices share one, the tail has its own), the start
-        traced, so a job compiles two in its first sync and none
-        after."""
-        size = hi - lo
-        program = self._slice_programs.get(size)
-        if program is None:
+    def _base_in_slices(self, bounds):
+        """The model's vector as it stands, copied into the slices it
+        will leave the device in, each a buffer of its own, by one
+        program (the device trace's `jit_snapshot`): the serial
+        chain's base where a delta leaves in slices. Nothing is
+        subtracted on the device there: this tuple is the base of the
+        window that follows AND what the sync of the window before
+        copies out, beside that window, for the host to subtract its
+        copy of the base before from (`_sync_local_updates`). 4 B a
+        parameter through the window, as a whole base was, and nothing
+        more at the sync's moment once the old base has been let go."""
+        if self._snapshot_in_slices is None:
+            bounds = tuple(bounds)
 
-            def delta_slice(delta, start):  # the trace's `jit_delta_slice`
-                return jax.lax.dynamic_slice(delta, (start,), (size,))
+            def snapshot(flat):
+                return tuple(flat[lo:hi] for lo, hi in bounds)
 
-            program = self._slice_programs[size] = jax.jit(delta_slice)
-        args = (delta_dev, np.int32(lo))
-        with self._first_call(program, args):
-            return program(*args)
+            self._snapshot_in_slices = jax.jit(snapshot)
+        args = (self._flat,)
+        with self._first_call(self._snapshot_in_slices, args):
+            return self._snapshot_in_slices(*args)
 
     @property
     def sync_decisions(self):
@@ -2769,6 +2885,7 @@ class Worker:
             self._sync_hold = "first"  # of the trajectory pulled next
             self._absorb_staged = None  # staged page-in predates the reset
             self._base_snapshots.clear()
+            self._host_base = None  # of a vector that is dropped here
             # lineage dies with the trajectory; the forced re-pull is
             # the next fold point
             self._lineage_version = -1
@@ -2999,6 +3116,8 @@ class Worker:
                 del self._base_snapshots[k]
             if snap is None:
                 return  # reset raced the response: state discarded
+            if isinstance(snap, tuple):  # the serial chain's, in slices
+                snap = jnp.concatenate(snap)
             # the merged progress is folded into the local trajectory
             # below — deltas spawned from HERE on really are computed
             # from the new version, so the LINEAGE advances here (see
@@ -3030,9 +3149,12 @@ class Worker:
                 merged = jnp.asarray(np.asarray(params_flat, dtype=np.float32))
             shift = merged - snap
             for k in list(self._base_snapshots):  # younger, unsettled
-                self._base_snapshots[k] = self._base_snapshots[k] + shift
+                self._base_snapshots[k] = _shifted(self._base_snapshots[k], shift)
+            # the host's copy is of the base before the shift: the next
+            # sync copies the shifted one out (it is held: `merged`)
+            self._host_base = None
         self._flat = self._flat + shift
-        self._base_flat = self._base_flat + shift
+        self._base_flat = _shifted(self._base_flat, shift)
         if aux:
             self._aux = jax.tree_util.tree_map(jnp.asarray, aux)
 

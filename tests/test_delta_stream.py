@@ -2,7 +2,8 @@
 PR 45): the copies' side alone, over fake device arrays, and a tiny
 window job whose slice size is lowered so its delta takes several, on
 the serial and on the overlapped chain, against the same job with the
-delta in one copy."""
+delta in one copy. On the serial chain the slices are a snapshot's and
+the host subtracts its base from each (PR 59)."""
 
 import threading
 import time
@@ -131,6 +132,124 @@ def test_a_copy_that_fails_fails_every_piece_not_yet_landed():
         assert ei.value.__cause__ is boom
 
 
+def _read_only(values):
+    landed = np.array(values, np.float32)
+    landed.flags.writeable = False  # as the runtime hands a copy out
+    return landed
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept_memory", "fresh"])
+def test_a_snapshots_slices_less_the_base_are_the_delta(kept):
+    """Piece i is what landed less the base's slice, float32, in the
+    caller's memory where it keeps some; what landed is the next base,
+    untouched; the old base is let go slice by slice."""
+    rng = np.random.default_rng(59)
+    old = rng.standard_normal(10).astype(np.float32)
+    new = (old + rng.standard_normal(10) * 1e-3).astype(np.float32)
+    bounds = [(0, 4), (4, 8), (8, 10)]
+    log, gate = [], threading.Event()
+    base = [_read_only(old[lo:hi]) for lo, hi in bounds]
+    landed = [_read_only(new[lo:hi]) for lo, hi in bounds]
+    out = np.full(10, np.nan, np.float32) if kept else None
+    stream = delta_stream.DeltaStream(
+        bounds,
+        (
+            _DevicePiece(log, i, landed[i], gate if i == 2 else None)
+            for i in range(3)
+        ),
+        base=base, out=out,
+    )
+    vector = stream.vector()
+    stream.start()
+    first = vector.pieces[0].landed(5)
+    assert first.tobytes() == (new[:4] - old[:4]).tobytes()
+    assert base[0] is None and stream.snapshot() is None  # not all landed yet
+    gate.set()
+    t_first, t_last = stream.settle()
+    assert np.asarray(vector).tobytes() == (new - old).tobytes()
+    if kept:
+        assert out.tobytes() == (new - old).tobytes()
+        assert np.shares_memory(first, out)
+    assert base == [None] * 3
+    snapshot = stream.snapshot()
+    assert all(a is b for a, b in zip(snapshot, landed))
+    assert np.concatenate(snapshot).tobytes() == new.tobytes()
+    began, ended, busy = stream.subtracting()
+    assert t_first <= began <= ended <= time.time() and 0 <= busy <= ended - began
+    assert codec.dumps({"d": vector}) == codec.dumps({"d": new - old})
+
+
+def test_the_hosts_difference_is_ieee_and_keeps_a_subnormal():
+    """Where the two forms can differ: a TPU flushes a subnormal
+    difference to zero, the host keeps it. Everything else is the one
+    correctly rounded float32 subtraction."""
+    old = np.array([1e-38, 1.0, -3.5, 0.0], np.float32)
+    new = np.array([1.1e-38, 1.0 + 2**-23, 2**24, -0.0], np.float32)
+    stream = delta_stream.DeltaStream(
+        [(0, 4)], iter([_DevicePiece([], 0, _read_only(new))]),
+        base=[_read_only(old)],
+    )
+    stream.start()
+    stream.settle()
+    (piece,) = stream.vector().pieces
+    got = piece.landed(1)
+    assert got.dtype == np.float32
+    assert got.tobytes() == (new - old).tobytes()
+    assert 0 < got[0] < np.finfo(np.float32).tiny  # subnormal, kept
+    assert got[1] == np.float32(2**-23) and got[2] == np.float32(2**24 + 4)
+
+
+def test_a_snapshot_whose_copy_fails_is_no_base():
+    vec = np.arange(12, dtype=np.float32)
+    bounds = [(0, 4), (4, 8), (8, 12)]
+    boom = ValueError("device lost")
+    stream = delta_stream.DeltaStream(
+        bounds,
+        (
+            _DevicePiece(
+                [], i, _read_only(vec[slice(*bounds[i])]),
+                error=boom if i == 1 else None,
+            )
+            for i in range(3)
+        ),
+        base=[_read_only(np.ones(4)) for _ in bounds],
+    )
+    vector = stream.vector()
+    stream.start()
+    stream.settle()
+    assert vector.pieces[0].landed(1).tobytes() == (vec[:4] - 1).tobytes()
+    for piece in vector.pieces[1:]:
+        with pytest.raises(RuntimeError, match="did not land") as ei:
+            piece.landed(1)
+        assert ei.value.__cause__ is boom
+    assert stream.snapshot() is None
+
+
+def test_the_thread_keeps_no_slice_when_it_has_ended():
+    """The stream's thread lets go of every device slice it was handed
+    (the slice asked for last, the generator that cuts them) when it
+    returns: a landed slice lives on only where its owner keeps it."""
+    import gc
+    import weakref
+
+    vec = np.arange(10, dtype=np.float32)
+    bounds = [(0, 4), (4, 8), (8, 10)]
+    alive = []
+
+    def cut(i):
+        piece = _DevicePiece([], i, vec[slice(*bounds[i])])
+        alive.append(weakref.ref(piece))
+        return piece
+
+    stream = delta_stream.DeltaStream(bounds, (cut(i) for i in range(3)))
+    vector = stream.vector()
+    stream.start()
+    stream.settle()
+    gc.collect()
+    assert [r() is not None for r in alive] == [False] * 3
+    assert np.array_equal(np.asarray(vector), vec)
+
+
 # -- a tiny window job --------------------------------------------------------
 
 
@@ -240,20 +359,27 @@ def test_a_sliced_delta_trains_the_same_model_bit_for_bit(
     # the master received into the frame as before and applied in place
     applies = _named(spans, "apply")
     assert len(applies) == len(syncs)
-    # the serial chain cuts its whole delta with two programs more
-    # than the job with one slice (equal slices, the tail); the
-    # overlapped chain's `jit_subtract` forms the delta in its slices,
-    # so it has the same programs. All in set-up: nothing compiles
-    # once the first sync has settled
+    # the serial chain forms no delta on its device: one program, the
+    # snapshot in slices, where the job with one slice has the
+    # subtraction and the base's copy, and the host subtracts inside
+    # every sync; the overlapped chain's `jit_subtract` forms the delta
+    # in its slices, so it has the one-slice job's programs. All in
+    # set-up: nothing compiles once the first sync has settled
     programs = [s["args"]["program"] for s in _named(spans, "setup.program")]
-    assert programs.count("jit_delta_slice") == (2 if chain == "off" else 0)
     whole_programs = [
         s["args"]["program"] for s in _named(whole[0], "setup.program")
     ]
-    assert sorted(p for p in programs if p != "jit_delta_slice") == sorted(
-        whole_programs
-    )
-    assert programs.count("jit_subtract") == 1
+    assert whole_programs.count("jit_subtract") == 1
+    if chain == "off":
+        assert sorted(programs + ["jit_subtract", "jit_copy"]) == sorted(
+            whole_programs + ["jit_snapshot"]
+        )
+        assert [s["tid"] for s in _named(spans, "worker.host_delta")] == [
+            s["tid"] for s in syncs
+        ]
+    else:
+        assert sorted(programs) == sorted(whole_programs)
+        assert not _named(spans, "worker.host_delta")
     first_settled = syncs[0]["ts"] + syncs[0]["dur"]
     assert compiles and not [e for e in compiles if e[1] > first_settled]
     # and the one-slice job says so
@@ -284,8 +410,9 @@ def test_another_wire_form_takes_the_one_copy(tmp_path, monkeypatch, worker_kw):
     assert all(t["args"]["streamed"] is False for t in trips)
     assert not [
         s for s in _named(spans, "setup.program")
-        if s["args"]["program"] == "jit_delta_slice"
+        if s["args"]["program"] == "jit_snapshot"
     ]
+    assert not _named(spans, "worker.host_delta")
 
 
 def test_a_slice_that_fails_to_land_is_a_failed_sync(tmp_path, monkeypatch):
@@ -293,17 +420,15 @@ def test_a_slice_that_fails_to_land_is_a_failed_sync(tmp_path, monkeypatch):
     a peer that closed mid-frame and applies nothing, and the worker
     takes the "sync failed" path it always had: the window's tasks go
     back to the dispatcher and the job ends at the exact version."""
-    from elasticdl_tpu.worker.worker import Worker
+    real, calls = delta_stream.DeltaStream._less_base, []
 
-    real, calls = Worker._delta_slice, []
-
-    def delta_slice(self, delta_dev, lo, hi):
-        calls.append(lo)
+    def less_base(self, i, new, began):
+        calls.append(i)
         if len(calls) == 5:  # the second sync's second slice
             raise RuntimeError("the device lost slice 1")
-        return real(self, delta_dev, lo, hi)
+        return real(self, i, new, began)
 
-    monkeypatch.setattr(Worker, "_delta_slice", delta_slice)
+    monkeypatch.setattr(delta_stream.DeltaStream, "_less_base", less_base)
     spans, _params, version, _ = _run_job(tmp_path, monkeypatch, 16, "off")
     assert version == 192 // 16
     trips = _named(spans, "rpc.client.ReportLocalUpdate")

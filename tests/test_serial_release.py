@@ -1,10 +1,15 @@
-"""The serial chain lets the step loop go at the sync's release point
-(PR 57): the host holds all the sync reads from the device and the
-delta is deleted there; the rest of the send, the master's apply, the
-answer and the reports run behind the next window. A tiny window job
-against an in-process master whose `ReportLocalUpdate` is held for a
-moment, the delta in slices (the slice lowered to four floats, as
-`test_delta_stream.py` does) and in one copy."""
+"""The serial chain lets the step loop go before its sync is over
+(PR 57), and since PR 59 before the sync has copied anything where the
+delta leaves in slices: the device forms no delta there, the sync's
+thread copies the next window's base out beside that window and the
+host subtracts its copy of the base before (`released: "snapshot"`). A
+delta in one copy is formed on the device and holds the step loop
+until it has left and is deleted (`released: "copied"`). The rest (the
+send, the master's apply, the answer, the reports) runs behind the next
+window either way. A tiny window job against an in-process master whose
+`ReportLocalUpdate` is held for a moment, the delta in slices (the
+slice lowered to four floats, as `test_delta_stream.py` does) and in
+one copy."""
 
 import sys
 import threading
@@ -30,6 +35,12 @@ FORMS = pytest.mark.parametrize("form", ["sliced", "whole"])
 RECORDS, MINIBATCH, WINDOW = 192, 16, 2
 STEPS = RECORDS // MINIBATCH
 SYNCS = STEPS // WINDOW
+# where the step loop goes on alone, by the delta's form
+EARLY = {"sliced": ("snapshot", None), "whole": ("copied", None)}
+
+
+def _releases(**counts):
+    return {"snapshot": 0, "copied": 0, "settled": 0, **counts}
 
 
 @pytest.fixture(autouse=True)
@@ -97,11 +108,12 @@ class _HeldMaster(InProcessMaster):
 
 
 def _job(tmp_path, monkeypatch, form, *, worker_cls=Worker, before=None,
-         records_per_task=64, chain="off", **worker_kw):
+         records_per_task=64, chain="off", slice_bytes=16, **worker_kw):
     """-> (worker, master, servicer, spans) of one finished job;
     `before(servicer)` gives `_HeldMaster` its hook."""
     monkeypatch.setattr(
-        delta_stream, "DELTA_SLICE_BYTES", 16 if form == "sliced" else 1 << 20
+        delta_stream, "DELTA_SLICE_BYTES",
+        slice_bytes if form == "sliced" else 1 << 20,
     )
     trace.RECORDER.clear()
     tmp_path.mkdir(parents=True, exist_ok=True)
@@ -153,9 +165,117 @@ def _released(spans):
     ]
 
 
-@FORMS
-def test_the_step_loop_goes_on_when_the_delta_has_left_the_device(
-    tmp_path, monkeypatch, form
+def _parts(spans, name, sync):
+    return [
+        s for s in _named(spans, name) if s["args"]["seq"] == sync["args"]["seq"]
+    ]
+
+
+def _check_the_waits(spans, after_spawn, for_the_program=0):
+    """The wait before the next delta found the sync settled or waited
+    for it under a reason of its own; `after_spawn` syncs held the step
+    loop after their spawn for what they read from the device,
+    `for_the_program` only until the device had made the snapshot, and
+    all of it lies inside `sync_wait`."""
+    reasons = [s["args"]["reason"] for s in _named(spans, "worker.sync_exposed")]
+    assert reasons.count("settle") == SYNCS - 2  # the last one: the drain
+    assert reasons.count("backpressure") == after_spawn and "drain" in reasons
+    assert reasons.count("snapshot") == for_the_program
+    phases = _named(spans, "sync_wait")
+    for s in _named(spans, "worker.sync_exposed"):
+        if s["args"]["reason"] in ("settle", "backpressure", "snapshot"):
+            assert any(
+                p["ts"] <= s["ts"] + 1e-6 and _end(s) <= _end(p) + 1e-6
+                for p in phases
+            )
+
+
+@pytest.mark.parametrize("carry", ["flat", "leaves"])
+def test_the_step_loop_goes_on_before_any_slice_has_landed(
+    tmp_path, monkeypatch, carry
+):
+    """The sliced form: the spawn asks for the snapshot's program and
+    nothing else, the next window is asked for while the stream's
+    thread has not begun, and the device never holds a delta or a
+    second base. Where the window's loop carries leaves, the cut of
+    the model's vector waits until the device has made the snapshot
+    (so that what the join and the snapshot read is free by then)."""
+    from elasticdl_tpu.worker import worker as worker_module
+
+    if carry == "leaves":
+        monkeypatch.setattr(worker_module, "CARRY_LEAVES_MIN_MEAN_ELEMENTS", 1)
+    let_go, workers = [], []
+    real_base, real_copy = Worker._base_in_slices, delta_stream.DeltaStream._copy
+
+    def base_in_slices(self, bounds):
+        workers.append(self)
+        # the worker holds no base when the program that makes the next
+        # one is asked for
+        let_go.append(self._base_flat is None)
+        return real_base(self, bounds)
+
+    def copy(stream):
+        # a copy that starts late: not before the next window has been
+        # asked for (the last sync of a job has none after it)
+        runs = workers[0]._device_runs
+        seq, deadline = runs.seq, time.monotonic() + 0.5
+        while runs.seq == seq and time.monotonic() < deadline:
+            time.sleep(0.001)
+        real_copy(stream)
+
+    def no_delta_on_the_device(self, *_a):
+        raise AssertionError("the serial chain formed a delta on the device")
+
+    monkeypatch.setattr(Worker, "_base_in_slices", base_in_slices)
+    monkeypatch.setattr(delta_stream.DeltaStream, "_copy", copy)
+    monkeypatch.setattr(Worker, "_delta_from_base", no_delta_on_the_device)
+    monkeypatch.setattr(Worker, "_delta_in_slices", no_delta_on_the_device)
+    worker, _master, servicer, spans = _job(tmp_path, monkeypatch, "sliced")
+    assert servicer._version == STEPS
+    syncs = _named(spans, "worker.window_sync")
+    runs = {s["args"]["seq"]: s for s in _named(spans, "worker.device_run")}
+    assert len(syncs) == SYNCS and len(runs) == SYNCS
+    assert _released(spans) == [("settled", "first")] + [("snapshot", None)] * (
+        SYNCS - 1
+    )
+    assert worker.sync_releases == _releases(snapshot=SYNCS - 1, settled=1)
+    assert [
+        (s["args"].get("released"), s["args"].get("why"))
+        for s in _named(spans, "worker.sync_spawn")
+    ] == _released(spans)
+    first, later = syncs[0], syncs[1:-1]  # the last has no window after it
+    assert runs[first["args"]["seq"] + 1]["args"]["asked"] >= _end(first)
+    # the first sync found no base on the host and copied the device's
+    # out before the snapshot; no later one did
+    assert [s["args"]["seq"] for s in _named(spans, "worker.base_d2h")] == [
+        first["args"]["seq"]
+    ]
+    for sync in later:
+        after = runs[sync["args"]["seq"] + 1]
+        assert after["args"]["asked"] < _end(sync)
+        (trip,) = _parts(spans, "worker.d2h", sync)
+        (less,) = _parts(spans, "worker.host_delta", sync)
+        # no slice had been asked for, let alone landed, when the next
+        # window was; all of them landed inside the sync, and the host
+        # subtracted inside it too
+        assert after["args"]["asked"] <= trip["ts"]
+        assert trip["args"]["slices"] == 3 and _end(trip) <= _end(sync) + 1e-6
+        assert trip["ts"] <= less["ts"] and _end(less) <= _end(sync) + 1e-6
+        assert less["args"]["busy_ms"] >= 0
+    # (the rebase, then every sync)
+    assert let_go == [True] * (1 + SYNCS)
+    programs = [s["args"]["program"] for s in _named(spans, "setup.program")]
+    assert programs.count("jit_snapshot") == 1
+    assert not {"jit_subtract", "jit_copy", "jit_delta_slice"} & set(programs)
+    # no sync but the first held the step loop after its spawn; every
+    # window's cut found its base made, or waited for that alone
+    _check_the_waits(
+        spans, after_spawn=1, for_the_program=SYNCS * (carry == "leaves")
+    )
+
+
+def test_the_step_loop_goes_on_when_a_whole_delta_has_left_the_device(
+    tmp_path, monkeypatch
 ):
     deltas, donated, freed = [], [], []
     real_delta, real_sync = Worker._delta_from_base, Worker._sync_local_updates
@@ -174,7 +294,7 @@ def test_the_step_loop_goes_on_when_the_delta_has_left_the_device(
 
     monkeypatch.setattr(Worker, "_delta_from_base", delta_from_base)
     monkeypatch.setattr(Worker, "_sync_local_updates", sync_local_updates)
-    worker, _master, servicer, spans = _job(tmp_path, monkeypatch, form)
+    worker, _master, servicer, spans = _job(tmp_path, monkeypatch, "whole")
     assert servicer._version == STEPS
     syncs = _named(spans, "worker.window_sync")
     runs = {s["args"]["seq"]: s for s in _named(spans, "worker.device_run")}
@@ -184,7 +304,7 @@ def test_the_step_loop_goes_on_when_the_delta_has_left_the_device(
     assert _released(spans) == [("settled", "first")] + [("copied", None)] * (
         SYNCS - 1
     )
-    assert worker.sync_releases == {"copied": SYNCS - 1, "settled": 1}
+    assert worker.sync_releases == _releases(copied=SYNCS - 1, settled=1)
     assert [
         (s["args"].get("released"), s["args"].get("why"))
         for s in _named(spans, "worker.sync_spawn")
@@ -196,27 +316,15 @@ def test_the_step_loop_goes_on_when_the_delta_has_left_the_device(
         # the next window was asked for while the master still held
         # this sync's request
         assert after["args"]["asked"] < _end(sync)
-        trip = [
-            s for s in _named(spans, "worker.d2h")
-            if s["args"]["seq"] == sync["args"]["seq"]
-        ]
-        assert len(trip) == 1 and _end(trip[0]) <= after["args"]["asked"]
-        assert trip[0]["args"]["slices"] == (3 if form == "sliced" else 1)
+        (trip,) = _parts(spans, "worker.d2h", sync)
+        assert _end(trip) <= after["args"]["asked"]
+        assert trip["args"]["slices"] == 1
     # nothing of a delta is on the device when the step loop goes on,
     # and no snapshot holds the base: every subtraction donates it
     assert freed == [True] * SYNCS and donated == [True] * SYNCS
-    # the wait before the next delta found the sync settled or waited
-    # for it under a reason of its own; the wait after the spawn kept its
-    reasons = [s["args"]["reason"] for s in _named(spans, "worker.sync_exposed")]
-    assert reasons.count("settle") == SYNCS - 2  # the last one: the drain
-    assert reasons.count("backpressure") == SYNCS and "drain" in reasons
-    phases = _named(spans, "sync_wait")
-    for s in _named(spans, "worker.sync_exposed"):
-        if s["args"]["reason"] in ("settle", "backpressure"):
-            assert any(
-                p["ts"] <= s["ts"] + 1e-6 and _end(s) <= _end(p) + 1e-6
-                for p in phases
-            )
+    assert not _named(spans, "worker.host_delta")
+    assert not _named(spans, "worker.base_d2h")
+    _check_the_waits(spans, after_spawn=SYNCS)
 
 
 @FORMS
@@ -233,8 +341,8 @@ def test_what_reaches_the_master_is_bit_identical_to_whole_syncs(
     finally:
         sys.setswitchinterval(interval)
     assert _released(whole[3]) == [("settled", "first")] * SYNCS
-    assert whole[0].sync_releases == {"copied": 0, "settled": SYNCS}
-    assert early[0].sync_releases["copied"] == SYNCS - 1
+    assert whole[0].sync_releases == _releases(settled=SYNCS)
+    assert early[0].sync_releases[EARLY[form][0]] == SYNCS - 1
     assert _model_bytes(early[2]) == _model_bytes(whole[2])
     assert early[2]._version == STEPS
     assert early[1].calls[SYNC] == whole[1].calls[SYNC] == SYNCS
@@ -243,6 +351,28 @@ def test_what_reaches_the_master_is_bit_identical_to_whole_syncs(
     for _w, master, _s, _spans in (whole, early):
         assert [err for _, err, _ in master.reports] == [""] * (RECORDS // 64)
         assert [v for _, _, v in master.reports] == [4, 8, 12]
+
+
+@pytest.mark.parametrize(
+    "slice_bytes", [16, 20, 4], ids=["short_tail", "equal_slices", "a_float"]
+)
+def test_the_hosts_subtraction_is_the_devices_bit_for_bit(
+    tmp_path, monkeypatch, slice_bytes
+):
+    """What reaches the master over the job's windows, delta by delta:
+    a landed snapshot less the host's base (ten parameters in slices of
+    4, 4, 2 | 5, 5 | one float each) against `flat - base` on the
+    device, fetched whole."""
+    device = _job(tmp_path / "d", monkeypatch, "whole")
+    host = _job(tmp_path / "h", monkeypatch, "sliced", slice_bytes=slice_bytes)
+    assert not _named(device[3], "worker.host_delta")
+    assert len(_named(host[3], "worker.host_delta")) == SYNCS
+    assert {s["args"]["slices"] for s in _named(host[3], "worker.d2h")} == {
+        -(-40 // slice_bytes)
+    }
+    assert host[1].pushes == device[1].pushes and len(host[1].pushes) == SYNCS
+    assert _model_bytes(host[2]) == _model_bytes(device[2])
+    assert host[2]._version == STEPS
 
 
 def _another_worker_writes(servicer, at):
@@ -278,8 +408,8 @@ def test_a_merged_answer_is_absorbed_a_window_late_as_at_depth_one(
     serial = _job(tmp_path / "s", monkeypatch, form, before=before)
     assert serial[2]._version == STEPS + 2
     assert _released(serial[3]) == [
-        ("settled", "first"), ("copied", None), ("copied", None),
-        ("settled", "merged"), ("copied", None), ("copied", None),
+        ("settled", "first"), EARLY[form], EARLY[form],
+        ("settled", "merged"), EARLY[form], EARLY[form],
     ]
     absorbs = _named(serial[3], "worker.absorb")
     syncs = _named(serial[3], "worker.window_sync")
@@ -287,6 +417,12 @@ def test_a_merged_answer_is_absorbed_a_window_late_as_at_depth_one(
     # one absorb, after window 4 had been asked for and before sync 4
     assert len(absorbs) == 1
     assert runs[4]["args"]["asked"] < absorbs[0]["ts"] < syncs[3]["ts"]
+    # the absorb shifted the device's base, so the host's copy was
+    # dropped: the held sync that follows copies the shifted one out
+    assert [s["args"]["seq"] for s in _named(serial[3], "worker.base_d2h")] == (
+        [syncs[0]["args"]["seq"], syncs[3]["args"]["seq"]]
+        if form == "sliced" else []
+    )
 
     # the overlapped chain, one sync in flight, its answer in by the
     # next boundary
@@ -329,7 +465,7 @@ def test_a_sparse_plane_waits_for_whole_syncs(tmp_path):
     assert dispatcher.finished()
     released = _released(trace.RECORDER.snapshot())
     assert len(released) >= 2 and set(released) == {("settled", "sparse")}
-    assert worker.sync_releases == {"copied": 0, "settled": len(released)}
+    assert worker.sync_releases == _releases(settled=len(released))
 
 
 @FORMS
@@ -371,64 +507,154 @@ def test_an_rpc_that_fails_in_the_hidden_tail(tmp_path, monkeypatch, form):
     assert len(released) == len(master.pushes) == SYNCS + 1
     assert master.calls[SYNC] == SYNCS  # what reached the servicer
     assert released[:4] == [
-        ("settled", "first"), ("copied", None), ("copied", None),
-        ("settled", "first"),
+        ("settled", "first"), EARLY[form], EARLY[form], ("settled", "first"),
     ]
-    assert set(released[4:]) == {("copied", None)}
-    assert worker.sync_releases == {"copied": SYNCS - 1, "settled": 2}
+    assert set(released[4:]) == {EARLY[form]}
+    assert worker.sync_releases == _releases(
+        settled=2, **{EARLY[form][0]: SYNCS - 1}
+    )
 
 
-class _Slice:
-    """What `DeltaStream` asks of a device array."""
+class _Lost:
+    """A slice of a snapshot whose copy is lost once the step loop has
+    asked its device for the next window."""
 
-    def __init__(self, values, error=None):
-        self._values, self._error = values, error
+    shape, dtype = (4,), np.dtype(np.float32)
+
+    def __init__(self, runs):
+        self._runs, self._seq = runs, runs.seq
 
     def copy_to_host_async(self):
         pass
 
     def __array__(self, dtype=None, copy=None):
-        if self._error is not None:
-            raise self._error
-        return self._values
+        deadline = time.monotonic() + 5
+        while self._runs.seq == self._seq and time.monotonic() < deadline:
+            time.sleep(0.001)
+        raise RuntimeError("the device lost slice 1")
 
 
-@pytest.mark.parametrize("fails", [False, True], ids=["landed", "failed"])
-def test_the_stream_says_once_when_it_holds_no_slice_any_more(fails):
-    """`on_end` is the sliced form's release point: called once, on
-    the stream's thread, after the last slice has landed or a copy has
-    failed, when the thread's frame (the slice asked for last) is gone."""
-    import gc
-    import weakref
+def test_a_slice_that_fails_to_land_after_the_step_loop_went_on(
+    tmp_path, monkeypatch
+):
+    """Sync 3's second slice never lands, and the step loop has gone
+    on by then: the sync fails at the settle before delta 4, window 4
+    is thrown away, the tasks behind sync 3 go unreported and back to
+    the dispatcher, the host's base goes with the device's vector, and
+    the sync after the reset copies its base out again."""
+    real, calls = Worker._base_in_slices, []
 
-    vec = np.arange(10, dtype=np.float32)
-    bounds = [(0, 4), (4, 8), (8, 10)]
-    alive, ends = [], []
+    def base_in_slices(self, bounds):
+        base = real(self, bounds)
+        calls.append(self._pending_steps)
+        if len(calls) == 4:  # the rebase, syncs 1 and 2, then sync 3
+            base = (base[0], _Lost(self._device_runs), base[2])
+        return base
 
-    def cut(i):
-        piece = _Slice(
-            vec[slice(*bounds[i])],
-            ValueError("lost") if fails and i == 1 else None,
-        )
-        alive.append(weakref.ref(piece))
-        return piece
-
-    def on_end():
-        gc.collect()
-        ends.append((
-            threading.current_thread().name, [r() is not None for r in alive]
-        ))
-
-    stream = delta_stream.DeltaStream(
-        bounds, (cut(i) for i in range(len(bounds))), on_end=on_end
+    monkeypatch.setattr(Worker, "_base_in_slices", base_in_slices)
+    worker, master, servicer, spans = _job(
+        tmp_path, monkeypatch, "sliced", records_per_task=32,
     )
-    vector = stream.vector()
-    stream.start()
-    stream.settle()
-    # (the slice whose copy failed lives on in its error's traceback)
-    assert ends == [("delta-stream", [False, fails, False])]
-    if fails:
-        with pytest.raises(RuntimeError, match="did not land"):
-            vector.pieces[2].landed(1)
-    else:
-        assert np.array_equal(np.asarray(vector), vec)
+    assert servicer._version == STEPS
+    failed = [(t, err) for t, err, _ in master.reports if err]
+    assert len(failed) == 2 and len({t for t, _ in failed}) == 2
+    assert any("did not land" in err for _, err in failed)
+    done = [(t, v) for t, err, v in master.reports if not err]
+    assert [v for _, v in done] == [WINDOW * k for k in range(1, SYNCS + 1)]
+    released = _released(spans)
+    assert released[:4] == [
+        ("settled", "first"), ("snapshot", None), ("snapshot", None),
+        ("settled", "first"),
+    ]
+    assert set(released[4:]) == {("snapshot", None)}
+    # the failed sync went on alone and was found failed at the settle
+    # (the step loop had asked for window 4 by then)
+    syncs = _named(spans, "worker.window_sync")
+    runs = {s["args"]["seq"]: s for s in _named(spans, "worker.device_run")}
+    assert runs[syncs[2]["args"]["seq"] + 1]["args"]["asked"] >= syncs[2]["ts"]
+    # two rebases (calls 1 and 5), and the sync that follows each finds
+    # no base on the host and copies the device's out
+    assert calls[0] == calls[4] == 0 and all(calls[1:4]) and all(calls[5:])
+    assert [s["args"]["seq"] for s in _named(spans, "worker.base_d2h")] == [
+        syncs[0]["args"]["seq"], syncs[3]["args"]["seq"]
+    ]
+    assert worker._host_base is not None  # the last sync's, landed
+    assert worker.sync_releases == _releases(snapshot=SYNCS - 1, settled=2)
+
+
+def _at_a_boundary(monkeypatch, boundary, act):
+    """`act(worker)` before the `boundary`-th window is made ready."""
+    real, windows = Worker._ensure_local_ready, []
+
+    def ensure_local_ready(self, features, task):
+        if self._pending_steps == 0:
+            windows.append(1)
+            if len(windows) == boundary:
+                act(self)
+        return real(self, features, task)
+
+    monkeypatch.setattr(Worker, "_ensure_local_ready", ensure_local_ready)
+
+
+def _shard_recovery(worker):
+    worker._join_sync()
+    worker._reset_local_state()  # what `_await_shard_recovery` ends in
+
+
+def _stale(worker):
+    worker._join_sync()
+    with worker._report_lock:
+        worker._fresh = False  # the model is pulled again at the boundary
+
+
+@pytest.mark.parametrize("act, why", [
+    (_shard_recovery, "first"), (_stale, "base"),
+], ids=["reset", "pull"])
+def test_a_vector_that_is_replaced_takes_the_hosts_base_with_it(
+    tmp_path, monkeypatch, act, why
+):
+    """Before window 4 the device's vector is replaced (a failover's
+    reset; a pull at the boundary): the rebase makes a new base on the
+    device and drops the host's, so sync 4 copies its base out first
+    and is waited for whole, and the master ends with the model the
+    device-side subtraction gives under the same disturbance."""
+    _at_a_boundary(monkeypatch, 4, act)
+    host = _job(tmp_path / "h", monkeypatch, "sliced")
+    _at_a_boundary(monkeypatch, 4, act)
+    device = _job(tmp_path / "d", monkeypatch, "whole")
+    assert _released(host[3]) == [
+        ("settled", "first"), ("snapshot", None), ("snapshot", None),
+        ("settled", why), ("snapshot", None), ("snapshot", None),
+    ]
+    syncs = _named(host[3], "worker.window_sync")
+    assert [s["args"]["seq"] for s in _named(host[3], "worker.base_d2h")] == [
+        syncs[0]["args"]["seq"], syncs[3]["args"]["seq"]
+    ]
+    assert host[1].pushes == device[1].pushes
+    assert _model_bytes(host[2]) == _model_bytes(device[2])
+    assert host[2]._version == device[2]._version == STEPS
+
+
+@FORMS
+def test_a_drain_waits_for_the_whole_sync(tmp_path, monkeypatch, form):
+    """`_finalize_local_updates` pushes what is pending with
+    `blocking=True`: that sync runs on the step loop's own thread."""
+    real, calls = Worker._sync_local_updates, []
+
+    def sync_local_updates(self, blocking=True):
+        if self._pending_steps:
+            calls.append(blocking)
+        real(self, blocking or (len(calls) == 3 and bool(self._pending_steps)))
+
+    monkeypatch.setattr(Worker, "_sync_local_updates", sync_local_updates)
+    worker, _master, servicer, spans = _job(tmp_path, monkeypatch, form)
+    assert servicer._version == STEPS
+    released = _released(spans)
+    assert released[:4] == [
+        ("settled", "first"), EARLY[form], ("settled", "drain"), EARLY[form],
+    ]
+    drained = _named(spans, "worker.window_sync")[2]
+    assert drained["tid"] == _named(spans, "worker.sync_spawn")[2]["tid"]
+    assert worker.sync_releases == _releases(
+        settled=2, **{EARLY[form][0]: SYNCS - 2}
+    )
