@@ -91,30 +91,6 @@ def add_model_spec_args(parser: argparse.ArgumentParser):
         "EDL_SYNC_COMPRESS overrides.",
     )
     parser.add_argument(
-        "--sync_local_steps", type=pos_int, default=1,
-        help="local-steps ladder: accumulate k windows of on-device "
-        "deltas before pushing one combined super-window delta (one "
-        "report_key per push; error-feedback residuals absorb the "
-        "longer horizon). 1 = today's per-window chain, bit-for-bit. "
-        "EDL_SYNC_LOCAL_STEPS overrides.",
-    )
-    parser.add_argument(
-        "--sync_adaptive", default="", choices=("", "on", "off"),
-        help="link-weather-adaptive wire selection: on lets "
-        "sync_policy.decide() pick f32/bf16/int8/topk per round from "
-        "push-timing link estimates (mixed rounds are legal); off "
-        "(default) keeps the static --sync_dtype/--sync_compress form. "
-        "EDL_SYNC_ADAPTIVE overrides.",
-    )
-    parser.add_argument(
-        "--sync_bucket_bytes", type=non_neg_int, default=0,
-        help="bucketed delta push: split each super-window delta into "
-        "~this-many-byte layer-aligned buckets streamed per push; the "
-        "PS parks partial sets and applies atomically at the window "
-        "boundary (0 = unbucketed flat push, the default; sharded-PS "
-        "route only). EDL_SYNC_BUCKET_BYTES overrides.",
-    )
-    parser.add_argument(
         "--overlap_sync", default="", choices=("", "on", "off"),
         help="worker overlap plane: on (default) pipelines window-delta "
         "encode/push on sync threads, pages model-down in on a "
@@ -614,12 +590,6 @@ def worker_forward_args(args, worker_id: int, master_addr: str) -> List[str]:
         argv += ["--sync_compress", args.sync_compress]
     if getattr(args, "overlap_sync", ""):
         argv += ["--overlap_sync", args.overlap_sync]
-    if getattr(args, "sync_local_steps", 1) != 1:
-        argv += ["--sync_local_steps", str(args.sync_local_steps)]
-    if getattr(args, "sync_adaptive", ""):
-        argv += ["--sync_adaptive", args.sync_adaptive]
-    if getattr(args, "sync_bucket_bytes", 0):
-        argv += ["--sync_bucket_bytes", str(args.sync_bucket_bytes)]
     if getattr(args, "master_candidates", ""):
         argv += ["--master_candidates", args.master_candidates]
     for flag in (

@@ -380,20 +380,6 @@ def slice_delta(obj: Any, start: int, stop: int) -> Any:
     return np.asarray(obj)[start:stop]
 
 
-def delta_nbytes(obj: Any) -> int:
-    """Wire payload bytes of a delta in any compression form — the
-    size the link actually carries (modulo framing), used by the
-    adaptive sync plane's passive bandwidth estimate and WireStats'
-    per-form accounting."""
-    if isinstance(obj, QuantizedDelta):
-        return int(np.asarray(obj.q).nbytes + np.asarray(obj.scale).nbytes)
-    if isinstance(obj, SparseDelta):
-        return int(np.asarray(obj.indices).nbytes) + delta_nbytes(obj.values)
-    if isinstance(obj, LeafVector):
-        return obj.size * 4  # counted, not joined
-    return int(np.asarray(obj).nbytes)
-
-
 def delta_to_f32(obj: Any, n: int | None = None) -> np.ndarray:
     """Decode any wire delta form to a dense f32 vector: dense arrays
     pass through `as_f32` (f32 stays a view), QuantizedDelta

@@ -82,8 +82,6 @@ ENV_SYNC_DEPTH = "EDL_SYNC_DEPTH"
 ENV_OVERLAP_SYNC = "EDL_OVERLAP_SYNC"
 ENV_SYNC_DTYPE = "EDL_SYNC_DTYPE"
 ENV_SYNC_COMPRESS = "EDL_SYNC_COMPRESS"
-ENV_SYNC_LOCAL_STEPS = "EDL_SYNC_LOCAL_STEPS"
-ENV_SYNC_ADAPTIVE = "EDL_SYNC_ADAPTIVE"
 ENV_SYNC_BUCKET_BYTES = "EDL_SYNC_BUCKET_BYTES"
 ENV_TRANSPORT = "EDL_TRANSPORT"
 ENV_UDS_DIR = "EDL_UDS_DIR"
@@ -166,26 +164,12 @@ ENV_REGISTRY = {
         "(indices, values) frames, error-feedback corrected; composes "
         "with EDL_SYNC_DTYPE int8/bf16 for the values (default off)"
     ),
-    ENV_SYNC_LOCAL_STEPS: (
-        "local-steps ladder: accumulate k windows of on-device deltas "
-        "before pushing one combined super-window delta (one "
-        "report_key per push; error-feedback residuals absorb the "
-        "longer horizon). Default 1 = today's per-window chain, "
-        "bit-for-bit (CLI --sync_local_steps)"
-    ),
-    ENV_SYNC_ADAPTIVE: (
-        "link-weather-adaptive wire selection: on lets "
-        "sync_policy.decide() pick f32/bf16/int8/topk per round from "
-        "push-timing link estimates (mixed rounds are legal; the PS "
-        "decodes every form per-push); off (default) keeps the static "
-        "EDL_SYNC_DTYPE/EDL_SYNC_COMPRESS form (CLI --sync_adaptive)"
-    ),
     ENV_SYNC_BUCKET_BYTES: (
-        "bucketed delta push: split each super-window delta into "
+        "bucketed delta push: split each window delta into "
         "~this-many-byte layer-aligned buckets streamed per push; the "
         "PS parks partial sets and applies the full set atomically at "
         "the window boundary (0 = unbucketed flat push, the default; "
-        "CLI --sync_bucket_bytes; sharded-PS route only)"
+        "no CLI flag; sharded-PS route only)"
     ),
     ENV_TRANSPORT: (
         "RPC transport tier override. Unset (the default) means uds: "

@@ -270,9 +270,9 @@ class PSPushDeltaRequest(_WireRequest):
 
 @dataclasses.dataclass
 class PSPushDeltaBucketRequest(_WireRequest):
-    """One layer-aligned bucket of a super-window delta (worker
+    """One layer-aligned bucket of a window delta (worker
     streaming push, worker._sync_local_updates). All buckets of one
-    super-window share `report_key` (the dedup/lineage key); `offset`
+    window share `report_key` (the dedup/lineage key); `offset`
     places this bucket's slice inside the SHARD's slice, and
     `bucket_index`/`num_buckets` let the shard detect the complete set
     — partial sets park (like fan-in's CombineBuffer) and the whole

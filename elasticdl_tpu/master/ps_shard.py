@@ -135,7 +135,7 @@ class PSShardServicer:
         # sets park here keyed by report_key — bucket_index ->
         # (offset, dense f32 part) — until num_buckets parts arrived,
         # then the WHOLE set applies atomically under self._lock (the
-        # fan-in CombineBuffer's park-then-apply shape, per super-window
+        # fan-in CombineBuffer's park-then-apply shape, per window
         # instead of per cohort). A re-sent parked part overwrites its
         # slot idempotently. Capacity-capped like the dedup ring: an
         # abandoned partial set (worker died mid-stream — its delta
@@ -616,9 +616,9 @@ class PSShardServicer:
         return resp
 
     def push_delta_bucket(self, req: dict) -> dict:
-        """One layer-aligned bucket of a super-window delta (the
+        """One layer-aligned bucket of a window delta (the
         worker's streaming push, ps_client.push_delta_bucketed). Parts
-        of one super-window share `report_key`; partial sets PARK (the
+        of one window share `report_key`; partial sets PARK (the
         fan-in CombineBuffer's park-then-apply shape) and the full set
         applies atomically at the window boundary — `version` advances
         by `steps` exactly once, and `_record_applied` registers the
@@ -670,7 +670,7 @@ class PSShardServicer:
                 if len(parked) < total:
                     # incomplete set: nothing applied yet (atomicity —
                     # the model other pullers see never contains a
-                    # torn super-window)
+                    # torn window)
                     return {"version": self._version, "parked": len(parked)}
                 del self._parked_buckets[key]
                 steps = int(req["steps"])
@@ -924,7 +924,7 @@ class PSShardServicer:
                 # (1.0 when combining is off or every batch had k=1)
                 "combined_batches": self._combined_batches,
                 "combined_reports": self._combined_reports,
-                # bucketed-push parking: partial super-window sets
+                # bucketed-push parking: partial window sets
                 # currently parked + abandoned sets evicted (a healthy
                 # run shows 0 evictions — parked sets complete within
                 # one push)

@@ -100,7 +100,7 @@ IDEMPOTENT_METHODS: FrozenSet[str] = frozenset(
         "PSPull",
         "PSPushGrad",
         "PSPushDelta",
-        # bucketed streaming push (worker adaptive sync plane): parked
+        # bucketed streaming push (EDL_SYNC_BUCKET_BYTES): parked
         # buckets overwrite idempotently by (report_key, bucket_index)
         # and an applied set dedups per bucket on report_key — a resend
         # of any bucket, before or after the atomic apply, is exact
@@ -190,10 +190,9 @@ class WireStats:
     def __init__(self, endpoint: str = ""):
         self.endpoint = endpoint
         # stripe -> (lock, method -> [sent, recv, calls],
-        #           transport tier -> [sent, recv, calls],
-        #           wire form -> [payload bytes, rounds])
+        #           transport tier -> [sent, recv, calls])
         self._stripes = [
-            (threading.Lock(), {}, {}, {})
+            (threading.Lock(), {}, {})
             for _ in range(self._NUM_STRIPES)
         ]
 
@@ -206,7 +205,7 @@ class WireStats:
         calls=None,
     ):
         n = (1 if sent else 0) if calls is None else int(calls)
-        lock, methods, transports, _ = self._stripes[_stripe_index()]
+        lock, methods, transports = self._stripes[_stripe_index()]
         with lock:
             row = methods.get(method)
             if row is None:
@@ -221,33 +220,13 @@ class WireStats:
             trow[1] += int(received)
             trow[2] += n
 
-    def record_wire_form(self, form: str, payload_bytes: int = 0):
-        """One adaptive-sync round chose `form` (sync_policy.WIRE_FORMS)
-        and shipped `payload_bytes` — the per-form breakdown the bench
-        decision log and stats() surfaces read."""
-        lock, _, _, forms = self._stripes[_stripe_index()]
-        with lock:
-            row = forms.get(form)
-            if row is None:
-                row = forms[form] = [0, 0]
-            row[0] += int(payload_bytes)
-            row[1] += 1
-
     def snapshot(self) -> dict:
         methods: dict = {}
         transports: dict = {}
-        wire_forms: dict = {}
-        for lock, smethods, stransports, sforms in self._stripes:
+        for lock, smethods, stransports in self._stripes:
             with lock:
                 srows = [(m, list(r)) for m, r in smethods.items()]
                 trows = [(t, list(r)) for t, r in stransports.items()]
-                frows = [(f, list(r)) for f, r in sforms.items()]
-            for f, r in frows:
-                agg = wire_forms.setdefault(
-                    f, {"bytes_sent": 0, "rounds": 0}
-                )
-                agg["bytes_sent"] += r[0]
-                agg["rounds"] += r[1]
             for m, r in srows:
                 agg = methods.setdefault(
                     m, {"bytes_sent": 0, "bytes_received": 0, "calls": 0}
@@ -271,15 +250,13 @@ class WireStats:
             "calls": sum(v["calls"] for v in methods.values()),
             "methods": methods,
             "transports": transports,
-            "wire_forms": wire_forms,
         }
 
     def reset(self):
-        for lock, methods, transports, forms in self._stripes:
+        for lock, methods, transports in self._stripes:
             with lock:
                 methods.clear()
                 transports.clear()
-                forms.clear()
 
 
 # Threads are pinned to stripes round-robin at first record: cheaper
@@ -329,7 +306,6 @@ def aggregate_wire_snapshots(snapshots) -> dict:
     is num_shards slice sends, and "bytes per sync" means their SUM."""
     methods: dict = {}
     transports: dict = {}
-    wire_forms: dict = {}
     for snap in snapshots:
         for m, row in snap["methods"].items():
             agg = methods.setdefault(
@@ -344,17 +320,11 @@ def aggregate_wire_snapshots(snapshots) -> dict:
             )
             for k in agg:
                 agg[k] += row[k]
-        # tolerate pre-adaptive snapshots (no "wire_forms")
-        for f, row in snap.get("wire_forms", {}).items():
-            agg = wire_forms.setdefault(f, {"bytes_sent": 0, "rounds": 0})
-            for k in agg:
-                agg[k] += row[k]
     return {
         "bytes_sent": sum(v["bytes_sent"] for v in methods.values()),
         "bytes_received": sum(v["bytes_received"] for v in methods.values()),
         "methods": methods,
         "transports": transports,
-        "wire_forms": wire_forms,
     }
 
 

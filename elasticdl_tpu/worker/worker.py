@@ -46,20 +46,16 @@ from elasticdl_tpu.common.constants import (
     ENV_BET_PREFETCH,
     ENV_OVERLAP_SYNC,
     ENV_SCHED_PHASE_SECS,
-    ENV_SYNC_ADAPTIVE,
     ENV_SYNC_BUCKET_BYTES,
     ENV_SYNC_COMPRESS,
     ENV_SYNC_DEPTH,
     ENV_SYNC_DTYPE,
-    ENV_SYNC_LOCAL_STEPS,
     ENV_WORKER_LOG_DIR,
     MAX_MINIBATCH_RETRY_NUM,
     WINDOW_STATS,
     Mode,
 )
 from elasticdl_tpu.common import codec
-from elasticdl_tpu.common import sync_policy
-from elasticdl_tpu.common.linkprobe import LinkWeather
 from elasticdl_tpu.common.device import device_report
 from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.common.timing import DeviceRuns, PhaseTimers
@@ -243,8 +239,6 @@ class Worker:
         sync_compress: Optional[str] = None,  # "topk:<ratio>" sparsification
         overlap_sync: Optional[str] = None,  # on|off overlap plane gate
         master_candidates=None,  # master-failover endpoints (migration.py)
-        sync_local_steps: Optional[int] = None,  # k windows per push (ladder)
-        sync_adaptive: Optional[str] = None,  # on|off per-round wire form
         sync_bucket_bytes: Optional[int] = None,  # layer-aligned bucket size
     ):
         self._id = worker_id
@@ -305,27 +299,6 @@ class Worker:
         if sync_compress is None:
             sync_compress = os.environ.get(ENV_SYNC_COMPRESS, "") or ""
         self._topk_ratio = _parse_sync_compress(sync_compress)
-        # Link-weather-adaptive wire selection (--sync_adaptive /
-        # EDL_SYNC_ADAPTIVE): each round sync_policy.decide() maps the
-        # passive link estimate (push timings the sync thread already
-        # has — see LinkWeather) to f32/bf16/int8/topk. Mixed rounds
-        # are legal: the PS decodes every wire form per-push, and the
-        # shared f32 EF residual carries each round's compression error
-        # into the NEXT round regardless of either round's form.
-        # Parsed before the transport_dtype supersede below: adaptive
-        # counts as lossy (_lossy_sync), so it too needs the
-        # full-precision delta as the residual source.
-        if sync_adaptive is None:
-            sync_adaptive = os.environ.get(ENV_SYNC_ADAPTIVE, "") or "off"
-        sync_adaptive = str(sync_adaptive).strip().lower()
-        if sync_adaptive in ("", "off", "0", "false"):
-            self._sync_adaptive = False
-        elif sync_adaptive in ("on", "1", "true"):
-            self._sync_adaptive = True
-        else:
-            raise ValueError(
-                f"unsupported sync_adaptive {sync_adaptive!r} (on|off)"
-            )
         if self._lossy_sync() and transport_dtype == "bfloat16":
             # EF compression needs the FULL-precision delta/grad as its
             # input (residual = f32 - compress(f32)); the legacy step-fn
@@ -434,42 +407,13 @@ class Worker:
             )
         if not self._overlap_sync:
             self._max_inflight_syncs = 0
-        # Local-steps ladder (--sync_local_steps / EDL_SYNC_LOCAL_STEPS):
-        # accumulate k windows of on-device deltas before pushing ONE
-        # combined super-window delta. The delta is already cumulative
-        # (_flat - _base_flat), so the ladder is purely a higher spawn
-        # threshold — no new buffers — and one report_key covers the
-        # whole super-window (dedup/replay semantics unchanged). The EF
-        # residuals absorb compression error across the longer horizon
-        # exactly as across windows. k=1 restores today's per-window
-        # chain bit-for-bit.
-        if sync_local_steps is None:
-            sync_local_steps = os.environ.get(ENV_SYNC_LOCAL_STEPS, "") or 1
-        try:
-            sync_local_steps = int(sync_local_steps)
-        except (TypeError, ValueError):
-            raise ValueError(
-                f"unsupported sync_local_steps {sync_local_steps!r} (int >= 1)"
-            )
-        if sync_local_steps < 1:
-            raise ValueError(
-                f"unsupported sync_local_steps {sync_local_steps!r} (int >= 1)"
-            )
-        self._sync_local_steps = sync_local_steps
-        self._link_weather = LinkWeather()
-        # per-round decision log: {round, form, link_mbps, delta_bytes,
-        # steps}. Appended at sync SPAWN (spawns are sequential, like
-        # the EF residual handoff) and read through `sync_decisions`
-        # after the chain settles.
-        self._sync_decisions: list = []
-        # Bucketed delta push (--sync_bucket_bytes /
-        # EDL_SYNC_BUCKET_BYTES): split the super-window delta into
-        # ~this-many-byte layer-aligned buckets (template leaf
-        # boundaries) and stream them; the PS shard parks partial sets
-        # and applies the full set atomically at the window boundary.
-        # Sharded-PS route only — the single-master path keeps flat
-        # pushes (its ReportLocalUpdate carries task metadata the
-        # bucket RPC does not).
+        # Bucketed delta push (EDL_SYNC_BUCKET_BYTES, no CLI flag):
+        # split the window delta into ~this-many-byte layer-aligned
+        # buckets (template leaf boundaries) and stream them; the PS
+        # shard parks partial sets and applies the full set atomically
+        # at the window boundary. Sharded-PS route only — the
+        # single-master path keeps flat pushes (its ReportLocalUpdate
+        # carries task metadata the bucket RPC does not).
         if sync_bucket_bytes is None:
             sync_bucket_bytes = (
                 os.environ.get(ENV_SYNC_BUCKET_BYTES, "") or 0
@@ -1127,15 +1071,8 @@ class Worker:
 
     def _lossy_sync(self) -> bool:
         """Whether the up-direction sync plane is lossy (EF-compressed):
-        bf16/int8 quantization or top-k sparsification. Adaptive mode
-        counts as lossy — any given round MAY pick a lossy form, so the
-        residual machinery must be engaged (an adaptive f32 round still
-        folds in and clears the residual; see _ef_quantize_delta)."""
-        return (
-            self._sync_adaptive
-            or self._sync_dtype in ("bfloat16", "int8")
-            or self._topk_ratio > 0
-        )
+        bf16/int8 quantization or top-k sparsification."""
+        return self._sync_dtype in ("bfloat16", "int8") or self._topk_ratio > 0
 
     def _model_wire_dtype(self):
         """Dtype requested for model-DOWN payloads (pull / piggyback).
@@ -1185,19 +1122,17 @@ class Worker:
         deq = (q.astype(jnp.float32) * scale[:, None]).reshape(-1)[:n]
         return q.reshape(-1)[:n], scale, deq
 
-    def _ef_compress(self, comp, topk: bool, dtype=None, ratio=None):
+    def _ef_compress(self, comp, topk: bool):
         """Compress `comp` (delta-or-grad + residual, f32 device) per
-        the configured knobs — or per a per-round override (`dtype`,
-        `ratio`) when the adaptive plane picks this round's form.
+        the configured knobs.
         Returns (meta, dev_arrays, residual): meta is a static
         descriptor consumed by _materialize_wire_delta after
         device_get, dev_arrays the device payload, residual the new
         on-device f32 error mass."""
-        dtype = self._sync_dtype if dtype is None else dtype
+        dtype = self._sync_dtype
         if topk:
-            ratio = self._topk_ratio if ratio is None else ratio
             n = int(comp.shape[0])
-            k = min(n, max(1, int(round(ratio * n))))
+            k = min(n, max(1, int(round(self._topk_ratio * n))))
             _, idx = jax.lax.top_k(jnp.abs(comp), k)
             idx = jnp.sort(idx)  # sorted => PS-shard slicing is a range
             vals = comp[idx]
@@ -1247,52 +1182,21 @@ class Worker:
             )
         raise ValueError(f"unknown wire-delta meta {meta!r}")
 
-    def _ef_quantize_delta(self, delta_dev, form=None):
+    def _ef_quantize_delta(self, delta_dev):
         """Window-delta EF (called at sync SPAWN on the main thread —
         spawns are sequential, so the residual handoff needs no lock).
         The residual is folded into the next window even when windows
         overlap in flight: each spawn consumes the residual left by the
-        previous spawn, preserving the telescoping sum. `form` is the
-        adaptive plane's per-round pick (sync_policy.WIRE_FORMS); None
-        keeps the statically configured knobs. An adaptive "f32" round
-        ships the residual-corrected delta exactly and clears the
-        residual (compress = identity). Returns (meta, dev_arrays) for
-        _materialize_wire_delta."""
+        previous spawn, preserving the telescoping sum. Returns
+        (meta, dev_arrays) for _materialize_wire_delta."""
         if self._ef_residual is None or (
             self._ef_residual.shape != delta_dev.shape
         ):
             self._ef_residual = jnp.zeros_like(delta_dev)
         comp = delta_dev + self._ef_residual
-        if form is None:
-            meta, arrays, residual = self._ef_compress(
-                comp, topk=self._topk_ratio > 0
-            )
-        elif form == "f32":
-            meta, arrays, residual = (
-                ("dense",),
-                (comp,),
-                jnp.zeros_like(comp),
-            )
-        elif form == "bf16":
-            meta, arrays, residual = self._ef_compress(
-                comp, topk=False, dtype="bfloat16"
-            )
-        elif form == "int8":
-            meta, arrays, residual = self._ef_compress(
-                comp, topk=False, dtype="int8"
-            )
-        elif form == "topk":
-            # exact kept values; the configured ratio if one is set,
-            # else a storm-weather default that still ships the bulk of
-            # the delta's magnitude
-            meta, arrays, residual = self._ef_compress(
-                comp,
-                topk=True,
-                dtype="float32",
-                ratio=self._topk_ratio or 0.1,
-            )
-        else:
-            raise ValueError(f"unknown adaptive wire form {form!r}")
+        meta, arrays, residual = self._ef_compress(
+            comp, topk=self._topk_ratio > 0
+        )
         self._ef_residual = residual
         return meta, arrays
 
@@ -1810,12 +1714,9 @@ class Worker:
         self._aux = new_aux or self._aux
         self._pending_steps += 1
         self._latest_step_loss = loss
-        if self._pending_steps >= self._local_updates * self._sync_local_steps:
+        if self._pending_steps >= self._local_updates:
             # async: the delta d2h + RPC ride a background thread while
             # the device starts the next window (double-buffering).
-            # With the local-steps ladder (k > 1) the threshold is k
-            # windows: the cumulative delta keeps growing on device and
-            # ONE push covers the super-window.
             self._sync_local_updates(blocking=False)
         return loss  # device array; resolve lazily so steps pipeline
 
@@ -2024,7 +1925,7 @@ class Worker:
         self._aux = new_aux or self._aux
         self._pending_steps += self._local_updates
         self._latest_step_loss = loss
-        if self._pending_steps >= self._local_updates * self._sync_local_steps:
+        if self._pending_steps >= self._local_updates:
             self._sync_local_updates(blocking=False)
         return loss
 
@@ -2221,18 +2122,6 @@ class Worker:
             if self._delta_scratch is None:
                 self._delta_scratch = np.empty(delta_f32_bytes // 4, np.float32)
         wire_meta = None
-        wire_form = None
-        link_mbps = None
-        if self._sync_adaptive:
-            # per-round wire-form pick from the passive link estimate
-            # (sync_policy.decide is pure; LinkWeather holds the push
-            # timings the sync threads already measured). Decided at
-            # spawn, like the EF residual handoff — spawns are
-            # sequential, so the decision log needs no lock.
-            link_mbps = self._link_weather.mbps()
-            wire_form = sync_policy.decide(
-                link_mbps, delta_f32_bytes, self._sync_decisions
-            )
         # serial chain: where the step loop is let go, and why not
         # sooner (on the spans; nothing on the overlapped chain's)
         released, why, at_release = {}, None, None
@@ -2253,12 +2142,6 @@ class Worker:
             self.sync_releases[released["released"]] += 1
             at_release = threading.Event()
         wspan_args = {"worker": self._id, "seq": run_seq, **released}
-        if wire_form is not None:
-            # the round's decision rides the window span for the
-            # critical-path/decision audits
-            wspan_args["wire_form"] = wire_form
-            if link_mbps is not None:
-                wspan_args["link_mbps"] = round(link_mbps, 2)
         # one trace per window: the spawn-side quantize and the async
         # sync chain (encode / push RPCs / apply) all hang off this
         # root; it ends when do_sync settles, so its duration IS the
@@ -2271,23 +2154,11 @@ class Worker:
             # carries bf16/int8/top-k but the SUM of what the PS
             # applies tracks the f32 trajectory (see _ef_quantize_delta)
             with self._chain_span("worker.quantize", parent=wctx, seq=run_seq):
-                wire_meta, delta_dev = self._ef_quantize_delta(
-                    delta_dev, form=wire_form
-                )
+                wire_meta, delta_dev = self._ef_quantize_delta(delta_dev)
         elif self._transport_dtype == "bfloat16":
             # plain cast on DEVICE: halves the per-window d2h bytes
             delta_dev = delta_dev.astype(jnp.bfloat16)
         steps = self._pending_steps
-        if wire_form is not None:
-            self._sync_decisions.append(
-                {
-                    "round": len(self._sync_decisions),
-                    "form": wire_form,
-                    "link_mbps": link_mbps,
-                    "delta_bytes": delta_f32_bytes,
-                    "steps": steps,
-                }
-            )
         # dedup key, fixed at spawn: deterministic when the task carries
         # a dispatcher spec_key (speculation-stable — both copies of a
         # speculated task name this window identically), else a fresh
@@ -2496,7 +2367,6 @@ class Worker:
                     if spawn_shard_bases is not None
                     else [base_version] * self._ps.num_shards
                 )
-                push_t0 = time.monotonic()
                 if self._sync_bucket_bytes:
                     # bucketed push: layer-aligned buckets stream to
                     # each shard under ONE report_key; the shard parks
@@ -2520,7 +2390,6 @@ class Worker:
                         model_dtype=req.get("model_dtype"),
                         report_key=report_key,
                     )
-                self._observe_push(delta_h, push_t0, wire_form)
                 meta = {
                     "worker_id": self._id,
                     "versions": versions,
@@ -2543,7 +2412,6 @@ class Worker:
                     resp["aux"] = meta_resp.get("aux")
             else:
                 versions = None
-                push_t0 = time.monotonic()
                 try:
                     resp = self._call_master("ReportLocalUpdate", req)
                 finally:
@@ -2564,7 +2432,6 @@ class Worker:
                                 busy_ms=round(busy * 1e3, 3),
                                 bytes=delta_f32_bytes, seq=run_seq,
                             )
-                self._observe_push(delta_h, push_t0, wire_form)
             with self._report_lock:
                 if epoch != self._sync_epoch:
                     return  # reset raced the RPC: discard the response
@@ -2759,24 +2626,6 @@ class Worker:
         args = (self._flat,)
         with self._first_call(self._snapshot_in_slices, args):
             return self._snapshot_in_slices(*args)
-
-    @property
-    def sync_decisions(self):
-        """Copy of the adaptive plane's per-round decision log. Empty
-        unless --sync_adaptive on."""
-        return [dict(d) for d in self._sync_decisions]
-
-    def _observe_push(self, delta_h, t0, wire_form):
-        """Post-push accounting on the sync thread: feed the passive
-        link tracker from the round-trip the push just paid (the cheap
-        per-round probe — zero extra traffic), and stamp the round's
-        chosen wire form into WireStats' per-form breakdown."""
-        wire_bytes = codec.delta_nbytes(delta_h)
-        self._link_weather.observe(wire_bytes, time.monotonic() - t0)
-        if wire_form is not None:
-            wire = getattr(self._master, "wire", None)
-            if wire is not None and hasattr(wire, "record_wire_form"):
-                wire.record_wire_form(wire_form, wire_bytes)
 
     def _bucket_bounds_for(self, n: int):
         """Layer-aligned cut points for the bucketed push: greedy

@@ -525,7 +525,10 @@ def _run_training_job(tmp, tag, monkeypatch, chaos_spec):
             # finish it before the victim has made the GetTask it dies
             # on, or before the manager has seen it die: the crash and
             # its relaunch are part of what this run must show, so it
-            # ends when they have happened, not a poll earlier
+            # ends when they have happened, not a poll earlier; on a
+            # clock of its own, so a job that a loaded host stretched
+            # towards its deadline leaves the relaunch its time
+            deadline = time.time() + 120
             while manager.relaunches() < 1:
                 assert time.time() < deadline, f"job[{tag}]: no relaunch"
                 time.sleep(0.05)
@@ -1447,8 +1450,13 @@ def test_traced_chaos_job_over_uds_emits_sync_span_tree(
 
         doc = obs_trace.chrome_trace_from_spans(spans)
         doc = json.loads(json.dumps(doc))  # serializable end to end
-        assert doc["traceEvents"]
-        assert {e["ph"] for e in doc["traceEvents"]} == {"X"}
+        # every span is one complete event; what else is there names
+        # the row of a span that names its thread, which a call or a
+        # collection that a loaded host held up does here
+        # (`rpc.server.slow`, `proc.gc`)
+        phases = [e["ph"] for e in doc["traceEvents"]]
+        assert phases.count("X") == len(spans) > 0
+        assert set(phases) <= {"X", "M"}
     finally:
         obs_trace.configure(None)
         obs_trace.RECORDER.clear()

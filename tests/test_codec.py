@@ -298,7 +298,6 @@ def test_a_delta_s_size_is_counted_without_waiting_or_joining():
     lander = _Lander(np.zeros(300, np.float32), [100])
     vector = lander.vector()
     assert codec.delta_length(vector) == 300
-    assert codec.delta_nbytes(vector) == 1200
     assert lander.waits == [0, 0]
 
 

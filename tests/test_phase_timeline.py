@@ -239,12 +239,24 @@ def test_a_burst_is_drained_before_the_ring_evicts_it(tmp_path, monkeypatch):
     monkeypatch.setenv("EDL_SCHED_PHASE_SECS", "30")
     out = trace.start_span_file(str(tmp_path), "worker-0")
     t = PhaseTimers(sink=trace.record_phase)
-    n = 3 * trace._DEFAULT_CAPACITY // trace._STRIPES  # three stripes' worth
+    stripe = trace._DEFAULT_CAPACITY // trace._STRIPES
+    n = 3 * stripe  # three stripes' worth
+
+    def written():
+        with open(out.path) as f:
+            return f.read().count("\n")
+
+    # with half a stripe not in the file yet the drainer gets the GIL
+    # until it has written, however loaded the host; in all for less
+    # long than its period, so a thread that `pressure` did not wake
+    # still loses spans
+    deadline = time.time() + 20
     for i in range(n):
         with t.phase("step", i=i):
             pass
         if i % 256 == 0:
-            time.sleep(0.02)  # the drainer gets the GIL
+            while i + 1 - written() >= stripe // 2 and time.time() < deadline:
+                time.sleep(0.005)
     out.stop()
     spans = trace.load_span_file(out.path)
     assert [s["args"]["i"] for s in spans] == list(range(n))

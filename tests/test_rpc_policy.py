@@ -407,12 +407,10 @@ def test_wire_stats_snapshot_shape_and_reset():
     ws = WireStats("ep:1")
     ws.record("Push", sent=10, received=4, transport="grpc")
     ws.record("Push", sent=0, received=0, transport="inproc", calls=1)
-    ws.record_wire_form("bf16", 5)
-    ws.record_wire_form("bf16", 7)
     snap = ws.snapshot()
     assert set(snap) == {
         "endpoint", "bytes_sent", "bytes_received", "calls",
-        "methods", "transports", "wire_forms",
+        "methods", "transports",
     }
     assert snap["endpoint"] == "ep:1"
     assert set(snap["methods"]["Push"]) == {
@@ -420,13 +418,11 @@ def test_wire_stats_snapshot_shape_and_reset():
     }
     assert snap["methods"]["Push"]["calls"] == 2  # explicit inproc call
     assert snap["transports"]["inproc"]["bytes_sent"] == 0
-    assert snap["wire_forms"] == {"bf16": {"bytes_sent": 12, "rounds": 2}}
 
     ws.reset()
     empty = ws.snapshot()
     assert empty["bytes_sent"] == 0
     assert empty["methods"] == {} and empty["transports"] == {}
-    assert empty["wire_forms"] == {}
 
 
 def test_aggregate_wire_snapshots_shape_identical():
@@ -436,20 +432,13 @@ def test_aggregate_wire_snapshots_shape_identical():
 
     a, b = WireStats("a"), WireStats("b")
     a.record("Report", sent=100, received=8, transport="uds")
-    a.record_wire_form("int8", 25)
     b.record("Report", sent=50, received=4, transport="uds")
     b.record("Pull", sent=3, received=900, transport="grpc")
-    b.record_wire_form("int8", 25)
     agg = aggregate_wire_snapshots([a.snapshot(), b.snapshot()])
     assert set(agg) == {
         "bytes_sent", "bytes_received", "methods", "transports",
-        "wire_forms",
     }
     assert agg["bytes_sent"] == 153
     assert agg["bytes_received"] == 912
     assert agg["methods"]["Report"]["bytes_sent"] == 150
     assert agg["transports"]["uds"]["calls"] == 2
-    assert agg["wire_forms"] == {"int8": {"bytes_sent": 50, "rounds": 2}}
-    # pre-adaptive snapshots (no "wire_forms" key) still aggregate
-    legacy = {k: v for k, v in a.snapshot().items() if k != "wire_forms"}
-    assert aggregate_wire_snapshots([legacy])["wire_forms"] == {}

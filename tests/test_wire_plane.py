@@ -577,7 +577,6 @@ def test_sync_dtype_env_fallback_and_validation(monkeypatch):
         _dummy_worker(sync_dtype="float16")
 
 
-@pytest.mark.parametrize("local_steps", [1, 4])
 @pytest.mark.parametrize(
     "sync_dtype,sync_compress",
     [
@@ -587,24 +586,14 @@ def test_sync_dtype_env_fallback_and_validation(monkeypatch):
         ("int8", "topk:0.5"),
     ],
 )
-def test_reset_local_state_drops_residuals(
-    sync_dtype, sync_compress, local_steps
-):
+def test_reset_local_state_drops_residuals(sync_dtype, sync_compress):
     """A sync-chain break invalidates the EF residual for EVERY lossy
     mode — a stale residual re-applied against a restored model would
-    inject error mass that was already (or never) shipped. With the
-    local-steps ladder (k>1) the residual additionally spans k windows
-    of accumulated error, so dropping it on reset matters MORE, not
-    less: the parametrization runs every mode at k=1 and k=4."""
+    inject error mass that was already (or never) shipped."""
     import jax.numpy as jnp
 
-    w = _dummy_worker(
-        sync_dtype=sync_dtype,
-        sync_compress=sync_compress,
-        sync_local_steps=local_steps,
-    )
+    w = _dummy_worker(sync_dtype=sync_dtype, sync_compress=sync_compress)
     assert w._lossy_sync()
-    assert w._sync_local_steps == local_steps
     w._ef_quantize_delta(jnp.ones(8, dtype=jnp.float32) * 1e-3)
     assert w._ef_residual is not None
     if w._sync_dtype in ("bfloat16", "int8"):
