@@ -17,6 +17,8 @@ auxiliary loss alongside the output.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 from typing import Optional, Tuple
@@ -346,6 +348,76 @@ def _relu2(h):
     return jnp.square(jax.nn.relu(h))
 
 
+# ---------------------------------------------------------------------------
+# The width the held experts' grouped matmuls run at
+
+# Measured (scripts/expert_width_probe.py; PERF.md, PR 61; one v5e chip,
+# jax 0.9.0, libtpu 0.0.34): what the compiler's grouped matmul costs
+# follows how its tiles divide the experts' inner width f, not f. The
+# set of a SwiGLU layer's nine products (forward and backward, 8 groups,
+# bfloat16, d = 2048, 11,000 rows on the 12,288 rung), ms and the useful
+# TFLOP/s at the stated width:
+#
+#    f      as it is       zero-padded to (pads and slices counted)
+#   1408  10.84   52.7     1536:  7.68  74.4   (1536 as it is: 6.65  93.7)
+#   1856  14.41   52.2     1920: 15.45  48.7    2048: 10.48  71.8
+#   1280   6.84   75.9     1536:  7.70  67.4
+#   1792   9.74   74.6     2048: 10.37  70.1
+#    512   2.13   97.4     1024:  4.47  92.9 as they are
+#
+# A multiple of 512 runs at 93-97, a multiple of 256 at 75, anything
+# else (11 x 128, 14.5 x 128, 15 x 128) at 49-53, at 6,144 rows and at
+# Nemotron's 490 rows of d = 2688 alike (there 1856 -> 2048 halves the
+# set, 6.50 -> 3.18 ms before the pads, 4.36 with them). A pad to the
+# next multiple of 256 pays for itself 1.3 to 1.5 times over; one from a
+# multiple of 256 to the next of 512 does not pay for its pads.
+WIDTH_TILE = 256
+
+
+def run_width(f: int) -> int:
+    """The inner width the grouped matmuls run an expert of width `f`
+    at: the smallest multiple of `WIDTH_TILE` that holds it; a width
+    inside one tile as it is. From `f` alone."""
+    return f if f <= WIDTH_TILE else -(-f // WIDTH_TILE) * WIDTH_TILE
+
+
+_WIDTHS_TRACED = contextvars.ContextVar("widths_traced", default=None)
+
+
+@contextlib.contextmanager
+def widths_traced():
+    """-> a set that gains (stated, run) for every expert layer this
+    thread traces inside the block whose width `run_width` moves."""
+    seen = set()
+    token = _WIDTHS_TRACED.set(seen)
+    try:
+        yield seen
+    finally:
+        _WIDTHS_TRACED.reset(token)
+
+
+def _at_run_width(experts):
+    """The leaves zero-padded from their inner width f to
+    `run_width(f)`: wg, wu [n, d, f] in their last axis, wd [n, f, d]
+    in its middle one. A padded column of wg and wu gives a hidden
+    entry silu(0) x 0 = 0 (relu(0)^2 = 0), which meets a zero row of
+    wd: every sum gains exact zeros, and the pads' gradients are
+    slices, so the leaves' come back at their own shapes. A width the
+    rule leaves alone comes back as it is, with no operation."""
+    *ups, wd = experts
+    f = wd.shape[1]
+    more = run_width(f) - f
+    if not more:
+        return experts
+    seen = _WIDTHS_TRACED.get()
+    if seen is not None:
+        seen.add((f, f + more))
+    return (
+        *(jnp.pad(w, ((0, 0), (0, 0), (0, more))) for w in ups),
+        jnp.pad(wd, ((0, 0), (0, more), (0, 0))),
+    )
+
+
 def _expert_groups(rows, experts, sizes):
     """The held experts on their groups of sorted rows, by the leaves
     they are given: three (wg, wu, wd) are SwiGLUs, wd(silu(wg x) * wu
@@ -546,7 +618,13 @@ def moe_topk_held(
             jax.nn.one_hot(group, n + 1, dtype=jnp.int32), axis=0
         )[:n]  # [n] rows of each held expert
         weight = jnp.where(here, gate * scaling, 0.0)  # [T, k] f32
-    routed = _held_experts(xf, weight, tuple(experts), order, sizes)
+    with jax.named_scope("experts"):
+        # in front of the switch, not in its branches: a branch's pad
+        # is an operation of its own, a pad out here fuses with the
+        # slice that cuts the layer's leaves from their stack, and the
+        # backward rule's branch finds the leaves padded already
+        experts = _at_run_width(tuple(experts))
+    routed = _held_experts(xf, weight, experts, order, sizes)
     with jax.named_scope("route"):
         balance_term = sequence_balance_loss(
             probs.reshape(b, s, -1), chosen.reshape(b, s, top_k)

@@ -61,6 +61,7 @@ from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.common.timing import DeviceRuns, PhaseTimers
 from elasticdl_tpu.obs import hlo_scopes
 from elasticdl_tpu.obs import trace as obs_trace
+from elasticdl_tpu.parallel import moe
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
 from elasticdl_tpu.worker import delta_stream
 from elasticdl_tpu.worker.task_data_service import (
@@ -1892,8 +1893,10 @@ class Worker:
         with first_call(
             window, args, carry="leaves" if leaves else "flat",
             carried=len(jax.tree_util.tree_leaves((model, state))),
-        ):
+        ) as span, moe.widths_traced() as widths:
             model, state, aux, loss = window(*args)
+            if span is not None:  # the call that traced the program
+                span["expert_widths"] = sorted(widths)
         runs = self._device_runs
         run = runs.asked("jit_window", self._local_updates)
         if not leaves:
@@ -3087,8 +3090,9 @@ class Worker:
         dispatch), at its call site: `program` is the jitted callable,
         with the call's arguments, or, for an eager op, the name jax
         gives its program; `attrs` is what else the span says. On the
-        way out a callable's map is written (`_write_scope_map`). Later
-        calls get a shared null context."""
+        way out a callable's map is written (`_write_scope_map`). The
+        block gets the span's arguments, to add what only the call
+        tells; later calls get a shared null context, and None."""
         called = self._programs_called
         if called is None:
             called = self._programs_called = set()
@@ -3102,8 +3106,8 @@ class Worker:
 
     @contextlib.contextmanager
     def _first_call_of(self, program, args, attrs):
-        with self._program_span(_program_name(program), **attrs):
-            yield
+        with self._program_span(_program_name(program), **attrs) as info:
+            yield info
         self._write_scope_map(program, args)
 
     @contextlib.contextmanager
@@ -3112,7 +3116,7 @@ class Worker:
             "setup.program", program=program, **attrs
         ) as info:
             with _compiles_into(info):
-                yield
+                yield info
 
     def _first_run_begins(self, mode: str):
         if not self._first_run_begun and self._first_run is not None:

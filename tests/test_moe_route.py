@@ -4,7 +4,10 @@ term, stats and every gradient are the full buffer's, and a dense
 per-expert reference's; the rung follows sum(sizes) to the row; a
 router that sends every token to held experts takes the top rung and
 loses nothing, forward or backward; `route_rows` and `route_full` say
-what was taken."""
+what was taken. And the width the grouped matmuls run at
+(`moe.run_width`): a layer whose width the rule moves is the layer at
+its stated width, outputs and every gradient, and a width it leaves
+alone traces the program it traced before the rule."""
 
 import os
 
@@ -280,10 +283,16 @@ def test_a_router_that_sends_nothing_here_takes_the_lowest_rung():
     assert all(float(jnp.max(jnp.abs(g))) == 0 for g in grads["experts"])
 
 
+@pytest.mark.parametrize("padded", [False, True])
 @pytest.mark.parametrize("remat", [False, True])
-def test_the_layer_differentiates_inside_a_scanned_rematerialised_stack(remat):
+def test_the_layer_differentiates_inside_a_scanned_rematerialised_stack(
+    remat, padded, monkeypatch
+):
     """As `plain_forward` runs it: the layer in a scanned body under
-    `jax.checkpoint`, two layers whose routers take different rungs."""
+    `jax.checkpoint`, two layers whose routers take different rungs;
+    `padded`, with the experts' 12 columns run as 16."""
+    if padded:
+        monkeypatch.setattr(moe, "WIDTH_TILE", 8)
     case = CASES["sigmoid-top4-renormalised"]
     rungs = moe.route_rungs(TOKENS, 4, 8)
     low, _ = lean(weights(case, 1), case, 0, rungs[0])
@@ -303,9 +312,11 @@ def test_the_layer_differentiates_inside_a_scanned_rematerialised_stack(remat):
         return jnp.sum(h * h), rows
 
     x = low["x"]
-    (_l, rows), grads = jax.jit(
-        jax.value_and_grad(lambda s, x: forward(s, x, layer), argnums=(0, 1), has_aux=True)
-    )(stack, x)
+    with moe.widths_traced() as widths:
+        (_l, rows), grads = jax.jit(
+            jax.value_and_grad(lambda s, x: forward(s, x, layer), argnums=(0, 1), has_aux=True)
+        )(stack, x)
+    assert widths == ({(F, 16)} if padded else set())
     # the second layer reads the first's output, so its rows are its own
     assert float(rows[0]) == rungs[0] and float(rows[1]) in rungs
     (_l, _r), want = jax.value_and_grad(
@@ -313,6 +324,119 @@ def test_the_layer_differentiates_inside_a_scanned_rematerialised_stack(remat):
     )(stack, x)
     for g, g_ref in zip(*(jax.tree_util.tree_leaves(g) for g in (grads, want))):
         assert close(g, g_ref, 5e-5)
+
+
+# ---------------------------------------------------------------------------
+# The width the grouped matmuls run at
+
+
+def rule_held_off(monkeypatch):
+    """Every width runs as it is stated: the layer before the rule."""
+    monkeypatch.setattr(moe, "run_width", lambda f: f)
+
+
+def of_kind(w, kind):
+    """`w` with experts (and a shared expert) of `kind`'s leaves."""
+    if kind == "swiglu":
+        return w
+    return {
+        **w, "experts": w["experts"][1:],
+        "shared": w["shared"] and w["shared"][1:],
+    }
+
+
+@pytest.mark.parametrize(
+    "f, run",
+    [(1408, 1536), (1536, 1536), (1024, 1024), (512, 512), (1856, 2048),
+     (F, F), (256, 256), (257, 512), (1280, 1280), (1792, 1792)],
+    ids=["deepseek-v2-lite", "lfm2-24b-a2b", "kimi-linear-48b-a3b",
+         "laguna-xs2.qwen3-next-80b-a3b", "nemotron-3-nano-30b-a3b",
+         "the-tests-own", "one-tile", "a-column-more", "5x256", "7x256"],
+)
+def test_the_rule_on_the_cells_widths(f, run):
+    assert moe.run_width(f) == run
+
+
+@pytest.mark.parametrize("rung", [0, 3])
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+def test_a_width_the_rule_moves_gives_the_stated_width_s_layer_and_gradients(
+    kind, rung, monkeypatch
+):
+    """12 columns run as 16, on the lowest rung and on the full buffer:
+    outputs, balance term and the gradient by x, the router and every
+    expert leaf are those of the layer run at 12, to float32 rounding,
+    and the leaves' gradients have the leaves' shapes."""
+    monkeypatch.setattr(moe, "WIDTH_TILE", 8)
+    case = CASES["softmax-top2-shared"]
+    rungs = moe.route_rungs(TOKENS, case["top_k"], case["held"][1])
+    w, _came = lean(
+        of_kind(weights(case), kind), case, rungs[rung - 1] if rung else 0, rungs[rung]
+    )
+    with moe.widths_traced() as widths:
+        (y, term, stats), grads = loss_and_grads(w, case)
+    assert widths == {(F, 16)}
+    assert float(stats["route_rows"]) == rungs[rung]
+    rule_held_off(monkeypatch)
+    with moe.widths_traced() as widths:
+        (y_as, term_as, _stats), grads_as = loss_and_grads(w, case)
+    assert widths == set()
+    assert close(y, y_as, 2e-6) and close(term, term_as, 1e-7)
+    assert len(grads["experts"]) == (3 if kind == "swiglu" else 2)
+    for g, leaf in zip(grads["experts"], w["experts"]):
+        assert g.shape == leaf.shape
+    flat, flat_as = (jax.tree_util.tree_leaves_with_path(g) for g in (grads, grads_as))
+    assert len(flat) == (8 if kind == "swiglu" else 6)
+    for (path, g), (_p, g_as) in zip(flat, flat_as):
+        where = jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(g_as))) > 0, where
+        assert close(g, g_as, 2e-6), where
+
+
+def traced(f, kind):
+    """The jaxpr of a small layer of width `f`, value and gradients."""
+    shape = jax.ShapeDtypeStruct
+    w = of_kind({
+        "x": shape((2, 32, D), jnp.float32), "router": shape((D, EXPERTS), jnp.float32),
+        "experts": tuple(
+            shape(s, jnp.float32) for s in ((8, D, f), (8, D, f), (8, f, D))
+        ),
+        "shared": None,
+    }, kind)
+    case = CASES["sigmoid-top4-renormalised"]
+
+    def loss(x, router, experts):
+        y, term, _stats = moe.moe_topk_held(x, router, experts, None, **settings(case))
+        return jnp.sum(y) + term
+
+    return jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        w["x"], w["router"], w["experts"]
+    )
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+@pytest.mark.parametrize("f", [512, 1024, 1536, F, 1408, 1856])
+def test_a_width_the_rule_leaves_alone_traces_the_program_it_traced_before(
+    f, kind, monkeypatch
+):
+    """512, 1024, 1536 (and the tests' own 12): no `pad`, and the jaxpr
+    of the layer with the rule held off, letter for letter. 1408 and
+    1856: pads, and the leaves' gradients at the leaves' shapes."""
+    run = moe.run_width(f)
+    moved = run != f
+    assert moved == (f in (1408, 1856))
+    program = traced(f, kind)
+    text = str(program)
+    rule_held_off(monkeypatch)
+    before = str(traced(f, kind))
+    assert " pad[" not in before
+    assert [v.shape for v in program.out_avals[3:]] == (
+        [(8, D, f)] * (2 if kind == "swiglu" else 1) + [(8, f, D)]
+    )
+    if moved:
+        assert " pad[" in text and f"{run}]" in text
+        assert f"{run}]" not in before
+    else:
+        assert text == before
 
 
 def test_the_transformer_reduces_the_two_counters_over_its_expert_layers():

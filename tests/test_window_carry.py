@@ -45,8 +45,8 @@ def leaves_carry(monkeypatch):
     monkeypatch.setattr(worker_module, "CARRY_LEAVES_MIN_MEAN_ELEMENTS", 1)
 
 
-def lm_worker(optimizer=clipped_adam):
-    spec = spec_from_module(transformer_lm_zoo, optimizer=optimizer)
+def lm_worker(optimizer=clipped_adam, zoo=transformer_lm_zoo):
+    spec = spec_from_module(zoo, optimizer=optimizer)
     servicer = MasterServicer(
         grads_to_wait=1, optimizer=PSOptimizer(spec.optimizer())
     )
@@ -355,6 +355,37 @@ def test_the_window_s_setup_span_says_what_the_loop_carries(
     )
     for name in ("jit_cut", "jit_join"):
         assert "carry" not in programs.get(name, {})
+
+
+@pytest.mark.parametrize("zoo", ["dense", "routed", "routed-padded"])
+def test_the_window_s_setup_span_says_which_expert_widths_it_pads(
+    monkeypatch, zoo
+):
+    """`expert_widths`: the distinct (stated, run) widths of the expert
+    layers the window's trace padded (`parallel/moe.run_width`); none
+    for a model without expert layers, none where the rule leaves the
+    width alone (the fixture's 20 columns lie inside one tile)."""
+    from elasticdl_tpu.parallel import moe
+    from tests.fixtures import routed_lm_tiny
+
+    if zoo == "routed-padded":
+        monkeypatch.setattr(moe, "WIDTH_TILE", 8)
+    trace.RECORDER.clear()
+    worker = lm_worker(
+        zoo=transformer_lm_zoo if zoo == "dense" else routed_lm_tiny
+    )
+    features, labels = batches()
+    worker._local_window_fn = worker._build_local_window_fn()
+    state = worker._spec.optimizer().init(worker._flat)
+    worker._run_window(worker._flat, state, worker._aux, features % 64, labels % 64)
+    programs = _programs()
+    assert programs["jit_window"]["expert_widths"] == (
+        [(20, 24)] if zoo == "routed-padded" else []
+    )
+    assert all(
+        "expert_widths" not in args
+        for name, args in programs.items() if name != "jit_window"
+    )
 
 
 # -- (e) donation and the warm-up --------------------------------------------
