@@ -39,7 +39,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from elasticdl_tpu.parallel.moe import moe_ffn
+from elasticdl_tpu.parallel.moe import EXPERT_KINDS, moe_ffn
 from elasticdl_tpu.parallel.pipeline import gpipe
 from elasticdl_tpu.parallel.ring_attention import ring_attention
 from elasticdl_tpu.parallel.tp_layers import rms_norm, swiglu
@@ -70,7 +70,8 @@ class TransformerConfig:
     rope_base: float = 10000.0
     norm_eps: float = 1e-6
     # "gelu": w2(gelu(w1 x)); "swiglu": wd(silu(wg x) * wu x), no bias;
-    # "relu2": wd relu(wu x)^2, two matrices and no gate (the routed
+    # "relu2": wd relu(wu x)^2, two matrices and no gate; "reglu":
+    # wd(relu(wg x) * wu x), the gated MLP under a ReLU (both the routed
     # stack's experts and shared expert only)
     mlp: str = "gelu"
     # four norms a layer: h + ln1b(attn(ln1(h))), h + ln2b(mlp(ln2(h)))
@@ -255,6 +256,14 @@ class TransformerConfig:
     # weight and a bias (`ln1_bias`, `ln2_bias`, `ln_f_bias`), its
     # statistics in float32; "rms": the RMS norm with a weight
     norm: str = "rms"
+    # the router of every expert layer reads the layer's INPUT, the
+    # residual stream as it enters the layer, un-normed and ahead of the
+    # mixer; the experts read ln2 of the stream behind the mixer as ever
+    # (scope `router`, in front of the mixer's; `moe_top_k` only)
+    early_router: bool = False
+    # the kinds of layer, of "mha" and "swa", whose queries and keys
+    # turn; None: both. `rope` False turns nothing whatever this says
+    rope_mixers: Optional[Tuple[str, ...]] = None
 
     @property
     def head_dim(self) -> int:
@@ -299,20 +308,23 @@ class TransformerConfig:
         """What parts an "mha" layer from a "swa" one. In a stack that
         holds both, each kind has a scope of its own under
         `attention`."""
+        turns = self.rope and (
+            self.rope_mixers is None or mixer in self.rope_mixers
+        )
         if mixer == "swa":
             return AttentionShape(
                 self.swa_heads, self.swa_window, self.swa_rope_base,
-                None, None, 1.0, "swa",
+                None, None, 1.0, "swa", turns,
             )
         if mixer == "cross":
             return AttentionShape(
                 self.n_heads, None, self.rope_base, self.rope_dim,
-                self.rope_yarn, self.rope_factor, "cross",
+                self.rope_yarn, self.rope_factor, "cross", turns,
             )
         return AttentionShape(
             self.n_heads, None, self.rope_base, self.rope_dim,
             self.rope_yarn, self.rope_factor,
-            "global" if "swa" in self.mixers else None,
+            "global" if "swa" in self.mixers else None, turns,
         )
 
     @property
@@ -341,6 +353,7 @@ class AttentionShape(NamedTuple):
     rope_yarn: Optional[YarnScaling]
     rope_factor: float
     scope: Optional[str]  # its scope under `attention`
+    turns: bool = True  # whether its queries and keys turn at all
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -387,8 +400,8 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
 
 def _expert_leaves(mlp: str):
     """(a routed expert's leaves, the shared expert's), in the order
-    the layer takes them: a SwiGLU's gate, up and down, a squared-ReLU
-    expert's up and down."""
+    the layer takes them: a gated expert's gate, up and down ("swiglu",
+    "reglu"), a squared-ReLU expert's up and down."""
     if mlp == "relu2":
         return ("eu", "ed"), ("su", "sd")
     return ("eg", "eu", "ed"), ("sg", "su", "sd")
@@ -540,8 +553,8 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
     if not (
         # top-k experts, or none at all: every layer's MLP the dense one
         (cfg.moe_top_k or not cfg.n_experts)
-        and cfg.mlp in ("swiglu", "relu2")
-        and not (dense and cfg.mlp == "relu2")
+        and cfg.mlp in EXPERT_KINDS
+        and not (dense and cfg.mlp != "swiglu")
         and set(cfg.mixers) <= set(ROUTED_MIXERS)
         and len(cfg.mixers) == cfg.n_layers
     ):
@@ -549,11 +562,13 @@ def _init_routed_params(norm, rng, cfg: TransformerConfig) -> Dict:
             "the routed stack is built with latent attention, delta-rule "
             "attention, short convolutions, state-space mixers or gated "
             "memory units beside grouped-query, differential and cross "
-            "attention, top-k experts and gated or squared-ReLU MLPs "
+            "attention, top-k experts and gated (SiLU or ReLU) or "
+            "squared-ReLU MLPs "
             "together (attention or layer_types of "
             + ", ".join(repr(m) for m in ROUTED_MIXERS)
             + ", one a layer; moe_top_k > 0, or n_experts = 0; "
-            "mlp='swiglu', or mlp='relu2' in a stack with no dense layer)"
+            "mlp='swiglu', or mlp='relu2' or 'reglu' in a stack with no "
+            "dense layer)"
         )
     _require_feeders(cfg)
 
@@ -841,6 +856,19 @@ def _require_feeders(cfg: TransformerConfig):
             "diff_attention pairs the heads (an even n_heads and "
             "n_kv_heads) and takes diff_depths, one published index a layer"
         )
+    if cfg.early_router and not cfg.moe_top_k:
+        raise ValueError(
+            "early_router places the top-k expert layers' router "
+            "(moe_top_k > 0); this stack has none"
+        )
+    if cfg.rope_mixers is not None and (
+        cfg.diff_attention or not set(cfg.rope_mixers) <= {"mha", "swa"}
+    ):
+        raise ValueError(
+            f"rope_mixers {cfg.rope_mixers} names the kinds of layer that "
+            "turn, of 'mha' and 'swa', outside differential attention "
+            "(whose 'cross' layers read another layer's turned keys)"
+        )
 
 
 def _require_mesh_support(cfg: TransformerConfig):
@@ -857,11 +885,12 @@ def _require_mesh_support(cfg: TransformerConfig):
         or cfg.ssm_heads or cfg.ssm_head_dim or cfg.ssm_state
         or cfg.ssm1_inner or cfg.ssm1_dt_rank or cfg.memory_layer is not None
         or cfg.diff_attention or cfg.diff_depths or cfg.kv_layer is not None
-        or cfg.attn_bias or cfg.norm != "rms"
+        or cfg.attn_bias or cfg.norm != "rms" or cfg.early_router
+        or cfg.rope_mixers is not None
     ):
         raise NotImplementedError(
             "the (pp, dp, sp, tp) mesh path runs the two-matrix GELU "
-            "block once: mlp='swiglu' and 'relu2', sandwich_norm, "
+            "block once: mlp='swiglu', 'relu2' and 'reglu', sandwich_norm, "
             "n_loops > 1, attention='mla', 'kda', 'gdn' and 'mamba2', "
             "layer_types (with 'swa' and 'conv'), bare_layers, "
             "n_dense_layers, moe_top_k, n_kv_heads, qk_norm, "
@@ -872,8 +901,9 @@ def _require_mesh_support(cfg: TransformerConfig):
             "the state-space mixers (ssm_heads, ssm_head_dim, ssm_state; "
             "'mamba1' with ssm1_inner, ssm1_dt_rank, memory_layer and the "
             "'gmu' that reads it), differential attention (diff_attention, "
-            "diff_depths, 'cross' and kv_layer), attn_bias and "
-            "norm='layer' exist on the unsharded path (plain_forward) only"
+            "diff_depths, 'cross' and kv_layer), attn_bias, norm='layer', "
+            "early_router and rope_mixers exist on the unsharded path "
+            "(plain_forward) only"
         )
 
 
@@ -1366,7 +1396,7 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
         v = (x @ lp["wv"]).reshape(b, l, cfg.kv_heads, cfg.head_dim)
         if cfg.qk_norm:
             q, k = _qk_norm(lp, q, k, cfg.norm_eps)
-        with _scope(cfg.rope and inner and "rope"):
+        with _scope(shape.turns and inner and "rope"):
             turn = dict(
                 freqs=shape.rope_yarn and yarn_frequencies(
                     shape.rope_dim or cfg.head_dim, shape.rope_base,
@@ -1374,7 +1404,7 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
                 ),
                 rot=shape.rope_dim, factor=shape.rope_factor,
             )
-            if cfg.rope:
+            if shape.turns:
                 q = _rope(q, positions, shape.rope_base, **turn)
                 k = _rope(k, positions, shape.rope_base, **turn)
         out = attention(q, k, v, causal=True, window=shape.window)
@@ -1641,7 +1671,9 @@ def plain_forward(cfg: TransformerConfig, params: Dict, tokens: jnp.ndarray):
     reader of a device trace joins to (obs/hlo_scopes.py): `embed`;
     in each layer `attention` (`mla`, `kda`, `gdn`, `conv`,
     `mamba2` > `run<i>`) and `mlp` (`moe` > `route`, `experts`,
-    `shared`; a layer of `bare_layers` has no such scope); `head` round
+    `shared`; a layer of `bare_layers` has no such scope; under
+    `early_router` the router's product lies under `router`, in front
+    of the layer's mixer); `head` round
     the final norm, the logits and
     the loss; and round those the looped LM's `looped_stack` and
     `exit_heads`."""
@@ -1657,7 +1689,9 @@ def plain_forward_stats(
     length of the sorted buffer a layer took) and `route_full`, how
     many layers took the full one ({} without `moe_top_k`). The zoo
     adapter leaves it in `window_stats`."""
-    from elasticdl_tpu.parallel.moe import moe_ffn_local, moe_topk_held
+    from elasticdl_tpu.parallel.moe import (
+        moe_ffn_local, moe_topk_held, router_logits,
+    )
 
     stored = params
     params = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), params)
@@ -1715,6 +1749,14 @@ def plain_forward_stats(
 
         def body(carry, lp):
             h, aux = carry
+            logits = None
+            if cfg.early_router and experts and routed:
+                # the scores of the layer's input: nothing the mixer
+                # does reaches them
+                with jax.named_scope("router"):
+                    logits = router_logits(
+                        h.reshape(b * l, cfg.d_model), lp["router"]
+                    )
             # a state-space mixer's operations name their run as well:
             # a reader of the trace counts the passes of the scan run
             # by run (`benchmark/layer_metrics/_ssm.py`)
@@ -1744,6 +1786,7 @@ def plain_forward_stats(
                         renormalize=cfg.moe_renormalize,
                         balance=bool(cfg.aux_weight),
                         shared_gate=lp.get("sgate"),
+                        kind=cfg.mlp, logits=logits,
                     )
                     stats = {**stats, **routing}
                 elif experts:

@@ -111,7 +111,15 @@ def pick_tiles(L: int, window=None):
     q x k edge: 128 x 128 41.9, 256 x 256 23.4, 512 x 512 16.4,
     1024 x 1024 20.8, 256 x 512 19.8, 512 x 256 22.2, 1024 x 512 21.0;
     the causal call of those shapes 42.2 (and at 48 heads 31.7 at
-    1024 x 1024, 41.9 at 512 x 512). Other windows are not measured."""
+    1024 x 1024, 41.9 at 512 x 512). At (1, 16384, 28, 128), window
+    4096 (2026-10-04, `--cases band4k`; forward alone in brackets):
+    1024 x 1024 34.0 (9.6), 512 x 1024 36.5 (10.5), 2048 x 2048 38.4
+    (11.0), 2048 x 1024 39.1 (11.1), 512 x 512 41.3 (14.9), 1024 x 512
+    42.8 (16.9), 256 x 256 79.6 (31.6); the causal call of those shapes
+    62.7 (17.7) at 1024 x 1024 and 87.9 (31.9) at 512 x 512. A band of
+    four tiles runs four to five a row, so the ladder's first edge wins
+    under a window that holds it as it does without one, and the rule
+    stays. Windows between 512 and 4096 are not measured."""
     most = max(window or TILE_LADDER[0], BLOCK)
     edge = next(
         (t for t in TILE_LADDER if t <= most and L > 0 and L % t == 0), None
@@ -694,6 +702,14 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 # and nothing of its widths. Against the float32 reference at (1, 2048,
 # 8, 64 | 128), band and full: o 0.24 %, dq 0.53 %, dk 0.37-0.38 %, dv
 # 0.25-0.28 % of the largest entry.
+# 16,384 tokens, 28 query heads of 128 on 4 key-value heads widened in
+# front of the call, a group of 7 (2026-10-04, the same chip kind and
+# versions, `scripts/swa_kernel_sweep.py --cases band4k`; `pick_tiles`
+# has the table): 34.0 ms under a window of 4096 and 62.7 ms in full at
+# 1024 x 1024, forward + backward; XLA's path would write 30 GB of
+# scores and is not run. Against the float32 reference at (1, 8192, 7,
+# 128), band and full: o 0.27 %, dq 0.40 %, dk 0.36-0.44 %, dv
+# 0.42-0.48 % of the largest entry.
 FLASH_MIN_LENGTH = 2048
 
 
@@ -706,7 +722,8 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
     On a TPU the Pallas kernels take a call whose sequence the tile
     ladder divides and that is at least FLASH_MIN_LENGTH long, at
     every head width measured (64, 128, 192 | 128, since 2026-10-02 256
-    at 8192 tokens and since 2026-10-03 64 | 128 at 4096, band and full:
+    at 8192 tokens, since 2026-10-03 64 | 128 at 4096, band and full,
+    and since 2026-10-04 128 at 16,384 under a window of 4096 and in full:
     FLASH_MIN_LENGTH's table; the rule reads nothing
     but the call's own shapes); XLA's attention takes the rest.
     EDL_TPU_FLASH=1 forces the kernels on for any block-divisible L,

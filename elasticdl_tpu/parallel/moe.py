@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from elasticdl_tpu.parallel.tp_layers import swiglu
 
 
 def _route(
@@ -126,7 +125,17 @@ def moe_ffn(
 # Top-k, dropless, gated experts, on the share of the experts held here
 
 
-def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int):
+def router_logits(x: jnp.ndarray, router_w: jnp.ndarray) -> jnp.ndarray:
+    """x W in float32 by a `HIGHEST` product, whatever x is: x [T, d],
+    router_w [d, E] -> [T, E] float32."""
+    return jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+
+
+def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
+               logits=None):
     """softmax over all of the router's outputs in float32, then the
     `top_k` largest, greedy: x [T, d], router_w [d, E] ->
     (probs [T, E] f32, gate [T, k] f32, chosen [T, k] int32).
@@ -137,11 +146,11 @@ def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int):
     probabilities, and two of 64 lie closer than bfloat16 tells apart
     for a token in every few. The gates are the chosen probabilities as
     they are (not renormalised over the k). Equal probabilities go to
-    the lower expert first (`lax.top_k` is stable)."""
-    logits = jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    )
+    the lower expert first (`lax.top_k` is stable). `logits` [T, E]
+    float32, where the caller formed them already (`router_logits`, from
+    another tensor than the experts read), take the product's place."""
+    if logits is None:
+        logits = router_logits(x, router_w)
     probs = jax.nn.softmax(logits, axis=-1)
     gate, chosen = lax.top_k(probs, top_k)
     return probs, gate, chosen.astype(jnp.int32)
@@ -149,18 +158,17 @@ def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int):
 
 def route_sigmoid_topk(
     x: jnp.ndarray, router_w: jnp.ndarray, bias, top_k: int,
-    renormalize: bool,
+    renormalize: bool, logits=None,
 ):
     """Each expert's own score, s = sigmoid(x W) in float32 (a
     `HIGHEST` product, as `route_topk`'s), then the `top_k` largest of
     s + `bias` ([E], a selection bias no gradient reaches; None = 0).
     The gates are the chosen experts' s, the bias not in them, and with
     `renormalize` divided by their sum over all `top_k` chosen, held
-    here or not. -> (s [T, E], gate [T, k], chosen [T, k] int32)."""
-    logits = jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    )
+    here or not. -> (s [T, E], gate [T, k], chosen [T, k] int32).
+    `logits` as `route_topk`'s."""
+    if logits is None:
+        logits = router_logits(x, router_w)
     scores = jax.nn.sigmoid(logits)
     biased = scores if bias is None else scores + lax.stop_gradient(
         bias.astype(jnp.float32)
@@ -400,8 +408,8 @@ def _at_run_width(experts):
     """The leaves zero-padded from their inner width f to
     `run_width(f)`: wg, wu [n, d, f] in their last axis, wd [n, f, d]
     in its middle one. A padded column of wg and wu gives a hidden
-    entry silu(0) x 0 = 0 (relu(0)^2 = 0), which meets a zero row of
-    wd: every sum gains exact zeros, and the pads' gradients are
+    entry silu(0) x 0 = 0 (relu(0) x 0 = 0, relu(0)^2 = 0), which meets
+    a zero row of wd: every sum gains exact zeros, and the pads' gradients are
     slices, so the leaves' come back at their own shapes. A width the
     rule leaves alone comes back as it is, with no operation."""
     *ups, wd = experts
@@ -418,30 +426,49 @@ def _at_run_width(experts):
     )
 
 
-def _expert_groups(rows, experts, sizes):
-    """The held experts on their groups of sorted rows, by the leaves
-    they are given: three (wg, wu, wd) are SwiGLUs, wd(silu(wg x) * wu
-    x); two (wu, wd) are squared-ReLU experts, wd relu(wu x)^2."""
-    if len(experts) == 2:
+# An expert's kind, as a model's `mlp` names it. "swiglu" and "reglu"
+# are gated, three leaves (wg, wu, wd): wd(act(wg x) * wu x), the gate's
+# activation SiLU or ReLU; "relu2" has two (wu, wd) and no gate: wd
+# relu(wu x)^2. A caller that names no kind gets it from the count of
+# leaves (three: "swiglu"), as before there were two gated kinds.
+_GATE_ACTIVATIONS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+EXPERT_KINDS = (*_GATE_ACTIVATIONS, "relu2")
+
+
+def _kind_of(leaves, kind: Optional[str]) -> str:
+    kind = kind or ("relu2" if len(leaves) == 2 else "swiglu")
+    if kind not in EXPERT_KINDS or len(leaves) != 2 + (kind != "relu2"):
+        raise ValueError(
+            f"an expert of kind {kind!r} with {len(leaves)} leaves: "
+            f"{EXPERT_KINDS[:-1]} take (wg, wu, wd), 'relu2' (wu, wd)"
+        )
+    return kind
+
+
+def _expert_groups(rows, experts, sizes, kind: Optional[str] = None):
+    """The held experts of `kind` on their groups of sorted rows."""
+    kind = _kind_of(experts, kind)
+    if kind == "relu2":
         wu, wd = experts
         return lax.ragged_dot(_relu2(lax.ragged_dot(rows, wu, sizes)), wd, sizes)
     wg, wu, wd = experts
-    hidden = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) * lax.ragged_dot(
-        rows, wu, sizes
-    )
+    hidden = _GATE_ACTIVATIONS[kind](
+        lax.ragged_dot(rows, wg, sizes)
+    ) * lax.ragged_dot(rows, wu, sizes)
     return lax.ragged_dot(hidden, wd, sizes)
 
 
-def _shared_expert(xf, shared):
-    """The shared expert on every token, by its leaves as
-    `_expert_groups`."""
-    if len(shared) == 2:
+def _shared_expert(xf, shared, kind: Optional[str] = None):
+    """The shared expert of `kind` on every token."""
+    kind = _kind_of(shared, kind)
+    if kind == "relu2":
         wu, wd = shared
         return _relu2(xf @ wu) @ wd
-    return swiglu(xf, *shared)
+    wg, wu, wd = shared
+    return (_GATE_ACTIVATIONS[kind](xf @ wg) * (xf @ wu)) @ wd
 
 
-def _on_rung(rung: int, xf, weight, experts, order, sizes):
+def _on_rung(rung: int, kind, xf, weight, experts, order, sizes):
     """The held experts' part of the layer on a buffer of `rung` rows,
     which must hold sum(sizes): xf [T, d], weight [T, k] f32 (0 for an
     assignment not held), order [T k] -> [T, d]."""
@@ -455,12 +482,12 @@ def _on_rung(rung: int, xf, weight, experts, order, sizes):
         rows = jnp.where(used[:, None], _to_experts(xf, tok), 0)
         gate = jnp.where(used, weight.reshape(-1)[taken], 0.0)
     with jax.named_scope("experts"):
-        out = _expert_groups(rows, experts, sizes)
+        out = _expert_groups(rows, experts, sizes, kind)
     with jax.named_scope("route"):
         return _to_tokens(jnp.where(used[:, None], out, 0), gate, tok, t)
 
 
-def _on_full_buffer(xf, weight, experts, order, sizes):
+def _on_full_buffer(kind, xf, weight, experts, order, sizes):
     """The top rung: T x min(k, n) rows, every move a gather of that
     many rows or of T k, whatever came."""
     t, k = weight.shape
@@ -470,18 +497,18 @@ def _on_full_buffer(xf, weight, experts, order, sizes):
         used = (jnp.arange(order.shape[0]) < jnp.sum(sizes))[:, None]
         rows = jnp.where(used, _dispatch(xf, order, pos), 0)
     with jax.named_scope("experts"):
-        out = _expert_groups(rows, experts, sizes)
+        out = _expert_groups(rows, experts, sizes, kind)
     with jax.named_scope("route"):
         return _collect(jnp.where(used, out, 0), weight, order, pos)
 
 
-def _branches(weight, sizes):
+def _branches(weight, sizes, kind):
     """(index of the rung to take, one function a rung)."""
     t, k = weight.shape
     rungs = route_rungs(t, k, sizes.shape[0])
     return _rung_taken(rungs, sizes), [
-        functools.partial(_on_rung, rung) for rung in rungs[:-1]
-    ] + [_on_full_buffer]
+        functools.partial(_on_rung, rung, kind) for rung in rungs[:-1]
+    ] + [functools.partial(_on_full_buffer, kind)]
 
 
 # One custom_vjp round the switch, each rule choosing the rung anew: a
@@ -494,22 +521,23 @@ def _branches(weight, sizes):
 # result nothing reads there, is dropped).
 
 
-@jax.custom_vjp
-def _held_experts(xf, weight, experts, order, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_experts(xf, weight, experts, order, sizes, kind=None):
     """sum_{e held} weight_e E_e(x) on the smallest rung that holds the
-    rows that came; the chip runs that branch alone."""
-    taken, branches = _branches(weight, sizes)
+    rows that came; the chip runs that branch alone. `kind` is the
+    experts' (`_expert_groups`)."""
+    taken, branches = _branches(weight, sizes, kind)
     return lax.switch(taken, branches, xf, weight, experts, order, sizes)
 
 
-def _held_experts_fwd(xf, weight, experts, order, sizes):
+def _held_experts_fwd(xf, weight, experts, order, sizes, kind):
     saved = (xf, weight, experts, order, sizes)
-    return _held_experts(*saved), saved
+    return _held_experts(*saved, kind), saved
 
 
-def _held_experts_bwd(saved, g):
+def _held_experts_bwd(kind, saved, g):
     xf, weight, experts, order, sizes = saved
-    taken, branches = _branches(weight, sizes)
+    taken, branches = _branches(weight, sizes, kind)
 
     def backward(branch):
         def run(xf, weight, experts, g):
@@ -552,10 +580,18 @@ def moe_topk_held(
     renormalize: bool = False,
     balance: bool = True,
     shared_gate=None,
+    kind: Optional[str] = None,
+    logits=None,
 ):
     """A top-k dropless expert layer that is told which experts it
     holds: y = sum_{e in top_k ∩ held} p_e E_e(x) + S(x) (no S(x)
-    and no `shared` scope when `shared` is None). With `shared_gate`
+    and no `shared` scope when `shared` is None). The experts, and the
+    shared one, are of `kind` (`EXPERT_KINDS`; None: "relu2" for two
+    leaves, "swiglu" for three). With `logits` [T, E] float32 the
+    router's product was formed ahead of the layer, from another tensor
+    than the x the experts read (`router_logits`; a router that reads
+    its layer's input in front of the mixer): `router_w` is then not
+    read, and everything behind the product is the layer's own. With `shared_gate`
     [1, d] the shared expert stands behind a gate of its own, one
     number a token: + sigmoid(x . shared_gate) S(x), the product
     accumulated and the sigmoid taken in float32 (scope `shared/gate`,
@@ -570,8 +606,8 @@ def moe_topk_held(
     x [B, S, d]; router_w [d, E] over ALL E experts; `experts` the
     stacked weights of the n experts `held = (first, n)` names, experts
     first .. first + n - 1 of E: three leaves (wg [n, d, f], wu
-    [n, d, f], wd [n, f, d]) are SwiGLUs, two (wu, wd) squared-ReLU
-    experts (`_expert_groups`); `shared` one expert of the same kind
+    [n, d, f], wd [n, f, d]) are gated experts, two (wu, wd)
+    squared-ReLU experts (`_expert_groups`); `shared` one expert of the same kind
     (the shared experts side by side), None for a layer without one.
     -> (y [B, S, d], the sequence-wise balance term (unweighted, f32),
     stats of the
@@ -600,13 +636,16 @@ def moe_topk_held(
     first, n = held
     xf = x.reshape(t, d)
     with jax.named_scope("route"):
+        # handed on only where given: the benchmark's comparisons swap
+        # both routes for their own, which take no logits
+        formed = () if logits is None else (logits,)
         if score == "softmax":
-            probs, gate, chosen = route_topk(xf, router_w, top_k)
+            probs, gate, chosen = route_topk(xf, router_w, top_k, *formed)
             if renormalize:  # over all k chosen, held here or not
                 gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
         else:
             probs, gate, chosen = route_sigmoid_topk(
-                xf, router_w, bias, top_k, renormalize
+                xf, router_w, bias, top_k, renormalize, *formed
             )
         local = chosen - first
         here = (local >= 0) & (local < n)
@@ -624,7 +663,7 @@ def moe_topk_held(
         # slice that cuts the layer's leaves from their stack, and the
         # backward rule's branch finds the leaves padded already
         experts = _at_run_width(tuple(experts))
-    routed = _held_experts(xf, weight, experts, order, sizes)
+    routed = _held_experts(xf, weight, experts, order, sizes, kind)
     with jax.named_scope("route"):
         balance_term = sequence_balance_loss(
             probs.reshape(b, s, -1), chosen.reshape(b, s, top_k)
@@ -647,7 +686,7 @@ def moe_topk_held(
         y = routed
     else:
         with jax.named_scope("shared"):
-            out = _shared_expert(xf, shared)
+            out = _shared_expert(xf, shared, kind)
             if shared_gate is not None:
                 with jax.named_scope("gate"):
                     gate = _shared_gate(xf, shared_gate)  # [T, 1] f32

@@ -24,6 +24,10 @@ nothing to a score, dq and dk are cut back by the pad's own gradient).
 window of 512 and in full: XLA's materialised path | the kernels at the
 ladder's tiles.
 
+`--cases band4k` (PR 62): 28 heads of 128 at 16,384 tokens under the
+window of 4096 and in full (SmallThinker's sliding and full layers, the
+key-value heads widened already), over the tile ladder.
+
     chiprun -- python scripts/swa_kernel_sweep.py --cases latent
 
 Writes chiprun_out/swa_kernel_sweep.<cases>.json. `--compile_only`
@@ -58,6 +62,8 @@ V_WIDTH = 128
 LATENT_SCALE = 192**-0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2
 WIDE = (1, 8192, 16, 256)
 DIFF = (1, 4096, 40, 64)  # both members of 20 pairs of heads
+BAND4K = (1, 16384, 28, 128)
+WINDOW4K = 4096
 # (shape, window, tiles, v_width, scale, path)
 CASES = {
     "wide": [
@@ -77,6 +83,15 @@ CASES = {
     ] + [
         (CAUSAL, None, (e, e), None, None, "kernels") for e in (512, 1024)
     ] + [(BANDED, None, (1024, 1024), None, None, "kernels")],
+    "band4k": [
+        (BAND4K, WINDOW4K, (bq, bk), None, None, "kernels")
+        for bq, bk in (
+            (1024, 1024), (512, 512), (2048, 1024), (1024, 512),
+            (512, 1024), (2048, 2048), (256, 256),
+        )
+    ] + [
+        (BAND4K, None, (e, e), None, None, "kernels") for e in (1024, 512)
+    ],
     "diff": [
         (DIFF, window, tiles, V_WIDTH, None, path)
         for window in (WINDOW, None)
@@ -175,6 +190,9 @@ def main():
             for shape in LATENT
         ],
         "wide": [((1, 2048, 16, 256), {}), (WIDE, {})],
+        "band4k": [
+            ((1, 8192, 7, 128), {"window": w}) for w in (WINDOW4K, None)
+        ],
         "diff": [
             ((1, 2048, 8, 64), {"v_width": V_WIDTH, "window": w})
             for w in (WINDOW, None)

@@ -61,6 +61,8 @@ def no_compile_cache():
     # and keys of 64 folded, the pairs' values of 128 in place
     (1, 4096, 40, 64, 512, 128),  # phi-4-mini-flash-reasoning's windowed layer
     (1, 4096, 40, 64, None, 128),  # its full and cross layers
+    (1, 16384, 28, 128, 4096),  # smallthinker-21b-a3b's sliding layers
+    (1, 16384, 28, 128),  # its full layer: the longest sequence a cell runs
 ], ids=lambda s: "x".join(map(str, s[:4])) + "".join(
     f"-{n}{x}" for n, x in zip("wv", s[4:]) if x
 ))

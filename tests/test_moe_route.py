@@ -461,3 +461,200 @@ def test_the_transformer_reduces_the_two_counters_over_its_expert_layers():
     assert stats["route_rows"].shape == stats["route_full"].shape == ()
     assert float(stats["route_rows"]) == pytest.approx(np.mean(taken))
     assert float(stats["route_full"]) == sum(r == rungs[-1] for r in taken)
+
+
+# ---------------------------------------------------------------------------
+# The experts' kind from the caller, and a router that reads another tensor
+
+
+def reglu_reference(w, case, route_from=None):
+    """`dense_reference` for gated-ReLU experts: every held expert
+    wd(relu(wg x) * wu x) on every token, times its weight or zero; the
+    router on `route_from`'s rows where that is given, on x otherwise."""
+    x = w["x"].reshape(-1, D)
+    scored = x if route_from is None else route_from.reshape(-1, D)
+    _probs, gate, chosen = moe.route_topk(scored, w["router"], case["top_k"])
+    if case["renormalize"]:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    first, n = case["held"]
+    y = 0.0
+    for j in range(n):
+        weight = jnp.sum(
+            jnp.where(chosen == first + j, gate * case["scaling"], 0.0), axis=-1
+        )
+        wg, wu, wd = (e[j] for e in w["experts"])
+        y = y + weight[:, None] * ((jax.nn.relu(x @ wg) * (x @ wu)) @ wd)
+    return y.reshape(w["x"].shape)
+
+
+REGLU = dict(
+    top_k=3, held=(4, 4), score="softmax", shared=False, bias=False,
+    renormalize=True, scaling=1.0,
+)
+
+
+@pytest.mark.parametrize("rung", [0, 3])
+@pytest.mark.parametrize("padded", [False, True])
+def test_reglu_experts_are_the_dense_gated_relu_layer_padded_or_not(
+    padded, rung, monkeypatch
+):
+    """`kind="reglu"` through `_at_run_width`: 12 columns run as 16
+    (relu(0) x 0 = 0 meets a zero row) or as they are, on the lowest
+    rung and on the full buffer: the output and the gradient by x, the
+    router and every expert leaf are the dense gated-ReLU layer's, and
+    the padded layer is the unpadded one."""
+    if padded:
+        monkeypatch.setattr(moe, "WIDTH_TILE", 8)
+    rungs = moe.route_rungs(TOKENS, REGLU["top_k"], REGLU["held"][1])
+    w, _came = lean(
+        weights(REGLU), REGLU, rungs[rung - 1] if rung else 0, rungs[rung]
+    )
+
+    def reglu(w, case):
+        return moe.moe_topk_held(
+            w["x"], w["router"], w["experts"], None, kind="reglu",
+            **{**settings(case), "balance": False},
+        )
+
+    with moe.widths_traced() as widths:
+        (y, term, stats), grads = loss_and_grads(w, REGLU, reglu)
+    assert widths == ({(F, 16)} if padded else set())
+    assert float(stats["route_rows"]) == rungs[rung] and float(term) == 0.0
+    (want, _t, _s), want_grads = loss_and_grads(
+        w, REGLU, lambda w, case: (reglu_reference(w, case), 0.0, {})
+    )
+    assert close(y, want)
+    # SiLU in the gate is another layer
+    (silu, _t, _s), _g = loss_and_grads(
+        w, REGLU, lambda w, case: moe.moe_topk_held(
+            w["x"], w["router"], w["experts"], None,
+            **{**settings(case), "balance": False},
+        )
+    )
+    assert not close(silu, want, 1e-2)
+    flat, flat_want = (
+        jax.tree_util.tree_leaves_with_path(g) for g in (grads, want_grads)
+    )
+    assert len(flat) == 5  # x, the router, wg, wu, wd
+    for (path, g), (_p, g_want) in zip(flat, flat_want):
+        where = jax.tree_util.keystr(path)
+        assert g.shape == g_want.shape, where
+        assert float(jnp.max(jnp.abs(g_want))) > 0, where
+        assert close(g, g_want, 5e-5), where
+
+
+@pytest.mark.parametrize("kind, leaves", [
+    ("swiglu", 2), ("reglu", 2), ("relu2", 3), ("gelu", 3),
+])
+def test_a_kind_that_its_leaves_do_not_make_is_refused(kind, leaves):
+    w = weights(REGLU)
+    with pytest.raises(ValueError, match="leaves"):
+        moe.moe_topk_held(
+            w["x"], w["router"], w["experts"][:leaves], None, kind=kind,
+            **settings(REGLU),
+        )
+
+
+def test_a_shared_expert_of_kind_reglu_is_the_gated_relu_mlp():
+    case = {**REGLU, "shared": True}
+    w = weights(case)
+    y, _term, _stats = moe.moe_topk_held(
+        w["x"], w["router"], w["experts"], w["shared"], kind="reglu",
+        **{**settings(case), "balance": False},
+    )
+    wg, wu, wd = w["shared"]
+    x = w["x"]
+    want = reglu_reference(w, case) + (jax.nn.relu(x @ wg) * (x @ wu)) @ wd
+    assert close(y, want)
+
+
+def test_logits_formed_elsewhere_route_the_layer_and_carry_its_gradient():
+    """`logits` from another tensor than x: the experts read x, the
+    router what the logits were formed from; the layer is the dense
+    one so told, the router's gradient comes through the logits, and
+    `router_w` is not read (None will do). Logits formed from x itself
+    are the layer as it was, bit for bit."""
+    w = weights(REGLU)
+    other = jnp.roll(w["x"], 7, axis=1) * 1.3
+    kwargs = {**settings(REGLU), "balance": False, "kind": "reglu"}
+
+    def early(x, router, experts):
+        logits = moe.router_logits(other.reshape(-1, D), router)
+        return moe.moe_topk_held(x, None, experts, None, logits=logits, **kwargs)
+
+    def loss(fn):
+        return lambda x, router, experts: jnp.sum(
+            fn(x, router, experts)[0] * jnp.cos(jnp.arange(x.size).reshape(x.shape))
+        )
+
+    got = early(w["x"], w["router"], w["experts"])
+    want = reglu_reference(w, REGLU, route_from=other)
+    assert close(got[0], want)
+    assert not close(got[0], reglu_reference(w, REGLU), 1e-2)
+    grads = jax.grad(loss(early), argnums=(0, 1, 2))(
+        w["x"], w["router"], w["experts"]
+    )
+    want_grads = jax.grad(loss(
+        lambda x, router, experts: (reglu_reference(
+            {"x": x, "router": router, "experts": experts}, REGLU, other
+        ),)
+    ), argnums=(0, 1, 2))(w["x"], w["router"], w["experts"])
+    for g, g_want in zip(*(jax.tree_util.tree_leaves(t) for t in (grads, want_grads))):
+        assert float(jnp.max(jnp.abs(g_want))) > 0
+        assert close(g, g_want, 5e-5)
+    # the same tensor: the layer as it was
+    same = moe.moe_topk_held(
+        w["x"], None, w["experts"], None,
+        logits=moe.router_logits(w["x"].reshape(-1, D), w["router"]), **kwargs,
+    )
+    as_it_was = moe.moe_topk_held(w["x"], w["router"], w["experts"], None, **kwargs)
+    assert np.array_equal(np.asarray(same[0]), np.asarray(as_it_was[0]))
+    for name in as_it_was[2]:
+        assert np.array_equal(
+            np.asarray(same[2][name]), np.asarray(as_it_was[2][name])
+        ), name
+
+
+def test_sigmoid_scores_take_logits_formed_elsewhere_too():
+    case = CASES["sigmoid-top3-bias"]
+    w = weights(case)
+    logits = moe.router_logits(w["x"].reshape(-1, D), w["router"])
+    given = moe.moe_topk_held(
+        w["x"], None, w["experts"], w["shared"], bias=w["bias"], logits=logits,
+        **settings(case),
+    )
+    own = layer(w, case)
+    assert np.array_equal(np.asarray(given[0]), np.asarray(own[0]))
+
+
+# The tiny programs of the two configurations `tests/test_mamba2_lm.py`
+# does not pin, traced on the parent of this change (commit 259850a):
+# the same digests here, so `moe_topk_held`'s `kind` and `logits`,
+# `AttentionShape.turns` and the `router` scope changed nothing they run
+# (the five others: `test_mamba2_lm.py`'s `TRACED`, which still holds).
+TRACED = {
+    "mamba2_lm_tiny": "2e9631248a6c7813",
+    "sambay_lm_tiny": "45f4e46ce1ee645c",
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(TRACED))
+def test_the_other_configurations_tiny_programs_trace_as_they_did(fixture):
+    import hashlib
+    import importlib
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "fixtures"))
+    module = importlib.import_module(fixture)
+    model = module.custom_model()
+    variables = model.init(jax.random.PRNGKey(0), None)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+
+    def loss(p):
+        out, _ = model.apply({**variables, "params": p}, tokens, mutable=True)
+        return module.loss(out, tokens)
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss))(variables["params"]))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == TRACED[fixture]
