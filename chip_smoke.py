@@ -392,8 +392,13 @@ def child_kernels(cpu):
     # latent attention as the routed cell calls it: values of 128 under
     # keys of 192 and deepseek-v2-lite's `mla_softmax_scale`
     latent = {"v_width": 128, "scale": 0.11472138679292611}
+    # fewer key-value heads than query heads, read where they lie: a
+    # group of 8 in place under a band, a group of 4 folded
     cases = [(shape, {}) for shape in shapes] + [
-        ((1, 256, 2, 192) if cpu else (4, 2048, 16, 192), latent)
+        ((1, 256, 2, 192) if cpu else (4, 2048, 16, 192), latent),
+        ((1, 256, 4, 128) if cpu else (1, 2048, 16, 128),
+         {"kv_heads": 2, "window": 128 if cpu else 512}),
+        ((1, 256, 4, 64) if cpu else (2, 2048, 8, 64), {"kv_heads": 2}),
     ]
     errors = {}
     for shape, how in cases:

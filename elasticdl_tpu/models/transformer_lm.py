@@ -1374,7 +1374,8 @@ def _attend(cfg: TransformerConfig, lp: Dict, x: jnp.ndarray, positions,
     """Multi-head attention on the normed x [B, L, d] -> ([B, L, d],
     its stats): the queries of `mixer`'s kind of layer
     (`cfg.attention_shape`: head count, window, rotation) over
-    `kv_heads` keys and values (the dispatcher widens them), under
+    `kv_heads` keys and values (the kernels read a key-value head where
+    it lies; XLA's path has them widened in front of it), under
     `qk_norm` queries and keys normed per head, then rotated; under
     `attn_gate` each head's output times sigmoid(x . wgate_h), the
     sigmoid in float32 (stat `attn_gate_mean`, its mean); under

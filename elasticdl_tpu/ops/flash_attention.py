@@ -41,15 +41,21 @@ tests/test_flash_attention.py in Pallas interpret mode on CPU, and
 compiled on the chip by chip_smoke.py and under EDL_TPU_TESTS=1
 (`check_against_reference`).
 
-Layout contract: q and k [B, L, H, D], v [B, L, H, Dv] ("blhd",
-matching transformer_lm), any float dtype; scores, softmax and
-accumulators are f32. L must divide
+Layout contract: q [B, L, H, D], k [B, L, Hkv, D], v [B, L, Hkv, Dv]
+("blhd", matching transformer_lm), any float dtype; scores, softmax and
+accumulators are f32. H is a multiple of Hkv (grouped-query attention;
+equal in most callers): query head i reads key-value head i // group
+through the k and v index maps, nothing is widened for the kernels, and
+the dk + dv kernel, whose grid walks the key-value heads, sums a
+group's query heads in its float32 accumulators. L must divide
 by the 128 block; callers with ragged L use the jnp fallback
 (`reference_attention`).
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 
@@ -248,7 +254,12 @@ class _Layout:
     must be a multiple of 128 or the whole of it), so it is folded to
     [B*H, L, D] through memory. The other way with 192, zeros padded to
     256 and read in place, measured the same (FLASH_MIN_LENGTH's
-    table) and is not built."""
+    table) and is not built.
+
+    H is the array's own head count: k and v with fewer heads than q
+    (grouped-query attention) are laid out, and folded, at theirs, and
+    a grid that walks the query heads reads head i // group of them
+    through `spec`'s `head`. Nothing is widened for the kernels."""
 
     def __init__(self, b, L, h, d):
         self.b, self.L, self.h, self.d = b, L, h, d
@@ -266,14 +277,22 @@ class _Layout:
             return x.reshape(b, L, h, d)
         return x.reshape(b, h, L, d).transpose(0, 2, 1, 3)
 
-    def spec(self, rows: int, pick):
+    def spec(self, rows: int, pick, head=None):
         """Tiles of `rows` rows; `pick(j, t)` is the tile's index along
-        L at grid step (head, j, t)."""
+        L at grid step (i, j, t). The step reads head i of this array's
+        B*H, or `head(i, t)` where the grid's first axis counts another
+        array's heads (a key-value head under a grid over the query
+        heads, a group's query heads under a grid over the key-value
+        heads)."""
         h = self.h
+        if head is None:  # the equal-heads maps, to the instruction
+            head = lambda i, t: i  # noqa: E731
         if self.in_place:
-            index = lambda i, j, t: (i // h, pick(j, t), i % h)  # noqa: E731
+            index = lambda i, j, t: (  # noqa: E731
+                head(i, t) // h, pick(j, t), head(i, t) % h
+            )
         else:
-            index = lambda i, j, t: (i, pick(j, t), 0)  # noqa: E731
+            index = lambda i, j, t: (head(i, t), pick(j, t), 0)  # noqa: E731
         return pl.BlockSpec((1, rows, self.d), index)
 
 
@@ -350,19 +369,36 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[...] + (y - 1.0 + l * jnp.exp(-y))
 
 
-def _layouts(q, v):
-    """(queries' and keys' layout, values' layout), each by its own
-    width; the output and its cotangent lie as the values do."""
+def _layouts(q, k, v):
+    """(queries', keys', values', output's layout, group): each array
+    by its own head count and width; the output and its cotangent have
+    the queries' heads at the values' width. `group` query heads read
+    one key-value head."""
     b, L, h, d = q.shape
-    return _Layout(b, L, h, d), _Layout(b, L, h, v.shape[-1])
+    h_kv, dv = k.shape[2], v.shape[-1]
+    return (
+        _Layout(b, L, h, d), _Layout(b, L, h_kv, d), _Layout(b, L, h_kv, dv),
+        _Layout(b, L, h, dv), h // h_kv,
+    )
+
+
+def _kv_head(group: int):
+    """`_Layout.spec`'s `head` for k and v under a grid over B*H query
+    heads: with H = group * H_kv, query head i of the B*H reads head
+    i // group of the B*H_kv. None with equal heads, whose index maps
+    hold no trace of a group."""
+    return None if group == 1 else (lambda i, t: i // group)
 
 
 def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band,
                    scale: float):
-    """Returns (o [B,L,H,Dv], lse [B*H, L, 1])."""
+    """Returns (o [B,L,H,Dv], lse [B*H, L, 1]). k and v may have fewer
+    heads than q: the grid walks the query heads and each reads its
+    key-value head where it lies."""
     b, L, h, d = q.shape
     bq, bk = tiles
-    lay, vlay = _layouts(q, v)
+    lay, klay, vlay, olay, group = _layouts(q, k, v)
+    kv_head = _kv_head(group)
     own, seen_k, _ = band.picks() if band else _tile_picks(bq, bk, causal)
     # rows ([B*H, L, 1]) carry a trailing singleton so Mosaic's tiling
     # rule holds: block (1, BQ, 1) -> last two dims (BQ, 1) are
@@ -374,22 +410,23 @@ def _flash_forward(q, k, v, causal: bool, interpret: bool, tiles, band,
             scale=scale, band=band,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(vlay.shape, q.dtype),
+            jax.ShapeDtypeStruct(olay.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32),
         ],
         grid=(b * h, L // bq, band.k_steps if band else L // bk),
         in_specs=[
-            lay.spec(bq, own), lay.spec(bk, seen_k), vlay.spec(bk, seen_k)
+            lay.spec(bq, own), klay.spec(bk, seen_k, kv_head),
+            vlay.spec(bk, seen_k, kv_head),
         ],
-        out_specs=[vlay.spec(bq, own), lse_spec],
+        out_specs=[olay.spec(bq, own), lse_spec],
         scratch_shapes=[
-            pltpu.VMEM((bq, vlay.d), jnp.float32),
+            pltpu.VMEM((bq, olay.d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         **_params(interpret),
-    )(lay.view(q), lay.view(k), vlay.view(v))
-    return vlay.unview(out), lse
+    )(lay.view(q), klay.view(k), vlay.view(v))
+    return olay.unview(out), lse
 
 
 # ---------------------------------------------------------------- backward
@@ -428,19 +465,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, dk_acc, dv_acc, *, bq: int, bk: int, causal: bool,
-                scale: float, band=None):
-    """Streaming dk/dv: grid (head, k tile, q tile), q innermost. The
-    owned k/v tiles stay resident (their index is constant over qi);
-    q/do/lse/delta tiles stream past; dk/dv accumulate in VMEM scratch.
-    No atomics: this kernel owns its k tile's outputs. The scores are
-    formed transposed, keys down and queries across ([BK, BQ]), so
-    p^T do and ds^T q are plain products and lse/delta come as rows
-    ([1, BQ], one dense line each) that broadcast down the tile."""
-    kj, step = pl.program_id(1), pl.program_id(2)
+                scale: float, band=None, q_steps=None):
+    """Streaming dk/dv: grid (key-value head, k tile, q tile), q
+    innermost. The owned k/v tiles stay resident (their index is
+    constant over the inner axis); q/do/lse/delta tiles stream past;
+    dk/dv accumulate in VMEM scratch. No atomics: this kernel owns its
+    k tile's outputs. The scores are formed transposed, keys down and
+    queries across ([BK, BQ]), so p^T do and ds^T q are plain products
+    and lse/delta come as rows ([1, BQ], one dense line each) that
+    broadcast down the tile. Under a group (`q_steps`: the q steps of
+    one member; None with equal heads) the inner axis is the group's
+    query heads one after another, step t member t // q_steps at q step
+    t % q_steps, and the group's sum is formed here, in float32, and
+    rounded once."""
+    kj, t = pl.program_id(1), pl.program_id(2)
+    step = t if q_steps is None else t % q_steps
     qi = step if band is None else band.q_tile(kj, step)
     window = band and band.window
 
-    @pl.when(step == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -461,7 +504,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         qi, kj, bq, bk, causal, body, window, band and band.nq
     )
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(t == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -471,8 +514,9 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
                     tiles, band, scale: float):
     b, L, h, d = q.shape
     bq, bk = tiles
-    lay, vlay = _layouts(q, v)
-    qf, kf, vf, gf = lay.view(q), lay.view(k), vlay.view(v), vlay.view(g)
+    lay, klay, vlay, olay, group = _layouts(q, k, v)
+    kv_head = _kv_head(group)
+    qf, kf, vf, gf = lay.view(q), klay.view(k), vlay.view(v), olay.view(g)
     # delta_i = rowsum(do_i * o_i): tiny elementwise+reduce, XLA fuses
     delta = jnp.sum(
         g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
@@ -488,35 +532,48 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, interpret: bool,
         out_shape=jax.ShapeDtypeStruct(lay.shape, q.dtype),
         grid=(b * h, L // bq, band.k_steps if band else L // bk),
         in_specs=[
-            lay.spec(bq, own), lay.spec(bk, seen_k), vlay.spec(bk, seen_k),
-            vlay.spec(bq, own), column, column,
+            lay.spec(bq, own), klay.spec(bk, seen_k, kv_head),
+            vlay.spec(bk, seen_k, kv_head), olay.spec(bq, own), column,
+            column,
         ],
         out_specs=lay.spec(bq, own),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         **_params(interpret),
     )(qf, kf, vf, gf, lse, delta)
-    row = pl.BlockSpec((1, 1, bq), lambda i, j, t: (i, 0, seen_q(j, t)))
+    # dk + dv: the grid walks the key-value heads. With a group its
+    # inner axis runs the group's query heads one after another, each
+    # over the q steps a lone head takes, into one pair of accumulators
+    q_steps = band.q_steps if band else L // bq
+    q_head = lambda i, t: i  # noqa: E731
+    if group > 1:  # equal heads keep their maps to the instruction
+        q_head = lambda i, t: i * group + t // q_steps  # noqa: E731
+        of_member = seen_q
+        seen_q = lambda j, t: of_member(j, t % q_steps)  # noqa: E731
+    row = pl.BlockSpec(
+        (1, 1, bq), lambda i, j, t: (q_head(i, t), 0, seen_q(j, t))
+    )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale, band=band
+            _dkv_kernel, bq=bq, bk=bk, causal=causal, scale=scale, band=band,
+            q_steps=q_steps if group > 1 else None,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(lay.shape, k.dtype),
+            jax.ShapeDtypeStruct(klay.shape, k.dtype),
             jax.ShapeDtypeStruct(vlay.shape, v.dtype),
         ],
-        grid=(b * h, L // bk, band.q_steps if band else L // bq),
+        grid=(b * klay.h, L // bk, group * q_steps),
         in_specs=[
-            lay.spec(bq, seen_q), lay.spec(bk, own), vlay.spec(bk, own),
-            vlay.spec(bq, seen_q), row, row,
+            lay.spec(bq, seen_q, q_head), klay.spec(bk, own),
+            vlay.spec(bk, own), olay.spec(bq, seen_q, q_head), row, row,
         ],
-        out_specs=[lay.spec(bk, own), vlay.spec(bk, own)],
+        out_specs=[klay.spec(bk, own), vlay.spec(bk, own)],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, vlay.d), jnp.float32),
         ],
         **_params(interpret),
     )(qf, kf, vf, gf, lse.reshape(b * h, 1, L), delta.reshape(b * h, 1, L))
-    return lay.unview(dq), lay.unview(dk), vlay.unview(dv)
+    return lay.unview(dq), klay.unview(dk), vlay.unview(dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -547,11 +604,44 @@ def _fa_bwd(causal, interpret, tiles, band, scale, residuals, g):
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
+def _group(q, k, v) -> int:
+    """The query heads that read one key-value head; heads that are not
+    whole groups are refused."""
+    group, rest = divmod(q.shape[2], k.shape[2])
+    if rest or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads over {k.shape[2]} key and "
+            f"{v.shape[2]} value heads: not whole groups"
+        )
+    return group
+
+
+_GROUPS_TRACED = contextvars.ContextVar("groups_traced", default=None)
+
+
+@contextlib.contextmanager
+def groups_traced():
+    """-> a set that gains (query heads, key-value heads) for every
+    call with fewer key-value heads that this thread hands to the
+    kernels inside the block: k and v read where they lie."""
+    seen = set()
+    token = _GROUPS_TRACED.set(seen)
+    try:
+        yield seen
+    finally:
+        _GROUPS_TRACED.reset(token)
+
+
 def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
                     tiles=None, window=None, scale=None):
     """Differentiable fused attention, [B, L, H, D] -> [B, L, H, Dv]:
     `v` may be of another width than `q` and `k` (latent attention),
-    each laid out by its own width (`_Layout`).
+    each laid out by its own width (`_Layout`). `k` and `v` may have
+    fewer heads than `q`, the query heads a multiple (grouped-query
+    attention): query head i reads key-value head i // group through
+    the kernels' index maps, out of k and v as they came, and dk and dv
+    come back at their heads, a group's sum formed in the dk + dv
+    kernel's float32 accumulators.
     `interpret=True` runs the kernel in the Pallas interpreter and is
     for tests only (no model path passes it); compiled, the kernels
     are Mosaic programs and exist on the TPU alone. `tiles` = (q edge,
@@ -575,6 +665,9 @@ def flash_attention(q, k, v, causal: bool = True, interpret: bool = False,
         )
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else float(scale)
     L = q.shape[1]
+    seen = _GROUPS_TRACED.get()
+    if _group(q, k, v) > 1 and seen is not None:
+        seen.add((q.shape[2], k.shape[2]))
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"window={window} needs causal=True and a key to see")
     if window is not None and window >= L:
@@ -595,7 +688,8 @@ REFERENCE_TOLERANCE = 2.0**-6
 
 
 def check_against_reference(shape, interpret: bool = False, seed: int = 0,
-                            window=None, v_width=None, scale=None):
+                            window=None, v_width=None, scale=None,
+                            kv_heads=None):
     """Forward and all three backward kernels at one [B, L, H, D] bf16
     shape against `reference_attention` in true f32 — chip_smoke.py's
     kernel phase and the gated chip tests. Returns, for o/dq/dk/dv,
@@ -604,14 +698,19 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
     The reference runs one head at a time: its [L, L] scores and their
     backward copies would not fit beside each other at L=8192.
     `v_width`: values (and the cotangent) of another width than D;
-    `scale`: in place of 1/sqrt(D), for both sides."""
+    `scale`: in place of 1/sqrt(D), for both sides; `kv_heads`: k and v
+    with that many heads under the H query heads, read in place by the
+    kernels; the reference's dk and dv are the float32 sums over a
+    group's query heads."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
-    v_shape = (*shape[:3], v_width or shape[3])
+    b, L, h, d = shape
+    h_kv, dv = kv_heads or h, v_width or d
+    group = h // h_kv
     q, k, v, w = (
         jnp.asarray(rng.standard_normal(s), dtype=jnp.bfloat16)
-        for s in (shape, shape, v_shape, v_shape)
+        for s in (shape, (b, L, h_kv, d), (b, L, h_kv, dv), (b, L, h, dv))
     )
 
     def through(attn):
@@ -631,9 +730,11 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
     )
     refs = []
     with jax.default_matmul_precision("highest"):
-        for h in range(shape[2]):
-            qh, kh, vh, wh = (
-                x[:, :, h : h + 1].astype(jnp.float32) for x in (q, k, v, w)
+        for at in range(h):
+            qh, wh = (x[:, :, at : at + 1].astype(jnp.float32) for x in (q, w))
+            kh, vh = (
+                x[:, :, at // group : at // group + 1].astype(jnp.float32)
+                for x in (k, v)
             )
             (_, oh), gh = ref_fn(qh, kh, vh, wh)
             refs.append((oh, *gh))
@@ -644,6 +745,8 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
         (jnp.concatenate(parts, axis=2) for parts in zip(*refs)),
     ):
         got = got.astype(jnp.float32)
+        if ref.shape != got.shape:  # dk, dv: the float32 sum over a group
+            ref = ref.reshape(b, L, h_kv, group, -1).sum(axis=3)
         errors[name] = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
     return errors
 
@@ -703,13 +806,40 @@ def check_against_reference(shape, interpret: bool = False, seed: int = 0,
 # 8, 64 | 128), band and full: o 0.24 %, dq 0.53 %, dk 0.37-0.38 %, dv
 # 0.25-0.28 % of the largest entry.
 # 16,384 tokens, 28 query heads of 128 on 4 key-value heads widened in
-# front of the call, a group of 7 (2026-10-04, the same chip kind and
-# versions, `scripts/swa_kernel_sweep.py --cases band4k`; `pick_tiles`
+# front of the call (as they were until PR 63), a group of 7
+# (2026-10-04, the same chip kind and versions,
+# `scripts/swa_kernel_sweep.py --cases band4k`; `pick_tiles`
 # has the table): 34.0 ms under a window of 4096 and 62.7 ms in full at
 # 1024 x 1024, forward + backward; XLA's path would write 30 GB of
 # scores and is not run. Against the float32 reference at (1, 8192, 7,
 # 128), band and full: o 0.27 %, dq 0.40 %, dk 0.36-0.44 %, dv
 # 0.42-0.48 % of the largest entry.
+# Fewer key-value heads than query heads (2026-10-05, the same chip kind
+# and versions, `scripts/swa_kernel_sweep.py --cases gqa`), at the
+# ladder's tiles, ms a call, forward + backward (forward alone): k and v
+# widened to the query heads in front of the kernels and dk, dv summed
+# over a group behind them | read where they lie through the index maps,
+# the group summed in the dk + dv kernel:
+#   (1, 8192, 64 on 8, 128), window 512    17.30 (6.17) | 14.84 (5.01)
+#   (1, 8192, 48 on 8, 128)                31.23 (8.93) | 30.07 (8.18)
+#   (1, 16384, 28 on 4, 128), window 4096  33.51 (9.41) | 32.74 (8.97)
+#   (1, 16384, 28 on 4, 128)               62.25 (17.54) | 61.25 (17.05)
+#   (1, 8192, 16 on 2, 256)                19.23 (4.96) | 18.37 (4.74)
+#   (4, 2048, 32 on 8, 64)                  8.24 (2.20) |  7.79 (2.04)
+#   (1, 4096, 32 on 2, 128)                 6.45 (1.72) |  6.00 (1.59)
+#   (1, 4096, 40 on 20, 64 | 128), w. 512   5.81 (1.97) |  4.48 (1.59)
+#   (1, 4096, 40 on 20, 64 | 128)           8.92 (2.49) |  7.55 (2.09)
+# In place is ahead at every shape, by what the copies cost (0.45 to
+# 2.5 ms a call; the kernels' own reads and products are what they
+# were), so the dispatcher hands k and v over as they come at any group
+# and no rule reads one. Against the float32 reference at (1, 2048, 16
+# on 2, 128) under the band, (1, 2048, 14 on 2, 128), (1, 4096, 12 on 2,
+# 128), (1, 2048, 8 on 1, 256), (2, 2048, 8 on 2, 64) and (1, 2048, 8 on
+# 4, 64 | 128): o and dq to the bit of the widened call's (0.19-0.43 %
+# of the largest entry), dk 0.26-0.39 % (widened 0.28-0.62 %) and dv
+# 0.23-0.41 % (widened 0.30-0.42 %): the group's sum rounded once, and
+# nearer the reference at five of the six; at 8 on 1 of 256 the largest
+# entry's error reads a little further (dk 0.36 | 0.34, dv 0.41 | 0.35).
 FLASH_MIN_LENGTH = 2048
 
 
@@ -737,22 +867,17 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
 
     `k` and `v` may have fewer heads than `q` (grouped-query attention,
     the query heads a multiple): query head i reads key-value head
-    i // group. Both paths know equal heads only, so k and v are
-    widened to the query heads here, in front of either, and the
-    gradient sums over a group by itself; a call with equal heads is
-    traced as it was."""
+    i // group. The kernels read that head where it lies, through their
+    index maps, and sum dk and dv over a group on the chip
+    (`flash_attention`): k and v go to them as they came. XLA's path
+    knows equal heads only, so in front of it, and of it alone, k and v
+    are widened to the query heads and the gradient sums over a group by
+    itself; a call with equal heads is traced as it was on either."""
     import os
 
     from elasticdl_tpu.common.constants import ENV_TPU_FLASH
 
-    group, rest = divmod(q.shape[2], k.shape[2])
-    if rest or k.shape[2] != v.shape[2]:
-        raise ValueError(
-            f"{q.shape[2]} query heads over {k.shape[2]} key and "
-            f"{v.shape[2]} value heads: not whole groups"
-        )
-    if group > 1:
-        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    group = _group(q, k, v)
     L = q.shape[1]
     if window is not None and window >= L:
         window = None
@@ -764,4 +889,6 @@ def attention(q, k, v, causal: bool = True, scale=None, window=None):
         and (flag == "1" or L >= FLASH_MIN_LENGTH)
     ):
         return flash_attention(q, k, v, causal, window=window, scale=scale)
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     return reference_attention(q, k, v, causal, scale, window)

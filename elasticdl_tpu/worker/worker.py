@@ -61,6 +61,7 @@ from elasticdl_tpu.common.log_util import get_logger
 from elasticdl_tpu.common.timing import DeviceRuns, PhaseTimers
 from elasticdl_tpu.obs import hlo_scopes
 from elasticdl_tpu.obs import trace as obs_trace
+from elasticdl_tpu.ops import flash_attention
 from elasticdl_tpu.parallel import moe
 from elasticdl_tpu.common.messages import MethodType, Task, TaskType
 from elasticdl_tpu.worker import delta_stream
@@ -1890,13 +1891,18 @@ class Worker:
         else:
             model, state = flat, opt_state
         args = (model, state, aux, features, labels)
-        with first_call(
-            window, args, carry="leaves" if leaves else "flat",
-            carried=len(jax.tree_util.tree_leaves((model, state))),
-        ) as span, moe.widths_traced() as widths:
+        with (
+            first_call(
+                window, args, carry="leaves" if leaves else "flat",
+                carried=len(jax.tree_util.tree_leaves((model, state))),
+            ) as span,
+            moe.widths_traced() as widths,
+            flash_attention.groups_traced() as groups,
+        ):
             model, state, aux, loss = window(*args)
             if span is not None:  # the call that traced the program
                 span["expert_widths"] = sorted(widths)
+                span["kv_groups"] = sorted(groups)
         runs = self._device_runs
         run = runs.asked("jit_window", self._local_updates)
         if not leaves:

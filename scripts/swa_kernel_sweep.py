@@ -28,6 +28,12 @@ ladder's tiles.
 window of 4096 and in full (SmallThinker's sliding and full layers, the
 key-value heads widened already), over the tile ladder.
 
+`--cases gqa` (PR 63): fewer key-value heads than query heads, at the
+six grouped cells' calls: k and v widened to the query heads in front of
+the kernels with the group's sum behind them (`widened`, what the
+dispatcher did until PR 63) | read where they lie through the index
+maps, dk and dv summed over the group in the kernel (`in_place`).
+
     chiprun -- python scripts/swa_kernel_sweep.py --cases latent
 
 Writes chiprun_out/swa_kernel_sweep.<cases>.json. `--compile_only`
@@ -64,8 +70,28 @@ WIDE = (1, 8192, 16, 256)
 DIFF = (1, 4096, 40, 64)  # both members of 20 pairs of heads
 BAND4K = (1, 16384, 28, 128)
 WINDOW4K = 4096
-# (shape, window, tiles, v_width, scale, path)
+# (q's shape, window, v_width, key-value heads): Laguna's sliding and
+# full layers, SmallThinker's, Qwen3-Next's gated attention, LFM2's,
+# Nemotron's, and phi-4's differential call (keys of 64 folded, the
+# pairs' values of 128 in place) under its window and in full
+GQA = (
+    ((1, 8192, 64, 128), WINDOW, None, 8),
+    ((1, 8192, 48, 128), None, None, 8),
+    (BAND4K, WINDOW4K, None, 4),
+    (BAND4K, None, None, 4),
+    (WIDE, None, None, 2),
+    ((4, 2048, 32, 64), None, None, 8),
+    ((1, 4096, 32, 128), None, None, 2),
+    (DIFF, WINDOW, V_WIDTH, 20),
+    (DIFF, None, V_WIDTH, 20),
+)
+# (shape, window, tiles, v_width, scale, path[, key-value heads])
 CASES = {
+    "gqa": [
+        (shape, window, None, v_width, None, path, kv_heads)
+        for shape, window, v_width, kv_heads in GQA
+        for path in ("widened", "in_place")
+    ],
     "wide": [
         (WIDE, None, tiles, None, None, "kernels")
         for tiles in (
@@ -113,6 +139,13 @@ CASES = {
 }
 
 
+def widened(q, k, v):
+    """k and v repeated to q's heads, as the dispatcher did in front of
+    the kernels until PR 63: the transpose sums dk and dv over a group."""
+    group = q.shape[2] // k.shape[2]
+    return (jnp.repeat(x, group, axis=2) for x in (k, v))
+
+
 def attend(window, tiles, scale, path):
     """q, k, v -> o by one of the paths a case names."""
     if path == "xla":
@@ -124,6 +157,8 @@ def attend(window, tiles, scale, path):
         if path == "padded":
             pad = ((0, 0),) * 3 + ((0, -q.shape[-1] % 128),)
             q, k = jnp.pad(q, pad), jnp.pad(k, pad)
+        if path == "widened":
+            k, v = widened(q, k, v)
         return fa.flash_attention(
             q, k, v, tiles=tiles, window=window, scale=scale
         )
@@ -141,6 +176,27 @@ def program(*case):
 
 def forward_program(*case):
     return jax.jit(attend(*case))
+
+
+def operands(shape, v_width=None, kv_heads=None):
+    """The shapes of q, k, v and the cotangent of a case."""
+    b, L, h, d = shape
+    kv = (b, L, kv_heads or h)
+    return shape, (*kv, d), (*kv, v_width or d), (b, L, h, v_width or d)
+
+
+def widened_errors(shape, **how):
+    """`check_against_reference` with k and v widened in front of the
+    kernels: what the in-place call's dk and dv are held beside."""
+    inner = fa.flash_attention
+
+    fa.flash_attention = lambda q, k, v, **kw: inner(
+        q, *widened(q, k, v), **kw
+    )
+    try:
+        return fa.check_against_reference(shape, **how)
+    finally:
+        fa.flash_attention = inner
 
 
 def timed(fn, args, repeats=10):
@@ -168,15 +224,18 @@ def main():
             platform="tpu", topology_name="v5e:2x2"
         )
         chip = SingleDeviceSharding(topo.devices[0])
-        for shape, window, tiles, v_width, scale, path in cases:
-            x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
-            v = jax.ShapeDtypeStruct(
-                (*shape[:3], v_width or shape[3]), jnp.bfloat16, sharding=chip
-            )
+        for shape, window, tiles, v_width, scale, path, *kv_heads in cases:
+            structs = [
+                jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)
+                for s in operands(shape, v_width, *kv_heads)
+            ]
             t0 = time.perf_counter()
-            program(window, tiles, scale, path).lower(x, x, v, v).compile()
-            print(shape, v_width, window, tiles, path, "compiles",
-                  f"{time.perf_counter() - t0:.1f}s", flush=True)
+            compiled = (
+                program(window, tiles, scale, path).lower(*structs).compile()
+            )
+            print(shape, v_width, window, tiles, path, *kv_heads, "compiles",
+                  f"{time.perf_counter() - t0:.1f}s", "temp bytes",
+                  compiled.memory_analysis().temp_size_in_bytes, flush=True)
         return
     rng = np.random.default_rng(0)
     results = {"device": jax.devices()[0].device_kind, "cases": []}
@@ -197,22 +256,35 @@ def main():
             ((1, 2048, 8, 64), {"v_width": V_WIDTH, "window": w})
             for w in (WINDOW, None)
         ],
+        # groups of 8 under the band, 7 and 6 in full, 8 at heads of
+        # 256, 4 folded, and phi-4's 2 at 64 | 128
+        "gqa": [
+            ((1, 2048, 16, 128), {"kv_heads": 2, "window": WINDOW}),
+            ((1, 2048, 14, 128), {"kv_heads": 2}),
+            ((1, 4096, 12, 128), {"kv_heads": 2}),
+            ((1, 2048, 8, 256), {"kv_heads": 1}),
+            ((2, 2048, 8, 64), {"kv_heads": 2}),
+            ((1, 2048, 8, 64),
+             {"kv_heads": 4, "v_width": V_WIDTH, "window": WINDOW}),
+        ],
     }[args.cases]
     for shape, how in checks:
         errors = fa.check_against_reference(shape, **how)
         print("check", shape, how, errors, flush=True)
-        results.setdefault("checks", []).append(
-            {"shape": shape, **how, "errors": errors}
-        )
+        check = {"shape": shape, **how, "errors": errors}
+        if "kv_heads" in how:
+            check["widened_errors"] = widened_errors(shape, **how)
+            print("  widened in front", check["widened_errors"], flush=True)
+        results.setdefault("checks", []).append(check)
     arrays = {}
-    for shape, window, tiles, v_width, scale, path in cases:
-        v_shape = (*shape[:3], v_width or shape[3])
-        if shape not in arrays:
-            arrays[shape] = [
+    for shape, window, tiles, v_width, scale, path, *kv_heads in cases:
+        shapes = operands(shape, v_width, *kv_heads)
+        if shapes not in arrays:
+            arrays[shapes] = [
                 jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
-                for s in (shape, shape, v_shape, v_shape)
+                for s in shapes
             ]
-        q, k, v, w = arrays[shape]
+        q, k, v, w = arrays[shapes]
         case = (window, tiles, scale, path)
         try:
             both = timed(program(*case), (q, k, v, w))
@@ -223,6 +295,8 @@ def main():
         case = {"shape": shape, "v_width": v_width, "window": window,
                 "tiles": tiles, "path": path, "fwd_bwd_ms": both,
                 "fwd_ms": fwd}
+        if kv_heads:
+            case["kv_heads"] = kv_heads[0]
         print(json.dumps(case), flush=True)
         results["cases"].append(case)
     out = os.path.join(

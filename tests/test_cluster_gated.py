@@ -220,6 +220,11 @@ print(json.dumps([
     check_against_reference(
         (4, 2048, 16, 192), v_width=128, scale=0.11472138679292611
     ),
+    # 16 query heads on 2 key-value heads read where they lie, banded;
+    # 8 on 2 folded; differential attention's 64 | 128 under a group
+    check_against_reference((1, 2048, 16, 128), kv_heads=2, window=512),
+    check_against_reference((2, 2048, 8, 64), kv_heads=2),
+    check_against_reference((1, 2048, 8, 64), kv_heads=4, v_width=128),
 ]))
 """ % (REPO,)
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}

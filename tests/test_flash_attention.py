@@ -402,6 +402,17 @@ def _through(attn, w):
     return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
 
 
+def _widened(attend):
+    """`attend` behind k and v repeated to the query heads: what the
+    dispatcher did in front of the kernels until PR 63 and still does in
+    front of XLA's path. Its transpose sums dk and dv over a group."""
+    def run(q, k, v):
+        group = q.shape[2] // k.shape[2]
+        return attend(q, *(jnp.repeat(x, group, axis=2) for x in (k, v)))
+
+    return run
+
+
 @pytest.mark.parametrize("tiles", BAND_TILES, ids=lambda t: f"q{t[0]}k{t[1]}")
 @pytest.mark.parametrize("window", [w for w, _ in WINDOWS],
                          ids=[name for _, name in WINDOWS])
@@ -557,49 +568,60 @@ def test_the_dispatcher_hands_the_window_to_whichever_path_takes_the_call(
 ], ids=["L256-q128k128", "L512-q256k128", "L512-q128k256"])
 def test_heads_of_256_under_a_group_of_eight_match_the_float32_math(L, tiles):
     """Qwen3-Next's gated attention: 8 query heads of 256 over ONE
-    key-value head (the cell's 16 over 2, halved), widened in front of
-    the kernels as the dispatcher widens them; forward, dq and, summed
-    over the group by the widening's own transpose, dk and dv, against
+    key-value head (the cell's 16 over 2, halved), read where it lies
+    by the kernels (what the dispatcher hands them since PR 63) and,
+    beside that, widened in front of them as it was until then;
+    forward, dq and, summed over the group (in the dk + dv kernel's
+    accumulators | by the widening's own transpose), dk and dv, against
     the float32 math and a generic cotangent, tile pair by tile pair. A
     width of 256 is read where it lies (`_Layout`), two passes of the
     128-wide multiplier a contraction."""
     q, w, _ = _qkv(b=1, L=L, h=8, d=256, seed=31)
     k, v, _ = _qkv(b=1, L=L, h=1, d=256, seed=32)
-
-    def widened(attend):
-        def run(q, k, v):
-            k, v = (jnp.repeat(x, 8, axis=2) for x in (k, v))
-            return attend(q, k, v)
-
-        return run
-
-    (_, o), grads = _through(widened(
-        lambda q, k, v: flash_attention(q, k, v, interpret=True, tiles=tiles)
-    ), w)(q, k, v)
-    (_, o_ref), grads_ref = _through(widened(reference_attention), w)(q, k, v)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
-    for name, got, want in zip(("dq", "dk", "dv"), grads, grads_ref):
-        assert got.shape == want.shape, name
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), atol=1e-4, err_msg=name
-        )
+    kernels = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, interpret=True, tiles=tiles
+    )
+    (_, o_ref), grads_ref = _through(_widened(reference_attention), w)(q, k, v)
+    for attend in (kernels, _widened(kernels)):
+        (_, o), grads = _through(attend, w)(q, k, v)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
+        for name, got, want in zip(("dq", "dk", "dv"), grads, grads_ref):
+            assert got.shape == want.shape, name
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), atol=1e-4, err_msg=name
+            )
 
 
-def test_the_dispatcher_widens_a_group_of_eight_at_256(monkeypatch):
+@pytest.mark.parametrize("flag, path, heads", [
+    (None, "kernel", 2), ("0", "xla", 16),
+], ids=["kernels", "xla"])
+def test_the_dispatcher_hands_two_key_value_heads_to_the_kernels_as_they_lie(
+    monkeypatch, flag, path, heads
+):
     """On a TPU the call of the cell's shape, 16 query heads of 256 over
     2 key-value heads at 8192 tokens, reaches the kernels with k and v
-    widened to 16 heads, at the ladder's tiles. Traced only."""
+    as they came, 2 heads, at the ladder's tiles; k and v are widened to
+    the 16 in front of XLA's path and of it alone. Traced only."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+    if flag is None:
+        monkeypatch.delenv("EDL_TPU_FLASH", raising=False)
+    else:
+        monkeypatch.setenv("EDL_TPU_FLASH", flag)
     seen = []
-    monkeypatch.setattr(
-        fa, "flash_attention",
-        lambda q, k, v, *a, **kw: seen.append((q.shape, k.shape, v.shape)) or q,
-    )
+
+    def taking(name):
+        return lambda q, k, v, *a, **kw: seen.append(
+            (name, q.shape, k.shape, v.shape)
+        ) or q
+
+    monkeypatch.setattr(fa, "flash_attention", taking("kernel"))
+    monkeypatch.setattr(fa, "reference_attention", taking("xla"))
     q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16)
     jax.eval_shape(lambda q, k, v: fa.attention(q, k, v), q, kv, kv)
-    assert seen == [((1, 8192, 16, 256),) * 3]
+    assert seen == [
+        (path, (1, 8192, 16, 256), *((1, 8192, heads, 256),) * 2)
+    ]
     assert fa.pick_tiles(8192) == (1024, 1024)

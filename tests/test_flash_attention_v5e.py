@@ -63,14 +63,31 @@ def no_compile_cache():
     (1, 4096, 40, 64, None, 128),  # its full and cross layers
     (1, 16384, 28, 128, 4096),  # smallthinker-21b-a3b's sliding layers
     (1, 16384, 28, 128),  # its full layer: the longest sequence a cell runs
+    # fewer key-value heads, read where they lie (PR 63): the six
+    # grouped cells' calls as the dispatcher hands them over
+    (1, 8192, 64, 128, 512, None, 8),  # laguna-xs2's sliding layers
+    (1, 8192, 48, 128, None, None, 8),  # its full layers: a group of 6
+    (1, 16384, 28, 128, 4096, None, 4),  # smallthinker-21b-a3b: a group of 7
+    (1, 8192, 16, 256, None, None, 2),  # qwen3-next-80b-a3b
+    (4, 2048, 32, 64, None, None, 8),  # lfm2-24b-a2b: folded at 8 heads
+    (1, 4096, 40, 64, 512, 128, 20),  # phi-4: k folded, the pairs' v in place
 ], ids=lambda s: "x".join(map(str, s[:4])) + "".join(
-    f"-{n}{x}" for n, x in zip("wv", s[4:]) if x
+    f"-{n}{x}" for n, x in zip(("w", "v", "kv"), s[4:]) if x
 ))
 def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
-    shape, (window, v_width) = shape[:4], (*shape[4:], None, None)[:2]
+    shape, (window, v_width, kv_heads) = (
+        shape[:4], (*shape[4:], None, None, None)[:3]
+    )
+    b, L, h, d = shape
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    v = jax.ShapeDtypeStruct(
-        (*shape[:3], v_width or shape[3]), jnp.bfloat16, sharding=one_chip
+    k = jax.ShapeDtypeStruct(
+        (b, L, kv_heads or h, d), jnp.bfloat16, sharding=one_chip
+    )
+    v, w = (
+        jax.ShapeDtypeStruct(
+            (b, L, heads, v_width or d), jnp.bfloat16, sharding=one_chip
+        )
+        for heads in (kv_heads or h, h)
     )
     tiles = fa.pick_tiles(shape[1], window)
     band = window and fa._Band(shape[1], *tiles, window)
@@ -83,12 +100,11 @@ def test_the_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape):
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
 
     compiled = (
-        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, v, v).compile()
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, k, v, w).compile()
     )
     # forward, dq, dk+dv, each under the scope it was traced in
     assert hlo_scopes.kernels(compiled.as_text()) == {"attention": 3}
     # nothing quadratic in L is set aside: O(L*D) residuals and copies
-    b, L, h, d = shape
     assert compiled.memory_analysis().temp_size_in_bytes < 20 * b * L * h * max(d, 128)
 
 

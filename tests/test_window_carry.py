@@ -388,6 +388,52 @@ def test_the_window_s_setup_span_says_which_expert_widths_it_pads(
     )
 
 
+@pytest.mark.parametrize("zoo", ["dense", "grouped", "grouped-on-xla"])
+def test_the_window_s_setup_span_says_which_groups_the_kernels_read_in_place(
+    monkeypatch, zoo
+):
+    """`kv_groups`: the distinct (query heads, key-value heads) of the
+    attention calls with fewer key-value heads that the window's trace
+    handed to the Pallas kernels, k and v as they lay
+    (`ops/flash_attention.groups_traced`). None for equal heads, and
+    none where XLA's path took the call and widened it. The fixture has
+    4 query heads on 2; the kernels run in the interpreter here, at the
+    one tile that 128 tokens are."""
+    import functools
+
+    from elasticdl_tpu.ops import flash_attention as fa
+    from tests.fixtures import shortconv_lm_tiny
+
+    if zoo != "grouped-on-xla":  # the dispatcher on a TPU, told to engage
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setenv("EDL_TPU_FLASH", "1")
+        monkeypatch.setattr(
+            fa, "flash_attention",
+            functools.partial(fa.flash_attention, interpret=True),
+        )
+    trace.RECORDER.clear()
+    worker = lm_worker(
+        zoo=transformer_lm_zoo if zoo == "dense" else shortconv_lm_tiny
+    )
+    tokens = np.random.default_rng(0).integers(
+        0, 64, (WINDOW, 2, fa.BLOCK + 1)
+    ).astype(np.int32)
+    worker._local_window_fn = worker._build_local_window_fn()
+    state = worker._spec.optimizer().init(worker._flat)
+    *_, loss = worker._run_window(
+        worker._flat, state, worker._aux, tokens[..., :-1], tokens[..., 1:]
+    )
+    assert np.isfinite(np.asarray(loss)).all()
+    programs = _programs()
+    assert programs["jit_window"]["kv_groups"] == (
+        [(4, 2)] if zoo == "grouped" else []
+    )
+    assert all(
+        "kv_groups" not in args
+        for name, args in programs.items() if name != "jit_window"
+    )
+
+
 # -- (e) donation and the warm-up --------------------------------------------
 
 
